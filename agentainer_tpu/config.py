@@ -305,7 +305,12 @@ def load_config(path: str | None = None) -> Config:
     cfg.store_url = env.get("ATPU_STORE_URL", cfg.store_url)
     cfg.data_dir = env.get("ATPU_DATA_DIR", cfg.data_dir)
     if "ATPU_SLICE_CHIPS" in env:
+        # the slice size is a fact about the machine the daemon runs on
+        # (1 for a single-chip host, 4 for a v5e 2x2): engines are bound to
+        # the chips the scheduler hands out of it
         cfg.slice.total_chips = int(env["ATPU_SLICE_CHIPS"])
+        if "name" not in sl:
+            cfg.slice.name = f"v5e-{cfg.slice.total_chips}"
     if "ATPU_SLICE_HOSTS" in env:
         cfg.slice.hosts = int(env["ATPU_SLICE_HOSTS"])
     if "ATPU_DEADLINES" in env:

@@ -1,4 +1,4 @@
-"""Framework error taxonomy.
+"""Framework error hierarchy.
 
 The reference returns ``fmt.Errorf`` strings surfaced as HTTP 4xx/5xx by the
 API layer (e.g. "agent not found" → 404, server.go:236-241). Typed exceptions

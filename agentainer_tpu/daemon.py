@@ -123,11 +123,21 @@ def build_services(
             else:
                 url = "mem://"
         store = open_store(url)
+    # the slice this machine holds, as configured for it (slice.total_chips
+    # / ATPU_SLICE_CHIPS): the scheduler places onto it and the backend
+    # binds each engine process to its placement's chips
+    topo = SliceTopology(
+        total_chips=config.slice.total_chips,
+        hbm_per_chip=config.slice.hbm_per_chip,
+        name=config.slice.name,
+        hosts=config.slice.hosts,
+    )
     if backend is None:
         from .runtime.local import LocalBackend
 
         backend = LocalBackend(
             store=store,
+            topology=topo,
             restart_backoff_base_s=config.resilience.restart_backoff_base_s,
             restart_backoff_max_s=config.resilience.restart_backoff_max_s,
             restart_window_s=config.resilience.restart_window_s,
@@ -138,12 +148,6 @@ def build_services(
     # multi-host note: jax.distributed is joined by the ENGINE subprocesses
     # (runtime/engine_main.py) — they run the JAX compute; the control-plane
     # daemon must never block on the cluster barrier.
-    topo = SliceTopology(
-        total_chips=config.slice.total_chips,
-        hbm_per_chip=config.slice.hbm_per_chip,
-        name=config.slice.name,
-        hosts=config.slice.hosts,
-    )
     scheduler = SliceScheduler(store, topo)
     manager = AgentManager(store, backend, scheduler)
     journal = RequestJournal(store)

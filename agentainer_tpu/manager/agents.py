@@ -200,9 +200,11 @@ class AgentManager:
         The single-replica path is the pre-fleet behavior exactly: one
         engine, ``replica_ids`` mirrors ``engine_id``. With N > 1 each
         replica is created with its own ordinal (its own process/failure
-        domain in the backend) over the agent's one chip placement, and a
-        fresh lease is registered so the replica monitor starts from an
-        ALIVE view instead of a cold SUSPECT window."""
+        domain in the backend), and a fresh lease is registered so the
+        replica monitor starts from an ALIVE view instead of a cold
+        SUSPECT window. Replicas of a chip-backed engine each get their
+        OWN placement — a chip belongs to one process at a time; echo-style
+        engines open no chip and share the agent's one placement."""
         n = self.replica_count(agent)
         live = [
             eid for eid in agent.all_engine_ids() if self.backend.engine_info(eid)
@@ -211,11 +213,15 @@ class AgentManager:
             from ..engine import is_tpu_engine
 
             # JAX-backed flavors sharing a model config share weight HBM
-            share_group = agent.model.config if is_tpu_engine(agent.model.engine) else ""
-            placement = self.scheduler.placement(agent.id) or self.scheduler.allocate(
-                agent, share_group=share_group
-            )
+            on_chips = is_tpu_engine(agent.model.engine)
+            share_group = agent.model.config if on_chips else ""
             for i in range(len(live), n):
+                ordinal = i if on_chips else 0
+                placement = self.scheduler.placement(
+                    agent.id, ordinal
+                ) or self.scheduler.allocate(
+                    agent, share_group=share_group, replica=ordinal
+                )
                 live.append(
                     self.backend.create_engine(
                         agent, placement.chips, replica_index=i

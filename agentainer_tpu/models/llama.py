@@ -27,10 +27,9 @@ from jax import lax
 
 from ..ops.attention import (
     attention_reference,
-    cache_attention,
     causal_mask,
     flash_attention,
-    paged_cache_attention,
+    plan_cache_attention,
     scatter_paged_kv,
 )
 from ..ops.norms import rms_norm
@@ -55,14 +54,15 @@ class KVCache(NamedTuple):
 
 class PagedKVCache(NamedTuple):
     """Block-table KV arena: a global pool of fixed-size pages
-    ``[L, n_pages, page_size, KV, hd]``. A sequence owns a LIST of pages
-    (its block table row) instead of a dense arena row, so resident
-    sessions are bounded by the pool, not the compiled batch width, and
-    shared prefixes are refcounted page mappings instead of copies. Same
-    pytree shape discipline as :class:`KVCache` (two leaves, leading layer
-    axis) so the engine's scan/donation/sharding machinery applies
-    unchanged — under tp the KV-head axis (3) shards exactly like the
-    dense arena's."""
+    ``[L, n_pages, KV, page_size, hd]`` (KV heads outside the page, so a
+    (page, head) block is one tile-aligned ``[page_size, hd]`` slab — see
+    ops/attention.py). A sequence owns a LIST of pages (its block table
+    row) instead of a dense arena row, so resident sessions are bounded by
+    the pool, not the compiled batch width, and shared prefixes are
+    refcounted page mappings instead of copies. Same pytree shape
+    discipline as :class:`KVCache` (two leaves, leading layer axis) so the
+    engine's scan/donation/sharding machinery applies unchanged — under tp
+    the KV-head axis (2) shards like the dense arena's."""
 
     k: jnp.ndarray
     v: jnp.ndarray
@@ -74,7 +74,7 @@ class PagedKVCache(NamedTuple):
         page_size: int,
         dtype: jnp.dtype = jnp.bfloat16,
     ) -> "PagedKVCache":
-        shape = (cfg.n_layers, n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+        shape = (cfg.n_layers, n_pages, cfg.n_kv_heads, page_size, cfg.head_dim)
         return PagedKVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
 
 
@@ -254,25 +254,27 @@ def _attention_block(
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
 
-    if ck is not None and block_table is not None:
-        # paged arena: write through the block table into pool pages, then
-        # attend over the gathered page view — same masking rule, same
-        # numbers as the dense scatter+attend below (bit-exact parity)
-        ck, cv = scatter_paged_kv(ck, cv, k, v, block_table, positions)
-        attn = paged_cache_attention(
-            q, ck, cv, block_table, positions, use_pallas=use_flash
-        )
-    elif ck is not None:
-        # scatter this step's K/V into the arena at per-sequence positions
-        batch_idx = jnp.arange(b)[:, None]
-        ck = ck.at[batch_idx, positions].set(k)
-        cv = cv.at[batch_idx, positions].set(v)
-        if cache_attn_impl is not None:
-            # meshed engines: per-device Pallas flash via shard_map
-            # (parallel/flash_mesh.py) — GSPMD can't partition pallas_call
-            attn = cache_attn_impl(q, ck, cv, positions)
+    if ck is not None:
+        if block_table is not None:
+            # paged arena: write through the block table into pool pages —
+            # same masking rule, same numbers as the dense scatter below
+            ck, cv = scatter_paged_kv(ck, cv, k, v, block_table, positions)
         else:
-            attn = cache_attention(q, ck, cv, positions, use_pallas=use_flash)
+            # scatter this step's K/V into the arena at per-sequence positions
+            batch_idx = jnp.arange(b)[:, None]
+            ck = ck.at[batch_idx, positions].set(k)
+            cv = cv.at[batch_idx, positions].set(v)
+        if cache_attn_impl is None:
+            # engines choose once at build and pass their choice in (it is
+            # what they report); direct callers get the same choice here
+            cache_attn_impl = plan_cache_attention(
+                cfg.n_heads,
+                cfg.n_kv_heads,
+                cfg.head_dim,
+                page_size=ck.shape[2] if block_table is not None else 0,
+                use_pallas=use_flash,
+            ).fn
+        attn = cache_attn_impl(q, ck, cv, positions, block_table)
     elif attn_impl is not None:
         # caller-supplied causal self-attention: the sequence-parallel
         # training path passes ring/Ulysses attention here (q/k/v are
@@ -311,7 +313,7 @@ def forward(
     """
     x = embed_lookup(params["embed"], tokens)
     if cache is not None:
-        mask = None  # cache_attention masks from positions (in-kernel on TPU)
+        mask = None  # arena attention masks from positions (in-kernel on TPU)
     else:
         t = tokens.shape[1]
         mask = jnp.broadcast_to(causal_mask(t), (tokens.shape[0], t, t))

@@ -5,14 +5,19 @@ path is einsum-shaped so the compiler tiles it onto the MXU; softmax runs in
 float32. GQA is handled by grouping query heads over shared KV heads rather
 than materializing repeated K/V (saves HBM bandwidth, the usual bottleneck).
 
-``flash_attention`` dispatches to the Pallas blockwise kernel
-(ops/pallas_attention.py) on TPU when shapes allow, else falls back to the
-reference path — CI runs the same code on CPU meshes.
+Which implementation serves a call is decided in ONE place,
+``plan_cache_attention`` (arena attention, the serving hot path) and
+``pallas_available`` (the predicate it shares with ``flash_attention``):
+the Pallas blockwise kernels (ops/pallas_attention.py) on a TPU backend
+when shapes allow, the XLA reference elsewhere — and every decision carries
+its reason, which the engine logs and exports, so a step that runs the
+reference on a chip says so instead of hiding it.
 """
 
 from __future__ import annotations
 
 import os
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -63,14 +68,22 @@ def cache_mask(q_positions: jnp.ndarray, cache_len: int) -> jnp.ndarray:
     return slots <= q_positions[:, :, None]
 
 
-def _use_pallas(n_heads: int, n_kv_heads: int, head_dim: int) -> bool:
+def pallas_available(n_heads: int, n_kv_heads: int, head_dim: int) -> tuple[bool, str]:
+    """Whether the compiled Pallas kernels can serve these head shapes in
+    this process, and the reason either way."""
     if os.environ.get("AGENTAINER_NO_PALLAS"):
-        return False
-    if jax.default_backend() != "tpu":
-        return False
+        return False, "AGENTAINER_NO_PALLAS is set"
+    backend = jax.default_backend()
+    if backend != "tpu":
+        return False, f"backend is {backend}; the Mosaic kernels need tpu"
     from .pallas_attention import kernel_supported
 
-    return kernel_supported(n_heads, n_kv_heads, head_dim)
+    if not kernel_supported(n_heads, n_kv_heads, head_dim):
+        return False, (
+            f"heads {n_heads}/{n_kv_heads} x {head_dim}: the kernels need "
+            "head_dim % 128 == 0 and whole GQA groups"
+        )
+    return True, "tpu backend, lane-aligned heads"
 
 
 def flash_attention(
@@ -82,7 +95,7 @@ def flash_attention(
 ) -> jnp.ndarray:
     """Dispatch: Pallas blockwise kernel on TPU (prefill-shaped inputs),
     XLA reference elsewhere."""
-    if causal and mask is None and _use_pallas(q.shape[2], k.shape[2], q.shape[3]):
+    if causal and mask is None and pallas_available(q.shape[2], k.shape[2], q.shape[3])[0]:
         from .pallas_attention import flash_attention_tpu
 
         return flash_attention_tpu(q, k, v)
@@ -94,18 +107,41 @@ def flash_attention(
 # -- paged KV (block-table) variants ------------------------------------
 #
 # The paged arena replaces per-sequence arena rows with a global pool of
-# fixed-size pages ``[P, page_size, KV, hd]`` plus a per-lane block table
-# ``[B, n_blocks]`` of physical page ids (vLLM idiom). The ops below are
-# the single definition of the page addressing scheme: logical position
-# ``p`` of lane ``b`` lives at ``(block_table[b, p // page_size],
-# p % page_size)``. Attention gathers a lane's pages into a contiguous
-# arena VIEW and then runs the exact same math as the dense path — which
-# is what makes greedy decode bit-exact across the two layouts, and lets
-# CPU CI run the identical code (the gather lowers to plain XLA).
+# fixed-size pages ``[P, KV, page_size, hd]`` plus a per-lane block table
+# ``[B, n_blocks]`` of physical page ids (vLLM idiom). KV heads sit OUTSIDE
+# the page so one (page, kv-head) block is a contiguous, tile-aligned
+# ``[page_size, hd]`` slab — the shape Mosaic can DMA pool→VMEM (the last
+# two block dims must be multiples of (8, 128); with the head axis inside
+# the page the squeezed axis was second-to-last and the chip's compiler
+# refused the fused kernels). The ops below are the single definition of
+# the page addressing scheme: logical position ``p`` of lane ``b``, head
+# ``h`` lives at ``(block_table[b, p // page_size], h, p % page_size)``.
+# Attention gathers a lane's pages into a contiguous arena VIEW and then
+# runs the exact same math as the dense path — which is what makes greedy
+# decode bit-exact across the two layouts, and lets CPU CI run the
+# identical code (the gather lowers to plain XLA).
+
+
+def pages_to_rows(pages: jnp.ndarray) -> jnp.ndarray:
+    """``[..., n, KV, page_size, hd]`` pages → ``[..., n * page_size, KV,
+    hd]`` rows, the dense arena's (and the snapshot blob's) layout."""
+    *lead, n, kv, ps, hd = pages.shape
+    nd = len(lead)
+    perm = (*range(nd), nd, nd + 2, nd + 1, nd + 3)
+    return pages.transpose(perm).reshape(*lead, n * ps, kv, hd)
+
+
+def rows_to_pages(rows: jnp.ndarray, page_size: int) -> jnp.ndarray:
+    """Inverse of :func:`pages_to_rows`: ``[..., n * page_size, KV, hd]``
+    → ``[..., n, KV, page_size, hd]``."""
+    *lead, s, kv, hd = rows.shape
+    nd = len(lead)
+    perm = (*range(nd), nd, nd + 2, nd + 1, nd + 3)
+    return rows.reshape(*lead, s // page_size, page_size, kv, hd).transpose(perm)
 
 
 def gather_pages(
-    pool_k: jnp.ndarray,  # [P, page_size, KV, hd]
+    pool_k: jnp.ndarray,  # [P, KV, page_size, hd]
     pool_v: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, n_blocks] int32 physical page ids
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -115,15 +151,11 @@ def gather_pages(
     Under a tp mesh (pool sharded on the KV-head axis) the gather is
     local per shard: the page index never crosses the head split, so no
     collective is needed (pinned by tests/test_paged_hlo.py)."""
-    b, nb = block_table.shape
-    ps = pool_k.shape[1]
-    k = pool_k[block_table].reshape(b, nb * ps, *pool_k.shape[2:])
-    v = pool_v[block_table].reshape(b, nb * ps, *pool_v.shape[2:])
-    return k, v
+    return pages_to_rows(pool_k[block_table]), pages_to_rows(pool_v[block_table])
 
 
 def scatter_paged_kv(
-    pool_k: jnp.ndarray,  # [P, page_size, KV, hd]
+    pool_k: jnp.ndarray,  # [P, KV, page_size, hd]
     pool_v: jnp.ndarray,
     k_new: jnp.ndarray,  # [B, T, KV, hd]
     v_new: jnp.ndarray,
@@ -136,62 +168,122 @@ def scatter_paged_kv(
     out-of-range scatter silently DROPS) clamp to the last logical slot —
     the per-lane scratch row — so they land somewhere no live query ever
     attends instead of wrapping into a live page."""
-    ps = pool_k.shape[1]
+    ps = pool_k.shape[2]
     s = block_table.shape[1] * ps
     cpos = jnp.minimum(positions, s - 1)
     b_idx = jnp.arange(positions.shape[0])[:, None]
     pages = block_table[b_idx, cpos // ps]
     offs = cpos % ps
-    return pool_k.at[pages, offs].set(k_new), pool_v.at[pages, offs].set(v_new)
+    # advanced indices split by the head slice: the indexed dims lead, so
+    # the update shape is [B, T, KV, hd] — exactly k_new's
+    return (
+        pool_k.at[pages, :, offs].set(k_new),
+        pool_v.at[pages, :, offs].set(v_new),
+    )
 
 
-def paged_cache_attention(
-    q: jnp.ndarray,  # [B, T, H, hd]
-    pool_k: jnp.ndarray,  # [P, page_size, KV, hd]
-    pool_v: jnp.ndarray,
-    block_table: jnp.ndarray,  # [B, n_blocks]
-    positions: jnp.ndarray,  # [B, T]
-    use_pallas: bool = True,
-) -> jnp.ndarray:
-    """Attention over a paged arena: gather the lane's pages, then dispatch
-    exactly like ``cache_attention`` (Pallas flash on TPU, XLA reference
-    elsewhere). The gathered view is bit-identical to the dense arena the
-    same tokens would have produced, so paged/dense greedy parity reduces
-    to the gather being a faithful copy."""
-    if use_pallas and _use_pallas(q.shape[2], pool_k.shape[2], q.shape[3]):
-        from .pallas_attention import paged_flash_decode, paged_flash_prefill
+class CacheAttention(NamedTuple):
+    """Arena attention as an engine's compiled steps trace it, chosen once
+    at build. ``fn(q, ck, cv, positions, block_table=None)`` is the only
+    attention those steps call — there is no second dispatch behind it —
+    so ``prefill`` (what a T > 1 call runs) and ``decode`` (T == 1) name
+    what is in the compiled programs, and ``reason`` says why."""
 
-        if q.shape[1] == 1:
-            out = paged_flash_decode(
-                q[:, 0], pool_k, pool_v, block_table, positions[:, 0]
-            )
-            return out[:, None]
-        return paged_flash_prefill(q, pool_k, pool_v, block_table, positions)
+    fn: Callable
+    prefill: str
+    decode: str
+    reason: str
+
+    def describe(self) -> dict:
+        return {"prefill": self.prefill, "decode": self.decode, "reason": self.reason}
+
+
+def _reference_dense(q, ck, cv, positions, block_table=None):
+    return attention_reference(q, ck, cv, mask=cache_mask(positions, ck.shape[1]))
+
+
+def _reference_paged(q, pool_k, pool_v, positions, block_table):
     ck, cv = gather_pages(pool_k, pool_v, block_table)
-    return attention_reference(q, ck, cv, mask=cache_mask(positions, ck.shape[1]))
+    return _reference_dense(q, ck, cv, positions)
 
 
-def cache_attention(
-    q: jnp.ndarray,  # [B, T, H, hd]
-    ck: jnp.ndarray,  # [B, S, KV, hd] arena (slots >= positions are unwritten)
-    cv: jnp.ndarray,  # [B, S, KV, hd]
-    positions: jnp.ndarray,  # [B, T] int32 per-sequence absolute positions
+def pallas_dense(q, ck, cv, positions, block_table=None, interpret: bool = False):
+    """The dense flash kernels by call shape: one token per sequence runs
+    ``flash_decode``, anything longer ``flash_prefill``."""
+    from .pallas_attention import flash_decode, flash_prefill
+
+    if q.shape[1] == 1:
+        out = flash_decode(q[:, 0], ck, cv, positions[:, 0], interpret=interpret)
+        return out[:, None]
+    return flash_prefill(q, ck, cv, positions, interpret=interpret)
+
+
+def _pallas_paged_gather(q, pool_k, pool_v, positions, block_table):
+    ck, cv = gather_pages(pool_k, pool_v, block_table)
+    return pallas_dense(q, ck, cv, positions)
+
+
+def _pallas_paged_fused(q, pool_k, pool_v, positions, block_table):
+    from .pallas_attention import fused_paged_flash_decode, fused_paged_flash_prefill
+
+    if q.shape[1] == 1:
+        out = fused_paged_flash_decode(
+            q[:, 0], pool_k, pool_v, block_table, positions[:, 0]
+        )
+        return out[:, None]
+    return fused_paged_flash_prefill(q, pool_k, pool_v, block_table, positions)
+
+
+def plan_cache_attention(
+    n_heads: int,
+    n_kv_heads: int,
+    head_dim: int,
+    *,
+    page_size: int = 0,
     use_pallas: bool = True,
-) -> jnp.ndarray:
-    """Attention over the KV arena: row t sees slot j iff j <= positions[b,t].
+) -> CacheAttention:
+    """Choose the arena attention for these head shapes in this process:
+    row t sees slot j iff ``j <= positions[b, t]`` (ragged cached prefill
+    and T == 1 decode alike). ``page_size > 0`` means the cache is a page
+    pool ``[P, KV, page_size, hd]`` read through a block table.
 
-    This is the serving hot path (both ragged cached prefill and T==1
-    decode). On TPU it dispatches to the Pallas flash kernels, which build
-    the mask in-register; elsewhere it materializes ``cache_mask`` and runs
-    the XLA reference. Callers running under GSPMD sharding (TP-sharded
-    engine) pass ``use_pallas=False`` — XLA cannot auto-partition a
-    pallas_call, while it shards the einsum path along the head axis for
-    free."""
-    if use_pallas and _use_pallas(q.shape[2], ck.shape[2], q.shape[3]):
-        from .pallas_attention import flash_decode, flash_prefill
-
-        if q.shape[1] == 1:
-            out = flash_decode(q[:, 0], ck, cv, positions[:, 0])
-            return out[:, None]
-        return flash_prefill(q, ck, cv, positions)
-    return attention_reference(q, ck, cv, mask=cache_mask(positions, ck.shape[1]))
+    On a TPU backend the Pallas flash kernels build the mask in-register —
+    dense, or fused with the block-table walk for a pool (the gather +
+    dense-kernel path stays as the reference the fused kernels are A/B'd
+    against; ``AGENTAINER_PAGED_GATHER=1`` forces it). Elsewhere, and for
+    shapes the kernels cannot take, the XLA reference materializes
+    ``cache_mask``. Callers running under GSPMD sharding pass
+    ``use_pallas=False`` — XLA cannot auto-partition a pallas_call, while
+    it shards the einsum path along the head axis for free."""
+    paged = page_size > 0
+    if use_pallas:
+        ok, why = pallas_available(n_heads, n_kv_heads, head_dim)
+    else:
+        ok, why = False, "caller runs under GSPMD sharding (pallas_call cannot be partitioned)"
+    if not ok:
+        if paged:
+            name = "xla:gather_pages+attention_reference"
+            return CacheAttention(_reference_paged, name, name, why)
+        name = "xla:attention_reference"
+        return CacheAttention(_reference_dense, name, name, why)
+    if not paged:
+        return CacheAttention(
+            pallas_dense, "pallas:flash_prefill", "pallas:flash_decode", why
+        )
+    if os.environ.get("AGENTAINER_PAGED_GATHER"):
+        gather_why = "AGENTAINER_PAGED_GATHER is set"
+    elif page_size % 8:
+        gather_why = f"page_size {page_size} is not sublane-aligned (multiple of 8)"
+    else:
+        return CacheAttention(
+            _pallas_paged_fused,
+            "pallas:fused_paged_flash_prefill",
+            "pallas:fused_paged_flash_decode",
+            why,
+        )
+    return CacheAttention(
+        _pallas_paged_gather,
+        "pallas:gather_pages+flash_prefill",
+        "pallas:gather_pages+flash_decode",
+        gather_why,
+    )

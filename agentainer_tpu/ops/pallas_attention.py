@@ -35,7 +35,6 @@ ops/attention.py's reference implementation bit-for-bit in f32.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -303,27 +302,25 @@ def flash_attention_tpu(q, k, v, mask=None):
 
 
 # ---------------------------------------------------------------------------
-# paged (block-table) attention: pool [P, page_size, KV, hd] + block table
+# paged (block-table) attention: pool [P, KV, page_size, hd] + block table
 # ---------------------------------------------------------------------------
 #
-# Two implementations share the masking rule:
+# The block table and query positions ride as scalar-prefetch operands
+# (PrefetchScalarGridSpec), so each page's K/V block is DMA'd HBM→VMEM
+# straight out of the pool at ``table[b, page]`` — the index_map IS the
+# page walk; no gathered [B, S, KV, hd] arena copy ever materializes in
+# HBM. One (page, kv-head) block is a contiguous ``[page_size, hd]`` slab
+# (KV heads sit outside the page in the pool layout), which is what keeps
+# the last two block dims on the (8, 128) tiling the chip's compiler
+# demands. The innermost grid dimension iterates logical pages and the
+# online-softmax (m, l, acc) recurrence is identical to the dense kernels
+# above with block_k == page_size.
 #
-# - **Fused Mosaic kernel (TPU default).** The block table and query
-#   positions ride as scalar-prefetch operands (PrefetchScalarGridSpec), so
-#   each page's K/V block is DMA'd HBM→VMEM straight out of the pool at
-#   ``table[b, page]`` — the index_map IS the page walk; no gathered
-#   [B, S, KV, hd] arena copy ever materializes in HBM. The innermost grid
-#   dimension iterates logical pages and the online-softmax (m, l, acc)
-#   recurrence is identical to the dense kernels above with
-#   block_k == page_size.
-# - **Gather + dense flash (reference / fallback).** One XLA dynamic-gather
-#   into a contiguous arena view, then the dense kernels. CPU CI A/Bs the
-#   fused kernels (interpret=True) against this path bit-for-bit in f32
-#   (tests/test_pallas_attention.py); AGENTAINER_PAGED_GATHER=1 forces it
-#   on TPU for on-device A/B.
-#
-# ``paged_flash_prefill`` / ``paged_flash_decode`` remain the dispatch
-# seam: callers (ops/attention.py) never see which path ran.
+# The reference these kernels are A/B'd against is gather + dense flash
+# (ops/attention.py: one XLA dynamic-gather into a contiguous arena view,
+# then the dense kernels): bit-for-bit in f32 under interpret=True on CPU
+# (tests/test_pallas_attention.py), and selectable on a chip with
+# AGENTAINER_PAGED_GATHER=1. ``plan_cache_attention`` picks between them.
 
 
 def _paged_prefill_kernel(
@@ -396,7 +393,7 @@ def _paged_prefill_kernel(
 @functools.partial(jax.jit, static_argnames=("block_q", "interpret"))
 def fused_paged_flash_prefill(
     q: jnp.ndarray,  # [B, T, H, hd]
-    pool_k: jnp.ndarray,  # [P, page_size, KV, hd]
+    pool_k: jnp.ndarray,  # [P, KV, page_size, hd]
     pool_v: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, n_blocks] int32
     q_positions: jnp.ndarray,  # [B, T] int32
@@ -407,7 +404,7 @@ def fused_paged_flash_prefill(
     K/V index_map reads ``table[b, page]`` from scalar-prefetch SMEM, so
     page blocks stream pool→VMEM with no gathered arena in between."""
     b, t, h, hd = q.shape
-    ps, kv = pool_k.shape[1], pool_k.shape[2]
+    kv, ps = pool_k.shape[1], pool_k.shape[2]
     n_blocks = block_table.shape[1]
     g = h // kv
     bq = min(block_q, _round_up(t, 8))
@@ -434,12 +431,12 @@ def fused_paged_flash_prefill(
             ),
             # the page walk: block index into the pool comes from the table
             pl.BlockSpec(
-                (None, ps, None, hd),
-                lambda ib, ih, iq, ik, tbl: (tbl[ib, ik], 0, ih, 0),
+                (None, None, ps, hd),
+                lambda ib, ih, iq, ik, tbl: (tbl[ib, ik], ih, 0, 0),
             ),
             pl.BlockSpec(
-                (None, ps, None, hd),
-                lambda ib, ih, iq, ik, tbl: (tbl[ib, ik], 0, ih, 0),
+                (None, None, ps, hd),
+                lambda ib, ih, iq, ik, tbl: (tbl[ib, ik], ih, 0, 0),
             ),
         ],
         out_specs=pl.BlockSpec(
@@ -526,7 +523,7 @@ def _paged_decode_kernel(
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def fused_paged_flash_decode(
     q: jnp.ndarray,  # [B, H, hd]
-    pool_k: jnp.ndarray,  # [P, page_size, KV, hd]
+    pool_k: jnp.ndarray,  # [P, KV, page_size, hd]
     pool_v: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, n_blocks] int32
     q_positions: jnp.ndarray,  # [B] int32
@@ -536,7 +533,7 @@ def fused_paged_flash_decode(
     the grid (block_k == page_size); pages past the lane's position are
     skipped entirely — decode reads exactly the live pages from HBM."""
     b, h, hd = q.shape
-    ps, kv = pool_k.shape[1], pool_k.shape[2]
+    kv, ps = pool_k.shape[1], pool_k.shape[2]
     n_blocks = block_table.shape[1]
     g = h // kv
     seq_len_k = n_blocks * ps
@@ -557,12 +554,12 @@ def fused_paged_flash_decode(
                 (None, None, g, hd), lambda ib, ih, ip, tbl, pos: (ib, ih, 0, 0)
             ),
             pl.BlockSpec(
-                (None, ps, None, hd),
-                lambda ib, ih, ip, tbl, pos: (tbl[ib, ip], 0, ih, 0),
+                (None, None, ps, hd),
+                lambda ib, ih, ip, tbl, pos: (tbl[ib, ip], ih, 0, 0),
             ),
             pl.BlockSpec(
-                (None, ps, None, hd),
-                lambda ib, ih, ip, tbl, pos: (tbl[ib, ip], 0, ih, 0),
+                (None, None, ps, hd),
+                lambda ib, ih, ip, tbl, pos: (tbl[ib, ip], ih, 0, 0),
             ),
         ],
         out_specs=pl.BlockSpec(
@@ -587,49 +584,3 @@ def fused_paged_flash_decode(
         pool_v,
     )
     return out.reshape(b, h, hd)
-
-
-def _fused_paged_enabled(page_size: int, head_dim: int) -> bool:
-    """The fused kernels need sublane-aligned pages and lane-aligned heads;
-    AGENTAINER_PAGED_GATHER=1 forces the gather reference for on-TPU A/B."""
-    if os.environ.get("AGENTAINER_PAGED_GATHER"):
-        return False
-    return (
-        jax.default_backend() == "tpu"
-        and page_size % 8 == 0
-        and head_dim % 128 == 0
-    )
-
-
-def paged_flash_prefill(
-    q: jnp.ndarray,  # [B, T, H, hd]
-    pool_k: jnp.ndarray,  # [P, page_size, KV, hd]
-    pool_v: jnp.ndarray,
-    block_table: jnp.ndarray,  # [B, n_blocks] int32
-    q_positions: jnp.ndarray,  # [B, T] int32
-) -> jnp.ndarray:
-    if _fused_paged_enabled(pool_k.shape[1], q.shape[-1]):
-        return fused_paged_flash_prefill(
-            q, pool_k, pool_v, block_table, q_positions
-        )
-    from .attention import gather_pages  # deferred: attention.py imports us
-
-    k, v = gather_pages(pool_k, pool_v, block_table)
-    return flash_prefill(q, k, v, q_positions)
-
-
-def paged_flash_decode(
-    q: jnp.ndarray,  # [B, H, hd]
-    pool_k: jnp.ndarray,  # [P, page_size, KV, hd]
-    pool_v: jnp.ndarray,
-    block_table: jnp.ndarray,  # [B, n_blocks] int32
-    q_positions: jnp.ndarray,  # [B] int32
-) -> jnp.ndarray:
-    if _fused_paged_enabled(pool_k.shape[1], q.shape[-1]):
-        return fused_paged_flash_decode(
-            q, pool_k, pool_v, block_table, q_positions
-        )
-    from .attention import gather_pages  # deferred: attention.py imports us
-
-    k, v = gather_pages(pool_k, pool_v, block_table)
-    return flash_decode(q, k, v, q_positions)

@@ -23,9 +23,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from .compat import shard_map
 
 from ..models.configs import ModelConfig
 from ..models.llama import _moe_mlp_routed

@@ -22,16 +22,15 @@ from __future__ import annotations
 
 import functools as _functools
 
+import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
+from ..ops.attention import CacheAttention, pallas_dense, plan_cache_attention
+from ..ops.pallas_attention import flash_prefill
+
 # the kernels' per-device bodies are value-replicated by construction but
-# typed "varying" — run every map with the vma/rep check off (compat.py
-# translates check_vma to the old API's check_rep when needed)
-from .compat import shard_map as _shard_map
-
-shard_map = _functools.partial(_shard_map, check_vma=False)
-
-from ..ops.pallas_attention import flash_decode, flash_prefill
+# typed "varying" — run every map with the vma check off
+shard_map = _functools.partial(jax.shard_map, check_vma=False)
 
 
 def make_meshed_cache_attention(mesh: Mesh, interpret: bool = False):
@@ -43,18 +42,17 @@ def make_meshed_cache_attention(mesh: Mesh, interpret: bool = False):
     cspec = P("dp", None, "tp", None)
     pspec = P("dp", None)
 
-    def local(q, ck, cv, pos):
-        if q.shape[1] == 1:  # decode: one token per sequence
-            out = flash_decode(q[:, 0], ck, cv, pos[:, 0], interpret=interpret)
-            return out[:, None]
-        return flash_prefill(q, ck, cv, pos, interpret=interpret)
-
-    return shard_map(
-        local,
+    mapped = shard_map(
+        _functools.partial(pallas_dense, interpret=interpret),
         mesh=mesh,
         in_specs=(qspec, cspec, cspec, pspec),
         out_specs=qspec,
     )
+
+    def attn(q, ck, cv, positions, block_table=None):
+        return mapped(q, ck, cv, positions)
+
+    return attn
 
 
 def make_meshed_causal_attention(mesh: Mesh, interpret: bool = False):
@@ -133,20 +131,41 @@ def supported(cfg, tp: int) -> bool:
     )
 
 
-def resolve_mesh_flash(cfg, tp: int) -> bool | None:
-    """One policy for every meshed-flash call site (serve + train):
-    returns the ``interpret`` flag to build the shard_map kernels with, or
-    None when the meshed einsum path should be used instead. Compiled
-    kernels on TPU when the per-device shapes satisfy them;
+def resolve_mesh_flash(cfg, tp: int) -> tuple[bool | None, str]:
+    """One policy for every meshed-flash call site (serve + train): the
+    ``interpret`` flag to build the shard_map kernels with — or None when
+    the meshed einsum path should be used instead — and the reason.
+    Compiled kernels on TPU when the per-device shapes satisfy them;
     ``ATPU_FORCE_MESH_FLASH`` forces interpret mode anywhere (CPU CI and
     unsupported shapes exercise the identical shard_map path)."""
     import os
 
-    import jax
-
-    on_tpu = jax.default_backend() == "tpu"
-    if on_tpu and supported(cfg, tp):
-        return False
+    backend = jax.default_backend()
+    if backend == "tpu" and supported(cfg, tp):
+        return False, f"tpu backend, per-device heads fit the kernels at tp={tp}"
     if os.environ.get("ATPU_FORCE_MESH_FLASH", ""):
-        return True
-    return None
+        return True, "ATPU_FORCE_MESH_FLASH is set"
+    if backend != "tpu":
+        return None, f"backend is {backend}; the Mosaic kernels need tpu"
+    return None, (
+        f"per-device heads {cfg.n_heads // tp}/{cfg.n_kv_heads / tp:g} x "
+        f"{cfg.head_dim} at tp={tp} do not fit the kernels"
+    )
+
+
+def plan_meshed_cache_attention(cfg, mesh: Mesh, tp: int) -> CacheAttention:
+    """The meshed engine's arena attention (dense arena, sp == pp == 1):
+    the flash kernels per device under shard_map when ``resolve_mesh_flash``
+    allows, else the einsum reference GSPMD partitions."""
+    interpret, why = resolve_mesh_flash(cfg, tp)
+    if interpret is None:
+        return plan_cache_attention(
+            cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, use_pallas=False
+        )._replace(reason=why)
+    mode = "pallas-interpret" if interpret else "pallas"
+    return CacheAttention(
+        make_meshed_cache_attention(mesh, interpret=interpret),
+        f"{mode}:shard_map(flash_prefill)",
+        f"{mode}:shard_map(flash_decode)",
+        why,
+    )

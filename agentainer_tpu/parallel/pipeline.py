@@ -27,8 +27,8 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from .compat import pcast, shard_map
+from jax import lax, shard_map
+from jax.lax import pcast
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.configs import ModelConfig

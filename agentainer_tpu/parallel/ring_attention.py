@@ -19,9 +19,9 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.lax import pcast
 from jax.sharding import Mesh, PartitionSpec as P
-from .compat import pcast, shard_map
 
 NEG_INF = -1e30
 

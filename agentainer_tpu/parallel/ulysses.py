@@ -14,9 +14,8 @@ from __future__ import annotations
 from functools import partial
 
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from .compat import shard_map
 
 from ..ops.attention import attention_reference, causal_mask
 

@@ -37,6 +37,13 @@ class Placement:
     chips: tuple[int, ...]
     hbm_bytes: int
     share_group: str = ""  # e.g. model config name when weights are shared
+    # fleet replica ordinal: replicas of a chip-backed agent are separate
+    # processes, and a chip belongs to one process — each gets its own chips
+    replica: int = 0
+
+    @property
+    def key(self) -> str:
+        return _placement_key(self.agent_id, self.replica)
 
     def to_dict(self) -> dict:
         return {
@@ -44,6 +51,7 @@ class Placement:
             "chips": list(self.chips),
             "hbm_bytes": self.hbm_bytes,
             "share_group": self.share_group,
+            "replica": self.replica,
         }
 
     @staticmethod
@@ -53,7 +61,12 @@ class Placement:
             chips=tuple(d["chips"]),
             hbm_bytes=int(d["hbm_bytes"]),
             share_group=d.get("share_group", ""),
+            replica=int(d.get("replica", 0)),
         )
+
+
+def _placement_key(agent_id: str, replica: int) -> str:
+    return agent_id if replica == 0 else f"{agent_id}#r{replica}"
 
 
 @dataclass
@@ -157,7 +170,9 @@ class SliceScheduler:
     def _load(self) -> None:
         raw = self._store.get_json(Keys.SLICE_ALLOCATIONS)
         if raw:
-            self._placements = {p["agent_id"]: Placement.from_dict(p) for p in raw}
+            self._placements = {
+                p.key: p for p in (Placement.from_dict(d) for d in raw)
+            }
 
     def _save(self) -> None:
         self._store.set_json(
@@ -193,18 +208,45 @@ class SliceScheduler:
         return usage
 
     # -- API -------------------------------------------------------------
-    def allocate(self, agent: Agent, share_group: str = "") -> Placement:
+    def allocate(self, agent: Agent, share_group: str = "", replica: int = 0) -> Placement:
+        """Place ``agent`` (or its ``replica``-th fleet replica).
+
+        A non-empty ``share_group`` marks a chip-backed engine: members of
+        one group co-locate (one host process, weights counted once), while
+        chips held by a DIFFERENT group are off limits — two processes
+        cannot open one chip. Replicas of an agent are separate processes
+        by design, so replica i > 0 forms its own group and avoids the
+        chips of the agent's other replicas."""
         with self._lock:
-            if agent.id in self._placements:
-                return self._placements[agent.id]
+            key = _placement_key(agent.id, replica)
+            if key in self._placements:
+                return self._placements[key]
             n = max(1, agent.resources.chips)
             if n > self.topology.total_chips:
                 raise ResourceExhausted(
                     f"requested {n} chips but slice {self.topology.name} has "
                     f"{self.topology.total_chips}"
                 )
+            if share_group and replica:
+                share_group = f"{share_group}#r{replica}"
             need_per_chip = agent.resources.hbm_bytes // n
             usage = self._chip_usage()
+            taken: set[int] = set()
+            if share_group:
+                taken = {
+                    c
+                    for p in self._placements.values()
+                    if p.share_group and p.share_group != share_group
+                    for c in p.chips
+                }
+
+            def place(chips: tuple[int, ...], group: str) -> Placement:
+                placement = Placement(
+                    agent.id, chips, agent.resources.hbm_bytes, group, replica
+                )
+                self._placements[key] = placement
+                self._save()
+                return placement
 
             # Weight sharing: prefer the chips the share group already owns —
             # but only if raising the group's per-chip claim still fits
@@ -219,12 +261,7 @@ class SliceScheduler:
                     )
                     delta = max(0, need_per_chip - current_claim)
                     if all(usage[c] + delta <= self.topology.hbm_per_chip for c in chips):
-                        placement = Placement(
-                            agent.id, chips, agent.resources.hbm_bytes, share_group
-                        )
-                        self._placements[agent.id] = placement
-                        self._save()
-                        return placement
+                        return place(chips, share_group)
                     # group chips can't absorb the larger claim: place solo
                     # (weights not shared rather than silently overcommitted)
                     share_group = ""
@@ -232,25 +269,29 @@ class SliceScheduler:
             # First-fit over ICI-adjacent windows (sub-rectangles of the
             # 2-D chip grid, squarer first — see SliceTopology.windows).
             for window in self.topology.windows(n):
-                if all(usage[c] + need_per_chip <= self.topology.hbm_per_chip for c in window):
-                    placement = Placement(agent.id, window, agent.resources.hbm_bytes, share_group)
-                    self._placements[agent.id] = placement
-                    self._save()
-                    return placement
+                if taken.isdisjoint(window) and all(
+                    usage[c] + need_per_chip <= self.topology.hbm_per_chip for c in window
+                ):
+                    return place(window, share_group)
             raise ResourceExhausted(
                 f"no ICI-adjacent {n}-chip window with {need_per_chip} B free HBM per chip "
                 f"on {self.topology.name} ({self.topology.mesh_shape[0]}x"
                 f"{self.topology.mesh_shape[1]} mesh)"
+                + (f"; chips {sorted(taken)} belong to other engine processes" if taken else "")
             )
 
     def release(self, agent_id: str) -> None:
+        """Drop every placement of the agent, its replicas' included."""
         with self._lock:
-            if self._placements.pop(agent_id, None) is not None:
+            keys = [k for k, p in self._placements.items() if p.agent_id == agent_id]
+            for k in keys:
+                del self._placements[k]
+            if keys:
                 self._save()
 
-    def placement(self, agent_id: str) -> Placement | None:
+    def placement(self, agent_id: str, replica: int = 0) -> Placement | None:
         with self._lock:
-            return self._placements.get(agent_id)
+            return self._placements.get(_placement_key(agent_id, replica))
 
     def placements(self) -> list[Placement]:
         with self._lock:

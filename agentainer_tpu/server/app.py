@@ -347,6 +347,12 @@ class ControlPlaneApp:
                 "status": "healthy",
                 "agents": len(self.s.manager.agent_ids()),
                 "slice": self.s.scheduler.topology.name,
+                "slice_chips": self.s.scheduler.topology.total_chips,
+                # which front door answers /agent/*: the C++ data plane, or
+                # the aiohttp proxy it falls back to when the native
+                # library did not build — visible, so a fallback is a fact
+                # an operator (and chip_smoke.py) can read, not a silence
+                "data_plane": "native" if self.s.dataplane is not None else "python",
                 "time": time.time(),
             }
         )
@@ -593,6 +599,24 @@ class ControlPlaneApp:
             return None
         return self.router.stats(agent)
 
+    # what an operator asks of ONE replica: which process it is, which
+    # chips it was bound to and which device it computes on, whether its
+    # model is up, and whether traffic reaches it
+    _REPLICA_ENGINE_KEYS = (
+        "replica",
+        "pid",
+        "chips",
+        "visible_chips",
+        "device",
+        "engine_devices",
+        "model_loaded",
+        "engine_error",
+        "engine_load_s",
+        "compile_cache",
+        "requests_total",
+        "tokens_generated",
+    )
+
     async def h_agent_metrics(self, request: web.Request) -> web.Response:
         agent_id = request.match_info["agent_id"]
         agent = self.s.manager.get_agent(agent_id)
@@ -601,6 +625,18 @@ class ControlPlaneApp:
         if fleet is not None:
             doc = dict(doc)
             doc["fleet"] = fleet
+            # the sampled engine block above is the primary's; the proxy
+            # routes /agent/{id}/metrics by affinity, so this is the one
+            # place that reads every replica's own live answer
+            eids = list(fleet["replicas"])
+            answers = await asyncio.gather(
+                *(asyncio.to_thread(self.s.backend.stats, eid) for eid in eids)
+            )
+            for eid, stats in zip(eids, answers):
+                if stats:
+                    fleet["replicas"][eid]["engine"] = {
+                        k: stats[k] for k in self._REPLICA_ENGINE_KEYS if k in stats
+                    }
         return ok(doc)
 
     async def h_agent_metrics_history(self, request: web.Request) -> web.Response:

@@ -86,7 +86,7 @@ def make_train_step(
         # f32 [B,KV,G,T,S] score materialization (VERDICT r2 weak #2)
         from .parallel.flash_mesh import make_trainable_causal_attention, resolve_mesh_flash
 
-        interp = resolve_mesh_flash(cfg, int(mesh.shape.get("tp", 1)))
+        interp, _ = resolve_mesh_flash(cfg, int(mesh.shape.get("tp", 1)))
         if interp is not None:
             attn_impl = make_trainable_causal_attention(mesh, interpret=interp)
     if sp > 1 and seq_attn != "none":

@@ -1,8 +1,7 @@
 """TPU hardware envelope: peak FLOPs and HBM bandwidth per device kind.
 
-Used for MFU/MBU accounting in the engine's metrics plane and bench_llm.py
-(VERDICT r2 items 1-2: the project had no FLOP model, so MFU could never be
-computed). Numbers are public spec-sheet peaks per CHIP; ``jax.devices()``
+Used for MFU/MBU accounting in the engine's metrics plane and bench_llm.py.
+Numbers are public spec-sheet peaks per CHIP; ``jax.devices()``
 reports one device per chip on v4+ (v2/v3 report per-core — the two-core
 kinds below carry per-core numbers for that reason).
 
@@ -12,7 +11,6 @@ a TP-sharded engine is measured against the peak of every chip it spans.
 
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass
 
 
@@ -36,36 +34,13 @@ _SPECS: tuple[ChipSpec, ...] = (
     ChipSpec("v2", 23e12, 23e12, 8 << 30, 350e9),  # per core
 )
 
-# CPU fallback keeps MFU math runnable in CI; the number is meaningless and
-# flagged by spec.kind so callers can label it.
-_CPU = ChipSpec("cpu-fallback", 1e12, 1e12, 8 << 30, 50e9)
 
-
-def chip_spec(device=None) -> ChipSpec:
-    """Spec for a jax device (default: the first visible device).
-
-    ``ATPU_PEAK_BF16_TFLOPS`` / ``ATPU_HBM_GBPS`` override for unlisted or
-    derated parts.
-    """
-    if device is None:
-        import jax
-
-        devices = jax.devices()
-        device = devices[0] if devices else None
-    kind = str(getattr(device, "device_kind", "") or "").lower()
-    spec = _CPU
-    for s in _SPECS:
-        if s.kind in kind:
-            spec = s
-            break
-    flops_env = os.environ.get("ATPU_PEAK_BF16_TFLOPS")
-    bw_env = os.environ.get("ATPU_HBM_GBPS")
-    if flops_env or bw_env:
-        spec = ChipSpec(
-            spec.kind,
-            float(flops_env) * 1e12 if flops_env else spec.bf16_flops,
-            spec.int8_ops,
-            spec.hbm_bytes,
-            float(bw_env) * 1e9 if bw_env else spec.hbm_gbps,
-        )
-    return spec
+def chip_spec(device_kind: str) -> ChipSpec | None:
+    """Spec for a jax ``device_kind``; None for a device that is not in the
+    table (a CPU, an unlisted part). A utilization against an invented peak
+    is worse than none, so callers report no MFU/MBU for an unknown kind."""
+    kind = device_kind.lower()
+    for spec in _SPECS:
+        if spec.kind in kind:
+            return spec
+    return None

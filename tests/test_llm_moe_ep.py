@@ -13,21 +13,10 @@ import jax
 import pytest
 
 from agentainer_tpu.engine.llm import LLMEngine
-from agentainer_tpu.parallel.compat import HAS_NATIVE_SHARD_MAP
 
-pytestmark = [
-    pytest.mark.skipif(
-        len(jax.devices()) < 8, reason="needs the virtual 8-device mesh"
-    ),
-    # the jax.experimental.shard_map fallback lowers the EP engine to HLO
-    # that SIGABRTs inside XLA:CPU's compiler (observed on jax 0.4.37) —
-    # a crash, not a failure, so it would take the whole suite down
-    pytest.mark.skipif(
-        not HAS_NATIVE_SHARD_MAP,
-        reason="EP serving engine needs first-class jax.shard_map "
-        "(the experimental fallback aborts XLA:CPU compilation)",
-    ),
-]
+pytestmark = pytest.mark.skipif(
+    len(jax.devices()) < 8, reason="needs the virtual 8-device mesh"
+)
 
 
 def _mk(**opts) -> LLMEngine:

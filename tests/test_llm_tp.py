@@ -41,14 +41,17 @@ def test_tp_engine_shards_params_and_cache():
         engine.shutdown()
 
 
-def test_single_chip_placement_honors_assignment():
-    """A tp==1 engine lands on its ASSIGNED chip, not device 0 — two
-    single-chip agents on one host must not stack onto the same chip."""
+def test_single_chip_placement_uses_local_device_indices():
+    """A tp==1 engine assigned slice chip 3 computes on ITS first device:
+    the backend starts each engine host seeing only its chips
+    (runtime/local.chip_visibility_env), so indices inside the process are
+    local — two single-chip agents are kept apart by their processes'
+    visibility, not by indexing a shared device list."""
     engine = LLMEngine.create("tiny", options={"chips": [3], "max_batch": 2, "max_seq": 128})
     try:
         assert engine.tp == 1
-        assert [d.id for d in engine.params["final_norm"].devices()] == [3]
-        assert [d.id for d in engine.cache.k.devices()] == [3]
+        assert [d.id for d in engine.params["final_norm"].devices()] == [0]
+        assert [d.id for d in engine.cache.k.devices()] == [0]
 
         async def go():
             return await engine.generate("placed", max_tokens=4)

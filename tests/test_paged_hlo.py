@@ -51,8 +51,8 @@ def _paged_attention(q, k_new, v_new, pool_k, pool_v, bt, positions):
 def _inputs():
     key = jax.random.PRNGKey(0)
     ks = jax.random.split(key, 5)
-    pool_k = jax.random.normal(ks[0], (POOL, PS, KV, HD), jnp.float32)
-    pool_v = jax.random.normal(ks[1], (POOL, PS, KV, HD), jnp.float32)
+    pool_k = jax.random.normal(ks[0], (POOL, KV, PS, HD), jnp.float32)
+    pool_v = jax.random.normal(ks[1], (POOL, KV, PS, HD), jnp.float32)
     q = jax.random.normal(ks[2], (B, T, H, HD), jnp.float32)
     k_new = jax.random.normal(ks[3], (B, T, KV, HD), jnp.float32)
     v_new = jax.random.normal(ks[4], (B, T, KV, HD), jnp.float32)
@@ -63,7 +63,7 @@ def _inputs():
 
 def _device_put_tp(args, mesh):
     head = NamedSharding(mesh, P(None, None, "tp", None))
-    pool = NamedSharding(mesh, P(None, None, "tp", None))
+    pool = NamedSharding(mesh, P(None, "tp", None, None))
     repl = NamedSharding(mesh, P())
     q, k_new, v_new, pool_k, pool_v, bt, pos = args
     return (

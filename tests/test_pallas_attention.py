@@ -96,8 +96,8 @@ def test_decode_matches_reference(block_k):
 
 def _paged_fixture(seed, b, nb, ps, kv, hd, n_pages):
     keys = jax.random.split(jax.random.PRNGKey(seed), 3)
-    pool_k = _rand(keys[0], n_pages, ps, kv, hd)
-    pool_v = _rand(keys[1], n_pages, ps, kv, hd)
+    pool_k = _rand(keys[0], n_pages, kv, ps, hd)
+    pool_v = _rand(keys[1], n_pages, kv, ps, hd)
     # non-trivial mapping: scrambled page ids, lane 0 and 1 SHARE page 7
     # (paged prefix sharing) — the walk must not assume contiguity or
     # exclusivity

@@ -12,22 +12,11 @@ import numpy as np
 import pytest
 
 from agentainer_tpu.models.configs import get_config
-from agentainer_tpu.parallel.compat import HAS_NATIVE_SHARD_MAP
 from agentainer_tpu.parallel.mesh import make_mesh
 from agentainer_tpu.train import make_train_step
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 4, reason="needs the virtual multi-device mesh"
-)
-
-# Differentiating the partial-manual pipeline (manual pp, auto dp/tp)
-# needs first-class jax.shard_map: the experimental fallback's backward
-# spec check rejects the scalar-loss cotangent (_SpecError). Forward-only
-# pipeline tests still run everywhere.
-requires_native_shard_map = pytest.mark.skipif(
-    not HAS_NATIVE_SHARD_MAP,
-    reason="pipeline autodiff needs first-class jax.shard_map "
-    "(jax.experimental.shard_map rejects the backward specs)",
 )
 
 CFG = get_config("tiny")  # n_layers=2 → pp=2 stages of 1 layer each
@@ -42,7 +31,6 @@ def _one_step(n_devices: int, pp: int, **kw):
     return float(loss), state
 
 
-@requires_native_shard_map
 def test_pp2_loss_matches_pp1():
     ref, _ = _one_step(1, pp=1)
     pipe, _ = _one_step(2, pp=2)
@@ -61,7 +49,6 @@ def test_pp_stages_hold_layer_shards():
     assert wq.sharding.shard_shape(wq.shape)[0] == CFG.n_layers // 2
 
 
-@requires_native_shard_map
 def test_pp_more_microbatches_and_learning():
     """M=4 microbatches over pp=2 stages: loss still matches, and two
     steps decrease it (gradients flow through ppermute's transpose)."""
@@ -76,7 +63,6 @@ def test_pp_more_microbatches_and_learning():
     assert float(l2) < float(l1)
 
 
-@requires_native_shard_map
 def test_pp_composes_with_dp_mesh_axis():
     """dp=2 × pp=2: microbatch tokens are genuinely dp-sharded (the loss()
     wrapper pins the mb axis onto dp) and the loss still matches."""
@@ -85,7 +71,6 @@ def test_pp_composes_with_dp_mesh_axis():
     np.testing.assert_allclose(pipe, ref, rtol=2e-5)
 
 
-@requires_native_shard_map
 def test_pp_composes_with_tp_mesh_axis():
     """tp=2 × pp=2: Megatron widths under GSPMD inside the partial-manual
     shard_map; loss matches the unstaged run and a step still learns."""
@@ -105,7 +90,6 @@ def test_pp_composes_with_tp_mesh_axis():
     assert float(l2) < float(l1)
 
 
-@requires_native_shard_map
 def test_pp_dp_tp_all_compose():
     """dp=2 × tp=2 × pp=2 on the full 8-device mesh."""
     if len(jax.devices()) < 8:

@@ -119,7 +119,8 @@ def test_quant_tp_matches_quant_single_chip():
 
 def test_tp_clamps_to_assigned_chips():
     """options.tp beyond the scheduler's chip assignment must NOT spill onto
-    other agents' chips (ADVICE round-1 medium): tp narrows to the span."""
+    other agents' chips (ADVICE round-1 medium): tp narrows to the span —
+    the process's first two devices, since device indices are local."""
     from agentainer_tpu.engine.llm import LLMEngine
 
     engine = LLMEngine.create(
@@ -128,7 +129,7 @@ def test_tp_clamps_to_assigned_chips():
     try:
         assert engine.tp == 2
         used = {d.id for d in engine.cache.k.sharding.device_set}
-        assert used == {2, 3}, used
+        assert used == {0, 1}, used
     finally:
         engine.shutdown()
 

@@ -1,0 +1,125 @@
+"""The chip's own compiler, asked from a sandbox that has no chip.
+
+libtpu compiles for a TPU that is *described*, not attached
+(``jax.experimental.topologies``), so the Mosaic lowering of every
+attention kernel the serving path can pick is checked here at llama3-8b
+widths — the shapes one v5e chip runs (32 query / 8 KV heads) and the
+per-device shapes of a tp=4 split (8 / 2) — plus the shard_map wrapper on
+the described 2x2 mesh. Interpret mode cannot see what this sees: a block
+whose last two dims miss the (8, 128) tiling, or a kernel over its VMEM
+budget, passes every interpret-mode parity test and is refused here.
+
+Nothing runs: a passing compile says the chip would accept the program,
+never that it is right or fast (parity lives in test_pallas_attention.py,
+on-chip numerics in chip_smoke.py).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from agentainer_tpu.ops.pallas_attention import (
+    flash_decode,
+    flash_prefill,
+    fused_paged_flash_decode,
+    fused_paged_flash_prefill,
+)
+from agentainer_tpu.parallel.flash_mesh import make_meshed_cache_attention
+
+# llama3-8b serving shapes (models/configs.py, engine defaults)
+B, S, HD = 8, 2048, 128  # max_batch, max_seq, head_dim
+T = 256  # prefill chunk
+PS = 64  # page size
+NB = S // PS  # block-table width
+POOL = B * NB + B  # data pages + one scratch page per lane
+HEADS = {"one-chip": (32, 8), "tp4-per-device": (8, 2)}
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The described v5e:2x2 topology, with the persistent compilation
+    cache off around the module: a compile for a described device is
+    written to the cache but cannot be read back without a chip, so the
+    next run would warn and compile again anyway."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+    # libtpu guards real chips with a one-process lockfile; nothing is
+    # attached here, and parallel test workers each load the compiler
+    os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here: nothing to ask
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {type(e).__name__}: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _kernel_args(kernel: str, h: int, kv: int, where):
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=where)
+
+    arena = s((B, S, kv, HD))
+    row = s((1, S, kv, HD))
+    pool = s((POOL, kv, PS, HD))
+    return {
+        "flash_prefill": (flash_prefill, (s((1, T, h, HD)), row, row, s((1, T), jnp.int32))),
+        "flash_decode": (flash_decode, (s((B, h, HD)), arena, arena, s((B,), jnp.int32))),
+        "fused_paged_flash_prefill": (
+            fused_paged_flash_prefill,
+            (s((1, T, h, HD)), pool, pool, s((1, NB), jnp.int32), s((1, T), jnp.int32)),
+        ),
+        "fused_paged_flash_decode": (
+            fused_paged_flash_decode,
+            (s((B, h, HD)), pool, pool, s((B, NB), jnp.int32), s((B,), jnp.int32)),
+        ),
+    }[kernel]
+
+
+@pytest.mark.parametrize("heads", sorted(HEADS))
+@pytest.mark.parametrize(
+    "kernel",
+    [
+        "flash_prefill",
+        "flash_decode",
+        "fused_paged_flash_prefill",
+        "fused_paged_flash_decode",
+    ],
+)
+def test_kernel_compiles_for_v5e(v5e, kernel, heads):
+    h, kv = HEADS[heads]
+    fn, args = _kernel_args(kernel, h, kv, SingleDeviceSharding(v5e.devices[0]))
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("t", [1, T], ids=["decode", "prefill"])
+def test_meshed_flash_compiles_for_v5e_2x2(v5e, t):
+    """The tp=4 engine's attention: the dense kernels per device under
+    shard_map on the described 2x2 mesh, compiled (not interpreted)."""
+    h, kv = HEADS["one-chip"]
+    mesh = Mesh(
+        np.array(v5e.devices).reshape(1, 4, 1, 1, 1),
+        axis_names=("dp", "tp", "sp", "ep", "pp"),
+    )
+    heads = NamedSharding(mesh, P("dp", None, "tp", None))
+    b = B if t == 1 else 1
+    args = (
+        jax.ShapeDtypeStruct((b, t, h, HD), jnp.bfloat16, sharding=heads),
+        jax.ShapeDtypeStruct((b, S, kv, HD), jnp.bfloat16, sharding=heads),
+        jax.ShapeDtypeStruct((b, S, kv, HD), jnp.bfloat16, sharding=heads),
+        jax.ShapeDtypeStruct(
+            (b, t), jnp.int32, sharding=NamedSharding(mesh, P("dp", None))
+        ),
+    )
+    compiled = jax.jit(make_meshed_cache_attention(mesh)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -140,11 +140,12 @@ class ExceptDiscipline(Rule):
 # the function here or tagging its def line with `# atp: hot`.
 HOT_PATHS: dict[str, re.Pattern] = {
     "agentainer_tpu/engine/llm.py": re.compile(
-        r"^(_loop|_pump_queue|_admit_waiting|_has_dispatchable|_prefill_tick"
-        r"|_decode_dispatch|_pick_chunk|_try_speculate|_spec_round|_spec_gamma"
-        r"|_spec_draft|_drain_readbacks|_process_first|_process_chunk|_finish"
-        r"|_fused_dispatch|_process_fused"
-        r"|_try_admit|_try_admit_paged|_try_admit_paged_locked|_bucket)$"
+        r"^(_loop|_serve|_pump_queue|_admit_waiting|_admit_items|_has_dispatchable"
+        r"|_prefill_tick|_prefill_chunk|_decode_dispatch|_dispatch_chunk|_pick_chunk"
+        r"|_try_speculate|_spec_round|_verify_readback|_spec_gamma"
+        r"|_spec_draft|_drain_readbacks|_process_first|_deliver_first|_process_chunk"
+        r"|_finish|_fused_dispatch|_process_fused"
+        r"|_try_admit|_prefix_fork|_try_admit_paged|_try_admit_paged_locked|_bucket)$"
     ),
 }
 

@@ -9,6 +9,12 @@ from __future__ import annotations
 # proxy ↔ engine headers
 REPLAY_HEADER = "X-Agentainer-Replay"
 REQUEST_ID_HEADER = "X-Agentainer-Request-ID"
+# when the front door had read the request, before its journal write:
+# CLOCK_REALTIME nanoseconds, stamped by the front door alone (a client's
+# value is dropped). The engine's distance to it is the journal layer's
+# dispatch time: journal write, mark_processing, replica choice, connect,
+# send. Replayed dispatches carry none.
+ACCEPTED_NS_HEADER = "X-Agentainer-Accepted-Ns"
 # end-to-end deadline: remaining milliseconds the caller will wait; the
 # proxy journals the absolute instant and forwards the remaining budget
 DEADLINE_HEADER = "X-Agentainer-Deadline-Ms"

@@ -28,6 +28,7 @@ from __future__ import annotations
 
 import asyncio
 import collections
+import functools
 import os
 import queue
 import threading
@@ -55,6 +56,7 @@ from .. import faults
 from ..models.configs import ModelConfig, get_config
 from ..models.llama import KVCache, PagedKVCache, forward, init_params
 from ..ops.attention import pages_to_rows, plan_cache_attention, rows_to_pages
+from ..utils.spans import Spans
 from .sampling import NEG_INF, sample, sample_step
 from .tokenizer import load_tokenizer
 
@@ -191,6 +193,21 @@ def _as_prefill_failure(e: Exception) -> Exception:
     if isinstance(e, (RequestAborted, EngineOverloaded, EngineShutdown)):
         return e
     return PrefillFailed(f"{type(e).__name__}: {e}")
+
+
+def _phase(name: str):
+    """Run a worker-thread method of the engine under the span ``name``
+    (spans whose trace event carries attributes are opened inline)."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def timed(self, *args, **kwargs):
+            with self._spans.span(name):
+                return fn(self, *args, **kwargs)
+
+        return timed
+
+    return wrap
 
 
 def _sharded_random_init(cfg: ModelConfig, dtype, mesh, specs: dict) -> dict:
@@ -767,7 +784,6 @@ class LLMEngine:
         self.tier_prewarm_hits_total = 0
         self.tier_demote_failures_total = 0
         self.tier_promote_failures_total = 0
-        self.tier_host_evictions_total = 0
         self.tier_promote_overlap_ms_total = 0.0
         # promote-start instants by session, consumed when the promoted
         # session's next request dispatches its first prefill chunk — the
@@ -862,9 +878,19 @@ class LLMEngine:
         self._rng = jax.random.PRNGKey(0)
         self._running = True
 
+        # where the worker thread's time goes (utils/spans.py): phase
+        # totals for metrics(), and the same spans on the profiler's clock
+        # while a /profile capture runs
+        self._spans = Spans()
+
         # counters
         self.tokens_generated = 0
         self.prefills = 0
+        # launches of the prefill step and the real tokens they carried
+        # (bucket padding excluded), and requests that got their reply
+        self.prefill_launches = 0
+        self.prefill_tokens = 0
+        self.requests_finished = 0
         self.ttft_ms_recent: collections.deque[float] = collections.deque(maxlen=256)
         self.itl_ms_recent: collections.deque[float] = collections.deque(maxlen=256)
         # TTFT phase decomposition: queue-wait (admission → first prefill
@@ -1947,6 +1973,9 @@ class LLMEngine:
         self.prefix_eviction_idle_s_recent.clear()
         self.tokens_generated = 0
         self.prefills = 0
+        self.prefill_launches = 0
+        self.prefill_tokens = 0
+        self.requests_finished = 0
         self.decode_steps = 0
         self._occupancy_sum = 0.0
         self.flops_done = 0.0
@@ -2176,6 +2205,7 @@ class LLMEngine:
         gap_ok = now - self._last_snapshot_at >= gap
         return (not gap_ok) or (busy and not overdue)
 
+    @_phase("engine.snapshot")
     def _stage_snapshot(self, cmd: SnapshotCmd, slot: Slot) -> None:
         """Stage a settled slot's prefix (worker thread), limiter-gated."""
         staged = None
@@ -2196,6 +2226,7 @@ class LLMEngine:
             staged = (k16, v16, slot.position, slot.pending_token)
         cmd.loop.call_soon_threadsafe(_resolve_value, cmd.future, staged)
 
+    @_phase("engine.snapshot")
     def _stage_snapshot_paged(self, cmd: SnapshotCmd, sess: PagedSession) -> None:
         """Paged staging: gather ONLY the session's live pages into a
         contiguous buffer — a 100-token session ships 2 pages, not a pow2
@@ -2339,6 +2370,7 @@ class LLMEngine:
             and item.session in self._host_tier
         )
 
+    @_phase("engine.tier_move")
     def _tier_demote(self, session: str, pressure: bool = False):
         """Worker thread: stage an idle session's exact KV prefix to host,
         free its device residency (pages via the quarantine discipline),
@@ -2468,8 +2500,8 @@ class LLMEngine:
             ):
                 oldest = next(iter(self._host_tier))
                 self._tier_drop_locked(oldest)
-                self.tier_host_evictions_total += 1
 
+    @_phase("engine.tier_move")
     def _tier_promote(self, session: str, prewarm: bool = False) -> bool:
         """Worker thread: swap a host-tier session back onto the device.
         The restore dispatch is ASYNC (no readback) — called from the
@@ -2608,7 +2640,6 @@ class LLMEngine:
             "tier_quantize": self.tier_quantize,
             "tier_host_sessions": host_sessions,
             "tier_host_bytes": host_bytes,
-            "tier_host_budget_bytes": self.tier_host_budget_bytes,
             "tier_quantized_pages": quantized_pages,
             "tier_demotions_total": self.tier_demotions_total,
             "tier_promotions_total": self.tier_promotions_total,
@@ -2616,7 +2647,6 @@ class LLMEngine:
             "tier_prewarm_hits_total": self.tier_prewarm_hits_total,
             "tier_demote_failures_total": self.tier_demote_failures_total,
             "tier_promote_failures_total": self.tier_promote_failures_total,
-            "tier_host_evictions_total": self.tier_host_evictions_total,
             "tier_promote_overlap_ms_total": round(
                 self.tier_promote_overlap_ms_total, 2
             ),
@@ -2700,6 +2730,7 @@ class LLMEngine:
                 self._page_refs[pid] = 1
             return ids
 
+    @_phase("engine.evict")
     def _reclaim_pages(self, need: int) -> None:
         """Evict until ``need`` pages are free (or nothing evictable is
         left): idle resident sessions LRU-first — they can re-prefill (or
@@ -2764,7 +2795,8 @@ class LLMEngine:
             if len(self._page_free) >= need or not self._page_quarantine:
                 return
         try:
-            jax.block_until_ready(self.cache.k)
+            with self._spans.span("engine.wait_device"):
+                jax.block_until_ready(self.cache.k)
         except Exception:
             return  # can't prove the writes landed; quarantine stays parked
         with self._page_lock:
@@ -3251,9 +3283,16 @@ class LLMEngine:
         pre = sorted(self.prefill_ms_recent)
         frb = sorted(self.first_readback_ms_recent)
         return {
+            # where the worker's time went, cumulative since it started
+            # (read as differences): per phase n / self_s / total_s, and
+            # loop_s, the wall time its spans tile (utils/spans.py)
+            **self._spans.snapshot(),
             "tokens_generated": self.tokens_generated,
             "tokens_per_s": round(self.tokens_generated / elapsed, 2),
             "prefills": self.prefills,
+            "prefill_launches": self.prefill_launches,
+            "prefill_tokens": self.prefill_tokens,
+            "requests_finished": self.requests_finished,
             "decode_steps": self.decode_steps,
             "batch_occupancy": round(self._occupancy_sum / max(1, self.decode_steps), 3),
             "ttft_ms_p50": round(recent[len(recent) // 2], 2) if recent else None,
@@ -3323,11 +3362,6 @@ class LLMEngine:
             "inloop_spec": self.inloop_spec,
             "inloop_spec_drafted": self.inloop_spec_drafted,
             "inloop_spec_accepted": self.inloop_spec_accepted,
-            "inloop_spec_acceptance_rate": (
-                round(self.inloop_spec_accepted / self.inloop_spec_drafted, 4)
-                if self.inloop_spec_drafted
-                else None
-            ),
             "approx_topk": self.approx_topk,
             "host_syncs_total": self.host_syncs_total,
             "host_syncs_per_token": (
@@ -3376,7 +3410,7 @@ class LLMEngine:
             # paged KV arena (block tables): pool occupancy gauges replace
             # the dense-only slot accounting as the HBM audit — resident
             # sessions are bounded by pages, not max_batch, so capacity
-            # questions are answered here, not by active_sessions alone
+            # questions are answered here
             **self._paged_metrics(),
             # tiered KV hierarchy: per-tier session counts, host-tier
             # bytes/quantized pages, demote/promote/prewarm totals, and the
@@ -3387,7 +3421,6 @@ class LLMEngine:
             # whatever warmup/compile history the deque still holds
             "ttft_samples": [round(x, 2) for x in self.ttft_ms_recent],
             "itl_samples": [round(x, 2) for x in self.itl_ms_recent],
-            "active_sessions": len(self.sessions),
             "max_batch": self.max_batch,
             "max_seq": self.max_seq,
             "tp": self.tp,
@@ -3395,13 +3428,6 @@ class LLMEngine:
             "sp": self.sp,
             "meshed_flash": self.meshed_flash,
             "moe_routed": self.routed_moe,
-            # decode-sized routed calls (t == 1) run dropless via the
-            # call-shape gate in models/llama._moe_mlp_routed for ANY
-            # max_batch (ADVICE r5: the old n<=64 gate silently reverted
-            # engines with max_batch > 64 to cf-capped routing) — only
-            # prefill can drop, bounded by the capacity factor
-            "moe_decode_dropless": self.routed_moe or None,
-            "moe_capacity_factor": self.moe_capacity_factor if self.routed_moe else None,
             # FLOP model + HBM telemetry: lifetime MFU here is a floor
             # (includes idle time); bench_llm.py samples flops_done twice
             # and computes windowed MFU over the loaded interval
@@ -3457,12 +3483,9 @@ class LLMEngine:
         with self._page_lock:
             free = len(self._page_free)
             quarantined = len(self._page_quarantine)
-            per_sess = sorted(
-                len(s.pages) for s in self.paged_sessions.values()
-            )
+            allocated = sum(len(s.pages) for s in self.paged_sessions.values())
             live_tokens = sum(s.position for s in self.paged_sessions.values())
             pinned = len(self._prefix_pinned_page_ids())
-        allocated = sum(per_sess)
         # internal fragmentation: allocated page capacity the resident
         # sessions' live tokens don't fill (the cost of page granularity —
         # dense slots score (1 - position/max_seq) on the same formula)
@@ -3477,11 +3500,8 @@ class LLMEngine:
             "kv_pages_total": self._data_pages,
             "kv_pages_free": free,
             "kv_pages_used": self._data_pages - free - quarantined,
-            "kv_pages_quarantined": quarantined,
             "kv_pages_prefix_pinned": pinned,
             "resident_sessions": len(self.paged_sessions),
-            "session_pages_p50": per_sess[len(per_sess) // 2] if per_sess else None,
-            "session_pages_max": per_sess[-1] if per_sess else None,
             "kv_fragmentation_pct": frag,
             "page_exhausted_total": self.page_exhausted_total,
             "pages_truncated_total": self.pages_truncated,
@@ -3542,6 +3562,18 @@ class LLMEngine:
     _PIPELINE_DEPTH = 1  # readback RTT < chunk compute, so depth 1 hides it
 
     def _loop(self) -> None:
+        with self._spans.loop():
+            self._serve()
+        # worker exit: nothing may hang on a dead worker — fail queued work,
+        # drained-but-unadmitted work, and in-flight requests (ADVICE r5:
+        # the None sentinel used to abandon SnapshotCmd/RestoreCmd/
+        # GenRequest futures forever)
+        self._fail_pending(EngineShutdown("engine shut down"))
+
+    def _serve(self) -> None:
+        """The worker's iterations. Everything that takes time in here runs
+        under a top-level ``engine.*`` span, so the spans tile the loop:
+        ``metrics()["loop_s"]`` less the phases' self times is glue."""
         while self._running and not self._sentinel:
             busy = any(s.request is not None for s in self.slots) or bool(self._readbacks)
             self._pump_queue(0.0 if (busy or self._waiting) else 0.2)
@@ -3627,12 +3659,8 @@ class LLMEngine:
                 self._readbacks.clear()
                 self._ensure_device_state()
             if not any(s.request is not None for s in self.slots) and self._waiting:
-                time.sleep(0.002)  # all slots busy-by-session; brief backoff
-        # worker exit: nothing may hang on a dead worker — fail queued work,
-        # drained-but-unadmitted work, and in-flight requests (ADVICE r5:
-        # the None sentinel used to abandon SnapshotCmd/RestoreCmd/
-        # GenRequest futures forever)
-        self._fail_pending(EngineShutdown("engine shut down"))
+                with self._spans.span("engine.wait_request"):
+                    time.sleep(0.002)  # all slots busy-by-session; brief backoff
 
     def _pump_queue(self, block_s: float) -> None:
         """Drain the submit queue into the waiting list (a burst admits
@@ -3640,7 +3668,8 @@ class LLMEngine:
         returning mid-drain so every caller unwinds to the exit drain."""
         try:
             if block_s > 0:
-                item = self._queue.get(timeout=block_s)
+                with self._spans.span("engine.wait_request"):  # nothing to do
+                    item = self._queue.get(timeout=block_s)
             else:
                 item = self._queue.get_nowait()
             while True:
@@ -3653,6 +3682,11 @@ class LLMEngine:
             pass
 
     def _admit_waiting(self) -> None:
+        if self._waiting:
+            self._admit_items()
+
+    @_phase("engine.admit")
+    def _admit_items(self) -> None:
         still = []
         for item in self._waiting:
             try:
@@ -3756,6 +3790,7 @@ class LLMEngine:
             self._fail_item(req, err)
             self._abandon_slot(slot)
 
+    @_phase("engine.inject_lane")
     def _inject_lane(
         self, idx: int, first, position: int, temp: float, top_k: int, top_p: float,
         hist_row=None, hist_n: int = 0,
@@ -3793,6 +3828,7 @@ class LLMEngine:
             jnp.int32(hist_n),
         )
 
+    @_phase("engine.inject_lane")
     def _stage_lane(
         self, idx: int, first, position: int, temp: float, top_k: int, top_p: float,
         hist_row=None, hist_n: int = 0,
@@ -4005,6 +4041,7 @@ class LLMEngine:
             ) = self._alloc_carry()
             self._staged_lane = None
 
+    @_phase("engine.restore")
     def _do_restore(self, cmd: RestoreCmd) -> None:
         from .checkpoint import restore_kv_slot
 
@@ -4139,32 +4176,8 @@ class LLMEngine:
             del slot.spec_hist[: -self.max_seq]
         if self._prefix_active and fresh:
             if self._prefix_levels and len(prompt) > self._prefix_levels[0]:
-                hit = self._prefix_lookup(prompt)
-                if hit is not None:
-                    key, entry = hit
-                    b = key[0]
-                    try:
-                        self.cache = self._prefix_fork_fn(b)(
-                            self.cache, jnp.int32(slot.idx), entry.k, entry.v
-                        )
-                    except Exception:
-                        # the fork may have consumed its donated cache
-                        # without producing one — repair device state, then
-                        # let _admit_waiting fail this request
-                        self._ensure_device_state()
-                        raise
-                    forked = b
-                    slot.position = b
-                    entry.hits += 1
-                    entry.last_used = time.monotonic()
-                    self._prefix_entries.move_to_end(key)
-                    self.prefix_hits += 1
-                    self.prefix_tokens_saved += b
-                    # the fork streams the entry's KV once (copy, no FLOPs
-                    # — that's the point); keeps the MBU model honest
-                    self.hbm_bytes_read += b * self._kv_bytes_per_pos
-                else:
-                    self.prefix_misses += 1
+                with self._spans.span("engine.prefix_fork", request_id=req.id):
+                    forked = self._prefix_fork(slot, prompt)
             # track the fresh context so the final prefill chunk registers
             # its bucket-prefixes (including levels above a partial hit)
             slot.prefix_ctx = list(prompt)
@@ -4176,6 +4189,36 @@ class LLMEngine:
         slot.pending_prompt = prompt[forked:]
         slot.last_used = time.monotonic()
         return True
+
+    def _prefix_fork(self, slot: Slot, prompt: list[int]) -> int:
+        """Copy the longest cached prefix of ``prompt`` into the slot's rows;
+        the number of tokens the slot now holds (0: a miss)."""
+        hit = self._prefix_lookup(prompt)
+        if hit is None:
+            self.prefix_misses += 1
+            return 0
+        key, entry = hit
+        b = key[0]
+        try:
+            self.cache = self._prefix_fork_fn(b)(
+                self.cache, jnp.int32(slot.idx), entry.k, entry.v
+            )
+        except Exception:
+            # the fork may have consumed its donated cache without
+            # producing one — repair device state, then let
+            # _admit_waiting fail this request
+            self._ensure_device_state()
+            raise
+        slot.position = b
+        entry.hits += 1
+        entry.last_used = time.monotonic()
+        self._prefix_entries.move_to_end(key)
+        self.prefix_hits += 1
+        self.prefix_tokens_saved += b
+        # the fork streams the entry's KV once (copy, no FLOPs — that's the
+        # point); keeps the MBU model honest
+        self.hbm_bytes_read += b * self._kv_bytes_per_pos
+        return b
 
     def _try_admit_paged(self, req: GenRequest) -> bool:
         """Paged admission: bind the session (resident or new) to ANY free
@@ -4238,12 +4281,13 @@ class LLMEngine:
         try:
             if self._prefix_active and fresh:
                 if self._prefix_levels and len(prompt) > self._prefix_levels[0]:
-                    hit = self._prefix_lookup(prompt)
-                    if hit is not None and hit[1].pages is not None:
-                        key, entry = hit
-                        forked = self._map_prefix_pages(sess, key, entry)
-                    else:
-                        self.prefix_misses += 1
+                    with self._spans.span("engine.prefix_fork", request_id=req.id):
+                        hit = self._prefix_lookup(prompt)
+                        if hit is not None and hit[1].pages is not None:
+                            key, entry = hit
+                            forked = self._map_prefix_pages(sess, key, entry)
+                        else:
+                            self.prefix_misses += 1
                 lane.prefix_ctx = list(prompt)
             else:
                 lane.prefix_ctx = None
@@ -4345,9 +4389,10 @@ class LLMEngine:
         fresh = [s for s in idle if not s.session]
         slot = fresh[0] if fresh else min(idle, key=lambda s: s.last_used)
         if slot.session and self.sessions.get(slot.session) == slot.idx:
-            self.sessions.pop(slot.session, None)  # evict LRU session's KV
-            self._count_eviction("session", time.monotonic() - slot.last_used)
-            self._flush_parked_snapshot(slot.session)
+            with self._spans.span("engine.evict"):
+                self.sessions.pop(slot.session, None)  # evict LRU session's KV
+                self._count_eviction("session", time.monotonic() - slot.last_used)
+                self._flush_parked_snapshot(slot.session)
         slot.session = session
         slot.position = 0
         slot.pending_token = None  # stale state from the previous occupant
@@ -4384,6 +4429,15 @@ class LLMEngine:
             key=lambda s: (s.request.prefill_started_at is not None, s.request.submitted_at),
         )
         self._prefilling_slot = slot  # fault attribution (worker loop)
+        with self._spans.span(
+            "engine.prefill_tick",
+            request_id=slot.request.id,
+            tokens=min(len(slot.pending_prompt), self.prefill_chunk),
+        ):
+            self._prefill_chunk(slot)
+
+    def _prefill_chunk(self, slot: Slot) -> None:
+        span = self._spans.span
         req = slot.request
         # failpoint: a poisoned prefill fails THIS request only — the worker
         # loop's per-request isolation (VERDICT r4 item 1b) is what the
@@ -4413,43 +4467,48 @@ class LLMEngine:
         slot.pending_prompt = slot.pending_prompt[self.prefill_chunk :]
         final = not slot.pending_prompt
         n = len(chunk)
-        bucket = self._bucket(n)
-        padded = chunk + [0] * (bucket - n)
-        # padding positions continue past the real tokens; every such slot is
-        # rewritten by a later real token (next chunk or decode) before any
-        # query can attend to it, and the position mask hides the rest
-        positions = np.arange(slot.position, slot.position + bucket, dtype=np.int32)
-        tokens = jnp.asarray(np.array(padded, dtype=np.int32)[None])
-        pos = jnp.asarray(positions[None])
-        if self.paged:
-            # pages cover the REAL tokens only; bucket-padding writes past
-            # them fall into the lane's scratch page via the table default
-            # (and clamp in-kernel past the logical arena) — exactly as
-            # invisible as the dense path's dropped out-of-range scatter
-            try:
-                self._ensure_lane_pages(
-                    slot, slot.position + n - 1, serving=bool(req.id)
+        with span("engine.prefill_dispatch"):  # host prep + the call returning
+            bucket = self._bucket(n)
+            padded = chunk + [0] * (bucket - n)
+            # padding positions continue past the real tokens; every such
+            # slot is rewritten by a later real token (next chunk or decode)
+            # before any query can attend to it, and the position mask hides
+            # the rest
+            positions = np.arange(slot.position, slot.position + bucket, dtype=np.int32)
+            tokens = jnp.asarray(np.array(padded, dtype=np.int32)[None])
+            pos = jnp.asarray(positions[None])
+            if self.paged:
+                # pages cover the REAL tokens only; bucket-padding writes
+                # past them fall into the lane's scratch page via the table
+                # default (and clamp in-kernel past the logical arena) —
+                # exactly as invisible as the dense path's dropped
+                # out-of-range scatter
+                try:
+                    self._ensure_lane_pages(
+                        slot, slot.position + n - 1, serving=bool(req.id)
+                    )
+                except EngineOverloaded as e:
+                    # policy backpressure, not a fault: fail THIS request
+                    # with the typed 429 and roll the session back — the
+                    # worker loop's generic prefill handler would count a
+                    # worker error and destroy the resident session
+                    self._fail_item(req, e)
+                    self._abandon_slot(slot, rollback=True)
+                    return
+                last_logits, self.cache = self._prefill(
+                    self.params,
+                    self.cache,
+                    jnp.asarray(self._bt[slot.idx : slot.idx + 1]),
+                    tokens,
+                    pos,
+                    jnp.int32(n),
                 )
-            except EngineOverloaded as e:
-                # policy backpressure, not a fault: fail THIS request with
-                # the typed 429 and roll the session back — the worker
-                # loop's generic prefill handler would count a worker
-                # error and destroy the resident session
-                self._fail_item(req, e)
-                self._abandon_slot(slot, rollback=True)
-                return
-            last_logits, self.cache = self._prefill(
-                self.params,
-                self.cache,
-                jnp.asarray(self._bt[slot.idx : slot.idx + 1]),
-                tokens,
-                pos,
-                jnp.int32(n),
-            )
-        else:
-            last_logits, self.cache = self._prefill(
-                self.params, self.cache, jnp.int32(slot.idx), tokens, pos, jnp.int32(n)
-            )
+            else:
+                last_logits, self.cache = self._prefill(
+                    self.params, self.cache, jnp.int32(slot.idx), tokens, pos, jnp.int32(n)
+                )
+        self.prefill_launches += 1
+        self.prefill_tokens += n
         # n real tokens, each attending ~its own position of context
         self.flops_done += n * self.cfg.flops_per_token(slot.position + n // 2)
         self.hbm_bytes_read += self.param_hbm_bytes + (
@@ -4462,17 +4521,19 @@ class LLMEngine:
         # whole fresh context now in KV: register its bucket-prefixes in
         # the arena (async device copies; positions [0:b] are real tokens —
         # the final chunk's padding lands strictly above slot.position)
-        self._prefix_register(slot)
-        self._rng, key = jax.random.split(self._rng)
-        first = sample_step(
-            last_logits[None],
-            key,
-            jnp.asarray([req.temperature], jnp.float32),
-            jnp.asarray([req.top_k], jnp.int32),
-            jnp.asarray([req.top_p], jnp.float32),
-            greedy_cond=self.mesh is None,
-            approx_topk=self.approx_topk,
-        )
+        with span("engine.prefix_register"):
+            self._prefix_register(slot)
+        with span("engine.first_token_sample"):
+            self._rng, key = jax.random.split(self._rng)
+            first = sample_step(
+                last_logits[None],
+                key,
+                jnp.asarray([req.temperature], jnp.float32),
+                jnp.asarray([req.top_k], jnp.int32),
+                jnp.asarray([req.top_p], jnp.float32),
+                greedy_cond=self.mesh is None,
+                approx_topk=self.approx_topk,
+            )
         hist_row = None
         hist_n = 0
         if self.inloop_spec:
@@ -4600,6 +4661,7 @@ class LLMEngine:
                 slot.psess.pending_token = slot.pending_token
             self._service_parked_snapshot(slot)
             self._detach_lane(slot)
+        self.requests_finished += 1  # before the caller can see its reply and scrape
         req.loop.call_soon_threadsafe(_resolve, req.future, result)
         # a cancel that raced a natural finish loses: drop its stale marker
         with self._lock:
@@ -4633,6 +4695,10 @@ class LLMEngine:
         if any(r.id for _, r, _ in snapshot):
             faults.fire("engine.decode_step")
         chunk = self._pick_chunk(needed)
+        with self._spans.span("engine.decode_dispatch", lanes=len(snapshot), chunk=chunk):
+            self._dispatch_chunk(snapshot, chunk)
+
+    def _dispatch_chunk(self, snapshot: list, chunk: int) -> None:
         if self.paged:
             # pre-allocate pages covering every step of the chunk so the
             # block table is constant across the compiled scan; a lane the
@@ -4712,6 +4778,10 @@ class LLMEngine:
         if any(r.id for _, r, _ in base):
             faults.fire("engine.fused_decode")
         chunk = self._pick_fused_chunk()
+        with self._spans.span("engine.decode_dispatch", lanes=len(base), chunk=chunk):
+            self._dispatch_fused(base, chunk)
+
+    def _dispatch_fused(self, base: list, chunk: int) -> None:  # atp: hot
         if self.paged:
             kept = []
             for s, r, p in base:
@@ -5079,21 +5149,24 @@ class LLMEngine:
             s.request is not None and s.pending_prompt for s in self.slots
         ):
             return False
-        plan = []
-        any_draft = False
-        for s in self.slots:
-            if not s.decoding or s.request is None:
-                continue
-            g = self._spec_gamma(s)
-            d = self._spec_draft(s, g) if g > 0 else []
-            if g > 0:
-                s.spec_probe_at = self.decode_steps
-                s.spec_miss = 0 if d else s.spec_miss + 1
-            any_draft = any_draft or bool(d)
-            plan.append((s, s.request, s.position, d))
-        if not any_draft:
-            return False
-        self._spec_round(plan)
+        lanes = sum(s.decoding and s.request is not None for s in self.slots)
+        with self._spans.span("engine.spec_round", lanes=lanes):
+            plan = []
+            any_draft = False
+            with self._spans.span("engine.spec_draft"):  # host n-gram lookup
+                for s in self.slots:
+                    if not s.decoding or s.request is None:
+                        continue
+                    g = self._spec_gamma(s)
+                    d = self._spec_draft(s, g) if g > 0 else []
+                    if g > 0:
+                        s.spec_probe_at = self.decode_steps
+                        s.spec_miss = 0 if d else s.spec_miss + 1
+                    any_draft = any_draft or bool(d)
+                    plan.append((s, s.request, s.position, d))
+            if not any_draft:
+                return False
+            self._spec_round(plan)
         return True
 
     def _spec_round(self, plan: list) -> None:
@@ -5103,46 +5176,53 @@ class LLMEngine:
         this round ride along as a plain decode step (draft_len 0)."""
         gmax = max(len(d) for _, _, _, d in plan)
         K = next(b for b in self._spec_buckets if b >= gmax)
-        if self.paged:
-            # pages must cover the whole verify write span [p, p+K]; a lane
-            # the pool can't cover fails with backpressure, the rest verify
-            kept = []
-            for s, r, p, d in plan:
-                try:
-                    self._ensure_lane_pages(
-                        s, min(p + K, self.max_seq - 2), serving=bool(r.id)
-                    )
-                    kept.append((s, r, p, d))
-                except EngineOverloaded as e:
-                    self._fail_item(r, e)
-                    self._abandon_slot(s, rollback=True)
-            plan = kept
-            if not plan:
-                return
-        drafts = np.zeros((self.max_batch, K), dtype=np.int32)
-        dlen = np.zeros((self.max_batch,), dtype=np.int32)
-        for s, _, _, d in plan:
-            if d:
-                drafts[s.idx, : len(d)] = d
-                dlen[s.idx] = len(d)
-        self._rng, key = jax.random.split(self._rng)
-        emitted_dev, count_dev, self._dtok, self._dpos, self.cache = (
-            self._verify_fn(K)(
-                self.params,
-                self.cache,
-                *self._bt_arg(),
-                self._dtok,
-                self._dpos,
-                self._dtemps,
-                self._dtopk,
-                self._dtopp,
-                jnp.asarray(drafts),
-                jnp.asarray(dlen),
-                key,
+        with self._spans.span("engine.verify_dispatch"):
+            if self.paged:
+                # pages must cover the whole verify write span [p, p+K]; a
+                # lane the pool can't cover fails with backpressure, the
+                # rest verify
+                kept = []
+                for s, r, p, d in plan:
+                    try:
+                        self._ensure_lane_pages(
+                            s, min(p + K, self.max_seq - 2), serving=bool(r.id)
+                        )
+                        kept.append((s, r, p, d))
+                    except EngineOverloaded as e:
+                        self._fail_item(r, e)
+                        self._abandon_slot(s, rollback=True)
+                plan = kept
+                if not plan:
+                    return
+            drafts = np.zeros((self.max_batch, K), dtype=np.int32)
+            dlen = np.zeros((self.max_batch,), dtype=np.int32)
+            for s, _, _, d in plan:
+                if d:
+                    drafts[s.idx, : len(d)] = d
+                    dlen[s.idx] = len(d)
+            self._rng, key = jax.random.split(self._rng)
+            emitted_dev, count_dev, self._dtok, self._dpos, self.cache = (
+                self._verify_fn(K)(
+                    self.params,
+                    self.cache,
+                    *self._bt_arg(),
+                    self._dtok,
+                    self._dpos,
+                    self._dtemps,
+                    self._dtopk,
+                    self._dtopp,
+                    jnp.asarray(drafts),
+                    jnp.asarray(dlen),
+                    key,
+                )
             )
-        )
-        emitted = np.asarray(emitted_dev)  # sync readback: spec rounds don't pipeline
-        count = np.asarray(count_dev)
+        with self._spans.span("engine.verify_readback"):
+            self._verify_readback(plan, K, dlen, emitted_dev, count_dev)
+
+    def _verify_readback(self, plan: list, K: int, dlen, emitted_dev, count_dev) -> None:
+        with self._spans.span("engine.wait_device"):
+            emitted = np.asarray(emitted_dev)  # sync readback: spec rounds don't pipeline
+            count = np.asarray(count_dev)
         self.host_syncs_total += 1
         end = time.monotonic()
         self.spec_rounds += 1
@@ -5255,6 +5335,7 @@ class LLMEngine:
                 self._process_chunk(entry)
             block = False
 
+    @_phase("engine.wait_device")
     def _wait_admitting(self, arr) -> None:
         """Forced-drain wait that keeps admitting: poll the submit queue
         while the readback completes, and dispatch a fresh arrival's FIRST
@@ -5305,7 +5386,12 @@ class LLMEngine:
         _, slot, req, first, _ = entry
         if slot.request is not req:
             return  # request failed/superseded while the copy was in flight
-        first_id = int(np.asarray(first)[0])
+        with self._spans.span("engine.process_readback", request_id=req.id):
+            self._deliver_first(slot, req, first)
+
+    def _deliver_first(self, slot: Slot, req: GenRequest, first) -> None:
+        with self._spans.span("engine.wait_device"):
+            first_id = int(np.asarray(first)[0])
         self.host_syncs_total += 1
         now = time.monotonic()
         req.ttft_ms = 1000 * (now - req.submitted_at)
@@ -5326,9 +5412,11 @@ class LLMEngine:
             # first token not yet in KV: carried into the next turn's prompt
             self._finish(slot, pending_last=True)
 
+    @_phase("engine.process_readback")
     def _process_chunk(self, entry) -> None:
         _, snapshot, toks_dev, _ = entry
-        toks = np.asarray(toks_dev)  # [chunk, B]
+        with self._spans.span("engine.wait_device"):
+            toks = np.asarray(toks_dev)  # [chunk, B]
         self.host_syncs_total += 1
         chunk = toks.shape[0]
         # ITL = wall time between consecutive chunk completions (including
@@ -5373,6 +5461,7 @@ class LLMEngine:
             else:
                 slot.position = start + chunk
 
+    @_phase("engine.process_readback")
     def _process_fused(self, entry) -> None:  # atp: hot
         """Process one fused loop's packed readback — the loop's ONE host
         sync. The host rescans the emitted tokens against its own remaining
@@ -5385,7 +5474,8 @@ class LLMEngine:
         _, snapshot, packed_dev, chunk, _ = entry
         cap_rows = self._fused_cap + 1
         # [cap_rows+5, B]: tokens / counts / reasons / steps / nacc / ndr
-        packed = np.asarray(packed_dev)
+        with self._spans.span("engine.wait_device"):
+            packed = np.asarray(packed_dev)
         self.host_syncs_total += 1
         steps = int(packed[cap_rows + 2, 0])
         self.fused_steps_total += steps

@@ -16,6 +16,7 @@ restarted engine resumes mid-conversation — BASELINE.json config #3.
 from __future__ import annotations
 
 import asyncio
+import collections
 import json
 import os
 import time
@@ -34,6 +35,7 @@ SESSION_CONVO_TTL_S = 7 * 24 * 3600
 # proxy ↔ engine wire headers: single definition site shared with the
 # control plane (core/protocol.py) — re-exported for existing importers
 from ..core.protocol import (  # noqa: E402, F401  (re-export)
+    ACCEPTED_NS_HEADER,
     DEADLINE_HEADER,
     DRAINING_HEADER,
     EXPIRED_HEADER,
@@ -131,6 +133,12 @@ class LLMServeApp:
         )
         self.started_at = time.time()
         self.requests_total = 0
+        # front door's accept stamp → this handler's entry, per proxied
+        # /chat (ms; one machine, one wall clock): the journal layer's
+        # dispatch time. Bounded like the engine's sample deques.
+        self.journal_dispatch_ms_recent: collections.deque[float] = collections.deque(
+            maxlen=256
+        )
         self._ready = asyncio.Event()
         # multi-tenant host state (host instance only)
         self._tenants: dict[str, tuple["LLMServeApp", web.AppRunner, int]] = {}
@@ -138,9 +146,7 @@ class LLMServeApp:
         self.kv_restores = 0
         self.prefix_prewarms = 0
         # tiered KV hierarchy (kv_tiering): proxy-hinted park/prewarm ops
-        self.kv_parks = 0
         self.kv_park_errors = 0
-        self.kv_prewarms = 0
         self.kv_prewarm_errors = 0
         self.kv_snapshots = 0
         self.kv_snapshots_deferred = 0
@@ -793,6 +799,9 @@ class LLMServeApp:
 
     async def h_chat(self, request: web.Request) -> web.Response:
         self.requests_total += 1
+        accepted_ns = request.headers.get(ACCEPTED_NS_HEADER, "")
+        if accepted_ns.isdigit():
+            self.journal_dispatch_ms_recent.append((time.time_ns() - int(accepted_ns)) / 1e6)
         err = await self._ensure_engine()
         if err is not None:
             return err
@@ -1168,7 +1177,6 @@ class LLMServeApp:
             )
         if blob is None:
             return web.json_response({"parked": False, "reason": "unknown or busy"})
-        self.kv_parks += 1
         if self.store.connected:
             try:
                 await self.store.set_bytes(self._kv_key(session), blob, ttl=24 * 3600)
@@ -1214,8 +1222,6 @@ class LLMServeApp:
                         self.kv_restores += 1
             except Exception:
                 self.kv_prewarm_errors += 1
-        if ok:
-            self.kv_prewarms += 1
         return web.json_response({"prewarmed": ok})
 
     async def _record_turn(self, session: str, message: str, reply: str) -> None:
@@ -1357,7 +1363,10 @@ class LLMServeApp:
     async def h_profile(self, request: web.Request) -> web.Response:
         """Capture a jax.profiler trace of live serving (device + host
         timelines). One capture at a time; the trace directory is shared
-        with the control plane so the management API can return its path."""
+        with the control plane so the management API can return its path.
+        The host planes hold the engine's phase spans (utils/spans.py) and
+        JAX's own events; ``python_tracer: true`` adds every Python frame,
+        which slows the worker that is being watched."""
         self.requests_total += 1
         err = await self._ensure_engine()
         if err is not None:
@@ -1388,11 +1397,17 @@ class LLMServeApp:
         try:
             import jax
 
-            jax.profiler.start_trace(trace_dir)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = int(bool(body.get("python_tracer", False)))
+            # starting, and stopping (a second of collection), block: off
+            # the event loop, so /chat is answered meanwhile
+            await asyncio.to_thread(
+                jax.profiler.start_trace, trace_dir, profiler_options=options
+            )
             try:
                 await asyncio.sleep(duration)
             finally:
-                jax.profiler.stop_trace()
+                await asyncio.to_thread(jax.profiler.stop_trace)
         except Exception as e:
             return web.json_response(
                 {"error": f"profiler failed: {type(e).__name__}: {e}"}, status=500
@@ -1400,7 +1415,12 @@ class LLMServeApp:
         finally:
             self._profiling = False
         return web.json_response(
-            {"trace_dir": trace_dir, "duration_s": duration, "agent_id": self.agent_id}
+            {
+                "trace_dir": trace_dir,
+                "duration_s": duration,
+                "agent_id": self.agent_id,
+                "python_tracer": bool(options.python_tracer_level),
+            }
         )
 
     async def h_metrics(self, request: web.Request) -> web.Response:
@@ -1415,16 +1435,21 @@ class LLMServeApp:
             "chips": list(self.chips),
             "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS") or None,
             "requests_total": self.requests_total,
-            "uptime_s": time.time() - self.started_at,
+            "journal_dispatch_ms_samples": [
+                round(x, 3) for x in self.journal_dispatch_ms_recent
+            ],
+            "journal_dispatch_ms_p50": (
+                round(jd[len(jd) // 2], 3)
+                if (jd := sorted(self.journal_dispatch_ms_recent))
+                else None
+            ),
             "model_loaded": self.engine is not None,
             "engine_error": self.engine_error or None,
             "kv_snapshots": self.kv_snapshots,
             "kv_snapshots_deferred": self.kv_snapshots_deferred,
             "kv_restores": self.kv_restores,
             "prefix_prewarms": self.prefix_prewarms,
-            "kv_parks": self.kv_parks,
             "kv_park_errors": self.kv_park_errors,
-            "kv_prewarms": self.kv_prewarms,
             "kv_prewarm_errors": self.kv_prewarm_errors,
             "kv_snapshot_errors": self.kv_snapshot_errors,
             "last_kv_snapshot_error": self.last_kv_snapshot_error or None,

@@ -46,6 +46,7 @@ if TYPE_CHECKING:
 # wire-protocol constants live in core/protocol.py (shared with the replay
 # worker and engine serve layer); re-exported here for existing importers
 from ..core.protocol import (  # noqa: F401  (re-export)
+    ACCEPTED_NS_HEADER,
     DEADLINE_HEADER,
     DISPATCH_ENGINE_GONE,
     DISPATCH_EXPIRED,
@@ -1036,7 +1037,13 @@ class ControlPlaneApp:
         if request.query_string:
             path = f"{path}?{request.query_string}"
         body = await request.read()
-        headers = {k: v for k, v in request.headers.items() if k.lower() not in _HOP_BY_HOP}
+        accepted_ns = time.time_ns()  # read, not yet journaled
+        headers = {
+            k: v
+            for k, v in request.headers.items()
+            # the accept stamp is this proxy's alone to set
+            if k.lower() not in _HOP_BY_HOP and k.lower() != ACCEPTED_NS_HEADER.lower()
+        }
 
         try:
             agent = self.s.manager.get_agent(agent_id)
@@ -1174,6 +1181,11 @@ class ControlPlaneApp:
                     },
                 )
             return fail("agent is not running", status=503)
+
+        # the engine measures its distance to this stamp: the journal
+        # layer's dispatch time. The journaled headers carry none, so a
+        # dispatch of the replay worker is not sampled
+        headers = {**headers, ACCEPTED_NS_HEADER: str(accepted_ns)}
 
         if self._tier_enabled() and path.startswith("/chat"):
             # returning turn: fire the prewarm hint BEFORE the chat dispatch

@@ -25,31 +25,43 @@ def compile_cache_dir() -> str:
 
 
 class CompileCacheStats:
-    """Counts of what this process asked of the persistent cache, from
-    JAX's own monitoring events: ``requests`` compiles that consulted it,
-    ``hits`` served from it, ``writes`` compiled afresh and stored (only
-    compiles over the persistence threshold are stored)."""
+    """What this process asked of the persistent cache and what compiling
+    cost it, from JAX's own monitoring events. Counts: ``requests`` compiles
+    that consulted the cache, ``hits`` served from it, ``misses`` not found
+    in it (also exported as ``writes``, the name the benchmark prints: a
+    miss is compiled afresh and, over the persistence threshold, stored).
+    Seconds, summed over the process: ``trace_s`` tracing Python to a jaxpr,
+    ``lower_s`` lowering it to an MLIR module, ``compile_s`` getting the
+    executable (the backend compiler, or the cache's copy: JAX times the
+    two as one), ``retrieval_s`` the part of ``compile_s`` spent reading
+    the cache on a hit. After warm-up all of them should stand still."""
 
-    _EVENTS = {
+    _FIELDS = {
         "/jax/compilation_cache/compile_requests_use_cache": "requests",
         "/jax/compilation_cache/cache_hits": "hits",
-        "/jax/compilation_cache/cache_misses": "writes",
+        "/jax/compilation_cache/cache_misses": "misses",
+        "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+        "/jax/core/compile/backend_compile_duration": "compile_s",
+        "/jax/compilation_cache/cache_retrieval_time_sec": "retrieval_s",
     }
 
     def __init__(self) -> None:
-        self.requests = self.hits = self.writes = 0
+        self.requests = self.hits = self.misses = 0
+        self.trace_s = self.lower_s = self.compile_s = self.retrieval_s = 0.0
 
-    def on_event(self, event: str, **_kwargs) -> None:
-        name = self._EVENTS.get(event)
+    def on_event(self, event: str, amount: float = 1, **_kwargs) -> None:
+        """Listener for JAX's events (one more) and for its durations
+        (``amount`` seconds more)."""
+        name = self._FIELDS.get(event)
         if name is not None:
-            setattr(self, name, getattr(self, name) + 1)
+            setattr(self, name, getattr(self, name) + amount)
 
     def as_dict(self) -> dict:
         return {
             "dir": compile_cache_dir(),
-            "requests": self.requests,
-            "hits": self.hits,
-            "writes": self.writes,
+            **{name: getattr(self, name) for name in self._FIELDS.values()},
+            "writes": self.misses,
         }
 
 
@@ -65,4 +77,5 @@ def enable_compile_cache() -> CompileCacheStats:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
     stats = CompileCacheStats()
     jax.monitoring.register_event_listener(stats.on_event)
+    jax.monitoring.register_event_duration_secs_listener(stats.on_event)
     return stats

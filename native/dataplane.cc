@@ -25,6 +25,12 @@ static double now_s() {
       .count();
 }
 
+static long long now_ns() {
+  return std::chrono::duration_cast<std::chrono::nanoseconds>(
+             std::chrono::system_clock::now().time_since_epoch())
+      .count();
+}
+
 static double mono_s() {
   return std::chrono::duration<double>(
              std::chrono::steady_clock::now().time_since_epoch())
@@ -645,11 +651,14 @@ struct ConnCtx {
 };
 
 // Build the raw upstream request for an agent dispatch or backend forward.
+// `accepted_ns` (agent dispatch only; 0: none) is when this front door had
+// read the request, before its journal write: the engine samples its
+// distance to it (core/protocol.py ACCEPTED_NS_HEADER).
 static std::string build_upstream_request(
     const std::string& method, const std::string& target,
     const std::vector<std::pair<std::string, std::string>>& headers,
     const std::string& body, const std::string& host_hdr,
-    const std::string& request_id, bool strip_auth) {
+    const std::string& request_id, bool strip_auth, long long accepted_ns = 0) {
   std::string out = method + " " + target + " HTTP/1.1\r\n";
   out += "Host: " + host_hdr + "\r\n";
   for (const auto& kv : headers) {
@@ -660,6 +669,8 @@ static std::string build_upstream_request(
     out += kv.first + ": " + kv.second + "\r\n";
   }
   if (!request_id.empty()) out += "X-Agentainer-Request-ID: " + request_id + "\r\n";
+  if (accepted_ns > 0)
+    out += "X-Agentainer-Accepted-Ns: " + std::to_string(accepted_ns) + "\r\n";
   out += "Content-Length: " + std::to_string(body.size()) + "\r\n";
   out += "Connection: keep-alive\r\n\r\n";
   out += body;
@@ -722,6 +733,8 @@ void DataPlane::handle_conn(int fd) {
         // blind spot the routing tier exists to fix).
       } else {
 
+      long long accepted_ns = now_ns();  // read, not yet journaled
+
       // journal entry (before dispatch — the signature guarantee)
       JEntry e;
       e.agent_id = agent_id;
@@ -731,8 +744,10 @@ void DataPlane::handle_conn(int fd) {
       e.created_at = now_s();
       for (const auto& kv : req.headers) {
         std::string l = lower(kv.first);
+        // the accept stamp is this front door's alone to set, and is not
+        // journaled: a replayed dispatch carries none
         if (is_hop_by_hop(l) || l == "x-agentainer-replay" ||
-            l == "x-agentainer-request-id")
+            l == "x-agentainer-request-id" || l == "x-agentainer-accepted-ns")
           continue;
         e.headers.push_back(kv);
       }
@@ -774,7 +789,8 @@ void DataPlane::handle_conn(int fd) {
 
       std::string upstream_req = build_upstream_request(
           req.method, path, e.headers, req.body,
-          route.host + ":" + std::to_string(route.port), e.rid, /*strip_auth=*/true);
+          route.host + ":" + std::to_string(route.port), e.rid, /*strip_auth=*/true,
+          accepted_ns);
       HttpMsg up;
       double t0 = mono_s();
       int rc = ctx.roundtrip(route.host, route.port, upstream_req, &up,

@@ -1,0 +1,415 @@
+"""Phase spans and what reads them (ISSUE 23): the span facility itself, the
+engine worker's phases tiling its loop, the counters beside them, the
+profiler capture that holds the same spans, the journal layer's dispatch
+timing through both front doors, the seconds beside the compile counts, and
+the XLA module names the benchmark's readers match by prefix."""
+
+import asyncio
+import glob
+import json
+import os
+import re
+import sys
+import threading
+import time
+
+import jax
+import jax.numpy as jnp
+import pytest
+from aiohttp.test_utils import TestClient, TestServer
+
+from agentainer_tpu.core.protocol import ACCEPTED_NS_HEADER, REQUEST_ID_HEADER
+from agentainer_tpu.engine.llm import LLMEngine
+from agentainer_tpu.engine.llm_serve import LLMServeApp
+from agentainer_tpu.utils.compile_cache import enable_compile_cache
+from agentainer_tpu.utils.spans import Spans
+from tests.conftest import _native_available
+
+TINY = {"max_batch": 4, "max_seq": 256, "decode_chunk": 8, "prefill_chunk": 32, "skip_warmup": True}
+SHARED = "the quick brown fox jumps over the lazy dog " * 2  # ~90 tokens: buckets 32 and 64
+
+
+# -- the facility ----------------------------------------------------------
+def test_self_time_is_total_less_children():
+    spans = Spans()
+    with spans.span("outer", request_id="r1"):
+        time.sleep(0.02)
+        with spans.span("inner", tokens=3):
+            time.sleep(0.03)
+            with spans.span("leaf"):
+                time.sleep(0.01)
+        with spans.span("inner"):
+            time.sleep(0.01)
+    p = spans.snapshot()["phases"]
+    assert {k: v["n"] for k, v in p.items()} == {"outer": 1, "inner": 2, "leaf": 1}
+    assert p["leaf"]["self_s"] == p["leaf"]["total_s"] >= 0.01
+    assert p["inner"]["self_s"] == pytest.approx(p["inner"]["total_s"] - p["leaf"]["total_s"], abs=1e-9)
+    assert p["outer"]["self_s"] == pytest.approx(p["outer"]["total_s"] - p["inner"]["total_s"], abs=1e-9)
+    assert p["outer"]["self_s"] >= 0.02 and p["outer"]["total_s"] >= 0.07
+    # self times partition the covered time
+    assert sum(v["self_s"] for v in p.values()) == pytest.approx(p["outer"]["total_s"], abs=1e-9)
+
+
+def test_span_survives_an_exception_in_its_body():
+    spans = Spans()
+    with pytest.raises(ValueError):
+        with spans.span("outer"):
+            with spans.span("inner"):
+                raise ValueError("boom")
+    with spans.span("outer"):  # the stack unwound: this one is top-level again
+        pass
+    p = spans.snapshot()["phases"]
+    assert p["outer"]["n"] == 2 and p["inner"]["n"] == 1
+    assert p["outer"]["total_s"] >= p["inner"]["total_s"]
+
+
+def test_loop_is_cut_where_the_last_top_level_span_ended():
+    spans = Spans()
+    with spans.loop():
+        with spans.span("a"):
+            time.sleep(0.01)
+        time.sleep(0.01)  # glue: in the loop, under no span
+        with spans.span("b"):
+            time.sleep(0.01)
+            mid = spans.snapshot()  # b is open: neither side counts it yet
+        cut = spans.snapshot()
+        time.sleep(0.02)
+    done = spans.snapshot()
+    assert set(mid["phases"]) == {"a"} and mid["loop_s"] == pytest.approx(mid["phases"]["a"]["total_s"], abs=2e-3)
+    covered = sum(v["self_s"] for v in cut["phases"].values())
+    assert 0.005 <= cut["loop_s"] - covered < 0.02  # the glue, and only it
+    assert done["loop_s"] >= cut["loop_s"] + 0.02  # a finished loop counts to its end
+    assert spans.snapshot() == done
+
+
+def test_snapshot_is_safe_against_writing_threads():
+    spans = Spans()
+    stop = threading.Event()
+    counts = [0] * 8
+
+    def writer(i: int) -> None:
+        while not stop.is_set():
+            # new names keep arriving, as a phase's first occurrence does
+            with spans.span(f"w{i}.{counts[i] % 50}"):
+                with spans.span("shared"):
+                    pass
+            counts[i] += 1
+
+    threads = [threading.Thread(target=writer, args=(i,)) for i in range(len(counts))]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        deadline = time.monotonic() + 1.5
+        last = 0
+        while time.monotonic() < deadline:
+            doc = spans.snapshot()
+            for v in doc["phases"].values():
+                assert v["total_s"] >= v["self_s"] >= 0.0
+            n = doc["phases"].get("shared", {"n": 0})["n"]
+            assert n >= last  # cumulative, never torn backwards
+            last = n
+    finally:
+        stop.set()
+        for t in threads:
+            t.join(timeout=10)
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    # every thread's every span was kept: no update lost between threads
+    assert spans.snapshot()["phases"]["shared"]["n"] == sum(counts) > 0
+
+
+def test_importing_spans_does_not_import_jax():
+    import subprocess
+
+    code = "import sys; import agentainer_tpu.utils.spans; print('jax' in sys.modules)"
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True, timeout=60)
+    assert out.stdout.strip() == "False", out
+
+
+# -- the engine's worker ---------------------------------------------------
+@pytest.fixture(scope="module")
+def served():
+    """A tiny engine that has served two rounds of six sessions, its replies
+    and its metrics before and after."""
+    eng = LLMEngine.create("tiny", options=dict(TINY))
+    before = eng.metrics()
+
+    async def drive():
+        first = await asyncio.gather(*[
+            eng.generate(SHARED + str(i), max_tokens=12, session=f"s{i}", request_id=f"a{i}", ignore_eos=True)
+            for i in range(6)
+        ])
+        later = await asyncio.gather(*[
+            eng.generate(f"and then {i}?", max_tokens=12, session=f"s{i}", request_id=f"b{i}", ignore_eos=True)
+            for i in range(6)
+        ])
+        return first + later
+
+    try:
+        replies = asyncio.run(drive())
+        time.sleep(0.5)  # the worker is back in its blocking get: a top-level span just ended
+        yield eng, before, eng.metrics(), replies
+    finally:
+        eng.shutdown()
+
+
+def test_top_level_phases_tile_the_loop(served):
+    _, _, m, _ = served
+    covered = sum(v["self_s"] for v in m["phases"].values())  # = the top-level spans' total time
+    assert m["loop_s"] > 0
+    assert abs(m["loop_s"] - covered) <= 0.02 * m["loop_s"], (m["loop_s"], covered)
+
+
+def test_phases_nest_as_the_worker_runs_them(served):
+    _, _, m, replies = served
+    p = m["phases"]
+    for name in ("engine.wait_request", "engine.admit", "engine.prefill_tick", "engine.prefill_dispatch",
+                 "engine.first_token_sample", "engine.inject_lane", "engine.decode_dispatch",
+                 "engine.wait_device", "engine.process_readback"):
+        assert p[name]["n"] > 0, (name, sorted(p))
+    assert p["engine.first_token_sample"]["n"] == len(replies)
+    assert p["engine.prefill_dispatch"]["n"] == p["engine.prefill_tick"]["n"] == m["prefill_launches"]
+    # a parent's total covers its children; a leaf's self time is its total
+    children = sum(p[c]["total_s"] for c in ("engine.prefill_dispatch", "engine.prefix_register",
+                                             "engine.first_token_sample"))
+    assert p["engine.prefill_tick"]["total_s"] >= children
+    assert p["engine.first_token_sample"]["self_s"] == p["engine.first_token_sample"]["total_s"]
+    # 12 sessions' worth of admissions over 4 slots: sessions lost their slot
+    assert p["engine.evict"]["n"] == m["session_evictions_total"] > 0
+
+
+def test_prefill_counters_count_where_the_work_happens(served):
+    _, before, m, replies = served
+    assert before["prefill_launches"] == before["prefill_tokens"] == before["requests_finished"] == 0
+    assert m["requests_finished"] == len(replies) == 12
+    assert m["prefill_launches"] >= m["prefills"] == 12
+    sent = sum(r["prompt_tokens"] for r in replies)
+    # a returning turn also feeds the token its last reply held out; an
+    # evicted session's returning turn starts a fresh context and feeds none
+    fed = m["prefill_tokens"] + m["prefix_tokens_saved"]
+    assert sent <= fed <= sent + 6, (sent, m["prefill_tokens"], m["prefix_tokens_saved"])
+    assert m["prefix_tokens_saved"] > 0
+
+
+def test_fresh_prompts_prefill_exactly_what_the_arena_did_not_serve():
+    eng = LLMEngine.create("tiny", options=dict(TINY))
+    try:
+        async def drive():
+            return [await eng.generate(SHARED + tail, max_tokens=4, ignore_eos=True) for tail in ("alpha", "beta", "gamma")]
+
+        replies = asyncio.run(drive())
+        m = eng.metrics()
+        assert m["prefix_tokens_saved"] >= 128  # two forks of the 64-token level
+        assert m["prefill_tokens"] == sum(r["prompt_tokens"] for r in replies) - m["prefix_tokens_saved"]
+        assert m["requests_finished"] == 3
+    finally:
+        eng.shutdown()
+
+
+# -- the same spans on the profiler's clock --------------------------------
+def _host_events(trace_dir: str) -> list[tuple[str, int, int]]:
+    files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
+    assert files, f"no .xplane.pb under {trace_dir}"
+    data = jax.profiler.ProfileData.from_file(max(files, key=os.path.getmtime))
+    return [
+        (ev.name, int(ev.start_ns), int(ev.start_ns + ev.duration_ns))
+        for plane in data.planes if plane.name.startswith("/host:")
+        for ln in plane.lines for ev in ln.events
+    ]
+
+
+@pytest.mark.parametrize("python_tracer", [None, True])
+def test_profile_capture_holds_the_engine_spans(tmp_path, monkeypatch, python_tracer):
+    monkeypatch.setenv("AGENTAINER_PROFILE_DIR", str(tmp_path))
+
+    async def body():
+        eng = LLMEngine.create("tiny", options=dict(TINY))
+        serve = LLMServeApp(env={"AGENTAINER_AGENT_ID": "spans"})
+        serve.engine = eng
+        client = TestClient(TestServer(serve.app()))
+        await client.start_server()
+        try:
+            # compile outside the capture
+            resp = await client.post("/chat", json={"message": "warm", "session": "w", "max_tokens": 4})
+            assert resp.status == 200, await resp.text()
+            req = {"duration_s": 1.0} if python_tracer is None else {"duration_s": 1.0, "python_tracer": python_tracer}
+            capture = asyncio.ensure_future(client.post("/profile", json=req))
+            await asyncio.sleep(0.3)
+            # the event loop answers while the profiler starts, runs and stops
+            resp = await client.post(
+                "/chat", json={"message": "hello spans", "session": "s", "max_tokens": 6},
+                headers={REQUEST_ID_HEADER: "rid-1"},
+            )
+            assert resp.status == 200, await resp.text()
+            prof = await capture
+            assert prof.status == 200, await prof.text()
+            return await prof.json()
+        finally:
+            await client.close()
+            eng.shutdown()
+
+    doc = asyncio.run(body())
+    assert doc["python_tracer"] is bool(python_tracer)
+    events = _host_events(doc["trace_dir"])
+    by_name: dict[str, list[tuple[int, int]]] = {}
+    for name, start, end in events:
+        by_name.setdefault(name, []).append((start, end))
+    ticks, samples = by_name.get("engine.prefill_tick", []), by_name.get("engine.first_token_sample", [])
+    assert ticks and samples, sorted(n for n in by_name if n.startswith("engine."))
+    assert all(any(t0 <= s0 and s1 <= t1 for t0, t1 in ticks) for s0, s1 in samples)
+    frames = [n for n in by_name if re.search(r"\.py:\d+", n)]
+    if python_tracer:
+        assert frames  # the operator asked for Python frames and got them
+    else:
+        assert not frames, frames[:5]
+
+
+# -- seconds beside the compile counts -------------------------------------
+def test_compile_seconds_rise_on_a_fresh_function_only():
+    stats = enable_compile_cache()  # one more listener pair on this process
+    assert stats.as_dict()["trace_s"] == 0.0
+
+    @jax.jit
+    def fresh(x):
+        return jnp.tanh(x) * 3.0 + x.sum()
+
+    x = jnp.arange(7.0)
+    fresh(x).block_until_ready()
+    first = stats.as_dict()
+    assert first["trace_s"] > 0 and first["lower_s"] > 0 and first["compile_s"] > 0
+    assert first["compile_s"] >= first["retrieval_s"] >= 0  # a cache read is timed inside compile_s
+    assert first["writes"] == first["misses"]
+    fresh(x).block_until_ready()  # cached: nothing is traced, lowered or compiled
+    again = stats.as_dict()
+    assert {k: again[k] for k in ("trace_s", "lower_s", "compile_s", "retrieval_s")} == {
+        k: first[k] for k in ("trace_s", "lower_s", "compile_s", "retrieval_s")
+    }
+
+
+# -- one dispatch timing for the journal layer -----------------------------
+async def _python_front_door(tmp_path):
+    from agentainer_tpu.config import Config
+    from agentainer_tpu.daemon import build_services
+    from agentainer_tpu.runtime.local import LocalBackend
+    from agentainer_tpu.store import MemoryStore
+
+    cfg = Config()
+    cfg.auth_token = "spans-token"
+    backend = LocalBackend(data_dir=str(tmp_path), ready_timeout_s=120.0)
+    services = build_services(
+        config=cfg, store=MemoryStore(), backend=backend, console_logs=False, data_dir=str(tmp_path)
+    )
+    client = TestClient(TestServer(services.app))
+    await client.start_server()
+    backend.set_control(f"http://127.0.0.1:{client.server.port}")
+    return client, client.close
+
+
+async def _native_front_door(tmp_path):
+    from tests.test_dataplane import start_stack, teardown
+
+    services, task, session = await start_stack(tmp_path)
+    return session, lambda: teardown(services, task, session)
+
+
+@pytest.mark.parametrize("front_door", ["python", "native"])
+def test_journal_dispatch_is_sampled_through_the_front_door(tmp_path, front_door):
+    if front_door == "native" and not _native_available():
+        pytest.skip("native library unavailable")
+    auth = {"Authorization": "Bearer " + ("spans-token" if front_door == "python" else "dp-token")}
+
+    async def body():
+        http, close = await (_python_front_door if front_door == "python" else _native_front_door)(tmp_path)
+        try:
+            resp = await http.post(
+                "/agents",
+                json={
+                    "name": "spans-llm",
+                    "model": {"engine": "llm", "config": "tiny",
+                              "options": {"max_batch": 2, "max_seq": 128, "skip_warmup": True}},
+                    "env": {"JAX_PLATFORMS": "cpu"},
+                },
+                headers=auth,
+            )
+            assert resp.status == 200, await resp.text()
+            aid = (await resp.json())["data"]["id"]
+            resp = await http.post(f"/agents/{aid}/start", headers=auth)
+            assert resp.status == 200, await resp.text()
+            doc = {}
+            for _ in range(600):
+                doc = await (await http.get(f"/agent/{aid}/metrics")).json()
+                if doc.get("model_loaded"):
+                    break
+                await asyncio.sleep(0.2)
+            assert doc.get("model_loaded"), doc
+            assert doc["journal_dispatch_ms_samples"] == [] and doc["journal_dispatch_ms_p50"] is None
+
+            # a stamp the client sends is dropped: the front door's own counts
+            resp = await http.post(
+                f"/agent/{aid}/chat",
+                data=json.dumps({"message": "hello", "max_tokens": 4}),
+                headers={ACCEPTED_NS_HEADER: "1"},
+            )
+            assert resp.status == 200, await resp.text()
+            rid = resp.headers.get(REQUEST_ID_HEADER, "")
+            assert rid
+            doc = await (await http.get(f"/agent/{aid}/metrics")).json()
+            samples = doc["journal_dispatch_ms_samples"]
+            assert len(samples) == 1 and 0.0 <= samples[0] < 30_000.0, samples
+            assert doc["journal_dispatch_ms_p50"] == samples[0]
+            # the engine's own document rides the same surface
+            assert doc["requests_finished"] == 1 and "engine.prefill_tick" in doc["phases"]
+            assert {"trace_s", "lower_s", "compile_s", "retrieval_s", "misses"} <= set(doc["compile_cache"])
+
+            # a replayed dispatch goes out with the journaled headers, which
+            # carry no stamp: served, not sampled
+            served = doc["requests_total"]
+            resp = await http.post(f"/agents/{aid}/requests/{rid}/replay", headers=auth)
+            assert resp.status == 200, await resp.text()
+            doc = await (await http.get(f"/agent/{aid}/metrics")).json()
+            assert doc["requests_total"] > served
+            assert doc["journal_dispatch_ms_samples"] == samples
+        finally:
+            await close()
+
+    asyncio.run(body())
+
+
+# -- the XLA module names the benchmark's readers match by prefix ----------
+@pytest.fixture(scope="module", params=["dense", "paged"])
+def step_engine(request):
+    eng = LLMEngine.create("tiny", options=dict(TINY, paged_kv=request.param == "paged"))
+    yield eng
+    eng.shutdown()
+
+
+def _lower_step(eng: LLMEngine, step: str):
+    B = eng.max_batch
+    if step == "prefill":
+        where = (jnp.asarray(eng._bt[0:1]),) if eng.paged else (jnp.int32(0),)
+        return eng._prefill.lower(
+            eng.params, eng.cache, *where, jnp.zeros((1, 32), jnp.int32), jnp.zeros((1, 32), jnp.int32), jnp.int32(5)
+        )
+    lanes = (eng._dtok, eng._dpos, eng._dtemps, eng._dtopk, eng._dtopp)
+    if step == "decode_n":
+        keys = jax.random.split(jax.random.PRNGKey(0), eng.decode_chunk)
+        return eng._decode_n.lower(eng.params, eng.cache, *eng._bt_arg(), *lanes, keys)
+    return eng._verify_fn(2).lower(
+        eng.params, eng.cache, *eng._bt_arg(), *lanes,
+        jnp.zeros((B, 2), jnp.int32), jnp.zeros((B,), jnp.int32), jax.random.PRNGKey(0),
+    )
+
+
+@pytest.mark.parametrize("step, prefix", [("prefill", "jit_prefill"), ("decode_n", "jit_decode_n"), ("verify", "jit_verify")])
+def test_step_module_names_the_benchmark_matches(step_engine, step, prefix):
+    """``benchmark/layer_metrics/{decode,prefill}_step_roofline.py`` and
+    ``prefill_dev_share.py`` find the steps' device time by these prefixes of
+    the XLA module name; a renamed step function must fail here, not turn a
+    roofline into ``None``."""
+    text = _lower_step(step_engine, step).as_text()
+    module = re.search(r"module @(\w+)", text).group(1)
+    assert module.startswith(prefix), module
+    others = {"jit_prefill", "jit_decode_n", "jit_verify"} - {prefix}
+    assert not any(module.startswith(o) for o in others), module

@@ -200,7 +200,7 @@ def engine_jit_fns(engine) -> dict[str, object]:
     prefix fork/slice buckets, paged snapshot/restore). The names are the
     compile-key families the recompile budget is written against."""
     fns: dict[str, object] = {}
-    for attr in ("_prefill", "_decode_n", "_inject", "_alloc_cache", "_alloc_carry"):
+    for attr in ("_prefill", "_first_token", "_decode_n", "_inject", "_alloc_cache", "_alloc_carry"):
         fn = getattr(engine, attr, None)
         if fn is not None:
             fns[attr] = fn

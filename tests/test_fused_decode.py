@@ -259,16 +259,23 @@ def test_inloop_spec_matches_nonspec_greedy(base):
         eng.shutdown()
 
 
-def _staggered(eng, n_long=28, n_late=8):
+def _staggered(eng, n_long=96, n_late=8):
     """One long generation, then a late arrival that prefills while the
     first lane's fused loops are in flight — the window the injection
-    staging slot exists for."""
+    staging slot exists for. The late one is sent when the long one's
+    first token lands (its loops are then running), not a fixed time
+    after it: on a fast host the long one is finished by then."""
 
     async def body():
+        loop = asyncio.get_running_loop()
+        decoding = asyncio.Event()
         t1 = asyncio.create_task(
-            eng.generate("spin spin spin", max_tokens=n_long, temperature=0.0)
+            eng.generate(
+                "spin spin spin", max_tokens=n_long, temperature=0.0,
+                emit=lambda start, ids: loop.call_soon_threadsafe(decoding.set),
+            )
         )
-        await asyncio.sleep(0.05)
+        await asyncio.wait_for(decoding.wait(), timeout=120)
         t2 = asyncio.create_task(
             eng.generate("late arrival", max_tokens=n_late, temperature=0.0)
         )

@@ -36,6 +36,7 @@ from agentainer_tpu.analysis.hlo_contracts import (
     recompile_budget,
 )
 from agentainer_tpu.engine.llm import LLMEngine
+from agentainer_tpu.utils.compile_cache import enable_compile_cache
 
 
 @pytest.fixture(scope="module")
@@ -236,3 +237,63 @@ def test_recompile_budget_mixed_workload(engine):
     counts = compile_count(engine_jit_fns(engine))
     assert any(k.startswith("_verify_fns") for k in counts), counts
     assert "_prefill" in counts and "_decode_n" in counts
+
+
+# ---------------------------------------------------------------------------
+# nothing is traced, lowered or compiled while a warmed engine serves
+
+
+@pytest.fixture(scope="module")
+def compile_stats():
+    """One more listener pair on this process: JAX's own compile events and
+    durations, whichever function or eager primitive they come from."""
+    return enable_compile_cache()
+
+
+@pytest.fixture(scope="module", params=["dense", "paged", "fused", "meshed"])
+def warmed(request):
+    extra = {
+        "dense": {},
+        "paged": {"paged_kv": True},
+        "fused": {"paged_kv": True, "fused_decode": True},
+        "meshed": {"tp": 2},
+    }[request.param]
+    eng = LLMEngine.create(
+        "tiny",
+        options={"max_batch": 4, "max_seq": 256, "decode_chunk": 8, "prefill_chunk": 32, **extra},
+    )
+    yield eng
+    eng.shutdown()
+
+
+def test_serving_window_lowers_nothing(warmed, compile_stats):
+    """The recompile budget above counts the engine's NAMED jitted
+    functions, so an eager control-flow call on the worker (the first-token
+    sampler's ``lax.cond``, re-lowered on every request until ISSUE 24)
+    passed it for as long as it existed: an eager primitive has no family
+    to count. This check is on any lowering at all in the serving window."""
+
+    async def serve(**kw):
+        return await warmed.generate(max_tokens=4, ignore_eos=True, **kw)
+
+    before = compile_stats.as_dict()
+    for kw in (
+        {"prompt": "greedy first"},
+        {"prompt": "warm draw", "temperature": 0.8},
+        {"prompt": "top-k draw", "temperature": 0.8, "top_k": 5},
+        {"prompt": "top-p draw", "temperature": 0.8, "top_p": 0.9},
+        {"prompt": "both filters", "temperature": 1.2, "top_k": 40, "top_p": 0.95},
+        {"prompt": "first turn of a session", "session": "lw"},
+        {"prompt": "and its second turn", "session": "lw", "temperature": 0.7},
+        {"prompt": "longer than one prefill chunk " * 3},
+    ):
+        assert len(asyncio.run(serve(**kw))["tokens"]) == 4
+    after = compile_stats.as_dict()
+    grew = {
+        k: (before[k], after[k])
+        for k in ("requests", "misses", "trace_s", "lower_s", "compile_s")
+        if after[k] != before[k]
+    }
+    assert not grew, grew
+    assert "_first_token" in engine_jit_fns(warmed)
+    assert warmed._first_token._cache_size() == 1

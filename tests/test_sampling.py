@@ -6,10 +6,14 @@ must mean "keep everything" (the filter disabled), and top_k = V-1 must
 exclude exactly the lowest-logit token.
 """
 
+import asyncio
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+from agentainer_tpu.engine.llm import LLMEngine
 from agentainer_tpu.engine.sampling import APPROX_SEG, sample, sample_step
 
 V = 8
@@ -217,3 +221,102 @@ def test_step_mixed_lane_batch_jits_once():
     )
     assert a.shape == b.shape == (2,)
     assert fn._cache_size() == 1
+
+
+# ---------------------------------------------------------------------------
+# the engine's first-token program (ISSUE 24): sample_step compiled once per
+# engine must draw what the eager call it replaces drew, and must not shift
+# the engine's key stream.
+
+TINY = {"max_batch": 4, "max_seq": 256, "decode_chunk": 8, "prefill_chunk": 32, "skip_warmup": True}
+# the engine's two static choices: exact or approximate top-k, and (under a
+# mesh) no batch-wide greedy conditional
+ENGINES = {
+    "dense": {},
+    "approx_topk": {"approx_topk": True},
+    "meshed": {"tp": 2},
+    "paged_fused": {"paged_kv": True, "fused_decode": True},
+}
+
+
+@pytest.fixture(scope="module", params=["dense", "approx_topk", "meshed"])
+def sampler_engine(request):
+    eng = LLMEngine.create("tiny", options=dict(TINY, **ENGINES[request.param]))
+    yield eng
+    eng.shutdown()
+
+
+@pytest.mark.parametrize(
+    "t, k, p",
+    [
+        (0.0, 0, 1.0),  # greedy
+        (0.0, 3, 0.5),  # greedy ignores the filters
+        (0.9, 0, 1.0),  # temperature
+        (1.0, 1, 1.0),  # top-k at its edges: one token, the whole vocab, beyond it
+        (1.0, 512, 1.0),
+        (1.0, 519, 1.0),
+        (1.0, 0, 0.8),  # top-p
+        (1.3, 40, 0.95),  # both
+    ],
+)
+def test_first_token_program_draws_what_eager_sample_step_draws(sampler_engine, t, k, p):
+    eng = sampler_engine
+    V = eng.cfg.vocab_size
+    assert V == 512  # the top-k edges above are written against it
+    logits = 3.0 * jax.random.normal(jax.random.PRNGKey(7), (V,), jnp.float32)
+    params = (np.float32(t), np.int32(k), np.float32(p))
+    rng = jax.random.PRNGKey(11)
+    for i in range(2):
+        # the engine's key stream: one split per first token, as the eager
+        # call site made it
+        want_rng, key = jax.random.split(rng)
+        want = sample_step(
+            logits[None], key, *(jnp.asarray(x)[None] for x in params),
+            greedy_cond=eng.mesh is None, approx_topk=eng.approx_topk,
+        )
+        rng, first, tok = eng._first_token(logits, rng, *params)
+        assert rng.tolist() == want_rng.tolist()
+        assert first.shape == (1,) and first.dtype == jnp.int32
+        assert tok.shape == () and tok.dtype == jnp.int32
+        assert first.tolist() == want.tolist() == [int(tok)], (t, k, p, i)
+        if t == 0.0:
+            assert int(tok) == int(jnp.argmax(logits))
+    assert eng._first_token._cache_size() == 1
+
+
+# replies of the parent commit (7e3d951, eager sample_step) on this backend:
+# a fresh tiny engine serves these three requests in this order
+SEEDED_REQUESTS = [
+    {"prompt": "the quick brown fox"},
+    {"prompt": "the quick brown fox", "temperature": 0.9, "top_k": 50, "top_p": 0.95},
+    {"prompt": "jumps over the lazy dog and runs far away from here today", "temperature": 1.3},
+]
+PARENT_TOKENS = {
+    "dense": [
+        [373, 166, 349, 189, 82, 250, 50, 144, 373, 166],
+        [306, 133, 392, 391, 38, 464, 100, 61, 187, 269],
+        [481, 466, 263, 418, 455, 134, 256, 463, 287, 81],
+    ],
+    "paged_fused": [
+        [373, 166, 349, 189, 82, 250, 50, 144, 373, 166],
+        [379, 164, 157, 420, 308, 241, 192, 141, 28, 414],
+        [266, 276, 354, 441, 431, 239, 279, 191, 147, 114],
+    ],
+}
+
+
+@pytest.mark.parametrize("kind", sorted(PARENT_TOKENS))
+def test_seeded_engine_replies_as_the_parent_did(kind):
+    """The key stream did not shift: one split of the engine's key per first
+    token, as before, so greedy and sampled replies are the parent's."""
+    eng = LLMEngine.create("tiny", options=dict(TINY, **ENGINES[kind]))
+    try:
+        async def drive():
+            return [
+                (await eng.generate(max_tokens=10, ignore_eos=True, **kw))["tokens"]
+                for kw in SEEDED_REQUESTS
+            ]
+
+        assert asyncio.run(drive()) == PARENT_TOKENS[kind]
+    finally:
+        eng.shutdown()

@@ -15,6 +15,7 @@ import time
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from aiohttp.test_utils import TestClient, TestServer
 
@@ -392,6 +393,11 @@ def _lower_step(eng: LLMEngine, step: str):
         return eng._prefill.lower(
             eng.params, eng.cache, *where, jnp.zeros((1, 32), jnp.int32), jnp.zeros((1, 32), jnp.int32), jnp.int32(5)
         )
+    if step == "first_token":
+        return eng._first_token.lower(
+            jnp.zeros((eng.cfg.vocab_size,), jnp.float32), jax.random.PRNGKey(0),
+            np.float32(0.0), np.int32(0), np.float32(1.0),
+        )
     lanes = (eng._dtok, eng._dpos, eng._dtemps, eng._dtopk, eng._dtopp)
     if step == "decode_n":
         keys = jax.random.split(jax.random.PRNGKey(0), eng.decode_chunk)
@@ -402,14 +408,19 @@ def _lower_step(eng: LLMEngine, step: str):
     )
 
 
-@pytest.mark.parametrize("step, prefix", [("prefill", "jit_prefill"), ("decode_n", "jit_decode_n"), ("verify", "jit_verify")])
+STEP_MODULES = {"prefill": "jit_prefill", "first_token": "jit_first_token", "decode_n": "jit_decode_n", "verify": "jit_verify"}
+
+
+@pytest.mark.parametrize("step, prefix", sorted(STEP_MODULES.items()))
 def test_step_module_names_the_benchmark_matches(step_engine, step, prefix):
     """``benchmark/layer_metrics/{decode,prefill}_step_roofline.py`` and
     ``prefill_dev_share.py`` find the steps' device time by these prefixes of
     the XLA module name; a renamed step function must fail here, not turn a
-    roofline into ``None``."""
+    roofline into ``None``. ``jit_first_token`` is the sampler after the
+    final prefill chunk: a module of its own, so that ``jit_prefill``'s
+    device time stays the forward pass alone."""
     text = _lower_step(step_engine, step).as_text()
     module = re.search(r"module @(\w+)", text).group(1)
     assert module.startswith(prefix), module
-    others = {"jit_prefill", "jit_decode_n", "jit_verify"} - {prefix}
+    others = set(STEP_MODULES.values()) - {prefix}
     assert not any(module.startswith(o) for o in others), module

@@ -1,4 +1,4 @@
-"""Plain reference forward of the benchmark's model families.
+"""Plain reference forward of the ``llama`` family (``families/llama.py``).
 
 A decoder-only transformer written from the published descriptions
 (Mistral 7B, arXiv:2310.06825; Mixtral of Experts, arXiv:2401.04088; the
@@ -11,7 +11,9 @@ chosen k, weighted sum) -> residual; final RMSNorm and an untied output head.
 float32 throughout at ``highest`` matmul precision, the full causal forward
 over the whole sequence: no kernels, no cache, no batching tricks, and
 nothing imported from ``agentainer_tpu``. Weights arrive as plain float32
-arrays in the layout documented at ``forward``.
+arrays in the layout documented at ``forward``. The comparison rule and its
+tolerance are not here: ``harness/compare.py``, applied by
+``harness/numerics_child.py`` to every family alike.
 
 Departure from the published models: none in the mathematics. Neither served
 configuration uses a sliding window (Mistral-7B-v0.3 and Mixtral-8x7B-v0.1
@@ -22,52 +24,6 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-
-# Agreement asked of the program's logits against this reference: per compared
-# position, the root mean square of the difference over the standard
-# deviation of the reference's logits there (``position_errs``); the median
-# over the positions has to be under the tolerance, and so do at least
-# ``MIN_SHARE_WITHIN`` of the positions. Per position, because a mixture's
-# router is a discontinuity: random weights give near-tied router logits, a
-# rounding in bfloat16 then sends a token to another expert, and that one
-# position is far off in the program and in the bf16 control alike (measured:
-# one seed of five, whole-sample error 8.8 % against 0.9 %) while every other
-# position agrees. A term left out of the mathematics moves every position. The program
-# computes in bfloat16 with float32 accumulation; every run measures, beside
-# the program's own error, two controls computed by THIS file at the same
-# widths on the same tokens: the reference with every matmul input rounded to
-# bfloat16 (what the configuration states: it must pass) and with every
-# matmul input quantized to int8 per tensor (a lower precision than stated:
-# it must fail). A run whose controls do not straddle the tolerance reports
-# ``correct: false``, so the tolerance cannot silently go slack. Readings at
-# the published widths, 2 layers (my chip run, PR 22): see PERF.md section 6.
-REL_TOL = 0.02
-MIN_SHARE_WITHIN = 0.85
-
-
-def position_errs(got, want):
-    """``got``, ``want`` ``[positions, vocab]`` -> relative error per position."""
-    got, want = jnp.asarray(got, jnp.float32), jnp.asarray(want, jnp.float32)
-    return jnp.sqrt(jnp.mean((got - want) ** 2, axis=-1)) / jnp.std(want, axis=-1)
-
-
-def rel_err(got, want) -> float:
-    """Median over the positions of the relative error."""
-    return float(jnp.median(position_errs(got, want)))
-
-
-def share_within(got, want) -> float:
-    return float(jnp.mean(position_errs(got, want) < REL_TOL))
-
-
-def as_bf16(x):
-    return x.astype(jnp.bfloat16).astype(jnp.float32)
-
-
-def as_int8(x):
-    """Per-tensor absmax int8 round trip of an activation."""
-    scale = jnp.max(jnp.abs(x)) / 127.0
-    return jnp.clip(jnp.round(x / scale), -127, 127) * scale
 
 
 def rms_norm(x, w, eps):

@@ -4,10 +4,11 @@ kernel names inside the program.
 
 Least time = (weight-streaming passes in the traced span) x (bytes a pass must
 read: every layer's weights as served and the output head, plus the keys and
-values of the live context; ``harness/bytes_flops.py``) / the chip's peak
-bytes/s. A pass is one decode step or one speculative verify forward. Passes =
-launches of the decode modules in the trace x the mean steps per launch +
-launches of the verify modules; measured time = device time of those modules.
+values of the live context; the arithmetic is the configuration's family's,
+``harness/family.py``) / the chip's peak bytes/s. A pass is one decode step or
+one speculative verify forward. Passes = launches of the decode modules in the
+trace x the mean steps per launch + launches of the verify modules; measured
+time = device time of those modules.
 Launches and time come from the trace's device plane. Steps per launch and
 the lanes in use come from the engine's counters (``decode_chunk_hist``,
 ``batch_occupancy``) read right before and right after the trace, so they
@@ -16,7 +17,8 @@ describe the same span in the same regime; the mean context is the mix's
 the step: at 8 lanes it does 16 FLOPs per weight byte against the chip's 240.
 """
 
-from harness import bytes_flops, counters, peaks
+from harness import counters, peaks
+from harness.family import family_of
 
 from layer_metrics import batch_occupancy
 
@@ -42,6 +44,6 @@ def read(before, after, responses, trace, cell):
     mean_context = sum(r["context_tokens"] for r in ok) / len(ok) if ok else 0.0
     occupancy = batch_occupancy.read(*around, responses, trace, cell) or 0.0
     lanes = (around[1][0].get("max_batch") or 8) * occupancy
-    need = bytes_flops.decode_step_bytes(cell["config"], live_kv_tokens=lanes * mean_context)
+    need = family_of(cell["config"]).decode_step_bytes(cell["config"], live_kv_tokens=lanes * mean_context)
     least_s = passes * need / peaks.peaks_of(cell["device"]["kind"])["hbm_bytes_per_s"]
     return 100.0 * least_s / time_s

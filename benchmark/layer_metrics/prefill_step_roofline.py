@@ -1,7 +1,8 @@
 """kernels: prefill's achieved share of the chip's bf16 peak, from the device
 trace: FLOPs the algorithm needs for the prompt tokens prefilled while the
-trace ran (routed experts only, ``harness/bytes_flops.py``) over the device
-time of the prefill modules, over the peak. Compute bounds a 256-token chunk.
+trace ran (routed experts only; the arithmetic is the configuration's
+family's, ``harness/family.py``) over the device time of the prefill modules,
+over the peak. Compute bounds a 256-token chunk.
 
 Both the launches and their device time come from the trace's device plane.
 The engine counts no prefill launches or tokens, so the tokens of a launch
@@ -14,7 +15,8 @@ is for cells of unshared prompts, and overstates elsewhere.
 
 import math
 
-from harness import bytes_flops, peaks
+from harness import peaks
+from harness.family import family_of
 
 PREFILL = ("jit_prefill",)
 PREFILL_CHUNK = 256  # the engine's shipped default of its ``prefill_chunk`` option
@@ -33,5 +35,5 @@ def read(before, after, responses, trace, cell):
     # a token at position j attends to j others: the mean over a prompt's
     # tokens is half its length, weighted here by the prompts' lengths
     mean_context = sum(p * p for p in prompts) / (2.0 * sum(prompts))
-    flops = bytes_flops.prefill_flops(cell["config"], tokens, mean_context)
+    flops = family_of(cell["config"]).prefill_flops(cell["config"], tokens, mean_context)
     return 100.0 * flops / time_s / peaks.peaks_of(cell["device"]["kind"])["bf16_flops"]

@@ -16,8 +16,9 @@ engines' log tails on standard error.
 This process never imports JAX. What runs on the device runs in children: the
 engine hosts (of the daemon) and ``harness/numerics_child.py`` before them.
 Everything belonging to one cell is data found by name from
-``BENCHMARK.json``: ``configs/<config>.json``, ``traffic/<traffic>.json``
-(naming ``generators/<generator>.py``) and ``layer_metrics/<metric>.py``.
+``BENCHMARK.json``: ``configs/<config>.json`` (naming its block's
+``families/<family>.py``), ``traffic/<traffic>.json`` (naming
+``generators/<generator>.py``) and ``layer_metrics/<metric>.py``.
 
 The builder's own extras, never used by the driver: ``--rehearse`` (tiny
 widths on the CPU; prints no device metric a chip run could be taken for) and
@@ -44,17 +45,13 @@ REPO = os.path.dirname(BENCH)
 sys.path.insert(0, BENCH)
 
 from harness import loadgen, stats  # noqa: E402
+from harness.family import family_of  # noqa: E402
 from harness.system import ONE_CHIP_ENV, Agent, Daemon, PhaseFailure, check, device_of  # noqa: E402
 
 LOAD_BUDGET_S = 1100.0  # a first run compiles every step program
 TRACE_S = 5.0
 WARM_SEED_SALT = 7_919_000  # the warm-up's sessions come from another seed
 PROBE = "You are an agent on a TPU. The control plane journals every request. Say what you do next."
-# --rehearse: the same cell at widths a CPU can serve (control flow only)
-REHEARSAL_WIDTHS = {
-    "hidden_size": 64, "intermediate_size": 128, "num_attention_heads": 4,
-    "num_key_value_heads": 2, "head_dim": 16, "vocab_size": 512, "num_hidden_layers": 2,
-}
 
 
 def emit(phase: str, **fields) -> None:
@@ -208,7 +205,8 @@ def main() -> int:
         if args.rehearse:
             env["JAX_PLATFORMS"] = "cpu"
             env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-            config = {**config, **REHEARSAL_WIDTHS}
+            # the same cell at widths a CPU can serve (control flow only)
+            config = {**config, **family_of(config).REHEARSAL_WIDTHS}
             os.makedirs(os.path.join(REPO, ".chipwork"), exist_ok=True)
             config_path = os.path.join(REPO, ".chipwork", f"rehearsal-{config['name']}.json")
             with open(config_path, "w") as f:

@@ -5,13 +5,16 @@ and the daemon validates a deploy against the same registry, so a benchmark
 configuration that is not in ``models/configs.py`` has to be registered in
 both processes. The engine host inherits the daemon's environment, so this
 file runs in each: it reads the configuration file named in
-``ATPU_BENCH_CONFIG`` and calls the program's public ``register()``. It
-imports nothing heavy (``models.configs`` is a dataclass and a dict) and
+``ATPU_BENCH_CONFIG``, asks the file's family (``harness/family.py``) for the
+program's ``ModelConfig`` and calls the program's public ``register()``. It
+imports nothing heavy (``models.configs`` is a dataclass and a dict; a family
+module imports JAX only inside the functions of the numerics check) and
 edits no program file. A model config accepted from a file by the program
 itself would make it unnecessary (PERF.md, Open questions).
 """
 
 import os
+import sys
 
 
 def _register() -> None:
@@ -20,33 +23,19 @@ def _register() -> None:
         return
     import json
 
-    from agentainer_tpu.models.configs import ModelConfig, register
+    from agentainer_tpu.models.configs import register
 
     with open(path) as f:
         doc = json.load(f)
-    register(ModelConfig(**model_fields(doc)))
+    bench = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, bench)  # for the family's import only: the program sees no benchmark module
+    try:
+        from harness.family import family_of
 
-
-def model_fields(doc: dict, n_layers: int | None = None) -> dict:
-    """The program's ``ModelConfig`` fields from a configuration file whose
-    top level holds the model's published ``config.json`` keys, as run."""
-    heads = int(doc["num_attention_heads"])
-    if "head_dim" in doc and int(doc["head_dim"]) * heads != int(doc["hidden_size"]):
-        raise ValueError("the program's block derives head_dim as hidden_size / heads")
-    return {
-        "name": doc["name"],
-        "vocab_size": int(doc["vocab_size"]),
-        "dim": int(doc["hidden_size"]),
-        "n_layers": int(n_layers if n_layers is not None else doc["num_hidden_layers"]),
-        "n_heads": heads,
-        "n_kv_heads": int(doc.get("num_key_value_heads", heads)),
-        "ffn_dim": int(doc["intermediate_size"]),
-        "max_seq_len": int(doc["max_position_embeddings"]),
-        "rope_theta": float(doc["rope_theta"]),
-        "norm_eps": float(doc["rms_norm_eps"]),
-        "n_experts": int(doc.get("num_local_experts", 0) or 0),
-        "experts_per_token": int(doc.get("num_experts_per_tok", 2) or 2),
-    }
+        cfg = family_of(doc).model_config(doc)
+    finally:
+        sys.path.remove(bench)
+    register(cfg)
 
 
 _register()

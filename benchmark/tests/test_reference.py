@@ -9,7 +9,7 @@ import pytest
 from agentainer_tpu.models.configs import get_config
 from agentainer_tpu.models.llama import forward, init_params
 
-from harness import reference
+from harness import compare, reference
 
 
 @pytest.mark.parametrize("name", ["tiny", "tiny-moe"])
@@ -25,11 +25,11 @@ def test_reference_matches_the_program_forward(name):
     kw = dict(n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads, rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps,
               top_k=cfg.experts_per_token if cfg.is_moe else 0)
     want = reference.forward(weights, tokens, **kw)
-    assert reference.rel_err(got[0], want) < 1e-5
+    assert compare.rel_err(got[0], want) < 1e-5
     # the controls: bf16 activations are closer than int8 activations
-    bf16 = reference.rel_err(reference.forward(weights, tokens, act=reference.as_bf16, **kw), want)
-    int8 = reference.rel_err(reference.forward(weights, tokens, act=reference.as_int8, **kw), want)
+    bf16 = compare.rel_err(reference.forward(weights, tokens, act=compare.as_bf16, **kw), want)
+    int8 = compare.rel_err(reference.forward(weights, tokens, act=compare.as_int8, **kw), want)
     assert 0 < bf16 < int8
     # a wrong rotary base is a thousand times the agreement asked above, even
     # at these widths, where attention moves the logits little
-    assert reference.rel_err(reference.forward(weights, tokens, **{**kw, "rope_theta": 1.0}), want) > 5e-3
+    assert compare.rel_err(reference.forward(weights, tokens, **{**kw, "rope_theta": 1.0}), want) > 5e-3

@@ -125,16 +125,18 @@ def test_a_request_cut_by_the_drain_is_a_failure_of_the_window():
 def test_roofline_readers_count_the_work_of_the_traced_span():
     """Launches and device time from the trace, work per launch from the
     counters around the trace and the prompts sent: not from window rates."""
-    from harness import bytes_flops, peaks
+    from harness import peaks
+    from harness.family import family_of
 
     cell = {"config": bench_config(), "device": {"kind": "TPU v5 lite"}, "seconds": 51.0}
+    family = family_of(cell["config"])
     peak = peaks.peaks_of("TPU v5 lite")
     prefill = importlib.import_module("layer_metrics.prefill_step_roofline")
     # prompts of 300 and 512 tokens are 2 + 2 launches: 203 tokens a launch
     responses = [{"ok": True, "want_prompt_tokens": 300, "context_tokens": 332},
                  {"ok": True, "want_prompt_tokens": 512, "context_tokens": 544}]
     trace = {"modules": {"jit_prefill": {"time_s": 0.5, "count": 10}}, "window_s": 5.0}
-    flops = bytes_flops.prefill_flops(cell["config"], 10 * 812 / 4, (300**2 + 512**2) / (2 * 812))
+    flops = family.prefill_flops(cell["config"], 10 * 812 / 4, (300**2 + 512**2) / (2 * 812))
     assert prefill.read([], [], responses, trace, cell) == pytest.approx(100 * flops / 0.5 / peak["bf16_flops"])
     # twice the window's requests at the same lengths: the same share
     assert prefill.read([], [], responses * 2, trace, cell) == pytest.approx(100 * flops / 0.5 / peak["bf16_flops"])
@@ -144,7 +146,7 @@ def test_roofline_readers_count_the_work_of_the_traced_span():
     ta = [{"decode_chunk_hist": {"8": 110, "4": 10}, "decode_steps": 120, "batch_occupancy": 0.5, "max_batch": 8}]
     trace = {"modules": {"jit_decode_n": {"time_s": 1.0, "count": 15}, "jit_verify_k": {"time_s": 0.2, "count": 5}},
              "counters_before": tb, "counters_after": ta}
-    need = bytes_flops.decode_step_bytes(cell["config"], live_kv_tokens=4 * 438.0)
+    need = family.decode_step_bytes(cell["config"], live_kv_tokens=4 * 438.0)
     want = 100 * (15 * 6 + 5) * need / peak["hbm_bytes_per_s"] / 1.2
     # the window's own counters (first two arguments) play no part
     assert decode.read([{"decode_chunk_hist": {"1": 0}}], [{"decode_chunk_hist": {"1": 999}}], responses, trace, cell) == pytest.approx(want)

@@ -43,6 +43,12 @@ STREAM_EVENT_TOKEN = "token"
 STREAM_EVENT_DONE = "done"
 STREAM_EVENT_ERROR = "error"
 
+# how long a dispatch waits for the engine's answer when the request carries
+# no deadline (with one, it waits that long): as long as a whole buffered
+# generation may take. A dead engine closes its socket and is seen at once;
+# this only bounds one that hangs. native/dataplane.cc holds the same number.
+DISPATCH_WAIT_S = 600.0
+
 # dispatch_to_agent sentinel outcomes (never valid HTTP statuses)
 DISPATCH_ENGINE_GONE = -1  # connection refused / engine vanished → stays pending
 DISPATCH_FAILED = -2  # timeout or protocol error → retry accounted

@@ -24,6 +24,11 @@ no permutation.
 Mixtral MoE:
     …block_sparse_moe.gate               → router[i]            [D, E]ᵀ
     …experts.{e}.w1 / w3 / w2            → w_gate/w_up/w_down[i,e]ᵀ
+
+OLMoE (``num_experts``, ``norm_topk_prob``; QK-norm where its weights are):
+    …mlp.gate                            → router[i]            [D, E]ᵀ
+    …mlp.experts.{e}.{gate,up,down}_proj → w_gate/w_up/w_down[i,e]ᵀ
+    …self_attn.{q,k}_norm.weight         → q_norm/k_norm[i]     [H*hd]
 """
 
 from __future__ import annotations
@@ -80,8 +85,10 @@ class _Loader:
 
 def config_from_hf(path: str | Path) -> ModelConfig:
     """Derive a ModelConfig from the checkpoint's own config.json."""
-    doc = json.loads((Path(path).expanduser() / "config.json").read_text())
-    n_experts = int(doc.get("num_local_experts", 0) or 0)
+    path = Path(path).expanduser()
+    doc = json.loads((path / "config.json").read_text())
+    # Mixtral publishes ``num_local_experts``, OLMoE ``num_experts``
+    n_experts = int(doc.get("num_local_experts") or doc.get("num_experts") or 0)
     return ModelConfig(
         name=doc.get("model_type", "hf") + "-import",
         vocab_size=int(doc["vocab_size"]),
@@ -95,6 +102,11 @@ def config_from_hf(path: str | Path) -> ModelConfig:
         norm_eps=float(doc.get("rms_norm_eps", 1e-5)),
         n_experts=n_experts,
         experts_per_token=int(doc.get("num_experts_per_tok", 2)),
+        # Mixtral has no such key and always renormalises; OLMoE states it
+        moe_renormalize=bool(doc.get("norm_topk_prob", True)),
+        # no config.json key states it: the checkpoint has the norm's weights
+        # or it has not (a model's name decides nothing here)
+        qk_norm="model.layers.0.self_attn.q_norm.weight" in _open_shards(path),
     )
 
 
@@ -129,15 +141,22 @@ def load_hf_params(
         "wo": stack(L + "self_attn.o_proj.weight"),
         "mlp_norm": stack(L + "post_attention_layernorm.weight", transpose=False),
     }
+    if cfg.qk_norm:
+        layers["q_norm"] = stack(L + "self_attn.q_norm.weight", transpose=False)
+        layers["k_norm"] = stack(L + "self_attn.k_norm.weight", transpose=False)
     if cfg.is_moe:
-        layers["router"] = stack(L + "block_sparse_moe.gate.weight")
+        # the two published spellings of a mixture: Mixtral's, else OLMoE's
+        mixtral = "model.layers.0.block_sparse_moe.gate.weight" in ld
+        moe = "block_sparse_moe" if mixtral else "mlp"
+        names = ("w1", "w3", "w2") if mixtral else ("gate_proj", "up_proj", "down_proj")
+        layers["router"] = stack(L + moe + ".gate.weight")
 
         def experts(w: str) -> np.ndarray:  # [L, E, …]
             return np.stack(
                 [
                     np.stack(
                         [
-                            t(f"model.layers.{i}.block_sparse_moe.experts.{e}.{w}.weight")
+                            t(f"model.layers.{i}.{moe}.experts.{e}.{w}.weight")
                             for e in range(cfg.n_experts)
                         ]
                     )
@@ -145,9 +164,7 @@ def load_hf_params(
                 ]
             )
 
-        layers["w_gate"] = experts("w1")
-        layers["w_down"] = experts("w2")
-        layers["w_up"] = experts("w3")
+        layers["w_gate"], layers["w_up"], layers["w_down"] = (experts(w) for w in names)
     else:
         layers["w_gate"] = stack(L + "mlp.gate_proj.weight")
         layers["w_up"] = stack(L + "mlp.up_proj.weight")

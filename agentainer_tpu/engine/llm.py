@@ -622,7 +622,7 @@ class LLMEngine:
             )
             p_sh = jax.tree.map(
                 lambda s: NamedSharding(self.mesh, s),
-                pipeline_param_specs(cfg.is_moe),
+                pipeline_param_specs(cfg.is_moe, qk_norm=cfg.qk_norm),
                 is_leaf=lambda x: isinstance(x, P),
             )
             params = jax.device_put(params, p_sh)
@@ -1199,7 +1199,7 @@ class LLMEngine:
             else:
                 from ..parallel.pipeline import pipeline_param_specs as _pps
 
-                params = _sharded_random_init(cfg, dtype, mesh, _pps(cfg.is_moe))
+                params = _sharded_random_init(cfg, dtype, mesh, _pps(cfg.is_moe, qk_norm=cfg.qk_norm))
             engine = cls(
                 cfg,
                 params,
@@ -1309,7 +1309,7 @@ class LLMEngine:
             # whole model on the default device (VERDICT r3 missing #3)
             from ..parallel.sharding import param_specs as _ps
 
-            params = _sharded_random_init(cfg, dtype, mesh, _ps(cfg.is_moe))
+            params = _sharded_random_init(cfg, dtype, mesh, _ps(cfg.is_moe, cfg.qk_norm))
         else:
             params = init_params(cfg, jax.random.PRNGKey(0), dtype=dtype)
         if quant and not (synthetic and not checkpoint):
@@ -1424,6 +1424,23 @@ class LLMEngine:
                     capacity_factor=self.moe_capacity_factor,
                 )
         self.routed_moe = moe_impl is not None
+        self.moe = {
+            "impl": (
+                "none" if not cfg.is_moe
+                else "all_experts_einsum" if moe_impl is None
+                else "routed_dispatch"
+            ),
+            "experts": cfg.n_experts,
+            "top_k": cfg.experts_per_token if cfg.is_moe else 0,
+            "renormalize": bool(cfg.is_moe and cfg.moe_renormalize),
+        }
+        if cfg.is_moe:
+            print(
+                f"[llm-engine] moe: impl={self.moe['impl']} experts={cfg.n_experts} "
+                f"top_k={cfg.experts_per_token} renormalize={cfg.moe_renormalize} "
+                f"qk_norm={cfg.qk_norm}",
+                flush=True,
+            )
 
         pp_forward = self._pp_forward
 
@@ -3468,6 +3485,16 @@ class LLMEngine:
             },
             "engine_devices": [self._device_doc(d) for d in self._devices],
             "attention": self.attention,
+            # which MoE path the compiled steps trace, and the block's shape
+            "moe": self.moe,
+            "model_arch": {
+                "layers": self.cfg.n_layers,
+                "dim": self.cfg.dim,
+                "heads": self.cfg.n_heads,
+                "kv_heads": self.cfg.n_kv_heads,
+                "head_dim": self.cfg.head_dim,
+                "qk_norm": self.cfg.qk_norm,
+            },
             "n_chips": self._n_chips,
             "param_hbm_bytes": self.param_hbm_bytes,
             "kv_arena_bytes": self.kv_arena_bytes,

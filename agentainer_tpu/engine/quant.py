@@ -75,7 +75,7 @@ def synthetic_quantized_params(cfg, dtype=jnp.bfloat16, device=None, mesh=None) 
 
         from ..parallel.sharding import param_specs, qtensor_sharding
 
-        sp = param_specs(cfg.is_moe)
+        sp = param_specs(cfg.is_moe, cfg.qk_norm)
         # name → spec lookup (leaves live at two depths in the pytree)
         specs = {**sp["layers"], "embed": sp["embed"], "lm_head": sp["lm_head"],
                  "final_norm": sp["final_norm"]}
@@ -134,6 +134,9 @@ def synthetic_quantized_params(cfg, dtype=jnp.bfloat16, device=None, mesh=None) 
             "attn_norm": ones((L, d), "attn_norm"),
             "mlp_norm": ones((L, d), "mlp_norm"),
         }
+        if cfg.qk_norm:
+            layers["q_norm"] = ones((L, cfg.n_heads * hd), "q_norm")
+            layers["k_norm"] = ones((L, cfg.n_kv_heads * hd), "k_norm")
         for name, shape in shapes.items():
             layers[name] = qleaf(next(keys), shape, name)
         return {

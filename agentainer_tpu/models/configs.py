@@ -28,6 +28,13 @@ class ModelConfig:
     # MoE (0 experts → dense FFN)
     n_experts: int = 0
     experts_per_token: int = 2
+    # gates: softmax over the chosen k (Mixtral) when True; softmax over all
+    # experts, top-k kept as it is (OLMoE, ``norm_topk_prob: false``) when False
+    moe_renormalize: bool = True
+    # RMSNorm with a learned weight over the whole projected query and the
+    # whole projected key, before the split into heads and the rotary
+    # embedding (OLMoE)
+    qk_norm: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -47,6 +54,8 @@ class ModelConfig:
         if self.is_moe:
             ffn = self.n_experts * ffn + self.dim * self.n_experts
         per_layer = per_layer_attn + ffn + 2 * self.dim
+        if self.qk_norm:
+            per_layer += (self.n_heads + self.n_kv_heads) * self.head_dim
         return 2 * embed + self.n_layers * per_layer + self.dim
 
     def param_bytes(self, dtype_bytes: int = 2) -> int:
@@ -121,6 +130,27 @@ MIXTRAL_8X7B = register(
     )
 )
 
+# OLMoE-1B-7B architecture (allenai/OLMoE-1B-7B-0125-Instruct config.json:
+# 16 layers, 2048 dim, 16/16 heads of 128, 64 experts of 1024 top-8 with
+# un-renormalised gates, QK-norm, 50304 vocab, theta 1e4, context 4096).
+OLMOE_1B_7B = register(
+    ModelConfig(
+        name="olmoe-1b-7b",
+        vocab_size=50_304,
+        dim=2048,
+        n_layers=16,
+        n_heads=16,
+        n_kv_heads=16,
+        ffn_dim=1024,
+        max_seq_len=4096,
+        rope_theta=10_000.0,
+        n_experts=64,
+        experts_per_token=8,
+        moe_renormalize=False,
+        qk_norm=True,
+    )
+)
+
 # Tiny CI configs — same code paths, CPU-mesh friendly shapes.
 TINY = register(
     ModelConfig(
@@ -149,6 +179,26 @@ TINY_MOE = register(
         rope_theta=10_000.0,
         n_experts=4,
         experts_per_token=2,
+    )
+)
+
+# OLMoE's block at CI shapes: more experts than lanes, un-renormalised
+# gates, QK-norm over all heads.
+TINY_OLMOE = register(
+    ModelConfig(
+        name="tiny-olmoe",
+        vocab_size=512,
+        dim=64,
+        n_layers=2,
+        n_heads=4,
+        n_kv_heads=4,
+        ffn_dim=32,
+        max_seq_len=256,
+        rope_theta=10_000.0,
+        n_experts=8,
+        experts_per_token=2,
+        moe_renormalize=False,
+        qk_norm=True,
     )
 )
 

@@ -95,6 +95,9 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype = jnp.bfloat1
         "wo": w(next(keys), cfg.n_layers, cfg.n_heads * hd, d),
         "mlp_norm": jnp.ones((cfg.n_layers, d), dtype),
     }
+    if cfg.qk_norm:
+        layers["q_norm"] = jnp.ones((cfg.n_layers, cfg.n_heads * hd), dtype)
+        layers["k_norm"] = jnp.ones((cfg.n_layers, cfg.n_kv_heads * hd), dtype)
     if cfg.is_moe:
         layers.update(
             {
@@ -126,6 +129,21 @@ def _mlp(x: jnp.ndarray, lp: dict) -> jnp.ndarray:
     return (gate * (x @ lp["w_up"])) @ lp["w_down"]
 
 
+def moe_gates(logits: jnp.ndarray, cfg: ModelConfig, dtype) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The router rule, once for every MoE path: ``(gates, chosen)``, both
+    ``[..., k]``, from router logits ``[..., E]``. ``moe_renormalize``
+    (Mixtral): top-k of the logits, float32 softmax over the chosen k, so a
+    token's gates sum to 1. Otherwise (OLMoE, ``norm_topk_prob: false``):
+    float32 softmax over all E experts, the top k kept as they are."""
+    k = cfg.experts_per_token
+    if cfg.moe_renormalize:
+        top, chosen = lax.top_k(logits, k)
+        return jax.nn.softmax(top.astype(jnp.float32), axis=-1).astype(dtype), chosen
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    gates, chosen = lax.top_k(probs, k)
+    return gates.astype(dtype), chosen
+
+
 def _moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig) -> jnp.ndarray:
     """Dense-einsum MoE (top-k routing, all experts computed, masked combine).
 
@@ -135,9 +153,7 @@ def _moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig) -> jnp.ndarray:
     default wherever ep > 1.
     """
     b, t, d = x.shape
-    logits = x @ lp["router"]  # [B,T,E]
-    weights, chosen = lax.top_k(logits, cfg.experts_per_token)
-    weights = jax.nn.softmax(weights.astype(jnp.float32), axis=-1).astype(x.dtype)
+    weights, chosen = moe_gates(x @ lp["router"], cfg, x.dtype)  # [B,T,K]
     onehot = jax.nn.one_hot(chosen, cfg.n_experts, dtype=x.dtype)  # [B,T,K,E]
     combine = jnp.einsum("btk,btke->bte", weights, onehot)  # [B,T,E]
     gate = jax.nn.silu(jnp.einsum("btd,edf->btef", x, lp["w_gate"]))
@@ -208,9 +224,8 @@ def _moe_mlp_routed(
     else:
         cap = routed_capacity(n, cfg.n_experts, k, capacity_factor)
     xf = x.reshape(n, d)
-    logits = xf @ lp["router"]  # [N, E] — full expert set
-    weights, chosen = lax.top_k(logits, k)
-    weights = jax.nn.softmax(weights.astype(jnp.float32), axis=-1).astype(x.dtype)
+    # [N, E] logits over the full expert set
+    weights, chosen = moe_gates(xf @ lp["router"], cfg, x.dtype)
     # one-hot over LOCAL experts; choices outside [base, base+e_loc) fall
     # out of range and one-hot to all-zero rows
     local = (chosen - base).reshape(n * k)
@@ -248,8 +263,15 @@ def _attention_block(
 ):
     b, t, d = x.shape
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    q = (h @ lp["wq"]).reshape(b, t, cfg.n_heads, cfg.head_dim)
-    k = (h @ lp["wk"]).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
+    q, k = h @ lp["wq"], h @ lp["wk"]
+    if cfg.qk_norm:
+        # over all heads' values at once, before the split and the rotary
+        # embedding; under tp the columns are sharded and GSPMD reduces
+        # across the shards (tests/test_olmoe.py pins it)
+        q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+    q = q.reshape(b, t, cfg.n_heads, cfg.head_dim)
+    k = k.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
     v = (h @ lp["wv"]).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)

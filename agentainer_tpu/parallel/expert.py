@@ -27,7 +27,7 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.configs import ModelConfig
-from ..models.llama import _moe_mlp_routed
+from ..models.llama import _moe_mlp_routed, moe_gates
 
 
 def _moe_local(x, router, w_gate, w_up, w_down, *, axis_name: str, cfg: ModelConfig):
@@ -36,9 +36,7 @@ def _moe_local(x, router, w_gate, w_up, w_down, *, axis_name: str, cfg: ModelCon
     ax = lax.axis_index(axis_name)
     e_local = w_gate.shape[0]
     # replicated routing over the FULL expert set
-    logits = x @ router  # [B,T,E]
-    weights, chosen = lax.top_k(logits, cfg.experts_per_token)
-    weights = jax.nn.softmax(weights.astype(jnp.float32), axis=-1).astype(x.dtype)
+    weights, chosen = moe_gates(x @ router, cfg, x.dtype)  # [B,T,K]
     onehot = jax.nn.one_hot(chosen, cfg.n_experts, dtype=x.dtype)  # [B,T,K,E]
     combine = jnp.einsum("btk,btke->bte", weights, onehot)  # [B,T,E]
     # slice my experts' combine weights

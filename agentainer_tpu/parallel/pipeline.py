@@ -38,7 +38,7 @@ from ..ops.norms import rms_norm
 from ..ops.quant import dequant, embed_lookup
 
 
-def pipeline_layer_specs(moe: bool, tp: bool = False) -> dict:
+def pipeline_layer_specs(moe: bool, tp: bool = False, qk_norm: bool = False) -> dict:
     """PartitionSpecs for the ``layers`` subtree with the leading layer
     axis sharded over pp (each stage holds its own L/pp slice whole).
     With ``tp`` the widths additionally carry Megatron shardings (column-
@@ -52,6 +52,9 @@ def pipeline_layer_specs(moe: bool, tp: bool = False) -> dict:
         "wo": P("pp", t, None),
         "mlp_norm": P("pp", None),
     }
+    if qk_norm:
+        specs["q_norm"] = P("pp", None)
+        specs["k_norm"] = P("pp", None)
     if moe:
         specs.update(
             {
@@ -72,7 +75,7 @@ def pipeline_layer_specs(moe: bool, tp: bool = False) -> dict:
     return specs
 
 
-def pipeline_param_specs(moe: bool, tp: bool = False) -> dict:
+def pipeline_param_specs(moe: bool, tp: bool = False, qk_norm: bool = False) -> dict:
     """Placement specs for the full pytree under a pp (optionally ×tp)
     mesh. Layers stage over pp; embed and lm_head VOCAB-shard over pp so
     every stage owns 1/pp of them instead of replicating both (the lookup
@@ -82,7 +85,7 @@ def pipeline_param_specs(moe: bool, tp: bool = False) -> dict:
     bodies pick up their tp collectives automatically."""
     return {
         "embed": P("pp", None),
-        "layers": pipeline_layer_specs(moe, tp=tp),
+        "layers": pipeline_layer_specs(moe, tp=tp, qk_norm=qk_norm),
         "final_norm": P(None),
         "lm_head": P(None, "pp"),
     }
@@ -124,7 +127,7 @@ def make_pipeline_loss(cfg: ModelConfig, mesh: Mesh, n_microbatch: int | None = 
     pp = int(mesh.shape["pp"])
     M = int(n_microbatch or pp)
     perm = [(i, (i + 1) % pp) for i in range(pp)]
-    layer_specs = pipeline_layer_specs(cfg.is_moe)
+    layer_specs = pipeline_layer_specs(cfg.is_moe, qk_norm=cfg.qk_norm)
     if cfg.vocab_size % pp:
         raise ValueError(f"vocab {cfg.vocab_size} must divide by pp={pp}")
     vshard = cfg.vocab_size // pp
@@ -257,7 +260,7 @@ def make_serve_pipeline_forward(cfg: ModelConfig, mesh: Mesh):
         raise ValueError(f"vocab {cfg.vocab_size} must divide by pp={pp}")
     vshard = cfg.vocab_size // pp
     perm = [(i, (i + 1) % pp) for i in range(pp)]
-    layer_specs = pipeline_layer_specs(cfg.is_moe)
+    layer_specs = pipeline_layer_specs(cfg.is_moe, qk_norm=cfg.qk_norm)
     cache_spec = P("pp", None, None, None, None)
 
     def local(layers_local, embed, final_norm, lm_head, tokens, positions, ck, cv):

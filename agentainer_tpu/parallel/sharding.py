@@ -17,7 +17,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def param_specs(moe: bool) -> dict:
+def param_specs(moe: bool, qk_norm: bool = False) -> dict:
     """PartitionSpec tree matching models/llama.init_params' structure."""
     layers = {
         "attn_norm": P(None, None),
@@ -27,6 +27,11 @@ def param_specs(moe: bool) -> dict:
         "wo": P(None, "tp", None),  # row-parallel: all-reduce after
         "mlp_norm": P(None, None),
     }
+    if qk_norm:
+        # a norm over all heads' values: its weight stays whole on every
+        # chip, and GSPMD reduces the mean square across the tp column shards
+        layers["q_norm"] = P(None, None)
+        layers["k_norm"] = P(None, None)
     if moe:
         layers.update(
             {
@@ -63,8 +68,8 @@ def shardings_from_specs(mesh: Mesh, specs) -> dict:
     )
 
 
-def param_shardings(mesh: Mesh, moe: bool = False) -> dict:
-    return shardings_from_specs(mesh, param_specs(moe))
+def param_shardings(mesh: Mesh, moe: bool = False, qk_norm: bool = False) -> dict:
+    return shardings_from_specs(mesh, param_specs(moe, qk_norm))
 
 
 def scale_spec(spec: P) -> P:
@@ -102,7 +107,7 @@ def param_shardings_for(params: dict, mesh: Mesh, moe: bool = False) -> dict:
 
     return jax.tree.map(
         mk,
-        param_specs(moe),
+        param_specs(moe, "q_norm" in params["layers"]),
         params,
         is_leaf=lambda x: isinstance(x, P),
     )
@@ -124,4 +129,4 @@ def cache_specs(sp: bool = False) -> P:
 
 
 def shard_params(params: dict, mesh: Mesh, moe: bool = False) -> dict:
-    return jax.device_put(params, param_shardings(mesh, moe))
+    return jax.device_put(params, param_shardings(mesh, moe, "q_norm" in params["layers"]))

@@ -52,6 +52,7 @@ from ..core.protocol import (  # noqa: F401  (re-export)
     DISPATCH_EXPIRED,
     DISPATCH_FAILED,
     DISPATCH_IN_FLIGHT,
+    DISPATCH_WAIT_S,
     DRAINING_HEADER,
     EXPIRED_HEADER,
     LAST_EVENT_ID_HEADER,
@@ -2151,16 +2152,16 @@ class ControlPlaneApp:
         fwd_headers.pop(DEADLINE_HEADER, None)
         if request_id:
             fwd_headers[REQUEST_ID_HEADER] = request_id
-        timeout = None  # session default (30 s)
+        # the wait follows the request, not the session's 30 s: a buffered
+        # generation may take longer than that on a healthy engine
+        timeout = ClientTimeout(total=DISPATCH_WAIT_S)
         if deadline_at is not None:
             # the engine sees the REMAINING budget, and the dispatch wait is
-            # clamped to it — the old fixed 30 s abandoned the HTTP call
-            # while the engine kept decoding for a caller that was gone
+            # that budget: a shorter fixed wait abandoned the HTTP call while
+            # the engine kept decoding, a longer one held a caller that was gone
             remaining = deadline_at - time.time()
             fwd_headers[DEADLINE_HEADER] = str(max(1, int(remaining * 1000)))
-            from aiohttp import ClientTimeout as _CT
-
-            timeout = _CT(total=min(30.0, max(0.1, remaining)))
+            timeout = ClientTimeout(total=max(0.1, remaining))
         t0 = time.monotonic()
         import aiohttp
 
@@ -2174,7 +2175,7 @@ class ControlPlaneApp:
                 url,
                 headers=fwd_headers,
                 data=body if body else None,
-                **({"timeout": timeout} if timeout is not None else {}),
+                timeout=timeout,
             ) as resp:
                 resp_body = await resp.read()
                 resp_headers = dict(resp.headers)

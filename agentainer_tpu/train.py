@@ -125,13 +125,13 @@ def make_train_step(
         tp_size = int(mesh.shape.get("tp", 1))
         p_shard = jax.tree.map(
             lambda s: NamedSharding(mesh, s),
-            pipeline_param_specs(cfg.is_moe, tp=tp_size > 1),
+            pipeline_param_specs(cfg.is_moe, tp=tp_size > 1, qk_norm=cfg.qk_norm),
             is_leaf=lambda x: isinstance(x, P),
         )
         data = NamedSharding(mesh, P("dp", None))  # dp-sharded tokens
         compute_loss = make_pipeline_loss(cfg, mesh, n_microbatch)
     else:
-        p_shard = param_shardings(mesh, moe=cfg.is_moe)
+        p_shard = param_shardings(mesh, moe=cfg.is_moe, qk_norm=cfg.qk_norm)
         # sp runs: tokens are [B, T+1] and T+1 need not divide by sp — place
         # them dp-sharded and let loss_fn re-shard the T-long slice over sp
         data = NamedSharding(mesh, P("dp", None) if sp > 1 else batch_spec())

@@ -13,6 +13,8 @@
 #include <algorithm>
 #include <cctype>
 #include <cerrno>
+#include <cmath>
+#include <cstdlib>
 #include <chrono>
 #include <cstring>
 #include <random>
@@ -93,13 +95,17 @@ struct HttpMsg {
 struct SockBuf {
   int fd;
   std::string buf;
+  bool timed_out = false;  // the last failed fill() ran out SO_RCVTIMEO
   explicit SockBuf(int f) : fd(f) {}
 
   // Returns false on EOF/error before any progress could complete.
   bool fill() {
     char chunk[1 << 14];
     ssize_t n = ::recv(fd, chunk, sizeof(chunk), 0);
-    if (n <= 0) return false;
+    if (n <= 0) {
+      timed_out = n < 0 && (errno == EAGAIN || errno == EWOULDBLOCK);
+      return false;
+    }
     buf.append(chunk, static_cast<size_t>(n));
     return true;
   }
@@ -142,8 +148,10 @@ static bool send_all(int fd, const std::string& s) {
 // Parse one HTTP message from the socket. is_response selects status-line vs
 // request-line. Handles Content-Length and chunked bodies. `eof_clean`
 // reports EOF-before-first-byte, which on a reused upstream connection means
-// a stale keepalive, not a crash. `response_to_head`: HEAD responses carry
-// Content-Length but no body (RFC 9110 §6.4.1), so body reads must be skipped.
+// a stale keepalive, not a crash; a receive timeout is not an EOF (the peer
+// holds the connection and has not answered yet). `response_to_head`: HEAD
+// responses carry Content-Length but no body (RFC 9110 §6.4.1), so body reads
+// must be skipped.
 static bool read_http(SockBuf& sb, bool is_response, HttpMsg* msg,
                       bool* eof_clean = nullptr, bool response_to_head = false) {
   static const long long MAX_BODY = 1LL << 31;  // shared CL/chunked cap
@@ -151,7 +159,7 @@ static bool read_http(SockBuf& sb, bool is_response, HttpMsg* msg,
   std::string line;
   if (sb.buf.empty() && eof_clean) {
     if (!sb.fill()) {
-      *eof_clean = true;
+      *eof_clean = !sb.timed_out;
       return false;
     }
   }
@@ -373,6 +381,17 @@ static std::string store_get(Store* s, const std::string& key, bool* found) {
 }
 
 static constexpr double REQUEST_TTL_S = 24 * 3600;  // requests.go:106
+// How long a forward waits for the upstream's answer. The management backend
+// answers at once or is broken. An agent dispatch waits as long as its caller
+// does: the request's X-Agentainer-Deadline-Ms (plus a grace in which the
+// engine's own "expired" answer arrives first), and without one as long as a
+// whole buffered generation may take (2048 tokens at 50 ms a step are 100 s;
+// a fixed 30 s failed healthy generations as "unreachable"). An engine that
+// dies closes its socket and is seen at once; this bound only catches one
+// that hangs with its socket open.
+static constexpr double UPSTREAM_WAIT_S = 30;
+static constexpr double DISPATCH_WAIT_S = 600;
+static constexpr double DEADLINE_GRACE_S = 1;
 
 // ---- DataPlane -------------------------------------------------------------
 
@@ -585,7 +604,7 @@ struct ConnCtx {
     if (fd < 0) return -1;
     int one = 1;
     ::setsockopt(fd, IPPROTO_TCP, TCP_NODELAY, &one, sizeof(one));
-    timeval tv{30, 0};
+    timeval tv{static_cast<time_t>(UPSTREAM_WAIT_S), 0};
     ::setsockopt(fd, SOL_SOCKET, SO_RCVTIMEO, &tv, sizeof(tv));
     ::setsockopt(fd, SOL_SOCKET, SO_SNDTIMEO, &tv, sizeof(tv));
     sockaddr_in addr{};
@@ -611,8 +630,10 @@ struct ConnCtx {
   // stale keepalive socket. Outcomes: 0 ok, 1 connection-refused/engine-gone,
   // 2 other failure (timeout / protocol error). `head` marks a HEAD request,
   // whose response advertises Content-Length without sending a body.
+  // `wait_s` is how long the answer is waited for; a wait that runs out is
+  // outcome 2 and is not retried (the upstream has the request).
   int roundtrip(const std::string& host, int port, const std::string& raw_req,
-                HttpMsg* resp, bool head = false) {
+                HttpMsg* resp, bool head = false, double wait_s = UPSTREAM_WAIT_S) {
     std::string key = host + ":" + std::to_string(port);
     for (int attempt = 0; attempt < 2; attempt++) {
       bool fresh = false;
@@ -628,6 +649,10 @@ struct ConnCtx {
       } else {
         fd = it->second;
       }
+      // per roundtrip: a kept-alive socket serves requests of any deadline
+      timeval tv{static_cast<time_t>(wait_s),
+                 static_cast<suseconds_t>((wait_s - std::floor(wait_s)) * 1e6)};
+      ::setsockopt(fd, SOL_SOCKET, SO_RCVTIMEO, &tv, sizeof(tv));
       if (!send_all(fd, raw_req)) {
         drop(key, fd);
         if (fresh) return 1;  // engine accepted then died: treat as gone
@@ -793,8 +818,11 @@ void DataPlane::handle_conn(int fd) {
           accepted_ns);
       HttpMsg up;
       double t0 = mono_s();
+      double deadline_ms = std::atof(req.header("x-agentainer-deadline-ms").c_str());
+      double wait_s =
+          deadline_ms > 0 ? deadline_ms / 1000.0 + DEADLINE_GRACE_S : DISPATCH_WAIT_S;
       int rc = ctx.roundtrip(route.host, route.port, upstream_req, &up,
-                             req.method == "HEAD");
+                             req.method == "HEAD", wait_s);
       double dt = mono_s() - t0;
 
       bool loading = rc == 0 && up.status == 503 &&

@@ -334,3 +334,69 @@ def test_agent_records_survive_daemon_restart(tmp_path):
             await teardown(services2, task2, session2)
 
     asyncio.run(body())
+
+
+async def _slow_engine(delay_s: float, seen: list):
+    """An upstream that holds its socket open and answers ``delay_s`` after
+    it has read a request: a healthy engine in a long generation."""
+
+    async def handle(reader, writer):
+        try:
+            while True:
+                head = await reader.readuntil(b"\r\n\r\n")
+                length = int(
+                    [ln.split(b":")[1] for ln in head.split(b"\r\n") if ln.lower().startswith(b"content-length")][0]
+                )
+                await reader.readexactly(length)
+                seen.append(head)
+                await asyncio.sleep(delay_s)
+                body = b'{"response": "done"}'
+                writer.write(
+                    b"HTTP/1.1 200 OK\r\nContent-Type: application/json\r\nContent-Length: "
+                    + str(len(body)).encode() + b"\r\n\r\n" + body
+                )
+                await writer.drain()
+        except (asyncio.IncompleteReadError, ConnectionError):
+            pass
+        finally:
+            writer.close()
+
+    server = await asyncio.start_server(handle, "127.0.0.1", 0)
+    return server, server.sockets[0].getsockname()[1]
+
+
+@pytest.mark.parametrize(
+    "deadline_ms, delay_s, status",
+    [(4000, 1.2, 200), (300, 3.0, 504), (None, 1.2, 200)],
+    ids=["answered_inside_its_deadline", "deadline_runs_out", "no_deadline"],
+)
+def test_dispatch_waits_as_long_as_the_request_says(tmp_path, deadline_ms, delay_s, status):
+    """The wait for the engine's answer follows the request's deadline, not a
+    fixed 30 s; a wait that runs out is a failed dispatch (504, one retry
+    charged), never read as a stale keepalive: the request reaches the engine
+    once and is not reported as "agent unreachable"."""
+
+    async def body():
+        services, task, session = await start_stack(tmp_path)
+        seen: list = []
+        server, port = await _slow_engine(delay_s, seen)
+        try:
+            services.dataplane.route_set("agent-slow", f"http://127.0.0.1:{port}", "running", True)
+            headers = {"X-Agentainer-Deadline-Ms": str(deadline_ms)} if deadline_ms else {}
+            # twice: the second dispatch reuses the kept-alive upstream socket
+            for _ in range(2):
+                t0 = asyncio.get_event_loop().time()
+                resp = await session.post("/agent/agent-slow/chat", data=b'{"message": "write"}', headers=headers)
+                took = asyncio.get_event_loop().time() - t0
+                doc = await resp.json()
+                assert resp.status == status, doc
+                if status == 200:
+                    assert doc == {"response": "done"} and took >= delay_s
+                else:
+                    assert "retry recorded" in doc["message"] and 0.3 <= took < delay_s
+            assert len(seen) == 2  # no request was sent twice
+        finally:
+            server.close()
+            await teardown(services, task, session)
+
+    asyncio.run(body())

@@ -151,3 +151,124 @@ def test_config_from_hf(tmp_path):
     assert derived.n_layers == cfg.n_layers
     assert derived.n_kv_heads == cfg.n_kv_heads
     assert not derived.is_moe
+
+
+def _write_hf_olmoe(tmp_path, cfg, seed=0):
+    """A checkpoint directory under OLMoE's published names: ``num_experts``
+    and ``norm_topk_prob`` in config.json, ``mlp.gate``,
+    ``mlp.experts.{e}.{gate,up,down}_proj`` and ``self_attn.{q,k}_norm``."""
+    from safetensors.numpy import save_file
+
+    rng = np.random.default_rng(seed)
+    d, hd = cfg.dim, cfg.head_dim
+
+    def w(*shape, scale=0.02):
+        return rng.standard_normal(shape).astype(np.float32) * scale
+
+    tensors = {
+        "model.embed_tokens.weight": w(cfg.vocab_size, d),
+        "model.norm.weight": np.ones(d, np.float32),
+        "lm_head.weight": w(cfg.vocab_size, d, scale=0.2),
+    }
+    for i in range(cfg.n_layers):
+        L = f"model.layers.{i}."
+        tensors[L + "input_layernorm.weight"] = np.ones(d, np.float32)
+        tensors[L + "post_attention_layernorm.weight"] = np.ones(d, np.float32)
+        tensors[L + "self_attn.q_proj.weight"] = w(cfg.n_heads * hd, d, scale=0.16)
+        tensors[L + "self_attn.k_proj.weight"] = w(cfg.n_kv_heads * hd, d, scale=0.16)
+        tensors[L + "self_attn.v_proj.weight"] = w(cfg.n_kv_heads * hd, d)
+        tensors[L + "self_attn.o_proj.weight"] = w(d, cfg.n_heads * hd)
+        tensors[L + "self_attn.q_norm.weight"] = rng.uniform(0.25, 4.0, cfg.n_heads * hd).astype(np.float32)
+        tensors[L + "self_attn.k_norm.weight"] = rng.uniform(0.25, 4.0, cfg.n_kv_heads * hd).astype(np.float32)
+        tensors[L + "mlp.gate.weight"] = w(cfg.n_experts, d, scale=0.2)
+        for e in range(cfg.n_experts):
+            E = L + f"mlp.experts.{e}."
+            tensors[E + "gate_proj.weight"] = w(cfg.ffn_dim, d)
+            tensors[E + "up_proj.weight"] = w(cfg.ffn_dim, d)
+            tensors[E + "down_proj.weight"] = w(d, cfg.ffn_dim, scale=0.8)
+    save_file(tensors, str(tmp_path / "model.safetensors"))
+    (tmp_path / "config.json").write_text(json.dumps({
+        "model_type": "olmoe", "architectures": ["OlmoeForCausalLM"], "vocab_size": cfg.vocab_size,
+        "hidden_size": cfg.dim, "num_hidden_layers": cfg.n_layers, "num_attention_heads": cfg.n_heads,
+        "num_key_value_heads": cfg.n_kv_heads, "intermediate_size": cfg.ffn_dim, "rope_theta": cfg.rope_theta,
+        "rms_norm_eps": cfg.norm_eps, "max_position_embeddings": cfg.max_seq_len, "num_experts": cfg.n_experts,
+        "num_experts_per_tok": cfg.experts_per_token, "norm_topk_prob": False, "clip_qkv": None,
+        "tie_word_embeddings": False,
+    }))
+    return tensors
+
+
+def test_config_from_hf_reads_an_olmoe_checkpoint_as_a_mixture(tmp_path):
+    """``num_experts`` (not Mixtral's ``num_local_experts``) used to read as
+    0 experts: a 64-expert model loaded as dense."""
+    from agentainer_tpu.engine.hf_convert import config_from_hf
+
+    cfg = get_config("tiny-olmoe")
+    _write_hf_olmoe(tmp_path, cfg)
+    derived = config_from_hf(tmp_path)
+    assert (derived.n_experts, derived.experts_per_token) == (cfg.n_experts, cfg.experts_per_token)
+    assert derived.qk_norm and not derived.moe_renormalize and derived.is_moe
+    assert derived.param_count() == cfg.param_count()
+
+
+def test_qk_norm_follows_the_checkpoint_not_the_model_name(tmp_path):
+    """No ``config.json`` key states QK-norm: a checkpoint that calls itself
+    ``olmoe`` but holds no ``q_norm`` weights has none, and one under another
+    name that holds them has."""
+    from agentainer_tpu.engine.hf_convert import config_from_hf
+
+    _write_hf_llama(tmp_path, get_config("tiny-moe"))
+    doc = json.loads((tmp_path / "config.json").read_text())
+    (tmp_path / "config.json").write_text(json.dumps({**doc, "model_type": "olmoe"}))
+    assert not config_from_hf(tmp_path).qk_norm
+
+    other = tmp_path / "other"
+    other.mkdir()
+    _write_hf_olmoe(other, get_config("tiny-olmoe"))
+    doc = json.loads((other / "config.json").read_text())
+    (other / "config.json").write_text(json.dumps({**doc, "model_type": "olmo-next"}))
+    assert config_from_hf(other).qk_norm
+
+
+def test_mixtral_config_keeps_its_rule(tmp_path):
+    from agentainer_tpu.engine.hf_convert import config_from_hf
+
+    _write_hf_llama(tmp_path, get_config("tiny-moe"))
+    derived = config_from_hf(tmp_path)
+    assert derived.moe_renormalize and not derived.qk_norm
+
+
+def test_olmoe_checkpoint_loads_and_matches_the_plain_reference(tmp_path):
+    import importlib.util
+    import os
+
+    from agentainer_tpu.engine.hf_convert import config_from_hf, load_hf_params
+
+    cfg = get_config("tiny-olmoe")
+    tensors = _write_hf_olmoe(tmp_path, cfg)
+    derived = config_from_hf(tmp_path)
+    params = load_hf_params(derived, tmp_path, dtype=jnp.float32)
+    assert jax.tree.structure(params) == jax.tree.structure(
+        jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0), jnp.float32)))
+    np.testing.assert_array_equal(
+        np.asarray(params["layers"]["q_norm"][1]), tensors["model.layers.1.self_attn.q_norm.weight"])
+    np.testing.assert_array_equal(
+        np.asarray(params["layers"]["w_up"][0, 3]), tensors["model.layers.0.mlp.experts.3.up_proj.weight"].T)
+    np.testing.assert_array_equal(
+        np.asarray(params["layers"]["router"][1]), tensors["model.layers.1.mlp.gate.weight"].T)
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "olmoe_reference", os.path.join(here, "benchmark", "families", "olmoe_reference.py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    tokens = jnp.asarray(np.random.default_rng(1).integers(3, cfg.vocab_size, 16), jnp.int32)
+    layers = [{k: jnp.asarray(v[i]) for k, v in params["layers"].items()} for i in range(cfg.n_layers)]
+    want = ref.forward(
+        {"embed": jnp.asarray(params["embed"]), "layers": layers, "final_norm": jnp.asarray(params["final_norm"]),
+         "lm_head": jnp.asarray(params["lm_head"])},
+        tokens, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads, rope_theta=cfg.rope_theta,
+        norm_eps=cfg.norm_eps, top_k=cfg.experts_per_token)
+    got, _ = forward(jax.tree.map(jnp.asarray, params), derived, tokens[None], jnp.arange(16)[None], use_flash=False)
+    err = np.sqrt(np.mean((np.asarray(got[0]) - np.asarray(want)) ** 2, -1)) / np.std(np.asarray(want), -1)
+    assert float(np.median(err)) < 1e-4

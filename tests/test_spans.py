@@ -424,3 +424,49 @@ def test_step_module_names_the_benchmark_matches(step_engine, step, prefix):
     assert module.startswith(prefix), module
     others = set(STEP_MODULES.values()) - {prefix}
     assert not any(module.startswith(o) for o in others), module
+
+
+# -- the ``moe`` block of ``/metrics`` (ISSUE 26) ------------------------------
+
+
+def test_moe_block_names_no_path_for_a_dense_model(served):
+    _, before, m, _ = served
+    for doc in (before, m):
+        assert doc["moe"] == {"impl": "none", "experts": 0, "top_k": 0, "renormalize": False}
+        assert doc["model_arch"]["qk_norm"] is False
+
+
+@pytest.mark.parametrize("config, options, impl", [
+    ("tiny-olmoe", {}, "all_experts_einsum"),
+    ("tiny-moe", {}, "all_experts_einsum"),
+    ("tiny-olmoe", {"routed": True}, "routed_dispatch"),
+], ids=["olmoe", "mixtral", "olmoe_routed"])
+def test_moe_block_names_the_path_the_steps_trace(config, options, impl):
+    eng = LLMEngine.create(config, options={**TINY, "speculative": False, **options})
+    try:
+        cfg, m = eng.cfg, eng.metrics()
+    finally:
+        eng.shutdown()
+    assert m["moe"] == {
+        "impl": impl, "experts": cfg.n_experts, "top_k": cfg.experts_per_token, "renormalize": cfg.moe_renormalize,
+    }
+    assert m["model_arch"]["qk_norm"] is cfg.qk_norm and m["model_arch"]["head_dim"] == cfg.head_dim
+
+
+def test_an_olmoe_engine_decodes_what_the_plain_scan_gives():
+    """Prefill and decode through the engine's arena and ladders, with the
+    QK-norm and the un-renormalised gates, give the plain greedy scan's
+    tokens (same weights)."""
+    from agentainer_tpu.models.configs import get_config
+    from agentainer_tpu.models.llama import greedy_decode, init_params
+
+    cfg = get_config("tiny-olmoe")
+    eng = LLMEngine.create("tiny-olmoe", options={**TINY, "speculative": False})
+    try:
+        got = asyncio.run(eng.generate("hello there", max_tokens=12, ignore_eos=True))
+    finally:
+        eng.shutdown()
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    prompt = jnp.asarray([eng.tokenizer.encode("hello there")], jnp.int32)
+    want = greedy_decode(params, cfg, prompt, 12, 64, dtype=jnp.float32)[0]
+    assert got["tokens"] == [int(t) for t in want]

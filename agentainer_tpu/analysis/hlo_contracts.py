@@ -20,6 +20,7 @@ Usage::
 
 from __future__ import annotations
 
+import math
 import re
 from contextlib import contextmanager
 from dataclasses import dataclass, field
@@ -30,6 +31,7 @@ __all__ = [
     "NoLargeAllGather",
     "HasCrossReduction",
     "DonationAliased",
+    "ArenaRidesInCarry",
     "check",
     "compile_hlo",
     "op_result_elems",
@@ -165,6 +167,88 @@ class DonationAliased:
             out.append(
                 f"only {len(aliased)} parameters alias an output "
                 f"(need >= {self.min_count}) — a donated buffer is being copied"
+            )
+        return out
+
+
+_WHILE = re.compile(r"stablehlo\.while\(.*?\) : (.*)$", re.M)
+# a scatter carries a region, so its type signature closes the region lines
+# later; a dynamic_update_slice is one line. Either way: (operand, ..update..)
+_SCATTER = re.compile(
+    r'"stablehlo\.scatter"\(.*?\}\) : \((tensor<[^>]*>), tensor<[^>]*>, (tensor<[^>]*>)\) -> ',
+    re.S,
+)
+_DUS = re.compile(
+    r"stablehlo\.dynamic_update_slice .*? : \((tensor<[^>]*>), (tensor<[^>]*>),"
+)
+
+
+def _tensor_dims(t: str) -> tuple[int, ...]:
+    """``tensor<2x4x256x2x16xf32>`` → ``(2, 4, 256, 2, 16)``."""
+    return tuple(int(d) for d in t[len("tensor<") : -1].split("x")[:-1])
+
+
+@dataclass
+class ArenaRidesInCarry:
+    """The stacked KV arena stays one buffer through a step program: over
+    the LOWERED (StableHLO) text of ``jit_decode_n`` / ``jit_verify`` /
+    ``jit_prefill``.
+
+    - Each K and V stack is a carried value of ``loops`` while loops (the
+      layer scan; for ``jit_decode_n`` the step scan around it too), and a
+      loop that carries the arena carries exactly the two stacks: the
+      ``xs``/``ys`` form of a scan carries four (the stacks it slices a
+      layer out of and the stacks it rebuilds layer by layer).
+    - Every write into a stack (scatter or dynamic_update_slice whose
+      operand has the arena's shape) updates exactly the step's new rows
+      ``[B, T, KV, hd]`` — never a layer ``[B, S, KV, hd]``, which is what
+      a stacked scan output writes back in every layer-step.
+
+    Reads are not judged here: an implementation that cannot address the
+    stack by layer (the XLA reference on CPU) takes ``stack[layer]`` itself
+    and says so (``CacheAttention.arena``). That the donated arena aliases
+    the output through the loops is ``DonationAliased`` on the compiled
+    module.
+    """
+
+    arena: tuple[int, ...]  # [L, B, S, KV, hd]
+    rows: tuple[int, ...]  # [B, T, KV, hd]
+    loops: int = 1
+
+    def failures(self, text: str) -> list[str]:
+        out: list[str] = []
+        arena, n_rows = tuple(self.arena), math.prod(self.rows)
+        # arena-shaped values each while loop carries, for the loops that carry any
+        carrying = [
+            n
+            for m in _WHILE.finditer(text)
+            if (n := sum(_tensor_dims(t) == arena for t in re.findall(r"tensor<[^>]*>", m.group(1))))
+        ]
+        if len(carrying) < self.loops:
+            out.append(
+                f"the arena {list(self.arena)} is carried by {len(carrying)} "
+                f"while loops (need {self.loops}): it is sliced and restacked "
+                "around the layer loop, not carried through it"
+            )
+        if any(n != 2 for n in carrying):
+            out.append(
+                f"loops carry {carrying} arena-shaped values each (want 2: the "
+                "K and V stacks) — a stacked scan output beside its input?"
+            )
+        writes = [
+            (_tensor_dims(m.group(1)), _tensor_dims(m.group(2)))
+            for rx in (_SCATTER, _DUS)
+            for m in rx.finditer(text)
+        ]
+        updates = [upd for operand, upd in writes if operand == arena]
+        if len(updates) < 2:
+            out.append(f"found {len(updates)} writes into the arena (need K and V)")
+        bad = [u for u in updates if math.prod(u) != n_rows]
+        if bad:
+            out.append(
+                f"writes into the arena of shapes {bad}: every write must be the "
+                f"step's new rows {list(self.rows)} ({n_rows} elements), "
+                f"a layer is {math.prod(arena[1:])}"
             )
         return out
 

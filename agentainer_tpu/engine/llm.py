@@ -1400,7 +1400,7 @@ class LLMEngine:
         self.meshed_flash = "shard_map" in attn.decode
         print(
             f"[llm-engine] attention: prefill={attn.prefill} "
-            f"decode={attn.decode} ({attn.reason})",
+            f"decode={attn.decode} arena={attn.arena} ({attn.reason})",
             flush=True,
         )
         cache_attn_impl = attn.fn
@@ -1444,9 +1444,19 @@ class LLMEngine:
 
         pp_forward = self._pp_forward
 
-        def run_forward(params, toks, pos, cache, bt=None):
+        def run_forward(params, toks, pos, cache, bt=None, slot=None):
+            """``slot``: the batch's rows are arena rows ``slot..`` (a lane's
+            prefill); ``forward`` addresses them in place, the pipeline's
+            staged cache gets the rows sliced out and written back."""
             if pp_forward is not None:
-                logits, k, v = pp_forward(params, toks, pos, cache.k, cache.v)
+                k, v = cache.k, cache.v
+                if slot is not None:
+                    k = lax.dynamic_slice_in_dim(k, slot, toks.shape[0], axis=1)
+                    v = lax.dynamic_slice_in_dim(v, slot, toks.shape[0], axis=1)
+                logits, k, v = pp_forward(params, toks, pos, k, v)
+                if slot is not None:
+                    k = lax.dynamic_update_slice_in_dim(cache.k, k, slot, axis=1)
+                    v = lax.dynamic_update_slice_in_dim(cache.v, v, slot, axis=1)
                 return logits, KVCache(k, v)
             return forward(
                 params,
@@ -1457,6 +1467,7 @@ class LLMEngine:
                 cache_attn_impl=cache_attn_impl,
                 moe_impl=moe_impl,
                 block_table=bt,
+                slot=slot,
             )
 
         # the paged fns can't read the logical arena length off the cache
@@ -1464,14 +1475,11 @@ class LLMEngine:
         scratch_static = self.max_seq - 1
 
         def prefill(params, cache, slot, tokens, positions, n_real):
-            # slice the slot's cache row, run the prompt, write the row back
-            rowk = lax.dynamic_slice_in_dim(cache.k, slot, 1, axis=1)
-            rowv = lax.dynamic_slice_in_dim(cache.v, slot, 1, axis=1)
-            logits, row = run_forward(params, tokens, positions, KVCache(rowk, rowv))
-            newk = lax.dynamic_update_slice_in_dim(cache.k, row.k, slot, axis=1)
-            newv = lax.dynamic_update_slice_in_dim(cache.v, row.v, slot, axis=1)
+            # the prompt runs against the slot's row where it lies in the
+            # arena: no row sliced out, none written back
+            logits, cache = run_forward(params, tokens, positions, cache, slot=slot)
             last = lax.dynamic_slice_in_dim(logits, n_real - 1, 1, axis=1)[0, 0]
-            return last, KVCache(newk, newv)
+            return last, cache
 
         def prefill_paged(params, cache, bt, tokens, positions, n_real):
             # no row slice/write-back: the lane's single-row block table IS

@@ -1378,9 +1378,9 @@ class LLMServeApp:
         if not isinstance(body, dict):
             body = {}
         try:
-            # clamp below the control plane's 30 s dispatch timeout: a trace
-            # the proxy can't wait out would 502 the caller while the engine
-            # completed it anyway (ADVICE r3)
+            # a capture is capped: the trace's size, and the time stop_trace
+            # takes to collect it (which every hop in front waits out), grow
+            # with the window
             duration = min(float(body.get("duration_s", 2.0) or 2.0), 25.0)
         except (TypeError, ValueError):
             return web.json_response(

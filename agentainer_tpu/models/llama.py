@@ -260,7 +260,15 @@ def _attention_block(
     attn_impl=None,
     cache_attn_impl=None,
     block_table=None,
+    layer=None,
+    slot=None,
 ):
+    """One layer's attention. With a cache, ``ck``/``cv`` are the STACKED
+    arena ``[L, B, S, KV, hd]`` (or page pool) and ``layer`` this layer's
+    index in it: the layer's new rows are written into the stack and the
+    attention reads layer ``layer`` of it, so nothing the size of a layer
+    is sliced out, copied or written back. ``slot`` offsets the batch's
+    rows in the arena (one lane's prefill in the whole arena)."""
     b, t, d = x.shape
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     q, k = h @ lp["wq"], h @ lp["wk"]
@@ -277,15 +285,18 @@ def _attention_block(
     k = apply_rope(k, positions, cfg.rope_theta)
 
     if ck is not None:
+        if layer is None:
+            raise ValueError("a cache is the stacked arena: say which layer of it")
         if block_table is not None:
             # paged arena: write through the block table into pool pages —
             # same masking rule, same numbers as the dense scatter below
-            ck, cv = scatter_paged_kv(ck, cv, k, v, block_table, positions)
+            ck, cv = scatter_paged_kv(ck, cv, k, v, block_table, positions, layer)
         else:
-            # scatter this step's K/V into the arena at per-sequence positions
-            batch_idx = jnp.arange(b)[:, None]
-            ck = ck.at[batch_idx, positions].set(k)
-            cv = cv.at[batch_idx, positions].set(v)
+            # scatter this step's K/V rows, and only them, into the stack at
+            # per-sequence positions (rows past S — bucket padding — drop)
+            rows = jnp.arange(b)[:, None] + (0 if slot is None else slot)
+            ck = ck.at[layer, rows, positions].set(k)
+            cv = cv.at[layer, rows, positions].set(v)
         if cache_attn_impl is None:
             # engines choose once at build and pass their choice in (it is
             # what they report); direct callers get the same choice here
@@ -293,10 +304,10 @@ def _attention_block(
                 cfg.n_heads,
                 cfg.n_kv_heads,
                 cfg.head_dim,
-                page_size=ck.shape[2] if block_table is not None else 0,
+                page_size=ck.shape[3] if block_table is not None else 0,
                 use_pallas=use_flash,
             ).fn
-        attn = cache_attn_impl(q, ck, cv, positions, block_table)
+        attn = cache_attn_impl(q, ck, cv, positions, block_table, layer, slot)
     elif attn_impl is not None:
         # caller-supplied causal self-attention: the sequence-parallel
         # training path passes ring/Ulysses attention here (q/k/v are
@@ -321,11 +332,17 @@ def forward(
     cache_attn_impl=None,
     moe_impl=None,
     block_table: jnp.ndarray | None = None,
+    slot: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, KVCache | None]:
     """Returns (logits [B, T, V], updated cache).
 
     With a cache: serves prefill (T = prompt chunk) and decode (T = 1) with
     per-sequence positions — the continuous-batching engine relies on this.
+    The stacked arena rides in the layer scan's carry: a layer writes its
+    B × T new rows into it and the attention reads that layer of it where
+    it lies, so a donated arena stays one buffer through the whole step.
+    With ``slot`` the batch's rows are arena rows ``slot .. slot + B`` (a
+    lane's prefill against the whole arena, no row sliced out).
     With ``block_table`` the cache is a :class:`PagedKVCache` pool and
     every KV read/write goes through the table (paged serving); the cache
     returned is the updated pool.
@@ -340,41 +357,41 @@ def forward(
         t = tokens.shape[1]
         mask = jnp.broadcast_to(causal_mask(t), (tokens.shape[0], t, t))
 
-    lp_stack = params["layers"]
-
-    def layer_step(carry, inputs):
-        x = carry
-        if cache is not None:
-            lp, ck, cv = inputs
-        else:
-            lp = inputs
+    def block(x, ck, cv, lp, layer):
         # int8-quantized weights (engine/quant.py) dequantize per layer
         # slice here: HBM holds the int8 stack, only the current layer is
         # dense, and XLA fuses the convert into the consuming matmuls
         lp = {k: dequant(v) for k, v in lp.items()}
-        if cache is not None:
-            x, ck, cv = _attention_block(
-                x, lp, cfg, positions, mask, ck, cv, use_flash,
-                cache_attn_impl=cache_attn_impl,
-                block_table=block_table,
-            )
-        else:
-            x, _, _ = _attention_block(
-                x, lp, cfg, positions, mask, None, None, use_flash, attn_impl
-            )
-            ck = cv = jnp.zeros((0,), x.dtype)  # scan needs a leaf
+        x, ck, cv = _attention_block(
+            x, lp, cfg, positions, mask, ck, cv, use_flash,
+            attn_impl=attn_impl,
+            cache_attn_impl=cache_attn_impl,
+            block_table=block_table,
+            layer=layer,
+            slot=slot,
+        )
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
         if cfg.is_moe:
             x = x + (moe_impl(h, lp) if moe_impl is not None else _moe_mlp(h, lp, cfg))
         else:
             x = x + _mlp(h, lp)
-        return x, (ck, cv)
+        return x, ck, cv
 
+    lp_stack = params["layers"]
     if cache is not None:
-        x, (new_k, new_v) = lax.scan(layer_step, x, (lp_stack, cache.k, cache.v))
+        def layer_step(carry, inputs):
+            lp, layer = inputs
+            return block(*carry, lp, layer), None
+
+        layers = jnp.arange(cache.k.shape[0], dtype=jnp.int32)
+        (x, new_k, new_v), _ = lax.scan(
+            layer_step, (x, cache.k, cache.v), (lp_stack, layers)
+        )
         new_cache = type(cache)(new_k, new_v)
     else:
-        x, _ = lax.scan(layer_step, x, lp_stack)
+        x, _ = lax.scan(
+            lambda x, lp: (block(x, None, None, lp, None)[0], None), x, lp_stack
+        )
         new_cache = None
 
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)

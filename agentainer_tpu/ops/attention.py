@@ -21,6 +21,7 @@ from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 NEG_INF = -1e30
 
@@ -81,7 +82,7 @@ def pallas_available(n_heads: int, n_kv_heads: int, head_dim: int) -> tuple[bool
     if not kernel_supported(n_heads, n_kv_heads, head_dim):
         return False, (
             f"heads {n_heads}/{n_kv_heads} x {head_dim}: the kernels need "
-            "head_dim % 128 == 0 and whole GQA groups"
+            "head_dim % 128 == 0, whole GQA groups and 1, 2, 4 or 8k KV heads"
         )
     return True, "tpu backend, lane-aligned heads"
 
@@ -155,20 +156,22 @@ def gather_pages(
 
 
 def scatter_paged_kv(
-    pool_k: jnp.ndarray,  # [P, KV, page_size, hd]
+    pool_k: jnp.ndarray,  # [P, KV, page_size, hd], or [L, P, ...] with ``layer``
     pool_v: jnp.ndarray,
     k_new: jnp.ndarray,  # [B, T, KV, hd]
     v_new: jnp.ndarray,
     block_table: jnp.ndarray,  # [B, n_blocks]
     positions: jnp.ndarray,  # [B, T] int32
+    layer=None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Write this step's K/V through the block table into pool pages.
+    """Write this step's K/V through the block table into pool pages — of
+    layer ``layer`` of the stacked pool when one is given.
 
     Positions past the logical arena (bucket padding that the dense path's
     out-of-range scatter silently DROPS) clamp to the last logical slot —
     the per-lane scratch row — so they land somewhere no live query ever
     attends instead of wrapping into a live page."""
-    ps = pool_k.shape[2]
+    ps = pool_k.shape[-2]
     s = block_table.shape[1] * ps
     cpos = jnp.minimum(positions, s - 1)
     b_idx = jnp.arange(positions.shape[0])[:, None]
@@ -176,56 +179,92 @@ def scatter_paged_kv(
     offs = cpos % ps
     # advanced indices split by the head slice: the indexed dims lead, so
     # the update shape is [B, T, KV, hd] — exactly k_new's
-    return (
-        pool_k.at[pages, :, offs].set(k_new),
-        pool_v.at[pages, :, offs].set(v_new),
-    )
+    idx = (pages, slice(None), offs) if layer is None else (layer, pages, slice(None), offs)
+    return pool_k.at[idx].set(k_new), pool_v.at[idx].set(v_new)
 
 
 class CacheAttention(NamedTuple):
     """Arena attention as an engine's compiled steps trace it, chosen once
-    at build. ``fn(q, ck, cv, positions, block_table=None)`` is the only
-    attention those steps call — there is no second dispatch behind it —
-    so ``prefill`` (what a T > 1 call runs) and ``decode`` (T == 1) name
-    what is in the compiled programs, and ``reason`` says why."""
+    at build. ``fn(q, ck, cv, positions, block_table, layer, slot)`` is the
+    only attention those steps call — there is no second dispatch behind
+    it — so ``prefill`` (what a T > 1 call runs) and ``decode`` (T == 1)
+    name what is in the compiled programs, and ``reason`` says why.
+
+    ``ck``/``cv`` are the STACKED arena ``[L, B, S, KV, hd]`` (or page pool
+    ``[L, P, KV, page_size, hd]``) as the layer scan carries it, ``layer``
+    the index of the layer to read and ``slot`` (or None) the arena row the
+    batch starts at. ``arena`` says what the implementation does with
+    them: ``"stack+layer"`` reads layer ``layer`` where it lies (the
+    kernels address it in their index maps), ``"layer_slice"`` takes
+    ``stack[layer]`` out first — a copy of the layer in every layer-step."""
 
     fn: Callable
     prefill: str
     decode: str
     reason: str
+    arena: str = "layer_slice"
 
     def describe(self) -> dict:
-        return {"prefill": self.prefill, "decode": self.decode, "reason": self.reason}
+        return {
+            "prefill": self.prefill,
+            "decode": self.decode,
+            "reason": self.reason,
+            "arena": self.arena,
+        }
 
 
-def _reference_dense(q, ck, cv, positions, block_table=None):
+def layer_slice(ck, cv, layer, slot=None, rows: int = 0):
+    """``stack[layer]`` of both stacks (and of that, the ``rows`` arena rows
+    from ``slot`` on): what an implementation that cannot address the stack
+    by layer reads instead."""
+    ck = lax.dynamic_index_in_dim(ck, layer, 0, keepdims=False)
+    cv = lax.dynamic_index_in_dim(cv, layer, 0, keepdims=False)
+    if slot is not None:
+        ck = lax.dynamic_slice_in_dim(ck, slot, rows, 0)
+        cv = lax.dynamic_slice_in_dim(cv, slot, rows, 0)
+    return ck, cv
+
+
+def _reference_dense(q, ck, cv, positions, block_table, layer, slot):
+    ck, cv = layer_slice(ck, cv, layer, slot, q.shape[0])
     return attention_reference(q, ck, cv, mask=cache_mask(positions, ck.shape[1]))
 
 
-def _reference_paged(q, pool_k, pool_v, positions, block_table):
-    ck, cv = gather_pages(pool_k, pool_v, block_table)
-    return _reference_dense(q, ck, cv, positions)
+def _reference_paged(q, pool_k, pool_v, positions, block_table, layer, slot):
+    ck, cv = gather_pages(*layer_slice(pool_k, pool_v, layer), block_table)
+    return attention_reference(q, ck, cv, mask=cache_mask(positions, ck.shape[1]))
 
 
-def pallas_dense(q, ck, cv, positions, block_table=None, interpret: bool = False):
+def pallas_dense(q, ck, cv, positions, block_table, layer, slot, interpret: bool = False):
     """The dense flash kernels by call shape: one token per sequence runs
-    ``flash_decode``, anything longer ``flash_prefill``."""
+    ``flash_decode``, anything longer ``flash_prefill``. Both read layer
+    ``layer`` (rows from ``slot``) of the stacks in the stored layout."""
     from .pallas_attention import flash_decode, flash_prefill
 
+    slot = 0 if slot is None else slot
     if q.shape[1] == 1:
-        out = flash_decode(q[:, 0], ck, cv, positions[:, 0], interpret=interpret)
+        out = flash_decode(
+            q[:, 0], ck, cv, positions[:, 0], layer, slot, interpret=interpret
+        )
         return out[:, None]
-    return flash_prefill(q, ck, cv, positions, interpret=interpret)
+    return flash_prefill(q, ck, cv, positions, layer, slot, interpret=interpret)
 
 
-def _pallas_paged_gather(q, pool_k, pool_v, positions, block_table):
-    ck, cv = gather_pages(pool_k, pool_v, block_table)
-    return pallas_dense(q, ck, cv, positions)
+def pallas_dense_layer(q, ck, cv, positions, interpret: bool = False):
+    """The same kernels over ONE layer's own ``[B, S, KV, hd]`` arrays (a
+    gathered pool, a shard under ``shard_map``): a one-layer stack."""
+    return pallas_dense(q, ck[None], cv[None], positions, None, 0, None, interpret)
 
 
-def _pallas_paged_fused(q, pool_k, pool_v, positions, block_table):
+def _pallas_paged_gather(q, pool_k, pool_v, positions, block_table, layer, slot):
+    ck, cv = gather_pages(*layer_slice(pool_k, pool_v, layer), block_table)
+    return pallas_dense_layer(q, ck, cv, positions)
+
+
+def _pallas_paged_fused(q, pool_k, pool_v, positions, block_table, layer, slot):
     from .pallas_attention import fused_paged_flash_decode, fused_paged_flash_prefill
 
+    pool_k, pool_v = layer_slice(pool_k, pool_v, layer)
     if q.shape[1] == 1:
         out = fused_paged_flash_decode(
             q[:, 0], pool_k, pool_v, block_table, positions[:, 0]
@@ -245,12 +284,15 @@ def plan_cache_attention(
     """Choose the arena attention for these head shapes in this process:
     row t sees slot j iff ``j <= positions[b, t]`` (ragged cached prefill
     and T == 1 decode alike). ``page_size > 0`` means the cache is a page
-    pool ``[P, KV, page_size, hd]`` read through a block table.
+    pool ``[L, P, KV, page_size, hd]`` read through a block table.
 
     On a TPU backend the Pallas flash kernels build the mask in-register —
-    dense, or fused with the block-table walk for a pool (the gather +
-    dense-kernel path stays as the reference the fused kernels are A/B'd
-    against; ``AGENTAINER_PAGED_GATHER=1`` forces it). Elsewhere, and for
+    dense (reading layer ``layer`` of the stacked arena where it lies:
+    ``arena: stack+layer``), or fused with the block-table walk for a pool
+    (the gather + dense-kernel path stays as the reference the fused
+    kernels are A/B'd against; ``AGENTAINER_PAGED_GATHER=1`` forces it;
+    both take the layer's pool out of the stack first:
+    ``arena: layer_slice``). Elsewhere, and for
     shapes the kernels cannot take, the XLA reference materializes
     ``cache_mask``. Callers running under GSPMD sharding pass
     ``use_pallas=False`` — XLA cannot auto-partition a pallas_call, while
@@ -268,7 +310,7 @@ def plan_cache_attention(
         return CacheAttention(_reference_dense, name, name, why)
     if not paged:
         return CacheAttention(
-            pallas_dense, "pallas:flash_prefill", "pallas:flash_decode", why
+            pallas_dense, "pallas:flash_prefill", "pallas:flash_decode", why, "stack+layer"
         )
     if os.environ.get("AGENTAINER_PAGED_GATHER"):
         gather_why = "AGENTAINER_PAGED_GATHER is set"

@@ -25,7 +25,12 @@ import functools as _functools
 import jax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..ops.attention import CacheAttention, pallas_dense, plan_cache_attention
+from ..ops.attention import (
+    CacheAttention,
+    layer_slice,
+    pallas_dense_layer,
+    plan_cache_attention,
+)
 from ..ops.pallas_attention import flash_prefill
 
 # the kernels' per-device bodies are value-replicated by construction but
@@ -37,20 +42,22 @@ def make_meshed_cache_attention(mesh: Mesh, interpret: bool = False):
     """Arena attention (the serving hot path): q ``[B, T, H, hd]`` against
     cache rows ``[B, S, KV, hd]`` with per-sequence positions ``[B, T]``.
     Heads shard over tp (KV heads likewise — GQA group ratio is preserved
-    per device), batch over dp; S must be unsharded (sp == 1)."""
+    per device), batch over dp; S must be unsharded (sp == 1). The layer
+    is sliced out of the stack before the map (``arena: layer_slice``):
+    the per-device kernels see one layer's shard."""
     qspec = P("dp", None, "tp", None)
     cspec = P("dp", None, "tp", None)
     pspec = P("dp", None)
 
     mapped = shard_map(
-        _functools.partial(pallas_dense, interpret=interpret),
+        _functools.partial(pallas_dense_layer, interpret=interpret),
         mesh=mesh,
         in_specs=(qspec, cspec, cspec, pspec),
         out_specs=qspec,
     )
 
-    def attn(q, ck, cv, positions, block_table=None):
-        return mapped(q, ck, cv, positions)
+    def attn(q, ck, cv, positions, block_table, layer, slot):
+        return mapped(q, *layer_slice(ck, cv, layer, slot, q.shape[0]), positions)
 
     return attn
 
@@ -66,7 +73,7 @@ def make_meshed_causal_attention(mesh: Mesh, interpret: bool = False):
     def local(q, k, v):
         b, t = q.shape[0], q.shape[1]
         positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
-        return flash_prefill(q, k, v, positions, interpret=interpret)
+        return flash_prefill(q, k[None], v[None], positions, 0, interpret=interpret)
 
     return shard_map(
         local,

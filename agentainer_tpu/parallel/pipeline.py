@@ -229,15 +229,19 @@ def _apply_stage_cached(x, lp_stack, cfg: ModelConfig, positions, ck, cv):
     """This stage's local layers against its local arena rows (same scan
     body as models/llama.forward, over L/pp layers)."""
 
-    def step(x, inputs):
-        lp, ckl, cvl = inputs
+    def step(carry, inputs):
+        x, ck, cv = carry
+        lp, layer = inputs
         lp = {k: dequant(v) for k, v in lp.items()}
-        x, ckl, cvl = _attention_block(x, lp, cfg, positions, None, ckl, cvl, False)
+        x, ck, cv = _attention_block(
+            x, lp, cfg, positions, None, ck, cv, False, layer=layer
+        )
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
         x = x + (_moe_mlp(h, lp, cfg) if cfg.is_moe else _mlp(h, lp))
-        return x, (ckl, cvl)
+        return (x, ck, cv), None
 
-    x, (ck, cv) = lax.scan(step, x, (lp_stack, ck, cv))
+    layers = jnp.arange(ck.shape[0], dtype=jnp.int32)
+    (x, ck, cv), _ = lax.scan(step, (x, ck, cv), (lp_stack, layers))
     return x, ck, cv
 
 

@@ -112,7 +112,12 @@ def kernel_check(rehearse: bool, seed: int) -> int:
     dpos = jnp.asarray(([s - 1, 0, ps - 1, ps, s // 2, 17, s - 2, 1000 % s] * b)[:b], jnp.int32)
     start = s // 3
     ppos = jnp.arange(start, start + t, dtype=jnp.int32)[None]
-    arena_k, arena_v = rand(b, s, kv, hd), rand(b, s, kv, hd)
+    # the dense kernels read layer 1 of a two-layer stack where it lies, the
+    # prefill row ``slot`` of it: what the engine's layer scan hands them
+    stack_k, stack_v = rand(2, b, s, kv, hd), rand(2, b, s, kv, hd)
+    arena_k, arena_v = stack_k[1], stack_v[1]
+    slot = b - 1
+    row_k, row_v = arena_k[slot : slot + 1], arena_v[slot : slot + 1]
     pool_k, pool_v = rand(b * nb + b, kv, ps, hd), rand(b * nb + b, kv, ps, hd)
     table = jax.random.permutation(next(keys), b * nb + b)[: b * nb].reshape(b, nb).astype(jnp.int32)
     paged_k, paged_v = gather_pages(pool_k, pool_v, table)
@@ -125,12 +130,12 @@ def kernel_check(rehearse: bool, seed: int) -> int:
 
     cases = {
         "flash_decode": (
-            lambda: flash_decode(qd, arena_k, arena_v, dpos, **kw)[:, None],
+            lambda: flash_decode(qd, stack_k, stack_v, dpos, 1, **kw)[:, None],
             lambda: reference(qd[:, None], arena_k, arena_v, dpos[:, None]),
         ),
         "flash_prefill": (
-            lambda: flash_prefill(qp, arena_k[:1], arena_v[:1], ppos, **kw),
-            lambda: reference(qp, arena_k[:1], arena_v[:1], ppos),
+            lambda: flash_prefill(qp, stack_k, stack_v, ppos, 1, slot, **kw),
+            lambda: reference(qp, row_k, row_v, ppos),
         ),
         "fused_paged_flash_decode": (
             lambda: fused_paged_flash_decode(qd, pool_k, pool_v, table, dpos, **kw)[:, None],

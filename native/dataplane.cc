@@ -382,13 +382,19 @@ static std::string store_get(Store* s, const std::string& key, bool* found) {
 
 static constexpr double REQUEST_TTL_S = 24 * 3600;  // requests.go:106
 // How long a forward waits for the upstream's answer. The management backend
-// answers at once or is broken. An agent dispatch waits as long as its caller
-// does: the request's X-Agentainer-Deadline-Ms (plus a grace in which the
-// engine's own "expired" answer arrives first), and without one as long as a
-// whole buffered generation may take (2048 tokens at 50 ms a step are 100 s;
-// a fixed 30 s failed healthy generations as "unreachable"). An engine that
-// dies closes its socket and is seen at once; this bound only catches one
-// that hangs with its socket open.
+// answers at once or is broken (UPSTREAM_WAIT_S), but for the one call that
+// waits for an engine behind it: POST /agents/{id}/profile answers after the
+// capture AND the collection of its trace, which grows with the device events
+// in the window (5 s of a 17 ms decode step over 16 layers took over 25 s to
+// collect, and a fixed 30 s answered 502 for a capture that then completed).
+// The backend bounds that call itself (duration_s <= 60), so its answer is
+// waited for like a dispatch without a deadline. An agent dispatch waits as
+// long as its caller does: the request's X-Agentainer-Deadline-Ms (plus a
+// grace in which the engine's own "expired" answer arrives first), and without
+// one as long as a whole buffered generation may take (2048 tokens at 50 ms a
+// step are 100 s; a fixed 30 s failed healthy generations as "unreachable").
+// An engine that dies closes its socket and is seen at once; this bound only
+// catches one that hangs with its socket open.
 static constexpr double UPSTREAM_WAIT_S = 30;
 static constexpr double DISPATCH_WAIT_S = 600;
 static constexpr double DEADLINE_GRACE_S = 1;
@@ -956,9 +962,15 @@ void DataPlane::handle_conn(int fd) {
       break;  // stream consumed the connection
     }
 
+    // the one management call that waits for an engine (see DISPATCH_WAIT_S)
+    std::string path = req.target.substr(0, req.target.find('?'));
+    bool profile = req.method == "POST" && path.rfind("/agents/", 0) == 0 &&
+                   path.size() > 8 &&
+                   path.compare(path.size() - 8, 8, "/profile") == 0;
     HttpMsg up;
     int rc = ctx.roundtrip(backend_host_, backend_port_, fwd, &up,
-                           req.method == "HEAD");
+                           req.method == "HEAD",
+                           profile ? DISPATCH_WAIT_S : UPSTREAM_WAIT_S);
     if (rc != 0) {
       resp_raw = build_response(
           502, {}, envelope(false, "management backend unavailable", ""), keep);

@@ -400,3 +400,43 @@ def test_dispatch_waits_as_long_as_the_request_says(tmp_path, deadline_ms, delay
             await teardown(services, task, session)
 
     asyncio.run(body())
+
+
+def test_management_forward_waits_long_only_for_a_profile(tmp_path):
+    """The management backend answers at once or is broken, so its forward
+    waits 30 s — but for ``POST /agents/{id}/profile``, which answers after
+    the capture and the collection of its trace: that one is waited for like
+    a dispatch. A backend that takes 32 s: the profile call gets its answer,
+    any other call its 502 at 30 s (both at once, each on a connection of
+    its own, so the test takes the longer of the two)."""
+    from agentainer_tpu.runtime.dataplane import NativeDataPlane
+    from agentainer_tpu.store.native import NativeStore
+
+    async def body():
+        seen: list = []
+        server, port = await _slow_engine(32.0, seen)
+        store = NativeStore(str(tmp_path / "store.aof"))
+        dp = NativeDataPlane(store, "127.0.0.1", 0, "127.0.0.1", port)
+        loop = asyncio.get_event_loop()
+
+        async def call(path):
+            t0 = loop.time()
+            async with aiohttp.ClientSession(base_url=f"http://127.0.0.1:{dp.port}") as session:
+                resp = await session.post(path, data=b"{}", headers=AUTH)
+                return resp.status, await resp.json(), loop.time() - t0
+
+        try:
+            profile, other = await asyncio.gather(
+                call("/agents/agent-x/profile?why=trace"), call("/agents/agent-x/start")
+            )
+            assert profile[0] == 200 and profile[1] == {"response": "done"}, profile
+            assert profile[2] >= 32.0
+            assert other[0] == 502 and "management backend unavailable" in other[1]["message"], other
+            assert 29.0 <= other[2] < 32.0
+            assert len(seen) == 2  # neither was sent twice
+        finally:
+            server.close()
+            await loop.run_in_executor(None, dp.stop)
+            store.close()
+
+    asyncio.run(body())

@@ -34,8 +34,10 @@ def test_meshed_cache_attention_matches_reference_prefill_and_decode():
     b, s, h, kv, hd = 2, 64, 4, 2, 16
     mesh = make_mesh(2, tp=2)
     keys = jax.random.split(jax.random.PRNGKey(0), 3)
-    ck = _rand(keys[0], b, s, kv, hd)
-    cv = _rand(keys[1], b, s, kv, hd)
+    # the engine hands the stacked arena and the layer to read: layer 1 of 2
+    stack_k = _rand(keys[0], 2, b, s, kv, hd)
+    stack_v = _rand(keys[1], 2, b, s, kv, hd)
+    ck, cv = stack_k[1], stack_v[1]
 
     impl = make_meshed_cache_attention(mesh, interpret=True)
 
@@ -46,7 +48,7 @@ def test_meshed_cache_attention_matches_reference_prefill_and_decode():
         [jnp.arange(3, 3 + t, dtype=jnp.int32), jnp.arange(20, 20 + t, dtype=jnp.int32)]
     )
     with mesh:
-        got = impl(q, ck, cv, pos)
+        got = impl(q, stack_k, stack_v, pos, None, 1, None)
     want = attention_reference(q, ck, cv, mask=cache_mask(pos, s))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 
@@ -54,7 +56,7 @@ def test_meshed_cache_attention_matches_reference_prefill_and_decode():
     q1 = q[:, :1]
     pos1 = pos[:, :1]
     with mesh:
-        got1 = impl(q1, ck, cv, pos1)
+        got1 = impl(q1, stack_k, stack_v, pos1, None, 1, None)
     want1 = attention_reference(q1, ck, cv, mask=cache_mask(pos1, s))
     np.testing.assert_allclose(np.asarray(got1), np.asarray(want1), atol=2e-5)
 

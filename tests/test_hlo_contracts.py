@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import pytest
 
 from agentainer_tpu.analysis.hlo_contracts import (
+    ArenaRidesInCarry,
     ContractViolation,
     DonationAliased,
     HasCrossReduction,
@@ -181,6 +182,101 @@ def test_fused_loop_donation_survives_while_carry(engine):
         .as_text()
     )
     check(hlo, DonationAliased(min_count=2))
+
+
+# ---------------------------------------------------------------------------
+# the arena stays where it lies: carried through the loops, only the new
+# rows written, the donated buffer aliased (ISSUE 27)
+
+
+@pytest.fixture(scope="module")
+def dense_engine():
+    eng = LLMEngine.create(
+        "tiny",
+        options={
+            "max_batch": 4, "max_seq": 256, "decode_chunk": 8,
+            "prefill_chunk": 32, "skip_warmup": True,
+        },
+    )
+    yield eng
+    eng.shutdown()
+
+
+def _step_lowering(eng, step: str):
+    """(lowered program, new-row count T, loops that carry the arena)."""
+    B = eng.max_batch
+    z = lambda dt: jnp.zeros((B,), dt)  # noqa: E731
+    lanes = (z(jnp.int32), z(jnp.int32), z(jnp.float32), z(jnp.int32), z(jnp.float32))
+    if step == "jit_decode_n":
+        keys = jax.random.split(jax.random.PRNGKey(0), 8)
+        return eng._decode_n.lower(eng.params, eng.cache, *lanes, keys), (B, 1), 2
+    if step == "jit_verify":
+        K = 4
+        drafts = jnp.zeros((B, K), jnp.int32)
+        lowered = eng._verify_fn(K).lower(
+            eng.params, eng.cache, *lanes, drafts, z(jnp.int32), jax.random.PRNGKey(0)
+        )
+        return lowered, (B, K + 1), 1
+    t = 32
+    tokens = jnp.zeros((1, t), jnp.int32)
+    lowered = eng._prefill.lower(
+        eng.params, eng.cache, jnp.int32(1), tokens, tokens, jnp.int32(4)
+    )
+    return lowered, (1, t), 1
+
+
+@pytest.mark.parametrize("step", ["jit_decode_n", "jit_verify", "jit_prefill"])
+def test_arena_rides_in_the_layer_loop_carry(dense_engine, step):
+    """The stacked arena is a carried value of the layer loop (and of the
+    step scan around it), never a stacked scan output; a step writes B × T
+    new rows into it and nothing the size of a layer; and the donated arena
+    aliases the output through both loops, so no second arena exists."""
+    lowered, (b, t), loops = _step_lowering(dense_engine, step)
+    assert f"module @{step}" in lowered.as_text()  # the names the readers find
+    arena = tuple(dense_engine.cache.k.shape)
+    check(
+        lowered.as_text(),
+        ArenaRidesInCarry(arena=arena, rows=(b, t) + arena[3:], loops=loops),
+    )
+    check(lowered.compile().as_text(), DonationAliased(min_count=2))
+
+
+def test_arena_contract_catches_the_xs_ys_scan():
+    """The form ISSUE 27 deleted: the stacks fed to ``lax.scan`` as ``xs``
+    and taken back as ``ys``. It computes the same cache, donation still
+    aliases — and every layer-step slices a whole layer out and writes a
+    whole layer back. The contract has to refuse it."""
+    L, B, S, KV, HD = 2, 4, 64, 2, 16
+
+    def old_form(ck, cv, rows, positions):
+        def layer(x, kv):
+            k, v = kv
+            b = jnp.arange(B)[:, None]
+            return x, (k.at[b, positions].set(rows), v.at[b, positions].set(rows))
+
+        _, (ck, cv) = jax.lax.scan(layer, 0.0, (ck, cv))
+        return ck, cv
+
+    def new_form(ck, cv, rows, positions):
+        def layer(carry, l):
+            k, v = carry
+            b = jnp.arange(B)[:, None]
+            return (k.at[l, b, positions].set(rows), v.at[l, b, positions].set(rows)), None
+
+        (ck, cv), _ = jax.lax.scan(layer, (ck, cv), jnp.arange(L))
+        return ck, cv
+
+    stack = jnp.zeros((L, B, S, KV, HD), jnp.float32)
+    args = (stack, stack, jnp.ones((B, 1, KV, HD)), jnp.zeros((B, 1), jnp.int32))
+    contract = ArenaRidesInCarry(arena=stack.shape, rows=(B, 1, KV, HD))
+    old = jax.jit(old_form, donate_argnums=(0, 1)).lower(*args)
+    check(old.compile().as_text(), DonationAliased(min_count=2))  # donation is blind to it
+    problems = contract.failures(old.as_text())
+    assert any("a layer is" in p for p in problems), problems
+    assert any("stacked scan output" in p for p in problems), problems
+    with pytest.raises(ContractViolation, match="new rows"):
+        check(old.as_text(), contract)
+    check(jax.jit(new_form, donate_argnums=(0, 1)).lower(*args).as_text(), contract)
 
 
 # ---------------------------------------------------------------------------

@@ -15,8 +15,10 @@ from agentainer_tpu.ops.attention import (
     cache_mask,
     causal_mask,
     gather_pages,
+    plan_cache_attention,
 )
 from agentainer_tpu.ops.pallas_attention import (
+    _kv_block,
     flash_decode,
     flash_prefill,
     fused_paged_flash_decode,
@@ -37,7 +39,7 @@ def test_prefill_causal_matches_reference(heads, kv_heads):
     v = _rand(k3, b, t, kv_heads, hd)
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
 
-    got = flash_prefill(q, k, v, positions, interpret=True)
+    got = flash_prefill(q, k[None], v[None], positions, 0, interpret=True)
     mask = jnp.broadcast_to(causal_mask(t), (b, t, t))
     want = attention_reference(q, k, v, mask=mask)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
@@ -54,7 +56,7 @@ def test_prefill_cached_ragged_positions():
     offsets = jnp.array([0, 77, 300], jnp.int32)
     positions = offsets[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
 
-    got = flash_prefill(q, ck, cv, positions, interpret=True)
+    got = flash_prefill(q, ck[None], cv[None], positions, 0, interpret=True)
     want = attention_reference(q, ck, cv, mask=cache_mask(positions, s))
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
@@ -67,7 +69,7 @@ def test_prefill_multiple_q_blocks():
     v = _rand(keys[2], b, s, kv_heads, hd)
     positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
 
-    got = flash_prefill(q, k, v, positions, block_q=128, block_k=128, interpret=True)
+    got = flash_prefill(q, k[None], v[None], positions, 0, block_q=128, block_k=128, interpret=True)
     want = attention_reference(q, k, v, mask=cache_mask(positions, s))
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
@@ -81,11 +83,136 @@ def test_decode_matches_reference(block_k):
     cv = _rand(keys[2], b, s, kv_heads, hd)
     positions = jnp.array([0, 5, 200, 383], jnp.int32)
 
-    got = flash_decode(q, ck, cv, positions, block_k=block_k, interpret=True)
+    got = flash_decode(q, ck[None], cv[None], positions, 0, block_k=block_k, interpret=True)
     want = attention_reference(
         q[:, None], ck, cv, mask=cache_mask(positions[:, None], s)
     )[:, 0]
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+# ---------------------------------------------------------------------------
+# the layer-indexed kernels: the engine hands them the STACKED arena
+# [L, B, S, KV, hd] as the layer scan carries it, with the layer (and the
+# prefilling lane's slot) as scalars. They must read exactly what the
+# per-layer call reads of that layer's own arrays — and that must be right.
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2], ids=["first", "middle", "last"])
+@pytest.mark.parametrize(
+    "kv_heads,groups,sized",
+    [
+        (8, 4, False),
+        (16, 1, False),
+        # two blocks of 16 heads: the head-block grid axis
+        (32, 1, False),
+        # no 16-head divisor: every head in a block, and the DEFAULT block
+        # sizes, which are then cut to the VMEM plan (shorter K/V and q tiles)
+        (40, 1, True),
+    ],
+    ids=["gqa8x4", "mha16", "mha32", "mha40-sized"],
+)
+@pytest.mark.parametrize("phase", ["decode", "prefill"])
+def test_kernels_read_layer_of_the_stack(phase, kv_heads, groups, sized, layer):
+    n_layers, lanes, s, hd = 3, 3, 384, 128
+    heads = kv_heads * groups
+    keys = jax.random.split(jax.random.PRNGKey(5), 4)
+    # every layer (and lane) holds different values: reading the wrong one
+    # cannot pass; bf16 is what the engine stores (two heads a 32-bit word)
+    stack_k = _rand(keys[0], n_layers, lanes, s, kv_heads, hd).astype(jnp.bfloat16)
+    stack_v = _rand(keys[1], n_layers, lanes, s, kv_heads, hd).astype(jnp.bfloat16)
+    lay = jnp.int32(layer)
+    if phase == "decode":
+        q = _rand(keys[2], lanes, heads, hd).astype(jnp.bfloat16)
+        # ragged: a fresh lane, one mid-arena, one parked at the scratch row
+        positions = jnp.array([5, 200, s - 1], jnp.int32)
+        kw = dict(interpret=True) if sized else dict(block_k=128, interpret=True)
+        got = flash_decode(q, stack_k, stack_v, positions, lay, **kw)
+        per_layer = flash_decode(q, stack_k[layer][None], stack_v[layer][None], positions, 0, **kw)
+        want = attention_reference(
+            q[:, None], stack_k[layer], stack_v[layer],
+            mask=cache_mask(positions[:, None], s),
+        )[:, 0]
+    else:
+        # one lane's prompt chunk against ITS row of the whole arena (slot 2
+        # of 3), three q blocks (the last one partial), starting mid-arena
+        t, slot = 160, 2
+        q = _rand(keys[2], 1, t, heads, hd).astype(jnp.bfloat16)
+        positions = (100 + jnp.arange(t, dtype=jnp.int32))[None]
+        kw = dict(interpret=True) if sized else dict(block_q=64, block_k=128, interpret=True)
+        got = flash_prefill(q, stack_k, stack_v, positions, lay, jnp.int32(slot), **kw)
+        row_k, row_v = stack_k[layer, slot : slot + 1], stack_v[layer, slot : slot + 1]
+        per_layer = flash_prefill(q, row_k[None], row_v[None], positions, 0, **kw)
+        want = attention_reference(q, row_k, row_v, mask=cache_mask(positions, s))
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(per_layer))
+    np.testing.assert_allclose(
+        got.astype(np.float32), want.astype(np.float32), rtol=3e-2, atol=3e-2
+    )
+
+
+@pytest.mark.parametrize(
+    "kv_heads,block,decode_positions",
+    [
+        (8, 8, 512),  # Mixtral, Llama-3: every head, the measured blocks
+        (16, 16, 512),  # OLMoE
+        (32, 16, 512),  # two head blocks
+        (40, 40, 128),  # no 16-head divisor: whole, and a shorter block
+        (24, 24, 256),
+        (2, 2, 512),  # a tp shard: padded to a 16-row tile, still in budget
+    ],
+)
+def test_kv_block_is_sized_to_the_vmem_plan(kv_heads, block, decode_positions):
+    """K and V blocks, two buffers each, stay inside the call's VMEM plan
+    whatever the head count (a block with every head of a 32- or 40-head
+    MHA model at 512 positions does not fit the chip's 16 MiB)."""
+    from agentainer_tpu.ops.pallas_attention import _DECODE_KV_VMEM
+
+    heads, bk = _kv_block(kv_heads, 128, jnp.bfloat16, 2048, 512, _DECODE_KV_VMEM)
+    assert (heads, bk) == (block, decode_positions)
+    assert kv_heads % heads == 0 and bk % 128 == 0
+    padded = -(-heads // 16) * 16  # bf16 sublane tile
+    assert bk == 128 or 4 * bk * padded * 128 * 2 <= _DECODE_KV_VMEM
+    # a short arena is one block, whatever the plan allows
+    assert _kv_block(kv_heads, 128, jnp.bfloat16, 100, 512, _DECODE_KV_VMEM)[1] == 128
+
+
+@pytest.mark.parametrize(
+    "heads,kv_heads,head_dim,ok",
+    [
+        (32, 8, 128, True),
+        (16, 16, 128, True),
+        (40, 40, 128, True),
+        (8, 1, 128, True),  # MQA
+        (28, 4, 128, True),
+        (8, 2, 256, True),
+        (40, 10, 128, False),  # [KV, hd] rows are stored padded: XLA would
+        (12, 12, 128, False),  # copy the whole arena into a temporary
+        (32, 8, 64, False),  # head_dim not lane-aligned
+        (32, 12, 128, False),  # no whole GQA groups
+    ],
+)
+def test_kernel_supported_shapes(heads, kv_heads, head_dim, ok):
+    from agentainer_tpu.ops.pallas_attention import kernel_supported
+
+    assert kernel_supported(heads, kv_heads, head_dim) is ok
+
+
+@pytest.mark.parametrize(
+    "backend,plan_kw,arena",
+    [
+        ("tpu", {}, "stack+layer"),  # the dense kernels index the stack
+        ("tpu", {"page_size": 64}, "layer_slice"),  # the pool's kernels do not
+        ("tpu", {"use_pallas": False}, "layer_slice"),  # GSPMD: XLA reference
+        ("cpu", {}, "layer_slice"),
+    ],
+    ids=["dense-kernels", "paged-kernels", "gspmd-reference", "cpu-reference"],
+)
+def test_plan_says_how_the_arena_reaches_the_kernel(monkeypatch, backend, plan_kw, arena):
+    """What /metrics ``attention.arena`` and the build log line print."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    plan = plan_cache_attention(32, 8, 128, **plan_kw)
+    assert plan.arena == arena and plan.describe()["arena"] == arena
+    assert ("pallas" in plan.decode) == (backend == "tpu" and "use_pallas" not in plan_kw)
 
 
 # ---------------------------------------------------------------------------
@@ -120,7 +247,7 @@ def test_fused_paged_decode_matches_gather_path():
         q, pool_k, pool_v, table, positions, interpret=True
     )
     ck, cv = gather_pages(pool_k, pool_v, table)
-    want = flash_decode(q, ck, cv, positions, interpret=True)
+    want = flash_decode(q, ck[None], cv[None], positions, 0, interpret=True)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     ref = attention_reference(
         q[:, None], ck, cv, mask=cache_mask(positions[:, None], nb * ps)
@@ -142,7 +269,7 @@ def test_fused_paged_prefill_ragged_matches_gather_path():
         q, pool_k, pool_v, table, positions, interpret=True
     )
     ck, cv = gather_pages(pool_k, pool_v, table)
-    want = flash_prefill(q, ck, cv, positions, interpret=True)
+    want = flash_prefill(q, ck[None], cv[None], positions, 0, interpret=True)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
     ref = attention_reference(q, ck, cv, mask=cache_mask(positions, nb * ps))
     np.testing.assert_allclose(got, ref, rtol=2e-5, atol=2e-5)
@@ -191,7 +318,7 @@ def test_decode_bf16():
     cv = _rand(keys[2], b, s, kv_heads, hd).astype(jnp.bfloat16)
     positions = jnp.array([31, 255], jnp.int32)
 
-    got = flash_decode(q, ck, cv, positions, interpret=True)
+    got = flash_decode(q, ck[None], cv[None], positions, 0, interpret=True)
     want = attention_reference(
         q[:, None], ck, cv, mask=cache_mask(positions[:, None], s)
     )[:, 0]

@@ -154,6 +154,9 @@ def test_cpu_engine_reports_device_and_no_utilization():
         assert key not in m, key
     assert m["attention"]["decode"] == "xla:attention_reference"
     assert "backend is cpu" in m["attention"]["reason"]
+    # and how the steps hand the arena to it: the reference cannot address
+    # the stacked arena by layer, so it says it slices the layer out
+    assert m["attention"]["arena"] == "layer_slice"
     assert m["engine_devices"] == [{"id": 0, "coords": None}]
 
 

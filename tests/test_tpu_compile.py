@@ -32,11 +32,28 @@ from agentainer_tpu.parallel.flash_mesh import make_meshed_cache_attention
 
 # llama3-8b serving shapes (models/configs.py, engine defaults)
 B, S, HD = 8, 2048, 128  # max_batch, max_seq, head_dim
+LAYERS = 4  # depth of the stacked arena the dense kernels index by layer
 T = 256  # prefill chunk
 PS = 64  # page size
 NB = S // PS  # block-table width
 POOL = B * NB + B  # data pages + one scratch page per lane
-HEADS = {"one-chip": (32, 8), "tp4-per-device": (8, 2)}
+HEADS = {
+    "one-chip": (32, 8),
+    "tp4-per-device": (8, 2),
+    "olmoe-mha": (16, 16),
+    # the dense kernels' K/V block holds a run of positions with a BLOCK of
+    # KV heads, and prefill's q tile every query head of that block: sized
+    # from a VMEM plan (ops/pallas_attention._kv_block), so checked where
+    # the plan has to cut — two and four head blocks of 16 (Llama-2-7B MHA
+    # and a 64-head one), a count with no 16-head divisor (Llama-2-13B MHA,
+    # shorter blocks), 64 query heads over 8 (Llama-70B GQA, a shorter q
+    # tile) — and at the other end one KV head (MQA)
+    "mha32": (32, 32),
+    "mha64": (64, 64),
+    "mha40": (40, 40),
+    "gqa64x8": (64, 8),
+    "mqa": (8, 1),
+}
 
 
 @pytest.fixture(scope="module")
@@ -68,12 +85,20 @@ def _kernel_args(kernel: str, h: int, kv: int, where):
     def s(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=where)
 
-    arena = s((B, S, kv, HD))
-    row = s((1, S, kv, HD))
+    # the dense kernels take the STACKED arena as the layer scan carries it,
+    # with the layer (and the prefilling lane's slot) as scalars
+    arena = s((LAYERS, B, S, kv, HD))
+    scalar = s((), jnp.int32)
     pool = s((POOL, kv, PS, HD))
     return {
-        "flash_prefill": (flash_prefill, (s((1, T, h, HD)), row, row, s((1, T), jnp.int32))),
-        "flash_decode": (flash_decode, (s((B, h, HD)), arena, arena, s((B,), jnp.int32))),
+        "flash_prefill": (
+            flash_prefill,
+            (s((1, T, h, HD)), arena, arena, s((1, T), jnp.int32), scalar, scalar),
+        ),
+        "flash_decode": (
+            flash_decode,
+            (s((B, h, HD)), arena, arena, s((B,), jnp.int32), scalar, scalar),
+        ),
         "fused_paged_flash_prefill": (
             fused_paged_flash_prefill,
             (s((1, T, h, HD)), pool, pool, s((1, NB), jnp.int32), s((1, T), jnp.int32)),
@@ -100,6 +125,11 @@ def test_kernel_compiles_for_v5e(v5e, kernel, heads):
     fn, args = _kernel_args(kernel, h, kv, SingleDeviceSharding(v5e.devices[0]))
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    if kernel in ("flash_prefill", "flash_decode"):
+        # the stack is read where it lies: no slice, transpose or relayout
+        # of a layer (2 MB a lane here) stands beside the kernel
+        layer_bytes = B * S * kv * HD * 2
+        assert compiled.memory_analysis().temp_size_in_bytes < layer_bytes // 8
 
 
 @pytest.mark.parametrize("t", [1, T], ids=["decode", "prefill"])
@@ -112,14 +142,18 @@ def test_meshed_flash_compiles_for_v5e_2x2(v5e, t):
         axis_names=("dp", "tp", "sp", "ep", "pp"),
     )
     heads = NamedSharding(mesh, P("dp", None, "tp", None))
+    stack = NamedSharding(mesh, P(None, "dp", None, "tp", None))
     b = B if t == 1 else 1
     args = (
         jax.ShapeDtypeStruct((b, t, h, HD), jnp.bfloat16, sharding=heads),
-        jax.ShapeDtypeStruct((b, S, kv, HD), jnp.bfloat16, sharding=heads),
-        jax.ShapeDtypeStruct((b, S, kv, HD), jnp.bfloat16, sharding=heads),
+        jax.ShapeDtypeStruct((LAYERS, b, S, kv, HD), jnp.bfloat16, sharding=stack),
+        jax.ShapeDtypeStruct((LAYERS, b, S, kv, HD), jnp.bfloat16, sharding=stack),
         jax.ShapeDtypeStruct(
             (b, t), jnp.int32, sharding=NamedSharding(mesh, P("dp", None))
         ),
+        None,  # no block table
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=NamedSharding(mesh, P())),
+        None,  # no slot: the batch is the arena's rows
     )
     compiled = jax.jit(make_meshed_cache_attention(mesh)).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()

@@ -110,7 +110,8 @@ chaos:
 server: native
 	$(PY) -m agentainer_tpu.cli server
 
-# compile-check the sharded multi-chip training step on a virtual device mesh
+# serve-time tp and MoE tp x ep engines on a virtual device mesh, then the
+# two-process jax.distributed smoke
 dryrun:
 	JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
 	$(PY) -c "import __graft_entry__ as g; g.dryrun_multichip(8); print('dryrun ok')"

@@ -74,7 +74,7 @@ def compile_hlo(fn: Callable, *args, **kwargs) -> str:
 class NoLargeAllGather:
     """No all-gather at or above ``min_elems`` result elements.
 
-    The never-all-gather invariant: under tp/sp meshes the KV arena (or
+    The never-all-gather invariant: under a tp mesh the KV arena (or
     page pool) must stay shard-local — an all-gather the size of one
     chip's shard means GSPMD re-materialized the whole cache and the
     sharding is decorative. Small all-gathers (control scalars, the

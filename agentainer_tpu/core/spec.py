@@ -24,6 +24,8 @@ from dataclasses import asdict, dataclass, field
 from enum import Enum
 from typing import Any
 
+from .errors import InvalidInput
+
 
 class AgentStatus(str, Enum):
     """Reference status enum, agent.go:21-29 (created/running/stopped/paused/failed)."""
@@ -104,6 +106,22 @@ class Resources:
         return Resources(chips=int(d.get("chips", 1)), hbm_bytes=int(d.get("hbm_bytes", 8 * 1024**3)))
 
 
+def unserved_layout(options: dict[str, Any]) -> str:
+    """What to tell a deployment whose model options name a layout no engine
+    serves (``sp``: a sequence-sharded arena, ``pp``: staged layers), or ""
+    when they name none. Such a deployment asked for a longer context or a
+    deeper model than one ``tp × ep`` engine holds: it must fail when it is
+    deployed, not come up smaller on one chip."""
+    for axis in ("sp", "pp"):
+        if options.get(axis) not in (None, 0, 1):
+            return (
+                f"model option {axis}={options[axis]!r}: this layout is not "
+                "served; an engine spans its chips as tp × ep "
+                "(options tp/ep), and replicas scale out"
+            )
+    return ""
+
+
 @dataclass
 class ModelRef:
     """What the agent serves — replaces the Docker image reference.
@@ -129,11 +147,15 @@ class ModelRef:
         if isinstance(d, str):  # shorthand: "echo" or "llm:llama3-8b"
             engine, _, config = d.partition(":")
             return ModelRef(engine=engine or "echo", config=config)
+        options = dict(d.get("options", {}))
+        refusal = unserved_layout(options)
+        if refusal:
+            raise InvalidInput(refusal)
         return ModelRef(
             engine=d.get("engine", "echo"),
             config=d.get("config", ""),
             checkpoint=d.get("checkpoint", ""),
-            options=dict(d.get("options", {})),
+            options=options,
         )
 
 

@@ -45,14 +45,15 @@ from jax import lax
 # legacy (non-partitionable) implementation computes WRONG values when a
 # random-init is jitted with out_shardings over a mesh with more than one
 # nontrivial axis and a spec that uses only a subset of them (jax 0.4.37:
-# P("tp", None) on a tp×sp mesh silently corrupts the embed table — the
-# tp×sp engine decoded garbage while tp-only and sp-only were fine).
+# P("tp", None) on a mesh with a second axis silently corrupted the embed
+# table, and the engine decoded garbage).
 # Partitionable threefry is sharding-invariant by construction. It changes
 # the random stream, so every in-process engine/model comparison shares
 # the new stream; no test pins absolute values from the old one.
 jax.config.update("jax_threefry_partitionable", True)
 
 from .. import faults
+from ..core.spec import unserved_layout
 from ..models.configs import ModelConfig, get_config
 from ..models.llama import KVCache, PagedKVCache, forward, init_params
 from ..ops.attention import pages_to_rows, plan_cache_attention, rows_to_pages
@@ -213,9 +214,9 @@ def _phase(name: str):
 def _sharded_random_init(cfg: ModelConfig, dtype, mesh, specs: dict) -> dict:
     """Random-init DIRECTLY into shards: ``jit(init, out_shardings=...)``
     makes every chip allocate only its own slice of every weight, so a
-    meshed/pp engine whose model needs more than one chip's HBM never
+    meshed engine whose model needs more than one chip's HBM never
     materializes the whole pytree on the default device first (VERDICT r3
-    missing #3 — init-then-reshard OOMs chip 0 exactly when tp/pp matter).
+    missing #3 — init-then-reshard OOMs chip 0 exactly when tp matters).
     """
     from ..parallel.sharding import shardings_from_specs
 
@@ -466,8 +467,6 @@ class LLMEngine:
         prefill_chunk: int = 256,
         tp: int = 1,
         ep: int = 1,
-        sp: int = 1,
-        pp: int = 1,
         devices: list | None = None,
         mesh=None,
         routed_moe: bool | None = None,
@@ -492,41 +491,20 @@ class LLMEngine:
         self.cfg = cfg
         self.tokenizer = tokenizer
         self.max_batch = max_batch
-        self.pp = max(1, pp)
-        self.sp = max(1, sp)
-        # the sequence axis must split evenly over sp chips
-        max_seq = ((max_seq + self.sp - 1) // self.sp) * self.sp
         # Paged KV arena (block tables): sessions hold lists of fixed-size
         # pages from a global pool instead of dense [max_seq] slots, so
         # resident sessions are bounded by the pool, prefix sharing maps
         # refcounted pages zero-copy, and speculative rewind truncates page
         # tails. paged_kv=False keeps the dense arena — the A/B baseline
-        # (mirrors adaptive_decode / prefix_cache / speculative). sp stages
-        # the SEQUENCE axis across chips and pp stages the cache over
-        # layers with its own alloc path — neither composes with the page
-        # pool yet, so they pin the dense arena.
-        self.paged = bool(paged_kv) and self.sp == 1 and self.pp == 1
-        if bool(paged_kv) and not self.paged:
-            print(
-                "[llm-engine] paged_kv disabled: not composable with "
-                f"sp={self.sp}/pp={self.pp} yet (dense arena retained)",
-                flush=True,
-            )
+        # (mirrors adaptive_decode / prefix_cache / speculative).
+        self.paged = bool(paged_kv)
         # Fused on-device decode loop: a per-ladder-rung compiled
         # lax.while_loop runs up to `chunk` forward+sample+append steps
         # entirely on device (per-lane EOS/budget masking, whole-batch
         # early exit) with ONE readback at loop exit — the per-chunk
         # host sync the ladder only shrank. fused_decode=False keeps the
-        # per-chunk scan dispatch exactly as-is (the A/B baseline). pp
-        # stages the forward across chips with host-side transfers per
-        # step, which cannot live inside a device loop — pp pins unfused.
-        self.fused_decode = bool(fused_decode) and self.pp == 1
-        if bool(fused_decode) and not self.fused_decode:
-            print(
-                "[llm-engine] fused_decode disabled: not composable with "
-                f"pp={self.pp} (per-chunk dispatch retained)",
-                flush=True,
-            )
+        # per-chunk scan dispatch exactly as-is (the A/B baseline).
+        self.fused_decode = bool(fused_decode)
         # Segmented approx top-k sampler (opt-in; exact shared-sort sampler
         # is the default). Static per engine: it picks which sample_step
         # pipeline every compiled decode path bakes in.
@@ -600,42 +578,7 @@ class LLMEngine:
             )
         else:
             cache_shape = (cfg.n_layers, max_batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
-        self._pp_forward = None
-        if self.pp > 1:
-            # serve-time pipeline: layer stack AND the KV arena stage over
-            # pp — each chip holds L/pp layers' weights plus L/pp of the
-            # cache, so a model deeper than one chip's HBM serves at all
-            # (parallel/pipeline.make_serve_pipeline_forward)
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            from ..parallel.mesh import make_mesh
-            from ..parallel.pipeline import (
-                make_serve_pipeline_forward,
-                pipeline_param_specs,
-            )
-
-            # the mesh create() initialized params onto, when given — one
-            # construction, so device_put below is a placement no-op rather
-            # than a silent whole-model reshard if the two ever drifted
-            self.mesh = mesh if mesh is not None else make_mesh(
-                self.pp, pp=self.pp, devices=devices
-            )
-            p_sh = jax.tree.map(
-                lambda s: NamedSharding(self.mesh, s),
-                pipeline_param_specs(cfg.is_moe, qk_norm=cfg.qk_norm),
-                is_leaf=lambda x: isinstance(x, P),
-            )
-            params = jax.device_put(params, p_sh)
-            cache_sh = NamedSharding(self.mesh, P("pp", None, None, None, None))
-            self._alloc_cache = jax.jit(
-                lambda: KVCache(
-                    jnp.zeros(cache_shape, dtype), jnp.zeros(cache_shape, dtype)
-                ),
-                out_shardings=KVCache(cache_sh, cache_sh),
-            )
-            cache = self._alloc_cache()
-            self._pp_forward = make_serve_pipeline_forward(cfg, self.mesh)
-        elif self.tp * self.ep * self.sp > 1:
+        if self.tp * self.ep > 1:
             # serve-time model parallelism over the agent's ASSIGNED chips:
             # Megatron-style GSPMD shardings on a tp×ep mesh — heads/FFN
             # width split over tp, MoE expert weights split over ep (each
@@ -653,11 +596,7 @@ class LLMEngine:
             from ..parallel.sharding import cache_specs, param_shardings_for
 
             self.mesh = mesh if mesh is not None else make_mesh(
-                self.tp * self.ep * self.sp,
-                tp=self.tp,
-                sp=self.sp,
-                ep=self.ep,
-                devices=devices,
+                self.tp, self.ep, devices=devices
             )
             # quant-aware: int8 QTensor leaves shard q on the dense spec and
             # replicate the scale across the contraction split
@@ -677,7 +616,7 @@ class LLMEngine:
                     out_shardings=PagedKVCache(cache_sh, cache_sh),
                 )
             else:
-                cache_sh = NamedSharding(self.mesh, cache_specs(sp=self.sp > 1))
+                cache_sh = NamedSharding(self.mesh, cache_specs())
                 self._alloc_cache = jax.jit(
                     lambda: KVCache(
                         jnp.zeros(cache_shape, dtype), jnp.zeros(cache_shape, dtype)
@@ -1077,7 +1016,7 @@ class LLMEngine:
         self.fused_early_exits_total = 0
         self.fused_exit_reason_hist: dict[str, int] = {}
         self.host_syncs_total = 0
-        self._n_chips = self.tp * self.ep * self.sp * self.pp
+        self._n_chips = self.tp * self.ep
         # the devices this engine computes on, as JAX reports them — what
         # /metrics names, so a number can always be traced to its device
         self._devices = (
@@ -1102,6 +1041,9 @@ class LLMEngine:
         options: dict | None = None,
     ) -> "LLMEngine":
         options = options or {}
+        refusal = unserved_layout(options)
+        if refusal:
+            raise ValueError(refusal)
         # HF checkpoints carry their own config.json — derive the config
         # from the checkpoint itself so a mistyped/missing config name can't
         # cause an opaque shape error deep in the loader (ADVICE round-1)
@@ -1136,15 +1078,14 @@ class LLMEngine:
         if quant and quant != "int8":
             raise ValueError(f"unknown quant scheme {quant!r} (supported: int8)")
 
-        # serve-time TP: the control plane passes the agent's assigned chip
-        # ids (llm_serve) and starts this process seeing ONLY those chips
-        # (runtime/local.py), so device indices here are local: the i-th
-        # assigned chip is jax.devices()[i], whatever its id on the slice.
-        # The span is clamped to a divisor of the model's head counts.
-        # Standalone default is single-chip. int8 quant keeps TP: the
+        # serve-time model parallelism: the control plane passes the agent's
+        # assigned chip ids (llm_serve) and starts this process seeing ONLY
+        # those chips (runtime/local.py), so device indices here are local:
+        # the i-th assigned chip is jax.devices()[i], whatever its id on the
+        # slice. Standalone default is single-chip. int8 quant keeps TP: the
         # QTensor pytree gets matching shardings
         # (parallel/sharding.param_shardings_for).
-        from ..parallel.mesh import pick_ep, pick_tp
+        from ..parallel.mesh import make_mesh, plan_layout
 
         all_devices = jax.devices()
         chips = [int(c) for c in options.get("chips", []) or []]
@@ -1153,127 +1094,15 @@ class LLMEngine:
                 f"assigned chips {chips} do not map to the {len(all_devices)} "
                 f"visible {backend} device(s) of this process"
             )
-        tp_asked = int(options.get("tp", 0) or 0)
-        ep_asked = int(options.get("ep", 0) or 0)
-        sp_asked = int(options.get("sp", 0) or 0)
-        pp_asked = int(options.get("pp", 0) or 0)
-        # chip budget: an explicit chip assignment is the placement
-        # authority — tp×sp×ep may only narrow the span, never spill onto
-        # chips owned by other agents; standalone (no assignment) spans
-        # exactly what the options ask for
-        if chips:
-            budget = len(chips)
-        else:
-            budget = min(
-                len(all_devices),
-                max(1, tp_asked) * max(1, ep_asked) * max(1, sp_asked) * max(1, pp_asked),
-            )
-        if pp_asked > 1:
-            # serve-time pipeline: layers + arena staged over pp (v0
-            # composes with nothing else — one axis, whole assignment)
-            if tp_asked or ep_asked or sp_asked:
-                raise ValueError("serve-time pp does not compose with tp/ep/sp yet")
-            if quant:
-                raise ValueError("serve-time pp does not support quantized weights yet")
-            if options.get("routed"):
-                raise ValueError("serve-time pp does not support routed MoE yet")
-            pp = min(pp_asked, budget)
-            if cfg.n_layers % pp or cfg.vocab_size % pp:
-                raise ValueError(
-                    f"pp={pp} must divide n_layers={cfg.n_layers} and "
-                    f"vocab={cfg.vocab_size}"
-                )
-            devices = list(all_devices[:pp])
-            from ..parallel.mesh import make_mesh as _mk
-
-            mesh = _mk(pp, pp=pp, devices=devices)
-            if checkpoint:
-                # deploy serves what you named (agent.go:104-142): pp
-                # engines load the checkpoint host-side; __init__'s
-                # device_put places each stage's slice straight onto its
-                # chip (VERDICT r3 missing #2 — this branch used to serve
-                # random weights silently)
-                from .checkpoint import load_params
-
-                params = load_params(cfg, checkpoint, dtype=dtype)
-            else:
-                from ..parallel.pipeline import pipeline_param_specs as _pps
-
-                params = _sharded_random_init(cfg, dtype, mesh, _pps(cfg.is_moe, qk_norm=cfg.qk_norm))
-            engine = cls(
-                cfg,
-                params,
-                tokenizer,
-                max_batch=int(options.get("max_batch", 8)),
-                max_seq=int(options.get("max_seq", min(cfg.max_seq_len, 2048))),
-                decode_chunk=int(options.get("decode_chunk", 8)),
-                prefill_chunk=int(options.get("prefill_chunk", 256)),
-                pp=pp,
-                devices=devices,
-                mesh=mesh,
-                adaptive_decode=bool(options.get("adaptive_decode", True)),
-                prefix_cache=bool(options.get("prefix_cache", True)),
-                prefix_cache_bytes=int(options.get("prefix_cache_bytes", 0) or 0),
-                deadlines=bool(options.get("deadlines", True)),
-                shed_watermark=int(options.get("shed_watermark", 0) or 0),
-                speculative=bool(options.get("speculative", True)),
-                spec_gamma_max=int(options.get("spec_gamma_max", 8) or 8),
-                paged_kv=bool(options.get("paged_kv", False)),
-                page_size=int(options.get("page_size", PAGE_SIZE_DEFAULT) or PAGE_SIZE_DEFAULT),
-                kv_pages=int(options.get("kv_pages", 0) or 0),
-                fused_decode=bool(options.get("fused_decode", False)),
-                inloop_spec=bool(options.get("inloop_spec", True)),
-                approx_topk=bool(options.get("approx_topk", False)),
-                kv_tiering=bool(options.get("kv_tiering", False)),
-                tier_quantize=int(options.get("tier_quantize", 1) or 0),
-                streaming=bool(options.get("streaming", False)),
-            )
-            if not options.get("skip_warmup"):
-                engine.warmup()
-            return engine
-        # sequence parallelism is opt-in (long-context serving); requested
-        # sp reserves its chips before the tp/ep split
-        model_budget = max(1, budget // max(1, sp_asked))
-        if cfg.is_moe:
-            # EP-first: experts dominate a MoE model's HBM footprint, and
-            # "Mixtral across the slice via EP" is the flagship scale-out
-            # config. Explicit tp/ep options override the split.
-            if ep_asked:
-                ep = pick_ep(cfg, min(ep_asked, model_budget))
-                tp = pick_tp(cfg, min(max(1, tp_asked), model_budget // ep))
-            elif tp_asked:
-                tp = pick_tp(cfg, min(tp_asked, model_budget))
-                ep = pick_ep(cfg, model_budget // tp)
-            else:
-                ep = pick_ep(cfg, model_budget)
-                tp = pick_tp(cfg, model_budget // ep)
-        else:
-            ep = 1
-            # dense + assigned chips + no explicit tp: span the whole
-            # assignment (the scheduler sized it; idle chips help nobody)
-            dense_tp = tp_asked if tp_asked else (model_budget if chips else 1)
-            tp = pick_tp(cfg, min(max(1, dense_tp), model_budget))
-        sp = max(1, min(sp_asked, budget // (tp * ep))) if sp_asked else 1
-        n_use = tp * ep * sp
-        asked = max(1, tp_asked) * max(1, ep_asked) * max(1, sp_asked)
-        if n_use < min(asked, budget) or (chips and n_use < len(chips)):
-            print(
-                f"[llm-engine] parallelism narrowed to tp={tp} ep={ep} sp={sp} "
-                f"(asked tp={tp_asked or 'auto'} ep={ep_asked or 'auto'} "
-                f"sp={sp_asked or 'auto'}, "
-                f"assigned chips={len(chips) or 'none'}, visible devices="
-                f"{len(all_devices)}, model kv_heads={cfg.n_kv_heads}, "
-                f"heads={cfg.n_heads}, experts={cfg.n_experts}); "
-                "extra chips idle",
-                flush=True,
-            )
-        devices = list(all_devices[:n_use])
-
-        mesh = None
-        if n_use > 1:
-            from ..parallel.mesh import make_mesh as _mk
-
-            mesh = _mk(n_use, tp=tp, sp=sp, ep=ep, devices=devices)
+        tp, ep = plan_layout(
+            cfg,
+            len(chips),
+            len(all_devices),
+            tp_asked=int(options.get("tp", 0) or 0),
+            ep_asked=int(options.get("ep", 0) or 0),
+        )
+        devices = list(all_devices[: tp * ep])
+        mesh = make_mesh(tp, ep, devices=devices) if tp * ep > 1 else None
         synthetic = bool(options.get("synthetic"))
         if checkpoint:
             from .checkpoint import load_params
@@ -1319,9 +1148,7 @@ class LLMEngine:
             # init already produced QTensors in device memory)
             params = quantize_params(params, dtype)
         max_batch = int(options.get("max_batch", 8))
-        # long-context default scales with sp: the sharded arena holds
-        # sp× one chip's context budget (explicit max_seq still wins)
-        max_seq = int(options.get("max_seq", min(cfg.max_seq_len, 2048 * sp)))
+        max_seq = int(options.get("max_seq", min(cfg.max_seq_len, 2048)))
         decode_chunk = int(options.get("decode_chunk", 8))
         prefill_chunk = int(options.get("prefill_chunk", 256))
         engine = cls(
@@ -1334,7 +1161,6 @@ class LLMEngine:
             prefill_chunk=prefill_chunk,
             tp=tp,
             ep=ep,
-            sp=sp,
             devices=devices,
             mesh=mesh,
             routed_moe=options.get("routed"),
@@ -1376,15 +1202,14 @@ class LLMEngine:
         # GSPMD partition a pallas_call, but attention is embarrassingly
         # parallel over heads/batch — so tp/ep engines run the SAME flash
         # kernels per device inside a shard_map body
-        # (parallel/flash_mesh.py). sp-sharded arenas, the pipeline's
-        # staged cache and a meshed page pool stay on the einsum path
-        # (they need the partial-softmax combine / partitioning XLA derives).
+        # (parallel/flash_mesh.py). A meshed page pool stays on the einsum
+        # path (it needs the partitioning XLA derives).
         page_size = self.page_size if self.paged else 0
         if self.mesh is None:
             attn = plan_cache_attention(
                 cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, page_size=page_size
             )
-        elif self.sp == 1 and self.pp == 1 and not self.paged:
+        elif not self.paged:
             from ..parallel.flash_mesh import plan_meshed_cache_attention
 
             attn = plan_meshed_cache_attention(cfg, self.mesh, self.tp)
@@ -1406,7 +1231,7 @@ class LLMEngine:
         cache_attn_impl = attn.fn
 
         moe_impl = None
-        if self.routed_moe and self.pp == 1:
+        if self.routed_moe:
             if self.mesh is not None and self.ep > 1:
                 from ..parallel.expert import make_routed_moe
 
@@ -1442,22 +1267,9 @@ class LLMEngine:
                 flush=True,
             )
 
-        pp_forward = self._pp_forward
-
         def run_forward(params, toks, pos, cache, bt=None, slot=None):
             """``slot``: the batch's rows are arena rows ``slot..`` (a lane's
-            prefill); ``forward`` addresses them in place, the pipeline's
-            staged cache gets the rows sliced out and written back."""
-            if pp_forward is not None:
-                k, v = cache.k, cache.v
-                if slot is not None:
-                    k = lax.dynamic_slice_in_dim(k, slot, toks.shape[0], axis=1)
-                    v = lax.dynamic_slice_in_dim(v, slot, toks.shape[0], axis=1)
-                logits, k, v = pp_forward(params, toks, pos, k, v)
-                if slot is not None:
-                    k = lax.dynamic_update_slice_in_dim(cache.k, k, slot, axis=1)
-                    v = lax.dynamic_update_slice_in_dim(cache.v, v, slot, axis=1)
-                return logits, KVCache(k, v)
+            prefill); ``forward`` addresses them in place."""
             return forward(
                 params,
                 cfg,
@@ -3474,7 +3286,6 @@ class LLMEngine:
             "max_seq": self.max_seq,
             "tp": self.tp,
             "ep": self.ep,
-            "sp": self.sp,
             "meshed_flash": self.meshed_flash,
             "moe_routed": self.routed_moe,
             # FLOP model + HBM telemetry: lifetime MFU here is a floor

@@ -355,8 +355,9 @@ class LLMServeApp:
                 opts[flag] = raw.lower() in ("1", "true", "yes")
         if self.chips:
             # no tp injection: LLMEngine.create derives the parallelism
-            # split from the chip budget itself (dense → tp-first, MoE →
-            # ep-first), and an explicit options.tp/ep/sp only narrows it
+            # split from the chip budget itself (parallel/mesh.plan_layout:
+            # dense → tp-first, MoE → ep-first), and an explicit
+            # options.tp/ep only narrows it
             opts["chips"] = list(self.chips)
         # warm boot (engine RESPAWN with a populated persistent XLA cache):
         # skip the serving warmup — every compile it would trigger is a disk

@@ -62,7 +62,7 @@ def synthetic_quantized_params(cfg, dtype=jnp.bfloat16, device=None, mesh=None) 
 
     With ``mesh`` each leaf is generated WITH its serving sharding
     (``jit(..., out_shardings=...)`` from parallel/sharding.param_specs),
-    so every chip of a tp/ep/sp engine allocates only its slice — nothing
+    so every chip of a tp × ep engine allocates only its slice — nothing
     is ever materialized whole on one device."""
     import jax
 
@@ -75,10 +75,10 @@ def synthetic_quantized_params(cfg, dtype=jnp.bfloat16, device=None, mesh=None) 
 
         from ..parallel.sharding import param_specs, qtensor_sharding
 
-        sp = param_specs(cfg.is_moe, cfg.qk_norm)
+        tree = param_specs(cfg.is_moe, cfg.qk_norm)
         # name → spec lookup (leaves live at two depths in the pytree)
-        specs = {**sp["layers"], "embed": sp["embed"], "lm_head": sp["lm_head"],
-                 "final_norm": sp["final_norm"]}
+        specs = {**tree["layers"], "embed": tree["embed"], "lm_head": tree["lm_head"],
+                 "final_norm": tree["final_norm"]}
 
         def shard_of(name):
             return NamedSharding(mesh, specs[name])

@@ -116,7 +116,7 @@ def sample_step(
     the where-merged pipeline — bit-identical output, just no fast path.
     MESHED engines must pass it: this jaxlib's XLA:CPU partitioner
     segfaults compiling a batch-wide conditional over sharded operands
-    (pp/sp/tp warmup died inside the cond), and on a real mesh the sort
+    (a tp engine's warmup died inside the cond), and on a real mesh the sort
     pipeline is cheap relative to the sharded forward anyway.
 
     ``approx_topk=True`` (static) swaps the full-vocab sort for a

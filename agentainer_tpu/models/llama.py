@@ -7,7 +7,7 @@ XLA from the start:
 - **pytree params with stacked layers**: every per-layer weight carries a
   leading ``[n_layers, ...]`` axis and the forward pass is one
   ``lax.scan`` over layers — one traced block regardless of depth (fast
-  compiles, and the natural substrate for pipeline parallelism later);
+  compiles);
 - **static shapes everywhere**: the KV cache is a fixed ``[L, B, S, KV, hd]``
   arena written by scatter at per-sequence positions, so the same compiled
   function serves prefill and continuous-batching decode (ragged batches);
@@ -257,7 +257,6 @@ def _attention_block(
     ck: jnp.ndarray | None,
     cv: jnp.ndarray | None,
     use_flash: bool,
-    attn_impl=None,
     cache_attn_impl=None,
     block_table=None,
     layer=None,
@@ -308,11 +307,6 @@ def _attention_block(
                 use_pallas=use_flash,
             ).fn
         attn = cache_attn_impl(q, ck, cv, positions, block_table, layer, slot)
-    elif attn_impl is not None:
-        # caller-supplied causal self-attention: the sequence-parallel
-        # training path passes ring/Ulysses attention here (q/k/v are
-        # sequence shards; global positions came in via ``positions``)
-        attn = attn_impl(q, k, v)
     elif use_flash:
         attn = flash_attention(q, k, v, causal=True)
     else:
@@ -328,7 +322,6 @@ def forward(
     positions: jnp.ndarray,  # [B, T] int32
     cache: KVCache | None = None,
     use_flash: bool = True,
-    attn_impl=None,
     cache_attn_impl=None,
     moe_impl=None,
     block_table: jnp.ndarray | None = None,
@@ -346,8 +339,8 @@ def forward(
     With ``block_table`` the cache is a :class:`PagedKVCache` pool and
     every KV read/write goes through the table (paged serving); the cache
     returned is the updated pool.
-    Without: pure causal self-attention (training / eval); ``attn_impl``
-    overrides the attention for sequence-parallel runs (ring / Ulysses).
+    Without: pure causal self-attention over the tokens given (what tests
+    compare the cached path with).
     ``moe_impl`` overrides the MoE MLP (routed token-dispatch, meshed EP).
     """
     x = embed_lookup(params["embed"], tokens)
@@ -364,7 +357,6 @@ def forward(
         lp = {k: dequant(v) for k, v in lp.items()}
         x, ck, cv = _attention_block(
             x, lp, cfg, positions, mask, ck, cv, use_flash,
-            attn_impl=attn_impl,
             cache_attn_impl=cache_attn_impl,
             block_table=block_table,
             layer=layer,

@@ -1,6 +1,6 @@
 """DCN / multi-host distributed backend (SURVEY §2.3 "collective backend",
 §5.8): ``jax.distributed`` wiring so meshes span hosts — on-slice traffic
-(tp/sp/ep) rides ICI, cross-host data parallelism rides DCN, the same way
+(tp/ep) rides ICI, cross-host data parallelism rides DCN, the same way
 the reference's role would be filled by NCCL/MPI in a GPU stack (the
 reference itself has neither — Docker bridge + Redis only).
 
@@ -13,7 +13,7 @@ and must happen before any jax computation:
 
 ``host_mesh`` builds the canonical multi-host mesh: the dp axis is laid out
 over PROCESS boundaries first (outermost), so gradient all-reduces cross
-DCN once per step while tp/sp/ep collectives stay inside each host's ICI
+DCN once per step while tp/ep collectives stay inside each host's ICI
 domain — the scaling-book recipe.
 """
 
@@ -75,27 +75,28 @@ def host_count() -> int:
     return jax.process_count()
 
 
-def host_mesh(tp: int = 1, sp: int = 1, ep: int = 1, pp: int = 1):
+def host_mesh(tp: int = 1, ep: int = 1):
     """Global mesh over every process's devices with dp spanning the host
-    (DCN) dimension outermost. Model axes (tp/sp/ep/pp) must fit within
-    one host's device count so their collectives never cross DCN."""
+    (DCN) dimension outermost — the one mesh with a dp axis; a served
+    engine's mesh (parallel/mesh.make_mesh) is tp × ep. The model axes must
+    fit within one host's device count so their collectives never cross
+    DCN."""
     import jax
     import numpy as np
     from jax.sharding import Mesh
 
     devs = jax.devices()  # global, ordered by process
     per_host = len(devs) // max(1, jax.process_count())
-    denom = tp * sp * ep * pp
+    denom = tp * ep
     if denom > per_host or per_host % denom:
         # divisibility matters, not just fit: a denom that doesn't divide
         # per_host would make consecutive-device model groups straddle a
         # host boundary, putting their collectives on DCN
         raise ValueError(
-            f"tp*sp*ep*pp={denom} must divide one host's {per_host} devices — "
+            f"tp*ep={denom} must divide one host's {per_host} devices — "
             "model-parallel collectives must stay on ICI, not DCN"
         )
     if len(devs) % denom:
         raise ValueError(f"{len(devs)} devices not divisible by {denom}")
     dp = len(devs) // denom
-    arr = np.array(devs).reshape(dp, pp, tp, sp, ep).transpose(0, 2, 3, 4, 1)
-    return Mesh(arr, axis_names=("dp", "tp", "sp", "ep", "pp"))
+    return Mesh(np.array(devs).reshape(dp, tp, ep), axis_names=("dp", "tp", "ep"))

@@ -60,8 +60,7 @@ def make_routed_moe(
 
     Partial-manual shard_map: only ``ep`` is manual — tp-sharded expert
     widths stay in GSPMD's hands, so their Megatron collectives compose
-    with the manual ep psum (same pattern as the pipeline's partial-manual
-    map, parallel/pipeline.py).
+    with the manual ep psum.
     """
     ep = int(mesh.shape[axis])
     if cfg.n_experts % ep:

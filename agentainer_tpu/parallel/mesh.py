@@ -1,19 +1,18 @@
-"""Device mesh construction.
+"""Device mesh construction and the layout rule.
 
 The TPU-native replacement for the reference's "distribution" layer (Docker
-bridge + replicas, SURVEY.md §2.3): parallelism here is a
-``jax.sharding.Mesh`` over the chips the slice scheduler assigned, with
-named axes
+bridge + replicas, SURVEY.md §2.3): an engine that spans more than one chip
+computes on a ``jax.sharding.Mesh`` over the chips the slice scheduler
+assigned it, with two named axes
 
-    dp  — data parallel (replica fan-out, the reference's ``replicas: N``)
     tp  — tensor parallel (attention heads / FFN width over ICI)
-    sp  — sequence/context parallel (ring attention / Ulysses)
-    ep  — expert parallel (MoE all-to-all)
-    pp  — pipeline parallel (layer stages, collective_permute between)
+    ep  — expert parallel (a MoE model's experts, whole, over ICI)
 
-Axis sizes are chosen to divide the model's head/expert counts; XLA/GSPMD
-inserts the all-gathers/reduce-scatters implied by the sharding annotations
-(parallel/sharding.py) so collectives ride ICI.
+Replica fan-out (the reference's ``replicas: N``) is separate engine
+processes behind the proxy, not a mesh axis. ``plan_layout`` is the one
+place that decides how many chips an engine spans and how; XLA/GSPMD
+inserts the collectives implied by the sharding annotations
+(parallel/sharding.py) so they ride ICI.
 """
 
 from __future__ import annotations
@@ -25,27 +24,14 @@ from jax.sharding import Mesh
 from ..models.configs import ModelConfig
 
 
-def make_mesh(
-    n_devices: int | None = None,
-    tp: int = 1,
-    sp: int = 1,
-    ep: int = 1,
-    pp: int = 1,
-    devices: list | None = None,
-) -> Mesh:
-    """Mesh with axes (dp, tp, sp, ep, pp); dp absorbs the remaining
-    devices. pp is last so pipeline stages are the widest strides — on a
-    physical slice that places a stage's tp/sp group on ICI neighbors."""
+def make_mesh(tp: int = 1, ep: int = 1, devices: list | None = None) -> Mesh:
+    """A ``tp × ep`` mesh over the first ``tp * ep`` of ``devices`` (default:
+    this process's devices)."""
     devs = devices if devices is not None else jax.devices()
-    if n_devices is not None:
-        devs = devs[:n_devices]
-    n = len(devs)
-    denom = tp * sp * ep * pp
-    if n % denom != 0:
-        raise ValueError(f"{n} devices not divisible by tp*sp*ep*pp={denom}")
-    dp = n // denom
-    arr = np.array(devs).reshape(dp, pp, tp, sp, ep).transpose(0, 2, 3, 4, 1)
-    return Mesh(arr, axis_names=("dp", "tp", "sp", "ep", "pp"))
+    n = tp * ep
+    if len(devs) < n:
+        raise ValueError(f"tp*ep={n} needs {n} devices, have {len(devs)}")
+    return Mesh(np.array(devs[:n]).reshape(tp, ep), axis_names=("tp", "ep"))
 
 
 def pick_tp(cfg: ModelConfig, n_devices: int) -> int:
@@ -69,3 +55,55 @@ def pick_ep(cfg: ModelConfig, n_devices: int) -> int:
         if cfg.n_experts % cand == 0:
             ep = cand
     return ep
+
+
+def plan_layout(
+    cfg: ModelConfig,
+    n_assigned: int,
+    n_visible: int,
+    tp_asked: int = 0,
+    ep_asked: int = 0,
+) -> tuple[int, int]:
+    """The ``(tp, ep)`` an engine for ``cfg`` is built with.
+
+    ``n_assigned`` is how many chips the scheduler assigned the agent (0 for
+    a standalone engine), ``n_visible`` how many devices the process sees,
+    ``tp_asked``/``ep_asked`` the deployment's options (0 = not given). An
+    assignment is the placement authority: the options may only narrow the
+    span, never spill onto chips other agents own. A standalone engine spans
+    exactly what its options ask for, at most what it sees. Both axes are
+    clamped to divisors of the model's head and expert counts, and a span
+    narrower than was asked for or assigned says so on stdout (the chips
+    left over stay idle).
+    """
+    budget = n_assigned or min(n_visible, max(1, tp_asked) * max(1, ep_asked))
+    if cfg.is_moe:
+        # EP-first: experts dominate a MoE model's HBM footprint. Explicit
+        # tp/ep options override the split.
+        if ep_asked:
+            ep = pick_ep(cfg, min(ep_asked, budget))
+            tp = pick_tp(cfg, min(max(1, tp_asked), budget // ep))
+        elif tp_asked:
+            tp = pick_tp(cfg, min(tp_asked, budget))
+            ep = pick_ep(cfg, budget // tp)
+        else:
+            ep = pick_ep(cfg, budget)
+            tp = pick_tp(cfg, budget // ep)
+    else:
+        ep = 1
+        # dense + assigned chips + no explicit tp: span the whole
+        # assignment (the scheduler sized it; idle chips help nobody)
+        dense_tp = tp_asked or (budget if n_assigned else 1)
+        tp = pick_tp(cfg, min(dense_tp, budget))
+    asked = max(1, tp_asked) * max(1, ep_asked)
+    if tp * ep < min(asked, budget) or tp * ep < n_assigned:
+        print(
+            f"[llm-engine] parallelism narrowed to tp={tp} ep={ep} "
+            f"(asked tp={tp_asked or 'auto'} ep={ep_asked or 'auto'}, "
+            f"assigned chips={n_assigned or 'none'}, visible devices="
+            f"{n_visible}, model kv_heads={cfg.n_kv_heads}, "
+            f"heads={cfg.n_heads}, experts={cfg.n_experts}); "
+            "extra chips idle",
+            flush=True,
+        )
+    return tp, ep

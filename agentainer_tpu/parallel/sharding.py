@@ -6,8 +6,7 @@ parallel output/down projections, vocab-sharded embed/lm_head. XLA inserts
 the matching all-reduce/all-gather on ICI. Expert weights additionally
 shard their expert axis over ``ep`` (parallel/expert.py's all-to-all path).
 
-Batch/sequence activations shard over ``dp``/``sp``; everything else
-replicates. These specs feed ``jax.jit(in_shardings=...)`` /
+Activations replicate. These specs feed ``jax.jit(in_shardings=...)`` /
 ``jax.device_put`` — model code never names a device.
 """
 
@@ -113,19 +112,9 @@ def param_shardings_for(params: dict, mesh: Mesh, moe: bool = False) -> dict:
     )
 
 
-def batch_spec() -> P:
-    """Tokens/positions: batch over dp, sequence over sp."""
-    return P("dp", "sp")
-
-
-def cache_specs(sp: bool = False) -> P:
-    """KV cache [L, B, S, KV, hd]: batch over dp, heads over tp; with
-    ``sp`` the SEQUENCE axis also shards — each chip holds S/sp of the
-    arena, so serving context scales past one chip's HBM. Attention over
-    the sharded axis partitions into per-chip partial softmax + psum
-    combines (distributed flash-decode), inserted by XLA from these
-    annotations."""
-    return P(None, "dp", "sp" if sp else None, "tp", None)
+def cache_specs() -> P:
+    """KV cache [L, B, S, KV, hd]: KV heads over tp, everything else whole."""
+    return P(None, None, None, "tp", None)
 
 
 def shard_params(params: dict, mesh: Mesh, moe: bool = False) -> dict:

@@ -123,6 +123,27 @@ def test_validation_errors():
         parse_deployment(cycle)
 
 
+@pytest.mark.parametrize("axis", ["sp", "pp"])
+def test_deployment_yaml_refuses_unserved_layout(tmp_path, axis):
+    path = tmp_path / "long.yaml"
+    path.write_text(
+        textwrap.dedent(
+            f"""
+            kind: AgentDeployment
+            spec:
+              agents:
+                - name: longctx
+                  model:
+                    engine: llm
+                    config: tiny
+                    options: {{{axis}: 4, max_seq: 8192}}
+            """
+        )
+    )
+    with pytest.raises(InvalidInput, match=f"{axis}=4.*not served"):
+        load_deployment(str(path))
+
+
 def test_forward_dependency_ok():
     """The reference only resolved deps against earlier-declared names
     (deployment.go:129-156); we accept forward declarations."""

@@ -88,6 +88,31 @@ def test_two_process_dp_collective(tmp_path):
         assert "cross-process sum OK" in out
 
 
+@pytest.mark.parametrize("n_devices", [2, 8])
+def test_dryrun_multichip_exits_cleanly(n_devices):
+    """The driver's multi-chip entry point, as the driver calls it: a tp
+    engine and a MoE tp × ep engine over n virtual devices, then the
+    two-process smoke. In a child, because it sets JAX's environment."""
+    import os
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    done = subprocess.run(
+        [
+            sys.executable,
+            "-c",
+            f"import __graft_entry__ as g; g.dryrun_multichip({n_devices})",
+        ],
+        cwd=repo,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        stdout=subprocess.PIPE,
+        stderr=subprocess.STDOUT,
+        timeout=280,
+    )
+    out = done.stdout.decode()
+    assert done.returncode == 0, out
+    assert out.count("dryrun_multichip OK") == 3, out
+
+
 def test_topology_prefers_single_host_windows():
     topo = SliceTopology(total_chips=16, hosts=2, mesh_shape=(4, 4))
     assert topo.chips_per_host == 8

@@ -10,9 +10,17 @@ expert contraction into an ICI psum. Runs on the virtual 8-device CPU mesh
 import asyncio
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from agentainer_tpu.engine.llm import LLMEngine
+from agentainer_tpu.models.configs import get_config
+from agentainer_tpu.models.llama import _moe_mlp, init_params
+from agentainer_tpu.parallel.expert import moe_expert_parallel
+from agentainer_tpu.parallel.mesh import make_mesh
+
+from .test_llm_tp import ENGINES, one_chip, two_turns  # noqa: F401  (fixture)
 
 pytestmark = pytest.mark.skipif(
     len(jax.devices()) < 8, reason="needs the virtual 8-device mesh"
@@ -46,20 +54,17 @@ def test_ep_engine_shards_expert_weights():
         engine.shutdown()
 
 
-def test_ep_matches_single_device():
-    """Same greedy tokens dense single-chip vs ep=4 vs tp=2×ep=2 (f32 CPU):
-    expert sharding only relocates compute, not the math."""
-    e1 = _mk()
-    e2 = _mk(ep=4)
-    e3 = _mk(tp=2, ep=2)
-    try:
-        r1, r2, r3 = _gen(e1), _gen(e2), _gen(e3)
-        assert r1["tokens"] == r2["tokens"], (r1["tokens"], r2["tokens"])
-        assert r1["tokens"] == r3["tokens"], (r1["tokens"], r3["tokens"])
-    finally:
-        e1.shutdown()
-        e2.shutdown()
-        e3.shutdown()
+@pytest.mark.parametrize("engine", ENGINES)
+@pytest.mark.parametrize(
+    "layout", [{"ep": 2}, {"tp": 2, "ep": 2}], ids=["ep2", "tp2xep2"]
+)
+@pytest.mark.parametrize("config", ["tiny-moe", "tiny-olmoe"])
+def test_ep_matches_single_device(config, layout, engine, one_chip):
+    """Same greedy tokens one chip vs ep=2 vs tp=2×ep=2 (f32 CPU), in both
+    MoE families and on every engine a mesh can carry: expert sharding only
+    relocates compute, not the math."""
+    got = two_turns(config, **layout, **ENGINES[engine])
+    assert got == one_chip(config), (got, one_chip(config))
 
 
 def test_moe_placement_defaults_ep_first():
@@ -103,3 +108,27 @@ def test_moe_tp_ep_session_roundtrip():
         asyncio.run(turn(engine2, "second turn"))
     finally:
         engine2.shutdown()
+
+
+def _layer0(cfg):
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    return {k: v[0] for k, v in params["layers"].items()}  # layer 0, no L axis
+
+
+def test_expert_parallel_matches_dense():
+    """The all-experts shard_map (every device computes its local experts
+    for every token, psum over ep) against the one-device MoE MLP."""
+    cfg = get_config("tiny-moe")  # 4 experts, top-2
+    lp = _layer0(cfg)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, cfg.dim), jnp.float32)
+    ep_out = moe_expert_parallel(x, lp, cfg, make_mesh(ep=4), axis="ep")
+    np.testing.assert_allclose(
+        np.asarray(ep_out), np.asarray(_moe_mlp(x, lp, cfg)), rtol=2e-4, atol=2e-4
+    )
+
+
+def test_expert_parallel_rejects_bad_ep():
+    cfg = get_config("tiny-moe")
+    x = jnp.zeros((1, 4, cfg.dim), jnp.float32)
+    with pytest.raises(ValueError):  # 8 does not divide 4 experts
+        moe_expert_parallel(x, _layer0(cfg), cfg, make_mesh(ep=8), axis="ep")

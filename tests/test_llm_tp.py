@@ -61,21 +61,54 @@ def test_single_chip_placement_uses_local_device_indices():
         engine.shutdown()
 
 
-def test_tp_matches_single_chip_greedy():
-    """Greedy decode must produce the same tokens sharded or not (f32 CPU;
-    the collectives only change the reduction layout)."""
-    e1, e2 = _mk(1), _mk(2)
+# The engines ROADMAP's four-chip cells and Speed 9 will deploy on a mesh:
+# the default one, the page pool, and the page pool under the fused loop.
+ENGINES = {
+    "default": {},
+    "paged": {"paged_kv": True},
+    "paged+fused": {"paged_kv": True, "fused_decode": True},
+}
+_TURNS = ("the quick brown fox jumps over", "and then the lazy dog")
+
+
+def two_turns(config: str, **options) -> list[list[int]]:
+    """Greedy tokens of two turns of one session on a fresh engine."""
+    engine = LLMEngine.create(
+        config, options={"max_batch": 2, "max_seq": 128, **options}
+    )
     try:
 
-        async def go(e):
-            return await e.generate("the quick brown fox", max_tokens=6)
+        async def go():
+            return [
+                (await engine.chat("s", turn, max_tokens=6))["tokens"] for turn in _TURNS
+            ]
 
-        r1 = asyncio.run(go(e1))
-        r2 = asyncio.run(go(e2))
-        assert r1["tokens"] == r2["tokens"], (r1["tokens"], r2["tokens"])
+        return asyncio.run(go())
     finally:
-        e1.shutdown()
-        e2.shutdown()
+        engine.shutdown()
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """Each family's tokens on the one-chip default engine, computed once."""
+    memo: dict[str, list[list[int]]] = {}
+
+    def tokens(config: str) -> list[list[int]]:
+        if config not in memo:
+            memo[config] = two_turns(config)
+        return memo[config]
+
+    return tokens
+
+
+@pytest.mark.parametrize("engine", ENGINES)
+@pytest.mark.parametrize("config", ["tiny", "tiny-moe", "tiny-olmoe"])
+def test_tp_matches_single_chip_greedy(config, engine, one_chip):
+    """Greedy decode must produce the same tokens sharded or not (f32 CPU;
+    the collectives only change the reduction layout), in every family and
+    on every engine a mesh can carry."""
+    got = two_turns(config, tp=2, **ENGINES[engine])
+    assert got == one_chip(config), (got, one_chip(config))
 
 
 def test_tp_session_snapshot_restore_roundtrip():
@@ -120,3 +153,11 @@ def test_dense_chips_default_to_tp_spanning_assignment():
         assert {d.id for d in engine.cache.k.sharding.device_set} == {0, 1}
     finally:
         engine.shutdown()
+
+
+@pytest.mark.parametrize("axis", ["sp", "pp"])
+def test_create_refuses_unserved_layout(axis):
+    """A deployment that asks for a sequence-sharded arena or staged layers
+    fails by name; it does not come up on one chip with less than it asked."""
+    with pytest.raises(ValueError, match=f"{axis}=2.*not served"):
+        LLMEngine.create("tiny", options={axis: 2, "max_batch": 2, "max_seq": 128})

@@ -51,6 +51,18 @@ def test_deploy_validation(mgr):
         mgr.deploy("a", "llm:no-such-model")
 
 
+@pytest.mark.parametrize("axis", ["sp", "pp"])
+def test_deploy_refuses_unserved_layout(mgr, axis):
+    """What the REST deploy hands the manager: a layout no engine serves is
+    a 400 at deploy, not an agent that starts on one chip; 1 names none."""
+    model = {"engine": "llm", "config": "tiny", "options": {axis: 2}}
+    with pytest.raises(InvalidInput, match=f"{axis}=2.*not served"):
+        mgr.deploy("a", model)
+    assert mgr.list_agents() == []
+    model["options"][axis] = 1
+    assert mgr.deploy("a", model).model.options == {axis: 1}
+
+
 def test_start_stop_restart(mgr):
     agent = mgr.deploy("a", "echo")
     agent = mgr.start(agent.id)

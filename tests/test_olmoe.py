@@ -201,7 +201,7 @@ def path_expert_parallel(x, lp, cfg):
     from agentainer_tpu.parallel.expert import moe_expert_parallel
     from agentainer_tpu.parallel.mesh import make_mesh
 
-    return moe_expert_parallel(x, lp, cfg, make_mesh(2, ep=2, devices=jax.devices()[:2]))
+    return moe_expert_parallel(x, lp, cfg, make_mesh(ep=2))
 
 
 @pytest.mark.parametrize("path", [path_dense, path_routed, path_expert_parallel], ids=["all_experts", "routed", "ep_shard_map"])
@@ -249,7 +249,7 @@ def test_qk_norm_under_tp_reduces_across_the_shards(case):
     from agentainer_tpu.parallel.sharding import param_shardings_for
 
     params, tokens, want = case
-    mesh = make_mesh(2, tp=2, devices=jax.devices()[:2])
+    mesh = make_mesh(tp=2)
     sharded = jax.device_put(params, param_shardings_for(params, mesh, moe=True))
     assert "tp" in str(sharded["layers"]["wq"].sharding.spec)
     pos = jnp.arange(tokens.shape[0])[None]

@@ -78,7 +78,7 @@ def _device_put_tp(args, mesh):
 
 
 def test_tp_paged_gather_keeps_pool_shard_local():
-    mesh = make_mesh(2, tp=2)
+    mesh = make_mesh(tp=2)
     args = _device_put_tp(_inputs(), mesh)
     hlo = jax.jit(_paged_attention).lower(*args).compile().as_text()
     check(hlo, NoLargeAllGather(SHARD_ELEMS, what="the paged KV pool shard"))
@@ -87,6 +87,6 @@ def test_tp_paged_gather_keeps_pool_shard_local():
 def test_tp_paged_numerics_match_unsharded():
     args = _inputs()
     want = _paged_attention(*args)
-    mesh = make_mesh(2, tp=2)
+    mesh = make_mesh(tp=2)
     got = jax.jit(_paged_attention)(*_device_put_tp(args, mesh))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)

@@ -2,8 +2,8 @@
 
 The TPU-world analogue of multi-node tests the reference never had
 (SURVEY.md §4): tensor-parallel forward must equal the single-device
-forward; the sharded train step must run and reduce loss; shardings must
-actually partition (not silently replicate).
+forward; shardings must actually partition (not silently replicate); and
+the layout rule (``plan_layout``) as a table, no engine built.
 """
 
 import jax
@@ -14,9 +14,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from agentainer_tpu.models.configs import get_config
 from agentainer_tpu.models.llama import forward, init_params
-from agentainer_tpu.parallel.mesh import make_mesh, pick_tp
+from agentainer_tpu.parallel.mesh import make_mesh, pick_tp, plan_layout
 from agentainer_tpu.parallel.sharding import param_shardings, shard_params
-from agentainer_tpu.train import make_train_step
 
 
 @pytest.fixture(scope="module")
@@ -43,19 +42,20 @@ def test_tp_forward_matches_single_device(eight_devices):
 
     ref_logits, _ = forward(params, cfg, tokens, positions, use_flash=False)
 
-    mesh = make_mesh(8, tp=pick_tp(cfg, 8))  # dp=4, tp=2
+    mesh = make_mesh(tp=pick_tp(cfg, 8))
     sharded = shard_params(params, mesh)
-    tok_sharded = jax.device_put(tokens, NamedSharding(mesh, P("dp", None)))
-    pos_sharded = jax.device_put(positions, NamedSharding(mesh, P("dp", None)))
+    replicated = NamedSharding(mesh, P())
 
     fwd = jax.jit(lambda p, t, pos: forward(p, cfg, t, pos, use_flash=False)[0])
-    tp_logits = fwd(sharded, tok_sharded, pos_sharded)
+    tp_logits = fwd(
+        sharded, jax.device_put(tokens, replicated), jax.device_put(positions, replicated)
+    )
     np.testing.assert_allclose(np.asarray(tp_logits), np.asarray(ref_logits), rtol=2e-4, atol=2e-4)
 
 
 def test_params_actually_partitioned(eight_devices):
     cfg = get_config("tiny")
-    mesh = make_mesh(8, tp=2)
+    mesh = make_mesh(tp=2)
     params = shard_params(init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32), mesh)
     wq = params["layers"]["wq"]  # sharded over tp on last axis
     shard_shapes = {s.data.shape for s in wq.addressable_shards}
@@ -66,30 +66,70 @@ def test_params_actually_partitioned(eight_devices):
     assert {s.data.shape for s in norm.addressable_shards} == {norm.shape}
 
 
-def test_train_step_runs_and_learns(eight_devices):
-    cfg = get_config("tiny")
-    mesh = make_mesh(8, tp=pick_tp(cfg, 8))
-    init_fn, step_fn, shard_batch = make_train_step(cfg, mesh, learning_rate=1e-2)
-    state = init_fn(jax.random.PRNGKey(0))
-    # a tiny repetitive corpus the model should memorize quickly
-    tokens = shard_batch(
-        jnp.tile(jnp.arange(16, dtype=jnp.int32)[None], (8, 1)) % cfg.vocab_size
-    )
-    state, loss0 = step_fn(state, tokens)
-    losses = []
-    for _ in range(10):
-        state, loss = step_fn(state, tokens)
-        losses.append(float(loss))
-    assert np.isfinite(losses).all()
-    assert losses[-1] < float(loss0) * 0.7, (float(loss0), losses)
-    assert int(state.step) == 11
+# (tp, ep) of every registered configuration on an assignment of 1, 2, 4 and
+# 8 chips with no tp/ep option: what LLMEngine.create derived at the commit
+# before the rule moved into plan_layout (PR 28), written down from it.
+ASSIGNED = {
+    "llama3-8b": [(1, 1), (2, 1), (4, 1), (8, 1)],
+    "mixtral-8x7b": [(1, 1), (1, 2), (1, 4), (1, 8)],
+    "olmoe-1b-7b": [(1, 1), (1, 2), (1, 4), (1, 8)],
+    "tiny": [(1, 1), (2, 1), (2, 1), (2, 1)],
+    "tiny-moe": [(1, 1), (1, 2), (1, 4), (2, 4)],
+    "tiny-olmoe": [(1, 1), (1, 2), (1, 4), (1, 8)],
+    "bench-1b": [(1, 1), (2, 1), (4, 1), (8, 1)],
+}
 
 
-def test_moe_train_step_runs(eight_devices):
-    cfg = get_config("tiny-moe")
-    mesh = make_mesh(8, tp=2, ep=2)  # dp=2, tp=2, ep=2
-    init_fn, step_fn, shard_batch = make_train_step(cfg, mesh)
-    state = init_fn(jax.random.PRNGKey(0))
-    tokens = shard_batch(jnp.ones((4, 12), jnp.int32))
-    state, loss = step_fn(state, tokens)
-    assert np.isfinite(float(loss))
+def test_layout_table_covers_every_registered_configuration():
+    from agentainer_tpu.models.configs import list_configs
+
+    assert sorted(ASSIGNED) == sorted(list_configs())
+
+
+@pytest.mark.parametrize("n_chips", [1, 2, 4, 8])
+@pytest.mark.parametrize("config", sorted(ASSIGNED))
+def test_plan_layout_spans_an_assignment(config, n_chips):
+    want = ASSIGNED[config][(1, 2, 4, 8).index(n_chips)]
+    assert plan_layout(get_config(config), n_chips, 8) == want
+
+
+# config, chips assigned (0 = standalone), tp asked, ep asked → (tp, ep), and
+# whether the span is narrower than asked or assigned (the log line)
+ASKED = [
+    # an assignment is the budget; options only narrow it
+    ("tiny-moe", 8, 2, 2, (2, 2), True),
+    ("tiny-moe", 8, 0, 2, (1, 2), True),
+    ("mixtral-8x7b", 8, 2, 0, (2, 4), False),
+    ("mixtral-8x7b", 8, 0, 4, (1, 4), True),
+    ("olmoe-1b-7b", 8, 4, 0, (4, 2), False),
+    ("llama3-8b", 8, 4, 0, (4, 1), True),
+    ("tiny", 2, 1, 0, (1, 1), True),
+    # standalone: exactly what the options ask for, at most what is visible
+    ("tiny", 0, 0, 0, (1, 1), False),
+    ("tiny", 0, 2, 0, (2, 1), False),
+    ("tiny-moe", 0, 2, 2, (2, 2), False),
+    ("tiny-moe", 0, 0, 4, (1, 4), False),
+    ("mixtral-8x7b", 0, 16, 64, (1, 8), False),
+    # asked beyond the heads: clamped to a divisor
+    ("tiny", 0, 16, 0, (2, 1), True),
+    # ep on a dense model is no axis at all
+    ("llama3-8b", 0, 0, 2, (1, 1), True),
+    # counts that divide nothing
+    ("tiny", 3, 0, 0, (1, 1), True),
+    ("tiny-moe", 3, 0, 0, (1, 2), True),
+    ("llama3-8b", 6, 0, 0, (2, 1), True),
+    ("tiny-moe", 4, 3, 3, (2, 2), False),
+]
+
+
+@pytest.mark.parametrize(
+    "config,n_assigned,tp_asked,ep_asked,want,narrowed",
+    ASKED,
+    ids=[f"{c}-chips{n}-tp{t}-ep{e}" for c, n, t, e, _, _ in ASKED],
+)
+def test_plan_layout_options_narrow(
+    config, n_assigned, tp_asked, ep_asked, want, narrowed, capsys
+):
+    got = plan_layout(get_config(config), n_assigned, 8, tp_asked, ep_asked)
+    assert got == want
+    assert ("parallelism narrowed" in capsys.readouterr().out) == narrowed

@@ -8,8 +8,6 @@ were deployed with (the deploy-serves-what-you-named contract,
 serving random weights.
 """
 
-import asyncio
-
 import jax
 import numpy as np
 import pytest
@@ -66,52 +64,3 @@ def test_meshed_synthetic_int8_init_allocates_into_shards():
             assert nbytes < 0.6 * total, (by_dev, total)
     finally:
         engine.shutdown()
-
-
-def test_pp_random_init_allocates_into_stages():
-    engine = LLMEngine.create("tiny", options={"pp": 2, "max_batch": 2, "max_seq": 128})
-    try:
-        total = sum(x.nbytes for x in jax.tree.leaves(engine.params))
-        by_dev = _per_device_bytes(engine.params)
-        assert len(by_dev) == 2
-        for nbytes in by_dev.values():
-            assert nbytes < 0.6 * total, (by_dev, total)
-    finally:
-        engine.shutdown()
-
-
-def test_pp_engine_loads_checkpoint(tmp_path):
-    """pp=2 engine deployed from a converted checkpoint serves the SAME
-    tokens as the single-chip engine from that checkpoint."""
-    from agentainer_tpu.engine.checkpoint import save_params
-    from agentainer_tpu.models.configs import get_config
-    from agentainer_tpu.models.llama import init_params
-
-    cfg = get_config("tiny")
-    # a DIFFERENT seed than engines' default PRNGKey(0): token equality
-    # below can only come from actually loading the checkpoint
-    params = init_params(cfg, jax.random.PRNGKey(7), dtype=jax.numpy.float32)
-    ckpt = tmp_path / "ckpt"
-    save_params(params, ckpt)
-
-    e1 = LLMEngine.create(
-        "tiny", checkpoint=str(ckpt), options={"max_batch": 2, "max_seq": 128}
-    )
-    e2 = LLMEngine.create(
-        "tiny", checkpoint=str(ckpt), options={"pp": 2, "max_batch": 2, "max_seq": 128}
-    )
-    try:
-
-        async def go(e):
-            r = await e.chat(session="s", message="the quick brown fox", max_tokens=8)
-            return r["tokens"]
-
-        t1 = asyncio.run(go(e1))
-        t2 = asyncio.run(go(e2))
-        assert t1 == t2, (t1, t2)
-        # staged placement: each stage holds half the layer stack
-        wq = e2.params["layers"]["wq"]
-        assert wq.sharding.shard_shape(wq.shape)[0] == cfg.n_layers // 2
-    finally:
-        e1.shutdown()
-        e2.shutdown()

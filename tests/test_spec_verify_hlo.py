@@ -42,7 +42,7 @@ def _verify_attention(q, k_new, v_new, ck, cv, positions):
 
 
 def _compile_verify(tp: int) -> str:
-    mesh = make_mesh(tp, tp=tp)
+    mesh = make_mesh(tp=tp)
     head_sh = NamedSharding(mesh, P(None, None, "tp", None))
     repl = NamedSharding(mesh, P())
     ck = jax.device_put(jnp.ones((B, S, KV, HD), jnp.float32), head_sh)
@@ -74,7 +74,7 @@ def test_tp_verify_numerics_match_unsharded():
     pos = jnp.broadcast_to(jnp.arange(40, 40 + K + 1, dtype=jnp.int32), (B, K + 1))
     want = _verify_attention(q, k_new, v_new, ck, cv, pos)
 
-    mesh = make_mesh(2, tp=2)
+    mesh = make_mesh(tp=2)
     head_sh = NamedSharding(mesh, P(None, None, "tp", None))
     repl = NamedSharding(mesh, P())
     got = jax.jit(_verify_attention)(

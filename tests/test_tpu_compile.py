@@ -137,20 +137,15 @@ def test_meshed_flash_compiles_for_v5e_2x2(v5e, t):
     """The tp=4 engine's attention: the dense kernels per device under
     shard_map on the described 2x2 mesh, compiled (not interpreted)."""
     h, kv = HEADS["one-chip"]
-    mesh = Mesh(
-        np.array(v5e.devices).reshape(1, 4, 1, 1, 1),
-        axis_names=("dp", "tp", "sp", "ep", "pp"),
-    )
-    heads = NamedSharding(mesh, P("dp", None, "tp", None))
-    stack = NamedSharding(mesh, P(None, "dp", None, "tp", None))
+    mesh = Mesh(np.array(v5e.devices).reshape(4, 1), axis_names=("tp", "ep"))
+    heads = NamedSharding(mesh, P(None, None, "tp", None))
+    stack = NamedSharding(mesh, P(None, None, None, "tp", None))
     b = B if t == 1 else 1
     args = (
         jax.ShapeDtypeStruct((b, t, h, HD), jnp.bfloat16, sharding=heads),
         jax.ShapeDtypeStruct((LAYERS, b, S, kv, HD), jnp.bfloat16, sharding=stack),
         jax.ShapeDtypeStruct((LAYERS, b, S, kv, HD), jnp.bfloat16, sharding=stack),
-        jax.ShapeDtypeStruct(
-            (b, t), jnp.int32, sharding=NamedSharding(mesh, P("dp", None))
-        ),
+        jax.ShapeDtypeStruct((b, t), jnp.int32, sharding=NamedSharding(mesh, P())),
         None,  # no block table
         jax.ShapeDtypeStruct((), jnp.int32, sharding=NamedSharding(mesh, P())),
         None,  # no slot: the batch is the arena's rows

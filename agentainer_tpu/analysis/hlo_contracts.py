@@ -253,6 +253,37 @@ class ArenaRidesInCarry:
         return out
 
 
+@dataclass
+class ExpertsSeeOnlyTheirRows:
+    """A step program over the cut (``ops/moe.sorted_from_rows``) computes
+    only the (token, chosen expert) pairs: over the LOWERED (StableHLO) text
+    of ``jit_prefill``. No value holds an F-wide activation of every row for
+    every expert (``[rows, E, F]``, what the all-experts einsum's gate and up
+    projections produce). That a grouped matmul stands in its place is the
+    traced program's to show (``ragged_dot`` off the TPU, which StableHLO
+    spells out, the ``moe_grouped_ffn`` kernel on it), and that nothing of
+    expert size is copied on the way the compiled one's
+    (tests/test_tpu_compile.py)."""
+
+    rows: int
+    experts: int
+    ffn: int
+
+    def failures(self, text: str) -> list[str]:
+        out: list[str] = []
+        want = (self.rows, self.experts, self.ffn)
+        wide = {
+            t for t in re.findall(r"tensor<[^>]*>", text)
+            if "x" in t and (dims := _tensor_dims(t))[-3:] == want and math.prod(dims) == math.prod(want)
+        }
+        if wide:
+            out.append(
+                f"values of shape {sorted(wide)}: every row goes through every "
+                f"expert ({self.experts} × the rows of FLOPs the router asked for)"
+            )
+        return out
+
+
 def check(hlo: str, *contracts) -> None:
     """Assert every contract against one compiled-HLO text."""
     problems: list[str] = []

@@ -55,8 +55,17 @@ jax.config.update("jax_threefry_partitionable", True)
 from .. import faults
 from ..core.spec import unserved_layout
 from ..models.configs import ModelConfig, get_config
-from ..models.llama import KVCache, PagedKVCache, forward, init_params
+from ..models.llama import (
+    KVCache,
+    PagedKVCache,
+    _moe_mlp,
+    _moe_mlp_routed,
+    forward,
+    init_params,
+    moe_sorted_from,
+)
 from ..ops.attention import pages_to_rows, plan_cache_attention, rows_to_pages
+from ..ops.moe import sorted_rows
 from ..utils.spans import Spans
 from .sampling import NEG_INF, sample, sample_step
 from .tokenizer import load_tokenizer
@@ -834,6 +843,10 @@ class LLMEngine:
         self.prefill_launches = 0
         self.prefill_tokens = 0
         self.requests_finished = 0
+        # passes through the model's layers launched so far, every step
+        # program counted (a decode launch of n steps is n): what a device
+        # trace's size follows (``h_profile`` bounds a capture by it)
+        self.forward_passes = 0
         self.ttft_ms_recent: collections.deque[float] = collections.deque(maxlen=256)
         self.itl_ms_recent: collections.deque[float] = collections.deque(maxlen=256)
         # TTFT phase decomposition: queue-wait (admission → first prefill
@@ -1230,6 +1243,12 @@ class LLMEngine:
         )
         cache_attn_impl = attn.fn
 
+        # the MoE FFN the steps trace. One chip, no option: ``forward`` itself
+        # splits at the chip's ridge (ops/moe.sorted_from_rows) — calls under
+        # it (decode, verify, short buckets) trace the all-experts einsum,
+        # longer prefill chunks the sorted grouped FFN over the int8 stack.
+        # A mesh pins one path: GSPMD cannot partition the grouped kernel, so
+        # ``tp`` keeps the einsum and ``ep`` its shard_map'd dispatch.
         moe_impl = None
         if self.routed_moe:
             if self.mesh is not None and self.ep > 1:
@@ -1239,29 +1258,47 @@ class LLMEngine:
                     self.mesh, cfg, capacity_factor=self.moe_capacity_factor
                 )
             else:
-                from functools import partial as _partial
-
-                from ..models.llama import _moe_mlp_routed
-
-                moe_impl = _partial(
+                moe_impl = functools.partial(
                     _moe_mlp_routed,
                     cfg=cfg,
                     capacity_factor=self.moe_capacity_factor,
                 )
-        self.routed_moe = moe_impl is not None
+        elif cfg.is_moe and self.mesh is not None:
+            moe_impl = functools.partial(_moe_mlp, cfg=cfg)
+        impl = (
+            "none" if not cfg.is_moe
+            else "routed_dispatch" if self.routed_moe
+            else "all_experts_einsum"
+        )
+        # rows a call needs to take the sorted FFN (no call under a pinned path)
+        self._moe_sorted_from = (
+            moe_sorted_from(cfg, self.params["layers"]) if moe_impl is None else None
+        )
         self.moe = {
-            "impl": (
-                "none" if not cfg.is_moe
-                else "all_experts_einsum" if moe_impl is None
-                else "routed_dispatch"
-            ),
+            # the path of the calls under ``routed_from_rows`` (every call
+            # where that is null), and the model's routing shape
+            "impl": impl,
             "experts": cfg.n_experts,
             "top_k": cfg.experts_per_token if cfg.is_moe else 0,
             "renormalize": bool(cfg.is_moe and cfg.moe_renormalize),
+            # the path of the calls with that many rows (B·T) or more
+            "prefill_impl": impl if self._moe_sorted_from is None else "sorted_grouped_ffn",
+            "routed_from_rows": self._moe_sorted_from,
+            # cumulative, counted on the host at each launch from its static
+            # shapes (``_count_forward``): Σ N·k; Σ N·E over launches under the
+            # cut; Σ rows the grouped FFN was given, tile padding included
+            "assignments": 0,
+            "rows_all_experts": 0,
+            "rows_routed": 0,
         }
         if cfg.is_moe:
+            served = (
+                "every call" if self._moe_sorted_from is None
+                else f"calls under {self._moe_sorted_from} rows; "
+                f"prefill_impl={self.moe['prefill_impl']} from there"
+            )
             print(
-                f"[llm-engine] moe: impl={self.moe['impl']} experts={cfg.n_experts} "
+                f"[llm-engine] moe: impl={impl} ({served}) experts={cfg.n_experts} "
                 f"top_k={cfg.experts_per_token} renormalize={cfg.moe_renormalize} "
                 f"qk_norm={cfg.qk_norm}",
                 flush=True,
@@ -3305,7 +3342,7 @@ class LLMEngine:
             "engine_devices": [self._device_doc(d) for d in self._devices],
             "attention": self.attention,
             # which MoE path the compiled steps trace, and the block's shape
-            "moe": self.moe,
+            "moe": dict(self.moe),
             "model_arch": {
                 "layers": self.cfg.n_layers,
                 "dim": self.cfg.dim,
@@ -4275,6 +4312,22 @@ class LLMEngine:
             self.sessions[session] = slot.idx
         return slot
 
+    def _count_forward(self, rows: int, passes: int = 1) -> None:
+        """A launch of ``passes`` passes through the layers, ``rows = B·T``
+        rows each, counted from static shapes only: ``forward_passes``, and
+        for an MoE model the ``moe`` block's counters — what the algorithm
+        asked for and what the path that served it executed (the ``routed``
+        option's buffers are not counted)."""
+        self.forward_passes += passes
+        if not self.cfg.is_moe:
+            return
+        e, k = self.cfg.n_experts, self.cfg.experts_per_token
+        self.moe["assignments"] += passes * rows * k
+        if self._moe_sorted_from is not None and rows >= self._moe_sorted_from:
+            self.moe["rows_routed"] += passes * sorted_rows(rows, e, k)
+        elif not self.routed_moe:
+            self.moe["rows_all_experts"] += passes * rows * e
+
     def _bucket(self, n: int) -> int:
         for b in PREFILL_BUCKETS:
             if n <= b:
@@ -4379,6 +4432,7 @@ class LLMEngine:
                 )
         self.prefill_launches += 1
         self.prefill_tokens += n
+        self._count_forward(bucket)
         # n real tokens, each attending ~its own position of context
         self.flops_done += n * self.cfg.flops_per_token(slot.position + n // 2)
         self.hbm_bytes_read += self.param_hbm_bytes + (
@@ -4604,6 +4658,7 @@ class LLMEngine:
             r.dispatched += chunk
         self.decode_chunk_hist[chunk] = self.decode_chunk_hist.get(chunk, 0) + 1
         self.decode_steps += 1
+        self._count_forward(self.max_batch, chunk)
         self._occupancy_sum += len(snapshot) / self.max_batch
         # weights stream once per scan step; each live lane streams its KV
         # prefix (parked lanes re-read the scratch row — not useful traffic)
@@ -4739,6 +4794,8 @@ class LLMEngine:
         self.fused_loops_total += 1
         self.decode_chunk_hist[chunk] = self.decode_chunk_hist.get(chunk, 0) + 1
         self.decode_steps += 1
+        # the loop's cap: it may stop early, and an in-loop verify is wider
+        self._count_forward(self.max_batch, chunk)
         self._occupancy_sum += len(snapshot) / self.max_batch
         try:
             packed.copy_to_host_async()
@@ -5085,6 +5142,7 @@ class LLMEngine:
                     key,
                 )
             )
+        self._count_forward(self.max_batch * (K + 1))
         with self._spans.span("engine.verify_readback"):
             self._verify_readback(plan, K, dlen, emitted_dev, count_dev)
 

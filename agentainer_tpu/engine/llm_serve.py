@@ -27,6 +27,9 @@ from ..runtime.store_client import StoreClient
 from ..utils.compile_cache import CompileCacheStats, compile_cache_dir, enable_compile_cache
 
 MAX_TURNS = 50
+# layer-steps (passes through the model × its layers) a /profile capture holds
+# at most: stop_trace collects about 100 of them a second (h_profile)
+PROFILE_LAYER_STEPS = 3_000
 # per-session conversation lists carry a sliding TTL: session ids are
 # client-supplied, so without one every ephemeral session would leave a
 # permanent (ltrim-bounded) list behind — the old shared key was bounded
@@ -1405,9 +1408,25 @@ class LLMServeApp:
             await asyncio.to_thread(
                 jax.profiler.start_trace, trace_dir, profiler_options=options
             )
+            # stop_trace's collection grows with the device events captured,
+            # not with the seconds: about 10 ms a layer-step of a prefill-heavy
+            # mix (measured on a v5e host: 66 s for the 383 passes × 16 layers
+            # of a 5 s window at 6.7 long requests a second, 21 s for 126
+            # passes), and the callers in front wait 60 s. So a capture ends
+            # at ``duration`` or once the engine has launched
+            # PROFILE_LAYER_STEPS (half that wait), whichever comes first;
+            # ``duration_s`` in the answer is what was captured.
+            t0 = time.monotonic()
+            first = self.engine.forward_passes
+            room = PROFILE_LAYER_STEPS / self.engine.cfg.n_layers
             try:
-                await asyncio.sleep(duration)
+                while (
+                    time.monotonic() - t0 < duration
+                    and self.engine.forward_passes - first < room
+                ):
+                    await asyncio.sleep(min(0.05, duration))
             finally:
+                duration = time.monotonic() - t0
                 await asyncio.to_thread(jax.profiler.stop_trace)
         except Exception as e:
             return web.json_response(

@@ -32,8 +32,9 @@ from ..ops.attention import (
     plan_cache_attention,
     scatter_paged_kv,
 )
+from ..ops.moe import EXPERT_WEIGHTS, sorted_from_rows, sorted_moe_ffn, stacked_experts
 from ..ops.norms import rms_norm
-from ..ops.quant import dequant, embed_lookup
+from ..ops.quant import QTensor, dequant, embed_lookup
 from ..ops.rope import apply_rope
 from .configs import ModelConfig
 
@@ -145,12 +146,14 @@ def moe_gates(logits: jnp.ndarray, cfg: ModelConfig, dtype) -> tuple[jnp.ndarray
 
 
 def _moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig) -> jnp.ndarray:
-    """Dense-einsum MoE (top-k routing, all experts computed, masked combine).
+    """All-experts einsum MoE (top-k routing, every expert computed, masked
+    combine): exact, dropless, branch-free, and E/k× the routed FLOPs.
 
-    Simple and branch-free, but ~E/k× the routed FLOPs — the single-chip
-    fallback. The routed path (`_moe_mlp_routed`, and parallel/expert.py
-    under a mesh) computes only dispatched tokens and is the serving
-    default wherever ep > 1.
+    Serves every call that is weight-bound anyway — decode, speculation's
+    verify, the short prefill buckets: fewer rows than
+    ``ops/moe.sorted_from_rows`` — on one chip, and every call of a ``tp``
+    mesh. Calls with more rows on one chip take ``_moe_mlp_sorted``; an
+    ``ep > 1`` mesh (or the ``routed`` option) takes ``_moe_mlp_routed``.
     """
     b, t, d = x.shape
     weights, chosen = moe_gates(x @ lp["router"], cfg, x.dtype)  # [B,T,K]
@@ -160,6 +163,39 @@ def _moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig) -> jnp.ndarray:
     up = jnp.einsum("btd,edf->btef", x, lp["w_up"])
     expert_out = jnp.einsum("btef,efd->bted", gate * up, lp["w_down"])
     return jnp.einsum("bted,bte->btd", expert_out, combine)
+
+
+def _moe_mlp_sorted(
+    x: jnp.ndarray, lp: dict, cfg: ModelConfig, experts: dict, layer
+) -> jnp.ndarray:
+    """The MoE FFN of a call with many rows (a prefill chunk): the same
+    router and the same sum as ``_moe_mlp``, computing only the (token,
+    chosen expert) pairs — sorted by expert, one grouped FFN over the
+    stacked ``experts`` (``ops/moe.stacked_experts``: int8 as stored, layer
+    ``layer`` of them read in place). Exact top-k and dropless at any skew;
+    a bucket's padding rows are routed like any other row."""
+    b, t, d = x.shape
+    xf = x.reshape(b * t, d)
+    gates, chosen = moe_gates(xf @ lp["router"], cfg, x.dtype)
+    return sorted_moe_ffn(xf, gates, chosen, experts, layer).reshape(b, t, d)
+
+
+def moe_sorted_from(cfg: ModelConfig, layers: dict) -> int | None:
+    """The row count ``B·T`` from which a call sorts its MoE FFN: the rule
+    (``ops/moe.sorted_from_rows``) applied to this model's experts as
+    ``layers`` stores them; ``None`` where no call does."""
+    if not cfg.is_moe:
+        return None
+    w = layers["w_gate"]
+    dtype = w.q.dtype if isinstance(w, QTensor) else w.dtype
+    return sorted_from_rows(cfg.n_experts, cfg.experts_per_token, dtype)
+
+
+def moe_sorts(cfg: ModelConfig, layers: dict, n_rows: int) -> bool:
+    """Whether a call of ``n_rows`` rows sorts its MoE FFN. Static: decided
+    when a step is traced."""
+    cut = moe_sorted_from(cfg, layers)
+    return cut is not None and n_rows >= cut
 
 
 # token counts at or below this run routed MoE with cap = n (dropless) even
@@ -189,7 +225,10 @@ def _moe_mlp_routed(
     base: int = 0,
 ) -> jnp.ndarray:
     """Top-k token-dispatch MoE — GShard-style one-hot dispatch/combine
-    einsums (static shapes, MXU matmuls, no gather/scatter).
+    einsums (static shapes, MXU matmuls, no gather/scatter). The ``routed``
+    option's path and, through parallel/expert.py, the ``ep > 1`` mesh's; no
+    default one-chip engine runs it (prefill there is ``_moe_mlp_sorted``,
+    which drops nothing).
 
     Computes ONLY routed (token, expert) work: per-token MLP FLOPs are
     ∝ k·capacity_factor, not E — the dense ``_moe_mlp`` computes every
@@ -341,9 +380,18 @@ def forward(
     returned is the updated pool.
     Without: pure causal self-attention over the tokens given (what tests
     compare the cached path with).
-    ``moe_impl`` overrides the MoE MLP (routed token-dispatch, meshed EP).
+    ``moe_impl`` overrides the MoE MLP (routed token-dispatch, meshed EP,
+    the einsum pinned under a ``tp`` mesh). Without one the call's static
+    row count decides (``moe_sorts``): few rows take the all-experts einsum,
+    many the sorted grouped FFN, which reads the expert stack in place — so
+    the experts then stay out of the layer scan's slices altogether.
     """
     x = embed_lookup(params["embed"], tokens)
+    lp_stack = params["layers"]
+    experts = None
+    if moe_impl is None and moe_sorts(cfg, lp_stack, tokens.shape[0] * tokens.shape[1]):
+        experts = stacked_experts(lp_stack)
+        lp_stack = {k: v for k, v in lp_stack.items() if k not in EXPERT_WEIGHTS}
     if cache is not None:
         mask = None  # arena attention masks from positions (in-kernel on TPU)
     else:
@@ -363,13 +411,14 @@ def forward(
             slot=slot,
         )
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        if cfg.is_moe:
+        if experts is not None:
+            x = x + _moe_mlp_sorted(h, lp, cfg, experts, layer)
+        elif cfg.is_moe:
             x = x + (moe_impl(h, lp) if moe_impl is not None else _moe_mlp(h, lp, cfg))
         else:
             x = x + _mlp(h, lp)
         return x, ck, cv
 
-    lp_stack = params["layers"]
     if cache is not None:
         def layer_step(carry, inputs):
             lp, layer = inputs
@@ -381,8 +430,9 @@ def forward(
         )
         new_cache = type(cache)(new_k, new_v)
     else:
+        layers = jnp.arange(cfg.n_layers, dtype=jnp.int32)
         x, _ = lax.scan(
-            lambda x, lp: (block(x, None, None, lp, None)[0], None), x, lp_stack
+            lambda x, inp: (block(x, None, None, *inp)[0], None), x, (lp_stack, layers)
         )
         new_cache = None
 

@@ -18,6 +18,9 @@ now consume the same module instead of three copies of the scan.
 """
 
 import asyncio
+import hashlib
+import json
+import os
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +30,7 @@ from agentainer_tpu.analysis.hlo_contracts import (
     ArenaRidesInCarry,
     ContractViolation,
     DonationAliased,
+    ExpertsSeeOnlyTheirRows,
     HasCrossReduction,
     NoLargeAllGather,
     check,
@@ -277,6 +281,78 @@ def test_arena_contract_catches_the_xs_ys_scan():
     with pytest.raises(ContractViolation, match="new rows"):
         check(old.as_text(), contract)
     check(jax.jit(new_form, donate_argnums=(0, 1)).lower(*args).as_text(), contract)
+
+
+# ---------------------------------------------------------------------------
+# the MoE FFN splits at the chip's ridge (ISSUE 29): over it a prefill chunk
+# computes only the routed pairs, under it every step program is the parent's
+
+
+MOE_OPTIONS = {
+    "max_batch": 4, "max_seq": 256, "decode_chunk": 8, "prefill_chunk": 256, "skip_warmup": True,
+}
+PARENT_PROGRAMS = json.load(
+    open(os.path.join(os.path.dirname(__file__), "data", "step_programs_parent_pr28.json"))
+)
+
+
+@pytest.fixture(scope="module")
+def moe_engine():
+    """``moe_engine(model, weights)``: one engine per pair for the module,
+    built on first use. ``float`` is this CPU's float32 experts (cut 482
+    rows), ``int8`` the served kind (cut 121)."""
+    built = {}
+
+    def get(model: str, weights: str):
+        if (model, weights) not in built:
+            quant = {"quant": "int8"} if weights == "int8" else {}
+            built[model, weights] = LLMEngine.create(model, options={**MOE_OPTIONS, **quant})
+        return built[model, weights]
+
+    yield get
+    for eng in built.values():
+        eng.shutdown()
+
+
+def _prefill_args(eng, t: int):
+    tokens = jnp.zeros((1, t), jnp.int32)
+    return eng.params, eng.cache, jnp.int32(1), tokens, tokens, jnp.int32(4)
+
+
+def _moe_step_lowering(eng, program: str):
+    if program.startswith("jit_prefill."):
+        return eng._prefill.lower(*_prefill_args(eng, int(program.split(".")[1])))
+    return _step_lowering(eng, program)[0]
+
+
+@pytest.mark.parametrize("key", sorted(PARENT_PROGRAMS))
+def test_steps_under_the_cut_lower_to_the_parents_programs(moe_engine, key):
+    """``jit_decode_n``, ``jit_verify`` and every ``jit_prefill`` bucket under
+    the cut lower to the StableHLO the parent commit (4711f7f, PR 28) lowered
+    on this backend, byte for byte: sha256 of the location-free text, taken
+    there with this file's own lowering calls. A later PR that changes a
+    step program on purpose regenerates the file and says why."""
+    model, weights, program = key.split(".", 2)
+    text = _moe_step_lowering(moe_engine(model, weights), program).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_PROGRAMS[key]
+
+
+@pytest.mark.parametrize("model", ["tiny-moe", "tiny-olmoe"])
+def test_prefill_over_the_cut_computes_only_the_routed_pairs(moe_engine, model):
+    """Bucket 256 of an int8 MoE engine: no ``[256, E, F]`` activation, a
+    grouped matmul in the traced program instead; bucket 64 of the same
+    engine still holds the all-experts form, so the contract can tell the
+    two apart."""
+    eng = moe_engine(model, "int8")
+    contract = lambda t: ExpertsSeeOnlyTheirRows(t, eng.cfg.n_experts, eng.cfg.ffn_dim)  # noqa: E731
+    over = _moe_step_lowering(eng, "jit_prefill.256").as_text()
+    assert "module @jit_prefill" in over
+    check(over, contract(256))
+    assert "ragged_dot" in str(eng._prefill.trace(*_prefill_args(eng, 256)).jaxpr)
+    under = _moe_step_lowering(eng, "jit_prefill.64").as_text()
+    with pytest.raises(ContractViolation, match="every row goes through every expert"):
+        check(under, contract(64))
+    assert "ragged_dot" not in str(eng._prefill.trace(*_prefill_args(eng, 64)).jaxpr)
 
 
 # ---------------------------------------------------------------------------

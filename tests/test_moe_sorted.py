@@ -1,0 +1,245 @@
+"""The sorted grouped MoE FFN (ISSUE 29): prefill stops computing every expert.
+
+Above the chip's ridge (``ops/moe.sorted_from_rows``) a call's MoE FFN sorts
+its (token, chosen expert) assignments by expert and runs one grouped FFN
+over the stacked expert weights, int8 as stored. Invariants held here, on
+the CPU: it is the all-experts einsum's function (same router, exact top-k,
+nothing dropped at any skew, padding rows harmless); the Pallas kernel
+(interpret mode) and the ``ragged_dot`` form agree; the cut is one function
+of static shapes; the engine's ``moe`` counters say how often it engages.
+"""
+
+import asyncio
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from agentainer_tpu.engine.llm import LLMEngine
+from agentainer_tpu.models.configs import get_config
+from agentainer_tpu.models.llama import (
+    _moe_mlp,
+    _moe_mlp_routed,
+    forward,
+    init_params,
+    moe_gates,
+    moe_sorts,
+)
+from agentainer_tpu.ops.moe import (
+    EXPERT_WEIGHTS,
+    route,
+    row_tile,
+    sorted_from_rows,
+    sorted_moe_ffn,
+    sorted_rows,
+    stacked_experts,
+)
+from agentainer_tpu.ops.quant import QTensor, dequant, quantize_array
+
+
+def _layers(cfg, weights: str, scale: float = 1.0):
+    """The stacked ``layers`` pytree with the experts stored as ``weights``
+    (``float32``, ``bfloat16`` or ``int8`` QTensors), router in float32."""
+    params = init_params(cfg, jax.random.PRNGKey(3), dtype=jnp.float32)
+    layers = {k: params["layers"][k] * scale for k in ("router", *EXPERT_WEIGHTS)}
+    for name in EXPERT_WEIGHTS:
+        if weights == "int8":
+            layers[name] = quantize_array(np.asarray(layers[name]), jnp.float32)
+        else:
+            layers[name] = layers[name].astype(weights)
+    return layers
+
+
+def _layer(layers, l):
+    """Layer ``l`` as ``forward``'s block sees it: sliced and dequantised."""
+    return {
+        k: dequant(QTensor(v.q[l], v.scale[l]) if isinstance(v, QTensor) else v[l])
+        for k, v in layers.items()
+    }
+
+
+def _sorted(x, layers, cfg, l, **how):
+    lp = _layer(layers, l)
+    xf = x.reshape(-1, x.shape[-1])
+    gates, chosen = moe_gates(xf @ lp["router"], cfg, x.dtype)
+    out = sorted_moe_ffn(xf, gates, chosen, stacked_experts(layers), jnp.int32(l), **how)
+    return out.reshape(x.shape)
+
+
+TOL = {"float32": 2e-6, "bfloat16": 2e-2, "int8": 2e-6}
+
+
+@pytest.mark.parametrize("how", ["ragged_dot", "pallas_interpret"])
+@pytest.mark.parametrize("weights", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("model", ["tiny-moe", "tiny-olmoe"])
+def test_sorted_ffn_is_the_all_experts_einsum(model, weights, how):
+    """Both forms of the grouped FFN against ``_moe_mlp`` on the layer's
+    dequantised weights, at the second layer of the stack (the kernel finds
+    it by index), Mixtral's routing (renormalised top-2 of 4) and OLMoE's
+    (un-renormalised top-2 of 8)."""
+    cfg = get_config(model)
+    layers = _layers(cfg, weights, scale=8.0)
+    act = jnp.bfloat16 if weights == "bfloat16" else jnp.float32
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, 160, cfg.dim), jnp.float32).astype(act)
+    layers["router"] = layers["router"].astype(act)
+    want = _moe_mlp(x, _layer(layers, 1), cfg).astype(jnp.float32)
+    kw = {"kernel": False} if how == "ragged_dot" else {"interpret": True}
+    got = _sorted(x, layers, cfg, 1, **kw).astype(jnp.float32)
+    err = float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+    assert err < TOL[weights], err
+
+
+@pytest.mark.parametrize("model", ["tiny-moe", "tiny-olmoe"])
+def test_every_row_on_the_same_experts_drops_nothing(model):
+    """The dropless proof: a zero router ties every logit, so every row
+    chooses experts 0 .. k-1 and each of them gets all N rows. The sorted FFN
+    still equals ``_moe_mlp``; the capacity-bound dispatch at its default
+    factor 2 loses rows and does not (with 2 of 4 experts a factor of 2 is
+    already every row, so ``tiny-moe`` shows the loss at factor 1)."""
+    cfg = get_config(model)
+    layers = _layers(cfg, "float32", scale=8.0)
+    layers["router"] = jnp.zeros_like(layers["router"])
+    x = jax.random.normal(jax.random.PRNGKey(7), (1, 128, cfg.dim), jnp.float32)
+    lp = _layer(layers, 0)
+    _, chosen = moe_gates(x[0] @ lp["router"], cfg, x.dtype)
+    assert np.asarray(chosen).tolist() == [list(range(cfg.experts_per_token))] * 128
+    want = _moe_mlp(x, lp, cfg)
+    for kw in ({"kernel": False}, {"interpret": True}):
+        got = _sorted(x, layers, cfg, 0, **kw)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5 * float(jnp.max(jnp.abs(want))))
+    factor = 2.0 if cfg.n_experts > 2 * cfg.experts_per_token else 1.0
+    dropped = _moe_mlp_routed(x, lp, cfg, capacity_factor=factor)
+    assert not np.allclose(np.asarray(dropped), np.asarray(want), atol=1e-3 * float(jnp.max(jnp.abs(want))))
+
+
+@pytest.mark.parametrize("n, n_experts, k", [(128, 8, 2), (256, 8, 2), (256, 64, 8), (1024, 64, 8), (130, 4, 2)])
+def test_route_places_every_assignment_once_in_its_experts_tiles(n, n_experts, k):
+    """The layout the kernel relies on: every assignment has one row, a row
+    tile holds one expert's rows only, used tiles come first, and the static
+    buffer holds the worst routing (random, and all rows on the same k)."""
+    tile, m = row_tile(n, n_experts, k), sorted_rows(n, n_experts, k)
+    assert m % tile == 0 and tile in (32, 64, 128)
+    rng = np.random.default_rng(n + n_experts)
+    random = np.stack([rng.permutation(n_experts)[:k] for _ in range(n)])
+    skewed = np.tile(np.arange(k), (n, 1))
+    for chosen in (random, skewed):
+        r = route(jnp.asarray(chosen, jnp.int32), n_experts, tile, m)
+        row_of = np.asarray(r.row_of)
+        n_active = int(r.n_active)
+        assert len(set(row_of.tolist())) == n * k and row_of.max() < n_active * tile <= m
+        tile_expert = np.asarray(r.tile_expert)
+        assert (tile_expert[row_of // tile] == chosen.reshape(-1)).all()
+        assert (tile_expert[n_active:] == tile_expert[n_active - 1]).all()
+        used = np.bincount(tile_expert[:n_active], minlength=n_experts)
+        counts = np.bincount(chosen.reshape(-1), minlength=n_experts)
+        assert (used == -(-counts // tile)).all()
+
+
+def test_the_cut_is_the_devices_ridge():
+    """One function of (E, k, stored dtype, device peaks): v5e int8 → 121
+    rows (197e12 ÷ (2 × 819e9) = 120.3), twice that for bf16; a device with
+    another ridge moves it; a CPU reads as the v5e; k = E never sorts."""
+    assert sorted_from_rows(8, 2, jnp.int8, "TPU v5 lite") == 121
+    assert sorted_from_rows(64, 8, jnp.int8, "TPU v5 lite") == 121
+    assert sorted_from_rows(8, 2, jnp.bfloat16, "TPU v5 lite") == 241
+    assert sorted_from_rows(8, 2, jnp.float32, "TPU v5 lite") == 482
+    assert sorted_from_rows(8, 2, jnp.int8, "TPU v6 lite") == 280
+    assert sorted_from_rows(8, 2, jnp.int8, "cpu") == 121
+    assert sorted_from_rows(8, 2, jnp.int8) == 121  # this process: a CPU
+    assert sorted_from_rows(4, 4, jnp.int8, "TPU v5 lite") is None
+
+
+@pytest.mark.parametrize("rows, sorts", [(120, False), (121, True)])
+def test_forward_takes_the_path_its_row_count_says(rows, sorts):
+    """Just under and just over the cut, no option: the traced program holds
+    the grouped matmul (``ragged_dot`` off the TPU) or the all-experts einsum,
+    and gives the einsum's logits either way."""
+    cfg = get_config("tiny-moe")
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    for name in EXPERT_WEIGHTS:
+        params["layers"][name] = quantize_array(np.asarray(params["layers"][name]), jnp.float32)
+    assert moe_sorts(cfg, params["layers"], rows) is sorts
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (1, rows), 0, cfg.vocab_size)
+    positions = jnp.arange(rows)[None]
+    jaxpr = str(jax.make_jaxpr(lambda p: forward(p, cfg, tokens, positions)[0])(params))
+    assert ("ragged_dot" in jaxpr) is sorts
+    got, _ = forward(params, cfg, tokens, positions)
+    want, _ = forward(params, cfg, tokens, positions, moe_impl=partial(_moe_mlp, cfg=cfg))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+
+
+def test_a_buckets_padding_rows_change_no_real_row():
+    """A 256-token bucket holding 200 real tokens: the padding rows are
+    routed like any other row (they take buffer rows and tiles), and the
+    real rows' logits are those of the 200 tokens alone."""
+    cfg = get_config("tiny-olmoe")
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    for name in EXPERT_WEIGHTS:
+        params["layers"][name] = quantize_array(np.asarray(params["layers"][name]), jnp.float32)
+    real = jax.random.randint(jax.random.PRNGKey(2), (1, 200), 1, cfg.vocab_size)
+    padded = jnp.concatenate([real, jnp.zeros((1, 56), jnp.int32)], axis=1)
+    assert moe_sorts(cfg, params["layers"], 256) and moe_sorts(cfg, params["layers"], 200)
+    got, _ = forward(params, cfg, padded, jnp.arange(256)[None])
+    want, _ = forward(params, cfg, real, jnp.arange(200)[None], moe_impl=partial(_moe_mlp, cfg=cfg))
+    np.testing.assert_allclose(np.asarray(got[:, :200]), np.asarray(want), atol=2e-5)
+
+
+def test_the_moe_counters_follow_the_launches():
+    """``/metrics`` ``moe``: the cut, the path's name, and three cumulative
+    counts taken from static shapes at each launch. A 401-token prompt is two
+    prefill launches of bucket 256 (both sorted), a short one a bucket of 32
+    (einsum); decode launches are ``max_batch`` rows a step."""
+    cfg = get_config("tiny-moe")
+    e, k, batch = cfg.n_experts, cfg.experts_per_token, 4
+    eng = LLMEngine.create(
+        "tiny-moe",
+        options={"max_batch": batch, "max_seq": 1024, "decode_chunk": 8, "prefill_chunk": 256,
+                 "quant": "int8", "speculative": False},
+    )
+    try:
+        def snap():
+            m = eng.metrics()
+            return m["moe"], sum(int(c) * n for c, n in m["decode_chunk_hist"].items())
+
+        moe0, steps0 = snap()
+        assert moe0["impl"] == "all_experts_einsum" and moe0["prefill_impl"] == "sorted_grouped_ffn"
+        assert moe0["routed_from_rows"] == 121
+        asyncio.run(eng.generate("word " * 80, max_tokens=8, ignore_eos=True))  # 401 tokens
+        moe1, steps1 = snap()
+        asyncio.run(eng.generate("hi", max_tokens=8, ignore_eos=True))  # 3 tokens: bucket 32
+        moe2, steps2 = snap()
+    finally:
+        eng.shutdown()
+    d1 = {key: moe1[key] - moe0[key] for key in ("assignments", "rows_all_experts", "rows_routed")}
+    decode1 = (steps1 - steps0) * batch
+    assert d1 == {
+        "assignments": (2 * 256 + decode1) * k,
+        "rows_all_experts": decode1 * e,
+        "rows_routed": 2 * sorted_rows(256, e, k),
+    }
+    d2 = {key: moe2[key] - moe1[key] for key in ("assignments", "rows_all_experts", "rows_routed")}
+    decode2 = (steps2 - steps1) * batch
+    assert d2 == {
+        "assignments": (32 + decode2) * k, "rows_all_experts": (32 + decode2) * e, "rows_routed": 0,
+    }
+    # the routed share the counters give: near 1 for the long prompt's prefill
+    share = d1["rows_routed"] / (d1["rows_routed"] + d1["rows_all_experts"] / (e / k))
+    assert share > 0.9 or decode1 > 256
+
+
+def test_a_tp_mesh_keeps_the_einsum():
+    """GSPMD cannot partition the grouped kernel: a meshed engine pins the
+    all-experts einsum for every call and says so."""
+    eng = LLMEngine.create(
+        "tiny-moe",
+        options={"max_batch": 4, "max_seq": 256, "decode_chunk": 8, "prefill_chunk": 256,
+                 "quant": "int8", "tp": 2, "skip_warmup": True},
+    )
+    try:
+        moe = eng.metrics()["moe"]
+    finally:
+        eng.shutdown()
+    assert moe["impl"] == moe["prefill_impl"] == "all_experts_einsum"
+    assert moe["routed_from_rows"] is None

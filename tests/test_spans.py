@@ -267,6 +267,46 @@ def test_profile_capture_holds_the_engine_spans(tmp_path, monkeypatch, python_tr
         assert not frames, frames[:5]
 
 
+def test_profile_capture_ends_with_the_layer_steps_it_may_hold(tmp_path, monkeypatch):
+    """stop_trace's collection grows with the device events captured, and
+    the callers in front wait 60 s (a traced run of a cell that got twice as
+    fast timed out there): a capture ends at the duration asked for or once
+    the engine has launched ``PROFILE_LAYER_STEPS``, whichever comes first,
+    and says what it captured. An idle engine's capture runs its time."""
+    from agentainer_tpu.engine import llm_serve
+
+    monkeypatch.setenv("AGENTAINER_PROFILE_DIR", str(tmp_path))
+
+    async def body():
+        eng = LLMEngine.create("tiny", options=dict(TINY))
+        serve = LLMServeApp(env={"AGENTAINER_AGENT_ID": "dense"})
+        serve.engine = eng
+        client = TestClient(TestServer(serve.app()))
+        await client.start_server()
+        try:
+            resp = await client.post("/chat", json={"message": "warm", "session": "w", "max_tokens": 4})
+            assert resp.status == 200, await resp.text()
+            idle = await (await client.post("/profile", json={"duration_s": 0.4})).json()
+            # room for 4 passes through tiny's layers: a chat's prefill and
+            # decode steps fill it long before 20 s are over
+            monkeypatch.setattr(llm_serve, "PROFILE_LAYER_STEPS", 4 * eng.cfg.n_layers)
+            before = eng.forward_passes
+            capture = asyncio.ensure_future(client.post("/profile", json={"duration_s": 20.0}))
+            await asyncio.sleep(0.3)
+            resp = await client.post("/chat", json={"message": "hello", "session": "s", "max_tokens": 24})
+            assert resp.status == 200, await resp.text()
+            dense = await (await capture).json()
+            return idle, dense, eng.forward_passes - before
+        finally:
+            await client.close()
+            eng.shutdown()
+
+    idle, dense, passes = asyncio.run(body())
+    assert 0.4 <= idle["duration_s"] < 2.0
+    assert passes >= 4 and dense["duration_s"] < 10.0, (dense, passes)
+    assert glob.glob(os.path.join(dense["trace_dir"], "**", "*.xplane.pb"), recursive=True)
+
+
 # -- seconds beside the compile counts -------------------------------------
 def test_compile_seconds_rise_on_a_fresh_function_only():
     stats = enable_compile_cache()  # one more listener pair on this process
@@ -432,16 +472,26 @@ def test_step_module_names_the_benchmark_matches(step_engine, step, prefix):
 def test_moe_block_names_no_path_for_a_dense_model(served):
     _, before, m, _ = served
     for doc in (before, m):
-        assert doc["moe"] == {"impl": "none", "experts": 0, "top_k": 0, "renormalize": False}
+        assert doc["moe"] == {
+            "impl": "none", "experts": 0, "top_k": 0, "renormalize": False,
+            "prefill_impl": "none", "routed_from_rows": None,
+            "assignments": 0, "rows_all_experts": 0, "rows_routed": 0,
+        }
         assert doc["model_arch"]["qk_norm"] is False
 
 
-@pytest.mark.parametrize("config, options, impl", [
-    ("tiny-olmoe", {}, "all_experts_einsum"),
-    ("tiny-moe", {}, "all_experts_einsum"),
-    ("tiny-olmoe", {"routed": True}, "routed_dispatch"),
-], ids=["olmoe", "mixtral", "olmoe_routed"])
-def test_moe_block_names_the_path_the_steps_trace(config, options, impl):
+@pytest.mark.parametrize("config, options, impl, prefill_impl, cut", [
+    # float32 experts on this CPU: the ridge of a 4-byte weight is 482 rows
+    ("tiny-olmoe", {}, "all_experts_einsum", "sorted_grouped_ffn", 482),
+    ("tiny-moe", {}, "all_experts_einsum", "sorted_grouped_ffn", 482),
+    ("tiny-moe", {"quant": "int8"}, "all_experts_einsum", "sorted_grouped_ffn", 121),
+    ("tiny-olmoe", {"routed": True}, "routed_dispatch", "routed_dispatch", None),
+], ids=["olmoe", "mixtral", "mixtral_int8", "olmoe_routed"])
+def test_moe_block_names_the_path_the_steps_trace(config, options, impl, prefill_impl, cut):
+    """``impl`` is the path of the calls under ``routed_from_rows`` (what
+    ``moe_rows_over_routed`` reads), ``prefill_impl`` that of the calls from
+    there on; the counters count launches, and nothing has been launched
+    (``skip_warmup``)."""
     eng = LLMEngine.create(config, options={**TINY, "speculative": False, **options})
     try:
         cfg, m = eng.cfg, eng.metrics()
@@ -449,6 +499,8 @@ def test_moe_block_names_the_path_the_steps_trace(config, options, impl):
         eng.shutdown()
     assert m["moe"] == {
         "impl": impl, "experts": cfg.n_experts, "top_k": cfg.experts_per_token, "renormalize": cfg.moe_renormalize,
+        "prefill_impl": prefill_impl, "routed_from_rows": cut,
+        "assignments": 0, "rows_all_experts": 0, "rows_routed": 0,
     }
     assert m["model_arch"]["qk_norm"] is cfg.qk_norm and m["model_arch"]["head_dim"] == cfg.head_dim
 

@@ -22,6 +22,10 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
+from agentainer_tpu.models.configs import get_config
+from agentainer_tpu.models.llama import KVCache, forward, init_params
+from agentainer_tpu.ops.moe import sorted_moe_ffn, sorted_rows
+from agentainer_tpu.ops.quant import QTensor
 from agentainer_tpu.ops.pallas_attention import (
     flash_decode,
     flash_prefill,
@@ -152,3 +156,99 @@ def test_meshed_flash_compiles_for_v5e_2x2(v5e, t):
     )
     compiled = jax.jit(make_meshed_cache_attention(mesh)).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# the sorted grouped MoE FFN (ISSUE 29) at the two published expert shapes,
+# int8 as served: (layers of the stack, experts, d, F, top-k)
+EXPERT_SHAPES = {"mixtral": (6, 8, 4096, 14336, 2), "olmoe": (16, 64, 2048, 1024, 8)}
+
+
+@pytest.mark.parametrize("rows", [128, 256, 512, 1024])
+@pytest.mark.parametrize("shape", sorted(EXPERT_SHAPES))
+def test_sorted_moe_ffn_compiles_for_v5e(v5e, shape, rows):
+    """Routing, the Mosaic grouped FFN and the combine, over the stacked int8
+    experts with the layer as a scalar: the kernel is there, and its
+    temporaries are the row buffer's (rows in, rows out, the 0/1 spread
+    matrix), under one int8 matrix of a layer's experts — no slice, relayout
+    or dequantised copy of them."""
+    layers, e, d, f, k = EXPERT_SHAPES[shape]
+    where = SingleDeviceSharding(v5e.devices[0])
+
+    def s(sh, dt):
+        return jax.ShapeDtypeStruct(sh, dt, sharding=where)
+
+    experts = {
+        name: (s((layers, e) + wsh, jnp.int8), s((layers, e, 1, wsh[1]), jnp.float32))
+        for name, wsh in (("w_gate", (d, f)), ("w_up", (d, f)), ("w_down", (f, d)))
+    }
+    fn = lambda x, g, c, ex, l: sorted_moe_ffn(x, g, c, ex, l, kernel=True)  # noqa: E731
+    compiled = (
+        jax.jit(fn)
+        .lower(s((rows, d), jnp.bfloat16), s((rows, k), jnp.bfloat16), s((rows, k), jnp.int32),
+               experts, s((), jnp.int32))
+        .compile()
+    )
+    assert "moe_grouped_ffn" in compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    row_buffer = sorted_rows(rows, e, k) * d * 2
+    assert temp < min(3 * row_buffer + (8 << 20), e * d * f), (temp, row_buffer)
+
+
+def test_prefill_over_the_cut_holds_no_expert_sized_temporary_on_v5e(v5e, monkeypatch):
+    """A whole prefill step of 256 rows against the arena, OLMoE's block at
+    published widths (two layers of it), int8, the program steered to its
+    chip branch (the process's backend is the CPU; the kernels are chosen by
+    ``jax.default_backend()``): the compiled step reads the expert stack in
+    place, 1.2 MB of temporaries in all. The all-experts form of the same
+    step writes what the traces showed as ``copy.53`` and
+    ``constant_dynamic-slice_fusion.6`` — the layer's int8 ``w_down`` sliced
+    out of the stack and relaid with its last two dims swapped — and a bf16
+    copy of each of the three matrices beside its einsum: two layer
+    matrices' worth of live temporaries (270 MB; 1.0 GB at Mixtral's
+    shape)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    import dataclasses
+    from functools import partial
+
+    from agentainer_tpu.engine.quant import _QUANT_KEYS
+    from agentainer_tpu.models.llama import _moe_mlp
+
+    cfg = dataclasses.replace(get_config("olmoe-1b-7b"), n_layers=2, name="olmoe-2l")
+    where = SingleDeviceSharding(v5e.devices[0])
+
+    def place(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=where)
+
+    def quantised(tree):
+        out = {}
+        for key, val in tree.items():
+            if isinstance(val, dict):
+                out[key] = quantised(val)
+            elif key in _QUANT_KEYS:
+                scale = val.shape[:-2] + (1, val.shape[-1])
+                out[key] = QTensor(
+                    jax.ShapeDtypeStruct(val.shape, jnp.int8, sharding=where),
+                    jax.ShapeDtypeStruct(scale, jnp.bfloat16, sharding=where),
+                )
+            else:
+                out[key] = place(val)
+        return out
+
+    params = quantised(jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    cache = jax.tree.map(place, jax.eval_shape(lambda: KVCache.create(cfg, 16, 2048)))
+    tokens = jax.ShapeDtypeStruct((1, 256), jnp.int32, sharding=where)
+    slot = jax.ShapeDtypeStruct((), jnp.int32, sharding=where)
+
+    def step(moe_impl, params, cache, slot, tokens, positions):
+        return forward(params, cfg, tokens, positions, cache, slot=slot, moe_impl=moe_impl)
+
+    def temp_bytes(moe_impl):
+        fn = jax.jit(partial(step, moe_impl), donate_argnums=(1,))
+        return fn.lower(params, cache, slot, tokens, tokens).compile().memory_analysis().temp_size_in_bytes
+
+    layer_matrix = cfg.n_experts * cfg.dim * cfg.ffn_dim  # one int8 matrix of a layer: 134 MB
+    sorted_temp = temp_bytes(None)
+    einsum_temp = temp_bytes(partial(_moe_mlp, cfg=cfg))
+    assert sorted_temp < layer_matrix // 16, sorted_temp
+    assert einsum_temp > layer_matrix, einsum_temp

@@ -32,6 +32,7 @@ __all__ = [
     "HasCrossReduction",
     "DonationAliased",
     "ArenaRidesInCarry",
+    "StacksRideInCarry",
     "check",
     "compile_hlo",
     "op_result_elems",
@@ -250,6 +251,63 @@ class ArenaRidesInCarry:
                 f"step's new rows {list(self.rows)} ({n_rows} elements), "
                 f"a layer is {math.prod(arena[1:])}"
             )
+        return out
+
+
+@dataclass
+class StacksRideInCarry:
+    """``ArenaRidesInCarry`` for a cache of several kinds of leaf (the hybrid
+    block's latent rows, recurrent state and conv state): over the LOWERED
+    (StableHLO) text of ``jit_decode_n`` / ``jit_prefill``.
+
+    - Each stack is a carried value of at least ``loops`` while loops (the
+      layer scan, the 0-or-1-trip loop of the mixer that updates it, and for
+      ``jit_decode_n`` the step scan around them), and a loop that carries a
+      stack carries it once: not the ``xs``/``ys`` pair of a scan that slices
+      a layer out and restacks it.
+    - Every write into a stack (scatter or dynamic_update_slice on an operand
+      of the stack's shape) updates at most ``updates[name]`` elements: the
+      step's new rows of a positional leaf, the stepping lanes' state of a
+      per-lane leaf — never more than it was told a step may touch.
+
+    That the donated leaves alias the outputs through the loops is
+    ``DonationAliased`` on the compiled module; that the chip's compiler adds
+    no copy of its own is the described-v5e compile in tests/test_tpu_compile.py.
+    """
+
+    stacks: dict  # name -> shape
+    updates: dict  # name -> most elements one write may update
+    loops: int = 2
+
+    def failures(self, text: str) -> list[str]:
+        out: list[str] = []
+        whiles = [
+            [_tensor_dims(t) for t in re.findall(r"tensor<[^>]*>", m.group(1))]
+            for m in _WHILE.finditer(text)
+        ]
+        writes = [
+            (_tensor_dims(m.group(1)), _tensor_dims(m.group(2)))
+            for rx in (_SCATTER, _DUS)
+            for m in rx.finditer(text)
+        ]
+        for name, shape in self.stacks.items():
+            shape = tuple(shape)
+            carrying = [n for dims in whiles if (n := sum(d == shape for d in dims))]
+            if len(carrying) < self.loops:
+                out.append(
+                    f"{name} {list(shape)} is carried by {len(carrying)} while loops "
+                    f"(need {self.loops}): sliced and restacked around a loop, not carried through it"
+                )
+            if any(n != 1 for n in carrying):
+                out.append(f"loops carry {carrying} values of {name}'s shape each (want 1)")
+            sizes = [math.prod(upd) for operand, upd in writes if operand == shape]
+            if not sizes:
+                out.append(f"found no write into {name}")
+            if any(n > self.updates[name] for n in sizes):
+                out.append(
+                    f"writes into {name} of {sizes} elements: a step may update "
+                    f"{self.updates[name]}, a layer is {math.prod(shape[1:])}"
+                )
         return out
 
 

@@ -53,7 +53,7 @@ def load_params(cfg: ModelConfig, path: str | Path, dtype=jnp.bfloat16) -> dict:
     return jax.tree.map(lambda x: np.asarray(x).astype(dtype), restored)
 
 
-# -- KV slot snapshots (engine ↔ store) ---------------------------------
+# -- cache slot snapshots (engine ↔ store) --------------------------------
 # v2: KV ships in the cache's EXACT dtype (v1 cast everything to fp16,
 # which rounded fp32/bf16 arenas on restore and broke the token-identical
 # resume guarantee under near-tie greedy argmax). bfloat16 has no portable
@@ -63,52 +63,84 @@ def load_params(cfg: ModelConfig, path: str | Path, dtype=jnp.bfloat16) -> dict:
 # [L, pos, KV, hd] prefix in the exact dtype) — a paged engine stages it
 # by gathering only the session's live pages, and the optional
 # ``page_size`` header records that provenance — so v3 blobs restore into
-# paged and dense engines alike, and v2/v1 blobs written before the
-# upgrade keep restoring (the reader accepts all three).
-SNAP_VERSION = 3
+# paged and dense engines alike.
+# v4: named leaves. A slot is "positional leaves up to ``position`` +
+# per-lane state leaves": the header's ``leaves`` maps each array's name to
+# its true dtype and whether it is positional (axis 1 = positions, trimmed
+# to ``position``) or per-lane (shipped whole: a recurrent state cannot be
+# trimmed). A K/V family's leaves are ``k`` and ``v`` with v3's layout, so
+# v1-v3 blobs written before the upgrade keep restoring (the reader accepts
+# all four) and read as those two leaves.
+SNAP_VERSION = 4
 
 
-def pack_kv_snapshot(k16, v16, position: int, meta: dict | None = None) -> bytes:
-    """Host half of a KV snapshot: block on the staged device buffers
-    (bucket-padded [L, bucket, KV, hd] — the engine's worker dispatched the
-    slice), trim to the live prefix, and pack a self-describing npz blob.
-    Only the written prefix ships — a 100-token conversation snapshot is
-    ~100/S of the slot arena."""
-    k = np.asarray(k16)[:, :position]
-    v = np.asarray(v16)[:, :position]
-    dtype_name = k.dtype.name
+def _portable(a: np.ndarray) -> np.ndarray:
+    return a.view(np.uint16) if a.dtype.name == "bfloat16" else a
+
+
+def _true_dtype(a: np.ndarray, dtype_name: str) -> np.ndarray:
     if dtype_name == "bfloat16":
-        k, v = k.view(np.uint16), v.view(np.uint16)
+        import ml_dtypes
+
+        return a.view(ml_dtypes.bfloat16)
+    return a
+
+
+def pack_snapshot(
+    leaves: dict, position: int, meta: dict | None = None, positional: tuple = ("k", "v")
+) -> bytes:
+    """Host half of a slot snapshot: block on the staged device buffers
+    (``name -> array``; positional leaves bucket-padded ``[L, bucket, ...]``
+    — the engine's worker dispatched the slice), trim those to the live
+    prefix, and pack a self-describing npz blob. Only the written prefix of
+    a positional leaf ships — a 100-token conversation snapshot is ~100/S
+    of the slot arena; a per-lane leaf ships whole."""
+    arrays, described = {}, {}
+    for name, staged in leaves.items():
+        a = np.asarray(staged)
+        if name in positional:
+            a = a[:, :position]
+        described[name] = {"dtype": a.dtype.name, "positional": name in positional}
+        arrays[name] = _portable(a)
+    header = {"version": SNAP_VERSION, "position": position, "leaves": described, **(meta or {})}
+    if "k" in described:
+        header["dtype"] = described["k"]["dtype"]  # what a v2/v3 reader looked for
     buf = io.BytesIO()
-    header = json.dumps(
-        {
-            "version": SNAP_VERSION,
-            "position": position,
-            "dtype": dtype_name,
-            **(meta or {}),
-        }
+    np.savez_compressed(
+        buf, header=np.frombuffer(json.dumps(header).encode(), dtype=np.uint8), **arrays
     )
-    np.savez_compressed(buf, k=k, v=v, header=np.frombuffer(header.encode(), dtype=np.uint8))
     return buf.getvalue()
 
 
-def deserialize_kv_slot(blob: bytes) -> tuple[np.ndarray, np.ndarray, dict]:
-    """Returns (k [L, pos, KV, hd], v, header dict) in the snapshot's true
-    dtype. Accepts v1 blobs (fp16 payload) so snapshots taken before an
-    engine upgrade still restore across it."""
+def pack_kv_snapshot(k16, v16, position: int, meta: dict | None = None) -> bytes:
+    """A K/V family's snapshot: the two positional leaves ``k`` and ``v``."""
+    return pack_snapshot({"k": k16, "v": v16}, position, meta)
+
+
+def deserialize_snapshot(blob: bytes) -> tuple[dict, dict]:
+    """Returns (``name -> array`` in each leaf's true dtype, header dict).
+    Accepts v1-v3 blobs (``k`` and ``v`` ``[L, pos, KV, hd]``; v1: fp16
+    payload) so snapshots taken before an engine upgrade still restore."""
     with np.load(io.BytesIO(blob)) as z:
         header = json.loads(bytes(z["header"]).decode())
         version = header.get("version")
-        k, v = z["k"], z["v"]
         if version == 1:
-            return k, v, header  # legacy: fp16 as stored
-        if version not in (2, SNAP_VERSION):  # v2 fallback: same payload layout
-            raise ValueError(f"unsupported KV snapshot version: {version}")
-        if header.get("dtype") == "bfloat16":
-            import ml_dtypes
+            return {"k": z["k"], "v": z["v"]}, header  # legacy: fp16 as stored
+        if version in (2, 3):
+            name = header.get("dtype", "")
+            return {"k": _true_dtype(z["k"], name), "v": _true_dtype(z["v"], name)}, header
+        if version != SNAP_VERSION:
+            raise ValueError(f"unsupported cache snapshot version: {version}")
+        return {n: _true_dtype(z[n], d["dtype"]) for n, d in header["leaves"].items()}, header
 
-            k, v = k.view(ml_dtypes.bfloat16), v.view(ml_dtypes.bfloat16)
-        return k, v, header
+
+def deserialize_kv_slot(blob: bytes) -> tuple[np.ndarray, np.ndarray, dict]:
+    """Returns (k [L, pos, KV, hd], v, header dict) of a K/V family's
+    snapshot, whatever version wrote it."""
+    leaves, header = deserialize_snapshot(blob)
+    if set(leaves) != {"k", "v"}:
+        raise ValueError(f"not a K/V snapshot: leaves {sorted(leaves)}")
+    return leaves["k"], leaves["v"], header
 
 
 def restore_kv_slot(cache: KVCache, slot: int, k: np.ndarray, v: np.ndarray) -> KVCache:

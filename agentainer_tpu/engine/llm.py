@@ -61,11 +61,12 @@ from ..models.llama import (
     _moe_mlp,
     _moe_mlp_routed,
     forward,
+    init_cache,
     init_params,
     moe_sorted_from,
 )
 from ..ops.attention import pages_to_rows, plan_cache_attention, rows_to_pages
-from ..ops.moe import sorted_rows
+from ..ops.moe import row_tile, sorted_rows
 from ..utils.spans import Spans
 from .sampling import NEG_INF, sample, sample_step
 from .tokenizer import load_tokenizer
@@ -115,6 +116,56 @@ SPEC_PROBE_EVERY = 32
 # looks so a 4096-token context can't turn every lookup miss into
 # milliseconds of host stall on the decode critical path
 SPEC_LOOKUP_WINDOW = 1024
+
+
+# -- what a family's cache can hold, decided in ONE place -------------------
+#
+# A K/V arena's rows are positional: they can be truncated (speculative
+# rewind), copied at a boundary (prefix forks), paged, overwritten by a
+# parked lane's garbage and moved off the device and back, all harmlessly.
+# A recurrent state (the hybrid block's KDA layers) is one value per lane
+# that only ever moves forward: none of that holds for it. The features
+# below either work for a family or are OFF for it with the reason given —
+# logged at build and reported in ``/metrics`` (``cache``) — and asking for
+# one explicitly is an error at build, never a silent fallback.
+_RECURRENT_OFF = {
+    "speculative": "a rejected draft would have to rewind the recurrent state",
+    "fused_decode": "the fused loop's in-loop speculation rewinds, and its masks are not the state's",
+    "paged_kv": "a per-lane state has no pages; the latent rows stay a dense arena",
+    "kv_tiering": "the host tier moves k and v only",
+    "prefix_cache": "a fork needs the state AT the boundary, and none is kept there",
+    "mesh": "the hybrid block is served on one chip (its share of the experts is cfg.experts_held)",
+}
+
+
+def cache_features(cfg: ModelConfig, asked: dict) -> tuple[dict, dict]:
+    """``asked``: feature → what the caller gave (``None``: nothing, take
+    the default). Returns (feature → on/off, feature → reason it is off for
+    this family). Raises where a feature this family's cache cannot hold was
+    asked for by name."""
+    defaults = {"speculative": True, "prefix_cache": True}
+    if not cfg.is_hybrid:
+        return {k: bool(defaults.get(k, False) if v is None else v) for k, v in asked.items()}, {}
+    refused = [k for k, v in asked.items() if v]
+    if refused:
+        raise ValueError(
+            f"model {cfg.name!r} keeps a recurrent state in its cache; not served with it: "
+            + "; ".join(f"{k} ({_RECURRENT_OFF[k]})" for k in refused)
+        )
+    return {k: False for k in asked}, {k: _RECURRENT_OFF[k] for k in asked}
+
+
+def fleet_default_applies(config_name: str, feature: str) -> bool:
+    """Whether a fleet-wide default (the daemon's ``ATPU_*`` environment)
+    reaches a deployment of ``config_name``: only for a feature its family's
+    cache can hold. A fleet default is nobody asking for the feature on this
+    model, so it falls away with its reason reported instead of failing the
+    build; ``model.options`` of the deployment itself still is an ask."""
+    try:
+        cfg = get_config(config_name)
+    except KeyError:
+        return True
+    return not (cfg.is_hybrid and feature in _RECURRENT_OFF)
 
 
 class SnapshotDeferred(Exception):
@@ -299,6 +350,8 @@ class RestoreCmd:
     pending_token: int | None
     loop: asyncio.AbstractEventLoop
     future: asyncio.Future
+    # every leaf of the snapshot by name (a K/V family's are k and v above)
+    leaves: dict | None = None
 
 
 @dataclass
@@ -481,11 +534,11 @@ class LLMEngine:
         routed_moe: bool | None = None,
         moe_capacity_factor: float = 2.0,
         adaptive_decode: bool = True,
-        prefix_cache: bool = True,
+        prefix_cache: bool | None = None,
         prefix_cache_bytes: int = 0,
         deadlines: bool = True,
         shed_watermark: int = 0,
-        speculative: bool = True,
+        speculative: bool | None = None,
         spec_gamma_max: int = 8,
         paged_kv: bool = False,
         page_size: int = PAGE_SIZE_DEFAULT,
@@ -500,6 +553,28 @@ class LLMEngine:
         self.cfg = cfg
         self.tokenizer = tokenizer
         self.max_batch = max_batch
+        # what this family's cache can hold (``cache_features``): None means
+        # "not asked", so a default never trips the refusal
+        feats, self._cache_off = cache_features(
+            cfg,
+            {
+                "speculative": speculative,
+                "prefix_cache": prefix_cache,
+                "paged_kv": paged_kv or None,
+                "fused_decode": fused_decode or None,
+                "kv_tiering": kv_tiering or None,
+                "mesh": (max(1, tp) * max(1, ep) > 1) or None,
+            },
+        )
+        speculative, prefix_cache = feats["speculative"], feats["prefix_cache"]
+        self._recurrent = cfg.is_hybrid
+        if self._cache_off:
+            print(
+                f"[llm-engine] cache: kinds={'+'.join(sorted(set(cfg.layer_kinds)))} "
+                f"(positional rows + per-lane state); off for this family: "
+                + "; ".join(f"{k}: {v}" for k, v in self._cache_off.items()),
+                flush=True,
+            )
         # Paged KV arena (block tables): sessions hold lists of fixed-size
         # pages from a global pool instead of dense [max_seq] slots, so
         # resident sessions are bounded by the pool, prefix sharing maps
@@ -658,7 +733,9 @@ class LLMEngine:
 
                 def _alloc_single():
                     with jax.default_device(dev):
-                        c = KVCache.create(cfg, max_batch, max_seq, dtype=dtype)
+                        # the model builds its cache; an engine's lanes of a
+                        # recurrent state start closed
+                        c = init_cache(cfg, max_batch, max_seq, dtype=dtype, live=False)
                     return jax.device_put(c, dev)
 
             self._alloc_cache = _alloc_single
@@ -909,9 +986,21 @@ class LLMEngine:
         # decode step streams the weights once plus each active lane's KV
         # prefix; prefill streams the weights once per chunk.
         self.hbm_bytes_read = 0.0
-        self._kv_bytes_per_pos = (
-            2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim * cache.k.dtype.itemsize
-        )
+        if self._recurrent:
+            # positional bytes a token adds (the latent rows); the per-lane
+            # state is read and written whole each step whatever the context
+            self._kv_bytes_per_pos = (
+                cfg.n_mla * cache.latent.shape[-1] * cache.latent.dtype.itemsize
+            )
+        else:
+            self._kv_bytes_per_pos = (
+                2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim * cache.k.dtype.itemsize
+            )
+        # cache-manager counters of the per-lane state (``cache`` in /metrics)
+        self.state_resets = 0
+        self.state_snapshots = 0
+        self.state_restores = 0
+        self._restore_fns: dict[int, Any] = {}
         self.decode_steps = 0
         self._occupancy_sum = 0.0
         self._last_decode_end: float | None = None
@@ -927,7 +1016,7 @@ class LLMEngine:
         self.param_hbm_bytes = sum(
             x.nbytes for x in jax.tree.leaves(params)
         )
-        self.kv_arena_bytes = cache.k.nbytes + cache.v.nbytes
+        self.kv_arena_bytes = sum(x.nbytes for x in jax.tree.leaves(cache))
         if not self.tier_host_budget_bytes:
             self.tier_host_budget_bytes = self.kv_arena_bytes
         # Cross-session prefix arena: bucket-length token prefixes → their
@@ -1179,11 +1268,12 @@ class LLMEngine:
             routed_moe=options.get("routed"),
             moe_capacity_factor=float(options.get("moe_cf", 2.0)),
             adaptive_decode=bool(options.get("adaptive_decode", True)),
-            prefix_cache=bool(options.get("prefix_cache", True)),
+            # None: not asked (the family's default; ``cache_features``)
+            prefix_cache=bool(options["prefix_cache"]) if "prefix_cache" in options else None,
             prefix_cache_bytes=int(options.get("prefix_cache_bytes", 0) or 0),
             deadlines=bool(options.get("deadlines", True)),
             shed_watermark=int(options.get("shed_watermark", 0) or 0),
-            speculative=bool(options.get("speculative", True)),
+            speculative=bool(options["speculative"]) if "speculative" in options else None,
             spec_gamma_max=int(options.get("spec_gamma_max", 8) or 8),
             paged_kv=bool(options.get("paged_kv", False)),
             page_size=int(options.get("page_size", PAGE_SIZE_DEFAULT) or PAGE_SIZE_DEFAULT),
@@ -1218,7 +1308,14 @@ class LLMEngine:
         # (parallel/flash_mesh.py). A meshed page pool stays on the einsum
         # path (it needs the partitioning XLA derives).
         page_size = self.page_size if self.paged else 0
-        if self.mesh is None:
+        if self._recurrent:
+            # the hybrid block's plan: a kernel per mechanism and call shape
+            # (models/hybrid.plan_hybrid), passed to ``forward`` in the same
+            # seat as the arena attention
+            from ..models.hybrid import plan_hybrid
+
+            attn = plan_hybrid(cfg)
+        elif self.mesh is None:
             attn = plan_cache_attention(
                 cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, page_size=page_size
             )
@@ -1235,13 +1332,20 @@ class LLMEngine:
                 use_pallas=False,
             )
         self.attention = attn.describe()
-        self.meshed_flash = "shard_map" in attn.decode
-        print(
-            f"[llm-engine] attention: prefill={attn.prefill} "
-            f"decode={attn.decode} arena={attn.arena} ({attn.reason})",
-            flush=True,
-        )
-        cache_attn_impl = attn.fn
+        self.meshed_flash = (not self._recurrent) and "shard_map" in attn.decode
+        if self._recurrent:
+            print(
+                f"[llm-engine] attention: kda prefill={attn.kda_prefill} decode={attn.kda_decode}; "
+                f"mla prefill={attn.mla_prefill} decode={attn.mla_decode} ({attn.reason})",
+                flush=True,
+            )
+        else:
+            print(
+                f"[llm-engine] attention: prefill={attn.prefill} "
+                f"decode={attn.decode} arena={attn.arena} ({attn.reason})",
+                flush=True,
+            )
+        cache_attn_impl = attn if self._recurrent else attn.fn
 
         # the MoE FFN the steps trace. One chip, no option: ``forward`` itself
         # splits at the chip's ridge (ops/moe.sorted_from_rows) — calls under
@@ -1272,13 +1376,20 @@ class LLMEngine:
         )
         # rows a call needs to take the sorted FFN (no call under a pinned path)
         self._moe_sorted_from = (
-            moe_sorted_from(cfg, self.params["layers"]) if moe_impl is None else None
+            moe_sorted_from(cfg, self.params if self._recurrent else self.params["layers"])
+            if moe_impl is None
+            else None
         )
         self.moe = {
             # the path of the calls under ``routed_from_rows`` (every call
             # where that is null), and the model's routing shape
             "impl": impl,
             "experts": cfg.n_experts,
+            # experts in this chip's stack (all of them unless it holds a
+            # share), the shared experts every token also takes, the rule
+            "experts_held": cfg.n_held if cfg.is_moe else 0,
+            "shared_experts": cfg.n_shared_experts,
+            "router": cfg.moe_router if cfg.is_moe else None,
             "top_k": cfg.experts_per_token if cfg.is_moe else 0,
             "renormalize": bool(cfg.is_moe and cfg.moe_renormalize),
             # the path of the calls with that many rows (B·T) or more
@@ -1304,7 +1415,7 @@ class LLMEngine:
                 flush=True,
             )
 
-        def run_forward(params, toks, pos, cache, bt=None, slot=None):
+        def run_forward(params, toks, pos, cache, bt=None, slot=None, **kw):
             """``slot``: the batch's rows are arena rows ``slot..`` (a lane's
             prefill); ``forward`` addresses them in place."""
             return forward(
@@ -1317,6 +1428,7 @@ class LLMEngine:
                 moe_impl=moe_impl,
                 block_table=bt,
                 slot=slot,
+                **kw,
             )
 
         # the paged fns can't read the logical arena length off the cache
@@ -1325,8 +1437,14 @@ class LLMEngine:
 
         def prefill(params, cache, slot, tokens, positions, n_real):
             # the prompt runs against the slot's row where it lies in the
-            # arena: no row sliced out, none written back
-            logits, cache = run_forward(params, tokens, positions, cache, slot=slot)
+            # arena: no row sliced out, none written back. A recurrent state
+            # must not see the bucket's padding rows: say which are real
+            kw = (
+                {"valid": jnp.arange(tokens.shape[1])[None, :] < n_real}
+                if self._recurrent
+                else {}
+            )
+            logits, cache = run_forward(params, tokens, positions, cache, slot=slot, **kw)
             last = lax.dynamic_slice_in_dim(logits, n_real - 1, 1, axis=1)[0, 0]
             return last, cache
 
@@ -1351,7 +1469,7 @@ class LLMEngine:
             pages) and the scratch clamp comes from the engine statics,
             since the pool's page axis says nothing about logical length."""
 
-            scratch = cache.k.shape[2] - 1 if bt is None else scratch_static
+            scratch = scratch_static  # = the dense arena's last row
 
             def step(carry, key):
                 tok, pos, cache = carry
@@ -1424,6 +1542,16 @@ class LLMEngine:
             self._decode_n = jax.jit(decode_n, donate_argnums=(1, 2, 3))
         self._inject = jax.jit(inject, donate_argnums=(0, 1, 2, 3, 4, 5, 6))
         self._first_token = jax.jit(first_token)
+        if self._recurrent:
+            from ..models import hybrid
+
+            # the cache manager's moves on a lane's state, as the model
+            # defines them: open a lane for a request (zeroing a fresh
+            # context's state), stage a lane's leaves, write them back
+            self._admit_state = jax.jit(hybrid.admit_lane, donate_argnums=(0,))
+            self._lane_leaves = hybrid.snapshot_lane
+            self._restore_lane = hybrid.restore_lane
+            self._positional = hybrid.HybridCache.POSITIONAL
         # the verify ladder reuses the same forward (one prefill-shaped call
         # with t = k+1 per round); fns are built per bucket on demand and
         # warmed alongside the decode ladder
@@ -2023,8 +2151,8 @@ class LLMEngine:
             raise SnapshotDeferred(session)
         if staged is None:
             return None
-        k16, v16, position, pending_token = staged
-        from .checkpoint import pack_kv_snapshot
+        leaves, position, pending_token = staged
+        from .checkpoint import pack_snapshot
 
         meta = {"session": session, "pending_token": pending_token}
         if self.paged:
@@ -2032,13 +2160,8 @@ class LLMEngine:
             # not a pow2 position bucket); payload layout is identical to
             # the dense staging so blobs restore across both arenas
             meta["page_size"] = self.page_size
-        return await asyncio.to_thread(
-            pack_kv_snapshot,
-            k16,
-            v16,
-            position,
-            meta,
-        )
+        positional = self._positional if self._recurrent else ("k", "v")
+        return await asyncio.to_thread(pack_snapshot, leaves, position, meta, positional)
 
     def _do_snapshot(self, cmd: SnapshotCmd) -> None:
         """Worker-thread half of snapshot_session: dispatch the bucketed
@@ -2113,15 +2236,19 @@ class LLMEngine:
             now = time.monotonic()
             self._last_snapshot_at = now
             self._snap_last_by_session[cmd.session] = now
-            k16, v16 = self._snap_fn(self._snap_bucket(slot.position))(
+            leaves = self._snap_fn(self._snap_bucket(slot.position))(
                 self.cache, jnp.int32(slot.idx)
             )
+            if not self._recurrent:
+                leaves = dict(zip(("k", "v"), leaves))
+            else:
+                self.state_snapshots += 1
             try:
-                k16.copy_to_host_async()
-                v16.copy_to_host_async()
+                for leaf in leaves.values():
+                    leaf.copy_to_host_async()
             except Exception:
                 pass
-            staged = (k16, v16, slot.position, slot.pending_token)
+            staged = (leaves, slot.position, slot.pending_token)
         cmd.loop.call_soon_threadsafe(_resolve_value, cmd.future, staged)
 
     @_phase("engine.snapshot")
@@ -2146,7 +2273,7 @@ class LLMEngine:
                 v16.copy_to_host_async()
             except Exception:
                 pass
-            staged = (k16, v16, sess.position, sess.pending_token)
+            staged = ({"k": k16, "v": v16}, sess.position, sess.pending_token)
         cmd.loop.call_soon_threadsafe(_resolve_value, cmd.future, staged)
 
     def _service_parked_snapshot(self, slot: Slot) -> None:
@@ -2189,6 +2316,10 @@ class LLMEngine:
                 # restore (found by the chaos soak's resume invariant).
                 # bf16/fp16 caches ship 2 bytes/elem as before; fp32 CPU
                 # caches pay 2x blob size for exactness.
+                if self._recurrent:
+                    # named leaves: the positional rows up to the bucket,
+                    # the per-lane state whole (it cannot be trimmed)
+                    return self._lane_leaves(cache, i, _b)
                 k = lax.dynamic_slice_in_dim(cache.k, i, 1, axis=1)[:, 0, :_b]
                 v = lax.dynamic_slice_in_dim(cache.v, i, 1, axis=1)[:, 0, :_b]
                 return k, v
@@ -3126,18 +3257,19 @@ class LLMEngine:
 
     async def restore_session(self, session: str, blob: bytes) -> bool:
         """Load a snapshot into a fresh slot (worker-thread mediated)."""
-        from .checkpoint import deserialize_kv_slot
+        from .checkpoint import deserialize_snapshot
 
-        k, v, header = deserialize_kv_slot(blob)
+        leaves, header = deserialize_snapshot(blob)
         loop = asyncio.get_running_loop()
         cmd = RestoreCmd(
             session=session,
-            k=k,
-            v=v,
+            k=leaves.get("k"),
+            v=leaves.get("v"),
             position=int(header["position"]),
             pending_token=header.get("pending_token"),
             loop=loop,
             future=loop.create_future(),
+            leaves=leaves,
         )
         self._queue.put(cmd)
         return await cmd.future
@@ -3350,13 +3482,44 @@ class LLMEngine:
                 "kv_heads": self.cfg.n_kv_heads,
                 "head_dim": self.cfg.head_dim,
                 "qk_norm": self.cfg.qk_norm,
+                # the mixers by kind (every layer "gqa" where none is named)
+                "layer_kinds": (
+                    {k: self.cfg.layer_kinds.count(k) for k in sorted(set(self.cfg.layer_kinds))}
+                    or {"gqa": self.cfg.n_layers}
+                ),
+                "dense_layers": (
+                    self.cfg.n_dense_layers if self.cfg.is_hybrid
+                    else 0 if self.cfg.is_moe else self.cfg.n_layers
+                ),
             },
+            # what the cache holds by kind of leaf, the features its kind
+            # turned off (``cache_features``), and the per-lane state's moves
+            "cache": self._cache_metrics(),
             "n_chips": self._n_chips,
             "param_hbm_bytes": self.param_hbm_bytes,
             "kv_arena_bytes": self.kv_arena_bytes,
             "hbm_bytes_per_chip_est": int(
                 (self.param_hbm_bytes + self.kv_arena_bytes) / self._n_chips
             ),
+        }
+
+    def _cache_metrics(self) -> dict:
+        if not self._recurrent:
+            return {
+                "kinds": ["kv"],
+                "kv_bytes": self.kv_arena_bytes,
+                "bytes_per_lane": self.kv_arena_bytes // self.max_batch if not self.paged else None,
+                "off": {},
+            }
+        sizes = {f"{name}_bytes": getattr(self.cache, name).nbytes for name in ("latent", "state", "conv")}
+        return {
+            "kinds": ["latent", "state", "conv"],
+            **sizes,
+            "bytes_per_lane": sum(sizes.values()) // self.max_batch,
+            "state_resets": self.state_resets,
+            "state_snapshots": self.state_snapshots,
+            "state_restores": self.state_restores,
+            "off": dict(self._cache_off),
         }
 
     def _utilization_metrics(self, elapsed: float) -> dict:
@@ -3873,7 +4036,7 @@ class LLMEngine:
         Reallocate anything lost so the engine keeps serving (sessions
         restart cold; the store-side KV snapshots still allow resume)."""
         lost = False
-        for arr in (self.cache.k, self.cache.v):
+        for arr in jax.tree.leaves(self.cache):
             try:
                 if arr.is_deleted():
                     lost = True
@@ -3957,6 +4120,11 @@ class LLMEngine:
             if self.paged:
                 ok = self._do_restore_paged(cmd)
                 return
+            if self._recurrent:
+                ok = self._do_restore_state(cmd)
+                return
+            if cmd.k is None or cmd.v is None:
+                return  # another family's snapshot: the caller re-prefills
             slot = self._find_slot(cmd.session)
             if slot is not None and cmd.position < self.max_seq - 1:
                 self.cache = restore_kv_slot(self.cache, slot.idx, cmd.k, cmd.v)
@@ -3973,6 +4141,43 @@ class LLMEngine:
             # resolve even on exception (shape-mismatched snapshots from a
             # redeployed model config must not hang the caller)
             cmd.loop.call_soon_threadsafe(_resolve_value, cmd.future, ok)
+
+    def _do_restore_state(self, cmd: RestoreCmd) -> bool:
+        """Restore into a lane whose cache is positional rows + per-lane
+        state: every named leaf has to be there and fit (a snapshot of
+        another family, or of other widths, is refused: the caller
+        re-prefills). The positional rows are padded to their snapshot
+        bucket, so the write is one of a handful of compiled programs, on
+        the donated cache: nothing the size of a state stack is copied."""
+        leaves = cmd.leaves or {}
+        want = jax.eval_shape(
+            lambda c: self._lane_leaves(c, 0, self.max_seq), self.cache
+        )
+        if set(leaves) != set(want) or not 0 < cmd.position < self.max_seq - 1:
+            return False
+        bucket = self._snap_bucket(cmd.position)
+        staged = {}
+        for name, spec in want.items():
+            a = np.asarray(leaves[name])
+            if name in self._positional:
+                if a.shape[0] != spec.shape[0] or a.shape[2:] != spec.shape[2:] or a.shape[1] > bucket:
+                    return False
+                a = np.pad(a, [(0, 0), (0, bucket - a.shape[1])] + [(0, 0)] * (a.ndim - 2))
+            elif a.shape != spec.shape:
+                return False
+            staged[name] = jnp.asarray(a, spec.dtype)
+        slot = self._find_slot(cmd.session)
+        if slot is None:
+            return False
+        fn = self._restore_fns.get(bucket)
+        if fn is None:
+            fn = self._restore_fns[bucket] = jax.jit(self._restore_lane, donate_argnums=(0,))
+        self.cache = fn(self.cache, jnp.int32(slot.idx), staged)
+        slot.position = cmd.position
+        slot.pending_token = cmd.pending_token
+        slot.last_used = time.monotonic()
+        self.state_restores += 1
+        return True
 
     def _do_restore_paged(self, cmd: RestoreCmd) -> bool:
         """Restore into PAGES, not a lane: the session enters residency
@@ -4090,6 +4295,21 @@ class LLMEngine:
             slot.prefix_ctx = list(prompt)
         else:
             slot.prefix_ctx = None
+        if self._recurrent:
+            # open the lane's per-lane state for this request, zeroed for a
+            # fresh context and carried on for a continuing one. Decode steps
+            # it while it feeds tokens the reply keeps: positions up to the
+            # prompt's end + max_tokens - 1 (the last token generated is never
+            # fed), closing early on an EOS the request heeds
+            with self._spans.span("engine.state_reset", request_id=req.id, fresh=fresh):
+                self.cache = self._admit_state(
+                    self.cache,
+                    jnp.int32(slot.idx),
+                    jnp.bool_(fresh),
+                    jnp.int32(slot.position + len(prompt) + req.max_tokens - 1),
+                    jnp.int32(-1 if req.ignore_eos else self.tokenizer.eos_id),
+                )
+            self.state_resets += int(fresh)
         # admit: the slot is busy from here; the worker's prefill tick feeds
         # the prompt through chunk-by-chunk, interleaved with decode steps
         slot.request = req
@@ -4324,9 +4544,12 @@ class LLMEngine:
         e, k = self.cfg.n_experts, self.cfg.experts_per_token
         self.moe["assignments"] += passes * rows * k
         if self._moe_sorted_from is not None and rows >= self._moe_sorted_from:
-            self.moe["rows_routed"] += passes * sorted_rows(rows, e, k)
+            # a chip's share: the stack's experts, the tile of the whole router
+            self.moe["rows_routed"] += passes * sorted_rows(
+                rows, self.cfg.n_held, k, row_tile(rows, e, k)
+            )
         elif not self.routed_moe:
-            self.moe["rows_all_experts"] += passes * rows * e
+            self.moe["rows_all_experts"] += passes * rows * self.cfg.n_held
 
     def _bucket(self, n: int) -> int:
         for b in PREFILL_BUCKETS:
@@ -5377,7 +5600,14 @@ class LLMEngine:
             # are real compute but wasted — MFU should show that, not hide it
             self.flops_done += used * self.cfg.flops_per_token(start + used // 2)
             finished = hit_eos or len(req.generated) >= req.max_tokens
-            if finished and used < chunk:
+            if finished and self._recurrent:
+                # a recurrent state cannot be rewound, so the device never
+                # feeds a reply's last token (the lane's stop / EOS mask):
+                # the state stands at the tokens before it, wherever in the
+                # chunk the reply ended, and the token is carried over
+                slot.position = start + used
+                self._finish(slot, pending_last=True)
+            elif finished and used < chunk:
                 # chunk overshot: the used-th token was already fed at
                 # position start+used; later writes overwrite the overshoot
                 slot.position = start + used + 1

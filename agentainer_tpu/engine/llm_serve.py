@@ -326,18 +326,23 @@ class LLMServeApp:
             print(f"[llm-serve] kv snapshot failed: {self.last_kv_snapshot_error}", flush=True)
 
     def _engine_options(self) -> dict:
+        from .llm import fleet_default_applies
+
         opts = dict(self.model_options)
+        # a fleet default never reaches a model whose cache cannot hold the
+        # feature (engine/llm.cache_features decides; the engine reports it off)
+        applies = lambda flag: fleet_default_applies(self.config_name, flag)  # noqa: E731
         # fleet-wide speculative-decoding default (config features.speculative
         # → daemon exports ATPU_SPECULATIVE → engine env): per-deployment
         # model options still win
         env_spec = os.environ.get("ATPU_SPECULATIVE")
-        if env_spec is not None and "speculative" not in opts:
+        if env_spec is not None and "speculative" not in opts and applies("speculative"):
             opts["speculative"] = env_spec.lower() in ("1", "true", "yes")
         # fleet-wide paged-KV-arena default (config features.paged_kv →
         # daemon exports ATPU_PAGED_KV → engine env); per-deployment model
         # options still win — same channel as speculative above
         env_paged = os.environ.get("ATPU_PAGED_KV")
-        if env_paged is not None and "paged_kv" not in opts:
+        if env_paged is not None and "paged_kv" not in opts and applies("paged_kv"):
             opts["paged_kv"] = env_paged.lower() in ("1", "true", "yes")
         # remaining engine A/B options ride the identical fleet-default
         # channel (daemon write-back -> engine env -> options, per-deploy
@@ -354,7 +359,7 @@ class LLMServeApp:
             ("streaming", "ATPU_STREAMING"),
         ):
             raw = os.environ.get(env_name)
-            if raw is not None and flag not in opts:
+            if raw is not None and flag not in opts and applies(flag):
                 opts[flag] = raw.lower() in ("1", "true", "yes")
         if self.chips:
             # no tp injection: LLMEngine.create derives the parallelism
@@ -1418,7 +1423,12 @@ class LLMServeApp:
             # ``duration_s`` in the answer is what was captured.
             t0 = time.monotonic()
             first = self.engine.forward_passes
-            room = PROFILE_LAYER_STEPS / self.engine.cfg.n_layers
+            # a layer-step of the hybrid block is about four of the K/V
+            # block's in device events (two 0-or-1-trip loops, two kernels, a
+            # conditional FFN): stop_trace took over 60 s for 3,000 of them
+            # (my chip run, PR 30), so its capture ends at a quarter as many
+            per_pass = self.engine.cfg.n_layers * (4 if self.engine.cfg.is_hybrid else 1)
+            room = PROFILE_LAYER_STEPS / per_pass
             try:
                 while (
                     time.monotonic() - t0 < duration

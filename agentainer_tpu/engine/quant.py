@@ -16,6 +16,9 @@ Core tensor type lives in ops/quant.py (model-agnostic).
 
 from __future__ import annotations
 
+import functools
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -25,6 +28,10 @@ from ..ops.quant import QTensor, dequant, quantize_array  # noqa: F401 (re-expor
 _QUANT_KEYS = {
     "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down", "router",
     "embed", "lm_head",
+    # the hybrid block's matrices (models/hybrid.param_shapes); its vectors —
+    # norms, a_log, dt_bias, conv filters, the selection bias — stay dense
+    "wqkv", "w_fa", "w_fb", "w_beta", "w_ga", "w_gb", "wkva", "wkvb",
+    "ws_gate", "ws_up", "ws_down",
 }
 
 
@@ -69,6 +76,10 @@ def synthetic_quantized_params(cfg, dtype=jnp.bfloat16, device=None, mesh=None) 
     from ..models.configs import ModelConfig
 
     assert isinstance(cfg, ModelConfig)
+    if cfg.is_hybrid:
+        if mesh is not None:
+            raise ValueError("the hybrid block is generated for one chip")
+        return _synthetic_hybrid(cfg, dtype, device)
     specs = None
     if mesh is not None:
         from jax.sharding import NamedSharding
@@ -145,6 +156,44 @@ def synthetic_quantized_params(cfg, dtype=jnp.bfloat16, device=None, mesh=None) 
             "final_norm": ones((d,), "final_norm"),
             "lm_head": qleaf(next(keys), (d, cfg.vocab_size), "lm_head"),
         }
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _int8_leaf(key, shape: tuple, scale_dtype: str) -> QTensor:
+    """One seeded int8 leaf generated where it will live: absmax 127 at a
+    weight scale of 0.02 (dequantised std about 0.012)."""
+    q = jax.random.randint(key, shape, -127, 128, jnp.int8)
+    return QTensor(q=q, scale=jnp.full(shape[:-2] + (1,) + shape[-1:], 0.02 / 127.0, scale_dtype))
+
+
+def _synthetic_hybrid(cfg, dtype, device) -> dict:
+    """The hybrid block's pytree (models/hybrid.param_shapes), generated on
+    the device: every matrix a seeded int8 leaf like the others', every
+    vector dense from ``hybrid.vector_values`` (decays spread over about
+    (0.5, 0.999), a non-zero selection bias)."""
+    import jax
+
+    from ..models import hybrid
+
+    def qleaf(key, shape) -> QTensor:
+        return _int8_leaf(key, shape, jnp.dtype(dtype).name)
+
+    shapes = hybrid.param_shapes(cfg)
+    ctx = jax.default_device(device) if device is not None else _nullcontext()
+    with ctx:
+        keys = iter(jax.random.split(jax.random.PRNGKey(0), sum(len(g) for g in shapes.values()) + 2))
+        out = {
+            g: {
+                name: qleaf(next(keys), shape) if matrix
+                else hybrid.vector_values(name, shape, next(keys), dtype)
+                for name, (shape, matrix) in group.items()
+            }
+            for g, group in shapes.items()
+        }
+        out["embed"] = qleaf(next(keys), (cfg.vocab_size, cfg.dim))
+        out["lm_head"] = qleaf(next(keys), (cfg.dim, cfg.vocab_size))
+        out["final_norm"] = jnp.ones((cfg.dim,), dtype)
+        return out
 
 
 def _nullcontext():

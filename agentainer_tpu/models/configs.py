@@ -1,8 +1,12 @@
 """Model config registry.
 
 The flagship targets are Llama-3-8B (BASELINE.json config #2) and
-Mixtral-8x7B expert-parallel (config #5). Tiny variants exist for CI and the
-virtual CPU mesh — same code path, small shapes.
+Mixtral-8x7B expert-parallel (config #5); OLMoE-1B-7B is the same block with
+its two switches; Kimi-Linear-48B-A3B is the hybrid block
+(``layer_kinds``: gated-delta linear attention beside NoPE latent attention,
+a dense first layer, then sigmoid-routed experts with a shared one —
+models/hybrid.py). Tiny variants exist for CI and the virtual CPU mesh —
+same code path, small shapes.
 
 All dims are chosen TPU-aware: head_dim and hidden sizes are multiples of
 128 (MXU/VPU lane width) for the real configs.
@@ -35,10 +39,78 @@ class ModelConfig:
     # whole projected key, before the split into heads and the rotary
     # embedding (OLMoE)
     qk_norm: bool = False
+    # -- the hybrid block (models/hybrid.py); every default is "not hybrid" --
+    # mixer of each layer in order, "kda" (gated-delta linear attention: a
+    # recurrent state and a short-conv state per lane) or "mla" (latent
+    # attention without rotary embedding: one shared latent row per token);
+    # empty: every layer is RoPE GQA attention over a K/V arena
+    layer_kinds: tuple[str, ...] = ()
+    kda_heads: int = 0
+    kda_head_dim: int = 0  # of keys and of values
+    kda_conv: int = 4  # short causal depthwise conv over q, k, v
+    mla_kv_rank: int = 0  # the cached latent c (kv_lora_rank)
+    mla_nope_dim: int = 0  # per-head key dims expanded from the latent
+    mla_rope_dim: int = 0  # key dims shared by all heads, cached beside c
+    mla_v_dim: int = 0
+    # the first ``n_dense_layers`` have a dense SwiGLU of ``dense_ffn_dim``;
+    # the rest are MoE with ``ffn_dim`` wide experts
+    n_dense_layers: int = 0
+    dense_ffn_dim: int = 0
+    n_shared_experts: int = 0  # SwiGLU of n_shared × ffn_dim, added whole
+    # "softmax": ``moe_renormalize`` picks Mixtral's or OLMoE's rule.
+    # "sigmoid": sigmoid scores, top-k of score + a selection bias, the
+    # chosen scores (renormalised if ``moe_renormalize``) × ``moe_scale``
+    moe_router: str = "softmax"
+    moe_scale: float = 1.0
+    # the chip's share of the experts: the stack holds experts
+    # [expert_offset, expert_offset + experts_held) of ``n_experts``; the
+    # router keeps all ``n_experts`` outputs. 0: every expert is held
+    experts_held: int = 0
+    expert_offset: int = 0
 
     @property
     def head_dim(self) -> int:
         return self.dim // self.n_heads
+
+    @property
+    def is_hybrid(self) -> bool:
+        return bool(self.layer_kinds)
+
+    @property
+    def n_held(self) -> int:
+        """Experts in the stack (all of them unless the chip holds a share)."""
+        return self.experts_held or self.n_experts
+
+    @property
+    def n_kda(self) -> int:
+        return sum(k == "kda" for k in self.layer_kinds)
+
+    @property
+    def n_mla(self) -> int:
+        return sum(k == "mla" for k in self.layer_kinds)
+
+    def _hybrid_counts(self) -> dict:
+        """Parameters of the hybrid pytree by part (models/hybrid.init_params)."""
+        d, h, hk = self.dim, self.kda_heads, self.kda_head_dim
+        kda = (
+            3 * d * h * hk + h * hk * d  # q, k, v, o
+            + 2 * (d * hk + hk * h * hk)  # decay and output-gate low-rank pairs
+            + d * h  # beta
+            + 3 * self.kda_conv * h * hk  # conv filters
+            + h + h * hk + hk  # A_log, dt_bias, head norm
+        )
+        qk = self.mla_nope_dim + self.mla_rope_dim
+        mla = (
+            d * self.n_heads * qk
+            + d * (self.mla_kv_rank + self.mla_rope_dim)
+            + self.mla_kv_rank * self.n_heads * (self.mla_nope_dim + self.mla_v_dim)
+            + self.n_heads * self.mla_v_dim * d
+            + self.mla_kv_rank
+        )
+        expert = 3 * d * self.ffn_dim
+        moe = d * self.n_experts + self.n_experts + self.n_shared_experts * expert
+        return {"kda": kda, "mla": mla, "expert": expert, "moe_fixed": moe,
+                "dense": 3 * d * self.dense_ffn_dim}
 
     @property
     def is_moe(self) -> bool:
@@ -47,6 +119,15 @@ class ModelConfig:
     def param_count(self) -> int:
         """Exact parameter count of models/llama.init_params' pytree."""
         embed = self.vocab_size * self.dim
+        if self.is_hybrid:
+            c = self._hybrid_counts()
+            n_moe = self.n_layers - self.n_dense_layers
+            return (
+                2 * embed + self.dim + 2 * self.n_layers * self.dim
+                + self.n_kda * c["kda"] + self.n_mla * c["mla"]
+                + self.n_dense_layers * c["dense"]
+                + n_moe * (c["moe_fixed"] + self.n_held * c["expert"])
+            )
         per_layer_attn = self.dim * self.dim + 2 * self.dim * (
             self.n_kv_heads * self.head_dim
         ) + self.dim * self.dim
@@ -69,6 +150,11 @@ class ModelConfig:
         chip, but FLOP-utilization accounting follows the routed math)."""
         if not self.is_moe:
             return self.param_count()
+        if self.is_hybrid:
+            # a token's k routed experts, wherever they live (the model's need)
+            c = self._hybrid_counts()
+            n_moe = self.n_layers - self.n_dense_layers
+            return self.param_count() + n_moe * (self.experts_per_token - self.n_held) * c["expert"]
         full_ffn = 3 * self.dim * self.ffn_dim
         unused = (self.n_experts - self.experts_per_token) * full_ffn
         return self.param_count() - self.n_layers * unused
@@ -82,6 +168,15 @@ class ModelConfig:
         """
         # every weight matmul: 2 FLOPs per weight actually contracted
         matmul = 2.0 * self.active_param_count()
+        if self.is_hybrid:
+            # a KDA layer reads and rewrites its state whatever the context
+            # (4 passes over H·dk·dv: decay, k·S, rank-1 update, q·S); an MLA
+            # layer scores 192 dims and combines 128 per head and slot
+            kda = 8.0 * self.kda_heads * self.kda_head_dim**2
+            mla = 2.0 * self.n_heads * context_len * (
+                self.mla_nope_dim + self.mla_rope_dim + self.mla_v_dim
+            )
+            return matmul + self.n_kda * kda + self.n_mla * mla
         # attention scores + value combine: q·K^T and p·V, each
         # 2 * heads * head_dim * context MACs → 4 FLOPs per context slot
         attn = 4.0 * self.n_heads * self.head_dim * context_len
@@ -199,6 +294,88 @@ TINY_OLMOE = register(
         experts_per_token=2,
         moe_renormalize=False,
         qk_norm=True,
+    )
+)
+
+
+
+def kimi_linear_kinds(n_layers: int, period: int = 4) -> tuple[str, ...]:
+    """Kimi-Linear's published order (1-indexed ``full_attn_layers`` 4, 8, …
+    and the last layer): every ``period``-th layer and the last are MLA, the
+    rest KDA — so 27 layers end …24 MLA, 25 KDA, 26 KDA, 27 MLA."""
+    return tuple(
+        "mla" if (i % period == 0 or i == n_layers) else "kda" for i in range(1, n_layers + 1)
+    )
+
+
+# Kimi-Linear-48B-A3B (moonshotai/Kimi-Linear-48B-A3B-Instruct config.json:
+# 27 layers, hidden 2304, 20 KDA layers of 32 heads × 128 with a conv of 4
+# beside 7 NoPE MLA layers (latent 512 + 64 shared key dims, q/k 192, v 128),
+# a dense first layer of 9216, then 256 experts of 1024 top-8 behind a
+# sigmoid router with a selection bias, renormalised × 2.446, one shared
+# expert; vocabulary 163,840, untied). All 256 experts: 49.1 B parameters —
+# a chip serves its share (``experts_held``; benchmark/configs).
+KIMI_LINEAR_48B = register(
+    ModelConfig(
+        name="kimi-linear-48b",
+        vocab_size=163_840,
+        dim=2304,
+        n_layers=27,
+        n_heads=32,
+        n_kv_heads=32,
+        ffn_dim=1024,
+        max_seq_len=1_048_576,
+        rope_theta=10_000.0,  # published and unused: mla_use_nope
+        norm_eps=1e-5,
+        n_experts=256,
+        experts_per_token=8,
+        moe_renormalize=True,
+        layer_kinds=kimi_linear_kinds(27),
+        kda_heads=32,
+        kda_head_dim=128,
+        kda_conv=4,
+        mla_kv_rank=512,
+        mla_nope_dim=128,
+        mla_rope_dim=64,
+        mla_v_dim=128,
+        n_dense_layers=1,
+        dense_ffn_dim=9216,
+        n_shared_experts=1,
+        moe_router="sigmoid",
+        moe_scale=2.446,
+    )
+)
+
+# The hybrid block at CI shapes: 9 layers in the published pattern (K K K M
+# K K K M M: the short last period), a dense first layer, 8 experts top-2
+# with a shared one.
+TINY_KIMI_LINEAR = register(
+    ModelConfig(
+        name="tiny-kimi-linear",
+        vocab_size=512,
+        dim=64,
+        n_layers=9,
+        n_heads=4,
+        n_kv_heads=4,
+        ffn_dim=32,
+        max_seq_len=256,
+        norm_eps=1e-5,
+        n_experts=8,
+        experts_per_token=2,
+        moe_renormalize=True,
+        layer_kinds=kimi_linear_kinds(9),
+        kda_heads=4,
+        kda_head_dim=16,
+        kda_conv=4,
+        mla_kv_rank=32,
+        mla_nope_dim=16,
+        mla_rope_dim=8,
+        mla_v_dim=16,
+        n_dense_layers=1,
+        dense_ffn_dim=128,
+        n_shared_experts=1,
+        moe_router="sigmoid",
+        moe_scale=2.446,
     )
 )
 

@@ -79,9 +79,27 @@ class PagedKVCache(NamedTuple):
         return PagedKVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
 
 
+def init_cache(
+    cfg: ModelConfig, lanes: int, max_seq: int, dtype: jnp.dtype = jnp.bfloat16, live: bool = True
+):
+    """The cache a model's ``forward`` reads and writes, built by the model:
+    a :class:`KVCache`, or the hybrid block's pytree (models/hybrid.py:
+    positional latent rows beside per-lane recurrent state). ``live=False``
+    starts a hybrid cache's lanes closed, as an engine wants them."""
+    if cfg.is_hybrid:
+        from . import hybrid
+
+        return hybrid.init_cache(cfg, lanes, max_seq, dtype, live=live)
+    return KVCache.create(cfg, lanes, max_seq, dtype=dtype)
+
+
 def init_params(cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype = jnp.bfloat16) -> dict:
     """Random init (truncated-normal-ish 0.02 scale). Checkpoint loading maps
     onto the same pytree (engine/checkpoint.py)."""
+    if cfg.is_hybrid:
+        from . import hybrid
+
+        return hybrid.init_params(cfg, key, dtype)
     keys = iter(jax.random.split(key, 16))
     d, hd = cfg.dim, cfg.head_dim
 
@@ -130,13 +148,26 @@ def _mlp(x: jnp.ndarray, lp: dict) -> jnp.ndarray:
     return (gate * (x @ lp["w_up"])) @ lp["w_down"]
 
 
-def moe_gates(logits: jnp.ndarray, cfg: ModelConfig, dtype) -> tuple[jnp.ndarray, jnp.ndarray]:
+def moe_gates(
+    logits: jnp.ndarray, cfg: ModelConfig, dtype, bias: jnp.ndarray | None = None
+) -> tuple[jnp.ndarray, jnp.ndarray]:
     """The router rule, once for every MoE path: ``(gates, chosen)``, both
     ``[..., k]``, from router logits ``[..., E]``. ``moe_renormalize``
     (Mixtral): top-k of the logits, float32 softmax over the chosen k, so a
     token's gates sum to 1. Otherwise (OLMoE, ``norm_topk_prob: false``):
-    float32 softmax over all E experts, the top k kept as they are."""
+    float32 softmax over all E experts, the top k kept as they are.
+    ``moe_router == "sigmoid"`` (Kimi-Linear): float32 sigmoid scores; the
+    top k of score + ``bias`` (the selection bias chooses and never weighs);
+    the chosen scores, divided by their sum under ``moe_renormalize``, times
+    ``moe_scale``."""
     k = cfg.experts_per_token
+    if cfg.moe_router == "sigmoid":
+        scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+        _, chosen = lax.top_k(scores if bias is None else scores + bias.astype(jnp.float32), k)
+        gates = jnp.take_along_axis(scores, chosen, axis=-1)
+        if cfg.moe_renormalize:
+            gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+        return (gates * cfg.moe_scale).astype(dtype), chosen
     if cfg.moe_renormalize:
         top, chosen = lax.top_k(logits, k)
         return jax.nn.softmax(top.astype(jnp.float32), axis=-1).astype(dtype), chosen
@@ -145,7 +176,7 @@ def moe_gates(logits: jnp.ndarray, cfg: ModelConfig, dtype) -> tuple[jnp.ndarray
     return gates.astype(dtype), chosen
 
 
-def _moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig) -> jnp.ndarray:
+def _moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig, logits: jnp.ndarray | None = None) -> jnp.ndarray:
     """All-experts einsum MoE (top-k routing, every expert computed, masked
     combine): exact, dropless, branch-free, and E/k× the routed FLOPs.
 
@@ -154,10 +185,20 @@ def _moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig) -> jnp.ndarray:
     ``ops/moe.sorted_from_rows`` — on one chip, and every call of a ``tp``
     mesh. Calls with more rows on one chip take ``_moe_mlp_sorted``; an
     ``ep > 1`` mesh (or the ``routed`` option) takes ``_moe_mlp_routed``.
+
+    A chip that holds a share of the experts (``cfg.experts_held``) routes
+    over all of them and sums the terms of the experts in its stack: a choice
+    of an absent expert one-hots to a zero row. ``logits [B, T, E]``: the
+    router's logits where the caller computes them itself (the hybrid block,
+    in float32: models/hybrid.router_logits).
     """
     b, t, d = x.shape
-    weights, chosen = moe_gates(x @ lp["router"], cfg, x.dtype)  # [B,T,K]
-    onehot = jax.nn.one_hot(chosen, cfg.n_experts, dtype=x.dtype)  # [B,T,K,E]
+    if logits is None:
+        logits = x @ lp["router"]
+    weights, chosen = moe_gates(logits, cfg, x.dtype, lp.get("router_bias"))  # [B,T,K]
+    if cfg.expert_offset:
+        chosen = chosen - cfg.expert_offset
+    onehot = jax.nn.one_hot(chosen, cfg.n_held, dtype=x.dtype)  # [B,T,K,E held]
     combine = jnp.einsum("btk,btke->bte", weights, onehot)  # [B,T,E]
     gate = jax.nn.silu(jnp.einsum("btd,edf->btef", x, lp["w_gate"]))
     up = jnp.einsum("btd,edf->btef", x, lp["w_up"])
@@ -166,7 +207,7 @@ def _moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig) -> jnp.ndarray:
 
 
 def _moe_mlp_sorted(
-    x: jnp.ndarray, lp: dict, cfg: ModelConfig, experts: dict, layer
+    x: jnp.ndarray, lp: dict, cfg: ModelConfig, experts: dict, layer, logits: jnp.ndarray | None = None
 ) -> jnp.ndarray:
     """The MoE FFN of a call with many rows (a prefill chunk): the same
     router and the same sum as ``_moe_mlp``, computing only the (token,
@@ -176,19 +217,23 @@ def _moe_mlp_sorted(
     a bucket's padding rows are routed like any other row."""
     b, t, d = x.shape
     xf = x.reshape(b * t, d)
-    gates, chosen = moe_gates(xf @ lp["router"], cfg, x.dtype)
-    return sorted_moe_ffn(xf, gates, chosen, experts, layer).reshape(b, t, d)
+    logits = xf @ lp["router"] if logits is None else logits.reshape(b * t, -1)
+    gates, chosen = moe_gates(logits, cfg, x.dtype, lp.get("router_bias"))
+    held = (cfg.expert_offset, cfg.n_experts) if cfg.experts_held else None
+    return sorted_moe_ffn(xf, gates, chosen, experts, layer, held=held).reshape(b, t, d)
 
 
 def moe_sorted_from(cfg: ModelConfig, layers: dict) -> int | None:
     """The row count ``B·T`` from which a call sorts its MoE FFN: the rule
     (``ops/moe.sorted_from_rows``) applied to this model's experts as
-    ``layers`` stores them; ``None`` where no call does."""
+    ``layers`` stores them (a hybrid model's whole pytree: its experts are
+    the ``moe`` stack, and the rule is asked with what this chip holds);
+    ``None`` where no call does."""
     if not cfg.is_moe:
         return None
-    w = layers["w_gate"]
+    w = (layers["moe"] if "moe" in layers else layers)["w_gate"]
     dtype = w.q.dtype if isinstance(w, QTensor) else w.dtype
-    return sorted_from_rows(cfg.n_experts, cfg.experts_per_token, dtype)
+    return sorted_from_rows(cfg.n_held, cfg.experts_per_token, dtype)
 
 
 def moe_sorts(cfg: ModelConfig, layers: dict, n_rows: int) -> bool:
@@ -365,8 +410,14 @@ def forward(
     moe_impl=None,
     block_table: jnp.ndarray | None = None,
     slot: jnp.ndarray | None = None,
+    valid: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, KVCache | None]:
     """Returns (logits [B, T, V], updated cache).
+
+    A config with ``layer_kinds`` is the hybrid block (models/hybrid.py: its
+    own cache pytree, ``cache_attn_impl`` its plan, ``valid [B, T]`` the rows
+    of a bucket that are real — a recurrent state, unlike K/V rows, must not
+    see padding).
 
     With a cache: serves prefill (T = prompt chunk) and decode (T = 1) with
     per-sequence positions — the continuous-batching engine relies on this.
@@ -386,6 +437,15 @@ def forward(
     many the sorted grouped FFN, which reads the expert stack in place — so
     the experts then stay out of the layer scan's slices altogether.
     """
+    if cfg.is_hybrid:
+        from . import hybrid
+
+        if block_table is not None:
+            raise ValueError("the hybrid block has no paged cache")
+        return hybrid.forward(
+            params, cfg, tokens, positions, cache,
+            plan=cache_attn_impl, moe_impl=moe_impl, slot=slot, valid=valid,
+        )
     x = embed_lookup(params["embed"], tokens)
     lp_stack = params["layers"]
     experts = None
@@ -453,7 +513,7 @@ def greedy_decode(
     Engine-grade batching lives in engine/llm.py; this is the simple path
     used by tests and the graft entry."""
     b, tp = prompt.shape
-    cache = KVCache.create(cfg, b, cache_len, dtype=dtype)
+    cache = init_cache(cfg, b, cache_len, dtype=dtype)
     positions = jnp.broadcast_to(jnp.arange(tp), (b, tp))
     logits, cache = forward(params, cfg, prompt, positions, cache)
     last = jnp.argmax(logits[:, -1], axis=-1)  # [B]

@@ -76,13 +76,15 @@ def row_tile(n_rows: int, n_experts: int, k: int) -> int:
     return min(_MAX_TILE, max(_MIN_TILE, 1 << (2 * share - 1).bit_length()))
 
 
-def sorted_rows(n_rows: int, n_experts: int, k: int) -> int:
+def sorted_rows(n_rows: int, n_experts: int, k: int, tile: int | None = None) -> int:
     """Rows of the buffer the grouped FFN is given for a call of ``n_rows``:
     every tile the worst routing can need. A group of c rows takes
     ``ceil(c / tile)`` tiles; the groups hold ``n_rows·k`` rows between
-    them and none more than ``n_rows`` (a token's choices are distinct)."""
-    tile = row_tile(n_rows, n_experts, k)
-    a = n_rows * k
+    them and none more than ``n_rows`` (a token's choices are distinct).
+    ``n_experts`` is what the stack holds (a token has at most that many of
+    its choices here)."""
+    tile = tile if tile is not None else row_tile(n_rows, n_experts, k)
+    a = n_rows * min(k, n_experts)
     tiles = min(n_experts * -(-n_rows // tile), (a + n_experts * (tile - 1)) // tile)
     return tiles * tile
 
@@ -160,10 +162,15 @@ def sorted_moe_ffn(
     *,
     kernel: bool | None = None,
     interpret: bool = False,
+    held: tuple[int, int] | None = None,
 ) -> jnp.ndarray:
     """``[N, d]``: ``Σ_j gates[n, j] · FFN_{chosen[n, j]}(x[n])``, computing
     only those pairs. ``kernel``: the Pallas grouped FFN (default: on a TPU
-    backend).
+    backend). ``held = (offset, n_total)``: ``experts`` is the chip's share
+    ``[offset, offset + E)`` of ``n_total`` experts and ``chosen`` indexes
+    all of them; assignments to absent experts are dropped before the sort
+    (they sort last, get no buffer row and add nothing), and the row tile
+    follows the share of a token's choices that lands here.
 
     Rows go to the buffer and come back through one 0/1 matrix ``[M, N]``
     (row r holds token n) on the MXU: exact — a row is one token's values,
@@ -173,9 +180,23 @@ def sorted_moe_ffn(
     grows with N², a gather with N.)"""
     n, k = chosen.shape
     n_experts = experts["w_gate"][0].shape[1]
-    tile = row_tile(n, n_experts, k)
-    m = sorted_rows(n, n_experts, k)
-    routing = route(chosen, n_experts, tile, m)
+    if held is None:
+        tile = row_tile(n, n_experts, k)
+        m = sorted_rows(n, n_experts, k)
+        routing = route(chosen, n_experts, tile, m)
+    else:
+        offset, n_total = held
+        tile = row_tile(n, n_total, k)
+        m = sorted_rows(n, n_experts, k, tile)
+        local = chosen - offset
+        here = (local >= 0) & (local < n_experts)
+        routing = route(jnp.where(here, local, n_experts), n_experts, tile, m)
+        # a call none of whose choices land here still runs tile 0 (rows of
+        # gate 0): the kernel's index maps address tile ``n_active - 1``
+        routing = routing._replace(
+            row_of=jnp.where(here.reshape(-1), routing.row_of, m),
+            n_active=jnp.maximum(routing.n_active, 1),
+        )
     # holds[r, a]: buffer row r is assignment a (token a // k). M·N·k compares,
     # 80 µs of a 0.73 ms OLMoE layer at 256 rows; with the tokens minor
     # (``[M, k, N]``) the gate's reduction measured 12 x slower

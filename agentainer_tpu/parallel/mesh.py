@@ -77,6 +77,18 @@ def plan_layout(
     left over stay idle).
     """
     budget = n_assigned or min(n_visible, max(1, tp_asked) * max(1, ep_asked))
+    if cfg.is_hybrid and not (tp_asked or ep_asked):
+        # the hybrid block is served on one chip (its share of a stated
+        # expert-parallel deployment is ``cfg.experts_held``); an explicit
+        # tp/ep passes through and the engine refuses it by name
+        # (engine/llm.cache_features)
+        if n_assigned > 1:
+            print(
+                f"[llm-engine] parallelism narrowed to tp=1 ep=1: {cfg.name} is served on one "
+                f"chip (assigned chips={n_assigned}); the others stay idle",
+                flush=True,
+            )
+        return 1, 1
     if cfg.is_moe:
         # EP-first: experts dominate a MoE model's HBM footprint. Explicit
         # tp/ep options override the split.

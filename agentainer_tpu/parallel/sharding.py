@@ -56,6 +56,29 @@ def param_specs(moe: bool, qk_norm: bool = False) -> dict:
     }
 
 
+def hybrid_param_specs(cfg) -> dict:
+    """PartitionSpec tree matching models/hybrid.init_params: the routed
+    experts split their expert axis over ``ep`` (each chip holds
+    ``n_experts / ep`` of every MoE layer); mixers, the shared expert, the
+    router, norms, embedding and head are replicated — the deployment the
+    benchmark's one-chip share stands for. (An engine serves the block on one
+    chip today, holding ``cfg.experts_held``: the exchange that sums the
+    shares is not written, and ``engine/llm.cache_features`` refuses a mesh.)"""
+    from ..models.hybrid import param_shapes
+
+    def spec(group: str, name: str, shape: tuple) -> P:
+        if group == "moe" and name in ("w_gate", "w_up", "w_down"):
+            return P(None, "ep", None, None)
+        return P(*([None] * len(shape)))
+
+    tree = {
+        g: {name: spec(g, name, shape) for name, (shape, _) in group.items()}
+        for g, group in param_shapes(cfg).items()
+    }
+    tree.update(embed=P(None, None), lm_head=P(None, None), final_norm=P(None))
+    return tree
+
+
 def shardings_from_specs(mesh: Mesh, specs) -> dict:
     """Map an arbitrary PartitionSpec tree onto ``mesh`` — THE one place a
     spec becomes a NamedSharding (init-time out_shardings and serve-time

@@ -77,6 +77,9 @@ ASSIGNED = {
     "tiny-moe": [(1, 1), (1, 2), (1, 4), (2, 4)],
     "tiny-olmoe": [(1, 1), (1, 2), (1, 4), (1, 8)],
     "bench-1b": [(1, 1), (2, 1), (4, 1), (8, 1)],
+    # the hybrid block is served on one chip whatever is assigned (PR 30)
+    "kimi-linear-48b": [(1, 1), (1, 1), (1, 1), (1, 1)],
+    "tiny-kimi-linear": [(1, 1), (1, 1), (1, 1), (1, 1)],
 }
 
 

@@ -474,6 +474,7 @@ def test_moe_block_names_no_path_for_a_dense_model(served):
     for doc in (before, m):
         assert doc["moe"] == {
             "impl": "none", "experts": 0, "top_k": 0, "renormalize": False,
+            "experts_held": 0, "shared_experts": 0, "router": None,
             "prefill_impl": "none", "routed_from_rows": None,
             "assignments": 0, "rows_all_experts": 0, "rows_routed": 0,
         }
@@ -499,6 +500,8 @@ def test_moe_block_names_the_path_the_steps_trace(config, options, impl, prefill
         eng.shutdown()
     assert m["moe"] == {
         "impl": impl, "experts": cfg.n_experts, "top_k": cfg.experts_per_token, "renormalize": cfg.moe_renormalize,
+        # every expert is in the stack, none is shared, the router is a softmax (ISSUE 30's three keys)
+        "experts_held": cfg.n_experts, "shared_experts": 0, "router": "softmax",
         "prefill_impl": prefill_impl, "routed_from_rows": cut,
         "assignments": 0, "rows_all_experts": 0, "rows_routed": 0,
     }

@@ -15,6 +15,7 @@ on-chip numerics in chip_smoke.py).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -252,3 +253,70 @@ def test_prefill_over_the_cut_holds_no_expert_sized_temporary_on_v5e(v5e, monkey
     einsum_temp = temp_bytes(partial(_moe_mlp, cfg=cfg))
     assert sorted_temp < layer_matrix // 16, sorted_temp
     assert einsum_temp > layer_matrix, einsum_temp
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_hybrid_step_holds_no_stack_sized_temporary_on_v5e(v5e, step):
+    """Kimi-Linear's block at published widths (the dense layer and one
+    period, K K K M K, 8 lanes of 1024), int8 as served, its kernels on: the
+    KDA decode kernel updates the layer of the float32 state stack in place
+    and the MLA decode kernel reads the latent stack where it lies (Mosaic
+    accepts both: a ``[bk, 640]`` row block, a ``[8, 128, 128]`` state tile),
+    and through the layer scan, the mixers' 0-or-1-trip loops and the step
+    scan no stack is copied. Three things this guards were all found by this
+    compile and by nothing on the CPU (PR 30): ``lax.cond`` over the mixers
+    copied the stack a branch only passed through, every layer (2.7 GB of
+    state in each MLA layer at 64 lanes); a 576-wide latent row made the
+    chip keep the arena position-minor and relayout it into and out of every
+    launch; a conv state ``[.., 3, 12288]`` was padded 42-fold."""
+    import dataclasses
+
+    from jax import lax
+
+    from agentainer_tpu.engine.quant import synthetic_quantized_params
+    from agentainer_tpu.models import hybrid
+    from agentainer_tpu.models.configs import kimi_linear_kinds
+    from agentainer_tpu.models.llama import init_cache
+
+    lanes, seq = 8, 1024
+    cfg = dataclasses.replace(
+        get_config("kimi-linear-48b"), n_layers=5, layer_kinds=kimi_linear_kinds(27)[:5],
+        experts_held=32, vocab_size=8192, name="kimi-5l",
+    )
+    where = SingleDeviceSharding(v5e.devices[0])
+    place = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=where)  # noqa: E731
+    params = jax.tree.map(place, jax.eval_shape(lambda: synthetic_quantized_params(cfg, jnp.bfloat16)))
+    cache = jax.tree.map(place, jax.eval_shape(lambda: init_cache(cfg, lanes, seq, jnp.bfloat16, live=False)))
+    plan = hybrid.plan_hybrid(cfg, use_pallas=True)
+    assert (plan.kda_decode, plan.mla_decode) == ("pallas_kda_decode", "pallas_mla_decode")
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=where)  # noqa: E731
+
+    def decode_n(params, cache, tokens, positions):
+        def one(carry, _):
+            tok, pos, cache = carry
+            logits, cache = forward(params, cfg, tok[:, None], pos[:, None], cache, cache_attn_impl=plan)
+            return (jnp.argmax(logits[:, 0], -1).astype(jnp.int32), jnp.minimum(pos + 1, seq - 1), cache), tok
+
+        (tok, pos, cache), toks = lax.scan(one, (tokens, positions, cache), None, length=4)
+        return toks, tok, pos, cache
+
+    def prefill(params, cache, slot, tokens, positions, n_real):
+        valid = jnp.arange(tokens.shape[1])[None, :] < n_real
+        logits, cache = forward(params, cfg, tokens, positions, cache, cache_attn_impl=plan, slot=slot, valid=valid)
+        return logits[0, -1], cache
+
+    if step == "decode":
+        compiled = jax.jit(decode_n, donate_argnums=(1, 2, 3)).lower(params, cache, i32(lanes), i32(lanes)).compile()
+    else:
+        compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
+            params, cache, i32(), i32(1, 256), i32(1, 256), i32()).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    if step == "decode":
+        assert "kda_decode" in text and "mla_decode" in text  # the names the roofline readers find
+    stacks = {name: getattr(cache, name) for name in ("latent", "state", "conv")}
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(s.size * s.dtype.itemsize for s in stacks.values())  # every leaf donated in place
+    for name, s in stacks.items():  # and no copy or relayout of a whole stack anywhere in the program
+        shape = ",".join(map(str, s.shape))
+        assert not re.search(rf"\[{shape}\][^ ]* (copy|transpose)\(", text), name

@@ -193,16 +193,18 @@ def _tensor_dims(t: str) -> tuple[int, ...]:
 class ArenaRidesInCarry:
     """The stacked KV arena stays one buffer through a step program: over
     the LOWERED (StableHLO) text of ``jit_decode_n`` / ``jit_verify`` /
-    ``jit_prefill``.
+    ``jit_prefill`` / ``jit_prefill_with_decode``.
 
-    - Each K and V stack is a carried value of ``loops`` while loops (the
-      layer scan; for ``jit_decode_n`` the step scan around it too), and a
+    - Each K and V stack is a carried value of exactly ``loops`` while loops
+      (the layer scan; for ``jit_decode_n`` the step scan around it too; one
+      more loop over the layers would stream the weights once more), and a
       loop that carries the arena carries exactly the two stacks: the
       ``xs``/``ys`` form of a scan carries four (the stacks it slices a
       layer out of and the stacks it rebuilds layer by layer).
     - Every write into a stack (scatter or dynamic_update_slice whose
       operand has the arena's shape) updates exactly the step's new rows
-      ``[B, T, KV, hd]`` — never a layer ``[B, S, KV, hd]``, which is what
+      ``[B, T, KV, hd]`` (``rows``: one shape, or one per group of rows the
+      step writes) — never a layer ``[B, S, KV, hd]``, which is what
       a stacked scan output writes back in every layer-step.
 
     Reads are not judged here: an implementation that cannot address the
@@ -213,12 +215,14 @@ class ArenaRidesInCarry:
     """
 
     arena: tuple[int, ...]  # [L, B, S, KV, hd]
-    rows: tuple[int, ...]  # [B, T, KV, hd]
+    rows: tuple  # [B, T, KV, hd], or a tuple of such shapes
     loops: int = 1
 
     def failures(self, text: str) -> list[str]:
         out: list[str] = []
-        arena, n_rows = tuple(self.arena), math.prod(self.rows)
+        arena = tuple(self.arena)
+        groups = self.rows if isinstance(self.rows[0], (tuple, list)) else (self.rows,)
+        n_rows = {math.prod(g) for g in groups}
         # arena-shaped values each while loop carries, for the loops that carry any
         carrying = [
             n
@@ -231,6 +235,11 @@ class ArenaRidesInCarry:
                 f"while loops (need {self.loops}): it is sliced and restacked "
                 "around the layer loop, not carried through it"
             )
+        if len(carrying) > self.loops:
+            out.append(
+                f"the arena is carried by {len(carrying)} while loops (want exactly "
+                f"{self.loops}): a second loop over the layers reads the weights again"
+            )
         if any(n != 2 for n in carrying):
             out.append(
                 f"loops carry {carrying} arena-shaped values each (want 2: the "
@@ -242,13 +251,16 @@ class ArenaRidesInCarry:
             for m in rx.finditer(text)
         ]
         updates = [upd for operand, upd in writes if operand == arena]
-        if len(updates) < 2:
-            out.append(f"found {len(updates)} writes into the arena (need K and V)")
-        bad = [u for u in updates if math.prod(u) != n_rows]
+        if len(updates) < 2 * len(groups):
+            out.append(
+                f"found {len(updates)} writes into the arena (need K and V of "
+                f"{len(groups)} group(s) of rows)"
+            )
+        bad = [u for u in updates if math.prod(u) not in n_rows]
         if bad:
             out.append(
                 f"writes into the arena of shapes {bad}: every write must be the "
-                f"step's new rows {list(self.rows)} ({n_rows} elements), "
+                f"step's new rows {[list(g) for g in groups]} ({sorted(n_rows)} elements), "
                 f"a layer is {math.prod(arena[1:])}"
             )
         return out
@@ -373,7 +385,10 @@ def engine_jit_fns(engine) -> dict[str, object]:
     prefix fork/slice buckets, paged snapshot/restore). The names are the
     compile-key families the recompile budget is written against."""
     fns: dict[str, object] = {}
-    for attr in ("_prefill", "_first_token", "_decode_n", "_inject", "_alloc_cache", "_alloc_carry"):
+    for attr in (
+        "_prefill", "_prefill_with_decode", "_first_token", "_decode_n", "_inject",
+        "_alloc_cache", "_alloc_carry",
+    ):
         fn = getattr(engine, attr, None)
         if fn is not None:
             fns[attr] = fn

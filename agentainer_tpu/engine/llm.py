@@ -247,6 +247,12 @@ class PrefillFailed(RuntimeError):
     riding the full respawn/backoff ladder."""
 
 
+class RidersFault(Exception):
+    """The decode failpoint fired before a prefill chunk's launch that was
+    to carry the decode lanes: the lanes' fault (``__cause__``), batch-wide
+    like any decode fault, not the prompt's."""
+
+
 def _as_prefill_failure(e: Exception) -> Exception:
     """Classify a prefill-tick exception: policy terminations pass through
     typed (they map to their own HTTP statuses); anything else becomes
@@ -940,6 +946,10 @@ class LLMEngine:
         # how often contention shrank below the configured chunk
         self.decode_chunk_hist: dict[int, int] = {}
         self.decode_chunks_shrunk = 0
+        # prefill launches that carried the decode lanes' step with them
+        # (``jit_prefill_with_decode``), and the live lanes that rode
+        self.mixed_launches = 0
+        self.mixed_decode_lanes = 0
         self.worker_errors = 0
         self.last_worker_error = ""
         self.cache_resets = 0
@@ -1487,6 +1497,26 @@ class LLMEngine:
             (tok, pos, cache), toks = lax.scan(step, (tokens, positions, cache), keys)
             return toks, tok, pos, cache  # toks [chunk, B]
 
+        def prefill_with_decode(
+            params, cache, slot, tokens, positions, n_real, lane_tok, lane_pos, temps, topk, topp, keys
+        ):
+            """A prefill chunk and the decode lanes' step beside it in ONE
+            launch: ``prefill``'s chunk at arena row ``slot`` and one step of
+            ``decode_n`` over the carry, their rows run together through
+            every layer (``forward``'s ``lanes``), so the weights stream once
+            for both. Returns what the two return: the chunk's last logits,
+            ``toks [1, B]``, the advanced carry, the cache. ``keys [1]`` is
+            the key a one-step ``decode_n`` would take."""
+            logits, cache = run_forward(
+                params, tokens, positions, cache, slot=slot,
+                lanes=(lane_tok[:, None], lane_pos[:, None]), last=n_real - 1,
+            )
+            nxt = sample_step(
+                logits[1:], keys[0], temps, topk, topp,
+                greedy_cond=True, approx_topk=self.approx_topk,
+            )
+            return logits[0], nxt[None], nxt, jnp.minimum(lane_pos + 1, scratch_static), cache
+
         def decode_n_paged(params, cache, bt, tokens, positions, temps, topk, topp, keys):
             # positional-arg adapter for the call-site splat (bt sits
             # between cache and the token state); the body is decode_n
@@ -1540,6 +1570,21 @@ class LLMEngine:
         else:
             self._prefill = jax.jit(prefill, donate_argnums=(1,))
             self._decode_n = jax.jit(decode_n, donate_argnums=(1, 2, 3))
+        # Does this engine have the mixed step? Where the plan is the K/V
+        # block's dense arena on one chip under the per-chunk decode driver,
+        # with ``forward`` choosing the MoE path by row count. The hybrid
+        # block (two mixers side by side, a state that must not see the other
+        # group's rows), the page pool, the fused loop, a mesh and the
+        # ``routed`` dispatch (whose capacity a chunk's rows would share with
+        # the lanes') each need a body of their own: they keep two launches,
+        # as does a decode ladder without the one-step rung the program
+        # stands in for.
+        self._prefill_with_decode = None
+        if self._decode_ladder[0] == 1 and not (
+            self._recurrent or self.paged or self.fused_decode
+            or self.mesh is not None or moe_impl is not None
+        ):
+            self._prefill_with_decode = jax.jit(prefill_with_decode, donate_argnums=(1, 6, 7))
         self._inject = jax.jit(inject, donate_argnums=(0, 1, 2, 3, 4, 5, 6))
         self._first_token = jax.jit(first_token)
         if self._recurrent:
@@ -1969,6 +2014,23 @@ class LLMEngine:
                     key,
                 )
             jax.block_until_ready(self.cache.k)
+        # the mixed step (a prefill chunk that carries the decode lanes'
+        # step): warm-up serves one request at a time and so never has a
+        # chunk pending beside a decoding lane. One program per bucket a
+        # chunk can take, against the live carry and cache like the verify
+        # ladder: every lane is parked, the chunk's rows land in slot 0,
+        # which ``clear_sessions`` below leaves cold.
+        if self._prefill_with_decode is not None:
+            for b in PREFILL_BUCKETS:
+                if b > top_bucket:
+                    break
+                self._launch_with_decode(
+                    0,
+                    jnp.asarray(np.zeros((1, b), np.int32)),
+                    jnp.asarray(np.arange(b, dtype=np.int32)[None]),
+                    b,
+                )
+            jax.block_until_ready(self.cache.k)
         # warmup traffic is not serving telemetry: TTFT samples here include
         # compile time and would pollute p50s until the deque rolls over
         self.clear_sessions()
@@ -1979,6 +2041,8 @@ class LLMEngine:
         self.first_readback_ms_recent.clear()
         self.decode_chunk_hist = {}
         self.decode_chunks_shrunk = 0
+        self.mixed_launches = 0
+        self.mixed_decode_lanes = 0
         self.fused_loops_total = 0
         self.fused_steps_total = 0
         self.fused_early_exits_total = 0
@@ -3351,6 +3415,14 @@ class LLMEngine:
                 str(k): v for k, v in sorted(self.decode_chunk_hist.copy().items())
             },
             "decode_chunks_shrunk": self.decode_chunks_shrunk,
+            # prefill launches that carried the decode lanes' step (they
+            # count in prefill_launches AND decode_steps, never in
+            # decode_chunk_hist, which is ``jit_decode_n``'s), and the live
+            # lanes that rode: mixed_decode_lanes ÷ (batch_occupancy ×
+            # decode_steps × max_batch) is the share of lane-steps that
+            # cost no weight stream of their own
+            "mixed_launches": self.mixed_launches,
+            "mixed_decode_lanes": self.mixed_decode_lanes,
             # self-speculative decoding: drafted/accepted/rejected token
             # counters, verify-bucket histogram (.copy() for the same
             # mid-scrape reason as decode_chunk_hist), and each slot's live
@@ -3661,14 +3733,19 @@ class LLMEngine:
             # ONE prefill chunk, then a decode chunk: a long prompt is fed
             # through chunk-by-chunk between decode chunks, so admitting it
             # never stalls active generations for more than one chunk's
-            # latency. When NOTHING is decoding, prefill multi-ticks back to
+            # latency. Where the decode chunk would be the one-step rung
+            # (someone still waits on the worker after this chunk), the
+            # chunk's launch CARRIES that step (``_riders``): one launch,
+            # one stream of the weights, for both. When NOTHING is decoding,
+            # prefill multi-ticks back to
             # back instead — a cold 1024-token prompt must not pay a full
             # worker iteration of decode-dispatch bookkeeping per 256-token
             # chunk. Prefill faults are PER-REQUEST: the culprit request
             # fails, everyone else keeps decoding (VERDICT r4 item 1b — a
             # single poisoned prompt used to fail every in-flight request).
+            rode = False
             try:
-                self._prefill_tick()
+                rode = self._prefill_tick(self._riders())
                 while self.adaptive_decode and not any(
                     s.decoding for s in self.slots
                 ) and any(
@@ -3682,6 +3759,8 @@ class LLMEngine:
                         break
                     self._admit_waiting()
                     self._prefill_tick()
+            except RidersFault as e:
+                self._fail_batch(e.__cause__)
             except Exception as e:
                 self._note_error(e)
                 slot = self._prefilling_slot
@@ -3692,7 +3771,9 @@ class LLMEngine:
             finally:
                 self._prefilling_slot = None
             try:
-                if any(s.decoding for s in self.slots):
+                if rode:
+                    pass  # the lanes' step went with the chunk
+                elif any(s.decoding for s in self.slots):
                     # speculative verify round when lanes have drafts;
                     # otherwise (or under contention) the plain pipelined
                     # decode-chunk path — gamma collapse makes low-match
@@ -3717,20 +3798,23 @@ class LLMEngine:
                     or not self._has_dispatchable()
                 )
             except Exception as e:
-                # a decode/readback fault is batch-wide by construction (one
-                # compiled call covers every lane): fail the in-flight
-                # requests, then verify the donated device state survived —
-                # if not, reallocate so the engine serves on, sessions cold
-                self._note_error(e)
-                for slot in self.slots:
-                    if slot.request is not None:
-                        self._fail_item(slot.request, e)
-                        self._reset_slot(slot)
-                self._readbacks.clear()
-                self._ensure_device_state()
+                self._fail_batch(e)
             if not any(s.request is not None for s in self.slots) and self._waiting:
                 with self._spans.span("engine.wait_request"):
                     time.sleep(0.002)  # all slots busy-by-session; brief backoff
+
+    def _fail_batch(self, e: Exception) -> None:
+        """A decode/readback fault is batch-wide by construction (one
+        compiled call covers every lane): fail the in-flight requests, then
+        verify the donated device state survived — if not, reallocate so the
+        engine serves on, sessions cold."""
+        self._note_error(e)
+        for slot in self.slots:
+            if slot.request is not None:
+                self._fail_item(slot.request, e)
+                self._reset_slot(slot)
+        self._readbacks.clear()
+        self._ensure_device_state()
 
     def _pump_queue(self, block_s: float) -> None:
         """Drain the submit queue into the waiting list (a burst admits
@@ -4557,15 +4641,44 @@ class LLMEngine:
                 return b
         return PREFILL_BUCKETS[-1]
 
-    def _prefill_tick(self) -> None:
+    def _riders(self) -> list | None:
+        """The decode lanes this tick's prefill chunk carries with it (one
+        launch of ``jit_prefill_with_decode`` in place of ``jit_prefill``
+        and the one-step ``jit_decode_n``), as ``_decode_dispatch`` snapshots
+        them; ``None`` where the tick keeps its two launches: an engine
+        without the program, nothing pending, nothing decoding or no budget
+        left, or a tick whose decode would not be the plain one-step rung —
+        nobody waits on the worker once this chunk is in (the only prompt's
+        last chunk, no ``_admissible_waiter``), so the lanes keep their
+        verify round or their longer rung."""
+        if self._prefill_with_decode is None:
+            return None
+        pending = [s for s in self.slots if s.request is not None and s.pending_prompt]
+        if not pending:
+            return None
+        snapshot = [
+            (s, s.request, s.dev_position)
+            for s in self.slots
+            if s.decoding and s.request is not None
+        ]
+        if not snapshot or max(r.max_tokens - r.dispatched for _, r, _ in snapshot) <= 0:
+            return None
+        last_chunk = len(pending) == 1 and len(pending[0].pending_prompt) <= self.prefill_chunk
+        if last_chunk and not self._admissible_waiter():
+            return None
+        return snapshot
+
+    def _prefill_tick(self, riders: list | None = None) -> bool:
         """Feed ONE chunk of one pending prompt through the model (FIFO by
         submission time). Non-final chunks only populate the slot's KV; the
         final chunk samples the first token. Interleaving these ticks with
         decode steps bounds how long one long prompt can stall every active
-        generation: one chunk's latency, not the whole prompt's."""
+        generation: one chunk's latency, not the whole prompt's. With
+        ``riders`` (``_riders``) the chunk's launch carries the decode
+        lanes' step; True when it did."""
         slots = [s for s in self.slots if s.request is not None and s.pending_prompt]
         if not slots:
-            return
+            return False
         # admission-first: a prompt that has not started prefilling yet beats
         # an in-progress prompt's next chunk, so one long prompt cannot
         # monopolize the tick and push new arrivals' admission latency to
@@ -4580,9 +4693,9 @@ class LLMEngine:
             request_id=slot.request.id,
             tokens=min(len(slot.pending_prompt), self.prefill_chunk),
         ):
-            self._prefill_chunk(slot)
+            return self._prefill_chunk(slot, riders)
 
-    def _prefill_chunk(self, slot: Slot) -> None:
+    def _prefill_chunk(self, slot: Slot, riders: list | None = None) -> bool:
         span = self._spans.span
         req = slot.request
         # failpoint: a poisoned prefill fails THIS request only — the worker
@@ -4592,6 +4705,13 @@ class LLMEngine:
         # traffic, and an env-armed failpoint must not brick engine boot.
         if req.id:
             faults.fire("engine.prefill")
+        if riders and any(r.id for _, r, _ in riders):
+            # the decode seam of a launch that carries the lanes' step: after
+            # the prompt's own, so a poisoned prompt still fails alone
+            try:
+                faults.fire("engine.decode_step")
+            except Exception as e:
+                raise RidersFault() from e
         if req.prefill_started_at is None:
             req.prefill_started_at = time.monotonic()
             self.admission_ms_recent.append(
@@ -4640,7 +4760,7 @@ class LLMEngine:
                     # worker error and destroy the resident session
                     self._fail_item(req, e)
                     self._abandon_slot(slot, rollback=True)
-                    return
+                    return False
                 last_logits, self.cache = self._prefill(
                     self.params,
                     self.cache,
@@ -4649,13 +4769,16 @@ class LLMEngine:
                     pos,
                     jnp.int32(n),
                 )
+            elif riders:
+                with span("engine.mixed_dispatch", lanes=len(riders), tokens=n):
+                    last_logits, toks = self._launch_with_decode(slot.idx, tokens, pos, n)
             else:
                 last_logits, self.cache = self._prefill(
                     self.params, self.cache, jnp.int32(slot.idx), tokens, pos, jnp.int32(n)
                 )
         self.prefill_launches += 1
         self.prefill_tokens += n
-        self._count_forward(bucket)
+        self._count_forward(bucket + self.max_batch if riders else bucket)
         # n real tokens, each attending ~its own position of context
         self.flops_done += n * self.cfg.flops_per_token(slot.position + n // 2)
         self.hbm_bytes_read += self.param_hbm_bytes + (
@@ -4663,8 +4786,10 @@ class LLMEngine:
         )
         slot.position += n
         slot.last_used = time.monotonic()
+        if riders:
+            self._count_decode_step(riders, toks, rode=True)
         if not final:
-            return
+            return bool(riders)
         # whole fresh context now in KV: register its bucket-prefixes in
         # the arena (async device copies; positions [0:b] are real tokens —
         # the final chunk's padding lands strictly above slot.position)
@@ -4750,6 +4875,19 @@ class LLMEngine:
         except Exception:
             pass
         self._readbacks.append(("first", slot, req, first, time.monotonic()))
+        return bool(riders)
+
+    def _launch_with_decode(self, idx: int, tokens, pos, n: int):
+        """One launch of ``jit_prefill_with_decode``: the chunk ``tokens
+        [1, bucket]`` (``n`` real) at arena row ``idx`` and a step of the
+        decode carry. Returns the chunk's last logits and ``toks [1, B]``."""
+        self._rng, key = jax.random.split(self._rng)
+        last_logits, toks, self._dtok, self._dpos, self.cache = self._prefill_with_decode(
+            self.params, self.cache, jnp.int32(idx), tokens, pos, jnp.int32(n),
+            self._dtok, self._dpos, self._dtemps, self._dtopk, self._dtopp,
+            jax.random.split(key, 1),
+        )
+        return last_logits, toks
 
     def _finish(self, slot: Slot, pending_last: bool) -> None:
         """``pending_last``: the final generated token was sampled but not yet
@@ -4876,16 +5014,30 @@ class LLMEngine:
             self._dtopp,
             keys,
         )
+        self.decode_chunk_hist[chunk] = self.decode_chunk_hist.get(chunk, 0) + 1
+        self._count_forward(self.max_batch, chunk)
+        self._count_decode_step(snapshot, toks)
+
+    def _count_decode_step(self, snapshot: list, toks, rode: bool = False) -> None:
+        """The bookkeeping of a dispatched decode chunk ``toks [chunk, B]``
+        over ``snapshot``'s lanes, and its readback entry. ``rode``: the
+        step went with a prefill chunk's launch (``jit_prefill_with_decode``):
+        that launch streamed the weights and counted its forward pass, and
+        ``decode_chunk_hist`` stays ``jit_decode_n``'s launches alone (the
+        benchmark's decode roofline multiplies it by that module's launches
+        in a trace)."""
+        chunk = toks.shape[0]
         for s, r, _ in snapshot:
             s.dev_position += chunk
             r.dispatched += chunk
-        self.decode_chunk_hist[chunk] = self.decode_chunk_hist.get(chunk, 0) + 1
         self.decode_steps += 1
-        self._count_forward(self.max_batch, chunk)
         self._occupancy_sum += len(snapshot) / self.max_batch
+        if rode:
+            self.mixed_launches += 1
+            self.mixed_decode_lanes += len(snapshot)
         # weights stream once per scan step; each live lane streams its KV
         # prefix (parked lanes re-read the scratch row — not useful traffic)
-        self.hbm_bytes_read += chunk * self.param_hbm_bytes + sum(
+        self.hbm_bytes_read += (0 if rode else chunk * self.param_hbm_bytes) + sum(
             chunk * (p + chunk // 2) * self._kv_bytes_per_pos for _, _, p in snapshot
         )
         try:
@@ -5039,13 +5191,28 @@ class LLMEngine:
         inactive."""
         if not self.adaptive_decode:
             return self.decode_chunk
-        contended = any(s.request is not None and s.pending_prompt for s in self.slots)
-        if not contended and (self._waiting or not self._queue.empty()):
-            contended = any(s.request is None for s in self.slots)
-        if contended and self._decode_ladder[0] < self._fused_cap:
+        if self._contended() and self._decode_ladder[0] < self._fused_cap:
             self.decode_chunks_shrunk += 1
             return self._decode_ladder[0]
         return self._fused_cap
+
+    def _admissible_waiter(self) -> bool:
+        """A queued request that a free slot could take. A waiter only
+        benefits from a shrunk chunk if it can actually be admitted: when
+        every slot is mid-generation it is gated on a FINISH, not on the
+        worker loop's cadence — keep the full chunk or a saturated engine's
+        throughput would collapse to chunk-1 dispatch overhead."""
+        return bool(self._waiting or not self._queue.empty()) and any(
+            s.request is None for s in self.slots
+        )
+
+    def _contended(self) -> bool:
+        """Does anyone wait on the worker's cadence: a mid-prefill prompt,
+        or a queued request that a free slot could take."""
+        return (
+            any(s.request is not None and s.pending_prompt for s in self.slots)
+            or self._admissible_waiter()
+        )
 
     def _pick_chunk(self, needed: int, tail_shrink: bool = True) -> int:
         """Adaptive decode-chunk policy (the admission-aware half of the
@@ -5067,15 +5234,7 @@ class LLMEngine:
         can't early-exit on a waiter's behalf)."""
         if not self.adaptive_decode:
             return self.decode_chunk
-        contended = any(s.request is not None and s.pending_prompt for s in self.slots)
-        if not contended and (self._waiting or not self._queue.empty()):
-            # a queued waiter only benefits from a shrunk chunk if it can
-            # actually be admitted (a free slot): when every slot is mid-
-            # generation the waiter is gated on a FINISH, not on the worker
-            # loop's cadence — keep the full chunk or a saturated engine's
-            # throughput would collapse to chunk-1 dispatch overhead
-            contended = any(s.request is None for s in self.slots)
-        if contended and self._decode_ladder[0] < self.decode_chunk:
+        if self._contended() and self._decode_ladder[0] < self.decode_chunk:
             self.decode_chunks_shrunk += 1
             return self._decode_ladder[0]
         if not tail_shrink:

@@ -345,14 +345,24 @@ def _attention_block(
     block_table=None,
     layer=None,
     slot=None,
+    lane_positions=None,
 ):
     """One layer's attention. With a cache, ``ck``/``cv`` are the STACKED
     arena ``[L, B, S, KV, hd]`` (or page pool) and ``layer`` this layer's
     index in it: the layer's new rows are written into the stack and the
     attention reads layer ``layer`` of it, so nothing the size of a layer
     is sliced out, copied or written back. ``slot`` offsets the batch's
-    rows in the arena (one lane's prefill in the whole arena)."""
+    rows in the arena (one lane's prefill in the whole arena).
+
+    ``lane_positions [B, 1]``: ``x`` is ``[1, T + B, d]``, a chunk's T rows
+    for arena row ``slot`` and then one row for each of the arena's B lanes
+    (``forward``'s second group). The projections and the rotary embedding
+    run over all of them at once; both groups' new rows are written before
+    either is read, and each group's attention reads its own arena rows."""
     b, t, d = x.shape
+    if lane_positions is not None:
+        chunk_positions = positions
+        positions = jnp.concatenate([positions, lane_positions.reshape(1, -1)], axis=1)
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     q, k = h @ lp["wq"], h @ lp["wk"]
     if cfg.qk_norm:
@@ -367,7 +377,31 @@ def _attention_block(
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
 
-    if ck is not None:
+    if ck is not None and cache_attn_impl is None:
+        # engines choose once at build and pass their choice in (it is
+        # what they report); direct callers get the same choice here
+        cache_attn_impl = plan_cache_attention(
+            cfg.n_heads,
+            cfg.n_kv_heads,
+            cfg.head_dim,
+            page_size=ck.shape[3] if block_table is not None else 0,
+            use_pallas=use_flash,
+        ).fn
+    if lane_positions is not None:
+        n_lanes = lane_positions.shape[0]
+        tc = t - n_lanes
+
+        def groups(a):  # the chunk's rows [1, T, ...], the lanes' [B, 1, ...]
+            return a[:, :tc], a[0, tc:, None]
+
+        (qc, ql), (kc, kl), (vc, vl) = groups(q), groups(k), groups(v)
+        lanes = jnp.arange(n_lanes)[:, None]
+        ck = ck.at[layer, slot, chunk_positions].set(kc).at[layer, lanes, lane_positions].set(kl)
+        cv = cv.at[layer, slot, chunk_positions].set(vc).at[layer, lanes, lane_positions].set(vl)
+        attn_c = cache_attn_impl(qc, ck, cv, chunk_positions, None, layer, slot)
+        attn_l = cache_attn_impl(ql, ck, cv, lane_positions, None, layer, None)
+        attn = jnp.concatenate([attn_c, attn_l.reshape(1, n_lanes, cfg.n_heads, cfg.head_dim)], axis=1)
+    elif ck is not None:
         if layer is None:
             raise ValueError("a cache is the stacked arena: say which layer of it")
         if block_table is not None:
@@ -380,16 +414,6 @@ def _attention_block(
             rows = jnp.arange(b)[:, None] + (0 if slot is None else slot)
             ck = ck.at[layer, rows, positions].set(k)
             cv = cv.at[layer, rows, positions].set(v)
-        if cache_attn_impl is None:
-            # engines choose once at build and pass their choice in (it is
-            # what they report); direct callers get the same choice here
-            cache_attn_impl = plan_cache_attention(
-                cfg.n_heads,
-                cfg.n_kv_heads,
-                cfg.head_dim,
-                page_size=ck.shape[3] if block_table is not None else 0,
-                use_pallas=use_flash,
-            ).fn
         attn = cache_attn_impl(q, ck, cv, positions, block_table, layer, slot)
     elif use_flash:
         attn = flash_attention(q, k, v, causal=True)
@@ -411,6 +435,8 @@ def forward(
     block_table: jnp.ndarray | None = None,
     slot: jnp.ndarray | None = None,
     valid: jnp.ndarray | None = None,
+    lanes: tuple[jnp.ndarray, jnp.ndarray] | None = None,
+    last: jnp.ndarray | None = None,
 ) -> tuple[jnp.ndarray, KVCache | None]:
     """Returns (logits [B, T, V], updated cache).
 
@@ -429,6 +455,14 @@ def forward(
     With ``block_table`` the cache is a :class:`PagedKVCache` pool and
     every KV read/write goes through the table (paged serving); the cache
     returned is the updated pool.
+    With ``lanes = (tokens [B, 1], positions [B, 1])`` the launch carries a
+    second group of rows: ``tokens [1, T]`` is a lane's prefill chunk at arena
+    row ``slot`` and ``lanes`` one decode step of each of the arena's B lanes.
+    The layer scan's body runs the ``T + B`` rows together through everything
+    that reads weights, so a launch streams them once for both; each group's
+    attention reads its own arena rows. The head runs on ``1 + B`` rows and
+    the logits returned are ``[1 + B, V]``: the chunk's row ``last``, then
+    the lanes' (the dense arena only: no page pool, no hybrid block).
     Without: pure causal self-attention over the tokens given (what tests
     compare the cached path with).
     ``moe_impl`` overrides the MoE MLP (routed token-dispatch, meshed EP,
@@ -442,14 +476,22 @@ def forward(
 
         if block_table is not None:
             raise ValueError("the hybrid block has no paged cache")
+        if lanes is not None:
+            raise ValueError("the hybrid block takes one group of rows")
         return hybrid.forward(
             params, cfg, tokens, positions, cache,
             plan=cache_attn_impl, moe_impl=moe_impl, slot=slot, valid=valid,
         )
     x = embed_lookup(params["embed"], tokens)
+    lane_positions = None
+    if lanes is not None:
+        if cache is None or slot is None or block_table is not None or tokens.shape[0] != 1:
+            raise ValueError("lanes ride one lane's chunk at ``slot`` of a dense arena")
+        lane_tokens, lane_positions = lanes
+        x = jnp.concatenate([x, embed_lookup(params["embed"], lane_tokens[:, 0])[None]], axis=1)
     lp_stack = params["layers"]
     experts = None
-    if moe_impl is None and moe_sorts(cfg, lp_stack, tokens.shape[0] * tokens.shape[1]):
+    if moe_impl is None and moe_sorts(cfg, lp_stack, x.shape[0] * x.shape[1]):
         experts = stacked_experts(lp_stack)
         lp_stack = {k: v for k, v in lp_stack.items() if k not in EXPERT_WEIGHTS}
     if cache is not None:
@@ -469,6 +511,7 @@ def forward(
             block_table=block_table,
             layer=layer,
             slot=slot,
+            lane_positions=lane_positions,
         )
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
         if experts is not None:
@@ -496,6 +539,10 @@ def forward(
         )
         new_cache = None
 
+    if lanes is not None:
+        # the head's rows: the chunk's ``last`` and the lanes', not T + B
+        t = tokens.shape[1]
+        x = jnp.concatenate([lax.dynamic_slice_in_dim(x[0], last, 1, 0), x[0, t:]], axis=0)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = (x @ dequant(params["lm_head"])).astype(jnp.float32)
     return logits, new_cache

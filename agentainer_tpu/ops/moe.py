@@ -65,15 +65,21 @@ def sorted_from_rows(
 
 
 def row_tile(n_rows: int, n_experts: int, k: int) -> int:
-    """Rows of a tile: the power of two at or over TWICE an expert's fair
-    share of the assignments, between 32 and 128. An expert whose rows
-    outgrow a tile is streamed once a tile, so a tile should hold what
-    routing's scatter gives nearly every expert (at the fair share itself
-    half of them overflow: 2.96 against 2.05 ms a Mixtral layer at 256
-    rows, measured); past 128 rows a tile the MXU is full and the padding
-    of half-filled tiles is all a larger one adds."""
+    """Rows of a tile: the power of two at or over ONE AND A HALF times an
+    expert's fair share of the assignments, between 32 and 128. An expert
+    whose rows outgrow a tile is streamed once a tile, so a tile should hold
+    what routing's scatter gives nearly every expert (at the fair share
+    itself half of them overflow: 2.96 against 2.05 ms a Mixtral layer at
+    256 rows, measured); past 128 rows a tile the MXU is full and the
+    padding of half-filled tiles is all a larger one adds. A prefill bucket
+    is a power of two and so is its share: the tile is then twice the share.
+    A launch that carries the decode lanes beside a chunk (256 + 16 rows)
+    has a share just over a power of two and keeps that bucket's tile — at
+    twice the share it took the next one, and every expert's padding, the
+    row buffer and the spread matrices doubled for a sixteenth more rows
+    (19.0 ms an OLMoE launch, measured)."""
     share = -(-n_rows * k // n_experts)
-    return min(_MAX_TILE, max(_MIN_TILE, 1 << (2 * share - 1).bit_length()))
+    return min(_MAX_TILE, max(_MIN_TILE, 1 << (3 * share // 2 - 1).bit_length()))
 
 
 def sorted_rows(n_rows: int, n_experts: int, k: int, tile: int | None = None) -> int:

@@ -40,7 +40,7 @@ from agentainer_tpu.analysis.hlo_contracts import (
     op_result_elems,
     recompile_budget,
 )
-from agentainer_tpu.engine.llm import LLMEngine
+from agentainer_tpu.engine.llm import PREFILL_BUCKETS, LLMEngine
 from agentainer_tpu.utils.compile_cache import enable_compile_cache
 
 
@@ -223,26 +223,75 @@ def _step_lowering(eng, step: str):
         return lowered, (B, K + 1), 1
     t = 32
     tokens = jnp.zeros((1, t), jnp.int32)
+    if step == "jit_prefill_with_decode":
+        return _mixed_lowering(eng, t), ((1, t), (B, 1)), 1
     lowered = eng._prefill.lower(
         eng.params, eng.cache, jnp.int32(1), tokens, tokens, jnp.int32(4)
     )
     return lowered, (1, t), 1
 
 
-@pytest.mark.parametrize("step", ["jit_decode_n", "jit_verify", "jit_prefill"])
+def _mixed_lowering(eng, t: int):
+    """The mixed step (ISSUE 31) for a chunk of bucket ``t``: the chunk at
+    arena row 1 and one decode step of every lane, in one program."""
+    return eng._prefill_with_decode.lower(*_mixed_args(eng, t))
+
+
+def _mixed_args(eng, t: int):
+    B = eng.max_batch
+    z = lambda dt: jnp.zeros((B,), dt)  # noqa: E731
+    tokens = jnp.zeros((1, t), jnp.int32)
+    return (
+        eng.params, eng.cache, jnp.int32(1), tokens, tokens, jnp.int32(4),
+        z(jnp.int32), z(jnp.int32), z(jnp.float32), z(jnp.int32), z(jnp.float32),
+        jax.random.split(jax.random.PRNGKey(0), 1),
+    )
+
+
+@pytest.mark.parametrize("step", ["jit_decode_n", "jit_verify", "jit_prefill", "jit_prefill_with_decode"])
 def test_arena_rides_in_the_layer_loop_carry(dense_engine, step):
     """The stacked arena is a carried value of the layer loop (and of the
     step scan around it), never a stacked scan output; a step writes B × T
     new rows into it and nothing the size of a layer; and the donated arena
-    aliases the output through both loops, so no second arena exists."""
-    lowered, (b, t), loops = _step_lowering(dense_engine, step)
+    aliases the output through both loops, so no second arena exists. The
+    mixed step writes two groups of rows (the chunk's ``[1, T]`` and the
+    lanes' ``[B, 1]``) inside exactly ONE loop over the layers (the weights
+    are read once for both), and its donated carry (token, position) aliases
+    beside the arena."""
+    lowered, rows, loops = _step_lowering(dense_engine, step)
     assert f"module @{step}" in lowered.as_text()  # the names the readers find
     arena = tuple(dense_engine.cache.k.shape)
+    mixed = step == "jit_prefill_with_decode"
+    groups = tuple(g + arena[3:] for g in rows) if mixed else rows + arena[3:]
     check(
         lowered.as_text(),
-        ArenaRidesInCarry(arena=arena, rows=(b, t) + arena[3:], loops=loops),
+        ArenaRidesInCarry(arena=arena, rows=groups, loops=loops),
     )
-    check(lowered.compile().as_text(), DonationAliased(min_count=2))
+    check(lowered.compile().as_text(), DonationAliased(min_count=4 if mixed else 2))
+
+
+def test_arena_contract_counts_the_loops_and_the_groups_of_a_mixed_step(dense_engine):
+    """What the mixed step's contract refuses: a chunk and a decode step as
+    two forwards in one program (two loops over the layers: the weights are
+    read twice), and a write that is neither group's rows."""
+    from agentainer_tpu.models.llama import forward
+
+    eng, B, t = dense_engine, dense_engine.max_batch, 32
+    arena = tuple(eng.cache.k.shape)
+    groups = ((1, t) + arena[3:], (B, 1) + arena[3:])
+
+    def two_forwards(params, cache, slot, tokens, positions, lane_tok, lane_pos):
+        _, cache = forward(params, eng.cfg, tokens, positions, cache, slot=slot)
+        return forward(params, eng.cfg, lane_tok[:, None], lane_pos[:, None], cache)
+
+    tokens = jnp.zeros((1, t), jnp.int32)
+    z = jnp.zeros((B,), jnp.int32)
+    text = jax.jit(two_forwards).lower(eng.params, eng.cache, jnp.int32(1), tokens, tokens, z, z).as_text()
+    contract = ArenaRidesInCarry(arena=arena, rows=groups)
+    assert any("reads the weights again" in p for p in contract.failures(text))
+    mixed = _mixed_lowering(eng, t).as_text()
+    one_group = ArenaRidesInCarry(arena=arena, rows=(1, t) + arena[3:])
+    assert any("every write must be" in p for p in one_group.failures(mixed))
 
 
 def test_arena_contract_catches_the_xs_ys_scan():
@@ -353,6 +402,30 @@ def test_prefill_over_the_cut_computes_only_the_routed_pairs(moe_engine, model):
     with pytest.raises(ContractViolation, match="every row goes through every expert"):
         check(under, contract(64))
     assert "ragged_dot" not in str(eng._prefill.trace(*_prefill_args(eng, 64)).jaxpr)
+
+
+@pytest.mark.parametrize("model", ["tiny-moe", "tiny-olmoe"])
+def test_a_mixed_step_over_the_cut_sorts_the_lanes_rows_with_the_chunks(moe_engine, model):
+    """256 + 4 rows of an int8 MoE engine: the launch's FFN is the sorted
+    grouped one for both groups (no ``[260, E, F]`` activation and none of
+    the lanes' ``[4, E, F]`` either: one grouped matmul), the experts stay out
+    of the layer loop's slices, and the arena rides in its one loop; 64 + 4
+    rows stay under the cut and keep the all-experts form."""
+    eng = moe_engine(model, "int8")
+    B, arena = eng.max_batch, tuple(eng.cache.k.shape)
+    over = _mixed_lowering(eng, 256).as_text()
+    assert "module @jit_prefill_with_decode" in over
+    for rows in (256 + B, 256, B):
+        check(over, ExpertsSeeOnlyTheirRows(rows, eng.cfg.n_experts, eng.cfg.ffn_dim))
+    check(over, ArenaRidesInCarry(
+        arena=arena, rows=((1, 256) + arena[3:], (B, 1) + arena[3:]),
+    ))
+    grouped = lambda fn, args: str(fn.trace(*args).jaxpr).count("ragged_dot")  # noqa: E731
+    # gate, up, down: ONE grouped FFN for the T + B rows, as many as the plain chunk's
+    assert grouped(eng._prefill_with_decode, _mixed_args(eng, 256)) == grouped(eng._prefill, _prefill_args(eng, 256)) > 0
+    under = _mixed_lowering(eng, 64).as_text()
+    with pytest.raises(ContractViolation, match="every row goes through every expert"):
+        check(under, ExpertsSeeOnlyTheirRows(64 + B, eng.cfg.n_experts, eng.cfg.ffn_dim))
 
 
 # ---------------------------------------------------------------------------
@@ -469,3 +542,40 @@ def test_serving_window_lowers_nothing(warmed, compile_stats):
     assert not grew, grew
     assert "_first_token" in engine_jit_fns(warmed)
     assert warmed._first_token._cache_size() == 1
+
+
+def test_serving_window_with_mixed_launches_lowers_nothing(warmed, compile_stats):
+    """Warm-up serves one synthetic request at a time and so never has a
+    chunk pending beside a decoding lane: it runs the mixed step
+    (``jit_prefill_with_decode``, ISSUE 31) explicitly, once per bucket a
+    chunk can take. Multi-chunk prompts beside a steady generation then ride
+    on the dense engine without a lowering; the page pool, the fused loop and
+    the mesh have no such program, launch none, and lower nothing either."""
+
+    async def contended():
+        steady = asyncio.ensure_future(warmed.generate("steady reply", max_tokens=40, ignore_eos=True))
+        await asyncio.sleep(0.05)
+        docs = [
+            warmed.generate("several chunks of prompt to read " * n, max_tokens=6, ignore_eos=True)
+            for n in (2, 3, 4)
+        ]
+        return await asyncio.gather(steady, *docs)
+
+    rides = warmed._prefill_with_decode is not None
+    assert rides is not (warmed.paged or warmed.fused_decode or warmed.mesh is not None)
+    buckets = [b for b in PREFILL_BUCKETS if b <= warmed.prefill_chunk]  # one program each
+    if rides:
+        assert warmed._prefill_with_decode._cache_size() == len(buckets) >= 1
+    before, launched = compile_stats.as_dict(), warmed.mixed_launches
+    assert [len(r["tokens"]) for r in asyncio.run(contended())] == [40, 6, 6, 6]
+    after = compile_stats.as_dict()
+    grew = {
+        k: (before[k], after[k])
+        for k in ("requests", "misses", "trace_s", "lower_s", "compile_s")
+        if after[k] != before[k]
+    }
+    assert not grew, grew
+    assert (warmed.mixed_launches > launched) is rides
+    if rides:
+        assert "_prefill_with_decode" in engine_jit_fns(warmed)
+        assert warmed._prefill_with_decode._cache_size() == len(buckets)

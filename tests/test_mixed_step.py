@@ -1,0 +1,433 @@
+"""The mixed step (ISSUE 31): ONE launch for a prefill chunk and the decode
+lanes' step beside it.
+
+``jit_prefill_with_decode`` runs a chunk's ``T`` rows and the ``B`` lanes'
+rows together through every layer (``models/llama.forward``'s ``lanes``), so
+a tick that used to launch ``jit_prefill`` and then the one-step
+``jit_decode_n`` streams the weights once. Checked here on the CPU, on a
+dense, a Mixtral-shaped and an OLMoE-shaped tiny model: the program against
+the two it stands in for, the scheduler's rule (``_riders``) and the engines
+that have no such program, the counters, the two failpoints, and a session
+whose turns rode mixed launches resumed in a new engine.
+"""
+
+import asyncio
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from agentainer_tpu import faults
+from agentainer_tpu.engine.llm import GenRequest, LLMEngine
+from agentainer_tpu.models.llama import moe_sorts
+
+FAMILIES = ["tiny", "tiny-moe", "tiny-olmoe"]  # dense, Mixtral-shaped, OLMoE-shaped
+OPTS = {"max_batch": 4, "max_seq": 256, "decode_chunk": 8, "prefill_chunk": 32,
+        "speculative": False, "skip_warmup": True}
+LONG = "a document of many words that takes several prefill chunks to read "  # 68 bytes
+
+
+def _copy(tree):
+    return jax.tree.map(jnp.copy, tree)
+
+
+# ---------------------------------------------------------------------------
+# the program: one launch against the two it replaces
+
+
+@pytest.mark.parametrize("weights", ["float", "int8"])
+@pytest.mark.parametrize("model", FAMILIES)
+def test_a_mixed_launch_is_the_chunk_then_the_one_step_decode(model, weights):
+    """Same arena rows, same last-row logits, same carry and the same greedy
+    tokens as ``jit_prefill`` followed by a one-step ``jit_decode_n``, from
+    the same state, to the tolerance the sorted MoE path is held to against
+    the einsum (tests/test_moe_sorted.py: 2e-5 at float32). ``float``: 128 +
+    4 rows stay under this CPU's cut and both sides run the all-experts
+    einsum (the matmuls' shapes differ, so a few sums round differently:
+    2e-7). ``int8``: 132 rows are over the 121-row cut, so the launch's lanes
+    go through the sorted grouped FFN where the decode step runs the einsum:
+    the same sum in another order."""
+    quant = {"quant": "int8"} if weights == "int8" else {}
+    eng = LLMEngine.create(model, options={**OPTS, "prefill_chunk": 128, **quant})
+    try:
+        B, S, T, n_real, lane = eng.max_batch, eng.max_seq, 128, 100, 2
+        if eng.cfg.is_moe:
+            assert moe_sorts(eng.cfg, eng.params["layers"], T + B) is (weights == "int8")
+            assert not moe_sorts(eng.cfg, eng.params["layers"], B)
+        rng = np.random.default_rng(7)
+        draw = lambda *shape: jnp.asarray(rng.integers(1, eng.cfg.vocab_size, shape), jnp.int32)  # noqa: E731
+        # three lanes hold a context of their own; lane 2 takes the chunk
+        cache, context = eng.cache, {0: 21, 1: 9, 3: 30}
+        for idx, n in context.items():
+            _, cache = eng._prefill(
+                eng.params, cache, jnp.int32(idx), draw(1, 32), jnp.arange(32, dtype=jnp.int32)[None], jnp.int32(n)
+            )
+        tokens, positions = draw(1, T), (40 + jnp.arange(T, dtype=jnp.int32))[None]
+        lane_tok = draw(B)
+        lane_pos = jnp.asarray([context.get(i, S - 1) for i in range(B)], jnp.int32)  # lane 2 parked at scratch
+        temps, topk, topp = jnp.zeros((B,), jnp.float32), jnp.zeros((B,), jnp.int32), jnp.ones((B,), jnp.float32)
+        keys = jax.random.split(jax.random.PRNGKey(3), 1)
+
+        last_a, cache_a = eng._prefill(eng.params, _copy(cache), jnp.int32(lane), tokens, positions, jnp.int32(n_real))
+        toks_a, tok_a, pos_a, cache_a = eng._decode_n(
+            eng.params, cache_a, _copy(lane_tok), _copy(lane_pos), temps, topk, topp, keys
+        )
+        last_m, toks_m, tok_m, pos_m, cache_m = eng._prefill_with_decode(
+            eng.params, _copy(cache), jnp.int32(lane), tokens, positions, jnp.int32(n_real),
+            _copy(lane_tok), _copy(lane_pos), temps, topk, topp, keys,
+        )
+        assert toks_m.shape == toks_a.shape == (1, B)
+        atol = 2e-5
+        np.testing.assert_allclose(np.asarray(last_m), np.asarray(last_a), atol=atol, rtol=0)
+        np.testing.assert_allclose(np.asarray(cache_m.k), np.asarray(cache_a.k), atol=atol, rtol=0)
+        np.testing.assert_allclose(np.asarray(cache_m.v), np.asarray(cache_a.v), atol=atol, rtol=0)
+        assert np.asarray(toks_m).tolist() == np.asarray(toks_a).tolist()
+        assert np.asarray(tok_m).tolist() == np.asarray(tok_a).tolist()
+        assert np.asarray(pos_m).tolist() == np.asarray(pos_a).tolist()
+        # the chunk's rows were written at lane 2 and the lanes' at their own positions
+        assert float(jnp.abs(cache_m.k[:, lane, 40:40 + T]).max()) > 0
+        assert float(jnp.abs(cache_m.k[:, 0, context[0]]).max()) > 0
+    finally:
+        eng.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# the scheduler: the served path takes it, counts it, and gives the same tokens
+
+
+async def _traffic(eng, n_long=3, reply=12):
+    """One steady generation, then ``n_long`` multi-chunk prompts beside it:
+    a chunk is pending while lanes decode for most of the run."""
+    steady = asyncio.ensure_future(eng.generate("steady reply", max_tokens=40, ignore_eos=True, session="steady"))
+    for _ in range(4000):
+        await asyncio.sleep(0.002)
+        idx = eng.sessions.get("steady")
+        if idx is not None and eng.slots[idx].decoding:
+            break
+    longs = await asyncio.gather(
+        *(eng.generate(LONG * (2 + i), max_tokens=reply, ignore_eos=True, session=f"doc{i}") for i in range(n_long))
+    )
+    return [await steady] + list(longs)
+
+
+@pytest.fixture(scope="module", params=FAMILIES)
+def served(request):
+    """(engine, the replies under contention, the same requests one at a
+    time on the same engine, its /metrics after the contended run)."""
+    eng = LLMEngine.create(request.param, options=dict(OPTS))
+    try:
+        contended = asyncio.run(_traffic(eng))
+        m = eng.metrics()
+        eng.clear_sessions()
+
+        async def alone():
+            out = [await eng.generate("steady reply", max_tokens=40, ignore_eos=True)]
+            for i in range(3):
+                out.append(await eng.generate(LONG * (2 + i), max_tokens=12, ignore_eos=True))
+            return out
+
+        before = eng.mixed_launches
+        one_by_one = asyncio.run(alone())
+        assert eng.mixed_launches == before  # a lone request never has a chunk beside a decoding lane
+        yield eng, contended, one_by_one, m
+    finally:
+        eng.shutdown()
+
+
+def test_contended_ticks_ride_and_give_the_two_launch_tokens(served):
+    _, contended, one_by_one, m = served
+    assert m["mixed_launches"] > 0 and m["worker_errors"] == 0
+    assert [r["tokens"] for r in contended] == [r["tokens"] for r in one_by_one]
+
+
+def test_a_mixed_launch_counts_as_a_prefill_and_a_decode_step_and_no_decode_chunk(served):
+    """``decode_chunk_hist`` is ``jit_decode_n``'s launches alone (the
+    benchmark's decode roofline multiplies it by that module's launches in a
+    trace): a mixed launch moves ``prefill_launches``, ``decode_steps`` and
+    ``mixed_launches``, the occupancy sum, and the spans of both paths."""
+    _, _, _, m = served
+    p = m["phases"]
+    assert m["mixed_launches"] == p["engine.mixed_dispatch"]["n"]
+    assert m["prefill_launches"] == p["engine.prefill_dispatch"]["n"] == p["engine.prefill_tick"]["n"]
+    assert sum(m["decode_chunk_hist"].values()) == p["engine.decode_dispatch"]["n"]
+    assert m["decode_steps"] == m["mixed_launches"] + sum(m["decode_chunk_hist"].values())
+    assert m["mixed_launches"] <= m["mixed_decode_lanes"] <= m["mixed_launches"] * (m["max_batch"] - 1)
+    assert 0 < m["batch_occupancy"] <= 1
+    # the mixed span nests in the prefill dispatch: a leaf
+    assert p["engine.mixed_dispatch"]["self_s"] == p["engine.mixed_dispatch"]["total_s"]
+    assert p["engine.prefill_dispatch"]["total_s"] >= p["engine.mixed_dispatch"]["total_s"]
+
+
+def test_the_moe_counters_count_a_mixed_launch_as_one_pass_of_its_rows():
+    """``_count_forward(bucket + max_batch)``: one pass, ``T + B`` rows."""
+    eng = LLMEngine.create("tiny-moe", options=dict(OPTS))
+    try:
+        eng.shutdown()  # the worker is gone; the counters are host arithmetic
+        k, e = eng.cfg.experts_per_token, eng.cfg.n_experts
+        passes, before = eng.forward_passes, dict(eng.moe)
+        eng._count_forward(32 + eng.max_batch)
+        assert eng.forward_passes == passes + 1
+        assert eng.moe["assignments"] - before["assignments"] == 36 * k
+        assert eng.moe["rows_all_experts"] - before["rows_all_experts"] == 36 * e  # under this CPU's cut
+    finally:
+        eng.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# the rule, case by case, on an engine whose worker has stopped
+
+
+def _request(max_tokens=16, dispatched=1):
+    r = GenRequest(id="r", session="", prompt_ids=[1], max_tokens=max_tokens, temperature=0.0, loop=None, future=None)
+    r.dispatched = dispatched
+    return r
+
+
+def _decoding(eng, idx, **kw):
+    s = eng.slots[idx]
+    s.request, s.decoding, s.pending_prompt, s.dev_position = _request(**kw), True, [], 10 + idx
+    return s
+
+
+def _prefilling(eng, idx, n_tokens):
+    s = eng.slots[idx]
+    s.request, s.decoding, s.pending_prompt = _request(), False, [5] * n_tokens
+    return s
+
+
+RULE = {
+    # name: (decoding lanes, pending prompts' lengths, a queued request, want riders)
+    "a_chunk_with_more_to_come_beside_two_lanes": ([0, 1], [70], False, [0, 1]),
+    "two_prompts_of_one_chunk_each": ([0], [20, 20], False, [0]),
+    "the_only_prompts_last_chunk_and_a_waiter_a_free_slot_could_take": ([0], [20], True, [0]),
+    "the_only_prompts_last_chunk_keeps_the_lanes_longer_rung_or_verify_round": ([0, 1], [20], False, None),
+    "nothing_pending": ([0, 1], [], False, None),
+    "nothing_decoding": ([], [70], False, None),
+}
+
+
+@pytest.fixture(scope="module")
+def stopped():
+    eng = LLMEngine.create("tiny", options=dict(OPTS))
+    eng.shutdown()
+    return eng
+
+
+@pytest.mark.parametrize("case", sorted(RULE))
+def test_a_tick_rides_exactly_when_its_decode_would_be_the_plain_one_step(stopped, case):
+    eng = stopped
+    lanes, prompts, waiter, want = RULE[case]
+    for s in eng.slots:
+        eng._reset_slot(s)
+    eng._waiting = []
+    for idx in lanes:
+        _decoding(eng, idx)
+    for j, n in enumerate(prompts):
+        _prefilling(eng, len(lanes) + j, n)
+    if waiter:
+        eng._waiting.append(_request())
+    got = eng._riders()
+    if want is None:
+        assert got is None
+    else:
+        assert [(s.idx, p) for s, _, p in got] == [(i, 10 + i) for i in want]
+
+
+def test_lanes_whose_budget_is_all_in_flight_do_not_ride(stopped):
+    eng = stopped
+    for s in eng.slots:
+        eng._reset_slot(s)
+    _decoding(eng, 0, max_tokens=8, dispatched=8)
+    _prefilling(eng, 1, 70)
+    assert eng._riders() is None
+    _decoding(eng, 2, max_tokens=8, dispatched=3)
+    assert [s.idx for s, _, _ in eng._riders()] == [0, 2]  # one launch steps every live lane, as decode_n does
+
+
+NO_PROGRAM = {
+    "hybrid": ("tiny-kimi-linear", {}),
+    "paged": ("tiny", {"paged_kv": True}),
+    "fused": ("tiny", {"paged_kv": True, "fused_decode": True}),
+    "meshed": ("tiny", {"tp": 2}),
+    "routed": ("tiny-moe", {"routed": True}),
+    "no_one_step_rung": ("tiny", {"adaptive_decode": False}),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(NO_PROGRAM))
+def test_engines_without_the_program_keep_two_launches(kind):
+    """Decided at build by what the engine is, never by a model's name: the
+    hybrid block, the page pool, the fused loop, a mesh, the ``routed``
+    dispatch and a ladder without the one-step rung have no mixed program;
+    under the traffic that makes the dense engine ride they launch none."""
+    model, extra = NO_PROGRAM[kind]
+    options = {k: v for k, v in OPTS.items() if not (kind == "hybrid" and k == "speculative")}
+    eng = LLMEngine.create(model, options={**options, **extra})
+    try:
+        assert eng._prefill_with_decode is None and eng._riders() is None
+        replies = asyncio.run(_traffic(eng, n_long=2, reply=6))
+        assert [r["completion_tokens"] for r in replies] == [40, 6, 6]
+        m = eng.metrics()
+        assert m["mixed_launches"] == 0 and m["mixed_decode_lanes"] == 0
+        assert "engine.mixed_dispatch" not in m["phases"]
+        assert m["decode_steps"] == sum(m["decode_chunk_hist"].values())
+    finally:
+        eng.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# failpoints: the prompt's seam first, then the lanes'
+
+
+@pytest.fixture()
+def armed():
+    yield faults
+    faults.disarm_all()
+
+
+async def _until_decoding(eng, session):
+    for _ in range(4000):
+        await asyncio.sleep(0.002)
+        idx = eng.sessions.get(session)
+        if idx is not None and eng.slots[idx].decoding and eng.slots[idx].request.generated:
+            return
+    raise AssertionError(f"{session} never started decoding")
+
+
+def test_a_poisoned_prompt_fails_alone_on_a_tick_that_would_have_ridden(armed):
+    """``engine.prefill`` fires before ``engine.decode_step`` and before the
+    launch: the culprit fails, the lanes it would have carried take their
+    plain step in the same tick, and their replies are the undisturbed ones."""
+    eng = LLMEngine.create("tiny", options=dict(OPTS))
+    try:
+        want = asyncio.run(eng.generate("steady reply", max_tokens=48, ignore_eos=True))["tokens"]
+
+        async def scenario():
+            steady = asyncio.ensure_future(
+                eng.generate("steady reply", max_tokens=48, ignore_eos=True, session="a", request_id="steady-1")
+            )
+            await _until_decoding(eng, "a")
+            armed.arm("engine.prefill", error="RuntimeError", count=1)
+            with pytest.raises(Exception, match="engine.prefill|injected|RuntimeError"):
+                await eng.generate(LONG * 3, max_tokens=4, request_id="poisoned-1")
+            after = await eng.generate(LONG * 3, max_tokens=4, ignore_eos=True, request_id="after-1")
+            return (await steady)["tokens"], after
+
+        got, after = asyncio.run(scenario())
+        assert got == want and after["completion_tokens"] == 4
+        m = eng.metrics()
+        assert m["worker_errors"] == 1 and m["cache_resets"] == 0
+    finally:
+        eng.shutdown()
+
+
+def test_both_seams_fire_before_a_mixed_launch_the_prompts_first(stopped, armed):
+    """On a stopped engine, ``_prefill_chunk`` with riders: the prompt's seam
+    raises the injected error itself (the worker fails that request alone);
+    the lanes' seam raises it wrapped as ``RidersFault`` (the worker fails the
+    batch); with both armed the prompt's comes first; nothing was launched."""
+    from agentainer_tpu.engine.llm import RidersFault
+
+    eng = stopped
+    for s in eng.slots:
+        eng._reset_slot(s)
+    riders = [(s, s.request, s.dev_position) for s in (_decoding(eng, 0),)]
+    slot = _prefilling(eng, 1, 70)
+    launches = eng.prefill_launches
+    armed.arm("engine.decode_step", error="TimeoutError", count=1)
+    with pytest.raises(RidersFault) as caught:
+        eng._prefill_chunk(slot, riders)
+    assert isinstance(caught.value.__cause__, TimeoutError)
+    armed.arm("engine.decode_step", error="TimeoutError", count=1)
+    armed.arm("engine.prefill", error="ConnectionError", count=1)
+    with pytest.raises(ConnectionError):
+        eng._prefill_chunk(slot, riders)
+    owed = {f["name"]: f["count"] for f in armed.active()}
+    assert owed["engine.decode_step"] == 1 and owed["engine.prefill"] == 0  # the lanes' seam was not reached
+    assert eng.prefill_launches == launches and len(slot.pending_prompt) == 70
+
+
+def test_a_decode_fault_while_chunks_ride_is_batch_wide(armed):
+    """``engine.decode_step`` armed while prompts prefill beside a decoding
+    lane (the seam of the next mixed launch takes it, or the plain dispatch's
+    on a tick that does not ride): every in-flight request fails (one
+    compiled call covers every lane), and the engine serves on."""
+    eng = LLMEngine.create("tiny", options=dict(OPTS))
+    try:
+        async def scenario():
+            steady = asyncio.ensure_future(
+                eng.generate("steady reply", max_tokens=200, ignore_eos=True, session="a", request_id="steady-2")
+            )
+            await _until_decoding(eng, "a")
+            before = eng.mixed_launches
+            # two multi-chunk prompts beside the steady lane: their ticks ride
+            docs = [
+                asyncio.ensure_future(eng.generate(LONG * 3, max_tokens=4, request_id=f"doc-{i}")) for i in range(2)
+            ]
+            for _ in range(4000):
+                await asyncio.sleep(0.001)
+                if eng.mixed_launches > before:
+                    break
+            assert eng.mixed_launches > before
+            armed.arm("engine.decode_step", error="RuntimeError", count=1)
+            return await asyncio.gather(steady, *docs, return_exceptions=True)
+
+        results = asyncio.run(scenario())
+        assert isinstance(results[0], Exception), results[0]
+        assert any(isinstance(r, Exception) for r in results[1:])
+        assert eng.metrics()["worker_errors"] == 1
+        ok = asyncio.run(eng.generate("after the fault", max_tokens=4, ignore_eos=True))
+        assert ok["completion_tokens"] == 4
+    finally:
+        eng.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# a journaled turn replayed after a kill
+
+
+@pytest.mark.parametrize("model", FAMILIES)
+def test_a_turn_killed_mid_decode_replays_token_identically_after_mixed_launches(model):
+    """Turn one of a session decodes while other prompts prefill, so its KV
+    rows were written by mixed launches; the engine dies mid-decode of turn
+    two (the journal then replays the request); a new engine restores the
+    snapshot taken after turn one and gives turn two the tokens of an engine
+    that never saw another request and never stopped."""
+
+    async def uninterrupted():
+        eng = LLMEngine.create(model, options=dict(OPTS))
+        try:
+            a = await eng.chat("s", "turn one", max_tokens=24, ignore_eos=True)
+            b = await eng.chat("s", "turn two", max_tokens=24, ignore_eos=True)
+            assert eng.mixed_launches == 0
+            return a["tokens"], b["tokens"]
+        finally:
+            eng.shutdown()
+
+    async def interrupted():
+        eng1 = LLMEngine.create(model, options=dict(OPTS))
+        try:
+            one = asyncio.ensure_future(eng1.chat("s", "turn one", max_tokens=24, ignore_eos=True))
+            await _until_decoding(eng1, "s")
+            docs = [asyncio.ensure_future(eng1.generate(LONG * 3, max_tokens=4, ignore_eos=True)) for _ in range(2)]
+            a = await one
+            await asyncio.gather(*docs)
+            rode = eng1.mixed_decode_lanes
+            assert rode > 0  # turn one's lane was a rider: the only lane decoding beside the chunks
+            blob = await eng1.snapshot_session("s")
+            two = asyncio.ensure_future(eng1.chat("s", "turn two", max_tokens=24, ignore_eos=True))
+            await _until_decoding(eng1, "s")
+        finally:
+            eng1.shutdown()  # the kill, mid-decode of turn two
+        with pytest.raises(Exception):
+            await two
+        eng2 = LLMEngine.create(model, options=dict(OPTS))
+        try:
+            assert await eng2.restore_session("s", blob) is True
+            b = await eng2.chat("s", "turn two", max_tokens=24, ignore_eos=True)  # the replayed request
+            return a["tokens"], b["tokens"]
+        finally:
+            eng2.shutdown()
+
+    assert asyncio.run(interrupted()) == asyncio.run(uninterrupted())

@@ -137,6 +137,21 @@ def test_route_places_every_assignment_once_in_its_experts_tiles(n, n_experts, k
         assert (used == -(-counts // tile)).all()
 
 
+@pytest.mark.parametrize("n_experts, k, bucket, lanes, tile", [
+    (8, 2, 128, 8, 64), (8, 2, 256, 8, 128), (64, 8, 128, 16, 32), (64, 8, 256, 16, 64), (64, 8, 512, 16, 128),
+], ids=["mixtral-128", "mixtral-256", "olmoe-128", "olmoe-256", "olmoe-512"])
+def test_rows_just_over_a_bucket_keep_its_tile(n_experts, k, bucket, lanes, tile):
+    """A launch that carries the decode lanes beside a prefill chunk (ISSUE
+    31) has ``bucket + lanes`` rows: an expert's fair share is just over a
+    power of two, and the tile stays the bucket's (twice the bucket's share,
+    1.9 times this one's) — so do the row buffer's tiles an expert. At twice
+    the share it took the next power of two and every expert's padding
+    doubled for a sixteenth more rows."""
+    assert row_tile(bucket, n_experts, k) == row_tile(bucket + lanes, n_experts, k) == tile
+    grown = sorted_rows(bucket + lanes, n_experts, k) - sorted_rows(bucket, n_experts, k)
+    assert 0 <= grown <= lanes * k + tile  # the lanes' own assignments, no expert's padding
+
+
 def test_the_cut_is_the_devices_ridge():
     """One function of (E, k, stored dtype, device peaks): v5e int8 → 121
     rows (197e12 ÷ (2 × 819e9) = 120.3), twice that for bf16; a device with

@@ -14,6 +14,7 @@ never that it is right or fast (parity lives in test_pallas_attention.py,
 on-chip numerics in chip_smoke.py).
 """
 
+import math
 import os
 import re
 
@@ -196,27 +197,10 @@ def test_sorted_moe_ffn_compiles_for_v5e(v5e, shape, rows):
     assert temp < min(3 * row_buffer + (8 << 20), e * d * f), (temp, row_buffer)
 
 
-def test_prefill_over_the_cut_holds_no_expert_sized_temporary_on_v5e(v5e, monkeypatch):
-    """A whole prefill step of 256 rows against the arena, OLMoE's block at
-    published widths (two layers of it), int8, the program steered to its
-    chip branch (the process's backend is the CPU; the kernels are chosen by
-    ``jax.default_backend()``): the compiled step reads the expert stack in
-    place, 1.2 MB of temporaries in all. The all-experts form of the same
-    step writes what the traces showed as ``copy.53`` and
-    ``constant_dynamic-slice_fusion.6`` — the layer's int8 ``w_down`` sliced
-    out of the stack and relaid with its last two dims swapped — and a bf16
-    copy of each of the three matrices beside its einsum: two layer
-    matrices' worth of live temporaries (270 MB; 1.0 GB at Mixtral's
-    shape)."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    import dataclasses
-    from functools import partial
-
+def _served_shapes(cfg, where):
+    """(the K/V block's parameters as served — int8 matmul weights with bf16
+    scales — as shapes placed on the described device, the placer)."""
     from agentainer_tpu.engine.quant import _QUANT_KEYS
-    from agentainer_tpu.models.llama import _moe_mlp
-
-    cfg = dataclasses.replace(get_config("olmoe-1b-7b"), n_layers=2, name="olmoe-2l")
-    where = SingleDeviceSharding(v5e.devices[0])
 
     def place(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=where)
@@ -236,7 +220,31 @@ def test_prefill_over_the_cut_holds_no_expert_sized_temporary_on_v5e(v5e, monkey
                 out[key] = place(val)
         return out
 
-    params = quantised(jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0))))
+    return quantised(jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))), place
+
+
+def test_prefill_over_the_cut_holds_no_expert_sized_temporary_on_v5e(v5e, monkeypatch):
+    """A whole prefill step of 256 rows against the arena, OLMoE's block at
+    published widths (two layers of it), int8, the program steered to its
+    chip branch (the process's backend is the CPU; the kernels are chosen by
+    ``jax.default_backend()``): the compiled step reads the expert stack in
+    place, 1.2 MB of temporaries in all. The all-experts form of the same
+    step writes what the traces showed as ``copy.53`` and
+    ``constant_dynamic-slice_fusion.6`` — the layer's int8 ``w_down`` sliced
+    out of the stack and relaid with its last two dims swapped — and a bf16
+    copy of each of the three matrices beside its einsum: two layer
+    matrices' worth of live temporaries (270 MB; 1.0 GB at Mixtral's
+    shape)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    import dataclasses
+    from functools import partial
+
+    from agentainer_tpu.models.llama import _moe_mlp
+
+    cfg = dataclasses.replace(get_config("olmoe-1b-7b"), n_layers=2, name="olmoe-2l")
+    where = SingleDeviceSharding(v5e.devices[0])
+
+    params, place = _served_shapes(cfg, where)
     cache = jax.tree.map(place, jax.eval_shape(lambda: KVCache.create(cfg, 16, 2048)))
     tokens = jax.ShapeDtypeStruct((1, 256), jnp.int32, sharding=where)
     slot = jax.ShapeDtypeStruct((), jnp.int32, sharding=where)
@@ -253,6 +261,46 @@ def test_prefill_over_the_cut_holds_no_expert_sized_temporary_on_v5e(v5e, monkey
     einsum_temp = temp_bytes(partial(_moe_mlp, cfg=cfg))
     assert sorted_temp < layer_matrix // 16, sorted_temp
     assert einsum_temp > layer_matrix, einsum_temp
+
+
+@pytest.mark.parametrize("model, lanes", [("olmoe-1b-7b", 16), ("mixtral-8x7b", 8)])
+def test_mixed_step_reads_weights_and_arena_in_place_on_v5e(v5e, monkeypatch, model, lanes):
+    """The mixed step (ISSUE 31) at published widths, two layers, int8, the
+    lanes the benchmark's configurations serve: a 256-row chunk at arena row
+    ``slot`` and one row for every lane through ONE layer loop. The chip's
+    compiler accepts it with the three kernels side by side (``flash_prefill``
+    for the chunk, ``flash_decode`` for the lanes, one ``moe_grouped_ffn`` for
+    all 256 + B rows: over the 121-row cut), adds no copy of the arena (four
+    scatters into the donated stacks, both groups' before either read) and
+    holds about 2 MB of temporaries: nothing the size of a layer's experts
+    or of an arena layer."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    import dataclasses
+
+    cfg = dataclasses.replace(get_config(model), n_layers=2, name=model + "-2l")
+    where = SingleDeviceSharding(v5e.devices[0])
+
+    params, place = _served_shapes(cfg, where)
+    cache = jax.tree.map(place, jax.eval_shape(lambda: KVCache.create(cfg, lanes, 2048)))
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=where)  # noqa: E731
+
+    def step(params, cache, slot, tokens, positions, last, lane_tok, lane_pos):
+        return forward(
+            params, cfg, tokens, positions, cache, slot=slot, lanes=(lane_tok, lane_pos), last=last
+        )
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, ints(), ints(1, 256), ints(1, 256), ints(), ints(lanes, 1), ints(lanes, 1)
+    ).compile()
+    text = compiled.as_text()
+    arena = ",".join(str(d) for d in cache.k.shape)
+    copies = [ln for ln in text.splitlines() if re.search(rf"= \w+\[{arena}\]", ln) and " copy(" in ln]
+    assert not copies, copies[:2]
+    assert text.count("tpu_custom_call") == 3, text.count("tpu_custom_call")
+    layer_matrix = cfg.n_experts * cfg.dim * cfg.ffn_dim  # one int8 expert matrix of a layer
+    arena_layer = 2 * math.prod(cache.k.shape[1:])  # a layer of the bf16 K stack
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < min(layer_matrix, arena_layer) // 16, temp
 
 
 @pytest.mark.parametrize("step", ["decode", "prefill"])

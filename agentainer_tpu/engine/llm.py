@@ -123,7 +123,7 @@ SPEC_LOOKUP_WINDOW = 1024
 # A K/V arena's rows are positional: they can be truncated (speculative
 # rewind), copied at a boundary (prefix forks), paged, overwritten by a
 # parked lane's garbage and moved off the device and back, all harmlessly.
-# A recurrent state (the hybrid block's KDA layers) is one value per lane
+# A recurrent state (the hybrid block's KDA or GDN layers) is one value per lane
 # that only ever moves forward: none of that holds for it. The features
 # below either work for a family or are OFF for it with the reason given —
 # logged at build and reported in ``/metrics`` (``cache``) — and asking for
@@ -131,10 +131,10 @@ SPEC_LOOKUP_WINDOW = 1024
 _RECURRENT_OFF = {
     "speculative": "a rejected draft would have to rewind the recurrent state",
     "fused_decode": "the fused loop's in-loop speculation rewinds, and its masks are not the state's",
-    "paged_kv": "a per-lane state has no pages; the latent rows stay a dense arena",
-    "kv_tiering": "the host tier moves k and v only",
+    "paged_kv": "a per-lane state has no pages; the positional rows (latent, or k and v) stay a dense arena",
+    "kv_tiering": "the host tier moves k and v only, never a state beside them",
     "prefix_cache": "a fork needs the state AT the boundary, and none is kept there",
-    "mesh": "the hybrid block is served on one chip (its share of the experts is cfg.experts_held)",
+    "mesh": "the hybrid block is served on one chip (a share of the experts, where it has any, is cfg.experts_held)",
 }
 
 
@@ -262,14 +262,15 @@ def _as_prefill_failure(e: Exception) -> Exception:
     return PrefillFailed(f"{type(e).__name__}: {e}")
 
 
-def _phase(name: str):
-    """Run a worker-thread method of the engine under the span ``name``
-    (spans whose trace event carries attributes are opened inline)."""
+def _phase(name: str, attrs=None):
+    """Run a worker-thread method of the engine under the span ``name``.
+    ``attrs(self, *args)``: what the span's trace event carries (read from a
+    capture; other spans with attributes are opened inline)."""
 
     def wrap(fn):
         @functools.wraps(fn)
         def timed(self, *args, **kwargs):
-            with self._spans.span(name):
+            with self._spans.span(name, **(attrs(self, *args) if attrs else {})):
                 return fn(self, *args, **kwargs)
 
         return timed
@@ -997,10 +998,11 @@ class LLMEngine:
         # prefix; prefill streams the weights once per chunk.
         self.hbm_bytes_read = 0.0
         if self._recurrent:
-            # positional bytes a token adds (the latent rows); the per-lane
-            # state is read and written whole each step whatever the context
-            self._kv_bytes_per_pos = (
-                cfg.n_mla * cache.latent.shape[-1] * cache.latent.dtype.itemsize
+            # positional bytes a token adds (the latent rows, or k and v as
+            # stored); the per-lane state is read and written whole each step
+            # whatever the context
+            self._kv_bytes_per_pos = sum(
+                a.shape[0] * int(np.prod(a.shape[3:])) * a.dtype.itemsize for a in cache.rows()
             )
         else:
             self._kv_bytes_per_pos = (
@@ -1011,6 +1013,7 @@ class LLMEngine:
         self.state_snapshots = 0
         self.state_restores = 0
         self._restore_fns: dict[int, Any] = {}
+        self._staged_bytes_by_bucket: dict[int, str] = {}
         self.decode_steps = 0
         self._occupancy_sum = 0.0
         self._last_decode_end: float | None = None
@@ -1345,8 +1348,9 @@ class LLMEngine:
         self.meshed_flash = (not self._recurrent) and "shard_map" in attn.decode
         if self._recurrent:
             print(
-                f"[llm-engine] attention: kda prefill={attn.kda_prefill} decode={attn.kda_decode}; "
-                f"mla prefill={attn.mla_prefill} decode={attn.mla_decode} ({attn.reason})",
+                "[llm-engine] attention: "
+                + "; ".join(f"{k} prefill={p} decode={d}" for k, (p, d) in attn.kinds().items())
+                + f" ({attn.reason})",
                 flush=True,
             )
         else:
@@ -1594,7 +1598,7 @@ class LLMEngine:
             # defines them: open a lane for a request (zeroing a fresh
             # context's state), stage a lane's leaves, write them back
             self._admit_state = jax.jit(hybrid.admit_lane, donate_argnums=(0,))
-            self._lane_leaves = hybrid.snapshot_lane
+            self._lane_leaves = functools.partial(hybrid.snapshot_lane, n_kv_heads=cfg.n_kv_heads)
             self._restore_lane = hybrid.restore_lane
             self._positional = hybrid.HybridCache.POSITIONAL
         # the verify ladder reuses the same forward (one prefill-shaped call
@@ -2290,7 +2294,21 @@ class LLMEngine:
         gap_ok = now - self._last_snapshot_at >= gap
         return (not gap_ok) or (busy and not overdue)
 
-    @_phase("engine.snapshot")
+    def _staged_bytes(self, cmd: SnapshotCmd, slot: Slot) -> dict:
+        """``bytes="leaf=n,..."`` of one lane staged at the slot's bucket: what
+        each kind of leaf ships, for a cache of several kinds."""
+        if not self._recurrent or slot.position <= 0:
+            return {}
+        bucket = self._snap_bucket(slot.position)
+        known = self._staged_bytes_by_bucket.get(bucket)
+        if known is None:
+            shapes = jax.eval_shape(lambda c: self._lane_leaves(c, 0, bucket), self.cache)
+            known = self._staged_bytes_by_bucket[bucket] = ",".join(
+                f"{name}={int(np.prod(a.shape)) * a.dtype.itemsize}" for name, a in shapes.items()
+            )
+        return {"bytes": known}
+
+    @_phase("engine.snapshot", attrs=_staged_bytes)
     def _stage_snapshot(self, cmd: SnapshotCmd, slot: Slot) -> None:
         """Stage a settled slot's prefix (worker thread), limiter-gated."""
         staged = None
@@ -3583,9 +3601,9 @@ class LLMEngine:
                 "bytes_per_lane": self.kv_arena_bytes // self.max_batch if not self.paged else None,
                 "off": {},
             }
-        sizes = {f"{name}_bytes": getattr(self.cache, name).nbytes for name in ("latent", "state", "conv")}
+        sizes = {f"{name}_bytes": a.nbytes for name, a in self.cache.leaves().items()}
         return {
-            "kinds": ["latent", "state", "conv"],
+            "kinds": list(self.cache.leaves()),
             **sizes,
             "bytes_per_lane": sum(sizes.values()) // self.max_batch,
             "state_resets": self.state_resets,
@@ -4195,20 +4213,27 @@ class LLMEngine:
             ) = self._alloc_carry()
             self._staged_lane = None
 
-    @_phase("engine.restore")
+    def _restored_bytes(self, cmd: RestoreCmd) -> dict:
+        if not self._recurrent or not cmd.leaves:
+            return {}
+        return {"bytes": ",".join(f"{n}={np.asarray(a).nbytes}" for n, a in cmd.leaves.items())}
+
+    @_phase("engine.restore", attrs=_restored_bytes)
     def _do_restore(self, cmd: RestoreCmd) -> None:
         from .checkpoint import restore_kv_slot
 
         ok = False
         try:
-            if self.paged:
-                ok = self._do_restore_paged(cmd)
-                return
             if self._recurrent:
                 ok = self._do_restore_state(cmd)
                 return
-            if cmd.k is None or cmd.v is None:
-                return  # another family's snapshot: the caller re-prefills
+            if cmd.k is None or cmd.v is None or set(cmd.leaves or ("k", "v")) != {"k", "v"}:
+                # another family's snapshot (a latent, or k and v BESIDE a
+                # state), refused by its leaves' names: the caller re-prefills
+                return
+            if self.paged:
+                ok = self._do_restore_paged(cmd)
+                return
             slot = self._find_slot(cmd.session)
             if slot is not None and cmd.position < self.max_seq - 1:
                 self.cache = restore_kv_slot(self.cache, slot.idx, cmd.k, cmd.v)

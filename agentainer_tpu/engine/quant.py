@@ -31,7 +31,7 @@ _QUANT_KEYS = {
     # the hybrid block's matrices (models/hybrid.param_shapes); its vectors —
     # norms, a_log, dt_bias, conv filters, the selection bias — stay dense
     "wqkv", "w_fa", "w_fb", "w_beta", "w_ga", "w_gb", "wkva", "wkvb",
-    "ws_gate", "ws_up", "ws_down",
+    "ws_gate", "ws_up", "ws_down", "w_a", "w_g",
 }
 
 
@@ -158,12 +158,12 @@ def synthetic_quantized_params(cfg, dtype=jnp.bfloat16, device=None, mesh=None) 
         }
 
 
-@functools.partial(jax.jit, static_argnums=(1, 2))
-def _int8_leaf(key, shape: tuple, scale_dtype: str) -> QTensor:
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _int8_leaf(key, shape: tuple, scale_dtype: str, absmax: float = 0.02) -> QTensor:
     """One seeded int8 leaf generated where it will live: absmax 127 at a
-    weight scale of 0.02 (dequantised std about 0.012)."""
+    weight scale of ``absmax`` (0.02: dequantised std about 0.012)."""
     q = jax.random.randint(key, shape, -127, 128, jnp.int8)
-    return QTensor(q=q, scale=jnp.full(shape[:-2] + (1,) + shape[-1:], 0.02 / 127.0, scale_dtype))
+    return QTensor(q=q, scale=jnp.full(shape[:-2] + (1,) + shape[-1:], absmax / 127.0, scale_dtype))
 
 
 def _synthetic_hybrid(cfg, dtype, device) -> dict:
@@ -185,12 +185,16 @@ def _synthetic_hybrid(cfg, dtype, device) -> dict:
         out = {
             g: {
                 name: qleaf(next(keys), shape) if matrix
-                else hybrid.vector_values(name, shape, next(keys), dtype)
+                else hybrid.vector_values(name, shape, next(keys), dtype, g)
                 for name, (shape, matrix) in group.items()
             }
             for g, group in shapes.items()
         }
-        out["embed"] = qleaf(next(keys), (cfg.vocab_size, cfg.dim))
+        # int8 values uniform over ±127 have an RMS of absmax / √3
+        rms = hybrid.embed_rms(cfg)
+        out["embed"] = _int8_leaf(
+            next(keys), (cfg.vocab_size, cfg.dim), jnp.dtype(dtype).name, 0.02 if rms is None else rms * 3.0**0.5
+        )
         out["lm_head"] = qleaf(next(keys), (cfg.dim, cfg.vocab_size))
         out["final_norm"] = jnp.ones((cfg.dim,), dtype)
         return out

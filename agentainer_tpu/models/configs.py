@@ -5,7 +5,9 @@ Mixtral-8x7B expert-parallel (config #5); OLMoE-1B-7B is the same block with
 its two switches; Kimi-Linear-48B-A3B is the hybrid block
 (``layer_kinds``: gated-delta linear attention beside NoPE latent attention,
 a dense first layer, then sigmoid-routed experts with a shared one —
-models/hybrid.py). Tiny variants exist for CI and the virtual CPU mesh —
+models/hybrid.py); Olmo-Hybrid-7B is the same block's other pair of mixers
+(a gated delta rule with one decay a head beside full softmax attention, a
+dense SwiGLU in every layer, the OLMo-2 norm placement). Tiny variants exist for CI and the virtual CPU mesh —
 same code path, small shapes.
 
 All dims are chosen TPU-aware: head_dim and hidden sizes are multiples of
@@ -15,6 +17,10 @@ All dims are chosen TPU-aware: head_dim and hidden sizes are multiples of
 from __future__ import annotations
 
 from dataclasses import dataclass, field
+
+
+LINEAR_KINDS = ("kda", "gdn")
+POSITIONAL_KINDS = ("mla", "full")
 
 
 @dataclass(frozen=True)
@@ -40,14 +46,25 @@ class ModelConfig:
     # embedding (OLMoE)
     qk_norm: bool = False
     # -- the hybrid block (models/hybrid.py); every default is "not hybrid" --
-    # mixer of each layer in order, "kda" (gated-delta linear attention: a
-    # recurrent state and a short-conv state per lane) or "mla" (latent
-    # attention without rotary embedding: one shared latent row per token);
-    # empty: every layer is RoPE GQA attention over a K/V arena
+    # mixer of each layer in order. Linear kinds keep a recurrent state and a
+    # short-conv state per lane: "kda" (gated delta rule, a decay per key
+    # channel, β = sigmoid) or "gdn" (gated delta rule, one decay a head, keys
+    # and values of different widths, a full-rank SiLU output gate).
+    # Positional kinds keep rows up to a lane's position: "mla" (latent
+    # attention without rotary embedding: one shared latent row per token) or
+    # "full" (softmax attention over K/V rows, QK-norm under ``qk_norm``, no
+    # rotary embedding where ``rope_theta`` is 0). A model has at most one
+    # kind of each. Empty: every layer is RoPE GQA attention over a K/V arena
     layer_kinds: tuple[str, ...] = ()
     kda_heads: int = 0
-    kda_head_dim: int = 0  # of keys and of values
+    kda_head_dim: int = 0  # of keys (and of values where ``kda_v_dim`` is 0)
+    kda_v_dim: int = 0  # of values ("gdn": the state is [kda_head_dim, kda_v_dim] a head)
     kda_conv: int = 4  # short causal depthwise conv over q, k, v
+    # β = 2 · sigmoid(·): the transition I − β k kᵀ has an eigenvalue in (−1, 1)
+    delta_neg_eigval: bool = False
+    # the residual adds the NORMED OUTPUT of each sublayer (x += norm(f(x)),
+    # no pre-norm: the OLMo-2 family) instead of x += f(norm(x))
+    post_norm: bool = False
     mla_kv_rank: int = 0  # the cached latent c (kv_lora_rank)
     mla_nope_dim: int = 0  # per-head key dims expanded from the latent
     mla_rope_dim: int = 0  # key dims shared by all heads, cached beside c
@@ -89,6 +106,27 @@ class ModelConfig:
     def n_mla(self) -> int:
         return sum(k == "mla" for k in self.layer_kinds)
 
+    @property
+    def linear_kind(self) -> str | None:
+        """The model's one kind of linear mixer (``LINEAR_KINDS``), or None."""
+        return next((k for k in LINEAR_KINDS if k in self.layer_kinds), None)
+
+    @property
+    def positional_kind(self) -> str | None:
+        return next((k for k in POSITIONAL_KINDS if k in self.layer_kinds), None)
+
+    @property
+    def n_linear(self) -> int:
+        return sum(k in LINEAR_KINDS for k in self.layer_kinds)
+
+    @property
+    def n_positional(self) -> int:
+        return sum(k in POSITIONAL_KINDS for k in self.layer_kinds)
+
+    @property
+    def delta_v_dim(self) -> int:
+        return self.kda_v_dim or self.kda_head_dim
+
     def _hybrid_counts(self) -> dict:
         """Parameters of the hybrid pytree by part (models/hybrid.init_params)."""
         d, h, hk = self.dim, self.kda_heads, self.kda_head_dim
@@ -109,7 +147,19 @@ class ModelConfig:
         )
         expert = 3 * d * self.ffn_dim
         moe = d * self.n_experts + self.n_experts + self.n_shared_experts * expert
-        return {"kda": kda, "mla": mla, "expert": expert, "moe_fixed": moe,
+        ck, cv = h * hk, h * self.delta_v_dim
+        gdn = (
+            d * (2 * ck + cv) + cv * d  # q, k, v, o
+            + d * cv  # output gate
+            + 2 * d * h  # decay and beta
+            + self.kda_conv * (2 * ck + cv)  # conv filters
+            + 2 * h + self.delta_v_dim  # A_log, dt_bias, head norm
+        )
+        hd = self.head_dim
+        full = 2 * d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd
+        if self.qk_norm:
+            full += (self.n_heads + self.n_kv_heads) * hd
+        return {"kda": kda, "mla": mla, "gdn": gdn, "full": full, "expert": expert, "moe_fixed": moe,
                 "dense": 3 * d * self.dense_ffn_dim}
 
     @property
@@ -124,9 +174,9 @@ class ModelConfig:
             n_moe = self.n_layers - self.n_dense_layers
             return (
                 2 * embed + self.dim + 2 * self.n_layers * self.dim
-                + self.n_kda * c["kda"] + self.n_mla * c["mla"]
+                + sum(c[k] for k in self.layer_kinds)
                 + self.n_dense_layers * c["dense"]
-                + n_moe * (c["moe_fixed"] + self.n_held * c["expert"])
+                + (n_moe * (c["moe_fixed"] + self.n_held * c["expert"]) if n_moe else 0)
             )
         per_layer_attn = self.dim * self.dim + 2 * self.dim * (
             self.n_kv_heads * self.head_dim
@@ -172,11 +222,13 @@ class ModelConfig:
             # a KDA layer reads and rewrites its state whatever the context
             # (4 passes over H·dk·dv: decay, k·S, rank-1 update, q·S); an MLA
             # layer scores 192 dims and combines 128 per head and slot
-            kda = 8.0 * self.kda_heads * self.kda_head_dim**2
+            delta = 8.0 * self.kda_heads * self.kda_head_dim * self.delta_v_dim
             mla = 2.0 * self.n_heads * context_len * (
                 self.mla_nope_dim + self.mla_rope_dim + self.mla_v_dim
             )
-            return matmul + self.n_kda * kda + self.n_mla * mla
+            full = 4.0 * self.n_heads * self.head_dim * context_len
+            positional = {"mla": mla, "full": full}.get(self.positional_kind, 0.0)
+            return matmul + self.n_linear * delta + self.n_positional * positional
         # attention scores + value combine: q·K^T and p·V, each
         # 2 * heads * head_dim * context MACs → 4 FLOPs per context slot
         attn = 4.0 * self.n_heads * self.head_dim * context_len
@@ -376,6 +428,69 @@ TINY_KIMI_LINEAR = register(
         n_shared_experts=1,
         moe_router="sigmoid",
         moe_scale=2.446,
+    )
+)
+
+# Olmo-Hybrid-7B (allenai/Olmo-Hybrid-7B config.json: 32 layers, hidden
+# 3840, (linear, linear, linear, full) x 8; the linear layers a gated delta
+# rule with one decay a head, 30 heads of 96-wide keys and 192-wide values,
+# conv of 4, negative eigenvalues allowed; the full layers 30/30 heads of 128
+# with QK-norm and no rotary embedding (``rope_theta`` null); a dense SwiGLU of
+# 11008 in every layer; the OLMo-2 family's norm placement; vocabulary
+# 100,352, untied). 7.42 B parameters.
+def olmo_hybrid_kinds(n_layers: int, period: int = 4) -> tuple[str, ...]:
+    return tuple("full" if i % period == 0 else "gdn" for i in range(1, n_layers + 1))
+
+
+OLMO_HYBRID_7B = register(
+    ModelConfig(
+        name="olmo-hybrid-7b",
+        vocab_size=100_352,
+        dim=3840,
+        n_layers=32,
+        n_heads=30,
+        n_kv_heads=30,
+        ffn_dim=11_008,
+        max_seq_len=65_536,
+        rope_theta=0.0,  # published null: no rotary embedding
+        norm_eps=1e-6,
+        qk_norm=True,
+        layer_kinds=olmo_hybrid_kinds(32),
+        kda_heads=30,
+        kda_head_dim=96,
+        kda_v_dim=192,
+        kda_conv=4,
+        delta_neg_eigval=True,
+        post_norm=True,
+        n_dense_layers=32,
+        dense_ffn_dim=11_008,
+    )
+)
+
+# The same block at CI shapes: two periods (L L L F L L L F), head counts
+# that are no multiple of 8 and keys narrower than values.
+TINY_OLMO_HYBRID = register(
+    ModelConfig(
+        name="tiny-olmo-hybrid",
+        vocab_size=512,
+        dim=96,
+        n_layers=8,
+        n_heads=6,
+        n_kv_heads=6,
+        ffn_dim=128,
+        max_seq_len=256,
+        rope_theta=0.0,
+        norm_eps=1e-6,
+        qk_norm=True,
+        layer_kinds=olmo_hybrid_kinds(8),
+        kda_heads=6,
+        kda_head_dim=12,
+        kda_v_dim=24,
+        kda_conv=4,
+        delta_neg_eigval=True,
+        post_norm=True,
+        n_dense_layers=8,
+        dense_ffn_dim=128,
     )
 )
 

@@ -1,5 +1,5 @@
-"""The hybrid block (Kimi-Linear): two mixers and two FFNs through the one
-forward.
+"""The hybrid block (Kimi-Linear, Olmo-Hybrid): a linear mixer beside a
+positional one, and two FFNs, through the one forward.
 
 ``models/llama.forward`` hands a config with ``layer_kinds`` to
 :func:`forward` here; the engine calls one ``forward`` and never learns a
@@ -13,9 +13,17 @@ with
   latent attention with no rotary embedding anywhere; the cache row is the
   normalised latent and the shared key dimensions, the up-projection
   absorbed into query and output);
+- or the mixer **GDN** (Gated DeltaNet: the same delta rule with ONE decay a
+  head, ``β = 2 · sigmoid`` where the model allows negative eigenvalues, keys
+  narrower than values, so a rectangular state, and a full-rank SiLU output
+  gate) or **full** (softmax attention over K/V rows read by the dense flash
+  kernels, QK-norm over the whole projections, no rotary embedding where the
+  model has none). A model has one linear kind and one positional kind;
 - the FFN a dense SwiGLU (the first ``n_dense_layers``) or the MoE of
   ``models/llama.py`` with the sigmoid router rule, a shared expert and the
-  experts this chip holds.
+  experts this chip holds;
+- the norms before each sublayer, or (``cfg.post_norm``, the OLMo-2 family)
+  on each sublayer's OUTPUT: ``x += rmsnorm(mixer(x)); x += rmsnorm(ffn(x))``.
 
 **Weights are stacked per kind** (``params["kda"]`` ``[n_kda, …]``,
 ``["mla"]``, ``["dense"]``, ``["moe"]``; the two pre-norm vectors of every
@@ -25,11 +33,14 @@ layer's kind (``lax.cond``) and indexes the per-kind stacks, and the caches,
 by the layer's index within its kind.
 
 **The cache is a pytree this module builds** (:class:`HybridCache`,
-``llama.init_cache``): positional rows ``latent [n_mla, B, S, 640]`` (576 values and padding to whole lane tiles; read
-up to a lane's position, like a K/V arena) and per-lane state ``state
-[n_kda, B, H, dk, dv]`` float32 and ``conv [n_kda, B, (W − 1)·3·H·dk]`` —
-which cannot be truncated, rewound or overwritten harmlessly. All ride in
-the scan's carry and are updated in place.
+``llama.init_cache``): positional rows — ``latent [n_mla, B, S, 640]`` (576
+values and padding to whole lane tiles) or ``k``, ``v`` ``[n_full, B, S, KV
+stored, hd]`` (:func:`stored_kv_heads`), read up to a lane's position like a
+K/V arena — and per-lane state ``state`` float32 (``[n_kda, B, H, dk, dv]``,
+or for GDN ``[n_gdn, B, dk, H·dv]``: whole tiles at 96 × 192 a head) and
+``conv [n, B, (W − 1)·channels]`` — which cannot be truncated, rewound or
+overwritten harmlessly. A leaf the model has no layer for is ``None``. All
+ride in the scan's carry and are updated in place.
 
 **Masking is part of the mathematics.** A token that is not valid leaves
 state and conv untouched (β = 0, g = 0, conv not shifted). Prefill says
@@ -56,24 +67,52 @@ from ..ops import mla as mla_ops
 from ..ops.moe import EXPERT_WEIGHTS, stacked_experts
 from ..ops.norms import rms_norm
 from ..ops.quant import QTensor, dequant, embed_lookup
-from .configs import ModelConfig
+from .configs import LINEAR_KINDS, POSITIONAL_KINDS, ModelConfig
 
 NO_STOP = np.iinfo(np.int32).max
 L2_EPS = 1e-6
 
 
 class HybridCache(NamedTuple):
-    """``latent`` is positional (rows up to a lane's position); ``state`` and
+    """``latent``, ``k`` and ``v`` are positional (rows up to a lane's
+    position; ``None`` where the model has no such layer); ``state`` and
     ``conv`` are per-lane; ``stop`` and ``eos`` are the per-lane decode
     controls (module docstring)."""
 
-    latent: jnp.ndarray  # [n_mla, B, S, latent_width]: R + r values, zero padding
-    state: jnp.ndarray  # [n_kda, B, H, dk, dv] float32
-    conv: jnp.ndarray  # [n_kda, B, (W - 1)·3·H·dk]: the last W − 1 conv inputs, row after row
+    latent: jnp.ndarray | None  # [n_mla, B, S, latent_width]: R + r values, zero padding
+    state: jnp.ndarray  # [n_kda, B, H, dk, dv] or [n_gdn, B, dk, H·dv], float32
+    conv: jnp.ndarray  # [n, B, (W - 1)·channels]: the last W − 1 conv inputs, row after row
     stop: jnp.ndarray  # [B] int32
     eos: jnp.ndarray  # [B] int32 (-1: no token closes the lane)
+    k: jnp.ndarray | None = None  # [n_full, B, S, stored_kv_heads, hd]
+    v: jnp.ndarray | None = None
 
-    POSITIONAL = ("latent",)
+    POSITIONAL = ("latent", "k", "v")
+
+    def rows(self) -> tuple:
+        """The positional leaves this cache has, in ``POSITIONAL``'s order."""
+        return tuple(a for a in (self.latent, self.k, self.v) if a is not None)
+
+    def leaves(self) -> dict:
+        """The leaves a slot is made of, by name (the controls left out)."""
+        named = {"latent": self.latent, "k": self.k, "v": self.v, "state": self.state, "conv": self.conv}
+        return {n: a for n, a in named.items() if a is not None}
+
+
+def stored_kv_heads(n_kv_heads: int) -> int:
+    """K/V heads a row of the ``k`` and ``v`` leaves holds. The dense flash
+    kernels read a block of whole ``[KV, hd]`` tiles, so a count that is not
+    1, 2, 4 or a multiple of 8 is stored rounded up to one (30 as 32): the
+    heads beyond the model's are never written, and a query padded with
+    zeros reads them and is dropped. HBM tiles the last two dims, so the
+    unpadded arena would occupy the same bytes, and XLA would pad it into a
+    temporary before every kernel call besides."""
+    return n_kv_heads if n_kv_heads in (1, 2, 4) else -(-n_kv_heads // 8) * 8
+
+
+def conv_channels(cfg: ModelConfig) -> int:
+    """Channels of the short conv: q, k and v side by side."""
+    return cfg.kda_heads * (2 * cfg.kda_head_dim + cfg.delta_v_dim)
 
 
 def latent_width(cfg: ModelConfig) -> int:
@@ -91,16 +130,22 @@ def init_cache(
     """A zeroed cache. ``live``: every lane steps (direct callers, tests);
     an engine starts its lanes closed (``stop = 0``) and opens one when it
     admits a request."""
-    h, dk = cfg.kda_heads, cfg.kda_head_dim
+    h, dk, dv, nl = cfg.kda_heads, cfg.kda_head_dim, cfg.delta_v_dim, cfg.n_linear
+    if any(sum(k in cfg.layer_kinds for k in kinds) > 1 for kinds in (LINEAR_KINDS, POSITIONAL_KINDS)):
+        raise ValueError("the hybrid block has one linear kind and one positional kind of mixer")
+    arena = (cfg.n_positional, lanes, max_seq, stored_kv_heads(cfg.n_kv_heads), cfg.head_dim)
+    full = cfg.positional_kind == "full"
     return HybridCache(
-        latent=jnp.zeros((cfg.n_mla, lanes, max_seq, latent_width(cfg)), dtype),
-        state=jnp.zeros((cfg.n_kda, lanes, h, dk, dk), jnp.float32),
+        latent=jnp.zeros((cfg.n_mla, lanes, max_seq, latent_width(cfg)), dtype) if cfg.n_mla else None,
+        state=jnp.zeros((nl, lanes, dk, h * dv) if cfg.linear_kind == "gdn" else (nl, lanes, h, dk, dv), jnp.float32),
         # the W − 1 rows of a lane side by side: a dimension of 3 next to the
         # channels would be padded to a whole sublane tile (or, minor-most,
         # to 128 lanes: compiled for a described v5e, 94 MB became 3.75 GB)
-        conv=jnp.zeros((cfg.n_kda, lanes, (cfg.kda_conv - 1) * 3 * h * dk), dtype),
+        conv=jnp.zeros((nl, lanes, (cfg.kda_conv - 1) * conv_channels(cfg)), dtype),
         stop=jnp.full((lanes,), NO_STOP if live else 0, jnp.int32),
         eos=jnp.full((lanes,), -1, jnp.int32),
+        k=jnp.zeros(arena, dtype) if full else None,
+        v=jnp.zeros(arena, dtype) if full else None,
     )
 
 
@@ -123,15 +168,20 @@ def admit_lane(cache: HybridCache, lane, fresh, stop, eos) -> HybridCache:
     )
 
 
-def snapshot_lane(cache: HybridCache, lane, bucket: int) -> dict:
-    """A lane's leaves by name: the positional rows ``[:, :bucket]``, the
-    per-lane state whole."""
+def snapshot_lane(cache: HybridCache, lane, bucket: int, n_kv_heads: int | None = None) -> dict:
+    """A lane's leaves by name: the positional rows ``[:, :bucket]`` (of
+    ``k`` and ``v`` the model's ``n_kv_heads`` heads, not the padding they
+    are stored with), the per-lane state whole."""
     lane_of = lambda a: lax.dynamic_index_in_dim(a, lane, axis=1, keepdims=False)  # noqa: E731
-    return {
-        "latent": lane_of(cache.latent)[:, :bucket],
-        "state": lane_of(cache.state),
-        "conv": lane_of(cache.conv),
-    }
+    out = {}
+    for name, a in cache.leaves().items():
+        a = lane_of(a)
+        if name in cache.POSITIONAL:
+            a = a[:, :bucket]
+            if name != "latent":
+                a = a[:, :, :n_kv_heads]
+        out[name] = a
+    return out
 
 
 def restore_lane(cache: HybridCache, lane, leaves: dict) -> HybridCache:
@@ -143,9 +193,7 @@ def restore_lane(cache: HybridCache, lane, leaves: dict) -> HybridCache:
         return lax.dynamic_update_slice(arena, value[:, None].astype(arena.dtype), start)
 
     return cache._replace(
-        latent=put(cache.latent, leaves["latent"]),
-        state=put(cache.state, leaves["state"]),
-        conv=put(cache.conv, leaves["conv"]),
+        **{name: put(a, leaves[name]) for name, a in cache.leaves().items()},
         stop=cache.stop.at[lane].set(0),
     )
 
@@ -162,15 +210,31 @@ class HybridPlan(NamedTuple):
     mla_decode: str
     mla_prefill: str
     reason: str
+    # the other family's kinds ("": the model has no such layer)
+    gdn_decode: str = ""
+    gdn_prefill: str = ""
+    full_decode: str = ""
+    full_prefill: str = ""
 
     def describe(self) -> dict:
-        return {**self._asdict(), "prefill": self.mla_prefill, "decode": self.mla_decode,
-                "arena": "stack+layer"}
+        mine = {k: v for k, v in self._asdict().items() if v}
+        prefill, decode = (
+            (self.full_prefill, self.full_decode) if self.full_decode else (self.mla_prefill, self.mla_decode)
+        )
+        arena = "layer_slice" if decode.startswith("xla:") else "stack+layer"
+        return {**mine, "prefill": prefill, "decode": decode, "arena": arena}
+
+    def kinds(self) -> dict:
+        """``kind -> (prefill, decode)`` of the kinds the model has."""
+        pairs = {k: (getattr(self, k + "_prefill"), getattr(self, k + "_decode")) for k in ("kda", "gdn", "mla", "full")}
+        return {k: v for k, v in pairs.items() if v[0]}
 
 
 def plan_hybrid(cfg: ModelConfig, use_pallas: bool | None = None) -> HybridPlan:
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
+    if cfg.linear_kind == "gdn" or cfg.positional_kind == "full":
+        return _plan_gdn_full(cfg, use_pallas)
     aligned = cfg.kda_head_dim % 128 == 0 and cfg.kda_heads % 8 == 0
     if use_pallas and aligned:
         return HybridPlan(
@@ -179,6 +243,38 @@ def plan_hybrid(cfg: ModelConfig, use_pallas: bool | None = None) -> HybridPlan:
         )
     why = "no tpu backend" if not use_pallas else "KDA heads not (8, 128)-aligned"
     return HybridPlan("xla_step", "xla_chunked", "xla_absorbed", "xla_absorbed", why)
+
+
+def _plan_gdn_full(cfg: ModelConfig, use_pallas: bool) -> HybridPlan:
+    """GDN beside full attention: the state kernel where a lane's ``[dk,
+    H·dv]`` tile is whole (8, 128) tiles and the heads pack into lane-aligned
+    windows; the dense flash kernels where they take the STORED head count."""
+    from ..ops.pallas_attention import kernel_supported
+    from ..ops.pallas_kda import gdn_supported
+
+    why = []
+    h, dk, dv = cfg.kda_heads, cfg.kda_head_dim, cfg.delta_v_dim
+    gdn = "xla_step"
+    if not use_pallas:
+        why.append("no tpu backend")
+    elif not gdn_supported(h, dk, dv):
+        why.append(f"GDN state tile [{dk}, {h}x{dv}] is not whole (8, 128) tiles")
+    else:
+        gdn = "pallas_gdn_decode"
+    full = ("xla:attention_reference",) * 2
+    if use_pallas:
+        kv = stored_kv_heads(cfg.n_kv_heads)
+        if kernel_supported(kv * (cfg.n_heads // cfg.n_kv_heads), kv, cfg.head_dim):
+            full = ("pallas:flash_prefill", "pallas:flash_decode")
+        else:
+            why.append(f"heads {cfg.n_heads}/{kv} stored x {cfg.head_dim}: not the flash kernels' shapes")
+    if not why:
+        why.append("tpu backend; state and K/V stacks read where they lie")
+    return HybridPlan(
+        "", "", "", "", "; ".join(why),
+        gdn_decode=gdn if cfg.linear_kind else "", gdn_prefill="xla_chunked" if cfg.linear_kind else "",
+        full_decode=full[1] if cfg.positional_kind else "", full_prefill=full[0] if cfg.positional_kind else "",
+    )
 
 
 # -- parameters ----------------------------------------------------------------
@@ -193,6 +289,16 @@ def param_shapes(cfg: ModelConfig) -> dict:
     nd, ne = cfg.n_dense_layers, cfg.n_layers - cfg.n_dense_layers
     qk = cfg.mla_nope_dim + cfg.mla_rope_dim
     fs = cfg.n_shared_experts * cfg.ffn_dim
+    ng, nf = cfg.layer_kinds.count("gdn"), cfg.layer_kinds.count("full")
+    cc, cv, hd = conv_channels(cfg), h * cfg.delta_v_dim, cfg.head_dim
+    full = {
+        "wq": ((nf, d, cfg.n_heads * hd), True),
+        "wk": ((nf, d, cfg.n_kv_heads * hd), True),
+        "wv": ((nf, d, cfg.n_kv_heads * hd), True),
+        "wo": ((nf, cfg.n_heads * hd, d), True),
+    }
+    if cfg.qk_norm:
+        full.update(q_norm=((nf, cfg.n_heads * hd), False), k_norm=((nf, cfg.n_kv_heads * hd), False))
     shapes = {
         "layers": {"attn_norm": ((cfg.n_layers, d), False), "mlp_norm": ((cfg.n_layers, d), False)},
         "kda": {
@@ -208,6 +314,18 @@ def param_shapes(cfg: ModelConfig) -> dict:
             "o_norm": ((nk, dk), False),
             "wo": ((nk, c, d), True),
         },
+        "gdn": {
+            "wqkv": ((ng, d, cc), True),  # q | k | v: 2·H·dk + H·dv columns
+            "conv": ((ng, cfg.kda_conv, cc), False),
+            "w_a": ((ng, d, h), True),
+            "dt_bias": ((ng, h), False),
+            "a_log": ((ng, h), False),
+            "w_beta": ((ng, d, h), True),
+            "w_g": ((ng, d, cv), True),
+            "o_norm": ((ng, cfg.delta_v_dim), False),
+            "wo": ((ng, cv, d), True),
+        },
+        "full": full,
         "mla": {
             "wq": ((nm, d, cfg.n_heads * qk), True),
             "wkva": ((nm, d, cfg.mla_kv_rank + cfg.mla_rope_dim), True),
@@ -235,12 +353,27 @@ def param_shapes(cfg: ModelConfig) -> dict:
     return {g: v for g, v in shapes.items() if all(s[0][0] > 0 for s in v.values())}
 
 
-def vector_values(name: str, shape: tuple, key, dtype):
+def vector_values(name: str, shape: tuple, key, dtype, group: str = "kda"):
     """The dense vectors of a hybrid model, seeded: norms at one; ``a_log``
     and ``dt_bias`` drawn so that a token's decays spread over about (0.5,
     0.999) (``−g = exp(a_log) · softplus(dt_bias + small)``: a reference that
     drops the gate is far off); conv filters of order ½; the selection bias
-    non-zero, so that choosing by ``s + b`` and weighing by ``s`` differ."""
+    non-zero, so that choosing by ``s + b`` and weighing by ``s`` differ.
+    ``group == "gdn"``: Gated DeltaNet's published initialisation (Mamba2's:
+    ``A ~ U(0, 16)``, ``dt`` log-uniform over (0.001, 0.1), ``dt_bias`` its
+    inverse softplus), so a head's decay ``exp(−A · softplus(w_a x + dt_bias))``
+    spreads from about 0.2 to 0.9999 with a median near 0.9: heads that forget
+    in a few tokens beside heads that remember hundreds. (A first draw kept
+    EVERY head's memory longer than the 200 tokens of the numerics check: the
+    rounding of each token's key then adds up through the state's
+    ``I − β k kᵀ`` transitions like the square root of the length, and the
+    reference with bfloat16 matmul inputs itself read 2.07 % at 200 tokens,
+    my chip run, PR 32.)"""
+    if group == "gdn" and name == "a_log":
+        return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0 / 16.0, 16.0)).astype(dtype)
+    if group == "gdn" and name == "dt_bias":
+        dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32, np.log(0.001), np.log(0.1)))
+        return jnp.log(jnp.expm1(dt)).astype(jnp.float32)  # softplus⁻¹: kept f32
     if name == "a_log":
         return jax.random.uniform(key, shape, jnp.float32, -0.3, 0.3).astype(dtype)
     if name == "dt_bias":
@@ -251,6 +384,20 @@ def vector_values(name: str, shape: tuple, key, dtype):
     if name == "router_bias":
         return (jax.random.normal(key, shape, jnp.float32) * 0.1).astype(jnp.float32)
     return jnp.ones(shape, dtype)
+
+
+def embed_rms(cfg: ModelConfig) -> float | None:
+    """RMS of a synthetic embedding's rows, where it is not the weights' own
+    scale (``None``): 1 for a block without a pre-norm (``cfg.post_norm``).
+    Such a block's first sublayers read the embedding itself, and at the
+    weights' 0.02 their outputs' mean squares lie under the norms' eps (3e-9
+    against 1e-6 in the first mixer, 2e-8 in the first FFN: read on the CPU at
+    published head sizes), so the norms that should set the stream's scale do
+    not, every sublayer is a product of small numbers, and a rounding of its
+    input is doubled by each (the reference with bfloat16 matmul inputs read
+    1.84 % at 4 layers that way, my chip run, PR 32). A served model's stream
+    is of order one from its first layer."""
+    return 1.0 if cfg.post_norm else None
 
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
@@ -265,12 +412,14 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
 
     out = {
         g: {
-            name: w(next(keys), shape) if matrix else vector_values(name, shape, next(keys), dtype)
+            name: w(next(keys), shape) if matrix else vector_values(name, shape, next(keys), dtype, g)
             for name, (shape, matrix) in group.items()
         }
         for g, group in shapes.items()
     }
     out["embed"] = w(next(keys), (cfg.vocab_size, cfg.dim))
+    if embed_rms(cfg) is not None:
+        out["embed"] = (out["embed"].astype(jnp.float32) * (embed_rms(cfg) / 0.02)).astype(dtype)
     out["lm_head"] = w(next(keys), (cfg.dim, cfg.vocab_size))
     out["final_norm"] = jnp.ones((cfg.dim,), dtype)
     return out
@@ -374,6 +523,76 @@ def kda_mixer(h, lp, cfg: ModelConfig, state, conv, idx, slot, valid, plan: Hybr
     return _proj(o.reshape(b, t, nh * dk).astype(h.dtype), lp["wo"]), state, conv
 
 
+def gdn_mixer(h, lp, cfg: ModelConfig, state, conv, idx, slot, valid, plan: HybridPlan):
+    """``h [B, T, d]`` → the Gated DeltaNet mixer's output, and the state
+    ``[n, B, dk, H·dv]`` and conv stacks with layer ``idx``'s lanes stepped by
+    the valid tokens. One decay a head; ``β = 2 · sigmoid`` under
+    ``cfg.delta_neg_eigval``; a full-rank SiLU gate on the normed output."""
+    b, t, _ = h.shape
+    nh, dk, dv = cfg.kda_heads, cfg.kda_head_dim, cfg.delta_v_dim
+    ck = nh * dk
+    n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
+    conv_rows = _rows(conv, idx, slot, b).reshape(b, cfg.kda_conv - 1, conv_channels(cfg))
+    qkv, new_conv = kda_ops.causal_conv(_proj(h, lp["wqkv"]), conv_rows, lp["conv"], n_valid)
+    qkv = jax.nn.silu(qkv)
+    q = _l2norm(qkv[..., :ck].reshape(b, t, nh, dk)) * dk**-0.5
+    k = _l2norm(qkv[..., ck : 2 * ck].reshape(b, t, nh, dk))
+    v = qkv[..., 2 * ck :].reshape(b, t, nh, dv)
+    g = -jnp.exp(lp["a_log"].astype(jnp.float32)) * jax.nn.softplus(
+        _proj(h, lp["w_a"]) + lp["dt_bias"].astype(jnp.float32)
+    )  # [B, T, H]
+    beta = jax.nn.sigmoid(_proj(h, lp["w_beta"])) * (2.0 if cfg.delta_neg_eigval else 1.0)
+    g, beta = kda_ops.mask_inputs(g[..., None], beta, valid)
+    if t == 1 and plan.gdn_decode == "pallas_gdn_decode" and slot is None:
+        from ..ops.pallas_kda import gdn_decode
+
+        o, state = gdn_decode(q[:, 0], k[:, 0], v[:, 0], g[:, 0, :, 0], beta[:, 0], state, idx)
+        o = o[:, None]
+    else:
+        # the stored tile [dk, H·dv] viewed a head at a time for the jnp forms
+        rows = jnp.swapaxes(_rows(state, idx, slot, b).reshape(b, dk, nh, dv), 1, 2)
+        if t == 1:
+            o, rows = kda_ops.kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], rows)
+            o = o[:, None]
+        else:
+            o, rows = kda_ops.kda_chunked(q, k, v, g, beta, rows)
+        state = _put_rows(state, jnp.swapaxes(rows, 1, 2).reshape(b, dk, nh * dv), idx, slot)
+    conv = _put_rows(conv, new_conv.reshape(b, -1), idx, slot)
+    gate = jax.nn.silu(_proj(h, lp["w_g"])).reshape(b, t, nh, dv)
+    o = rms_norm(o, lp["o_norm"], cfg.norm_eps) * gate
+    return _proj(o.reshape(b, t, nh * dv).astype(h.dtype), lp["wo"]), state, conv
+
+
+def full_mixer(h, lp, cfg: ModelConfig, ck, cv, idx, slot, positions, valid, plan: HybridPlan):
+    """``h [B, T, d]`` → softmax attention's output and the K/V stacks with
+    this step's rows written at their positions (rows past S drop). The rows
+    hold :func:`stored_kv_heads` heads; the query is padded to match and the
+    padding's output dropped. A lane that does not step attends to one row
+    instead of all S, like :func:`mla_mixer`'s."""
+    from ..ops import attention as attn_ops
+
+    b, t, _ = h.shape
+    nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    group, stored = nh // nkv, ck.shape[3]
+    q, k, v = _proj(h, lp["wq"]), _proj(h, lp["wk"]), _proj(h, lp["wv"])
+    if cfg.qk_norm:  # over the whole projection, before the heads are split
+        q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+        k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+    q, k, v = q.reshape(b, t, nh, hd), k.reshape(b, t, nkv, hd), v.reshape(b, t, nkv, hd)
+    if cfg.rope_theta:
+        from ..ops.rope import apply_rope
+
+        q, k = apply_rope(q, positions, cfg.rope_theta), apply_rope(k, positions, cfg.rope_theta)
+    heads = lambda a, n: jnp.pad(a.astype(h.dtype), [(0, 0), (0, 0), (0, n - a.shape[2]), (0, 0)])  # noqa: E731
+    lanes = jnp.arange(b)[:, None] + (0 if slot is None else slot)
+    ck = ck.at[idx, lanes, positions].set(heads(k, stored).astype(ck.dtype))
+    cv = cv.at[idx, lanes, positions].set(heads(v, stored).astype(cv.dtype))
+    seen = jnp.where(valid, positions, 0) if t == 1 else positions
+    impl = attn_ops.pallas_dense if plan.full_decode.startswith("pallas:") else attn_ops._reference_dense
+    o = impl(heads(q, stored * group), ck, cv, seen, None, idx, slot)[:, :, :nh]
+    return _proj(o.reshape(b, t, nh * hd).astype(h.dtype), lp["wo"]), ck, cv
+
+
 def mla_mixer(h, lp, cfg: ModelConfig, latent, idx, slot, positions, valid, plan: HybridPlan):
     """``h [B, T, d]`` (normed) → the mixer's output and the latent stack with
     this step's rows written at their positions (rows past S drop). A lane
@@ -446,7 +665,7 @@ def forward(
     # run, PR 30); what a matmul takes is rounded to the weights' dtype once
     act = params["final_norm"].dtype
     x = embed_lookup(params["embed"], tokens).astype(jnp.float32)
-    kinds = np.array([k == "mla" for k in cfg.layer_kinds])
+    kinds = np.array([k in POSITIONAL_KINDS for k in cfg.layer_kinds])
     mixer_idx = np.where(kinds, np.cumsum(kinds) - 1, np.cumsum(~kinds) - 1).astype(np.int32)
     dense = np.arange(cfg.n_layers) < cfg.n_dense_layers
     ffn_idx = np.where(dense, np.arange(cfg.n_layers), np.arange(cfg.n_layers) - cfg.n_dense_layers)
@@ -459,18 +678,24 @@ def forward(
     # shared expert are computed here, from the int8 leaves)
     routed = {k: v for k, v in (moe_stack or {}).items() if k != "router" and not k.startswith("ws_")}
     shared = {k: v for k, v in (moe_stack or {}).items() if k.startswith("ws_")}
+    lin_kind, pos_kind = cfg.linear_kind, cfg.positional_kind
 
-    def mixer(h, latent, state, conv, is_mla, idx):
-        def kda(state, conv):
-            return kda_mixer(h, _layer_of(params["kda"], idx), cfg, state, conv, idx, slot, valid, plan)
+    def mixer(h, rows, state, conv, is_pos, idx):
+        """``rows``: the positional leaves (``(latent,)`` or ``(k, v)``)."""
 
-        def mla(latent):
-            return mla_mixer(h, _layer_of(params["mla"], idx), cfg, latent, idx, slot, positions, valid, plan)
+        def linear(state, conv):
+            fn = kda_mixer if lin_kind == "kda" else gdn_mixer
+            return fn(h, _layer_of(params[lin_kind], idx), cfg, state, conv, idx, slot, valid, plan)
 
-        if not cfg.n_mla:
-            y, state, conv = kda(state, conv)
-        elif not cfg.n_kda:
-            y, latent = mla(latent)
+        def positional(rows):
+            fn = mla_mixer if pos_kind == "mla" else full_mixer
+            y, *rows = fn(h, _layer_of(params[pos_kind], idx), cfg, *rows, idx, slot, positions, valid, plan)
+            return y, tuple(rows)
+
+        if pos_kind is None:
+            y, state, conv = linear(state, conv)
+        elif lin_kind is None:
+            y, rows = positional(rows)
         else:
             # Either mixer as a loop of 0 or 1 trips over the stacks it
             # updates. A ``lax.cond`` would do, but XLA copies what a branch
@@ -478,12 +703,12 @@ def forward(
             # layer (compiled for a described v5e: 2.7 GB of state copied in
             # each MLA layer). A while loop's carry stays one buffer whether
             # it trips or not, like the layer scan's own.
-            trips = is_mla.astype(jnp.int32)
+            trips = is_pos.astype(jnp.int32)
             y, state, conv = lax.fori_loop(
-                0, 1 - trips, lambda _, c: kda(c[1], c[2]), (jnp.zeros(h.shape, jnp.float32), state, conv)
+                0, 1 - trips, lambda _, c: linear(c[1], c[2]), (jnp.zeros(h.shape, jnp.float32), state, conv)
             )
-            y, latent = lax.fori_loop(0, trips, lambda _, c: mla(c[1]), (y, latent))
-        return y, latent, state, conv
+            y, rows = lax.fori_loop(0, trips, lambda _, c: positional(c[1]), (y, rows))
+        return y, rows, state, conv
 
     def ffn(h32, is_dense, idx):
         def dense_ffn(h32):
@@ -511,20 +736,29 @@ def forward(
         return lax.cond(is_dense, dense_ffn, moe_ffn, h32)
 
     def layer_step(carry, xs):
-        x, latent, state, conv = carry
-        attn_norm, mlp_norm, is_mla, m_idx, is_dense, f_idx = xs
+        x, rows, state, conv = carry
+        attn_norm, mlp_norm, is_pos, m_idx, is_dense, f_idx = xs
+        if cfg.post_norm:
+            # the OLMo-2 placement: each sublayer reads the stream as it is
+            # and the residual adds its NORMED output
+            y, rows, state, conv = mixer(x.astype(act), rows, state, conv, is_pos, m_idx)
+            x = x + rms_norm(y.astype(jnp.float32), attn_norm, cfg.norm_eps)
+            x = x + rms_norm(ffn(x, is_dense, f_idx).astype(jnp.float32), mlp_norm, cfg.norm_eps)
+            return (x, rows, state, conv), None
         h = rms_norm(x, attn_norm, cfg.norm_eps).astype(act)
-        y, latent, state, conv = mixer(h, latent, state, conv, is_mla, m_idx)
+        y, rows, state, conv = mixer(h, rows, state, conv, is_pos, m_idx)
         x = x + y.astype(jnp.float32)
         x = x + ffn(rms_norm(x, mlp_norm, cfg.norm_eps), is_dense, f_idx).astype(jnp.float32)
-        return (x, latent, state, conv), None
+        return (x, rows, state, conv), None
 
     xs = (
         params["layers"]["attn_norm"], params["layers"]["mlp_norm"],
         jnp.asarray(kinds), jnp.asarray(mixer_idx), jnp.asarray(dense), jnp.asarray(ffn_idx, jnp.int32),
     )
-    (x, latent, state, conv), _ = lax.scan(layer_step, (x, cache.latent, cache.state, cache.conv), xs)
+    (x, rows, state, conv), _ = lax.scan(layer_step, (x, cache.rows(), cache.state, cache.conv), xs)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps).astype(act)
     logits = _proj(x, params["lm_head"])
-    new_cache = HybridCache(latent, state, conv, stop, cache.eos) if keep_cache else None
-    return logits, new_cache
+    if not keep_cache:
+        return logits, None
+    named = dict(zip([n for n in cache.POSITIONAL if getattr(cache, n) is not None], rows))
+    return logits, cache._replace(**named, state=state, conv=conv, stop=stop)

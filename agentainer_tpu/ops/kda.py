@@ -1,10 +1,16 @@
-"""Kimi Delta Attention (KDA): a gated delta rule with a per-channel decay.
+"""The gated delta rule: Kimi Delta Attention (KDA, a decay per key channel)
+and Gated DeltaNet (one decay a head).
 
-Per head, with keys and values of ``dk`` and a state ``S [dk, dv]`` kept in
-float32 (``a_t = exp(g_t) ∈ (0, 1]^dk`` the decay, ``β_t ∈ [0, 1]``)::
+Per head, with keys of ``dk``, values of ``dv`` and a state ``S [dk, dv]``
+kept in float32 (``a_t = exp(g_t) ∈ (0, 1]`` the decay, ``β_t`` in ``[0, 1]``
+or, where the model allows negative eigenvalues, ``[0, 2]``)::
 
     S_t = (I − β_t k_t k_tᵀ) · diag(a_t) · S_{t−1} + β_t k_t v_tᵀ
     o_t = S_tᵀ q_t
+
+The log decay ``g`` is ``[..., H, dk]`` (KDA) or ``[..., H, 1]`` (one a head:
+``diag(a_t)`` is then ``a_t I`` and commutes with the rest); every function
+here takes either, and ``dv`` need not be ``dk``.
 
 Three forms of the same mathematics:
 
@@ -38,8 +44,11 @@ summed log-decay passes −88 (a published checkpoint's decays can: ``exp(A_log)
 up to 16 times a softplus), so ``A`` and ``B`` are built in :data:`SUB`-token
 blocks — a block below the diagonal factorises around the decay at the start
 of its ROW block (both factors then ≤ 1), a block on the diagonal takes
-``exp(G_t − G_s)`` pair by pair. The matmuls inside are float32 at
-``highest`` precision: they are a few hundredths of the layer's projections.
+``exp(G_t − G_s)`` pair by pair. With one decay a head there is nothing to
+sum over channels: ``A = (K Kᵀ) ⊙ exp(G_t − G_s)`` is one matmul and a mask
+with every exponent ≤ 0 (:func:`_chunk_scalar`). The matmuls inside are
+float32 at ``highest`` precision: they are a few hundredths of the layer's
+projections.
 """
 
 from __future__ import annotations
@@ -54,15 +63,15 @@ _HI = lax.Precision.HIGHEST
 
 
 def mask_inputs(g: jnp.ndarray, beta: jnp.ndarray, valid: jnp.ndarray):
-    """``g [..., H, dk]``, ``beta [..., H]`` with the invalid tokens' rows
+    """``g [..., H, dk]`` (or ``[..., H, 1]``), ``beta [..., H]`` with the invalid tokens' rows
     (``valid [...]`` false) set to leave the state alone."""
     return jnp.where(valid[..., None, None], g, 0.0), jnp.where(valid[..., None], beta, 0.0)
 
 
 def kda_step(q, k, v, g, beta, state):
-    """One token for every lane: ``q, k, v, g [B, H, dk]`` (``dv = dk``),
-    ``beta [B, H]``, ``state [B, H, dk, dv]`` float32 → ``(o [B, H, dv]
-    float32, new state)``."""
+    """One token for every lane: ``q, k [B, H, dk]``, ``v [B, H, dv]``,
+    ``g [B, H, dk]`` or ``[B, H, 1]``, ``beta [B, H]``, ``state [B, H, dk,
+    dv]`` float32 → ``(o [B, H, dv] float32, new state)``."""
     f32 = jnp.float32
     q, k, v, g, beta = (x.astype(f32) for x in (q, k, v, g, beta))
     a = jnp.exp(g)
@@ -127,6 +136,27 @@ def _chunk(q, k, v, g, beta, s0):
     return o, s1
 
 
+def _chunk_scalar(q, k, v, g, beta, s0):
+    """One chunk with one decay a head: ``g [B, H, C, 1]``, the rest as
+    :func:`_chunk`."""
+    c = q.shape[2]
+    mm = lambda eq, *xs: jnp.einsum(eq, *xs, precision=_HI)  # noqa: E731
+    big_g = jnp.cumsum(g[..., 0], axis=2)  # [B, H, C], ≤ 0 and falling
+    tri = jnp.tril(jnp.ones((c, c), bool))
+    # exp(G_t − G_s) for s ≤ t: the exponent is ≤ 0 there, clamped elsewhere
+    decay = jnp.where(tri, jnp.exp(jnp.minimum(big_g[..., :, None] - big_g[..., None, :], 0.0)), 0.0)
+    gamma = jnp.exp(big_g)[..., None]
+    lower = mm("bhtk,bhsk->bhts", k, k) * decay * ~jnp.eye(c, dtype=bool) * beta[..., None]
+    rhs = beta[..., None] * (v - mm("bhtk,bhkv->bhtv", k * gamma, s0))
+    u = lax.linalg.triangular_solve(
+        lower + jnp.eye(c, dtype=lower.dtype), rhs, left_side=True, lower=True, unit_diagonal=True
+    )
+    o = mm("bhtk,bhkv->bhtv", q * gamma, s0) + mm("bhts,bhsv->bhtv", mm("bhtk,bhsk->bhts", q, k) * decay, u)
+    to_end = jnp.exp(big_g[..., -1:] - big_g)[..., None]  # a token's decay up to the chunk's end
+    s1 = s0 * gamma[:, :, -1][..., None] + mm("bhsk,bhsv->bhkv", k * to_end, u)
+    return o, s1
+
+
 def kda_chunked(q, k, v, g, beta, state, chunk: int = CHUNK):
     """The chunked form: shapes as :func:`kda_recurrent`. ``T`` is padded up
     to whole chunks with tokens that leave the state alone."""
@@ -142,8 +172,10 @@ def kda_chunked(q, k, v, g, beta, state, chunk: int = CHUNK):
 
     xs = (lay(q), lay(k), lay(v), lay(g), lay(beta[..., None])[..., 0])
 
+    one_chunk = _chunk_scalar if g.shape[-1] == 1 and dk > 1 else _chunk
+
     def step(s, x):
-        o, s = _chunk(*x, s)
+        o, s = one_chunk(*x, s)
         return s, o
 
     state, o = lax.scan(step, state.astype(f32), xs)  # o [n, B, H, C, dv]

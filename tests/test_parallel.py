@@ -80,6 +80,8 @@ ASSIGNED = {
     # the hybrid block is served on one chip whatever is assigned (PR 30)
     "kimi-linear-48b": [(1, 1), (1, 1), (1, 1), (1, 1)],
     "tiny-kimi-linear": [(1, 1), (1, 1), (1, 1), (1, 1)],
+    "olmo-hybrid-7b": [(1, 1), (1, 1), (1, 1), (1, 1)],
+    "tiny-olmo-hybrid": [(1, 1), (1, 1), (1, 1), (1, 1)],
 }
 
 

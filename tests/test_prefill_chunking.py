@@ -61,6 +61,17 @@ def test_decode_interleaves_with_long_prefill():
         return orig_d(*a, **k)
 
     engine._prefill, engine._decode_n = spy_p, spy_d
+    if engine._prefill_with_decode is not None:
+        # since PR 31 a chunk may carry the lanes' decode step in its own
+        # launch: a prefill chunk and a decode step, in that order (which of
+        # B's chunks launch that way is a matter of timing)
+        orig_m = engine._prefill_with_decode
+
+        def spy_m(*a, **k):
+            calls.extend(["p", "d"])
+            return orig_m(*a, **k)
+
+        engine._prefill_with_decode = spy_m
 
     async def scenario():
         loop = asyncio.get_running_loop()

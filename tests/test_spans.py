@@ -525,3 +525,42 @@ def test_an_olmoe_engine_decodes_what_the_plain_scan_gives():
     prompt = jnp.asarray([eng.tokenizer.encode("hello there")], jnp.int32)
     want = greedy_decode(params, cfg, prompt, 12, 64, dtype=jnp.float32)[0]
     assert got["tokens"] == [int(t) for t in want]
+
+
+# -- a slot of K/V rows and a recurrent state (the hybrid block's second family) ----
+def test_an_olmo_hybrid_engine_names_its_kinds_its_leaves_and_what_a_snapshot_ships():
+    """Names the benchmark's readers and a reader of a capture rely on:
+    ``model_arch.layer_kinds`` with the kinds ``gdn`` and ``full``;
+    ``attention.{gdn,full}_{prefill,decode}`` naming the implementation each
+    call traces, with the reason; ``cache`` bytes by kind of leaf, ``k`` and
+    ``v`` beside ``state`` and ``conv``; and ``engine.snapshot`` /
+    ``engine.restore`` spans whose trace events carry ``bytes=`` by leaf."""
+    eng = LLMEngine.create("tiny-olmo-hybrid", options={"max_batch": 2, "max_seq": 128, "decode_chunk": 4, "prefill_chunk": 32})
+    seen = []
+    span = eng._spans.span
+    eng._spans.span = lambda name, **attrs: (seen.append((name, attrs)), span(name, **attrs))[1]
+    try:
+        async def drive():
+            await eng.chat("s", "a session that will be snapshotted", max_tokens=5)
+            eng.snapshot_min_gap_s = eng.snapshot_busy_gap_s = 0.0
+            blob = await eng.snapshot_session("s")
+            return await eng.restore_session("t", blob)
+
+        assert asyncio.run(drive()) is True
+        time.sleep(0.2)
+        m = eng.metrics()
+    finally:
+        eng.shutdown()
+    assert m["model_arch"]["layer_kinds"] == {"full": 2, "gdn": 6}
+    for key in ("gdn_prefill", "gdn_decode", "full_prefill", "full_decode", "reason"):
+        assert m["attention"][key], key
+    assert m["cache"]["kinds"] == ["k", "v", "state", "conv"]
+    assert all(m["cache"][leaf + "_bytes"] > 0 for leaf in m["cache"]["kinds"])
+    for name in ("engine.snapshot", "engine.restore", "engine.state_reset"):
+        assert m["phases"][name]["n"] >= 1, (name, sorted(m["phases"]))
+    carried = {name: attrs["bytes"] for name, attrs in seen if name in ("engine.snapshot", "engine.restore") and "bytes" in attrs}
+    assert set(carried) == {"engine.snapshot", "engine.restore"}
+    for text in carried.values():
+        sizes = dict(part.split("=") for part in text.split(","))
+        assert sorted(sizes) == ["conv", "k", "state", "v"] and all(int(v) > 0 for v in sizes.values())
+    assert int(dict(p.split("=") for p in carried["engine.restore"].split(","))["state"]) == 6 * 12 * 6 * 24 * 4

@@ -368,3 +368,73 @@ def test_hybrid_step_holds_no_stack_sized_temporary_on_v5e(v5e, step):
     for name, s in stacks.items():  # and no copy or relayout of a whole stack anywhere in the program
         shape = ",".join(map(str, s.shape))
         assert not re.search(rf"\[{shape}\][^ ]* (copy|transpose)\(", text), name
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_olmo_hybrid_step_copies_no_stack_and_pads_no_arena_on_v5e(v5e, step):
+    """Olmo-Hybrid's block at published widths (one period, L L L F, 8 lanes
+    of 4096 as served), int8, its kernels on. The GDN decode kernel updates
+    the layer of the float32 state stack in place — Mosaic accepts the ``[96,
+    1920]`` block of a ``[96, 5760]`` lane tile, whole (8, 128) tiles where
+    ``[30, 96, 192]`` would pad every head's 192 lanes to 256 — and the dense
+    flash kernels read the K/V leaf where it lies, STORED with 32 heads for
+    the model's 30: with 30 the chip keeps the arena position-minor and copies
+    it whole into the kernels' layout before each call (0.54 GB of temporaries
+    in this one-full-layer program, found by this compile). Here no stack is
+    copied, transposed or padded, every leaf is donated in place, and the
+    temporaries are a few megabytes."""
+    import dataclasses
+
+    from jax import lax
+
+    from agentainer_tpu.engine.quant import synthetic_quantized_params
+    from agentainer_tpu.models import hybrid
+    from agentainer_tpu.models.configs import olmo_hybrid_kinds
+    from agentainer_tpu.models.llama import init_cache
+
+    lanes, seq = 8, 4096
+    cfg = dataclasses.replace(
+        get_config("olmo-hybrid-7b"), n_layers=4, layer_kinds=olmo_hybrid_kinds(4), n_dense_layers=4,
+        vocab_size=8192, name="olmo-hybrid-4l",
+    )
+    where = SingleDeviceSharding(v5e.devices[0])
+    place = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=where)  # noqa: E731
+    params = jax.tree.map(place, jax.eval_shape(lambda: synthetic_quantized_params(cfg, jnp.bfloat16)))
+    cache = jax.tree.map(place, jax.eval_shape(lambda: init_cache(cfg, lanes, seq, jnp.bfloat16, live=False)))
+    plan = hybrid.plan_hybrid(cfg, use_pallas=True)
+    assert (plan.gdn_decode, plan.full_decode) == ("pallas_gdn_decode", "pallas:flash_decode")
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=where)  # noqa: E731
+
+    def decode_n(params, cache, tokens, positions):
+        def one(carry, _):
+            tok, pos, cache = carry
+            logits, cache = forward(params, cfg, tok[:, None], pos[:, None], cache, cache_attn_impl=plan)
+            return (jnp.argmax(logits[:, 0], -1).astype(jnp.int32), jnp.minimum(pos + 1, seq - 1), cache), tok
+
+        (tok, pos, cache), toks = lax.scan(one, (tokens, positions, cache), None, length=4)
+        return toks, tok, pos, cache
+
+    def prefill(params, cache, slot, tokens, positions, n_real):
+        valid = jnp.arange(tokens.shape[1])[None, :] < n_real
+        logits, cache = forward(params, cfg, tokens, positions, cache, cache_attn_impl=plan, slot=slot, valid=valid)
+        return logits[0, -1], cache
+
+    if step == "decode":
+        compiled = jax.jit(decode_n, donate_argnums=(1, 2, 3)).lower(params, cache, i32(lanes), i32(lanes)).compile()
+    else:
+        compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
+            params, cache, i32(), i32(1, 256), i32(1, 256), i32()).compile()
+    text = compiled.as_text()
+    if step == "decode":
+        assert "gdn_decode" in text and text.count("tpu_custom_call") == 2  # the state kernel and flash_decode
+    else:
+        assert text.count("tpu_custom_call") == 1  # flash_prefill; the chunked delta rule is XLA
+    stacks = cache.leaves()
+    assert cache.k.shape[3] == 32 and set(stacks) == {"k", "v", "state", "conv"}
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(s.size * s.dtype.itemsize for s in stacks.values())  # every leaf donated in place
+    for name, s in stacks.items():  # and no copy, relayout or padding of a whole stack anywhere in the program
+        shape = ",".join(map(str, s.shape))
+        assert not re.search(rf"\[{shape}\][^ ]* (copy|transpose|pad)\(", text), name
+    arena_layer = 2 * math.prod(cache.k.shape[1:])  # one layer of the bf16 K stack: 268 MB
+    assert mem.temp_size_in_bytes < arena_layer // 4, mem.temp_size_in_bytes

@@ -1345,6 +1345,18 @@ class LLMEngine:
                 use_pallas=False,
             )
         self.attention = attn.describe()
+        # what ``flash_decode``'s index map lets through, counted on the host
+        # at each decode launch (``_count_decode_blocks``), cumulative: blocks
+        # of ``decode_block_positions`` positions the lanes' positions reach,
+        # and blocks the arena rows hold; their ratio is the share of the
+        # K/V arena a decode step fetches. 0 where no dense K/V arena serves
+        from ..ops.pallas_attention import decode_kv_block
+
+        k = None if self.paged else getattr(self.cache, "k", None)
+        self._decode_bk = decode_kv_block(k.shape[3], k.shape[4], k.dtype, k.shape[2])[1] if k is not None else 0
+        self.attention.update(
+            decode_block_positions=self._decode_bk, decode_blocks_live=0, decode_blocks_stored=0
+        )
         self.meshed_flash = (not self._recurrent) and "shard_map" in attn.decode
         if self._recurrent:
             print(
@@ -3562,7 +3574,7 @@ class LLMEngine:
                 "count": jax.device_count(),
             },
             "engine_devices": [self._device_doc(d) for d in self._devices],
-            "attention": self.attention,
+            "attention": dict(self.attention),
             # which MoE path the compiled steps trace, and the block's shape
             "moe": dict(self.moe),
             "model_arch": {
@@ -4660,6 +4672,21 @@ class LLMEngine:
         elif not self.routed_moe:
             self.moe["rows_all_experts"] += passes * rows * self.cfg.n_held
 
+    def _count_decode_blocks(self, positions: list[int], steps: int) -> None:
+        """A decode launch of ``steps`` steps whose stepping lanes start at
+        ``positions``: the K/V blocks ``flash_decode`` fetches (a lane at
+        position p, below the arena's last row, reads ``p // bk + 1``; every
+        other lane is parked and reads one) against the blocks the arena
+        rows hold, a head block and a layer counted once. From what the
+        worker knows at dispatch, never from the device."""
+        bk = self._decode_bk
+        if not bk:
+            return
+        pos = np.asarray(positions, np.int64).reshape(-1, 1) + np.arange(steps)
+        live = np.where(pos >= self.max_seq - 1, 0, pos) // bk + 1
+        self.attention["decode_blocks_live"] += int(live.sum()) + steps * (self.max_batch - len(positions))
+        self.attention["decode_blocks_stored"] += steps * self.max_batch * -(-self.max_seq // bk)
+
     def _bucket(self, n: int) -> int:
         for b in PREFILL_BUCKETS:
             if n <= b:
@@ -5057,6 +5084,7 @@ class LLMEngine:
             r.dispatched += chunk
         self.decode_steps += 1
         self._occupancy_sum += len(snapshot) / self.max_batch
+        self._count_decode_blocks([p for _, _, p in snapshot], chunk)
         if rode:
             self.mixed_launches += 1
             self.mixed_decode_lanes += len(snapshot)
@@ -5196,6 +5224,7 @@ class LLMEngine:
         self.decode_steps += 1
         # the loop's cap: it may stop early, and an in-loop verify is wider
         self._count_forward(self.max_batch, chunk)
+        self._count_decode_blocks([p for _, _, p, _ in snapshot], chunk)
         self._occupancy_sum += len(snapshot) / self.max_batch
         try:
             packed.copy_to_host_async()

@@ -207,19 +207,31 @@ def _moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig, logits: jnp.ndarray | N
 
 
 def _moe_mlp_sorted(
-    x: jnp.ndarray, lp: dict, cfg: ModelConfig, experts: dict, layer, logits: jnp.ndarray | None = None
+    x: jnp.ndarray,
+    lp: dict,
+    cfg: ModelConfig,
+    experts: dict,
+    layer,
+    logits: jnp.ndarray | None = None,
+    routed: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
     """The MoE FFN of a call with many rows (a prefill chunk): the same
     router and the same sum as ``_moe_mlp``, computing only the (token,
     chosen expert) pairs — sorted by expert, one grouped FFN over the
     stacked ``experts`` (``ops/moe.stacked_experts``: int8 as stored, layer
     ``layer`` of them read in place). Exact top-k and dropless at any skew;
-    a bucket's padding rows are routed like any other row."""
+    a bucket's padding rows are routed like any other row. ``routed [B·T]``
+    bool: rows whose choices count; the others choose no expert, take no
+    buffer row and get 0 (the mixed step's parked lanes: a row that is
+    nobody's, alone at an expert, would stream that expert for one tile)."""
     b, t, d = x.shape
     xf = x.reshape(b * t, d)
     logits = xf @ lp["router"] if logits is None else logits.reshape(b * t, -1)
     gates, chosen = moe_gates(logits, cfg, x.dtype, lp.get("router_bias"))
-    held = (cfg.expert_offset, cfg.n_experts) if cfg.experts_held else None
+    if routed is not None:
+        # expert -1 is nobody's: dropped before the sort, like an absent one
+        chosen = jnp.where(routed[:, None], chosen, -1)
+    held = (cfg.expert_offset, cfg.n_experts) if cfg.experts_held or routed is not None else None
     return sorted_moe_ffn(xf, gates, chosen, experts, layer, held=held).reshape(b, t, d)
 
 
@@ -332,6 +344,21 @@ def _moe_mlp_routed(
     return out.reshape(b, t, d)
 
 
+def _parked(positions: jnp.ndarray, arena_len: int) -> jnp.ndarray:
+    """One-token rows of the dense arena that sit at its last row: where the
+    engine parks idle, finished and still-prefilling lanes (its ``scratch``;
+    admission keeps real lanes below it). Their output is nobody's."""
+    return positions >= arena_len - 1
+
+
+def _seen(positions: jnp.ndarray, arena_len: int) -> jnp.ndarray:
+    """What a one-token row of the dense arena attends up to: its own
+    position, or row 0 alone where it is parked, so such a lane reads one K/V
+    block and not all S positions. Its rows are still WRITTEN at the true
+    position: only the read's bound changes."""
+    return jnp.where(_parked(positions, arena_len), 0, positions)
+
+
 def _attention_block(
     x: jnp.ndarray,
     lp: dict,
@@ -399,7 +426,7 @@ def _attention_block(
         ck = ck.at[layer, slot, chunk_positions].set(kc).at[layer, lanes, lane_positions].set(kl)
         cv = cv.at[layer, slot, chunk_positions].set(vc).at[layer, lanes, lane_positions].set(vl)
         attn_c = cache_attn_impl(qc, ck, cv, chunk_positions, None, layer, slot)
-        attn_l = cache_attn_impl(ql, ck, cv, lane_positions, None, layer, None)
+        attn_l = cache_attn_impl(ql, ck, cv, _seen(lane_positions, ck.shape[2]), None, layer, None)
         attn = jnp.concatenate([attn_c, attn_l.reshape(1, n_lanes, cfg.n_heads, cfg.head_dim)], axis=1)
     elif ck is not None:
         if layer is None:
@@ -414,6 +441,8 @@ def _attention_block(
             rows = jnp.arange(b)[:, None] + (0 if slot is None else slot)
             ck = ck.at[layer, rows, positions].set(k)
             cv = cv.at[layer, rows, positions].set(v)
+            if t == 1:
+                positions = _seen(positions, ck.shape[2])
         attn = cache_attn_impl(q, ck, cv, positions, block_table, layer, slot)
     elif use_flash:
         attn = flash_attention(q, k, v, causal=True)
@@ -490,10 +519,15 @@ def forward(
         lane_tokens, lane_positions = lanes
         x = jnp.concatenate([x, embed_lookup(params["embed"], lane_tokens[:, 0])[None]], axis=1)
     lp_stack = params["layers"]
-    experts = None
+    experts = routed = None
     if moe_impl is None and moe_sorts(cfg, lp_stack, x.shape[0] * x.shape[1]):
         experts = stacked_experts(lp_stack)
         lp_stack = {k: v for k, v in lp_stack.items() if k not in EXPERT_WEIGHTS}
+        if lanes is not None:
+            # the chunk's rows, and the lanes that step: a parked lane's row
+            # is routed nowhere (``_moe_mlp_sorted``)
+            live = ~_parked(lane_positions[:, 0], cache.k.shape[2])
+            routed = jnp.concatenate([jnp.ones(tokens.shape[1], bool), live])
     if cache is not None:
         mask = None  # arena attention masks from positions (in-kernel on TPU)
     else:
@@ -515,7 +549,7 @@ def forward(
         )
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
         if experts is not None:
-            x = x + _moe_mlp_sorted(h, lp, cfg, experts, layer)
+            x = x + _moe_mlp_sorted(h, lp, cfg, experts, layer, routed=routed)
         elif cfg.is_moe:
             x = x + (moe_impl(h, lp) if moe_impl is not None else _moe_mlp(h, lp, cfg))
         else:

@@ -174,9 +174,10 @@ def sorted_moe_ffn(
     only those pairs. ``kernel``: the Pallas grouped FFN (default: on a TPU
     backend). ``held = (offset, n_total)``: ``experts`` is the chip's share
     ``[offset, offset + E)`` of ``n_total`` experts and ``chosen`` indexes
-    all of them; assignments to absent experts are dropped before the sort
-    (they sort last, get no buffer row and add nothing), and the row tile
-    follows the share of a token's choices that lands here.
+    all of them; assignments to absent experts (a negative choice, a row
+    routed nowhere, among them) are dropped before the sort (they sort last,
+    get no buffer row and add nothing), and the row tile follows the share
+    of a token's choices that lands here.
 
     Rows go to the buffer and come back through one 0/1 matrix ``[M, N]``
     (row r holds token n) on the MXU: exact — a row is one token's values,

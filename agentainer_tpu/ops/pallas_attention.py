@@ -28,7 +28,10 @@ Design notes (why this shape, not a torch translation):
   way GQA intends.
 - **Causal block skipping.** KV blocks entirely in the future of every
   query row in the tile (``k_start > max(pos)``) skip their matmuls via
-  ``pl.when`` predication — ~2x prefill FLOP cut at long context.
+  ``pl.when`` predication — ~2x prefill FLOP cut at long context. The dense
+  kernels skip their DMA too: the K/V index maps name no block past the
+  one that holds the rows' last position (``kv_block_index``), so a decode
+  lane reads the blocks it has written and not the arena row.
 
 CPU CI runs the same kernels under ``interpret=True`` (tests/), matching
 ops/attention.py's reference implementation bit-for-bit in f32.
@@ -95,9 +98,31 @@ def _kv_block(kv: int, hd: int, dtype, s: int, block_k: int, vmem: int) -> tuple
     return heads, min(block_k, _round_up(s, 128), fit)
 
 
+def decode_kv_block(kv: int, hd: int, dtype, s: int, block_k: int = 512) -> tuple[int, int]:
+    """``flash_decode``'s K/V block over an arena of ``s`` positions:
+    ``(heads, positions)`` (the engine counts fetched blocks with it)."""
+    return _kv_block(kv, hd, dtype, s, block_k, _DECODE_KV_VMEM)
+
+
 def _scalar(x) -> jnp.ndarray:
     """A prefetched scalar operand: int32 ``[1]``."""
     return jnp.asarray(x, jnp.int32).reshape(1)
+
+
+def kv_block_index(ik, n_steps, last_pos, block_k: int):
+    """The position block that step ``ik`` of the ``n_steps`` a grid gives a
+    K/V row holds, for query rows that see the arena up to ``last_pos``; the
+    steps before the first block read negative. A K/V index map never names a
+    block past the last position its rows can attend to: the ``live =
+    last_pos // block_k + 1`` blocks are the row's LAST steps, the steps
+    before them hold block 0 and do nothing, and a step whose block index did
+    not change fetches nothing, so a lane costs the DMA of what it has
+    written and not of the arena row. Idle steps first, not last: the
+    pipeline fetches one step ahead, so a row's first block is then fetched
+    under the previous row's last matmuls; behind idle steps it would wait
+    for its DMA with nothing to hide it (measured: section 6 of PERF.md, PR
+    33). A position past the arena's end sees every block, as it always did."""
+    return ik - (n_steps - jnp.minimum(last_pos // block_k + 1, n_steps))
 
 
 def _head(ref, h: int) -> jnp.ndarray:
@@ -126,6 +151,7 @@ def _head(ref, h: int) -> jnp.ndarray:
 def _prefill_kernel(
     layer_ref,  # [1] int32 (SMEM, scalar prefetch; used by the index maps)
     slot_ref,  # [1] int32 (SMEM, scalar prefetch; used by the index maps)
+    last_ref,  # [B] int32 (SMEM, scalar prefetch) each sequence's last position
     pos_ref,  # [G, bq, 1] int32           (VMEM) the q tile's positions, per group
     q_ref,  # [heads, G, bq, hd]          (VMEM) heads: this block of KV heads
     k_ref,  # [1, 1, bk, heads, hd]       (VMEM)
@@ -151,12 +177,14 @@ def _prefill_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     pos = pos_ref[...].reshape(rows, 1)  # row g * bq + i is query i of group g
-    k_start = ik * block_k
+    blk = kv_block_index(ik, nk, last_ref[pl.program_id(0)], block_k)
+    k_start = blk * block_k
     col = k_start + lax.broadcasted_iota(jnp.int32, (rows, block_k), 1)
     mask = (col <= pos) & (col < seq_len_k)  # [G * bq, bk]
 
-    # skip KV blocks strictly in the future of every row in this q tile
-    @pl.when(k_start <= jnp.max(pos))
+    # skip the steps before the sequence's first block, and KV blocks
+    # strictly in the future of every row in this q tile
+    @pl.when((blk >= 0) & (k_start <= jnp.max(pos)))
     def _compute():
         # rows past the arena end are padded garbage (can be NaN): zero them,
         # since 0 * NaN from the masked-out probabilities would poison acc
@@ -224,23 +252,30 @@ def flash_prefill(
     # positions once per group, so a q tile's [G, bq] rows carry their own
     # ([…, 1]: the (sublane, lane) dims stay TPU-block-legal)
     pos = jnp.broadcast_to(q_positions.astype(jnp.int32)[:, None, :, None], (b, g, t, 1))
+    # the last position any row of a sequence sees: the K/V index map stops
+    # there (a bucket's padding rows carry positions that run on past the real
+    # tokens and may pass the arena's end, where ``kv_block_index`` stops)
+    last = q_positions.astype(jnp.int32).max(axis=1)
 
     kernel = functools.partial(
         _prefill_kernel, block_k=bk, seq_len_k=s, scale=1.0 / (hd**0.5)
     )
     q_spec = pl.BlockSpec(
-        (None, heads, g, bq, hd), lambda ib, ih, iq, ik, lay, slt: (ib, ih, 0, iq, 0)
+        (None, heads, g, bq, hd), lambda ib, ih, iq, ik, lay, slt, last: (ib, ih, 0, iq, 0)
     )
-    kv_spec = pl.BlockSpec(
-        (1, 1, bk, heads, hd),
-        lambda ib, ih, iq, ik, lay, slt: (lay[0], slt[0] + ib, ik, ih, 0),
-    )
+
+    n_blocks = pl.cdiv(s, bk)
+
+    def kv_map(ib, ih, iq, ik, lay, slt, last):
+        return lay[0], slt[0] + ib, jnp.maximum(kv_block_index(ik, n_blocks, last[ib], bk), 0), ih, 0
+
+    kv_spec = pl.BlockSpec((1, 1, bk, heads, hd), kv_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # layer, slot
-        grid=(b, kv // heads, pl.cdiv(t, bq), pl.cdiv(s, bk)),
+        num_scalar_prefetch=3,  # layer, slot, each sequence's last position
+        grid=(b, kv // heads, pl.cdiv(t, bq), n_blocks),
         in_specs=[
             pl.BlockSpec(
-                (None, g, bq, 1), lambda ib, ih, iq, ik, lay, slt: (ib, 0, iq, 0)
+                (None, g, bq, 1), lambda ib, ih, iq, ik, lay, slt, last: (ib, 0, iq, 0)
             ),
             q_spec,
             kv_spec,
@@ -258,7 +293,7 @@ def flash_prefill(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
         interpret=interpret,
-    )(_scalar(layer), _scalar(slot), pos, qh, k, v)
+    )(_scalar(layer), _scalar(slot), last, pos, qh, k, v)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, hd)
 
 
@@ -289,9 +324,10 @@ def _decode_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     pos = pos_ref[pl.program_id(0)]
-    k_start = ik * block_k
+    blk = kv_block_index(ik, nk, pos, block_k)
+    k_start = blk * block_k
 
-    @pl.when(k_start <= pos)
+    @pl.when(blk >= 0)  # the steps before the lane's first block do nothing
     def _compute():
         col = k_start + lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
         mask = (col <= pos) & (col < seq_len_k)  # [1, bk]
@@ -334,11 +370,12 @@ def flash_decode(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Single-token attention over the KV arena, fused softmax — no [B,H,S]
-    score tensor ever reaches HBM (the decode path is HBM-bandwidth-bound)."""
+    score tensor ever reaches HBM (the decode path is HBM-bandwidth-bound),
+    and a lane fetches the ``positions // block + 1`` blocks it can see."""
     b, h, hd = q.shape
     s, kv = k.shape[2], k.shape[3]
     g = h // kv
-    heads, bk = _kv_block(kv, hd, k.dtype, s, block_k, _DECODE_KV_VMEM)
+    heads, bk = decode_kv_block(kv, hd, k.dtype, s, block_k)
 
     qh = q.reshape(b, kv, g, hd)
 
@@ -348,13 +385,16 @@ def flash_decode(
     q_spec = pl.BlockSpec(
         (None, heads, g, hd), lambda ib, ih, ik, lay, slt, pos: (ib, ih, 0, 0)
     )
-    kv_spec = pl.BlockSpec(
-        (1, 1, bk, heads, hd),
-        lambda ib, ih, ik, lay, slt, pos: (lay[0], slt[0] + ib, ik, ih, 0),
-    )
+
+    n_blocks = pl.cdiv(s, bk)
+
+    def kv_map(ib, ih, ik, lay, slt, pos):
+        return lay[0], slt[0] + ib, jnp.maximum(kv_block_index(ik, n_blocks, pos[ib], bk), 0), ih, 0
+
+    kv_spec = pl.BlockSpec((1, 1, bk, heads, hd), kv_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,  # layer, slot, positions
-        grid=(b, kv // heads, pl.cdiv(s, bk)),
+        grid=(b, kv // heads, n_blocks),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=q_spec,
         scratch_shapes=[

@@ -380,7 +380,11 @@ def test_steps_under_the_cut_lower_to_the_parents_programs(moe_engine, key):
     the cut lower to the StableHLO the parent commit (4711f7f, PR 28) lowered
     on this backend, byte for byte: sha256 of the location-free text, taken
     there with this file's own lowering calls. A later PR that changes a
-    step program on purpose regenerates the file and says why."""
+    step program on purpose regenerates the file and says why: PR 33 did for
+    the four ``jit_decode_n`` entries, whose one-token rows now see a lane at
+    the arena's last row at row 0 (``models/llama._seen``: one select a layer
+    on the positions handed to the attention; the scatter is the parent's);
+    ``jit_verify`` and every ``jit_prefill`` bucket are still PR 28's."""
     model, weights, program = key.split(".", 2)
     text = _moe_step_lowering(moe_engine(model, weights), program).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == PARENT_PROGRAMS[key]

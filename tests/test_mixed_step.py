@@ -47,7 +47,11 @@ def test_a_mixed_launch_is_the_chunk_then_the_one_step_decode(model, weights):
     einsum (the matmuls' shapes differ, so a few sums round differently:
     2e-7). ``int8``: 132 rows are over the 121-row cut, so the launch's lanes
     go through the sorted grouped FFN where the decode step runs the einsum:
-    the same sum in another order."""
+    the same sum in another order. The lane parked at the arena's last row is
+    left out of the comparison where the FFN sorts: its row is routed to no
+    expert there (ISSUE 33: alone at an expert it would stream that expert
+    for one tile), so its token and the scratch row it rewrites are its own
+    — nobody reads either."""
     quant = {"quant": "int8"} if weights == "int8" else {}
     eng = LLMEngine.create(model, options={**OPTS, "prefill_chunk": 128, **quant})
     try:
@@ -79,12 +83,20 @@ def test_a_mixed_launch_is_the_chunk_then_the_one_step_decode(model, weights):
         )
         assert toks_m.shape == toks_a.shape == (1, B)
         atol = 2e-5
+        stepping = sorted(context)  # every lane but the parked one
+
+        def rows(arena):  # all but the parked lane's scratch row
+            return np.asarray(arena.at[:, lane, S - 1].set(0))
+
         np.testing.assert_allclose(np.asarray(last_m), np.asarray(last_a), atol=atol, rtol=0)
-        np.testing.assert_allclose(np.asarray(cache_m.k), np.asarray(cache_a.k), atol=atol, rtol=0)
-        np.testing.assert_allclose(np.asarray(cache_m.v), np.asarray(cache_a.v), atol=atol, rtol=0)
-        assert np.asarray(toks_m).tolist() == np.asarray(toks_a).tolist()
-        assert np.asarray(tok_m).tolist() == np.asarray(tok_a).tolist()
+        np.testing.assert_allclose(rows(cache_m.k), rows(cache_a.k), atol=atol, rtol=0)
+        np.testing.assert_allclose(rows(cache_m.v), rows(cache_a.v), atol=atol, rtol=0)
+        assert np.asarray(toks_m)[:, stepping].tolist() == np.asarray(toks_a)[:, stepping].tolist()
+        assert np.asarray(tok_m)[stepping].tolist() == np.asarray(tok_a)[stepping].tolist()
         assert np.asarray(pos_m).tolist() == np.asarray(pos_a).tolist()
+        assert np.isfinite(np.asarray(cache_m.k)).all() and np.isfinite(np.asarray(cache_m.v)).all()
+        if eng.cfg.is_moe and weights == "int8":  # the parked row went through no expert
+            assert not np.allclose(np.asarray(cache_m.k[1:, lane, S - 1]), np.asarray(cache_a.k[1:, lane, S - 1]), atol=atol)
         # the chunk's rows were written at lane 2 and the lanes' at their own positions
         assert float(jnp.abs(cache_m.k[:, lane, 40:40 + T]).max()) > 0
         assert float(jnp.abs(cache_m.k[:, 0, context[0]]).max()) > 0

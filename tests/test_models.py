@@ -100,6 +100,48 @@ def test_ragged_positions_in_one_batch(tiny):
     np.testing.assert_allclose(step[1, 0], lb[0, 2], rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("group", ["decode", "lanes"])
+def test_a_lane_parked_at_the_arenas_last_row_reads_one_row_and_still_writes_there(tiny, group):
+    """The engine parks idle lanes at the arena's last row. Such a lane's
+    one-token row is handed to the attention at position 0 (one K/V block for
+    the kernel to fetch, not the arena row), in the T == 1 step and in the
+    lanes' group of the mixed step alike; its K/V rows are still written at
+    the last row, and a lane one row short of it attends as before."""
+    from agentainer_tpu.ops.attention import _reference_dense
+
+    cfg, params = tiny
+    lanes, s = 3, 64
+    cache = KVCache.create(cfg, lanes, s, dtype=jnp.float32)
+    tok = jnp.array([[3], [4], [5]], jnp.int32)
+    pos = jnp.array([[10], [s - 1], [s - 2]], jnp.int32)
+    seen = jnp.array([[10], [0], [s - 2]], jnp.int32)
+
+    def told(q, ck, cv, positions, *rest):
+        # the one-token rows read up to ``seen`` whatever they were handed
+        return _reference_dense(q, ck, cv, seen if q.shape[1] == 1 else positions, *rest)
+
+    if group == "decode":
+        call = lambda **kw: forward(params, cfg, tok, pos, cache, **kw)  # noqa: E731
+    else:
+        chunk = jnp.arange(1, 9, dtype=jnp.int32)[None]
+        call = lambda **kw: forward(  # noqa: E731
+            params, cfg, chunk, chunk - 1, cache, slot=jnp.int32(1), lanes=(tok, pos), last=jnp.int32(7), **kw
+        )
+    logits, new = call()
+    want, _ = call(cache_attn_impl=told)
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(want))
+    # and not what reading the whole row gives: the parked lane's logits moved
+    whole, _ = call(cache_attn_impl=lambda q, ck, cv, positions, *rest: _reference_dense(
+        q, ck, cv, pos if q.shape[1] == 1 else positions, *rest))
+    rows = slice(0, 3) if group == "decode" else slice(1, 4)
+    moved = np.abs(np.asarray(logits)[rows] - np.asarray(whole)[rows]).reshape(3, -1).max(-1)
+    assert moved[0] == 0 and moved[2] == 0 and moved[1] > 0
+    assert np.isfinite(np.asarray(logits)).all()
+    written = np.asarray(new.k)
+    assert written[:, 1, s - 1].any() and written[:, 0, 10].any() and written[:, 2, s - 2].any()
+    assert not written[:, 1, 0].any() or group == "lanes"  # row 0 is the chunk's in the mixed step
+
+
 def test_gqa_against_naive_numpy():
     """attention_reference (grouped einsum) vs a naive per-head numpy loop."""
     rng = np.random.default_rng(0)

@@ -22,6 +22,7 @@ from agentainer_tpu.models.configs import get_config
 from agentainer_tpu.models.llama import (
     _moe_mlp,
     _moe_mlp_routed,
+    _moe_mlp_sorted,
     forward,
     init_params,
     moe_gates,
@@ -135,6 +136,41 @@ def test_route_places_every_assignment_once_in_its_experts_tiles(n, n_experts, k
         used = np.bincount(tile_expert[:n_active], minlength=n_experts)
         counts = np.bincount(chosen.reshape(-1), minlength=n_experts)
         assert (used == -(-counts // tile)).all()
+
+
+@pytest.mark.parametrize("model", ["tiny-moe", "tiny-olmoe"])
+def test_rows_routed_nowhere_take_no_tile_and_change_no_other_row(model):
+    """The mixed step's parked lanes (ISSUE 33): ``routed`` False rows choose
+    no expert, so they get 0, take no buffer row and open no tile, and every
+    other row's sum is the einsum's. Here the last three of 132 rows are the
+    only ones that would choose the last expert (the router's column for it
+    is 0 for every row, and theirs alone are negative everywhere else):
+    routed like any row they cost that expert's whole stream for one tile."""
+    cfg = get_config(model)
+    layers = _layers(cfg, "float32", scale=8.0)
+    e, k, n, parked = cfg.n_experts, cfg.experts_per_token, 132, 3
+    layers["router"] = layers["router"].at[:, :, e - 1].set(0.0)
+    x = jax.random.normal(jax.random.PRNGKey(11), (1, n, cfg.dim), jnp.float32)
+    lp = _layer(layers, 1)
+    logits = x[0] @ lp["router"]
+    # the real rows like some other experts better than 0; the parked rows like nothing better
+    logits = logits.at[: n - parked, :k].add(50.0).at[n - parked :, : e - 1].add(-50.0)
+    _, chosen = moe_gates(logits, cfg, x.dtype)
+    chosen = np.asarray(chosen)
+    assert (chosen[: n - parked] != e - 1).all() and (chosen[n - parked :] == e - 1).any(axis=1).all()
+    routed = jnp.arange(n) < n - parked
+    experts = stacked_experts(layers)
+    want = _moe_mlp(x, lp, cfg, logits=logits[None])
+    got = _moe_mlp_sorted(x, lp, cfg, experts, jnp.int32(1), logits=logits[None], routed=routed)
+    scale = float(jnp.max(jnp.abs(want)))
+    np.testing.assert_allclose(np.asarray(got[0, : n - parked]), np.asarray(want[0, : n - parked]), atol=2e-6 * scale)
+    assert not np.asarray(got[0, n - parked :]).any()
+    # the layout: what sorted_moe_ffn hands ``route`` with and without the rule
+    tile, m = row_tile(n, e, k), sorted_rows(n, e, k)
+    all_rows = route(jnp.asarray(chosen), e, tile, m)
+    live_rows = route(jnp.where(routed[:, None], jnp.asarray(chosen), e), e, tile, m)
+    assert int(all_rows.n_active) > int(live_rows.n_active)  # a tile for each expert only they chose
+    assert e - 1 in np.asarray(all_rows.tile_expert) and e - 1 not in np.asarray(live_rows.tile_expert)
 
 
 @pytest.mark.parametrize("n_experts, k, bucket, lanes, tile", [

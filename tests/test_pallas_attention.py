@@ -326,3 +326,133 @@ def test_decode_bf16():
     np.testing.assert_allclose(
         got.astype(np.float32), want.astype(np.float32), rtol=3e-2, atol=3e-2
     )
+
+
+# ---------------------------------------------------------------------------
+# the dense kernels fetch only what their rows can see (ISSUE 33): the K/V
+# index maps name no block past the one that holds the rows' last position
+# (the live blocks are a row's last grid steps, the steps before them hold
+# block 0 and do nothing), and a step whose block index did not change issues
+# no DMA. What a live row computes is the parent's arithmetic, bit for bit:
+# the parent's map (step ik reads block ik) stays reachable here alone, by
+# swapping the one function the specs and the bodies are built with.
+
+
+def _unclamped(monkeypatch, kernel):
+    """``kernel`` as the parent built it: every grid step names its own block."""
+    from agentainer_tpu.ops import pallas_attention
+
+    def parent(*args, **kw):
+        with monkeypatch.context() as m:
+            m.setattr(pallas_attention, "kv_block_index", lambda ik, n_steps, last_pos, block_k: ik)
+            return kernel.__wrapped__(*args, **kw)  # untraced: no cached program
+
+    return parent
+
+
+@pytest.mark.parametrize("block_k,arena", [(128, 512), (256, 1024), (128, 384)])
+def test_kv_block_index_never_names_a_block_past_the_position(block_k, arena):
+    from agentainer_tpu.ops.pallas_attention import kv_block_index
+
+    n = -(-arena // block_k)
+    ik, pos = np.meshgrid(np.arange(n), np.arange(arena + block_k), indexing="ij")
+    got = np.asarray(kv_block_index(jnp.asarray(ik), n, jnp.asarray(pos), block_k))
+    live = np.minimum(pos // block_k + 1, n)
+    np.testing.assert_array_equal(got, ik - (n - live))
+    fetched = np.maximum(got, 0)  # what the index maps name
+    assert (fetched <= pos // block_k).all() and (fetched < n).all()
+    for p in (0, block_k - 1, block_k, arena - 2, arena - 1, arena + 5):
+        # a row's steps: idle ones first (block 0 held, nothing computed), then
+        # every block it can see once, in order, ending on its last step
+        steps = got[:, p]
+        assert (steps[steps < 0].size + live[0, p]) == n
+        np.testing.assert_array_equal(steps[steps >= 0], np.arange(live[0, p]))
+    # a lane one short of the arena's last row fetches what it fetched before
+    np.testing.assert_array_equal(got[:, arena - 2], np.arange(n))
+    # a lane at position 0 holds block 0 through every step: one fetch
+    assert not fetched[:, 0].any()
+
+
+@pytest.mark.parametrize("slot", [0, 2], ids=["slot0", "slot2"])
+@pytest.mark.parametrize(
+    "kv_heads,groups,q_heads",
+    [(8, 4, 32), (16, 1, 16), (32, 1, 30)],
+    ids=["gqa8x4", "mha16", "mha32-stored-for-30"],
+)
+def test_decode_fetches_live_blocks_and_computes_what_the_parent_did(monkeypatch, kv_heads, groups, q_heads, slot):
+    """Lanes at 0, bk − 1, bk, 2·bk + 3 and S − 2 in one batch, reading arena
+    rows ``slot ..`` of layer 1: the reference's numbers, and the unclamped
+    kernel's bits."""
+    from agentainer_tpu.ops.attention import _reference_dense, pallas_dense
+
+    bk, s, hd, n_layers = 128, 512, 128, 2
+    positions = jnp.array([0, bk - 1, bk, 2 * bk + 3, s - 2], jnp.int32)
+    lanes = positions.shape[0]
+    keys = jax.random.split(jax.random.PRNGKey(33), 3)
+    ck = _rand(keys[0], n_layers, lanes + slot, s, kv_heads, hd).astype(jnp.bfloat16)
+    cv = _rand(keys[1], n_layers, lanes + slot, s, kv_heads, hd).astype(jnp.bfloat16)
+    q = _rand(keys[2], lanes, q_heads, hd).astype(jnp.bfloat16)
+    # a K/V leaf stored with more heads than the model has: zero query heads
+    q = jnp.pad(q, [(0, 0), (0, kv_heads * groups - q_heads), (0, 0)])
+    lay, slt = jnp.int32(1), jnp.int32(slot)
+
+    got = flash_decode(q, ck, cv, positions, lay, slt, block_k=bk, interpret=True)
+    parent = _unclamped(monkeypatch, flash_decode)(q, ck, cv, positions, lay, slt, block_k=bk, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(parent))
+    want = _reference_dense(q[:, None], ck, cv, positions[:, None], None, lay, slt)[:, 0]
+    np.testing.assert_allclose(got.astype(np.float32), want.astype(np.float32), rtol=3e-2, atol=3e-2)
+    # the default block (sized by the VMEM plan) through the dispatch the steps trace
+    planned = pallas_dense(q[:, None], ck, cv, positions[:, None], None, lay, slt, interpret=True)[:, 0]
+    np.testing.assert_allclose(planned.astype(np.float32), want.astype(np.float32), rtol=3e-2, atol=3e-2)
+
+
+def test_a_parked_lane_reads_one_row_and_leaves_the_live_lanes_alone(monkeypatch):
+    """The engine parks a lane at the arena's last row; the model runner hands
+    the kernel position 0 for it (``models/llama._seen``). Its output is
+    finite and nobody's; the lanes beside it read what they read before."""
+    from agentainer_tpu.models.llama import _seen
+
+    bk, s, kv_heads, hd = 128, 512, 16, 128
+    positions = jnp.array([300, s - 1, 7, s - 1], jnp.int32)
+    seen = _seen(positions, s)
+    np.testing.assert_array_equal(np.asarray(seen), [300, 0, 7, 0])
+    np.testing.assert_array_equal(np.asarray(_seen(jnp.array([s - 2]), s)), [s - 2])
+    keys = jax.random.split(jax.random.PRNGKey(34), 3)
+    ck = _rand(keys[0], 1, 4, s, kv_heads, hd).astype(jnp.bfloat16)
+    cv = _rand(keys[1], 1, 4, s, kv_heads, hd).astype(jnp.bfloat16)
+    q = _rand(keys[2], 4, kv_heads, hd).astype(jnp.bfloat16)
+    got = flash_decode(q, ck, cv, seen, 0, block_k=bk, interpret=True)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    # one row seen: the softmax over it is 1, the output is that row's values
+    np.testing.assert_array_equal(np.asarray(got[3]), np.asarray(cv[0, 3, 0]))
+    before = _unclamped(monkeypatch, flash_decode)(q, ck, cv, positions, 0, block_k=bk, interpret=True)
+    np.testing.assert_array_equal(np.asarray(got[0::2]), np.asarray(before[0::2]))
+
+
+@pytest.mark.parametrize("offset", [0, 256, 1024 - 256], ids=["first", "second", "last"])
+def test_prefill_chunk_fetches_up_to_its_last_row(monkeypatch, offset):
+    """A 256-row chunk at ``offset`` of a 1,024-row arena (slot 1 of 2, GQA,
+    two q tiles): the reference's numbers, the unclamped kernel's bits, and a
+    bucket's padding rows that run past the arena's end do not move the map
+    out of it."""
+    from agentainer_tpu.ops.attention import _reference_dense
+
+    t, s, kv_heads, groups, hd = 256, 1024, 8, 2, 128
+    keys = jax.random.split(jax.random.PRNGKey(35), 3)
+    ck = _rand(keys[0], 1, 2, s, kv_heads, hd).astype(jnp.bfloat16)
+    cv = _rand(keys[1], 1, 2, s, kv_heads, hd).astype(jnp.bfloat16)
+    q = _rand(keys[2], 1, t, kv_heads * groups, hd).astype(jnp.bfloat16)
+    # the last chunk's positions as the engine sends a padded bucket's: the
+    # real tokens stop short of the end and the padding's run on past it
+    start = offset if offset + t < s else offset + 64
+    positions = (start + jnp.arange(t, dtype=jnp.int32))[None]
+    slot = jnp.int32(1)
+    kw = dict(block_q=128, block_k=256, interpret=True)
+    got = flash_prefill(q, ck, cv, positions, 0, slot, **kw)
+    parent = _unclamped(monkeypatch, flash_prefill)(q, ck, cv, positions, 0, slot, **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(parent))
+    want = _reference_dense(q, ck, cv, positions, None, jnp.int32(0), slot)
+    real = np.asarray(positions[0]) < s
+    np.testing.assert_allclose(
+        got[0, real].astype(np.float32), want[0, real].astype(np.float32), rtol=3e-2, atol=3e-2
+    )

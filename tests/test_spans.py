@@ -466,6 +466,65 @@ def test_step_module_names_the_benchmark_matches(step_engine, step, prefix):
     assert not any(module.startswith(o) for o in others), module
 
 
+# -- the ``attention`` block's fetch counters (ISSUE 33) -----------------------
+
+
+def test_decode_block_counters_follow_the_lanes_positions():
+    """``/metrics`` ``attention``: ``decode_blocks_live`` counts the K/V blocks
+    ``flash_decode``'s clamped index map lets through (a lane at position p
+    reads ``p // bk + 1``, a parked lane one), ``decode_blocks_stored`` the
+    blocks the arena rows hold; both cumulative, counted on the host at each
+    decode launch. Two lanes of 1,024 (two blocks of 512 each): a 601-token
+    prompt decodes from position 601 (two blocks) beside a parked lane (one),
+    then a 3-token prompt decodes in the first block beside a parked lane."""
+    eng = LLMEngine.create(
+        "tiny", options={"max_batch": 2, "max_seq": 1024, "decode_chunk": 8, "prefill_chunk": 256,
+                         "speculative": False, "skip_warmup": True},
+    )
+    try:
+        def snap():
+            m = eng.metrics()
+            assert m["mixed_launches"] == 0  # one request at a time: plain decode launches only
+            return m["attention"], sum(int(c) * n for c, n in m["decode_chunk_hist"].items())
+
+        att0, steps0 = snap()
+        assert att0["decode_block_positions"] == 512
+        assert att0["decode_blocks_live"] == att0["decode_blocks_stored"] == 0
+        asyncio.run(eng.generate("word " * 120, max_tokens=8, ignore_eos=True))  # 601 tokens
+        att1, steps1 = snap()
+        asyncio.run(eng.generate("hi", max_tokens=8, ignore_eos=True))  # 3 tokens
+        att2, steps2 = snap()
+    finally:
+        eng.shutdown()
+    long_steps, short_steps = steps1 - steps0, steps2 - steps1
+    assert long_steps >= 7 and short_steps >= 7
+    assert att1["decode_blocks_live"] == (2 + 1) * long_steps
+    assert att1["decode_blocks_stored"] == 2 * 2 * long_steps
+    assert att2["decode_blocks_live"] - att1["decode_blocks_live"] == (1 + 1) * short_steps
+    assert att2["decode_blocks_stored"] - att1["decode_blocks_stored"] == 2 * 2 * short_steps
+    assert att2["decode_blocks_live"] <= att2["decode_blocks_stored"]
+
+
+@pytest.mark.parametrize("positions, steps, live", [
+    # lanes 0 and 2 step; 1 and 3 are parked (one block a step each)
+    ([0, 511], 1, 1 + 1 + 2),
+    # a lane crossing into its second block during an 8-step launch
+    ([508], 8, 4 * 1 + 4 * 2 + 8 * 3),
+    # a lane the host still steps that has reached the arena's last row is seen at row 0
+    ([1020], 8, 3 * 2 + 5 * 1 + 8 * 3),
+    ([], 2, 2 * 4),
+], ids=["two-lanes", "crossing", "reaches-scratch", "all-parked"])
+def test_decode_block_count_by_hand(positions, steps, live):
+    eng = LLMEngine.create("tiny", options={**TINY, "max_seq": 1024, "speculative": False})
+    try:
+        eng._count_decode_blocks(positions, steps)
+        att = eng.metrics()["attention"]
+    finally:
+        eng.shutdown()
+    assert att["decode_blocks_live"] == live
+    assert att["decode_blocks_stored"] == steps * 4 * 2
+
+
 # -- the ``moe`` block of ``/metrics`` (ISSUE 26) ------------------------------
 
 

@@ -303,6 +303,40 @@ def test_mixed_step_reads_weights_and_arena_in_place_on_v5e(v5e, monkeypatch, mo
     assert temp < min(layer_matrix, arena_layer) // 16, temp
 
 
+def test_decode_step_with_the_clamped_map_copies_no_arena_on_v5e(v5e, monkeypatch):
+    """The decode step of ``olmoe-1b-7b-1chip`` (16 lanes of 2,048, int8, two
+    layers of it) since ``flash_decode``'s K/V index map reads the lanes'
+    positions (ISSUE 33) and a parked lane is seen at row 0: the chip's
+    compiler takes the data-dependent map, the donated arena still rides in
+    the layer loop's carry (one scatter a stack, no arena-sized copy, no
+    layer sliced out) and the step holds no temporary the size of a layer."""
+    from agentainer_tpu.analysis.hlo_contracts import ArenaRidesInCarry, check
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    import dataclasses
+
+    lanes = 16
+    cfg = dataclasses.replace(get_config("olmoe-1b-7b"), n_layers=2, name="olmoe-2l")
+    where = SingleDeviceSharding(v5e.devices[0])
+    params, place = _served_shapes(cfg, where)
+    cache = jax.tree.map(place, jax.eval_shape(lambda: KVCache.create(cfg, lanes, 2048)))
+    rows = jax.ShapeDtypeStruct((lanes, 1), jnp.int32, sharding=where)
+
+    def step(params, cache, tokens, positions):
+        return forward(params, cfg, tokens, positions, cache)
+
+    lowered = jax.jit(step, donate_argnums=(1,)).lower(params, cache, rows, rows)
+    arena = tuple(cache.k.shape)
+    check(lowered.as_text(), ArenaRidesInCarry(arena=arena, rows=(lanes, 1) + arena[3:], loops=1))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    dims = ",".join(str(d) for d in arena)
+    copies = [ln for ln in text.splitlines() if re.search(rf"= \w+\[{dims}\]", ln) and " copy(" in ln]
+    assert not copies, copies[:2]
+    assert text.count("tpu_custom_call") == 1, text.count("tpu_custom_call")
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * math.prod(arena[1:]) // 16
+
+
 @pytest.mark.parametrize("step", ["decode", "prefill"])
 def test_hybrid_step_holds_no_stack_sized_temporary_on_v5e(v5e, step):
     """Kimi-Linear's block at published widths (the dense layer and one

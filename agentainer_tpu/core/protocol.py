@@ -15,6 +15,11 @@ REQUEST_ID_HEADER = "X-Agentainer-Request-ID"
 # dispatch time: journal write, mark_processing, replica choice, connect,
 # send. Replayed dispatches carry none.
 ACCEPTED_NS_HEADER = "X-Agentainer-Accepted-Ns"
+# backend → engine process, in the environment of the spawn: CLOCK_REALTIME
+# nanoseconds right before it. A stamp like the one above, not a setting:
+# engine_main reads it once and the boot's timeline (utils/boot.py) starts
+# there; an engine nobody spawned has none
+SPAWNED_NS_ENV = "AGENTAINER_SPAWNED_NS"
 # end-to-end deadline: remaining milliseconds the caller will wait; the
 # proxy journals the absolute instant and forwards the remaining budget
 DEADLINE_HEADER = "X-Agentainer-Deadline-Ms"

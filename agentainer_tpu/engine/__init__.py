@@ -44,7 +44,10 @@ _EXTRA: dict[str, str] = {}
 def register_engine(name: str, module: str, tpu: bool = False) -> None:
     """Register a user engine: ``module`` must expose ``serve()`` (run in
     the engine subprocess with the AGENTAINER_* env contract). ``tpu``
-    marks it JAX-backed (model-config validation + chip placement)."""
+    marks it JAX-backed (model-config validation + chip placement); its
+    ``serve(boot=)`` is handed the process's boot timeline
+    (``utils/boot.BootTimeline``, ``boot.import`` open: ``imported()``
+    closes it), which ``runtime/engine_main`` started at its entry."""
     if not name or ":" in name or "," in name:
         raise ValueError(f"bad engine name {name!r}")
     _EXTRA[name] = module

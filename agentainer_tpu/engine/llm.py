@@ -1154,8 +1154,14 @@ class LLMEngine:
         agent_id: str = "",
         store=None,
         options: dict | None = None,
+        boot: Spans | None = None,
     ) -> "LLMEngine":
+        """``boot``: the recorder of the boot this load is part of (the
+        serve app's ``utils/boot.BootTimeline``); its stages here are
+        ``boot.backend``, ``boot.weights``, ``boot.engine_init`` and
+        ``boot.warmup``."""
         options = options or {}
+        boot = Spans() if boot is None else boot
         refusal = unserved_layout(options)
         if refusal:
             raise ValueError(refusal)
@@ -1179,7 +1185,9 @@ class LLMEngine:
         else:
             cfg = get_config(config_name or "tiny")
         tokenizer = load_tokenizer(cfg.vocab_size, checkpoint)
-        backend = jax.default_backend()
+        with boot.span("boot.backend"):  # the runtime comes up here
+            backend = jax.default_backend()
+            all_devices = jax.devices()
         if backend == "cpu" and os.environ.get("JAX_PLATFORMS", "").split(",")[0] != "cpu":
             # an engine that finds no chip must say so, not serve float32
             # from the host as if nothing were wrong; tests and rehearsals
@@ -1202,7 +1210,6 @@ class LLMEngine:
         # (parallel/sharding.param_shardings_for).
         from ..parallel.mesh import make_mesh, plan_layout
 
-        all_devices = jax.devices()
         chips = [int(c) for c in options.get("chips", []) or []]
         if len(chips) > len(all_devices):
             raise ValueError(
@@ -1219,85 +1226,88 @@ class LLMEngine:
         devices = list(all_devices[: tp * ep])
         mesh = make_mesh(tp, ep, devices=devices) if tp * ep > 1 else None
         synthetic = bool(options.get("synthetic"))
-        if checkpoint:
-            from .checkpoint import load_params
+        source = "checkpoint" if checkpoint else "synthetic" if synthetic and quant else "random"
+        with boot.span("boot.weights", source=source):
+            if checkpoint:
+                from .checkpoint import load_params
 
-            params = load_params(cfg, checkpoint, dtype=dtype)  # host-side
-        elif synthetic and quant:
-            # benchmark-grade int8 weights generated directly in HBM: no
-            # minutes-long host init, no multi-GB host→device transfer.
-            # Meshed engines generate each leaf WITH its sharding, so every
-            # chip allocates only its slice (VERDICT r3 missing #3).
-            from .quant import synthetic_quantized_params
+                params = load_params(cfg, checkpoint, dtype=dtype)  # host-side
+            elif synthetic and quant:
+                # benchmark-grade int8 weights generated directly in HBM: no
+                # minutes-long host init, no multi-GB host→device transfer.
+                # Meshed engines generate each leaf WITH its sharding, so every
+                # chip allocates only its slice (VERDICT r3 missing #3).
+                from .quant import synthetic_quantized_params
 
-            if mesh is not None:
-                params = synthetic_quantized_params(cfg, dtype, mesh=mesh)
-            else:
-                params = synthetic_quantized_params(
-                    cfg, dtype, device=devices[0] if devices else None
-                )
-        elif quant:
-            # random init on the HOST when quantizing: the dense bf16 model
-            # may be exactly what doesn't fit the chip
-            try:
-                cpu0 = jax.local_devices(backend="cpu")[0]
-            except Exception:
-                cpu0 = None
-            if cpu0 is not None:
-                with jax.default_device(cpu0):
+                if mesh is not None:
+                    params = synthetic_quantized_params(cfg, dtype, mesh=mesh)
+                else:
+                    params = synthetic_quantized_params(
+                        cfg, dtype, device=devices[0] if devices else None
+                    )
+            elif quant:
+                # random init on the HOST when quantizing: the dense bf16 model
+                # may be exactly what doesn't fit the chip
+                try:
+                    cpu0 = jax.local_devices(backend="cpu")[0]
+                except Exception:
+                    cpu0 = None
+                if cpu0 is not None:
+                    with jax.default_device(cpu0):
+                        params = init_params(cfg, jax.random.PRNGKey(0), dtype=dtype)
+                else:
                     params = init_params(cfg, jax.random.PRNGKey(0), dtype=dtype)
+            elif mesh is not None:
+                # meshed random init allocates straight into shards — never the
+                # whole model on the default device (VERDICT r3 missing #3)
+                from ..parallel.sharding import param_specs as _ps
+
+                params = _sharded_random_init(cfg, dtype, mesh, _ps(cfg.is_moe, cfg.qk_norm))
             else:
                 params = init_params(cfg, jax.random.PRNGKey(0), dtype=dtype)
-        elif mesh is not None:
-            # meshed random init allocates straight into shards — never the
-            # whole model on the default device (VERDICT r3 missing #3)
-            from ..parallel.sharding import param_specs as _ps
+            if quant and not (synthetic and not checkpoint):
+                from .quant import quantize_params
 
-            params = _sharded_random_init(cfg, dtype, mesh, _ps(cfg.is_moe, cfg.qk_norm))
-        else:
-            params = init_params(cfg, jax.random.PRNGKey(0), dtype=dtype)
-        if quant and not (synthetic and not checkpoint):
-            from .quant import quantize_params
-
-            # host-side: only the int8 model ever reaches HBM (synthetic
-            # init already produced QTensors in device memory)
-            params = quantize_params(params, dtype)
+                # host-side: only the int8 model ever reaches HBM (synthetic
+                # init already produced QTensors in device memory)
+                params = quantize_params(params, dtype)
         max_batch = int(options.get("max_batch", 8))
         max_seq = int(options.get("max_seq", min(cfg.max_seq_len, 2048)))
         decode_chunk = int(options.get("decode_chunk", 8))
         prefill_chunk = int(options.get("prefill_chunk", 256))
-        engine = cls(
-            cfg,
-            params,
-            tokenizer,
-            max_batch=max_batch,
-            max_seq=max_seq,
-            decode_chunk=decode_chunk,
-            prefill_chunk=prefill_chunk,
-            tp=tp,
-            ep=ep,
-            devices=devices,
-            mesh=mesh,
-            routed_moe=options.get("routed"),
-            moe_capacity_factor=float(options.get("moe_cf", 2.0)),
-            adaptive_decode=bool(options.get("adaptive_decode", True)),
-            # None: not asked (the family's default; ``cache_features``)
-            prefix_cache=bool(options["prefix_cache"]) if "prefix_cache" in options else None,
-            prefix_cache_bytes=int(options.get("prefix_cache_bytes", 0) or 0),
-            deadlines=bool(options.get("deadlines", True)),
-            shed_watermark=int(options.get("shed_watermark", 0) or 0),
-            speculative=bool(options["speculative"]) if "speculative" in options else None,
-            spec_gamma_max=int(options.get("spec_gamma_max", 8) or 8),
-            paged_kv=bool(options.get("paged_kv", False)),
-            page_size=int(options.get("page_size", PAGE_SIZE_DEFAULT) or PAGE_SIZE_DEFAULT),
-            kv_pages=int(options.get("kv_pages", 0) or 0),
-            fused_decode=bool(options.get("fused_decode", False)),
-            inloop_spec=bool(options.get("inloop_spec", True)),
-            approx_topk=bool(options.get("approx_topk", False)),
-            kv_tiering=bool(options.get("kv_tiering", False)),
-            tier_quantize=int(options.get("tier_quantize", 1) or 0),
-            streaming=bool(options.get("streaming", False)),
-        )
+        with boot.span("boot.engine_init"):
+            engine = cls(
+                cfg,
+                params,
+                tokenizer,
+                max_batch=max_batch,
+                max_seq=max_seq,
+                decode_chunk=decode_chunk,
+                prefill_chunk=prefill_chunk,
+                tp=tp,
+                ep=ep,
+                devices=devices,
+                mesh=mesh,
+                routed_moe=options.get("routed"),
+                moe_capacity_factor=float(options.get("moe_cf", 2.0)),
+                adaptive_decode=bool(options.get("adaptive_decode", True)),
+                # None: not asked (the family's default; ``cache_features``)
+                prefix_cache=bool(options["prefix_cache"]) if "prefix_cache" in options else None,
+                prefix_cache_bytes=int(options.get("prefix_cache_bytes", 0) or 0),
+                deadlines=bool(options.get("deadlines", True)),
+                shed_watermark=int(options.get("shed_watermark", 0) or 0),
+                speculative=bool(options["speculative"]) if "speculative" in options else None,
+                spec_gamma_max=int(options.get("spec_gamma_max", 8) or 8),
+                paged_kv=bool(options.get("paged_kv", False)),
+                page_size=int(options.get("page_size", PAGE_SIZE_DEFAULT) or PAGE_SIZE_DEFAULT),
+                kv_pages=int(options.get("kv_pages", 0) or 0),
+                fused_decode=bool(options.get("fused_decode", False)),
+                inloop_spec=bool(options.get("inloop_spec", True)),
+                approx_topk=bool(options.get("approx_topk", False)),
+                kv_tiering=bool(options.get("kv_tiering", False)),
+                tier_quantize=int(options.get("tier_quantize", 1) or 0),
+                streaming=bool(options.get("streaming", False)),
+            )
         # pay the decode/prefill compiles here (inside the loader thread, while
         # /health keeps answering) instead of on the first user request.
         # skip_warmup (set on engine RESPAWN when the persistent XLA cache is
@@ -1305,7 +1315,8 @@ class LLMEngine:
         # requests for a much shorter crash-recovery time — the compiles are
         # disk loads, not recompiles.
         if not options.get("skip_warmup"):
-            engine.warmup()
+            with boot.span("boot.warmup"):
+                engine.warmup(boot)
         return engine
 
     def _build_compiled(self) -> None:
@@ -1881,7 +1892,7 @@ class LLMEngine:
             )
         return fn
 
-    def warmup(self) -> None:
+    def warmup(self, boot: Spans | None = None) -> None:
         """Pre-compile every serve-path signature BY SERVING: one synthetic
         request per reachable prefill bucket runs through the real worker
         machinery (admission → chunked prefill → device-carry injection →
@@ -1894,7 +1905,13 @@ class LLMEngine:
         prefill feeds at most ``prefill_chunk`` tokens per tick, so the
         reachable buckets are those ≤ bucket(min(prefill_chunk,
         max_seq-2)). Runs behind the loading marker — /health answers 503
-        throughout; telemetry from warmup traffic is dropped at the end."""
+        throughout; telemetry from warmup traffic is dropped at the end.
+
+        Five parts, each a span of ``boot`` (under ``create``'s
+        ``boot.warmup``), entered where the engine has the part:
+        ``boot.warmup_serve`` (the bucket passes and the decode ladder),
+        ``_snapshot``, ``_prefix``, ``_verify`` and ``_mixed``."""
+        boot = Spans() if boot is None else boot
         top_bucket = self._bucket(min(self.prefill_chunk, max(1, self.max_seq - 2)))
         filler = min(5, self.cfg.vocab_size - 1)
 
@@ -1954,42 +1971,44 @@ class LLMEngine:
         # Speculation is OFF too: the filler prompts are maximally
         # repetitive, and a spec round replacing a decode chunk would leave
         # ladder buckets uncompiled. The verify ladder is warmed explicitly.
-        self._prefix_active = False
-        self._spec_active = False
-        try:
-            t = threading.Thread(target=_runner, name="llm-warmup")
-            t.start()
-            t.join()
-        finally:
-            self._prefix_active = self.prefix_cache
-            self._spec_active = self.speculative
+        with boot.span("boot.warmup_serve"):
+            self._prefix_active = False
+            self._spec_active = False
+            try:
+                t = threading.Thread(target=_runner, name="llm-warmup")
+                t.start()
+                t.join()
+            finally:
+                self._prefix_active = self.prefix_cache
+                self._spec_active = self.speculative
         if box:
             raise box[0]
         # pre-compile the snapshot slicers too: their first jit used to
         # land on the serving worker thread mid-traffic, stalling every
         # in-flight decode for the compile's duration, long enough to 502
         # a request at the proxy
-        if self.paged:
-            # paged snapshot stagers: exact-page-count gathers, warmed at
-            # pow2 counts (odd counts compile on demand — a trivial gather)
-            c = 1
-            while True:
-                count = min(c, self._n_blocks)
-                ids = jnp.zeros((count,), jnp.int32)
-                jax.block_until_ready(self._snap_fn_paged(count)(self.cache, ids))
-                if c >= self._n_blocks:
-                    break
-                c *= 2
-        else:
-            b = PREFILL_BUCKETS[0]
-            snap_buckets = set()
-            while True:
-                snap_buckets.add(min(b, self.max_seq))
-                if b >= self.max_seq:
-                    break
-                b *= 2
-            for bucket in sorted(snap_buckets):
-                jax.block_until_ready(self._snap_fn(bucket)(self.cache, jnp.int32(0)))
+        with boot.span("boot.warmup_snapshot"):
+            if self.paged:
+                # paged snapshot stagers: exact-page-count gathers, warmed at
+                # pow2 counts (odd counts compile on demand — a trivial gather)
+                c = 1
+                while True:
+                    count = min(c, self._n_blocks)
+                    ids = jnp.zeros((count,), jnp.int32)
+                    jax.block_until_ready(self._snap_fn_paged(count)(self.cache, ids))
+                    if c >= self._n_blocks:
+                        break
+                    c *= 2
+            else:
+                b = PREFILL_BUCKETS[0]
+                snap_buckets = set()
+                while True:
+                    snap_buckets.add(min(b, self.max_seq))
+                    if b >= self.max_seq:
+                        break
+                    b *= 2
+                for bucket in sorted(snap_buckets):
+                    jax.block_until_ready(self._snap_fn(bucket)(self.cache, jnp.int32(0)))
         # prefix-arena copy fns (same warm-up pattern as the snapshot
         # slicers): one slice + one fork executable per bucket level, so an
         # admission-time fork never pays a serve-time compile. The fork
@@ -1997,39 +2016,39 @@ class LLMEngine:
         # read, so warmed state is untouched. Paged engines fork by PAGE
         # MAPPING (no compiled copy at all); only the partial-tail CoW
         # single-page copy needs warming.
-        if self.prefix_cache and self.paged:
-            scr = jnp.int32(self._scratch_page(0))
-            self.cache = self._page_copy_fn()(self.cache, scr, scr)
-            jax.block_until_ready(self.cache.k)
-        elif self.prefix_cache:
-            for b in self._prefix_levels:
-                k, v = self._prefix_slice_fn(b)(self.cache, jnp.int32(0))
-                self.cache = self._prefix_fork_fn(b)(
-                    self.cache, jnp.int32(0), k, v
-                )
-            jax.block_until_ready(self.cache.k)
+        if self.prefix_cache:
+            with boot.span("boot.warmup_prefix"):
+                if self.paged:
+                    scr = jnp.int32(self._scratch_page(0))
+                    self.cache = self._page_copy_fn()(self.cache, scr, scr)
+                else:
+                    for b in self._prefix_levels:
+                        k, v = self._prefix_slice_fn(b)(self.cache, jnp.int32(0))
+                        self.cache = self._prefix_fork_fn(b)(self.cache, jnp.int32(0), k, v)
+                jax.block_until_ready(self.cache.k)
         # verify ladder (speculative decoding): one compiled k-token verify
         # program per bucket, exercised against the live carry/cache — all
         # lanes are parked at scratch here, so the round's writes land in
         # the scratch rows exactly like plain parked decode. A serving-time
         # spec round must never pay a compile.
         if self.speculative:
-            for b in self._spec_buckets:
-                self._rng, key = jax.random.split(self._rng)
-                _, _, self._dtok, self._dpos, self.cache = self._verify_fn(b)(
-                    self.params,
-                    self.cache,
-                    *self._bt_arg(),
-                    self._dtok,
-                    self._dpos,
-                    self._dtemps,
-                    self._dtopk,
-                    self._dtopp,
-                    jnp.zeros((self.max_batch, b), jnp.int32),
-                    jnp.zeros((self.max_batch,), jnp.int32),
-                    key,
-                )
-            jax.block_until_ready(self.cache.k)
+            with boot.span("boot.warmup_verify"):
+                for b in self._spec_buckets:
+                    self._rng, key = jax.random.split(self._rng)
+                    _, _, self._dtok, self._dpos, self.cache = self._verify_fn(b)(
+                        self.params,
+                        self.cache,
+                        *self._bt_arg(),
+                        self._dtok,
+                        self._dpos,
+                        self._dtemps,
+                        self._dtopk,
+                        self._dtopp,
+                        jnp.zeros((self.max_batch, b), jnp.int32),
+                        jnp.zeros((self.max_batch,), jnp.int32),
+                        key,
+                    )
+                jax.block_until_ready(self.cache.k)
         # the mixed step (a prefill chunk that carries the decode lanes'
         # step): warm-up serves one request at a time and so never has a
         # chunk pending beside a decoding lane. One program per bucket a
@@ -2037,16 +2056,17 @@ class LLMEngine:
         # ladder: every lane is parked, the chunk's rows land in slot 0,
         # which ``clear_sessions`` below leaves cold.
         if self._prefill_with_decode is not None:
-            for b in PREFILL_BUCKETS:
-                if b > top_bucket:
-                    break
-                self._launch_with_decode(
-                    0,
-                    jnp.asarray(np.zeros((1, b), np.int32)),
-                    jnp.asarray(np.arange(b, dtype=np.int32)[None]),
-                    b,
-                )
-            jax.block_until_ready(self.cache.k)
+            with boot.span("boot.warmup_mixed"):
+                for b in PREFILL_BUCKETS:
+                    if b > top_bucket:
+                        break
+                    self._launch_with_decode(
+                        0,
+                        jnp.asarray(np.zeros((1, b), np.int32)),
+                        jnp.asarray(np.arange(b, dtype=np.int32)[None]),
+                        b,
+                    )
+                jax.block_until_ready(self.cache.k)
         # warmup traffic is not serving telemetry: TTFT samples here include
         # compile time and would pollute p50s until the deque rolls over
         self.clear_sessions()

@@ -24,6 +24,7 @@ import time
 from aiohttp import web
 
 from ..runtime.store_client import StoreClient
+from ..utils.boot import BootTimeline
 from ..utils.compile_cache import CompileCacheStats, compile_cache_dir, enable_compile_cache
 
 MAX_TURNS = 50
@@ -82,6 +83,7 @@ class LLMServeApp:
         env: dict | None = None,
         host: "LLMServeApp | None" = None,
         compile_stats: CompileCacheStats | None = None,
+        boot: BootTimeline | None = None,
     ) -> None:
         E = os.environ if env is None else env
         self._host = host
@@ -90,6 +92,13 @@ class LLMServeApp:
         # persistent-compile-cache counters of this process (serve() turns
         # the cache on); None when embedded in a process that did not
         self._compile_stats = compile_stats
+        # the boot's timeline, begun by engine_main for a spawned host and
+        # here for an embedded one; a tenant's boot is its host's
+        if boot is None and host is None:
+            boot = BootTimeline()
+        if boot is not None:
+            boot.compile_stats = compile_stats
+        self._boot = boot
         self.engine_load_s: float | None = None
         self.warmup_skipped = False
         self.agent_id = E.get("AGENTAINER_AGENT_ID", "standalone")
@@ -401,7 +410,8 @@ class LLMServeApp:
         """Build the JAX engine (slow: compile + weight init). Runs in a
         thread at startup so /health can answer while loading."""
         try:
-            from .llm import LLMEngine
+            with self._boot.span("boot.import"):
+                from .llm import LLMEngine
 
             opts = self._engine_options()
             t0 = time.monotonic()
@@ -414,6 +424,7 @@ class LLMServeApp:
                 # chip assignment always rides along (placement authority),
                 # while an explicit options.tp can narrow the span
                 options=opts,
+                boot=self._boot,
             )
             self.engine_load_s = round(time.monotonic() - t0, 2)
             self.warmup_skipped = bool(opts.get("skip_warmup"))
@@ -583,6 +594,7 @@ class LLMServeApp:
                 # an engine was injected before startup (embedding, tests):
                 # loading again would orphan a second worker thread and
                 # race the injected engine out of self.engine
+                self._boot.ready()
                 self._ready.set()
                 self._fan_out_ready()
                 return
@@ -608,9 +620,11 @@ class LLMServeApp:
                             for tenant, _, _ in list(self._tenants.values()):
                                 await tenant._prewarm_prefix()
 
-                        asyncio.run(_prewarm_all())
+                        with self._boot.span("boot.prewarm_prefix"):
+                            asyncio.run(_prewarm_all())
                 finally:
                     # set even on loader death: waiters unblock
+                    self._boot.ready()
                     loop.call_soon_threadsafe(self._ready.set)
                     if self.engine is not None:
                         self._fan_out_ready()
@@ -811,9 +825,15 @@ class LLMServeApp:
         accepted_ns = request.headers.get(ACCEPTED_NS_HEADER, "")
         if accepted_ns.isdigit():
             self.journal_dispatch_ms_recent.append((time.time_ns() - int(accepted_ns)) / 1e6)
+        boot = (self._host or self)._boot
+        entered_ns = time.perf_counter_ns() if boot.first_dispatch_s is None else 0
         err = await self._ensure_engine()
         if err is not None:
             return err
+        if entered_ns:
+            # the first request taken after ready; a replayed dispatch
+            # carries no accept stamp (core/protocol.py)
+            boot.first_dispatch(entered_ns, replayed=not accepted_ns.isdigit())
         try:
             body = await request.json()
         except json.JSONDecodeError:
@@ -1496,9 +1516,12 @@ class LLMServeApp:
         host = self._host if self._host is not None else self
         # set-up cost of the engine this surface serves from: seconds to
         # build it (weights + warm-up compiles), whether a warm boot skipped
-        # the warm-up, and what the process asked of the compile cache
+        # the warm-up, the boot's timeline from main's entry to ready with
+        # its stages (utils/boot.py), and what the process asked of the
+        # compile cache, by program
         doc["engine_load_s"] = host.engine_load_s
         doc["warmup_skipped"] = host.warmup_skipped
+        doc["boot"] = host._boot.as_dict()
         if host._compile_stats is not None:
             doc["compile_cache"] = host._compile_stats.as_dict()
         if self._host is not None or self._tenants:
@@ -1513,7 +1536,9 @@ class LLMServeApp:
         return web.json_response(doc)
 
 
-def serve() -> None:
-    app_obj = LLMServeApp(compile_stats=enable_compile_cache())
+def serve(boot: BootTimeline | None = None) -> None:
+    app_obj = LLMServeApp(compile_stats=enable_compile_cache(), boot=boot)
+    if boot is not None:
+        boot.imported()
     port = int(os.environ.get("AGENTAINER_PORT", "8000"))
     web.run_app(app_obj.app(), host="127.0.0.1", port=port, print=None)

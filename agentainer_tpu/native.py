@@ -13,6 +13,7 @@ import os
 import subprocess
 import sys
 import threading
+import time
 from pathlib import Path
 
 _REPO_ROOT = Path(__file__).resolve().parent.parent
@@ -22,6 +23,7 @@ _LIB_PATH = _NATIVE_DIR / "build" / "libagentainer_native.so"
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _load_error: str | None = None
+_load_s: float | None = None  # what the one real load() took, build included
 
 
 def _build() -> bool:
@@ -105,26 +107,30 @@ def _bind(lib: ctypes.CDLL) -> None:
     ]
 
 
+def _load_once() -> ctypes.CDLL | None:
+    global _load_error
+    if not _LIB_PATH.exists() or _stale():
+        if not _build():
+            _load_error = "native build failed (make -C native)"
+            return None
+    try:
+        lib = ctypes.CDLL(str(_LIB_PATH))
+        _bind(lib)
+        return lib
+    except OSError as e:
+        _load_error = f"dlopen failed: {e}"
+        return None
+
+
 def load() -> ctypes.CDLL | None:
     """Load (building if needed) the native library; None if unavailable."""
-    global _lib, _load_error
+    global _lib, _load_s
     with _lock:
-        if _lib is not None:
-            return _lib
-        if _load_error is not None:
-            return None
-        if not _LIB_PATH.exists() or _stale():
-            if not _build():
-                _load_error = "native build failed (make -C native)"
-                return None
-        try:
-            lib = ctypes.CDLL(str(_LIB_PATH))
-            _bind(lib)
-            _lib = lib
-            return lib
-        except OSError as e:
-            _load_error = f"dlopen failed: {e}"
-            return None
+        if _lib is None and _load_error is None:
+            t0 = time.monotonic()
+            _lib = _load_once()
+            _load_s = time.monotonic() - t0
+        return _lib
 
 
 def _stale() -> bool:
@@ -150,3 +156,9 @@ def available() -> bool:
 
 def load_error() -> str | None:
     return _load_error
+
+
+def load_seconds() -> float | None:
+    """Seconds this process spent building or loading the library (its
+    first ``load()``, whatever came of it); None if it never tried."""
+    return _load_s

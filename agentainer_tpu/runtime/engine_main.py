@@ -11,9 +11,12 @@ from __future__ import annotations
 import logging
 import os
 import sys
+import time
 
 
 def main() -> None:
+    # where the boot's timeline starts (utils/boot.py): both clocks, together
+    started_ns, started_unix_ns = time.perf_counter_ns(), time.time_ns()
     # request lines (aiohttp.access) and engine warnings go to stdout, which
     # the backend captures into the engine's log file — the same visibility
     # a container gets from docker logs (agent.go:411-429 / logs --follow)
@@ -32,7 +35,13 @@ def main() -> None:
     engine = os.environ.get("AGENTAINER_ENGINE", "echo")
     from ..engine import is_tpu_engine
 
+    boot = None
     if is_tpu_engine(engine):
+        # boot.import runs from main's entry until the serve app is built;
+        # the recorder's first span imports JAX (the echo engine never does)
+        from ..utils.boot import BootTimeline
+
+        boot = BootTimeline.at_main(started_ns, started_unix_ns, os.environ)
         # The platform and the chips this process may open come from its
         # environment alone (JAX_PLATFORMS, the TPU visibility variables
         # runtime/local.py sets): nothing has imported JAX before this
@@ -59,7 +68,11 @@ def main() -> None:
     if module is None:
         print(f"unknown engine {engine!r}", file=sys.stderr)
         sys.exit(2)
-    importlib.import_module(module).serve()
+    serve = importlib.import_module(module).serve
+    if boot is None:
+        serve()
+    else:
+        serve(boot=boot)
 
 
 if __name__ == "__main__":

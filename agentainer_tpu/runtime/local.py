@@ -41,6 +41,7 @@ from pathlib import Path
 from typing import Callable
 
 from .. import faults
+from ..core.protocol import SPAWNED_NS_ENV
 from ..core.spec import Agent
 from ..store.base import Store
 from ..utils.compile_cache import compile_cache_dir
@@ -433,6 +434,7 @@ class LocalBackend(Backend):
             host.env["AGENTAINER_WARM_BOOT"] = "1"
         # the child opens the chips named in its environment; this process
         # has not imported JAX (and must not), so they are free to open
+        host.env[SPAWNED_NS_ENV] = str(time.time_ns())
         host.proc = subprocess.Popen(
             [self.python, "-m", "agentainer_tpu.runtime.engine_main"],
             env=host.env,
@@ -549,6 +551,7 @@ class LocalBackend(Backend):
             # respawn: the persistent XLA cache is warm — the engine may
             # skip its warmup serving pass (recovery-time win)
             rec.env["AGENTAINER_WARM_BOOT"] = "1"
+        rec.env[SPAWNED_NS_ENV] = str(time.time_ns())
         rec.proc = subprocess.Popen(
             rec.cmd,
             env=rec.env,

@@ -32,12 +32,13 @@ from typing import TYPE_CHECKING
 
 from aiohttp import ClientSession, ClientTimeout, web
 
-from .. import faults
+from .. import faults, native
 from ..core.errors import AgentainerError, AgentNotFound
 from ..core.resilience import CircuitBreaker, retry_after_jitter
 from ..core.spec import AgentStatus, HealthCheckConfig, ModelRef, Resources
 from ..manager.journal import RequestStatus, StreamGapError
 from ..store.schema import Keys
+from ..utils.boot import process_age_s
 from .router import ReplicaChoice, ReplicaRouter
 
 if TYPE_CHECKING:
@@ -180,6 +181,7 @@ class ControlPlaneApp:
         self.app = web.Application(middlewares=[self._error_mw, self._auth_mw])
         self._routes()
         self._client: ClientSession | None = None
+        self._listening_s: float | None = None  # /health boot: set by the first answer
         # global pending depth is a store SCAN — cached briefly so the shed
         # check stays O(1) per proxied request (staleness bound: a burst can
         # overshoot the global ceiling by ~one cache window of arrivals)
@@ -344,6 +346,8 @@ class ControlPlaneApp:
 
     # -- management handlers ---------------------------------------------
     async def h_server_health(self, request: web.Request) -> web.Response:
+        if self._listening_s is None:
+            self._listening_s = process_age_s()
         return ok(
             {
                 "status": "healthy",
@@ -355,6 +359,10 @@ class ControlPlaneApp:
                 # library did not build — visible, so a fallback is a fact
                 # an operator (and chip_smoke.py) can read, not a silence
                 "data_plane": "native" if self.s.dataplane is not None else "python",
+                # the daemon's own start: process start → this surface's
+                # first answer, and inside it the native library's build or
+                # load (None: never tried)
+                "boot": {"listening_s": self._listening_s, "data_plane_s": native.load_seconds()},
                 "time": time.time(),
             }
         )

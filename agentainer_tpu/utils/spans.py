@@ -84,6 +84,24 @@ class _Span:
             th.loop_ns = th.loop_base_ns + t1 - th.loop_t0
 
 
+class _SpanSince(_Span):
+    """A span that began before its recorder could be made (a boot's first:
+    importing what the recorder needs is part of what it measures). The
+    trace event starts where the span is entered; the accumulator counts
+    from ``since_ns`` on ``time.perf_counter_ns``'s clock."""
+
+    __slots__ = ("_since",)
+
+    def __init__(self, thread: _Thread, name: str, attrs: dict, since_ns: int) -> None:
+        super().__init__(thread, name, attrs)
+        self._since = since_ns
+
+    def __enter__(self) -> "_SpanSince":
+        super().__enter__()
+        self._t0 = self._since
+        return self
+
+
 class _Loop:
     __slots__ = ("_thread",)
 
@@ -120,6 +138,11 @@ class Spans:
         """``attrs`` go to the trace event only: ``request_id=`` on a
         request-scoped span, ``lanes=``/``tokens=`` on a batch-scoped one."""
         return _Span(self._thread(), name, attrs)
+
+    def span_since(self, name: str, since_ns: int, **attrs) -> _Span:
+        """A span counted from ``since_ns``, an earlier reading of
+        ``time.perf_counter_ns`` on the calling thread."""
+        return _SpanSince(self._thread(), name, attrs, since_ns)
 
     def loop(self) -> _Loop:
         return _Loop(self._thread())

@@ -345,7 +345,12 @@ async def _python_front_door(tmp_path):
     client = TestClient(TestServer(services.app))
     await client.start_server()
     backend.set_control(f"http://127.0.0.1:{client.server.port}")
-    return client, client.close
+
+    async def close():
+        await client.close()
+        await asyncio.to_thread(backend.close)  # no engine_main process outlives the test
+
+    return client, close
 
 
 async def _native_front_door(tmp_path):

@@ -234,6 +234,55 @@ def test_chunked_kda_is_the_token_by_token_recurrence_across_chunks_and_padding(
     assert float(jnp.abs(o2[:, :150] - o_ref).max()) < 1e-4 and float(jnp.abs(s2 - s_ref).max()) < 1e-5
 
 
+@pytest.mark.parametrize("c", [8, 16, 32, 64, 128])
+def test_the_blocked_inverse_is_the_inverse_at_every_chunk_length(c):
+    """``_unit_lower_inverse`` on ``[2, 3, C, C]`` strictly lower-triangular
+    blocks, from a chunk shorter than its 16-token blocks (substitution
+    alone) to 128 (three levels of merges), against numpy's float64 inverse;
+    the rows of tokens that are masked (zero rows of ``lower``) come back as
+    unit rows bit for bit, which is what leaves a masked token's state alone."""
+    rng = np.random.default_rng(c)
+    lower = np.tril(rng.normal(size=(2, 3, c, c)) * 0.3, -1)
+    lower[:, :, [1, c - 2]] = 0.0
+    got = np.asarray(kda._unit_lower_inverse(jnp.asarray(lower, jnp.float32)))
+    want = np.linalg.inv(np.eye(c) + lower)
+    assert np.abs(got - want).max() < 2e-6 * np.abs(want).max()
+    assert np.array_equal(got[:, :, [1, c - 2]], np.broadcast_to(np.eye(c, dtype=np.float32)[[1, c - 2]], (2, 3, 2, c)))
+    assert np.array_equal(np.triu(got, 1), np.zeros_like(got))
+
+
+def test_the_blocked_inverse_refuses_a_length_it_cannot_halve():
+    with pytest.raises(ValueError, match="power-of-two"):
+        kda._unit_lower_inverse(jnp.zeros((48, 48)))
+
+
+@pytest.mark.parametrize("t, chunk", [(20, 64), (64, 64), (150, 32), (150, 8), (200, 128)])
+def test_chunked_kda_at_other_chunk_lengths_and_buckets(t, chunk):
+    """The per-channel chunk at a bucket shorter than a chunk (20 tokens
+    padded to 64), at one whole chunk, and at chunks of 8, 32 and 128 tokens
+    (no merge, one level, three), each with a ragged last chunk: the
+    token-by-token recurrence within the bounds of the 64-token chunk."""
+    q, k, v, g, beta, s0 = kda_inputs(seed=t, t=t)
+    o_ref, s_ref = kda.kda_recurrent(q, k, v, g, beta, s0)
+    o, s = kda.kda_chunked(q, k, v, g, beta, s0, chunk=chunk)
+    assert o.shape == o_ref.shape
+    assert float(jnp.abs(o - o_ref).max()) < 1e-4 and float(jnp.abs(s - s_ref).max()) < 1e-5
+
+
+def test_a_masked_chunk_leaves_the_state_bit_identical():
+    """A lane whose every token of a launch is masked (β = 0, g = 0: a parked
+    lane in a prefill bucket) gets its state back bit for bit from the chunked
+    rule, beside a lane that steps; so does a lane whose real tokens are
+    followed by whole chunks of padding, from the last real token on."""
+    q, k, v, g, beta, s0 = kda_inputs(t=150)
+    valid = jnp.stack([jnp.zeros(150, bool), jnp.arange(150) < 64])
+    gm, bm = kda.mask_inputs(g, beta, valid)
+    _, s = kda.kda_chunked(q, k, v, gm, bm, s0)
+    assert np.array_equal(np.asarray(s[0]), np.asarray(s0[0]))
+    _, s_one = kda.kda_chunked(q[:, :64], k[:, :64], v[:, :64], g[:, :64], beta[:, :64], s0)
+    assert np.array_equal(np.asarray(s[1]), np.asarray(s_one[1]))
+
+
 def test_a_masked_token_leaves_state_and_conv_bit_identical():
     q, k, v, g, beta, s0 = kda_inputs(t=1)
     g0, b0 = kda.mask_inputs(g[:, 0], beta[:, 0], jnp.array([False, True]))

@@ -212,6 +212,107 @@ def test_chunked_is_recurrent_is_step_with_beta_up_to_two_and_a_chunk_past_minus
     assert float(jnp.abs(o2[:, :150] - o_ref).max()) < 2e-4 and float(jnp.abs(s2 - s_ref).max()) < 1e-5
 
 
+def recurrent64(q, k, v, g, beta, s):
+    """The recurrence of ``kda_step`` token by token in float64 (numpy)."""
+    q, k, v, g, beta, s = (np.asarray(x, np.float64) for x in (q, k, v, g, beta, s))
+    out = []
+    for t in range(q.shape[1]):
+        s = s * np.exp(g[:, t])[..., None]
+        u = beta[:, t][..., None] * (v[:, t] - np.einsum("bhk,bhkv->bhv", k[:, t], s))
+        s = s + k[:, t][..., None] * u[..., None, :]
+        out.append(np.einsum("bhk,bhkv->bhv", q[:, t], s))
+    return np.stack(out, 1), s
+
+
+def solve_chunked(q, k, v, g, beta, s):
+    """The chunked rule with one decay a head as it stood before the blocked
+    inverse: ``lax.linalg.triangular_solve`` on each chunk of 64, float32."""
+    mm = lambda eq, *xs: jnp.einsum(eq, *xs, precision=jax.lax.Precision.HIGHEST)  # noqa: E731
+    tri, outs = jnp.tril(jnp.ones((64, 64), bool)), []
+    for at in range(0, q.shape[1], 64):
+        qc, kc, vc, gc, bc = (jnp.moveaxis(x[:, at : at + 64], 1, 2) for x in (q, k, v, g[..., 0], beta))
+        big_g = jnp.cumsum(gc, axis=2)
+        decay = jnp.where(tri, jnp.exp(jnp.minimum(big_g[..., :, None] - big_g[..., None, :], 0.0)), 0.0)
+        gamma = jnp.exp(big_g)[..., None]
+        lower = mm("bhtk,bhsk->bhts", kc, kc) * decay * ~jnp.eye(64, dtype=bool) * bc[..., None]
+        rhs = bc[..., None] * (vc - mm("bhtk,bhkv->bhtv", kc * gamma, s))
+        u = jax.lax.linalg.triangular_solve(
+            lower + jnp.eye(64), rhs, left_side=True, lower=True, unit_diagonal=True)
+        outs.append(mm("bhtk,bhkv->bhtv", qc * gamma, s) + mm("bhts,bhsv->bhtv", mm("bhtk,bhsk->bhts", qc, kc) * decay, u))
+        s = s * gamma[:, :, -1][..., None] + mm("bhsk,bhsv->bhkv", kc * jnp.exp(big_g[..., -1:] - big_g)[..., None], u)
+    return jnp.moveaxis(jnp.concatenate(outs, axis=2), 2, 1), s
+
+
+def hard_inputs(keys: str, beta, fastest: float, seed=1, b=1, t=128, h=2, dk=16, dv=24):
+    """``keys``: ``random``, ``same`` (one unit key and a thousandth of noise:
+    a repeated token) or ``rank4``; ``beta`` a constant or ``None`` for a
+    draw over (0, 2); log-decays over ``(−fastest, 0)``, one a head."""
+    rng = np.random.default_rng(seed)
+    if keys == "same":
+        k = rng.normal(size=(b, 1, h, dk)) + 1e-3 * rng.normal(size=(b, t, h, dk))
+    elif keys == "rank4":
+        k = np.einsum("btr,brhk->bthk", rng.normal(size=(b, t, 4)), rng.normal(size=(b, 4, h, dk)))
+    else:
+        k = rng.normal(size=(b, t, h, dk))
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    raw = (rng.normal(size=(b, t, h, dk)), k, rng.normal(size=(b, t, h, dv)),
+           -rng.uniform(0.0, fastest, size=(b, t, h, 1)),
+           2.0 * rng.uniform(size=(b, t, h)) if beta is None else np.full((b, t, h), beta),
+           rng.normal(size=(b, h, dk, dv)))
+    return [jnp.asarray(x, jnp.float32) for x in raw]
+
+
+HARD = {  # name -> (keys, beta, fastest log-decay, tokens, held to the solve's error and not the absolute bounds)
+    "random": ("random", None, 0.3, 128, False),
+    "rank4": ("rank4", None, 0.3, 128, False),
+    "past-88": ("random", None, 3.0, 128, False),
+    "ragged": ("random", None, 0.3, 150, False),
+    "same-b1": ("same", 1.0, 0.0, 128, True),
+    "same-b1.5": ("same", 1.5, 0.0, 128, True),
+    "same-b2": ("same", 2.0, 0.0, 128, True),
+    "same-b1-decay": ("same", 1.0, 0.1, 128, True),
+    "same-b1.5-decay": ("same", 1.5, 0.1, 128, True),
+    "same-b2-decay": ("same", 2.0, 0.1, 128, True),
+    "rank4-b2": ("rank4", 2.0, 0.0, 128, True),
+}
+
+
+@pytest.mark.parametrize("gate", ["head", "channel"])
+@pytest.mark.parametrize("case", sorted(HARD))
+def test_chunked_rule_with_the_blocked_inverse_against_float64(case, gate):
+    """``kda_chunked`` (float32; the in-chunk triangle inverted in 16-token
+    blocks and merged, ``ops/kda._unit_lower_inverse``) against the recurrence
+    in float64, with one decay a head (``_scores_scalar``) and with the same
+    decay handed over per channel (``_scores``). Keys drawn at random, of rank
+    4, with a chunk whose summed log-decay passes −88 and with a ragged last
+    chunk hold the bounds the chunked rule always had: 2e-4 on the outputs,
+    1e-5 on the state. 128 near-identical unit keys (a repeated token) at
+    β = 1, 1.5 and 2, with and without decay, and rank-4 keys at β = 2 are the
+    inputs on which an inverse of ``I + diag(β) A`` is worst conditioned (the
+    transitions ``I − β k kᵀ`` all flip or shrink the same direction): there
+    the error over the largest value may be at most 4 × that of the chunked
+    rule solved by ``lax.linalg.triangular_solve`` on the same inputs. Read on
+    the CPU (PR 35) with one decay a head, outputs, blocked inverse /
+    ``triangular_solve``: β = 1: 5.1e-7 / 4.1e-7, β = 1.5: 1.6e-6 / 1.2e-6,
+    β = 2: 1.8e-5 / 1.6e-5; with decay 5.4e-7 / 3.9e-7, 8.8e-7 / 9.2e-7,
+    4.3e-6 / 6.2e-6; rank 4 at β = 2: 6.1e-6 / 2.9e-6 (the widest, 2.1 ×; the
+    states read alike). On the solve alone, such keys 96 wide against a
+    float64 solve: 1.7e-6 / 3.0e-6 at β = 2. The product form ``(I − L)(I + L²)(I + L⁴)…`` reads 4.5e10 at
+    β = 1 and 6.8e20 at β = 2 on such keys (the powers of ``L`` grow
+    binomially and cancel), which is why it is not what the chunk uses."""
+    keys, beta, fastest, t, by_solve = HARD[case]
+    q, k, v, g, beta, s0 = hard_inputs(keys, beta, fastest, t=t)
+    o_ref, s_ref = recurrent64(q, k, v, g, beta, s0)
+    o, s = kda.kda_chunked(q, k, v, g if gate == "head" else jnp.broadcast_to(g, q.shape), beta, s0)
+    over = lambda x, want: float(np.abs(np.asarray(x, np.float64) - want).max() / np.abs(want).max())  # noqa: E731
+    if by_solve:
+        o_ts, s_ts = solve_chunked(q, k, v, g, beta, s0)
+        assert over(o, o_ref) <= 4.0 * over(o_ts, o_ref) and over(s, s_ref) <= 4.0 * over(s_ts, s_ref)
+        assert over(o, o_ref) < 5e-5  # and small in itself
+    else:
+        assert float(np.abs(np.asarray(o) - o_ref).max()) < 2e-4 and float(np.abs(np.asarray(s) - s_ref).max()) < 1e-5
+
+
 def test_negative_eigenvalues_are_entered():
     """With β = 2 and a unit key the transition ``I − β k kᵀ`` flips the
     state's component along k: the case ``sigmoid`` alone never reaches."""

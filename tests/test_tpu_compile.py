@@ -337,40 +337,37 @@ def test_decode_step_with_the_clamped_map_copies_no_arena_on_v5e(v5e, monkeypatc
     assert compiled.memory_analysis().temp_size_in_bytes < 2 * math.prod(arena[1:]) // 16
 
 
-@pytest.mark.parametrize("step", ["decode", "prefill"])
-def test_hybrid_step_holds_no_stack_sized_temporary_on_v5e(v5e, step):
-    """Kimi-Linear's block at published widths (the dense layer and one
-    period, K K K M K, 8 lanes of 1024), int8 as served, its kernels on: the
-    KDA decode kernel updates the layer of the float32 state stack in place
-    and the MLA decode kernel reads the latent stack where it lies (Mosaic
-    accepts both: a ``[bk, 640]`` row block, a ``[8, 128, 128]`` state tile),
-    and through the layer scan, the mixers' 0-or-1-trip loops and the step
-    scan no stack is copied. Three things this guards were all found by this
-    compile and by nothing on the CPU (PR 30): ``lax.cond`` over the mixers
-    copied the stack a branch only passed through, every layer (2.7 GB of
-    state in each MLA layer at 64 lanes); a 576-wide latent row made the
-    chip keep the arena position-minor and relayout it into and out of every
-    launch; a conv state ``[.., 3, 12288]`` was padded 42-fold."""
+def _hybrid_case(model: str, where):
+    """``kimi-5l`` (Kimi-Linear's dense layer and one period, K K K M K, 8
+    lanes of 1024) or ``olmo-hybrid-4l`` (Olmo-Hybrid's one period, L L L F, 8
+    lanes of 4096), at published widths, int8 as served, the kernels on: the
+    configuration, the abstract parameters and cache placed on ``where``, the
+    plan, and the decode and prefill steps as the engine jits them."""
     import dataclasses
 
     from jax import lax
 
     from agentainer_tpu.engine.quant import synthetic_quantized_params
     from agentainer_tpu.models import hybrid
-    from agentainer_tpu.models.configs import kimi_linear_kinds
+    from agentainer_tpu.models.configs import kimi_linear_kinds, olmo_hybrid_kinds
     from agentainer_tpu.models.llama import init_cache
 
-    lanes, seq = 8, 1024
-    cfg = dataclasses.replace(
-        get_config("kimi-linear-48b"), n_layers=5, layer_kinds=kimi_linear_kinds(27)[:5],
-        experts_held=32, vocab_size=8192, name="kimi-5l",
-    )
-    where = SingleDeviceSharding(v5e.devices[0])
+    if model == "kimi-5l":
+        lanes, seq = 8, 1024
+        cfg = dataclasses.replace(
+            get_config("kimi-linear-48b"), n_layers=5, layer_kinds=kimi_linear_kinds(27)[:5],
+            experts_held=32, vocab_size=8192, name=model,
+        )
+    else:
+        lanes, seq = 8, 4096
+        cfg = dataclasses.replace(
+            get_config("olmo-hybrid-7b"), n_layers=4, layer_kinds=olmo_hybrid_kinds(4), n_dense_layers=4,
+            vocab_size=8192, name=model,
+        )
     place = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=where)  # noqa: E731
     params = jax.tree.map(place, jax.eval_shape(lambda: synthetic_quantized_params(cfg, jnp.bfloat16)))
     cache = jax.tree.map(place, jax.eval_shape(lambda: init_cache(cfg, lanes, seq, jnp.bfloat16, live=False)))
     plan = hybrid.plan_hybrid(cfg, use_pallas=True)
-    assert (plan.kda_decode, plan.mla_decode) == ("pallas_kda_decode", "pallas_mla_decode")
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=where)  # noqa: E731
 
     def decode_n(params, cache, tokens, positions):
@@ -387,13 +384,33 @@ def test_hybrid_step_holds_no_stack_sized_temporary_on_v5e(v5e, step):
         logits, cache = forward(params, cfg, tokens, positions, cache, cache_attn_impl=plan, slot=slot, valid=valid)
         return logits[0, -1], cache
 
-    if step == "decode":
-        compiled = jax.jit(decode_n, donate_argnums=(1, 2, 3)).lower(params, cache, i32(lanes), i32(lanes)).compile()
-    else:
-        compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
-            params, cache, i32(), i32(1, 256), i32(1, 256), i32()).compile()
+    steps = {
+        "decode": (jax.jit(decode_n, donate_argnums=(1, 2, 3)), (params, cache, i32(lanes), i32(lanes))),
+        "prefill": (jax.jit(prefill, donate_argnums=(1,)), (params, cache, i32(), i32(1, 256), i32(1, 256), i32())),
+    }
+    return cfg, cache, plan, steps
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_hybrid_step_holds_no_stack_sized_temporary_on_v5e(v5e, step):
+    """Kimi-Linear's block at published widths (the dense layer and one
+    period, K K K M K, 8 lanes of 1024), int8 as served, its kernels on: the
+    KDA decode kernel updates the layer of the float32 state stack in place
+    and the MLA decode kernel reads the latent stack where it lies (Mosaic
+    accepts both: a ``[bk, 640]`` row block, a ``[8, 128, 128]`` state tile),
+    and through the layer scan, the mixers' 0-or-1-trip loops and the step
+    scan no stack is copied. Three things this guards were all found by this
+    compile and by nothing on the CPU (PR 30): ``lax.cond`` over the mixers
+    copied the stack a branch only passed through, every layer (2.7 GB of
+    state in each MLA layer at 64 lanes); a 576-wide latent row made the
+    chip keep the arena position-minor and relayout it into and out of every
+    launch; a conv state ``[.., 3, 12288]`` was padded 42-fold."""
+    _, cache, plan, steps = _hybrid_case("kimi-5l", SingleDeviceSharding(v5e.devices[0]))
+    assert (plan.kda_decode, plan.mla_decode) == ("pallas_kda_decode", "pallas_mla_decode")
+    fn, args = steps[step]
+    compiled = fn.lower(*args).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
+    assert "tpu_custom_call" in text and "InvertDiagBlocks" not in text  # what a triangular_solve becomes here
     if step == "decode":
         assert "kda_decode" in text and "mla_decode" in text  # the names the roofline readers find
     stacks = {name: getattr(cache, name) for name in ("latent", "state", "conv")}
@@ -417,52 +434,16 @@ def test_olmo_hybrid_step_copies_no_stack_and_pads_no_arena_on_v5e(v5e, step):
     in this one-full-layer program, found by this compile). Here no stack is
     copied, transposed or padded, every leaf is donated in place, and the
     temporaries are a few megabytes."""
-    import dataclasses
-
-    from jax import lax
-
-    from agentainer_tpu.engine.quant import synthetic_quantized_params
-    from agentainer_tpu.models import hybrid
-    from agentainer_tpu.models.configs import olmo_hybrid_kinds
-    from agentainer_tpu.models.llama import init_cache
-
-    lanes, seq = 8, 4096
-    cfg = dataclasses.replace(
-        get_config("olmo-hybrid-7b"), n_layers=4, layer_kinds=olmo_hybrid_kinds(4), n_dense_layers=4,
-        vocab_size=8192, name="olmo-hybrid-4l",
-    )
-    where = SingleDeviceSharding(v5e.devices[0])
-    place = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=where)  # noqa: E731
-    params = jax.tree.map(place, jax.eval_shape(lambda: synthetic_quantized_params(cfg, jnp.bfloat16)))
-    cache = jax.tree.map(place, jax.eval_shape(lambda: init_cache(cfg, lanes, seq, jnp.bfloat16, live=False)))
-    plan = hybrid.plan_hybrid(cfg, use_pallas=True)
+    _, cache, plan, steps = _hybrid_case("olmo-hybrid-4l", SingleDeviceSharding(v5e.devices[0]))
     assert (plan.gdn_decode, plan.full_decode) == ("pallas_gdn_decode", "pallas:flash_decode")
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=where)  # noqa: E731
-
-    def decode_n(params, cache, tokens, positions):
-        def one(carry, _):
-            tok, pos, cache = carry
-            logits, cache = forward(params, cfg, tok[:, None], pos[:, None], cache, cache_attn_impl=plan)
-            return (jnp.argmax(logits[:, 0], -1).astype(jnp.int32), jnp.minimum(pos + 1, seq - 1), cache), tok
-
-        (tok, pos, cache), toks = lax.scan(one, (tokens, positions, cache), None, length=4)
-        return toks, tok, pos, cache
-
-    def prefill(params, cache, slot, tokens, positions, n_real):
-        valid = jnp.arange(tokens.shape[1])[None, :] < n_real
-        logits, cache = forward(params, cfg, tokens, positions, cache, cache_attn_impl=plan, slot=slot, valid=valid)
-        return logits[0, -1], cache
-
-    if step == "decode":
-        compiled = jax.jit(decode_n, donate_argnums=(1, 2, 3)).lower(params, cache, i32(lanes), i32(lanes)).compile()
-    else:
-        compiled = jax.jit(prefill, donate_argnums=(1,)).lower(
-            params, cache, i32(), i32(1, 256), i32(1, 256), i32()).compile()
+    fn, args = steps[step]
+    compiled = fn.lower(*args).compile()
     text = compiled.as_text()
     if step == "decode":
         assert "gdn_decode" in text and text.count("tpu_custom_call") == 2  # the state kernel and flash_decode
     else:
         assert text.count("tpu_custom_call") == 1  # flash_prefill; the chunked delta rule is XLA
+        assert "InvertDiagBlocks" not in text  # what a triangular_solve becomes here: the rule is matmuls alone
     stacks = cache.leaves()
     assert cache.k.shape[3] == 32 and set(stacks) == {"k", "v", "state", "conv"}
     mem = compiled.memory_analysis()
@@ -472,3 +453,41 @@ def test_olmo_hybrid_step_copies_no_stack_and_pads_no_arena_on_v5e(v5e, step):
         assert not re.search(rf"\[{shape}\][^ ]* (copy|transpose|pad)\(", text), name
     arena_layer = 2 * math.prod(cache.k.shape[1:])  # one layer of the bf16 K stack: 268 MB
     assert mem.temp_size_in_bytes < arena_layer // 4, mem.temp_size_in_bytes
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs its equations hold."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub)
+
+
+@pytest.mark.parametrize("model", ["kimi-5l", "olmo-hybrid-4l"])
+def test_hybrid_prefill_inverts_its_triangles_before_the_scan_over_chunks(model):
+    """The 256-row prefill program of both hybrid families as traced (no
+    compile): nowhere a ``triangular_solve`` (on the chip XLA expands it to an
+    ``InvertDiagBlocksLowerTriangular`` custom call that was 27 % of
+    Olmo-Hybrid's chunk and half of Kimi's chunked rule; the two compiles above
+    look for that name), and the scan over the launch's four chunks of 64
+    takes the inverse-applied operands and the query scores as ``xs`` — ``[4,
+    1, H, 64, 64]`` among them — and its body holds four matmuls (three
+    against the carried state, one against ``U``) and nothing that builds a
+    score matrix, a mask or an inverse: no ``exp``, no ``cumsum``, no
+    comparison, no inner loop."""
+    cfg, _, _, steps = _hybrid_case(model, None)
+    fn, args = steps["prefill"]
+    eqns = list(_equations(jax.make_jaxpr(fn)(*args).jaxpr))
+    assert "triangular_solve" not in {e.primitive.name for e in eqns}
+    scores = (4, 1, cfg.kda_heads, 64, 64)
+    chunk_scans = [
+        e for e in eqns
+        if e.primitive.name == "scan" and e.params["length"] == 4 and scores in [v.aval.shape for v in e.invars]
+    ]
+    assert len(chunk_scans) == 1  # the layer scan traces the linear mixer once
+    body = [e.primitive.name for e in _equations(chunk_scans[0].params["jaxpr"].jaxpr)]
+    assert body.count("dot_general") == 4, body
+    assert not {"exp", "cumsum", "iota", "select_n", "while", "scan", "lt", "ge", "gt", "eq"} & set(body), body

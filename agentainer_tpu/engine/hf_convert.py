@@ -87,26 +87,45 @@ def config_from_hf(path: str | Path) -> ModelConfig:
     """Derive a ModelConfig from the checkpoint's own config.json."""
     path = Path(path).expanduser()
     doc = json.loads((path / "config.json").read_text())
-    # Mixtral publishes ``num_local_experts``, OLMoE ``num_experts``
-    n_experts = int(doc.get("num_local_experts") or doc.get("num_experts") or 0)
+    # Mixtral publishes ``num_local_experts``, OLMoE ``num_experts``,
+    # SmallThinker ``moe_num_primary_experts``
+    smallthinker = "moe_num_primary_experts" in doc
+    n_experts = int(
+        doc.get("num_local_experts") or doc.get("num_experts") or doc.get("moe_num_primary_experts") or 0
+    )
+    heads, dim = int(doc["num_attention_heads"]), int(doc["hidden_size"])
+    # a head width the model states is kept only where it is not the derived one
+    head_size = int(doc.get("head_dim") or 0)
+    window_layers = tuple(int(x) for x in doc.get("sliding_window_layout") or ())
     return ModelConfig(
         name=doc.get("model_type", "hf") + "-import",
         vocab_size=int(doc["vocab_size"]),
-        dim=int(doc["hidden_size"]),
+        dim=dim,
         n_layers=int(doc["num_hidden_layers"]),
-        n_heads=int(doc["num_attention_heads"]),
-        n_kv_heads=int(doc.get("num_key_value_heads", doc["num_attention_heads"])),
-        ffn_dim=int(doc["intermediate_size"]),
+        n_heads=heads,
+        n_kv_heads=int(doc.get("num_key_value_heads", heads)),
+        head_size=head_size if head_size * heads != dim else 0,
+        ffn_dim=int(doc.get("intermediate_size") or doc["moe_ffn_hidden_size"]),
         max_seq_len=int(doc.get("max_position_embeddings", 8192)),
         rope_theta=float(doc.get("rope_theta", 500_000.0)),
         norm_eps=float(doc.get("rms_norm_eps", 1e-5)),
         n_experts=n_experts,
-        experts_per_token=int(doc.get("num_experts_per_tok", 2)),
+        experts_per_token=int(doc.get("num_experts_per_tok") or doc.get("moe_num_active_primary_experts") or 2),
         # Mixtral has no such key and always renormalises; OLMoE states it
         moe_renormalize=bool(doc.get("norm_topk_prob", True)),
         # no config.json key states it: the checkpoint has the norm's weights
         # or it has not (a model's name decides nothing here)
         qk_norm="model.layers.0.self_attn.q_norm.weight" in _open_shards(path),
+        # the per-layer layouts, as published (the keys the benchmark's
+        # ``families/smallthinker.model_config`` reads)
+        window=int(doc.get("sliding_window_size") or 0) if any(window_layers) else 0,
+        window_layers=window_layers if any(window_layers) else (),
+        rope_layers=tuple(int(x) for x in doc.get("rope_layout") or ()),
+        # no key of that config.json states the gate's activation or where the
+        # router reads: they follow the keys only that block publishes (ReGLU
+        # and the layer's input; ``moe_enable_early_router`` where it is given)
+        ffn_act="relu" if smallthinker else "silu",
+        early_router=bool(doc.get("moe_enable_early_router", smallthinker)),
     )
 
 
@@ -119,6 +138,13 @@ def load_hf_params(
     device_puts them with its target sharding, so a TP-sharded model is
     never materialized whole on one chip's HBM — required when the weights
     only fit *because* of TP."""
+    if cfg.early_router or cfg.ffn_act != "silu":
+        # config_from_hf reads that block's config.json; its tensors' names
+        # (experts, router) wait for a checkpoint to check them against
+        raise NotImplementedError(
+            "no checkpoint key mapping for a block with an early router or a ReGLU gate yet: "
+            "it is served with synthetic weights"
+        )
     p = Path(path).expanduser().resolve()
     ld = _Loader(p)
 

@@ -33,6 +33,7 @@ import os
 import queue
 import threading
 import time
+import weakref
 from dataclasses import dataclass, field
 from typing import Any
 
@@ -64,6 +65,7 @@ from ..models.llama import (
     init_cache,
     init_params,
     moe_sorted_from,
+    ring_plan,
 )
 from ..ops.attention import pages_to_rows, plan_cache_attention, rows_to_pages
 from ..ops.moe import row_tile, sorted_rows
@@ -138,21 +140,48 @@ _RECURRENT_OFF = {
 }
 
 
+# A window layer's leaf is a ring (models/llama.WindowKVCache): the row of
+# position p is ``p mod R``, a lane keeps its last R rows of such a layer, and
+# a launch may write at most ``R - window + 1`` rows of one lane. Its rows are
+# positional, so snapshots, restores, eviction, re-admission, parking and the
+# mixed step hold (the ring ships whole). What reads or copies rows BY POSITION
+# FROM 0, or writes more rows a launch than the ring was sized for, does not,
+# until the mechanism named with it exists (ROADMAP Reach B2).
+_WINDOW_OFF = {
+    "speculative": "the verify launch and its rewind are not taken through the ring: its rows a launch (and the fused rung's) are not in the ring's size, and no verify program reads the ring's lower bound",
+    "fused_decode": "the fused loop's in-loop speculation writes drafted rows and rewinds; its masks are the dense arena's, not the ring's",
+    "paged_kv": "the page pool gives every layer a page for every position; window layers would need a pool that takes a page back once the window has passed it",
+    "kv_tiering": "the host tier moves k and v rows from position 0; a ring leaf beside them is not parked or promoted",
+    "prefix_cache": "a fork copies rows 0..n of every layer into a fresh lane; a ring keeps only the last R rows, so a fork would have to copy the last R rows at the boundary, and none are kept there",
+    "mesh": "the shard_map'd kernels and GSPMD's einsum path take the dense arena; the ring's index maps are the one-chip kernels'",
+}
+
+
 def cache_features(cfg: ModelConfig, asked: dict) -> tuple[dict, dict]:
     """``asked``: feature → what the caller gave (``None``: nothing, take
     the default). Returns (feature → on/off, feature → reason it is off for
     this family). Raises where a feature this family's cache cannot hold was
     asked for by name."""
     defaults = {"speculative": True, "prefix_cache": True}
-    if not cfg.is_hybrid:
+    off, what = _cache_off(cfg)
+    if off is None:
         return {k: bool(defaults.get(k, False) if v is None else v) for k, v in asked.items()}, {}
     refused = [k for k, v in asked.items() if v]
     if refused:
         raise ValueError(
-            f"model {cfg.name!r} keeps a recurrent state in its cache; not served with it: "
-            + "; ".join(f"{k} ({_RECURRENT_OFF[k]})" for k in refused)
+            f"model {cfg.name!r} {what}; not served with it: "
+            + "; ".join(f"{k} ({off[k]})" for k in refused)
         )
-    return {k: False for k in asked}, {k: _RECURRENT_OFF[k] for k in asked}
+    return {k: False for k in asked}, {k: off[k] for k in asked}
+
+
+def _cache_off(cfg: ModelConfig) -> tuple[dict | None, str]:
+    """The features a family's cache turns off, with what the cache is."""
+    if cfg.is_hybrid:
+        return _RECURRENT_OFF, "keeps a recurrent state in its cache"
+    if cfg.n_window:
+        return _WINDOW_OFF, "keeps its window layers' rows in a ring"
+    return None, ""
 
 
 def fleet_default_applies(config_name: str, feature: str) -> bool:
@@ -165,7 +194,7 @@ def fleet_default_applies(config_name: str, feature: str) -> bool:
         cfg = get_config(config_name)
     except KeyError:
         return True
-    return not (cfg.is_hybrid and feature in _RECURRENT_OFF)
+    return feature not in (_cache_off(cfg)[0] or ())
 
 
 class SnapshotDeferred(Exception):
@@ -575,10 +604,19 @@ class LLMEngine:
         )
         speculative, prefix_cache = feats["speculative"], feats["prefix_cache"]
         self._recurrent = cfg.is_hybrid
+        # window layers beside global ones: a ring leaf beside the arena
+        self._windowed = bool(cfg.n_window)
+        # a cache of NAMED leaves (the hybrid block's, or k, v + the ring):
+        # snapshots and restores move a dict of them, not the pair (k, v)
+        self._named_leaves = self._recurrent or self._windowed
         if self._cache_off:
+            kinds = (
+                f"kinds={'+'.join(sorted(set(cfg.layer_kinds)))} (positional rows + per-lane state)"
+                if self._recurrent
+                else f"global rows x{cfg.n_global} + a ring of the last rows x{cfg.n_window} (window {cfg.window})"
+            )
             print(
-                f"[llm-engine] cache: kinds={'+'.join(sorted(set(cfg.layer_kinds)))} "
-                f"(positional rows + per-lane state); off for this family: "
+                f"[llm-engine] cache: {kinds}; off for this family: "
                 + "; ".join(f"{k}: {v}" for k, v in self._cache_off.items()),
                 flush=True,
             )
@@ -742,7 +780,10 @@ class LLMEngine:
                     with jax.default_device(dev):
                         # the model builds its cache; an engine's lanes of a
                         # recurrent state start closed
-                        c = init_cache(cfg, max_batch, max_seq, dtype=dtype, live=False)
+                        c = init_cache(
+                            cfg, max_batch, max_seq, dtype=dtype, live=False,
+                            **ring_plan(cfg, dtype, self.prefill_chunk),
+                        )
                     return jax.device_put(c, dev)
 
             self._alloc_cache = _alloc_single
@@ -985,6 +1026,7 @@ class LLMEngine:
         self.snapshot_busy_gap_s = 10.0
         # gap-free first snapshot, but the force timer starts fresh
         self._last_snapshot_at = time.monotonic() - self.snapshot_min_gap_s
+        self._staged_leaf = None  # weakref to the last staged snapshot's first leaf
         # session → SnapshotCmd parked until the session's request settles
         self._snap_parked: dict[str, SnapshotCmd] = {}
         # per-session staging times for the durability floor (bounded: one
@@ -1008,6 +1050,9 @@ class LLMEngine:
             self._kv_bytes_per_pos = (
                 2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim * cache.k.dtype.itemsize
             )
+        # of it, what the window layers add: read for the last ``window``
+        # positions only (``_kv_read_bytes``)
+        self._kv_bytes_per_pos_window = self._kv_bytes_per_pos * cfg.n_window // cfg.n_layers
         # cache-manager counters of the per-lane state (``cache`` in /metrics)
         self.state_resets = 0
         self.state_snapshots = 0
@@ -1368,6 +1413,26 @@ class LLMEngine:
         self.attention.update(
             decode_block_positions=self._decode_bk, decode_blocks_live=0, decode_blocks_stored=0
         )
+        if self._windowed:
+            # the same count by kind of layer (a layer of each kind counted
+            # once): ``global_*`` is what the first pair counts; ``window_*``
+            # the ring blocks the lower and upper bounds let through against
+            # the blocks an UNBOUNDED read of the same lanes would fetch (a
+            # window layer that kept its whole context); lanes whose position
+            # passed the ring's length (``window_wraps``, counted once a
+            # request) and the rows a lane keeps of each kind
+            wk = self.cache.wk
+            self._ring_rows = wk.shape[2]
+            self._ring_bk = decode_kv_block(wk.shape[3], wk.shape[4], wk.dtype, wk.shape[2])[1]
+            self.attention.update(
+                window=cfg.window, window_layers=cfg.n_window, global_layers=cfg.n_global,
+                window_rows=self._ring_rows, global_rows=self.max_seq,
+                window_block_positions=self._ring_bk,
+                global_decode_blocks_live=0, global_decode_blocks_stored=0,
+                window_decode_blocks_live=0, window_decode_blocks_unbounded=0,
+                window_decode_blocks_stored=0, window_wraps=0,
+                global_decode_rows=0, window_decode_rows=0,
+            )
         self.meshed_flash = (not self._recurrent) and "shard_map" in attn.decode
         if self._recurrent:
             print(
@@ -1624,6 +1689,14 @@ class LLMEngine:
             self._lane_leaves = functools.partial(hybrid.snapshot_lane, n_kv_heads=cfg.n_kv_heads)
             self._restore_lane = hybrid.restore_lane
             self._positional = hybrid.HybridCache.POSITIONAL
+        elif self._windowed:
+            from ..models import llama
+
+            # a lane's leaves as the model ships them: the global layers'
+            # rows up to the position's bucket, the ring whole
+            self._lane_leaves = llama.snapshot_lane
+            self._restore_lane = llama.restore_lane
+            self._positional = ("k", "v")
         # the verify ladder reuses the same forward (one prefill-shaped call
         # with t = k+1 per round); fns are built per bucket on demand and
         # warmed alongside the decode ladder
@@ -2254,13 +2327,20 @@ class LLMEngine:
         leaves, position, pending_token = staged
         from .checkpoint import pack_snapshot
 
+        # read the staged buffers to the host and let go of them BEFORE the
+        # slow half (the npz compression of an incompressible 0.8 GB takes
+        # tens of seconds): a staged lane then stands on the device for a
+        # transfer's time, and the next staging waits only for that
+        # (``_staged_on_device``)
+        leaves = await asyncio.to_thread(lambda staged: {n: np.asarray(a) for n, a in staged.items()}, leaves)
+        del staged, cmd  # the future's result is the last holder of the device buffers
         meta = {"session": session, "pending_token": pending_token}
         if self.paged:
             # staged from live pages only (ceil(position/page_size) pages,
             # not a pow2 position bucket); payload layout is identical to
             # the dense staging so blobs restore across both arenas
             meta["page_size"] = self.page_size
-        positional = self._positional if self._recurrent else ("k", "v")
+        positional = self._positional if self._named_leaves else ("k", "v")
         return await asyncio.to_thread(pack_snapshot, leaves, position, meta, positional)
 
     def _do_snapshot(self, cmd: SnapshotCmd) -> None:
@@ -2324,12 +2404,24 @@ class LLMEngine:
         # the per-session floor degrades gracefully to ~n_sessions×busy_gap
         gap = self.snapshot_busy_gap_s if busy else self.snapshot_min_gap_s
         gap_ok = now - self._last_snapshot_at >= gap
-        return (not gap_ok) or (busy and not overdue)
+        return self._staged_on_device() or (not gap_ok) or (busy and not overdue)
+
+    def _staged_on_device(self) -> bool:
+        """Does the last staged snapshot still stand on the device? Its
+        leaves are fresh device buffers until the caller has read them to
+        the host (``snapshot_session``); a lane of a 16k arena is most of a
+        GB (0.8 GB where window layers ship their ring whole), and staging
+        the next one meanwhile stacks them on the arena's headroom."""
+        held = self._staged_leaf
+        return held is not None and held() is not None
+
+    def _hold_staged(self, leaves: dict) -> None:
+        self._staged_leaf = weakref.ref(next(iter(leaves.values())))
 
     def _staged_bytes(self, cmd: SnapshotCmd, slot: Slot) -> dict:
         """``bytes="leaf=n,..."`` of one lane staged at the slot's bucket: what
         each kind of leaf ships, for a cache of several kinds."""
-        if not self._recurrent or slot.position <= 0:
+        if not self._named_leaves or slot.position <= 0:
             return {}
         bucket = self._snap_bucket(slot.position)
         known = self._staged_bytes_by_bucket.get(bucket)
@@ -2353,7 +2445,7 @@ class LLMEngine:
             leaves = self._snap_fn(self._snap_bucket(slot.position))(
                 self.cache, jnp.int32(slot.idx)
             )
-            if not self._recurrent:
+            if not self._named_leaves:
                 leaves = dict(zip(("k", "v"), leaves))
             else:
                 self.state_snapshots += 1
@@ -2362,6 +2454,7 @@ class LLMEngine:
                     leaf.copy_to_host_async()
             except Exception:
                 pass
+            self._hold_staged(leaves)
             staged = (leaves, slot.position, slot.pending_token)
         cmd.loop.call_soon_threadsafe(_resolve_value, cmd.future, staged)
 
@@ -2388,6 +2481,7 @@ class LLMEngine:
             except Exception:
                 pass
             staged = ({"k": k16, "v": v16}, sess.position, sess.pending_token)
+            self._hold_staged(staged[0])
         cmd.loop.call_soon_threadsafe(_resolve_value, cmd.future, staged)
 
     def _service_parked_snapshot(self, slot: Slot) -> None:
@@ -2430,9 +2524,9 @@ class LLMEngine:
                 # restore (found by the chaos soak's resume invariant).
                 # bf16/fp16 caches ship 2 bytes/elem as before; fp32 CPU
                 # caches pay 2x blob size for exactness.
-                if self._recurrent:
+                if self._named_leaves:
                     # named leaves: the positional rows up to the bucket,
-                    # the per-lane state whole (it cannot be trimmed)
+                    # the per-lane state (or a window layer's ring) whole
                     return self._lane_leaves(cache, i, _b)
                 k = lax.dynamic_slice_in_dim(cache.k, i, 1, axis=1)[:, 0, :_b]
                 v = lax.dynamic_slice_in_dim(cache.v, i, 1, axis=1)[:, 0, :_b]
@@ -3626,6 +3720,19 @@ class LLMEngine:
         }
 
     def _cache_metrics(self) -> dict:
+        if self._windowed:
+            sizes = {
+                "kv_bytes": self.cache.k.nbytes + self.cache.v.nbytes,
+                "kv_ring_bytes": self.cache.wk.nbytes + self.cache.wv.nbytes,
+            }
+            return {
+                "kinds": ["kv", "kv_ring"],
+                **sizes,
+                "bytes_per_lane": sum(sizes.values()) // self.max_batch,
+                "state_snapshots": self.state_snapshots,
+                "state_restores": self.state_restores,
+                "off": dict(self._cache_off),
+            }
         if not self._recurrent:
             return {
                 "kinds": ["kv"],
@@ -4246,7 +4353,7 @@ class LLMEngine:
             self._staged_lane = None
 
     def _restored_bytes(self, cmd: RestoreCmd) -> dict:
-        if not self._recurrent or not cmd.leaves:
+        if not self._named_leaves or not cmd.leaves:
             return {}
         return {"bytes": ",".join(f"{n}={np.asarray(a).nbytes}" for n, a in cmd.leaves.items())}
 
@@ -4256,7 +4363,7 @@ class LLMEngine:
 
         ok = False
         try:
-            if self._recurrent:
+            if self._named_leaves:
                 ok = self._do_restore_state(cmd)
                 return
             if cmd.k is None or cmd.v is None or set(cmd.leaves or ("k", "v")) != {"k", "v"}:
@@ -4692,6 +4799,12 @@ class LLMEngine:
         elif not self.routed_moe:
             self.moe["rows_all_experts"] += passes * rows * self.cfg.n_held
 
+    def _kv_read_bytes(self, position: float) -> float:
+        """Bytes of cache a row at ``position`` reads (the host's MBU model):
+        every layer's rows up to it, a window layer's for its window only."""
+        past = max(0.0, position - self.cfg.window) if self._windowed else 0.0
+        return position * self._kv_bytes_per_pos - past * self._kv_bytes_per_pos_window
+
     def _count_decode_blocks(self, positions: list[int], steps: int) -> None:
         """A decode launch of ``steps`` steps whose stepping lanes start at
         ``positions``: the K/V blocks ``flash_decode`` fetches (a lane at
@@ -4703,9 +4816,25 @@ class LLMEngine:
         if not bk:
             return
         pos = np.asarray(positions, np.int64).reshape(-1, 1) + np.arange(steps)
-        live = np.where(pos >= self.max_seq - 1, 0, pos) // bk + 1
-        self.attention["decode_blocks_live"] += int(live.sum()) + steps * (self.max_batch - len(positions))
-        self.attention["decode_blocks_stored"] += steps * self.max_batch * -(-self.max_seq // bk)
+        pos = np.where(pos >= self.max_seq - 1, 0, pos)
+        parked = steps * (self.max_batch - len(positions))
+        live = int((pos // bk + 1).sum()) + parked
+        stored = steps * self.max_batch * -(-self.max_seq // bk)
+        self.attention["decode_blocks_live"] += live
+        self.attention["decode_blocks_stored"] += stored
+        if self._windowed:
+            a, wb = self.attention, self._ring_bk
+            first = np.maximum(pos - (self.cfg.window - 1), 0)
+            ring = np.minimum(pos // wb - first // wb + 1, -(-self._ring_rows // wb))
+            a["global_decode_blocks_live"] += live
+            a["global_decode_blocks_stored"] += stored
+            a["window_decode_blocks_live"] += int(ring.sum()) + parked
+            a["window_decode_blocks_unbounded"] += int((pos // wb + 1).sum()) + parked
+            a["window_decode_blocks_stored"] += steps * self.max_batch * -(-self._ring_rows // wb)
+            # the rows the stepping lanes' queries see, as the equations name
+            # them (a parked lane's query sees nothing anyone needs)
+            a["global_decode_rows"] += int((pos + 1).sum())
+            a["window_decode_rows"] += int(np.minimum(pos + 1, self.cfg.window).sum())
 
     def _bucket(self, n: int) -> int:
         for b in PREFILL_BUCKETS:
@@ -4854,7 +4983,7 @@ class LLMEngine:
         # n real tokens, each attending ~its own position of context
         self.flops_done += n * self.cfg.flops_per_token(slot.position + n // 2)
         self.hbm_bytes_read += self.param_hbm_bytes + (
-            (slot.position + n // 2) * self._kv_bytes_per_pos
+            self._kv_read_bytes(slot.position + n // 2)
         )
         slot.position += n
         slot.last_used = time.monotonic()
@@ -4970,6 +5099,8 @@ class LLMEngine:
         slot.request = None
         slot.last_used = time.monotonic()
         slot.pending_token = (req.generated[-1] if req.generated else None) if pending_last else None
+        if self._windowed and slot.position > self._ring_rows:
+            self.attention["window_wraps"] += 1  # this context lapped the ring
         # fold the reply into the drafting corpus; a held-out pending token
         # re-arrives via the next turn's prompt, so it is excluded here
         slot.spec_hist.extend(
@@ -5111,7 +5242,7 @@ class LLMEngine:
         # weights stream once per scan step; each live lane streams its KV
         # prefix (parked lanes re-read the scratch row — not useful traffic)
         self.hbm_bytes_read += (0 if rode else chunk * self.param_hbm_bytes) + sum(
-            chunk * (p + chunk // 2) * self._kv_bytes_per_pos for _, _, p in snapshot
+            chunk * self._kv_read_bytes(p + chunk // 2) for _, _, p in snapshot
         )
         try:
             toks.copy_to_host_async()
@@ -5895,7 +6026,7 @@ class LLMEngine:
         # executed step count is data-dependent: weights stream once per
         # while_loop iteration actually run, plus each lane's KV prefix
         self.hbm_bytes_read += steps * self.param_hbm_bytes + sum(
-            steps * (p + steps // 2) * self._kv_bytes_per_pos for _, _, p, _ in snapshot
+            steps * self._kv_read_bytes(p + steps // 2) for _, _, p, _ in snapshot
         )
         eos = self.tokenizer.eos_id
         for slot, req, start, _adv in snapshot:

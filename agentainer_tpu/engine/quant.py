@@ -108,9 +108,9 @@ def synthetic_quantized_params(cfg, dtype=jnp.bfloat16, device=None, mesh=None) 
         shapes.update(
             {
                 "router": (L, d, cfg.n_experts),
-                "w_gate": (L, cfg.n_experts, d, cfg.ffn_dim),
-                "w_up": (L, cfg.n_experts, d, cfg.ffn_dim),
-                "w_down": (L, cfg.n_experts, cfg.ffn_dim, d),
+                "w_gate": (L, cfg.n_held, d, cfg.ffn_dim),
+                "w_up": (L, cfg.n_held, d, cfg.ffn_dim),
+                "w_down": (L, cfg.n_held, cfg.ffn_dim, d),
             }
         )
     else:

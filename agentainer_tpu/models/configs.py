@@ -84,10 +84,54 @@ class ModelConfig:
     # router keeps all ``n_experts`` outputs. 0: every expert is held
     experts_held: int = 0
     expert_offset: int = 0
+    # -- the K/V block's per-layer switches; every default is "as Llama" ------
+    # width of one attention head where the model states it (SmallThinker:
+    # 28 heads of 128 over a hidden size of 2560). 0: ``dim // n_heads``.
+    # Read ``head_dim``; nothing outside this class derives it
+    head_size: int = 0
+    # sliding-window attention: a query at position i of a layer whose flag
+    # in ``window_layers`` is 1 sees keys j with 0 <= i - j < ``window``; the
+    # other layers see their whole context. ``window_layers`` has one 0/1
+    # flag a layer, or is empty (no window layer: the block of every model
+    # before this field). The cache of a window layer is a ring of
+    # ``models/llama.ring_rows`` rows a lane, not ``max_seq``
+    window: int = 0
+    window_layers: tuple[int, ...] = ()
+    # one 0/1 flag a layer: 1 rotates q and k (rotate-half, ``rope_theta``),
+    # 0 gives the layer no positional embedding at all. Empty: every layer
+    # rotates
+    rope_layers: tuple[int, ...] = ()
+    # the gate activation of the (expert) FFN: "silu" (SwiGLU) or "relu"
+    # (ReGLU: relu(x Wg) * (x Wu))
+    ffn_act: str = "silu"
+    # the router reads the layer's INPUT (the residual stream as it enters
+    # the layer, before any norm) instead of the normed post-attention stream
+    early_router: bool = False
+
+    def __post_init__(self):
+        for name in ("window_layers", "rope_layers"):
+            flags = getattr(self, name)
+            if flags and (len(flags) != self.n_layers or set(flags) - {0, 1}):
+                raise ValueError(f"{name}: one 0/1 flag a layer ({self.n_layers}), got {flags}")
+        if any(self.window_layers) and self.window <= 0:
+            raise ValueError("window_layers names window layers and window is 0")
+        if self.ffn_act not in ("silu", "relu"):
+            raise ValueError(f"ffn_act {self.ffn_act!r}: silu or relu")
 
     @property
     def head_dim(self) -> int:
-        return self.dim // self.n_heads
+        """Width of an attention head: the model's own (``head_size``) or
+        ``dim // n_heads``. ``n_heads * head_dim`` need not be ``dim``."""
+        return self.head_size or self.dim // self.n_heads
+
+    @property
+    def n_window(self) -> int:
+        """Layers whose attention is windowed (their cache is a ring)."""
+        return sum(self.window_layers)
+
+    @property
+    def n_global(self) -> int:
+        return self.n_layers - self.n_window
 
     @property
     def is_hybrid(self) -> bool:
@@ -178,12 +222,10 @@ class ModelConfig:
                 + self.n_dense_layers * c["dense"]
                 + (n_moe * (c["moe_fixed"] + self.n_held * c["expert"]) if n_moe else 0)
             )
-        per_layer_attn = self.dim * self.dim + 2 * self.dim * (
-            self.n_kv_heads * self.head_dim
-        ) + self.dim * self.dim
+        per_layer_attn = 2 * self.dim * self.head_dim * (self.n_heads + self.n_kv_heads)
         ffn = 3 * self.dim * self.ffn_dim
         if self.is_moe:
-            ffn = self.n_experts * ffn + self.dim * self.n_experts
+            ffn = self.n_held * ffn + self.dim * self.n_experts
         per_layer = per_layer_attn + ffn + 2 * self.dim
         if self.qk_norm:
             per_layer += (self.n_heads + self.n_kv_heads) * self.head_dim
@@ -206,7 +248,8 @@ class ModelConfig:
             n_moe = self.n_layers - self.n_dense_layers
             return self.param_count() + n_moe * (self.experts_per_token - self.n_held) * c["expert"]
         full_ffn = 3 * self.dim * self.ffn_dim
-        unused = (self.n_experts - self.experts_per_token) * full_ffn
+        # a token's k routed experts, wherever they live (the model's need)
+        unused = (self.n_held - self.experts_per_token) * full_ffn
         return self.param_count() - self.n_layers * unused
 
     def flops_per_token(self, context_len: int) -> float:
@@ -231,8 +274,10 @@ class ModelConfig:
             return matmul + self.n_linear * delta + self.n_positional * positional
         # attention scores + value combine: q·K^T and p·V, each
         # 2 * heads * head_dim * context MACs → 4 FLOPs per context slot
-        attn = 4.0 * self.n_heads * self.head_dim * context_len
-        return matmul + self.n_layers * attn
+        # (a window layer sees at most its window of them)
+        attn = 4.0 * self.n_heads * self.head_dim
+        seen = self.n_global * context_len + self.n_window * min(context_len, self.window)
+        return matmul + attn * seen
 
 
 _REGISTRY: dict[str, ModelConfig] = {}
@@ -491,6 +536,72 @@ TINY_OLMO_HYBRID = register(
         post_norm=True,
         n_dense_layers=8,
         dense_ffn_dim=128,
+    )
+)
+
+# SmallThinker-21BA3B-Instruct (PowerInfer/SmallThinker-21BA3B-Instruct
+# config.json: 52 layers, hidden 2560, 28 query / 4 KV heads of 128 (28 x 128
+# = 3584: the head is its own key), layouts (0, 1, 1, 1) x 13: layers 0, 4, 8,
+# ... are global attention with NO positional embedding, the other 39 a
+# 4096-token sliding window with rotate-half RoPE (theta 1.5e6); 64 ReGLU
+# experts of 768, top-6, softmax over the chosen six, the router reading the
+# layer's input; vocabulary 151,936, untied, context 16,384). All 64 experts:
+# 21.5 B parameters — a chip serves its share (``experts_held``).
+def smallthinker_layout(n_layers: int, period: int = 4) -> tuple[int, ...]:
+    """The published ``sliding_window_layout`` and ``rope_layout`` (they are
+    the same list): every ``period``-th layer from layer 0 is global and
+    unrotated, the rest windowed and rotated."""
+    return tuple(int(i % period != 0) for i in range(n_layers))
+
+
+SMALLTHINKER_21B = register(
+    ModelConfig(
+        name="smallthinker-21b",
+        vocab_size=151_936,
+        dim=2560,
+        n_layers=52,
+        n_heads=28,
+        n_kv_heads=4,
+        head_size=128,
+        ffn_dim=768,
+        max_seq_len=16_384,
+        rope_theta=1_500_000.0,
+        norm_eps=1e-6,
+        n_experts=64,
+        experts_per_token=6,
+        moe_renormalize=True,
+        window=4096,
+        window_layers=smallthinker_layout(52),
+        rope_layers=smallthinker_layout(52),
+        ffn_act="relu",
+        early_router=True,
+    )
+)
+
+# The same block at CI shapes: two periods (G W W W G W W W), a window of 16,
+# a head of 16 over a hidden size of 48 (3 x 16 = 48 would hide the key: 6
+# heads of 16 = 96), a GQA group of 3, 8 experts top-2.
+TINY_SMALLTHINKER = register(
+    ModelConfig(
+        name="tiny-smallthinker",
+        vocab_size=512,
+        dim=48,
+        n_layers=8,
+        n_heads=6,
+        n_kv_heads=2,
+        head_size=16,
+        ffn_dim=32,
+        max_seq_len=256,
+        rope_theta=10_000.0,
+        norm_eps=1e-6,
+        n_experts=8,
+        experts_per_token=2,
+        moe_renormalize=True,
+        window=16,
+        window_layers=smallthinker_layout(8),
+        rope_layers=smallthinker_layout(8),
+        ffn_act="relu",
+        early_router=True,
     )
 )
 

@@ -23,6 +23,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from ..ops.attention import (
@@ -32,7 +33,7 @@ from ..ops.attention import (
     plan_cache_attention,
     scatter_paged_kv,
 )
-from ..ops.moe import EXPERT_WEIGHTS, sorted_from_rows, sorted_moe_ffn, stacked_experts
+from ..ops.moe import EXPERT_WEIGHTS, gate_act, sorted_from_rows, sorted_moe_ffn, stacked_experts
 from ..ops.norms import rms_norm
 from ..ops.quant import QTensor, dequant, embed_lookup
 from ..ops.rope import apply_rope
@@ -51,6 +52,75 @@ class KVCache(NamedTuple):
     ) -> "KVCache":
         shape = (cfg.n_layers, batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
         return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+
+
+class WindowKVCache(NamedTuple):
+    """The K/V arena of a model with sliding-window layers beside global
+    ones (``cfg.window_layers``), two leaves a side: ``k``/``v`` ``[Lg, B,
+    S, KV, hd]`` hold the global layers' rows as :class:`KVCache` does, and
+    ``wk``/``wv`` ``[Lw, B, R, KV, hd]`` the window layers' as a RING: the
+    row of position p is ``p mod R``, so a lane holds R rows of such a layer
+    whatever its context. ``R`` is :func:`ring_rows`."""
+
+    k: jnp.ndarray
+    v: jnp.ndarray
+    wk: jnp.ndarray
+    wv: jnp.ndarray
+
+    @staticmethod
+    def create(
+        cfg: ModelConfig, batch: int, max_seq: int, dtype: jnp.dtype = jnp.bfloat16,
+        launch_rows: int | None = None, block: int = 1,
+    ) -> "WindowKVCache":
+        if not cfg.n_global:
+            # the arena's length, and with it the row a parked lane sits at,
+            # is read off the global leaf
+            raise ValueError("a model whose every layer is windowed is not served: no global leaf")
+        r = ring_rows(cfg.window, max_seq, launch_rows, block)
+        g = (cfg.n_global, batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
+        w = (cfg.n_window, batch, r, cfg.n_kv_heads, cfg.head_dim)
+        return WindowKVCache(jnp.zeros(g, dtype), jnp.zeros(g, dtype), jnp.zeros(w, dtype), jnp.zeros(w, dtype))
+
+
+def ring_rows(window: int, max_seq: int, launch_rows: int | None = None, block: int = 1) -> int:
+    """Rows ``R`` a lane keeps of a window layer: ``window + launch_rows``,
+    rounded up to the K/V block the kernels read, and never more than the
+    arena itself rounded the same way.
+
+    Why the launch's rows on top of the window: every launch writes its new
+    rows BEFORE it reads (``_attention_block``). A launch whose rows of one
+    lane sit at positions ``s .. s + T - 1`` overwrites the ring rows of
+    positions ``s - R .. s + T - 1 - R``, and its first query still sees
+    position ``s - window + 1``: nothing a query of the launch sees is lost
+    iff ``s + T - 1 - R < s - window + 1``, i.e. ``R >= window + T - 1``.
+    ``launch_rows`` is the longest run of rows of ONE lane a launch carries
+    (an engine's prefill chunk; a bucket's padding rows count, they are
+    written too). ``None``: the arena's length, so any launch is safe and
+    the ring never wraps (direct callers, tests of the plain path). The
+    same bound makes a ring row's position a function of the launch's last
+    position alone, which is what the kernels' masks compute."""
+    up = lambda n: -(-n // block) * block  # noqa: E731
+    if launch_rows is None:
+        return up(max_seq)
+    return min(up(window + launch_rows), up(max_seq))
+
+
+def ring_plan(cfg: ModelConfig, dtype, launch_rows: int) -> dict:
+    """``init_cache``'s keywords for a caller whose launches carry at most
+    ``launch_rows`` rows of one lane (an engine's prefill chunk, bucket padding
+    included; the decode lanes add one row each, to their own lanes): the ring
+    is whole K/V blocks where the flash kernels read it. Empty for a model
+    without window layers."""
+    if not cfg.n_window:
+        return {}
+    from ..ops.attention import pallas_available
+    from ..ops.pallas_attention import ring_block
+
+    kernels = pallas_available(cfg.n_heads, cfg.n_kv_heads, cfg.head_dim)[0]
+    return {
+        "launch_rows": launch_rows,
+        "block": ring_block(cfg.n_kv_heads, cfg.head_dim, dtype) if kernels else 1,
+    }
 
 
 class PagedKVCache(NamedTuple):
@@ -80,17 +150,41 @@ class PagedKVCache(NamedTuple):
 
 
 def init_cache(
-    cfg: ModelConfig, lanes: int, max_seq: int, dtype: jnp.dtype = jnp.bfloat16, live: bool = True
+    cfg: ModelConfig, lanes: int, max_seq: int, dtype: jnp.dtype = jnp.bfloat16, live: bool = True,
+    launch_rows: int | None = None, block: int = 1,
 ):
     """The cache a model's ``forward`` reads and writes, built by the model:
-    a :class:`KVCache`, or the hybrid block's pytree (models/hybrid.py:
-    positional latent rows beside per-lane recurrent state). ``live=False``
-    starts a hybrid cache's lanes closed, as an engine wants them."""
+    a :class:`KVCache`, a :class:`WindowKVCache` where some layers are
+    windowed (``launch_rows``, ``block``: :func:`ring_rows`), or the hybrid
+    block's pytree (models/hybrid.py: positional latent rows beside per-lane
+    recurrent state). ``live=False`` starts a hybrid cache's lanes closed, as
+    an engine wants them."""
     if cfg.is_hybrid:
         from . import hybrid
 
         return hybrid.init_cache(cfg, lanes, max_seq, dtype, live=live)
+    if cfg.n_window:
+        return WindowKVCache.create(cfg, lanes, max_seq, dtype, launch_rows, block)
     return KVCache.create(cfg, lanes, max_seq, dtype=dtype)
+
+
+def snapshot_lane(cache: WindowKVCache, lane, bucket: int) -> dict:
+    """Lane ``lane``'s leaves as a snapshot ships them: the global layers'
+    rows up to ``bucket`` positions, the window layers' ring whole (its R
+    rows are the last R positions wherever the lane stands)."""
+    def row(a):
+        return lax.dynamic_index_in_dim(a, lane, 1, keepdims=False)
+
+    return {"k": row(cache.k)[:, :bucket], "v": row(cache.v)[:, :bucket], "wk": row(cache.wk), "wv": row(cache.wv)}
+
+
+def restore_lane(cache: WindowKVCache, lane, leaves: dict) -> WindowKVCache:
+    """Write a snapshot's leaves back into lane ``lane`` (the global rows
+    from position 0, the ring whole)."""
+    def put(arena, value):
+        return lax.dynamic_update_slice(arena, value[:, None].astype(arena.dtype), (0, lane, 0, 0, 0))
+
+    return WindowKVCache(*(put(getattr(cache, n), leaves[n]) for n in WindowKVCache._fields))
 
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype = jnp.bfloat16) -> dict:
@@ -121,9 +215,9 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype = jnp.bfloat1
         layers.update(
             {
                 "router": w(next(keys), cfg.n_layers, d, cfg.n_experts),
-                "w_gate": w(next(keys), cfg.n_layers, cfg.n_experts, d, cfg.ffn_dim),
-                "w_up": w(next(keys), cfg.n_layers, cfg.n_experts, d, cfg.ffn_dim),
-                "w_down": w(next(keys), cfg.n_layers, cfg.n_experts, cfg.ffn_dim, d),
+                "w_gate": w(next(keys), cfg.n_layers, cfg.n_held, d, cfg.ffn_dim),
+                "w_up": w(next(keys), cfg.n_layers, cfg.n_held, d, cfg.ffn_dim),
+                "w_down": w(next(keys), cfg.n_layers, cfg.n_held, cfg.ffn_dim, d),
             }
         )
     else:
@@ -142,9 +236,9 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype = jnp.bfloat1
     }
 
 
-def _mlp(x: jnp.ndarray, lp: dict) -> jnp.ndarray:
-    """SwiGLU."""
-    gate = jax.nn.silu(x @ lp["w_gate"])
+def _mlp(x: jnp.ndarray, lp: dict, act: str = "silu") -> jnp.ndarray:
+    """SwiGLU (ReGLU under ``act="relu"``)."""
+    gate = gate_act(act)(x @ lp["w_gate"])
     return (gate * (x @ lp["w_up"])) @ lp["w_down"]
 
 
@@ -200,7 +294,7 @@ def _moe_mlp(x: jnp.ndarray, lp: dict, cfg: ModelConfig, logits: jnp.ndarray | N
         chosen = chosen - cfg.expert_offset
     onehot = jax.nn.one_hot(chosen, cfg.n_held, dtype=x.dtype)  # [B,T,K,E held]
     combine = jnp.einsum("btk,btke->bte", weights, onehot)  # [B,T,E]
-    gate = jax.nn.silu(jnp.einsum("btd,edf->btef", x, lp["w_gate"]))
+    gate = gate_act(cfg.ffn_act)(jnp.einsum("btd,edf->btef", x, lp["w_gate"]))
     up = jnp.einsum("btd,edf->btef", x, lp["w_up"])
     expert_out = jnp.einsum("btef,efd->bted", gate * up, lp["w_down"])
     return jnp.einsum("bted,bte->btd", expert_out, combine)
@@ -232,7 +326,7 @@ def _moe_mlp_sorted(
         # expert -1 is nobody's: dropped before the sort, like an absent one
         chosen = jnp.where(routed[:, None], chosen, -1)
     held = (cfg.expert_offset, cfg.n_experts) if cfg.experts_held or routed is not None else None
-    return sorted_moe_ffn(xf, gates, chosen, experts, layer, held=held).reshape(b, t, d)
+    return sorted_moe_ffn(xf, gates, chosen, experts, layer, held=held, act=cfg.ffn_act).reshape(b, t, d)
 
 
 def moe_sorted_from(cfg: ModelConfig, layers: dict) -> int | None:
@@ -337,7 +431,7 @@ def _moe_mlp_routed(
     disp_tok = disp.sum(1).astype(x.dtype)  # [N, E_loc, C]
     combine_tok = (disp * weights[..., None, None]).sum(1).astype(x.dtype)
     xe = jnp.einsum("nd,nec->ecd", xf, disp_tok)  # gather into [E_loc, C, D]
-    gate = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, w_gate))
+    gate = gate_act(cfg.ffn_act)(jnp.einsum("ecd,edf->ecf", xe, w_gate))
     up = jnp.einsum("ecd,edf->ecf", xe, lp["w_up"])
     out_buf = jnp.einsum("ecf,efd->ecd", gate * up, lp["w_down"])
     out = jnp.einsum("ecd,nec->nd", out_buf, combine_tok)  # weighted scatter
@@ -359,6 +453,70 @@ def _seen(positions: jnp.ndarray, arena_len: int) -> jnp.ndarray:
     return jnp.where(_parked(positions, arena_len), 0, positions)
 
 
+def _ring_index(positions: jnp.ndarray, ring: int, arena_len: int) -> jnp.ndarray:
+    """Where a window layer's ring keeps the rows of ``positions``: ``p mod
+    R``. A row at or past the arena's last row is nobody's (a parked lane, a
+    bucket's padding run past the arena) and goes nowhere: index ``R`` is out
+    of range and the scatter drops it, where the global leaf has its own last
+    row for such writes; in a ring that row would be a live position's."""
+    return jnp.where(positions >= arena_len - 1, ring, positions % ring)
+
+
+def _cache_attention(
+    q, k, v, ck, cv, positions, cache_attn_impl, block_table, layer, slot,
+    chunk_positions=None, lane_positions=None, window: int = 0, arena_len: int | None = None,
+):
+    """Write a layer's new rows into its leaf of the stacked arena and attend
+    over it: ``(attn [B, T, H, hd], ck, cv)``. ``window``: the leaf is a ring
+    of ``ck.shape[2]`` rows (``WindowKVCache``) and a row sees the last
+    ``window`` positions; ``arena_len`` is then the GLOBAL leaf's length (where
+    parked lanes sit). Without ``window`` this is the dense arena's path,
+    traced as it always was."""
+    b, t = q.shape[:2]
+    kw = {"window": window} if window else {}
+    if arena_len is None:
+        arena_len = ck.shape[2]
+    if window:
+        ring = ck.shape[2]
+
+        def at(p):
+            return _ring_index(p, ring, arena_len)
+    else:
+        def at(p):
+            return p
+    if lane_positions is not None:
+        n_lanes = lane_positions.shape[0]
+        tc = t - n_lanes
+
+        def groups(a):  # the chunk's rows [1, T, ...], the lanes' [B, 1, ...]
+            return a[:, :tc], a[0, tc:, None]
+
+        (qc, ql), (kc, kl), (vc, vl) = groups(q), groups(k), groups(v)
+        lanes = jnp.arange(n_lanes)[:, None]
+        ck = ck.at[layer, slot, at(chunk_positions)].set(kc).at[layer, lanes, at(lane_positions)].set(kl)
+        cv = cv.at[layer, slot, at(chunk_positions)].set(vc).at[layer, lanes, at(lane_positions)].set(vl)
+        attn_c = cache_attn_impl(qc, ck, cv, chunk_positions, None, layer, slot, **kw)
+        attn_l = cache_attn_impl(ql, ck, cv, _seen(lane_positions, arena_len), None, layer, None, **kw)
+        attn = jnp.concatenate([attn_c, attn_l.reshape(1, n_lanes, *q.shape[2:])], axis=1)
+    else:
+        if layer is None:
+            raise ValueError("a cache is the stacked arena: say which layer of it")
+        if block_table is not None:
+            # paged arena: write through the block table into pool pages —
+            # same masking rule, same numbers as the dense scatter below
+            ck, cv = scatter_paged_kv(ck, cv, k, v, block_table, positions, layer)
+        else:
+            # scatter this step's K/V rows, and only them, into the stack at
+            # per-sequence positions (rows past S — bucket padding — drop)
+            rows = jnp.arange(b)[:, None] + (0 if slot is None else slot)
+            ck = ck.at[layer, rows, at(positions)].set(k)
+            cv = cv.at[layer, rows, at(positions)].set(v)
+            if t == 1:
+                positions = _seen(positions, arena_len)
+        attn = cache_attn_impl(q, ck, cv, positions, block_table, layer, slot, **kw)
+    return attn, ck, cv
+
+
 def _attention_block(
     x: jnp.ndarray,
     lp: dict,
@@ -373,6 +531,8 @@ def _attention_block(
     layer=None,
     slot=None,
     lane_positions=None,
+    ring=None,
+    kind=None,
 ):
     """One layer's attention. With a cache, ``ck``/``cv`` are the STACKED
     arena ``[L, B, S, KV, hd]`` (or page pool) and ``layer`` this layer's
@@ -385,11 +545,19 @@ def _attention_block(
     for arena row ``slot`` and then one row for each of the arena's B lanes
     (``forward``'s second group). The projections and the rotary embedding
     run over all of them at once; both groups' new rows are written before
-    either is read, and each group's attention reads its own arena rows."""
+    either is read, and each group's attention reads its own arena rows.
+
+    A model with window layers (``cfg.window_layers``): ``kind = (windowed,
+    rotary)``, this layer's two flags as the layer scan hands them (traced
+    scalars), ``ring = (wk, wv)`` the window layers' leaf beside the global
+    layers' ``ck``/``cv``, and ``layer`` the layer's index in ITS leaf. Returns
+    ``ring`` as a fourth value then."""
     b, t, d = x.shape
     if lane_positions is not None:
         chunk_positions = positions
         positions = jnp.concatenate([positions, lane_positions.reshape(1, -1)], axis=1)
+    else:
+        chunk_positions = None
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     q, k = h @ lp["wq"], h @ lp["wk"]
     if cfg.qk_norm:
@@ -401,8 +569,14 @@ def _attention_block(
     q = q.reshape(b, t, cfg.n_heads, cfg.head_dim)
     k = k.reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
     v = (h @ lp["wv"]).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if kind is None:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+    else:
+        # a layer without the flag has no positional embedding at all
+        windowed, rotary = kind[:2]
+        q = jnp.where(rotary, apply_rope(q, positions, cfg.rope_theta), q)
+        k = jnp.where(rotary, apply_rope(k, positions, cfg.rope_theta), k)
 
     if ck is not None and cache_attn_impl is None:
         # engines choose once at build and pass their choice in (it is
@@ -414,41 +588,51 @@ def _attention_block(
             page_size=ck.shape[3] if block_table is not None else 0,
             use_pallas=use_flash,
         ).fn
-    if lane_positions is not None:
-        n_lanes = lane_positions.shape[0]
-        tc = t - n_lanes
+    if ck is not None and kind is not None:
+        arena_len = ck.shape[2]
 
-        def groups(a):  # the chunk's rows [1, T, ...], the lanes' [B, 1, ...]
-            return a[:, :tc], a[0, tc:, None]
+        def attend(leaf_k, leaf_v, window):
+            return _cache_attention(
+                q, k, v, leaf_k, leaf_v, positions if chunk_positions is None else None,
+                cache_attn_impl, None, layer, slot, chunk_positions, lane_positions,
+                window=window, arena_len=arena_len,
+            )
 
-        (qc, ql), (kc, kl), (vc, vl) = groups(q), groups(k), groups(v)
-        lanes = jnp.arange(n_lanes)[:, None]
-        ck = ck.at[layer, slot, chunk_positions].set(kc).at[layer, lanes, lane_positions].set(kl)
-        cv = cv.at[layer, slot, chunk_positions].set(vc).at[layer, lanes, lane_positions].set(vl)
-        attn_c = cache_attn_impl(qc, ck, cv, chunk_positions, None, layer, slot)
-        attn_l = cache_attn_impl(ql, ck, cv, _seen(lane_positions, ck.shape[2]), None, layer, None)
-        attn = jnp.concatenate([attn_c, attn_l.reshape(1, n_lanes, cfg.n_heads, cfg.head_dim)], axis=1)
-    elif ck is not None:
-        if layer is None:
-            raise ValueError("a cache is the stacked arena: say which layer of it")
-        if block_table is not None:
-            # paged arena: write through the block table into pool pages —
-            # same masking rule, same numbers as the dense scatter below
-            ck, cv = scatter_paged_kv(ck, cv, k, v, block_table, positions, layer)
+        def on_global(c):
+            with jax.named_scope("attn_global"):
+                return attend(c[1], c[2], 0)
+
+        def on_window(c):
+            with jax.named_scope("attn_window"):
+                return attend(c[1], c[2], cfg.window)
+
+        if ring is None:  # per-layer rotary flags alone: one leaf, no window
+            attn, ck, cv = attend(ck, cv, 0)
         else:
-            # scatter this step's K/V rows, and only them, into the stack at
-            # per-sequence positions (rows past S — bucket padding — drop)
-            rows = jnp.arange(b)[:, None] + (0 if slot is None else slot)
-            ck = ck.at[layer, rows, positions].set(k)
-            cv = cv.at[layer, rows, positions].set(v)
-            if t == 1:
-                positions = _seen(positions, ck.shape[2])
-        attn = cache_attn_impl(q, ck, cv, positions, block_table, layer, slot)
+            # Either leaf as a loop of 0 or 1 trips over the stack it updates:
+            # a ``lax.cond`` would copy the leaf its branch passes through
+            # untouched (models/hybrid.py found that on the chip); a while
+            # loop's carry stays one buffer whether it trips or not
+            trips = windowed.astype(jnp.int32)
+            attn, ck, cv = lax.fori_loop(0, 1 - trips, lambda _, c: on_global(c), (jnp.zeros_like(q), ck, cv))
+            attn, *ring = lax.fori_loop(0, trips, lambda _, c: on_window(c), (attn, *ring))
+    elif ck is not None:
+        attn, ck, cv = _cache_attention(
+            q, k, v, ck, cv, positions if chunk_positions is None else None,
+            cache_attn_impl, block_table, layer, slot, chunk_positions, lane_positions,
+        )
+    elif kind is not None:
+        # no cache: the window is a mask over the tokens given
+        i = jnp.arange(t)
+        near = (i[:, None] - i[None, :]) < jnp.where(windowed, cfg.window, t)
+        attn = attention_reference(q, k, v, mask=mask & near[None])
     elif use_flash:
         attn = flash_attention(q, k, v, causal=True)
     else:
         attn = attention_reference(q, k, v, mask=mask)
     out = attn.reshape(b, t, cfg.n_heads * cfg.head_dim) @ lp["wo"]
+    if kind is not None:
+        return x + out, ck, cv, None if ring is None else tuple(ring)
     return x + out, ck, cv
 
 
@@ -494,6 +678,10 @@ def forward(
     the lanes' (the dense arena only: no page pool, no hybrid block).
     Without: pure causal self-attention over the tokens given (what tests
     compare the cached path with).
+    A config with ``window_layers`` / ``rope_layers`` hands each layer its
+    two flags as scan inputs; its cache is a :class:`WindowKVCache` (the
+    window layers' leaf a ring), and a launch may carry at most
+    ``R - window + 1`` rows of one lane (:func:`ring_rows`).
     ``moe_impl`` overrides the MoE MLP (routed token-dispatch, meshed EP,
     the einsum pinned under a ``tp`` mesh). Without one the call's static
     row count decides (``moe_sorts``): few rows take the all-experts einsum,
@@ -533,30 +721,91 @@ def forward(
     else:
         t = tokens.shape[1]
         mask = jnp.broadcast_to(causal_mask(t), (tokens.shape[0], t, t))
+    # the per-layer switches of a model that has them, as scan inputs beside
+    # the weights (static absences everywhere else: no operand, the programs
+    # of every other model are what they were)
+    switched = bool(cfg.n_window or cfg.rope_layers)
+    if (cfg.early_router or cfg.ffn_act != "silu") and moe_impl is not None:
+        raise ValueError("a pinned MoE path (routed, or a mesh) computes its own router and SwiGLU")
+    if switched and block_table is not None:
+        raise ValueError("window and no-rope layers are served from the dense arena, not the page pool")
+    if cfg.n_window and cache is not None:
+        ring_len, run = cache.wk.shape[2], tokens.shape[1]
+        if ring_len < cache.k.shape[2] and run > ring_len - cfg.window + 1:
+            raise ValueError(
+                f"a launch of {run} rows a lane would write over rows its own queries see: "
+                f"the ring holds {ring_len} rows for a window of {cfg.window} (ring_rows)"
+            )
 
-    def block(x, ck, cv, lp, layer):
+    def block(x, ck, cv, lp, layer, ring=None, kind=None):
         # int8-quantized weights (engine/quant.py) dequantize per layer
         # slice here: HBM holds the int8 stack, only the current layer is
         # dense, and XLA fuses the convert into the consuming matmuls
         lp = {k: dequant(v) for k, v in lp.items()}
-        x, ck, cv = _attention_block(
+        logits = None
+        if cfg.early_router:
+            # the router reads the stream as it enters the layer
+            # (float32 logits: a near-tie among 64 decided by a bfloat16
+            # rounding sends a token to another expert)
+            with jax.named_scope("moe_early_router"):
+                logits = jnp.dot(x, lp["router"], preferred_element_type=jnp.float32)
+        x, ck, cv, *ring = _attention_block(
             x, lp, cfg, positions, mask, ck, cv, use_flash,
             cache_attn_impl=cache_attn_impl,
             block_table=block_table,
             layer=layer,
             slot=slot,
             lane_positions=lane_positions,
+            **({"ring": ring, "kind": kind} if kind is not None else {}),
         )
         h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+        early = {"logits": logits} if logits is not None else {}
         if experts is not None:
-            x = x + _moe_mlp_sorted(h, lp, cfg, experts, layer, routed=routed)
+            x = x + _moe_mlp_sorted(h, lp, cfg, experts, moe_layer(layer, kind), routed=routed, **early)
         elif cfg.is_moe:
-            x = x + (moe_impl(h, lp) if moe_impl is not None else _moe_mlp(h, lp, cfg))
+            x = x + (moe_impl(h, lp) if moe_impl is not None else _moe_mlp(h, lp, cfg, **early))
         else:
-            x = x + _mlp(h, lp)
-        return x, ck, cv
+            x = x + _mlp(h, lp, cfg.ffn_act)
+        return (x, ck, cv, *ring)
 
-    if cache is not None:
+    def moe_layer(layer, kind):
+        # the expert stack is indexed by the model's layer; ``layer`` is the
+        # index in the layer's own cache leaf where the leaves are two
+        return layer if kind is None else kind[2]
+
+    if switched:
+        n = cfg.n_layers
+        win = np.asarray(cfg.window_layers or (0,) * n, bool)
+        rot = np.asarray(cfg.rope_layers or (1,) * n, bool)
+        leaf_idx = np.where(win, np.cumsum(win) - 1, np.cumsum(~win) - 1).astype(np.int32)
+        kinds = (jnp.asarray(win), jnp.asarray(rot), jnp.arange(n, dtype=jnp.int32))
+        if cache is not None and cfg.n_window:
+            def layer_step(carry, inputs):
+                lp, layer, kind = inputs
+                x, ck, cv, wk, wv = carry
+                x, ck, cv, ring = block(x, ck, cv, lp, layer, (wk, wv), kind)
+                return (x, ck, cv, *ring), None
+
+            (x, *leaves), _ = lax.scan(
+                layer_step, (x, *cache), (lp_stack, jnp.asarray(leaf_idx), kinds)
+            )
+            new_cache = type(cache)(*leaves)
+        elif cache is not None:
+            def layer_step(carry, inputs):
+                lp, layer, kind = inputs
+                return block(*carry, lp, layer, None, kind)[:3], None
+
+            (x, new_k, new_v), _ = lax.scan(
+                layer_step, (x, cache.k, cache.v), (lp_stack, kinds[2], kinds)
+            )
+            new_cache = type(cache)(new_k, new_v)
+        else:
+            x, _ = lax.scan(
+                lambda x, inp: (block(x, None, None, inp[0], inp[1][2], None, inp[1])[0], None),
+                x, (lp_stack, kinds),
+            )
+            new_cache = None
+    elif cache is not None:
         def layer_step(carry, inputs):
             lp, layer = inputs
             return block(*carry, lp, layer), None

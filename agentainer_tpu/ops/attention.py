@@ -69,6 +69,18 @@ def cache_mask(q_positions: jnp.ndarray, cache_len: int) -> jnp.ndarray:
     return slots <= q_positions[:, :, None]
 
 
+def ring_mask(q_positions: jnp.ndarray, ring: int, window: int) -> jnp.ndarray:
+    """Mask for attending over a window layer's RING of ``ring`` rows, where
+    the row of position p is ``p mod ring`` (models/llama.WindowKVCache). Row
+    r holds, for a query at position i, the position ``i - ((i - r) mod
+    ring)``: the newest at or before i that lands there (nothing newer was
+    written to it: ``models/llama.ring_rows``). The query sees it iff that
+    position is within the last ``window`` and not before position 0.
+    q_positions ``[B, Tq]`` → mask ``[B, Tq, ring]``."""
+    back = (q_positions[:, :, None] - jnp.arange(ring)[None, None, :]) % ring
+    return (back < window) & (back <= q_positions[:, :, None])
+
+
 def pallas_available(n_heads: int, n_kv_heads: int, head_dim: int) -> tuple[bool, str]:
     """Whether the compiled Pallas kernels can serve these head shapes in
     this process, and the reason either way."""
@@ -225,9 +237,10 @@ def layer_slice(ck, cv, layer, slot=None, rows: int = 0):
     return ck, cv
 
 
-def _reference_dense(q, ck, cv, positions, block_table, layer, slot):
+def _reference_dense(q, ck, cv, positions, block_table, layer, slot, window: int = 0):
     ck, cv = layer_slice(ck, cv, layer, slot, q.shape[0])
-    return attention_reference(q, ck, cv, mask=cache_mask(positions, ck.shape[1]))
+    mask = ring_mask(positions, ck.shape[1], window) if window else cache_mask(positions, ck.shape[1])
+    return attention_reference(q, ck, cv, mask=mask)
 
 
 def _reference_paged(q, pool_k, pool_v, positions, block_table, layer, slot):
@@ -235,19 +248,22 @@ def _reference_paged(q, pool_k, pool_v, positions, block_table, layer, slot):
     return attention_reference(q, ck, cv, mask=cache_mask(positions, ck.shape[1]))
 
 
-def pallas_dense(q, ck, cv, positions, block_table, layer, slot, interpret: bool = False):
+def pallas_dense(q, ck, cv, positions, block_table, layer, slot, interpret: bool = False, window: int = 0):
     """The dense flash kernels by call shape: one token per sequence runs
     ``flash_decode``, anything longer ``flash_prefill``. Both read layer
-    ``layer`` (rows from ``slot``) of the stacks in the stored layout."""
+    ``layer`` (rows from ``slot``) of the stacks in the stored layout.
+    ``window``: the stack is a window layer's ring and a row sees its last
+    ``window`` positions."""
     from .pallas_attention import flash_decode, flash_prefill
 
     slot = 0 if slot is None else slot
+    kw = {"window": window} if window else {}
     if q.shape[1] == 1:
         out = flash_decode(
-            q[:, 0], ck, cv, positions[:, 0], layer, slot, interpret=interpret
+            q[:, 0], ck, cv, positions[:, 0], layer, slot, interpret=interpret, **kw
         )
         return out[:, None]
-    return flash_prefill(q, ck, cv, positions, layer, slot, interpret=interpret)
+    return flash_prefill(q, ck, cv, positions, layer, slot, interpret=interpret, **kw)
 
 
 def pallas_dense_layer(q, ck, cv, positions, interpret: bool = False):

@@ -139,7 +139,12 @@ def stacked_experts(layers: dict) -> dict:
     return out
 
 
-def _grouped_ffn_xla(x_rows, row_gate, routing, experts, layer, tile):
+def gate_act(name: str):
+    """The FFN's gate activation by ``cfg.ffn_act``: SwiGLU's or ReGLU's."""
+    return {"silu": jax.nn.silu, "relu": jax.nn.relu}[name]
+
+
+def _grouped_ffn_xla(x_rows, row_gate, routing, experts, layer, tile, act: str = "silu"):
     """The grouped FFN without the kernel (any backend): the layer's experts
     taken out of the stack and dequantised, ``lax.ragged_dot`` over the
     tile-aligned groups."""
@@ -153,7 +158,7 @@ def _grouped_ffn_xla(x_rows, row_gate, routing, experts, layer, tile):
         w, s = (lax.dynamic_index_in_dim(t, layer, 0, keepdims=False) for t in experts[name])
         return (w.astype(jnp.float32) * s).astype(x_rows.dtype)
 
-    gate = jax.nn.silu(lax.ragged_dot(x_rows, dense("w_gate"), sizes))
+    gate = gate_act(act)(lax.ragged_dot(x_rows, dense("w_gate"), sizes))
     up = lax.ragged_dot(x_rows, dense("w_up"), sizes)
     out = lax.ragged_dot(gate * up, dense("w_down"), sizes)
     return (out * row_gate).astype(x_rows.dtype)
@@ -169,9 +174,11 @@ def sorted_moe_ffn(
     kernel: bool | None = None,
     interpret: bool = False,
     held: tuple[int, int] | None = None,
+    act: str = "silu",
 ) -> jnp.ndarray:
     """``[N, d]``: ``Σ_j gates[n, j] · FFN_{chosen[n, j]}(x[n])``, computing
-    only those pairs. ``kernel``: the Pallas grouped FFN (default: on a TPU
+    only those pairs. ``act``: the gate's activation, ``silu`` (SwiGLU) or
+    ``relu`` (ReGLU). ``kernel``: the Pallas grouped FFN (default: on a TPU
     backend). ``held = (offset, n_total)``: ``experts`` is the chip's share
     ``[offset, offset + E)`` of ``n_total`` experts and ``chosen`` indexes
     all of them; assignments to absent experts (a negative choice, a row
@@ -222,7 +229,8 @@ def sorted_moe_ffn(
         y = grouped_ffn(
             x_rows, row_gate, routing.tile_expert, routing.n_active,
             wg, sg, wu, su, wd, sd, layer, tile=tile, interpret=interpret,
+            **({"act": act} if act != "silu" else {}),
         )
     else:
-        y = _grouped_ffn_xla(x_rows, row_gate, routing, experts, layer, tile)
+        y = _grouped_ffn_xla(x_rows, row_gate, routing, experts, layer, tile, act)
     return jnp.einsum("mn,md->nd", spread, y, preferred_element_type=jnp.float32).astype(x.dtype)

@@ -104,6 +104,13 @@ def decode_kv_block(kv: int, hd: int, dtype, s: int, block_k: int = 512) -> tupl
     return _kv_block(kv, hd, dtype, s, block_k, _DECODE_KV_VMEM)
 
 
+def ring_block(kv: int, hd: int, dtype) -> int:
+    """Rows a window layer's ring has to be whole multiples of: the larger of
+    the two kernels' K/V blocks (each a power-of-two count of 128s)."""
+    long = 1 << 20
+    return max(_kv_block(kv, hd, dtype, long, 256, _PREFILL_KV_VMEM)[1], decode_kv_block(kv, hd, dtype, long)[1])
+
+
 def _scalar(x) -> jnp.ndarray:
     """A prefetched scalar operand: int32 ``[1]``."""
     return jnp.asarray(x, jnp.int32).reshape(1)
@@ -123,6 +130,33 @@ def kv_block_index(ik, n_steps, last_pos, block_k: int):
     for its DMA with nothing to hide it (measured: section 6 of PERF.md, PR
     33). A position past the arena's end sees every block, as it always did."""
     return ik - (n_steps - jnp.minimum(last_pos // block_k + 1, n_steps))
+
+
+def ring_block_index(ik, n_steps, lo, last, block_k: int):
+    """``kv_block_index`` for a window layer's ring of ``n_steps`` blocks (row
+    of position p: ``p mod R``, ``R = n_steps * block_k``): ``(ring block,
+    live)`` of step ``ik`` for query rows that see positions ``lo .. last``.
+    The blocks that hold those positions are the row's LAST steps, in the
+    order of their positions; the steps before them hold the first of them
+    and do nothing, so blocks under ``lo`` are not fetched, as blocks over
+    ``last`` are not. A span that laps the ring (its first and last position
+    share a block) visits every block once: ``ring_positions`` gives each row
+    of a block the position it holds."""
+    first = lo // block_k
+    live = jnp.minimum(last // block_k - first + 1, n_steps)
+    step = ik - (n_steps - live)
+    return (first + jnp.maximum(step, 0)) % n_steps, step >= 0
+
+
+def ring_positions(block, last, block_k: int, ring: int) -> jnp.ndarray:
+    """``[1, block_k]``: the position each row of ring block ``block`` holds
+    once a launch has written up to position ``last``: the newest position at
+    or before ``last`` that lands on the row (rows past ``last mod ring`` are
+    a lap behind; negative: never written). What ``models/llama.ring_rows``
+    guarantees is that every position a query of the launch sees is still the
+    newest on its row."""
+    row = block * block_k + lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+    return row + ring * (last // ring - (row > last % ring).astype(jnp.int32))
 
 
 def _head(ref, h: int) -> jnp.ndarray:
@@ -152,19 +186,20 @@ def _prefill_kernel(
     layer_ref,  # [1] int32 (SMEM, scalar prefetch; used by the index maps)
     slot_ref,  # [1] int32 (SMEM, scalar prefetch; used by the index maps)
     last_ref,  # [B] int32 (SMEM, scalar prefetch) each sequence's last position
-    pos_ref,  # [G, bq, 1] int32           (VMEM) the q tile's positions, per group
-    q_ref,  # [heads, G, bq, hd]          (VMEM) heads: this block of KV heads
-    k_ref,  # [1, 1, bk, heads, hd]       (VMEM)
-    v_ref,  # [1, 1, bk, heads, hd]       (VMEM)
-    o_ref,  # [heads, G, bq, hd]          (VMEM)
-    m_ref,  # [heads, G * bq] f32 scratch
-    l_ref,  # [heads, G * bq] f32 scratch
-    acc_ref,  # [heads, G * bq, hd] f32 scratch
-    *,
+    *refs,  # with ``window``: lo_ref [B] int32 (SMEM, scalar prefetch) first; then
+    # pos_ref [G, bq, 1] int32 (VMEM) the q tile's positions, per group
+    # q_ref [heads, G, bq, hd] (VMEM) heads: this block of KV heads
+    # k_ref, v_ref [1, 1, bk, heads, hd] (VMEM)
+    # o_ref [heads, G, bq, hd] (VMEM)
+    # m_ref, l_ref [heads, G * bq] f32 scratch; acc_ref [heads, G * bq, hd] f32 scratch
     block_k: int,
     seq_len_k: int,
     scale: float,
+    window: int = 0,
 ):
+    if window:
+        lo_ref, *refs = refs
+    pos_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs
     ik = pl.program_id(3)
     nk = pl.num_programs(3)
     kv_heads, groups, bq, hd = q_ref.shape
@@ -177,14 +212,29 @@ def _prefill_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     pos = pos_ref[...].reshape(rows, 1)  # row g * bq + i is query i of group g
-    blk = kv_block_index(ik, nk, last_ref[pl.program_id(0)], block_k)
-    k_start = blk * block_k
-    col = k_start + lax.broadcasted_iota(jnp.int32, (rows, block_k), 1)
-    mask = (col <= pos) & (col < seq_len_k)  # [G * bq, bk]
+    if window:
+        # a ring (``seq_len_k`` rows, whole blocks): the block of this step and
+        # the position each of its rows holds; a row sees the last ``window``
+        last = last_ref[pl.program_id(0)]
+        blk, live = ring_block_index(ik, nk, lo_ref[pl.program_id(0)], last, block_k)
+        held = ring_positions(blk, last, block_k, seq_len_k)  # [1, bk]
+        # (``held + window``, never ``pos - window``: a q tile's padding rows
+        # carry whatever positions the block's padding holds)
+        mask = (held <= pos) & (held + window > pos) & (held >= 0)  # [G * bq, bk]
+        # skip the steps before the first block, and blocks wholly in the
+        # future or wholly behind the window of every row in this q tile
+        run = live & (jnp.min(held) <= jnp.max(pos)) & (jnp.max(held) + window > jnp.min(pos))
+        k_start = 0  # every row of a ring block is inside the ring
+    else:
+        blk = kv_block_index(ik, nk, last_ref[pl.program_id(0)], block_k)
+        k_start = blk * block_k
+        col = k_start + lax.broadcasted_iota(jnp.int32, (rows, block_k), 1)
+        mask = (col <= pos) & (col < seq_len_k)  # [G * bq, bk]
+        # skip the steps before the sequence's first block, and KV blocks
+        # strictly in the future of every row in this q tile
+        run = (blk >= 0) & (k_start <= jnp.max(pos))
 
-    # skip the steps before the sequence's first block, and KV blocks
-    # strictly in the future of every row in this q tile
-    @pl.when((blk >= 0) & (k_start <= jnp.max(pos)))
+    @pl.when(run)
     def _compute():
         # rows past the arena end are padded garbage (can be NaN): zero them,
         # since 0 * NaN from the masked-out probabilities would poison acc
@@ -222,7 +272,7 @@ def _prefill_kernel(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_q", "block_k", "interpret")
+    jax.jit, static_argnames=("block_q", "block_k", "interpret", "window")
 )
 def flash_prefill(
     q: jnp.ndarray,  # [B, T, H, hd]
@@ -234,8 +284,12 @@ def flash_prefill(
     block_q: int = 128,
     block_k: int = 256,
     interpret: bool = False,
+    window: int = 0,
 ) -> jnp.ndarray:
-    """Blockwise flash attention; row t sees arena slot j iff j <= pos[b, t]."""
+    """Blockwise flash attention; row t sees arena slot j iff j <= pos[b, t].
+    ``window``: the arena is a window layer's ring (row of position p: ``p mod
+    S``, S whole K/V blocks) and row t sees the positions ``pos - window <
+    j <= pos`` in it; blocks that hold none of a sequence's are not fetched."""
     b, t, h, hd = q.shape
     s, kv = k.shape[2], k.shape[3]
     g = h // kv
@@ -256,26 +310,39 @@ def flash_prefill(
     # there (a bucket's padding rows carry positions that run on past the real
     # tokens and may pass the arena's end, where ``kv_block_index`` stops)
     last = q_positions.astype(jnp.int32).max(axis=1)
+    scalars = (_scalar(layer), _scalar(slot), last)
+    window_kw = {}
+    if window:
+        if s % bk:
+            raise ValueError(f"a ring of {s} rows is not whole K/V blocks of {bk}")
+        # the first position any row of a sequence sees: the lower bound
+        scalars += (jnp.maximum(q_positions.astype(jnp.int32).min(axis=1) - (window - 1), 0),)
+        window_kw = {"window": window}
 
     kernel = functools.partial(
-        _prefill_kernel, block_k=bk, seq_len_k=s, scale=1.0 / (hd**0.5)
+        _prefill_kernel, block_k=bk, seq_len_k=s, scale=1.0 / (hd**0.5), **window_kw
     )
     q_spec = pl.BlockSpec(
-        (None, heads, g, bq, hd), lambda ib, ih, iq, ik, lay, slt, last: (ib, ih, 0, iq, 0)
+        (None, heads, g, bq, hd), lambda ib, ih, iq, ik, *scalars: (ib, ih, 0, iq, 0)
     )
 
     n_blocks = pl.cdiv(s, bk)
 
-    def kv_map(ib, ih, iq, ik, lay, slt, last):
-        return lay[0], slt[0] + ib, jnp.maximum(kv_block_index(ik, n_blocks, last[ib], bk), 0), ih, 0
+    if window:
+        def kv_map(ib, ih, iq, ik, lay, slt, last, lo):
+            return lay[0], slt[0] + ib, ring_block_index(ik, n_blocks, lo[ib], last[ib], bk)[0], ih, 0
+    else:
+        def kv_map(ib, ih, iq, ik, lay, slt, last):
+            return lay[0], slt[0] + ib, jnp.maximum(kv_block_index(ik, n_blocks, last[ib], bk), 0), ih, 0
 
     kv_spec = pl.BlockSpec((1, 1, bk, heads, hd), kv_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # layer, slot, each sequence's last position
+        # layer, slot, each sequence's last position (and, windowed, its first)
+        num_scalar_prefetch=len(scalars),
         grid=(b, kv // heads, pl.cdiv(t, bq), n_blocks),
         in_specs=[
             pl.BlockSpec(
-                (None, g, bq, 1), lambda ib, ih, iq, ik, lay, slt, last: (ib, 0, iq, 0)
+                (None, g, bq, 1), lambda ib, ih, iq, ik, *scalars: (ib, 0, iq, 0)
             ),
             q_spec,
             kv_spec,
@@ -293,7 +360,7 @@ def flash_prefill(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
         interpret=interpret,
-    )(_scalar(layer), _scalar(slot), last, pos, qh, k, v)
+    )(*scalars, pos, qh, k, v)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, hd)
 
 
@@ -312,6 +379,7 @@ def _decode_kernel(
     block_k: int,
     seq_len_k: int,
     scale: float,
+    window: int = 0,
 ):
     ik = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -324,13 +392,22 @@ def _decode_kernel(
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     pos = pos_ref[pl.program_id(0)]
-    blk = kv_block_index(ik, nk, pos, block_k)
+    if window:
+        # a ring: the lane's last ``window`` positions, wherever they lie in it
+        blk, run = ring_block_index(ik, nk, jnp.maximum(pos - (window - 1), 0), pos, block_k)
+    else:
+        blk = kv_block_index(ik, nk, pos, block_k)
+        run = blk >= 0
     k_start = blk * block_k
 
-    @pl.when(blk >= 0)  # the steps before the lane's first block do nothing
+    @pl.when(run)  # the steps before the lane's first block do nothing
     def _compute():
         col = k_start + lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-        mask = (col <= pos) & (col < seq_len_k)  # [1, bk]
+        if window:
+            held = ring_positions(blk, pos, block_k, seq_len_k)
+            mask = (held <= pos) & (held + window > pos) & (held >= 0)  # [1, bk]
+        else:
+            mask = (col <= pos) & (col < seq_len_k)  # [1, bk]
         row_valid = col.reshape(block_k, 1) < seq_len_k
         for h in range(kv_heads):
             qb = q_ref[h].astype(jnp.float32)  # [G, hd]
@@ -358,7 +435,7 @@ def _decode_kernel(
         o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_k", "interpret", "window"))
 def flash_decode(
     q: jnp.ndarray,  # [B, H, hd]
     k: jnp.ndarray,  # [L, Bc, S, KV, hd] the stacked arena
@@ -368,19 +445,27 @@ def flash_decode(
     slot=0,  # int32 scalar: sequence b reads arena row slot + b
     block_k: int = 512,
     interpret: bool = False,
+    window: int = 0,
 ) -> jnp.ndarray:
     """Single-token attention over the KV arena, fused softmax — no [B,H,S]
     score tensor ever reaches HBM (the decode path is HBM-bandwidth-bound),
-    and a lane fetches the ``positions // block + 1`` blocks it can see."""
+    and a lane fetches the ``positions // block + 1`` blocks it can see.
+    ``window``: the arena is a window layer's ring and a lane fetches the
+    blocks that hold its last ``window`` positions (``flash_prefill``)."""
     b, h, hd = q.shape
     s, kv = k.shape[2], k.shape[3]
     g = h // kv
     heads, bk = decode_kv_block(kv, hd, k.dtype, s, block_k)
+    window_kw = {}
+    if window:
+        if s % bk:
+            raise ValueError(f"a ring of {s} rows is not whole K/V blocks of {bk}")
+        window_kw = {"window": window}
 
     qh = q.reshape(b, kv, g, hd)
 
     kernel = functools.partial(
-        _decode_kernel, block_k=bk, seq_len_k=s, scale=1.0 / (hd**0.5)
+        _decode_kernel, block_k=bk, seq_len_k=s, scale=1.0 / (hd**0.5), **window_kw
     )
     q_spec = pl.BlockSpec(
         (None, heads, g, hd), lambda ib, ih, ik, lay, slt, pos: (ib, ih, 0, 0)
@@ -388,8 +473,13 @@ def flash_decode(
 
     n_blocks = pl.cdiv(s, bk)
 
-    def kv_map(ib, ih, ik, lay, slt, pos):
-        return lay[0], slt[0] + ib, jnp.maximum(kv_block_index(ik, n_blocks, pos[ib], bk), 0), ih, 0
+    if window:
+        def kv_map(ib, ih, ik, lay, slt, pos):
+            lo = jnp.maximum(pos[ib] - (window - 1), 0)
+            return lay[0], slt[0] + ib, ring_block_index(ik, n_blocks, lo, pos[ib], bk)[0], ih, 0
+    else:
+        def kv_map(ib, ih, ik, lay, slt, pos):
+            return lay[0], slt[0] + ib, jnp.maximum(kv_block_index(ik, n_blocks, pos[ib], bk), 0), ih, 0
 
     kv_spec = pl.BlockSpec((1, 1, bk, heads, hd), kv_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(

@@ -66,6 +66,8 @@ def _ffn_kernel(
     x_ref, rg_ref, wg_ref, sg_ref, wu_ref, su_ref, wd_ref, sd_ref,
     o_ref,
     acc_ref,
+    *,
+    act: str = "silu",
 ):
     i, j = pl.program_id(0), pl.program_id(1)
     active = i < n_active_ref[0]
@@ -80,7 +82,7 @@ def _ffn_kernel(
         dot = functools.partial(jnp.dot, preferred_element_type=jnp.float32)
         gate = dot(x, wg_ref[...].astype(x.dtype)) * sg_ref[...]
         up = dot(x, wu_ref[...].astype(x.dtype)) * su_ref[...]
-        h = (jax.nn.silu(gate) * up).astype(x.dtype)
+        h = ((jax.nn.silu(gate) if act == "silu" else jnp.maximum(gate, 0.0)) * up).astype(x.dtype)
         acc_ref[...] += dot(h, wd_ref[...].astype(x.dtype))
 
     last = j == pl.num_programs(1) - 1
@@ -94,7 +96,7 @@ def _ffn_kernel(
         o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+@functools.partial(jax.jit, static_argnames=("tile", "interpret", "act"))
 def grouped_ffn(
     x_rows: jnp.ndarray,  # [M, d]: rows sorted by expert, groups tile-aligned
     row_gate: jnp.ndarray,  # [M, 1] float32: a row's gate, 0 for padding
@@ -110,9 +112,11 @@ def grouped_ffn(
     *,
     tile: int,
     interpret: bool = False,
+    act: str = "silu",
 ) -> jnp.ndarray:
     """``[M, d]``: row r is ``gate_r · FFN_e(x_r)`` for the expert ``e`` of
-    r's tile; rows of tiles past ``n_active`` are 0."""
+    r's tile; rows of tiles past ``n_active`` are 0. ``act``: ``silu``
+    (SwiGLU) or ``relu`` (ReGLU: ``relu(x Wg) * (x Wu)``)."""
     m, d = x_rows.shape
     f = w_gate.shape[-1]
     n_tiles = m // tile
@@ -144,7 +148,7 @@ def grouped_ffn(
     weight_bytes = 3 * d * fb * (2 * w_gate.dtype.itemsize + x_rows.dtype.itemsize)
     tile_bytes = tile * d * (4 * x_rows.dtype.itemsize + 4) + 6 * tile * fb * 4
     return pl.pallas_call(
-        _ffn_kernel,
+        _ffn_kernel if act == "silu" else functools.partial(_ffn_kernel, act=act),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(n_tiles, nf),

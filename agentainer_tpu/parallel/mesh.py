@@ -77,10 +77,11 @@ def plan_layout(
     left over stay idle).
     """
     budget = n_assigned or min(n_visible, max(1, tp_asked) * max(1, ep_asked))
-    if cfg.is_hybrid and not (tp_asked or ep_asked):
-        # the hybrid block is served on one chip (its share of a stated
-        # expert-parallel deployment is ``cfg.experts_held``); an explicit
-        # tp/ep passes through and the engine refuses it by name
+    if (cfg.is_hybrid or cfg.n_window) and not (tp_asked or ep_asked):
+        # the hybrid block, and a model whose window layers keep a ring, are
+        # served on one chip (its share of a stated expert-parallel
+        # deployment is ``cfg.experts_held``); an explicit tp/ep passes
+        # through and the engine refuses it by name
         # (engine/llm.cache_features)
         if n_assigned > 1:
             print(

@@ -272,3 +272,40 @@ def test_olmoe_checkpoint_loads_and_matches_the_plain_reference(tmp_path):
     got, _ = forward(jax.tree.map(jnp.asarray, params), derived, tokens[None], jnp.arange(16)[None], use_flash=False)
     err = np.sqrt(np.mean((np.asarray(got[0]) - np.asarray(want)) ** 2, -1)) / np.std(np.asarray(want), -1)
     assert float(np.median(err)) < 1e-4
+
+
+def test_config_from_hf_reads_a_smallthinker_config_as_the_benchmarks_family_does(tmp_path):
+    """The published ``config.json`` (the benchmark's configuration file
+    holds its keys) gives the registered model: a head of its own, the two
+    per-layer layouts, the window, ReGLU and the early router. The tensors'
+    names wait for a checkpoint: loading refuses by name, it does not guess."""
+    import dataclasses
+    import os
+
+    from agentainer_tpu.engine.hf_convert import config_from_hf, load_hf_params
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs", "smallthinker-21b-ep4-1chip.json")) as f:
+        doc = json.load(f)
+    published = {k: v for k, v in doc.items() if k not in ("name", "family", "source", "reduced", "assumed", "engine_options",
+                                                           "why_engine_options", "expert_parallel", "experts_published",
+                                                           "hbm_claim_bytes_per_chip", "stands_for", "torch_dtype")}
+    published["moe_num_primary_experts"] = doc["experts_published"]
+    (tmp_path / "config.json").write_text(json.dumps(published))
+    from safetensors.numpy import save_file
+
+    save_file({"model.norm.weight": np.ones((4,), np.float32)}, str(tmp_path / "model.safetensors"))
+    derived = config_from_hf(tmp_path)
+    assert derived == dataclasses.replace(get_config("smallthinker-21b"), name=derived.name)
+    assert derived.head_dim == 128 and derived.n_window == 39 and derived.ffn_act == "relu" and derived.early_router
+    with pytest.raises(NotImplementedError, match="checkpoint key mapping"):
+        load_hf_params(derived, tmp_path)
+    # a Llama config that states the derived head width keeps deriving it
+    other = tmp_path / "llama"
+    other.mkdir()
+    _write_hf_llama(other, get_config("tiny"))
+    llama_doc = json.loads((other / "config.json").read_text())
+    stated = {**llama_doc, "head_dim": llama_doc["hidden_size"] // llama_doc["num_attention_heads"]}
+    (other / "config.json").write_text(json.dumps(stated))
+    plain = config_from_hf(other)
+    assert plain.head_size == 0 and not plain.window_layers and plain.ffn_act == "silu" and not plain.early_router

@@ -456,3 +456,115 @@ def test_prefill_chunk_fetches_up_to_its_last_row(monkeypatch, offset):
     np.testing.assert_allclose(
         got[0, real].astype(np.float32), want[0, real].astype(np.float32), rtol=3e-2, atol=3e-2
     )
+
+
+# ---- a window layer's ring (ISSUE 37) ---------------------------------------
+#
+# A window layer's leaf is a ring of R rows (row of position p: p mod R) and a
+# row sees its last ``window`` positions. The index maps bound a row's blocks
+# from BELOW as well as from above: of the ring's blocks only those that hold
+# a position in lo .. last are named, in the order of their positions, as the
+# row's last grid steps. The spec is ``ring_mask`` + ``attention_reference``.
+
+
+@pytest.mark.parametrize("block_k,ring", [(128, 512), (128, 640), (256, 1024), (512, 4608)])
+def test_ring_block_index_names_only_the_blocks_between_the_bounds(block_k, ring):
+    from agentainer_tpu.ops.pallas_attention import ring_block_index, ring_positions
+
+    n = ring // block_k
+    window = ring - block_k  # as ``ring_rows`` sizes it: the window plus a launch
+    for last in (0, 5, block_k - 1, block_k, window - 1, window, ring - 1, ring, ring + 3, 3 * ring + block_k + 7, 7 * ring - 1):
+        lo = max(last - (window - 1), 0)
+        blk, live = (np.asarray(x) for x in ring_block_index(jnp.arange(n), n, jnp.int32(lo), jnp.int32(last), block_k))
+        want = [(b % n) for b in range(lo // block_k, last // block_k + 1)]
+        assert len(want) <= n
+        # the idle steps come first and hold the first live block (no fetch of
+        # their own); the live ones follow in the order of their positions
+        assert live.sum() == len(want) and not live[: n - len(want)].any()
+        np.testing.assert_array_equal(blk[live], want)
+        np.testing.assert_array_equal(blk[~live], [want[0]] * (n - len(want)))
+        # blocks wholly under the lower bound are not named: an unbounded read
+        # of the same row would name last // block_k + 1 of them
+        assert len(want) <= min(last // block_k + 1, window // block_k + 1)
+        # every row of a named block holds the newest position at or before
+        # ``last`` that lands on it, and every position lo .. last is there once
+        held = np.concatenate([np.asarray(ring_positions(jnp.int32(b), jnp.int32(last), block_k, ring))[0] for b in want])
+        seen = held[(held >= lo) & (held <= last)]
+        np.testing.assert_array_equal(np.sort(seen), np.arange(lo, last + 1))
+        assert ((held <= last) & (held % ring == (np.concatenate([np.arange(b * block_k, (b + 1) * block_k) for b in want])))).all()
+
+
+@pytest.mark.parametrize(
+    "positions",
+    [[0, 5, 299], [300, 511, 512], [700, 1023, 1500], [5000, 127, 128]],
+    ids=["under_the_window", "at_the_ring_end", "wrapped", "far_past_and_block_edges"],
+)
+@pytest.mark.parametrize("kv_heads,groups", [(2, 3), (4, 7)], ids=["gqa2x3", "gqa4x7"])
+def test_windowed_decode_reads_the_ring(positions, kv_heads, groups):
+    """Lanes at assorted positions of a ring of 512 rows with a window of 300
+    (not whole blocks), layer 1 of 2: ``flash_decode`` with ``window`` against
+    the ring mask over the same leaf. GQA group 7 is SmallThinker's."""
+    from agentainer_tpu.ops.attention import _reference_dense, pallas_dense
+
+    ring, window, hd = 512, 300, 128
+    keys = jax.random.split(jax.random.PRNGKey(37), 3)
+    ck = _rand(keys[0], 2, 3, ring, kv_heads, hd)
+    cv = _rand(keys[1], 2, 3, ring, kv_heads, hd)
+    q = _rand(keys[2], 3, 1, kv_heads * groups, hd)
+    pos = jnp.asarray(positions, jnp.int32)[:, None]
+    want = _reference_dense(q, ck, cv, pos, None, 1, None, window=window)
+    got = pallas_dense(q, ck, cv, pos, None, 1, None, interpret=True, window=window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+    short = flash_decode(q[:, 0], ck, cv, pos[:, 0], 1, block_k=128, interpret=True, window=window)
+    np.testing.assert_allclose(np.asarray(short), np.asarray(want[:, 0]), rtol=2e-5, atol=2e-5)
+    # the window matters: the unbounded kernel over the same rows reads more
+    if max(positions) >= window:
+        plain = pallas_dense(q, ck, cv, jnp.minimum(pos, ring - 1), None, 1, None, interpret=True)
+        assert float(jnp.abs(plain - want).max()) > 1e-3
+
+
+@pytest.mark.parametrize(
+    "start,rows",
+    [(0, 64), (250, 64), (480, 64), (1000, 200), (1336, 213)],
+    ids=["first", "across_the_window", "across_the_ring_end", "wrapped_two_q_tiles", "ragged_wrapped"],
+)
+@pytest.mark.parametrize("block_k", [128, 256])
+def test_windowed_prefill_chunk_reads_the_ring(start, rows, block_k):
+    """A chunk of ``rows`` rows at ``start`` on slot 1 of a ring of 512 with a
+    window of 300: rows of one q tile see different lower bounds, the chunk's
+    own rows may straddle the ring's end, and blocks wholly behind every row's
+    window are skipped."""
+    from agentainer_tpu.ops.attention import _reference_dense
+
+    ring, window, kv_heads, groups, hd = 512, 300, 2, 3, 128
+    assert rows <= ring - window + 1  # what ``ring_rows`` guarantees a launch
+    keys = jax.random.split(jax.random.PRNGKey(38), 3)
+    ck = _rand(keys[0], 2, 3, ring, kv_heads, hd)
+    cv = _rand(keys[1], 2, 3, ring, kv_heads, hd)
+    q = _rand(keys[2], 1, rows, kv_heads * groups, hd)
+    pos = (start + jnp.arange(rows, dtype=jnp.int32))[None]
+    got = flash_prefill(q, ck, cv, pos, 1, 1, block_k=block_k, interpret=True, window=window)
+    want = _reference_dense(q, ck, cv, pos, None, 1, 1, window=window)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
+
+
+def test_a_ring_that_is_not_whole_blocks_is_refused():
+    ck = jnp.zeros((1, 1, 300, 2, 128), jnp.float32)
+    q = jnp.zeros((1, 6, 128), jnp.float32)
+    with pytest.raises(ValueError, match="whole K/V blocks"):
+        flash_decode(q, ck, ck, jnp.zeros((1,), jnp.int32), 0, block_k=128, interpret=True, window=100)
+    with pytest.raises(ValueError, match="whole K/V blocks"):
+        flash_prefill(q[None], ck, ck, jnp.zeros((1, 1), jnp.int32), 0, 0, block_k=128, interpret=True, window=100)
+
+
+def test_ring_mask_is_the_window_over_positions():
+    """The XLA spec itself, against the definition: row r of a ring of R holds
+    the newest position at or before the query's that lands on it."""
+    from agentainer_tpu.ops.attention import ring_mask
+
+    ring, window = 24, 16
+    for i in (0, 3, 15, 16, 23, 24, 25, 47, 100):
+        mask = np.asarray(ring_mask(jnp.asarray([[i]]), ring, window))[0, 0]
+        seen = sorted(j for j in range(max(0, i - window + 1), i + 1))
+        assert sorted(np.flatnonzero(mask)) == sorted(j % ring for j in seen)
+        assert mask.sum() == min(i + 1, window)

@@ -82,6 +82,9 @@ ASSIGNED = {
     "tiny-kimi-linear": [(1, 1), (1, 1), (1, 1), (1, 1)],
     "olmo-hybrid-7b": [(1, 1), (1, 1), (1, 1), (1, 1)],
     "tiny-olmo-hybrid": [(1, 1), (1, 1), (1, 1), (1, 1)],
+    # and so is a windowed cache: the ring's kernels are the one-chip ones (PR 37)
+    "smallthinker-21b": [(1, 1), (1, 1), (1, 1), (1, 1)],
+    "tiny-smallthinker": [(1, 1), (1, 1), (1, 1), (1, 1)],
 }
 
 

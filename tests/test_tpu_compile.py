@@ -455,6 +455,94 @@ def test_olmo_hybrid_step_copies_no_stack_and_pads_no_arena_on_v5e(v5e, step):
     assert mem.temp_size_in_bytes < arena_layer // 4, mem.temp_size_in_bytes
 
 
+def _windowed_case(where, lanes=8, periods=1):
+    """SmallThinker's block at published widths (``periods`` whole periods
+    G W W W), the chip's share of ep = 4 (16 of 64 experts), int8 as served,
+    the two-leaf cache of 8 lanes of 16,384 sized as an engine sizes it for
+    chunks of 256 (a ring of 4,608 rows a lane)."""
+    import dataclasses
+
+    from agentainer_tpu.models.llama import init_cache, ring_plan
+
+    big = get_config("smallthinker-21b")
+    n = 4 * periods
+    cfg = dataclasses.replace(
+        big, n_layers=n, window_layers=big.window_layers[:n], rope_layers=big.rope_layers[:n],
+        experts_held=16, name=f"smallthinker-{n}l",
+    )
+    params, place = _served_shapes(cfg, where)
+    plan = ring_plan(cfg, jnp.bfloat16, 256)
+    cache = jax.tree.map(place, jax.eval_shape(lambda: init_cache(cfg, lanes, 16384, jnp.bfloat16, **plan)))
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=where)  # noqa: E731
+
+    def decode(params, cache, tokens, positions):
+        return forward(params, cfg, tokens, positions, cache)
+
+    def prefill(params, cache, slot, tokens, positions):
+        return forward(params, cfg, tokens, positions, cache, slot=slot)
+
+    def mixed(params, cache, slot, tokens, positions, last, lane_tok, lane_pos):
+        return forward(params, cfg, tokens, positions, cache, slot=slot, lanes=(lane_tok, lane_pos), last=last)
+
+    steps = {
+        "decode": (decode, (params, cache, ints(lanes, 1), ints(lanes, 1))),
+        "prefill": (prefill, (params, cache, ints(), ints(1, 256), ints(1, 256))),
+        "mixed": (mixed, (params, cache, ints(), ints(1, 256), ints(1, 256), ints(), ints(lanes, 1), ints(lanes, 1))),
+    }
+    return cfg, plan, cache, steps
+
+
+@pytest.mark.parametrize("kernel", ["flash_prefill", "flash_decode"])
+def test_windowed_kernel_compiles_for_v5e(v5e, kernel):
+    """The ring's index maps (a second prefetched bound for prefill, the
+    modular block walk) at SmallThinker's shapes: 28 query heads over 4 K/V
+    heads of 128 (group 7, a shape no other configuration runs), the 39
+    window layers' leaf of 8 lanes x 4,608 rows, window 4,096."""
+    where = SingleDeviceSharding(v5e.devices[0])
+
+    def s(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=where)
+
+    ring, scalar = s((39, B, 4608, 4, HD)), s((), jnp.int32)
+    if kernel == "flash_prefill":
+        fn = lambda q, k, v, p, lay, slot: flash_prefill(q, k, v, p, lay, slot, window=4096)  # noqa: E731
+        args = (s((1, T, 28, HD)), ring, ring, s((1, T), jnp.int32), scalar, scalar)
+    else:
+        fn = lambda q, k, v, p, lay, slot: flash_decode(q, k, v, p, lay, slot, window=4096)  # noqa: E731
+        args = (s((B, 28, HD)), ring, ring, s((B,), jnp.int32), scalar, scalar)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < B * 4608 * 4 * HD * 2 // 8
+
+
+@pytest.mark.parametrize("step, kernels", [("decode", 2), ("prefill", 3), ("mixed", 5)])
+def test_windowed_step_keeps_both_leaves_in_place_on_v5e(v5e, monkeypatch, step, kernels):
+    """One period of SmallThinker's block through the ONE layer scan with the
+    two kinds of attention as 0-or-1-trip loops over their own leaf: the
+    chip's compiler takes the kernels under the loops (``flash_decode`` for
+    each kind; ``flash_prefill`` for each kind and the grouped FFN over the
+    held experts from 256 rows on; all five in the mixed step), both leaves
+    are donated in place, and no copy or relayout of either stands anywhere
+    in the program (a ``lax.cond`` over the two kinds would copy the leaf its
+    branch passes through: models/hybrid.py found that on the chip)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, plan, cache, steps = _windowed_case(SingleDeviceSharding(v5e.devices[0]))
+    assert plan == {"launch_rows": 256, "block": 512}
+    assert cache.k.shape == (1, 8, 16384, 4, 128) and cache.wk.shape == (3, 8, 4608, 4, 128)
+    fn, args = steps[step]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == kernels, text.count("tpu_custom_call")
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(leaf.size * 2 for leaf in cache)
+    for leaf in (cache.k, cache.wk):
+        shape = ",".join(map(str, leaf.shape))
+        assert not re.search(rf"\[{shape}\][^ ]* (copy|transpose)\(", text), shape
+    # nothing the size of a layer's held experts or of a leaf's layer
+    layer_matrix = cfg.n_held * cfg.dim * cfg.ffn_dim
+    assert mem.temp_size_in_bytes < min(layer_matrix, 2 * math.prod(cache.wk.shape[1:])) // 8, mem.temp_size_in_bytes
+
+
 def _equations(jaxpr):
     """Every equation of a jaxpr and of the jaxprs its equations hold."""
     for eqn in jaxpr.eqns:

@@ -238,7 +238,7 @@ def plan_hybrid(cfg: ModelConfig, use_pallas: bool | None = None) -> HybridPlan:
     aligned = cfg.kda_head_dim % 128 == 0 and cfg.kda_heads % 8 == 0
     if use_pallas and aligned:
         return HybridPlan(
-            "pallas_kda_decode", "xla_chunked", "pallas_mla_decode", "xla_absorbed",
+            "pallas_kda_decode", "xla_chunked", "pallas_mla_decode", "pallas_mla_prefill",
             "tpu backend; state and latent stacks read where they lie",
         )
     why = "no tpu backend" if not use_pallas else "KDA heads not (8, 128)-aligned"
@@ -597,7 +597,9 @@ def mla_mixer(h, lp, cfg: ModelConfig, latent, idx, slot, positions, valid, plan
     """``h [B, T, d]`` (normed) → the mixer's output and the latent stack with
     this step's rows written at their positions (rows past S drop). A lane
     that does not step (``valid`` false: parked at the arena's last row)
-    attends to one row instead of all S: its output is nobody's."""
+    attends to one row instead of all S: its output is nobody's. Where the
+    plan names the kernels, neither call shape slices a lane's row out of the
+    stack or writes scores to HBM."""
     b, t, _ = h.shape
     nh, rank, nope = cfg.n_heads, cfg.mla_kv_rank, cfg.mla_nope_dim
     q = _proj(h, lp["wq"]).astype(h.dtype).reshape(b, t, nh, nope + cfg.mla_rope_dim)
@@ -616,6 +618,12 @@ def mla_mixer(h, lp, cfg: ModelConfig, latent, idx, slot, positions, valid, plan
         seen = jnp.where(valid[:, 0], positions[:, 0], 0)
         o_lat = mla_decode(q_full[:, 0], latent, seen, idx, 0 if slot is None else slot,
                            scale=scale, rank=rank)[:, None]
+    elif t > 1 and plan.mla_prefill == "pallas_mla_prefill":
+        from ..ops.pallas_mla import mla_prefill
+
+        # a bucket's padding sees nothing: a tile of it costs nothing
+        seen = jnp.where(valid, positions, -1)
+        o_lat = mla_prefill(q_full, latent, seen, idx, 0 if slot is None else slot, scale=scale, rank=rank)
     else:
         o_lat = mla_ops.attend(q_full, _rows(latent, idx, slot, b), positions, scale, rank)
     o = jnp.einsum(

@@ -8,7 +8,10 @@ folded into the query and ``W_uv`` applied to the output, so every head
 scores against the one shared ``R + r`` wide row and combines the one
 ``R`` wide value — the arena is read once for all heads, where it lies.
 :func:`expanded` is the same mathematics written the long way (the test
-oracle for the absorption).
+oracle for the absorption). On a TPU the plan (``models/hybrid.plan_hybrid``)
+hands both call shapes to the kernels of ``ops/pallas_mla.py``, which read the
+stacked arena in place and only up to the last position seen; :func:`attend`
+over a lane's sliced row is the path everywhere else, and their oracle.
 """
 
 from __future__ import annotations

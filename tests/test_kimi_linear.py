@@ -37,6 +37,7 @@ import pytest
 from agentainer_tpu.analysis.hlo_contracts import DonationAliased, StacksRideInCarry, check
 from agentainer_tpu.models import llama
 from agentainer_tpu.models.configs import get_config
+from agentainer_tpu.models.hybrid import plan_hybrid
 from agentainer_tpu.models.llama import forward, init_cache, init_params
 from agentainer_tpu.ops import kda, mla
 from agentainer_tpu.ops.moe import stacked_experts
@@ -338,6 +339,50 @@ def test_pallas_kernels_compute_what_their_jnp_twins_do():
     assert float(jnp.abs(got - want).max()) < 1e-5
 
 
+# (tokens, first position, arena rows, real tokens, block_k, block_q): a stack of
+# 2 layers x 3 lanes, read at layer 1, lane 2
+MLA_PREFILL_CASES = {
+    "bucket-32-at-offset-0": (32, 0, 300, 32, 128, 16),
+    "bucket-64-in-mid-row": (64, 100, 300, 64, 128, 16),
+    "bucket-128-ending-at-the-arenas-last-row": (128, 172, 300, 128, 128, 16),
+    "bucket-256-over-one-block-that-does-not-divide-S": (256, 0, 300, 256, 512, 16),
+    "numerics-childs-192-rows": (192, 0, 256, 192, 128, 16),
+    "bucket-256-with-padding-past-n_real": (256, 40, 600, 130, 128, 16),
+    "padding-that-runs-past-the-arenas-end": (64, 270, 300, 20, 128, 8),
+    "token-tile-that-does-not-divide-T": (40, 33, 300, 40, 128, 16),
+    "one-tile-of-every-token": (64, 500, 600, 64, 256, 64),
+}
+
+
+@pytest.mark.parametrize("case", MLA_PREFILL_CASES)
+def test_mla_prefill_kernel_is_attend_over_the_stack_where_it_lies(case):
+    """Interpret mode: ``pallas_mla.mla_prefill`` against ``mla.attend`` over
+    the lane's sliced row, and, through the output projection, against
+    ``mla.expanded``. A token past ``n_real`` is handed position -1 (what
+    ``mla_mixer`` does with ``valid``): its output is nobody's, and finite."""
+    from agentainer_tpu.ops.pallas_mla import mla_prefill
+
+    t, start, s, n_real, block_k, block_q = MLA_PREFILL_CASES[case]
+    rng = np.random.default_rng(t + start)
+    h, rank, nope, r, dv, width, layer, lane = 4, 96, 16, 8, 16, 128, 1, 2
+    stack = jnp.asarray(rng.normal(size=(2, 3, s, width)), jnp.float32).at[..., rank + r :].set(0.0)
+    q = jnp.asarray(rng.normal(size=(1, t, h, nope + r)), jnp.float32)
+    w_kvb = jnp.asarray(rng.normal(size=(rank, h, nope + dv)), jnp.float32) * 0.3
+    pos = jnp.asarray(start + np.arange(t), jnp.int32)[None]
+    scale = (nope + r) ** -0.5
+    q_full = jnp.pad(mla.absorb_query(q, w_kvb, nope), [(0, 0)] * 3 + [(0, width - rank - r)])
+    seen = jnp.where(jnp.arange(t)[None] < n_real, pos, -1)
+    got = mla_prefill(q_full, stack, seen, layer, lane, scale=scale, rank=rank,
+                      block_q=block_q, block_k=block_k, interpret=True)
+    assert got.shape == (1, t, h, rank) and got.dtype == jnp.float32 and bool(jnp.isfinite(got).all())
+    rows = stack[layer, lane : lane + 1]
+    want = mla.attend(q_full, rows, pos, scale, rank)
+    assert float(jnp.abs(got - want)[:, :n_real].max()) < 1e-5
+    out = jnp.einsum("bthr,rhv->bthv", got, w_kvb[..., nope:])
+    long_way = mla.expanded(q, rows[..., : rank + r], pos, w_kvb, scale, rank, nope)
+    assert float(jnp.abs(out - long_way)[:, :n_real].max()) < 1e-4 * float(jnp.abs(long_way).max())
+
+
 def test_the_sigmoid_router_rule_is_the_references():
     logits = jax.random.normal(jax.random.PRNGKey(0), (40, CFG.n_experts)) * 3.0
     bias = jax.random.normal(jax.random.PRNGKey(1), (CFG.n_experts,)) * 0.5
@@ -601,7 +646,41 @@ def test_metrics_name_the_cache_kinds_the_plan_and_what_is_off():
     assert m["speculative"] is False and m["prefix_cache"] is False
     assert m["model_arch"]["layer_kinds"] == {"kda": 6, "mla": 3} and m["model_arch"]["dense_layers"] == 1
     assert m["attention"]["kda_decode"] == "xla_step" and m["attention"]["mla_decode"] == "xla_absorbed"
+    # the name the ledger's readers of ``attention.mla_prefill`` find: the XLA
+    # form off the chip, the plan's string (``pallas_mla_prefill``) on it
+    assert m["attention"]["mla_prefill"] == m["attention"]["prefill"] == "xla_absorbed"
+    on_chip = plan_hybrid(get_config("kimi-linear-48b"), use_pallas=True).describe()
+    assert on_chip["mla_prefill"] == on_chip["prefill"] == "pallas_mla_prefill" and on_chip["arena"] == "stack+layer"
     assert m["moe"]["experts_held"] == 8 and m["moe"]["shared_experts"] == 1 and m["moe"]["router"] == "sigmoid"
+
+
+def test_engine_with_the_prefill_kernel_planned_returns_the_xla_plans_tokens():
+    """A prompt of several chunks and two more turns through an engine whose
+    plan names ``pallas_mla_prefill`` (the kernel in interpret mode; the other
+    mechanisms as on the CPU): the tokens of the ``xla_absorbed`` engine, and
+    ``/metrics`` says which of the two served."""
+    import functools
+
+    from agentainer_tpu.models import hybrid
+    from agentainer_tpu.ops import pallas_mla
+
+    turns = [("a first prompt long enough to be cut into more than three chunks of thirty-two tokens, "
+              "the last of them a bucket with padding", 9), ("and a second turn", 7), ("a third", 5)]
+
+    def tokens(eng):
+        try:
+            return asyncio.run(chat_all(eng, turns=turns)), eng.metrics()["attention"]
+        finally:
+            eng.shutdown()
+
+    want, said = tokens(make_engine(skip_warmup=True))
+    assert said["mla_prefill"] == "xla_absorbed"
+    planned = lambda cfg, use_pallas=None: plan_hybrid(cfg, False)._replace(mla_prefill="pallas_mla_prefill")  # noqa: E731
+    interpreted = functools.partial(pallas_mla.mla_prefill, interpret=True)
+    with mock.patch.object(hybrid, "plan_hybrid", planned), mock.patch.object(pallas_mla, "mla_prefill", interpreted):
+        got, said = tokens(make_engine(skip_warmup=True))
+    assert said["mla_prefill"] == said["prefill"] == "pallas_mla_prefill" and said["mla_decode"] == "xla_absorbed"
+    assert got == want and sum(map(len, got)) == 21
 
 
 @pytest.mark.parametrize("step", ["jit_decode_n", "jit_prefill"])
@@ -670,3 +749,5 @@ def test_kernel_names_are_the_ones_the_benchmarks_readers_look_for():
         assert f'name="{name}"' in inspect.getsource(module)
         with open(os.path.join(REPO, "benchmark", "layer_metrics", f"{name}_roofline.py")) as f:
             assert re.search(rf'^KERNEL = "{name}"$', f.read(), re.M)
+    # no reader yet: the name a trace's ops carry, for the one that will come
+    assert 'name="mla_prefill"' in inspect.getsource(pallas_mla)

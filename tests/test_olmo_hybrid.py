@@ -396,6 +396,13 @@ def test_plan_takes_the_kernels_where_the_tiles_are_whole():
     assert hybrid.plan_hybrid(CFG, use_pallas=False).describe()["arena"] == "layer_slice"
     kimi = hybrid.plan_hybrid(get_config("tiny-kimi-linear"), use_pallas=False)
     assert set(kimi.describe()) == {"kda_decode", "kda_prefill", "mla_decode", "mla_prefill", "reason", "prefill", "decode", "arena"}
+    # the other family's planner gives what it gave before Kimi's MLA prefill got a kernel (PR 38)
+    assert plan == hybrid.HybridPlan(
+        "", "", "", "", "tpu backend; state and K/V stacks read where they lie",
+        gdn_decode="pallas_gdn_decode", gdn_prefill="xla_chunked",
+        full_decode="pallas:flash_decode", full_prefill="pallas:flash_prefill",
+    )
+    assert set(plan.describe()) == {"gdn_decode", "gdn_prefill", "full_decode", "full_prefill", "reason", "prefill", "decode", "arena"}
 
 
 # -- the cache manager on K/V rows and a recurrent state in one slot ----------------

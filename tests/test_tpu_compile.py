@@ -396,8 +396,9 @@ def test_hybrid_step_holds_no_stack_sized_temporary_on_v5e(v5e, step):
     """Kimi-Linear's block at published widths (the dense layer and one
     period, K K K M K, 8 lanes of 1024), int8 as served, its kernels on: the
     KDA decode kernel updates the layer of the float32 state stack in place
-    and the MLA decode kernel reads the latent stack where it lies (Mosaic
-    accepts both: a ``[bk, 640]`` row block, a ``[8, 128, 128]`` state tile),
+    and the MLA decode and prefill kernels read the latent stack where it lies
+    (Mosaic accepts: a ``[bk, 640]`` row block, a ``[8, 128, 128]`` state tile,
+    a ``[16 · 32, 640]`` query tile under the prefill kernel's VMEM plan),
     and through the layer scan, the mixers' 0-or-1-trip loops and the step
     scan no stack is copied. Three things this guards were all found by this
     compile and by nothing on the CPU (PR 30): ``lax.cond`` over the mixers
@@ -406,13 +407,17 @@ def test_hybrid_step_holds_no_stack_sized_temporary_on_v5e(v5e, step):
     chip keep the arena position-minor and relayout it into and out of every
     launch; a conv state ``[.., 3, 12288]`` was padded 42-fold."""
     _, cache, plan, steps = _hybrid_case("kimi-5l", SingleDeviceSharding(v5e.devices[0]))
-    assert (plan.kda_decode, plan.mla_decode) == ("pallas_kda_decode", "pallas_mla_decode")
+    assert (plan.kda_decode, plan.mla_decode, plan.mla_prefill) == ("pallas_kda_decode", "pallas_mla_decode", "pallas_mla_prefill")
     fn, args = steps[step]
     compiled = fn.lower(*args).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "InvertDiagBlocks" not in text  # what a triangular_solve becomes here
     if step == "decode":
         assert "kda_decode" in text and "mla_decode" in text  # the names the roofline readers find
+    else:
+        # the chunk's scores stay in the kernel's VMEM: no float32 [heads, T, S]
+        # buffer (134 MB a layer-chunk at 4,096 positions before PR 38)
+        assert "mla_prefill" in text and not re.search(r"f32\[(1,)?32,256,1024\]", text)
     stacks = {name: getattr(cache, name) for name in ("latent", "state", "conv")}
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= sum(s.size * s.dtype.itemsize for s in stacks.values())  # every leaf donated in place

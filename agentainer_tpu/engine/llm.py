@@ -157,6 +157,23 @@ _WINDOW_OFF = {
 }
 
 
+# A cache of latent rows and nothing else (the hybrid block with MLA in every
+# layer and no linear mixer): one ``[n_mla, B, S, W]`` leaf of POSITIONAL rows,
+# no per-lane state. Truncating, copying at a boundary, paging, parking or
+# overwriting such rows would all be harmless, as for k and v: none of the
+# reasons in ``_RECURRENT_OFF`` holds. What keeps each feature off is that its
+# mechanism was written for the pair of leaves (k, v) and has not been taken
+# through a named latent leaf (ROADMAP Reach B3).
+_LATENT_OFF = {
+    "speculative": "the verify program and its rewind read and truncate k and v leaves; no verify step is built over the latent leaf (a rejected draft's rows would be overwritten harmlessly)",
+    "fused_decode": "the fused loop carries k and v and speculates inside the loop; it has no body over the latent leaf",
+    "paged_kv": "the page pool and its block tables hold k and v pages; the latent kernels' index maps address a dense stack, not a table of pages",
+    "kv_tiering": "the host tier parks and promotes k and v rows; a latent leaf is not in its transfers (a lane's rows would move like any positional rows)",
+    "prefix_cache": "the prefix arena copies k and v rows 0..n into a fresh lane; a fork of latent rows at a boundary would be as harmless, and no program copies the latent leaf",
+    "mesh": "the hybrid block is served on one chip (a share of the experts is cfg.experts_held); the latent kernels have no shard_map form",
+}
+
+
 def cache_features(cfg: ModelConfig, asked: dict) -> tuple[dict, dict]:
     """``asked``: feature → what the caller gave (``None``: nothing, take
     the default). Returns (feature → on/off, feature → reason it is off for
@@ -177,6 +194,8 @@ def cache_features(cfg: ModelConfig, asked: dict) -> tuple[dict, dict]:
 
 def _cache_off(cfg: ModelConfig) -> tuple[dict | None, str]:
     """The features a family's cache turns off, with what the cache is."""
+    if cfg.is_hybrid and cfg.linear_kind is None:
+        return _LATENT_OFF, "keeps latent rows in a leaf the k/v mechanisms do not take"
     if cfg.is_hybrid:
         return _RECURRENT_OFF, "keeps a recurrent state in its cache"
     if cfg.n_window:
@@ -611,7 +630,8 @@ class LLMEngine:
         self._named_leaves = self._recurrent or self._windowed
         if self._cache_off:
             kinds = (
-                f"kinds={'+'.join(sorted(set(cfg.layer_kinds)))} (positional rows + per-lane state)"
+                f"kinds={'+'.join(sorted(set(cfg.layer_kinds)))} "
+                + ("(positional rows + per-lane state)" if cfg.linear_kind else "(positional rows, no per-lane state)")
                 if self._recurrent
                 else f"global rows x{cfg.n_global} + a ring of the last rows x{cfg.n_window} (window {cfg.window})"
             )
@@ -1413,6 +1433,24 @@ class LLMEngine:
         self.attention.update(
             decode_block_positions=self._decode_bk, decode_blocks_live=0, decode_blocks_stored=0
         )
+        # the same count for a latent leaf (``mla_decode``'s index map: a
+        # stepping lane at position p fetches ``p // bk + 1`` blocks of its
+        # row, a layer counted once); absent where the cache has no such leaf
+        latent = getattr(self.cache, "latent", None)
+        self._latent_bk = 0
+        if latent is not None:
+            from ..ops.pallas_mla import decode_block_rows
+
+            self._latent_bk = decode_block_rows(latent.shape[3], latent.dtype.itemsize, latent.shape[2])
+            self.attention.update(
+                latent_block_positions=self._latent_bk, latent_decode_blocks_live=0, latent_decode_blocks_stored=0
+            )
+        if cfg.rope_original_max:
+            # rows (prefill and decode) at or past the position from which the
+            # scaled frequencies and the query's scale differ from plain RoPE
+            self.attention.update(
+                rope_original_max=cfg.rope_original_max, rows_past_original_max=0, rows_positioned=0
+            )
         if self._windowed:
             # the same count by kind of layer (a layer of each kind counted
             # once): ``global_*`` is what the first pair counts; ``window_*``
@@ -4557,7 +4595,7 @@ class LLMEngine:
                     jnp.int32(slot.position + len(prompt) + req.max_tokens - 1),
                     jnp.int32(-1 if req.ignore_eos else self.tokenizer.eos_id),
                 )
-            self.state_resets += int(fresh)
+            self.state_resets += int(fresh and self.cache.state is not None)
         # admit: the slot is busy from here; the worker's prefill tick feeds
         # the prompt through chunk-by-chunk, interleaved with decode steps
         slot.request = req
@@ -4813,11 +4851,18 @@ class LLMEngine:
         rows hold, a head block and a layer counted once. From what the
         worker knows at dispatch, never from the device."""
         bk = self._decode_bk
-        if not bk:
+        if not (bk or self._latent_bk or self.cfg.rope_original_max):
             return
         pos = np.asarray(positions, np.int64).reshape(-1, 1) + np.arange(steps)
+        self._count_positioned(pos)
         pos = np.where(pos >= self.max_seq - 1, 0, pos)
         parked = steps * (self.max_batch - len(positions))
+        if self._latent_bk:
+            lb = self._latent_bk
+            self.attention["latent_decode_blocks_live"] += int((pos // lb + 1).sum()) + parked
+            self.attention["latent_decode_blocks_stored"] += steps * self.max_batch * -(-self.max_seq // lb)
+        if not bk:
+            return
         live = int((pos // bk + 1).sum()) + parked
         stored = steps * self.max_batch * -(-self.max_seq // bk)
         self.attention["decode_blocks_live"] += live
@@ -4835,6 +4880,14 @@ class LLMEngine:
             # them (a parked lane's query sees nothing anyone needs)
             a["global_decode_rows"] += int((pos + 1).sum())
             a["window_decode_rows"] += int(np.minimum(pos + 1, self.cfg.window).sum())
+
+    def _count_positioned(self, positions: np.ndarray) -> None:
+        """Rows a launch gives these positions (a chunk's real tokens, or the
+        stepping lanes' steps), and those of them at or past
+        ``rope_original_max``; nothing where the model has no such boundary."""
+        if self.cfg.rope_original_max:
+            self.attention["rows_positioned"] += int(positions.size)
+            self.attention["rows_past_original_max"] += int((positions >= self.cfg.rope_original_max).sum())
 
     def _bucket(self, n: int) -> int:
         for b in PREFILL_BUCKETS:
@@ -4979,6 +5032,7 @@ class LLMEngine:
                 )
         self.prefill_launches += 1
         self.prefill_tokens += n
+        self._count_positioned(positions[:n])
         self._count_forward(bucket + self.max_batch if riders else bucket)
         # n real tokens, each attending ~its own position of context
         self.flops_done += n * self.cfg.flops_per_token(slot.position + n // 2)

@@ -30,7 +30,7 @@ _QUANT_KEYS = {
     "embed", "lm_head",
     # the hybrid block's matrices (models/hybrid.param_shapes); its vectors —
     # norms, a_log, dt_bias, conv filters, the selection bias — stay dense
-    "wqkv", "w_fa", "w_fb", "w_beta", "w_ga", "w_gb", "wkva", "wkvb",
+    "wqkv", "w_fa", "w_fb", "w_beta", "w_ga", "w_gb", "wkva", "wkvb", "wq_a", "wq_b",
     "ws_gate", "ws_up", "ws_down", "w_a", "w_g",
 }
 

@@ -7,7 +7,9 @@ its two switches; Kimi-Linear-48B-A3B is the hybrid block
 a dense first layer, then sigmoid-routed experts with a shared one —
 models/hybrid.py); Olmo-Hybrid-7B is the same block's other pair of mixers
 (a gated delta rule with one decay a head beside full softmax attention, a
-dense SwiGLU in every layer, the OLMo-2 norm placement). Tiny variants exist for CI and the virtual CPU mesh —
+dense SwiGLU in every layer, the OLMo-2 norm placement); Mistral-Small-4-119B
+is that block with latent attention in every layer and no linear mixer (a
+low-rank query, a YaRN rotary embedding on the shared key dims). Tiny variants exist for CI and the virtual CPU mesh —
 same code path, small shapes.
 
 All dims are chosen TPU-aware: head_dim and hidden sizes are multiples of
@@ -16,6 +18,7 @@ All dims are chosen TPU-aware: head_dim and hidden sizes are multiples of
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, field
 
 
@@ -69,6 +72,30 @@ class ModelConfig:
     mla_nope_dim: int = 0  # per-head key dims expanded from the latent
     mla_rope_dim: int = 0  # key dims shared by all heads, cached beside c
     mla_v_dim: int = 0
+    # -- MLA's switches; every default is "as Kimi-Linear" --------------------
+    # the query's own low-rank pair: q = W_qb · RMSNorm(W_qa x) with a latent
+    # of this width (``q_lora_rank``). 0: one full-rank ``wq``
+    mla_q_rank: int = 0
+    # the ``mla_rope_dim`` shared key dims and the query's matching part carry
+    # a rotary embedding (``rope_theta`` and the ``rope_*`` fields below); the
+    # cached row then holds the ROTATED k_s. False: no rotary embedding
+    # anywhere (Kimi-Linear's ``mla_use_nope``)
+    mla_rotary: bool = False
+    # the rotation pairs dims (2i, 2i + 1) instead of (i, i + d/2)
+    rope_interleave: bool = False
+    # YaRN (``rope_type: yarn``): frequencies whose wavelength passes
+    # ``rope_original_max`` are divided by ``rope_factor``, those that turn
+    # ``rope_beta_fast`` times in it are kept, a linear ramp between
+    # (``ops/rope.yarn_frequencies``). 1: plain RoPE
+    rope_factor: float = 1.0
+    rope_original_max: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    # the softmax scale takes (0.1 · this · ln rope_factor + 1)² (``softmax_mscale``)
+    rope_mscale_all_dim: float = 0.0
+    # Llama-4's query scale by position: q · (1 + this · ln(1 + ⌊p /
+    # rope_original_max⌋)), 1 below ``rope_original_max``. 0: none
+    q_pos_scale_beta: float = 0.0
     # the first ``n_dense_layers`` have a dense SwiGLU of ``dense_ffn_dim``;
     # the rest are MoE with ``ffn_dim`` wide experts
     n_dense_layers: int = 0
@@ -117,6 +144,10 @@ class ModelConfig:
             raise ValueError("window_layers names window layers and window is 0")
         if self.ffn_act not in ("silu", "relu"):
             raise ValueError(f"ffn_act {self.ffn_act!r}: silu or relu")
+        if (self.rope_factor != 1.0 or self.q_pos_scale_beta) and self.rope_original_max <= 0:
+            raise ValueError("rope_factor and q_pos_scale_beta are read against rope_original_max, which is 0")
+        if self.mla_rotary and (self.mla_rope_dim % 2 or not self.rope_theta):
+            raise ValueError("mla_rotary rotates an even mla_rope_dim by a rope_theta that is not 0")
 
     @property
     def head_dim(self) -> int:
@@ -168,6 +199,15 @@ class ModelConfig:
         return sum(k in POSITIONAL_KINDS for k in self.layer_kinds)
 
     @property
+    def softmax_mscale(self) -> float:
+        """YaRN's correction of the softmax scale, squared where it is used
+        (``scale · m²``): ``m = 0.1 · mscale_all_dim · ln(factor) + 1``; 1
+        where the config scales no rotary embedding."""
+        if self.rope_factor <= 1.0 or not self.rope_mscale_all_dim:
+            return 1.0
+        return 0.1 * self.rope_mscale_all_dim * math.log(self.rope_factor) + 1.0
+
+    @property
     def delta_v_dim(self) -> int:
         return self.kda_v_dim or self.kda_head_dim
 
@@ -182,15 +222,21 @@ class ModelConfig:
             + h + h * hk + hk  # A_log, dt_bias, head norm
         )
         qk = self.mla_nope_dim + self.mla_rope_dim
+        # the query: one matrix, or the low-rank pair and its norm vector
+        wq = d * self.n_heads * qk
+        if self.mla_q_rank:
+            wq = d * self.mla_q_rank + self.mla_q_rank + self.mla_q_rank * self.n_heads * qk
         mla = (
-            d * self.n_heads * qk
+            wq
             + d * (self.mla_kv_rank + self.mla_rope_dim)
             + self.mla_kv_rank * self.n_heads * (self.mla_nope_dim + self.mla_v_dim)
             + self.n_heads * self.mla_v_dim * d
             + self.mla_kv_rank
         )
         expert = 3 * d * self.ffn_dim
-        moe = d * self.n_experts + self.n_experts + self.n_shared_experts * expert
+        # the router, its selection bias (the sigmoid rule's) and the shared expert
+        bias = self.n_experts if self.moe_router == "sigmoid" else 0
+        moe = d * self.n_experts + bias + self.n_shared_experts * expert
         ck, cv = h * hk, h * self.delta_v_dim
         gdn = (
             d * (2 * ck + cv) + cv * d  # q, k, v, o
@@ -473,6 +519,90 @@ TINY_KIMI_LINEAR = register(
         n_shared_experts=1,
         moe_router="sigmoid",
         moe_scale=2.446,
+    )
+)
+
+# Mistral-Small-4-119B-2603 (mistralai/Mistral-Small-4-119B-2603 config.json,
+# ``model_type: mistral4``, the text decoder: 36 layers, hidden 4096, every
+# layer MLA with 32 heads (latent 256 + 64 shared key dims, q/k 64 + 64, v
+# 128) and a low-rank query (1024), the shared key dims and the query's
+# matching half rotated in adjacent pairs with YaRN's frequencies (factor 128
+# over an original 8,192, theta 1e4), the softmax scale x (0.1 ln 128 + 1)^2,
+# the query x (1 + 0.1 ln(1 + floor(p / 8192))); no dense layer
+# (``first_k_dense_replace`` 0: the published ``intermediate_size`` 12288 is
+# used by none), 128 experts of 2048 top-4 behind a softmax router
+# renormalised over the chosen four, one shared expert; vocabulary 131,072,
+# untied). All 128 experts: 119.0 B parameters — a chip serves its share
+# (``experts_held``; benchmark/configs).
+MISTRAL_SMALL_4_119B = register(
+    ModelConfig(
+        name="mistral-small-4-119b",
+        vocab_size=131_072,
+        dim=4096,
+        n_layers=36,
+        n_heads=32,
+        n_kv_heads=32,
+        ffn_dim=2048,
+        max_seq_len=1_048_576,
+        rope_theta=10_000.0,
+        norm_eps=1e-6,
+        n_experts=128,
+        experts_per_token=4,
+        moe_renormalize=True,
+        layer_kinds=("mla",) * 36,
+        mla_kv_rank=256,
+        mla_nope_dim=64,
+        mla_rope_dim=64,
+        mla_v_dim=128,
+        mla_q_rank=1024,
+        mla_rotary=True,
+        rope_interleave=True,
+        rope_factor=128.0,
+        rope_original_max=8192,
+        rope_beta_fast=32.0,
+        rope_beta_slow=1.0,
+        rope_mscale_all_dim=1.0,
+        q_pos_scale_beta=0.1,
+        n_shared_experts=1,
+        moe_router="softmax",
+    )
+)
+
+# The same block at CI shapes: 3 MLA layers and nothing else, a query latent
+# narrower than the hidden size, 16 rotated dims whose YaRN ramp has a pair
+# strictly inside it (original context 32, factor 8: a 256-token test crosses
+# the boundary eight times), 8 experts top-2 with a shared one.
+TINY_MISTRAL4 = register(
+    ModelConfig(
+        name="tiny-mistral4",
+        vocab_size=512,
+        dim=64,
+        n_layers=3,
+        n_heads=4,
+        n_kv_heads=4,
+        ffn_dim=32,
+        max_seq_len=256,
+        rope_theta=10_000.0,
+        norm_eps=1e-6,
+        n_experts=8,
+        experts_per_token=2,
+        moe_renormalize=True,
+        layer_kinds=("mla",) * 3,
+        mla_kv_rank=32,
+        mla_nope_dim=16,
+        mla_rope_dim=16,
+        mla_v_dim=16,
+        mla_q_rank=24,
+        mla_rotary=True,
+        rope_interleave=True,
+        rope_factor=8.0,
+        rope_original_max=32,
+        rope_beta_fast=32.0,
+        rope_beta_slow=1.0,
+        rope_mscale_all_dim=1.0,
+        q_pos_scale_beta=0.1,
+        n_shared_experts=1,
+        moe_router="softmax",
     )
 )
 

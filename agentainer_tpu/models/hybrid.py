@@ -1,5 +1,6 @@
-"""The hybrid block (Kimi-Linear, Olmo-Hybrid): a linear mixer beside a
-positional one, and two FFNs, through the one forward.
+"""The hybrid block (Kimi-Linear, Olmo-Hybrid, Mistral-Small-4): a linear
+mixer beside a positional one, or a positional one alone, and two FFNs,
+through the one forward.
 
 ``models/llama.forward`` hands a config with ``layer_kinds`` to
 :func:`forward` here; the engine calls one ``forward`` and never learns a
@@ -10,9 +11,17 @@ with
   v through a short causal depthwise conv and SiLU, l2-normalised q and k, a
   per-channel decay from a low-rank pair, a sigmoid β, a head-wise RMSNorm
   and a low-rank sigmoid gate on the output) or **MLA** (``ops/mla.py``:
-  latent attention with no rotary embedding anywhere; the cache row is the
-  normalised latent and the shared key dimensions, the up-projection
-  absorbed into query and output);
+  latent attention; the cache row is the normalised latent and the shared
+  key dimensions, the up-projection absorbed into query and output. With no
+  rotary embedding anywhere, a full-rank query: Kimi-Linear. Under
+  ``cfg.mla_q_rank`` the query has its own low-rank pair and norm; under
+  ``cfg.mla_rotary`` the shared key dimensions and the query's matching part
+  are rotated (``ops/rope.py``: YaRN's frequencies, adjacent pairs) BEFORE
+  the row is written and the query absorbed, so the cached row holds a
+  rotated ``k_s`` and absorbs as before; the softmax scale takes YaRN's
+  ``m²`` and the query Llama-4's scale by position: Mistral-Small-4, every
+  layer of which is such an MLA layer, with no linear mixer and no per-lane
+  state at all);
 - or the mixer **GDN** (Gated DeltaNet: the same delta rule with ONE decay a
   head, ``β = 2 · sigmoid`` where the model allows negative eigenvalues, keys
   narrower than values, so a rectangular state, and a full-rank SiLU output
@@ -20,8 +29,8 @@ with
   kernels, QK-norm over the whole projections, no rotary embedding where the
   model has none). A model has one linear kind and one positional kind;
 - the FFN a dense SwiGLU (the first ``n_dense_layers``) or the MoE of
-  ``models/llama.py`` with the sigmoid router rule, a shared expert and the
-  experts this chip holds;
+  ``models/llama.py`` with the sigmoid router rule (or the softmax rules
+  of ``llama.moe_gates``), a shared expert and the experts this chip holds;
 - the norms before each sublayer, or (``cfg.post_norm``, the OLMo-2 family)
   on each sublayer's OUTPUT: ``x += rmsnorm(mixer(x)); x += rmsnorm(ffn(x))``.
 
@@ -39,8 +48,10 @@ stored, hd]`` (:func:`stored_kv_heads`), read up to a lane's position like a
 K/V arena — and per-lane state ``state`` float32 (``[n_kda, B, H, dk, dv]``,
 or for GDN ``[n_gdn, B, dk, H·dv]``: whole tiles at 96 × 192 a head) and
 ``conv [n, B, (W − 1)·channels]`` — which cannot be truncated, rewound or
-overwritten harmlessly. A leaf the model has no layer for is ``None``. All
-ride in the scan's carry and are updated in place.
+overwritten harmlessly. A leaf the model has no layer for is ``None`` (a
+model with no linear kind has no ``state`` and no ``conv``: its cache is
+positional rows and the two control leaves). All ride in the scan's carry
+and are updated in place.
 
 **Masking is part of the mathematics.** A token that is not valid leaves
 state and conv untouched (β = 0, g = 0, conv not shifted). Prefill says
@@ -67,6 +78,7 @@ from ..ops import mla as mla_ops
 from ..ops.moe import EXPERT_WEIGHTS, stacked_experts
 from ..ops.norms import rms_norm
 from ..ops.quant import QTensor, dequant, embed_lookup
+from ..ops.rope import apply_rope, yarn_frequencies
 from .configs import LINEAR_KINDS, POSITIONAL_KINDS, ModelConfig
 
 NO_STOP = np.iinfo(np.int32).max
@@ -80,8 +92,8 @@ class HybridCache(NamedTuple):
     controls (module docstring)."""
 
     latent: jnp.ndarray | None  # [n_mla, B, S, latent_width]: R + r values, zero padding
-    state: jnp.ndarray  # [n_kda, B, H, dk, dv] or [n_gdn, B, dk, H·dv], float32
-    conv: jnp.ndarray  # [n, B, (W - 1)·channels]: the last W − 1 conv inputs, row after row
+    state: jnp.ndarray | None  # [n_kda, B, H, dk, dv] or [n_gdn, B, dk, H·dv], float32
+    conv: jnp.ndarray | None  # [n, B, (W - 1)·channels]: the last W − 1 conv inputs, row after row
     stop: jnp.ndarray  # [B] int32
     eos: jnp.ndarray  # [B] int32 (-1: no token closes the lane)
     k: jnp.ndarray | None = None  # [n_full, B, S, stored_kv_heads, hd]
@@ -116,8 +128,9 @@ def conv_channels(cfg: ModelConfig) -> int:
 
 
 def latent_width(cfg: ModelConfig) -> int:
-    """Columns of a stored latent row: the ``R + r`` values (576 published)
-    and zeros up to whole 128-lane tiles (640). Unpadded, the TPU keeps the
+    """Columns of a stored latent row: the ``R + r`` values (Kimi-Linear's
+    576, Mistral-Small-4's 320) and zeros up to whole 128-lane tiles (640,
+    384). Unpadded, the TPU keeps the
     arena position-minor to save the padding itself, and every step program
     that hands it to a kernel relayouts it on the way in and out (compiled
     for a described v5e: two 2.1 GB copies a launch)."""
@@ -135,13 +148,14 @@ def init_cache(
         raise ValueError("the hybrid block has one linear kind and one positional kind of mixer")
     arena = (cfg.n_positional, lanes, max_seq, stored_kv_heads(cfg.n_kv_heads), cfg.head_dim)
     full = cfg.positional_kind == "full"
+    state_shape = (nl, lanes, dk, h * dv) if cfg.linear_kind == "gdn" else (nl, lanes, h, dk, dv)
     return HybridCache(
         latent=jnp.zeros((cfg.n_mla, lanes, max_seq, latent_width(cfg)), dtype) if cfg.n_mla else None,
-        state=jnp.zeros((nl, lanes, dk, h * dv) if cfg.linear_kind == "gdn" else (nl, lanes, h, dk, dv), jnp.float32),
+        state=jnp.zeros(state_shape, jnp.float32) if nl else None,
         # the W − 1 rows of a lane side by side: a dimension of 3 next to the
         # channels would be padded to a whole sublane tile (or, minor-most,
         # to 128 lanes: compiled for a described v5e, 94 MB became 3.75 GB)
-        conv=jnp.zeros((nl, lanes, (cfg.kda_conv - 1) * conv_channels(cfg)), dtype),
+        conv=jnp.zeros((nl, lanes, (cfg.kda_conv - 1) * conv_channels(cfg)), dtype) if nl else None,
         stop=jnp.full((lanes,), NO_STOP if live else 0, jnp.int32),
         eos=jnp.full((lanes,), -1, jnp.int32),
         k=jnp.zeros(arena, dtype) if full else None,
@@ -157,14 +171,15 @@ def admit_lane(cache: HybridCache, lane, fresh, stop, eos) -> HybridCache:
     position ``stop`` (exclusive) and closes on ``eos``. ``fresh``: a new
     context starts from zero state (the latent rows need no reset: they are
     read only up to the position)."""
+    cache = cache._replace(stop=cache.stop.at[lane].set(stop), eos=cache.eos.at[lane].set(eos))
+    if cache.state is None:  # no linear kind: nothing of a lane outlives its rows
+        return cache
     keep = jnp.where(fresh, 0.0, 1.0)
     lane_of = lambda a: lax.dynamic_slice_in_dim(a, lane, 1, axis=1)  # noqa: E731
     put = lambda a, v: lax.dynamic_update_slice_in_dim(a, v, lane, axis=1)  # noqa: E731
     return cache._replace(
         state=put(cache.state, lane_of(cache.state) * keep),
         conv=put(cache.conv, lane_of(cache.conv) * keep.astype(cache.conv.dtype)),
-        stop=cache.stop.at[lane].set(stop),
-        eos=cache.eos.at[lane].set(eos),
     )
 
 
@@ -215,6 +230,8 @@ class HybridPlan(NamedTuple):
     gdn_prefill: str = ""
     full_decode: str = ""
     full_prefill: str = ""
+    # the rotary embedding of the MLA layers' shared key dims ("": none)
+    mla_rotary: str = ""
 
     def describe(self) -> dict:
         mine = {k: v for k, v in self._asdict().items() if v}
@@ -235,14 +252,28 @@ def plan_hybrid(cfg: ModelConfig, use_pallas: bool | None = None) -> HybridPlan:
         use_pallas = jax.default_backend() == "tpu"
     if cfg.linear_kind == "gdn" or cfg.positional_kind == "full":
         return _plan_gdn_full(cfg, use_pallas)
+    if cfg.linear_kind is None:
+        # latent attention alone: no state kernel to choose, and the latent
+        # kernels take any width that is whole lane tiles (``latent_width``)
+        mla = ("pallas_mla_decode", "pallas_mla_prefill") if use_pallas else ("xla_absorbed",) * 2
+        why = "tpu backend; the latent stack read where it lies" if use_pallas else "no tpu backend"
+        return HybridPlan("", "", *mla, why, mla_rotary=rotary_kind(cfg))
     aligned = cfg.kda_head_dim % 128 == 0 and cfg.kda_heads % 8 == 0
     if use_pallas and aligned:
         return HybridPlan(
             "pallas_kda_decode", "xla_chunked", "pallas_mla_decode", "pallas_mla_prefill",
-            "tpu backend; state and latent stacks read where they lie",
+            "tpu backend; state and latent stacks read where they lie", mla_rotary=rotary_kind(cfg),
         )
     why = "no tpu backend" if not use_pallas else "KDA heads not (8, 128)-aligned"
-    return HybridPlan("xla_step", "xla_chunked", "xla_absorbed", "xla_absorbed", why)
+    return HybridPlan("xla_step", "xla_chunked", "xla_absorbed", "xla_absorbed", why, mla_rotary=rotary_kind(cfg))
+
+
+def rotary_kind(cfg: ModelConfig) -> str:
+    """The MLA layers' rotary embedding in words, for the plan's description."""
+    if not cfg.mla_rotary:
+        return ""
+    freqs = f"yarn x{cfg.rope_factor:g} past {cfg.rope_original_max}" if cfg.rope_factor > 1.0 else "rope"
+    return f"{freqs}, theta {cfg.rope_theta:g}, {'adjacent pairs' if cfg.rope_interleave else 'split halves'}"
 
 
 def _plan_gdn_full(cfg: ModelConfig, use_pallas: bool) -> HybridPlan:
@@ -327,7 +358,15 @@ def param_shapes(cfg: ModelConfig) -> dict:
         },
         "full": full,
         "mla": {
-            "wq": ((nm, d, cfg.n_heads * qk), True),
+            **(
+                {
+                    "wq_a": ((nm, d, cfg.mla_q_rank), True),
+                    "q_norm": ((nm, cfg.mla_q_rank), False),
+                    "wq_b": ((nm, cfg.mla_q_rank, cfg.n_heads * qk), True),
+                }
+                if cfg.mla_q_rank
+                else {"wq": ((nm, d, cfg.n_heads * qk), True)}
+            ),
             "wkva": ((nm, d, cfg.mla_kv_rank + cfg.mla_rope_dim), True),
             "kv_norm": ((nm, cfg.mla_kv_rank), False),
             "wkvb": ((nm, cfg.mla_kv_rank, cfg.n_heads * (cfg.mla_nope_dim + cfg.mla_v_dim)), True),
@@ -340,7 +379,8 @@ def param_shapes(cfg: ModelConfig) -> dict:
         },
         "moe": {
             "router": ((ne, d, cfg.n_experts), True),
-            "router_bias": ((ne, cfg.n_experts), False),
+            # the selection bias is the sigmoid rule's (``llama.moe_gates``)
+            **({"router_bias": ((ne, cfg.n_experts), False)} if cfg.moe_router == "sigmoid" else {}),
             "w_gate": ((ne, cfg.n_held, d, cfg.ffn_dim), True),
             "w_up": ((ne, cfg.n_held, d, cfg.ffn_dim), True),
             "w_down": ((ne, cfg.n_held, cfg.ffn_dim, d), True),
@@ -593,25 +633,73 @@ def full_mixer(h, lp, cfg: ModelConfig, ck, cv, idx, slot, positions, valid, pla
     return _proj(o.reshape(b, t, nh * hd).astype(h.dtype), lp["wo"]), ck, cv
 
 
+def _mla_rotate(x, positions, cfg: ModelConfig):
+    """``x [B, T, n, r]`` float32 rotated by its tokens' positions with the
+    model's frequencies and pairing (``ops/rope.py``)."""
+    inv_freq = None
+    if cfg.rope_factor > 1.0:
+        inv_freq = yarn_frequencies(
+            x.shape[-1], cfg.rope_theta, cfg.rope_factor, cfg.rope_original_max, cfg.rope_beta_fast, cfg.rope_beta_slow
+        )
+    return apply_rope(x, positions, cfg.rope_theta, interleave=cfg.rope_interleave, inv_freq=inv_freq)
+
+
+def _mla_query(h, lp, cfg: ModelConfig, positions):
+    """The layer's queries ``[B, T, H, nope + r]`` in the weights' dtype, as
+    the absorption takes them. A full-rank ``wq`` with nothing after it
+    (Kimi-Linear) is rounded where it leaves the matmul; otherwise the
+    low-rank pair, the rotation of the last ``r`` dims and the scale by
+    position are float32 and the query is rounded once, after them."""
+    b, t, _ = h.shape
+    shape = (b, t, cfg.n_heads, cfg.mla_nope_dim + cfg.mla_rope_dim)
+    if cfg.mla_q_rank:
+        with jax.named_scope("mla_q_lora"):
+            c_q = rms_norm(_proj(h, lp["wq_a"]), lp["q_norm"], cfg.norm_eps).astype(h.dtype)
+            q = _proj(c_q, lp["wq_b"])
+    else:
+        q = _proj(h, lp["wq"])
+    if not (cfg.mla_rotary or cfg.q_pos_scale_beta):
+        return q.astype(h.dtype).reshape(shape)
+    q = q.reshape(shape)
+    with jax.named_scope("mla_rope"):
+        if cfg.mla_rotary:
+            q = jnp.concatenate(
+                [q[..., : cfg.mla_nope_dim], _mla_rotate(q[..., cfg.mla_nope_dim :], positions, cfg)], axis=-1
+            )
+        if cfg.q_pos_scale_beta:
+            # Llama-4's scale: 1 inside the original context, then a step a
+            # whole multiple of it (the softmax sharpens as the context grows)
+            past = (positions // cfg.rope_original_max).astype(jnp.float32)
+            q = q * (1.0 + cfg.q_pos_scale_beta * jnp.log1p(past))[..., None, None]
+    return q.astype(h.dtype)
+
+
 def mla_mixer(h, lp, cfg: ModelConfig, latent, idx, slot, positions, valid, plan: HybridPlan):
     """``h [B, T, d]`` (normed) → the mixer's output and the latent stack with
     this step's rows written at their positions (rows past S drop). A lane
     that does not step (``valid`` false: parked at the arena's last row)
     attends to one row instead of all S: its output is nobody's. Where the
     plan names the kernels, neither call shape slices a lane's row out of the
-    stack or writes scores to HBM."""
+    stack or writes scores to HBM. Under ``cfg.mla_rotary`` the row's shared
+    key dims are written ROTATED by the row's position, and the query's
+    matching dims by the query's: their product depends on the distance, and
+    the row absorbs like an unrotated one."""
     b, t, _ = h.shape
     nh, rank, nope = cfg.n_heads, cfg.mla_kv_rank, cfg.mla_nope_dim
-    q = _proj(h, lp["wq"]).astype(h.dtype).reshape(b, t, nh, nope + cfg.mla_rope_dim)
+    q = _mla_query(h, lp, cfg, positions)
     ckv = _proj(h, lp["wkva"])
     pad = jnp.zeros((b, t, latent.shape[-1] - ckv.shape[-1]), ckv.dtype)
-    row = jnp.concatenate([rms_norm(ckv[..., :rank], lp["kv_norm"], cfg.norm_eps), ckv[..., rank:], pad], -1)
+    c, k_s = rms_norm(ckv[..., :rank], lp["kv_norm"], cfg.norm_eps), ckv[..., rank:]
+    if cfg.mla_rotary:
+        with jax.named_scope("mla_rope"):
+            k_s = _mla_rotate(k_s[:, :, None], positions, cfg)[:, :, 0]
+    row = jnp.concatenate([c, k_s, pad], -1)
     lanes = jnp.arange(b)[:, None] + (0 if slot is None else slot)
     latent = latent.at[idx, lanes, positions].set(row.astype(latent.dtype))
     w_kvb = dequant(lp["wkvb"]).reshape(rank, nh, nope + cfg.mla_v_dim)
     q_full = mla_ops.absorb_query(q, w_kvb, nope)  # float32: rounded once, where the scores take it
     q_full = jnp.pad(q_full, [(0, 0)] * 3 + [(0, pad.shape[-1])])  # zeros against the padding
-    scale = (nope + cfg.mla_rope_dim) ** -0.5
+    scale = (nope + cfg.mla_rope_dim) ** -0.5 * cfg.softmax_mscale**2
     if t == 1 and plan.mla_decode == "pallas_mla_decode":
         from ..ops.pallas_mla import mla_decode
 
@@ -734,7 +822,8 @@ def forward(
                 y = moe_impl(h, lp) if moe_impl is not None else _moe_mlp(h, lp, cfg, logits=logits)
             if cfg.n_shared_experts:
                 lp = _layer_of(shared, idx)
-                y = y.astype(jnp.float32) + _swiglu(h, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
+                with jax.named_scope("moe_shared_expert"):
+                    y = y.astype(jnp.float32) + _swiglu(h, lp["ws_gate"], lp["ws_up"], lp["ws_down"])
             return y.astype(jnp.float32)
 
         if not cfg.n_dense_layers:

@@ -109,6 +109,14 @@ def _mla_decode_kernel(
         o_ref[...] = (acc_ref[...] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
+def decode_block_rows(width: int, itemsize: int, seq_len: int, block_k: int = 512) -> int:
+    """Latent rows of one block of :func:`mla_decode` over an arena of
+    ``seq_len`` rows of ``width`` columns (what the engine's count of fetched
+    blocks divides a lane's position by)."""
+    fit = max(128, _ROWS_VMEM // (2 * width * itemsize) // 128 * 128)
+    return min(block_k, _round_up(seq_len, 128), fit)
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "rank", "block_k", "interpret"))
 def mla_decode(
     q_full: jnp.ndarray,  # [B, H, R + r] the absorbed queries
@@ -125,8 +133,7 @@ def mla_decode(
     """The combined latent ``[B, H, R]`` float32 of one token a lane."""
     b, h, w = q_full.shape
     s = latent.shape[2]
-    fit = max(128, _ROWS_VMEM // (2 * w * latent.dtype.itemsize) // 128 * 128)
-    bk = min(block_k, _round_up(s, 128), fit)
+    bk = decode_block_rows(w, latent.dtype.itemsize, s, block_k)
     n_blocks = pl.cdiv(s, bk)
 
     def rows_map(ib, ik, lay, slt, pos):

@@ -309,3 +309,48 @@ def test_config_from_hf_reads_a_smallthinker_config_as_the_benchmarks_family_doe
     (other / "config.json").write_text(json.dumps(stated))
     plain = config_from_hf(other)
     assert plain.head_size == 0 and not plain.window_layers and plain.ffn_act == "silu" and not plain.early_router
+
+
+def test_config_from_hf_reads_a_mistral4_config_as_the_benchmarks_family_does(tmp_path):
+    """``model_type: mistral4``: the published ``config.json`` (the benchmark's
+    configuration file holds its keys) gives the registered model — MLA in
+    every layer, the query's low-rank pair, the YaRN parameters, the query's
+    scale, the softmax router with a shared expert — and the same fields the
+    benchmark's family derives from the file as run. The hybrid block's
+    tensors' names wait for a checkpoint: loading refuses by name."""
+    import dataclasses
+    import os
+    import sys
+
+    from agentainer_tpu.engine.hf_convert import config_from_hf, load_hf_params
+
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs", "mistral-small-4-119b-ep4-1chip.json")) as f:
+        doc = json.load(f)
+    ours = ("name", "family", "source", "reduced", "assumed", "engine_options", "why_engine_options", "expert_parallel",
+            "pipeline", "experts_published", "layers_published", "hbm_claim_bytes_per_chip", "stands_for", "torch_dtype")
+    as_run = {k: v for k, v in doc.items() if k not in ours}
+    published = {**as_run, "n_routed_experts": doc["experts_published"], "num_hidden_layers": doc["layers_published"],
+                 "max_position_embeddings": 1_048_576}
+    from safetensors.numpy import save_file
+
+    for sub, conf in (("published", published), ("as_run", as_run)):
+        (tmp_path / sub).mkdir()
+        (tmp_path / sub / "config.json").write_text(json.dumps(conf))
+        save_file({"model.norm.weight": np.ones((4,), np.float32)}, str(tmp_path / sub / "model.safetensors"))
+    derived = config_from_hf(tmp_path / "published")
+    assert derived == dataclasses.replace(get_config("mistral-small-4-119b"), name=derived.name)
+    assert derived.mla_q_rank == 1024 and derived.rope_factor == 128.0 and derived.linear_kind is None
+    with pytest.raises(NotImplementedError, match="hybrid block"):
+        load_hf_params(derived, tmp_path / "published")
+    # the file as run, read by the benchmark's family: the same model but for the chip's share
+    sys.path.insert(0, os.path.join(here, "benchmark"))
+    try:
+        from harness.family import family_of
+
+        served = family_of(doc).model_config(doc)
+    finally:
+        sys.path.remove(os.path.join(here, "benchmark"))
+    same = dataclasses.replace(config_from_hf(tmp_path / "as_run"), name=served.name, experts_held=32, n_experts=128,
+                               dense_ffn_dim=served.dense_ffn_dim)
+    assert same == served

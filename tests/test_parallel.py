@@ -85,6 +85,9 @@ ASSIGNED = {
     # and so is a windowed cache: the ring's kernels are the one-chip ones (PR 37)
     "smallthinker-21b": [(1, 1), (1, 1), (1, 1), (1, 1)],
     "tiny-smallthinker": [(1, 1), (1, 1), (1, 1), (1, 1)],
+    # the hybrid block with latent attention alone is the hybrid block (PR 40)
+    "mistral-small-4-119b": [(1, 1), (1, 1), (1, 1), (1, 1)],
+    "tiny-mistral4": [(1, 1), (1, 1), (1, 1), (1, 1)],
 }
 
 

@@ -358,6 +358,14 @@ def _hybrid_case(model: str, where):
             get_config("kimi-linear-48b"), n_layers=5, layer_kinds=kimi_linear_kinds(27)[:5],
             experts_held=32, vocab_size=8192, name=model,
         )
+    elif model == "mistral4-9l":
+        # the served share whole: 9 layers, 32 of 128 experts, the whole
+        # vocabulary, 16 lanes of 16,384 (benchmark/configs/mistral-small-4-119b-ep4-1chip.json)
+        lanes, seq = 16, 16_384
+        cfg = dataclasses.replace(
+            get_config("mistral-small-4-119b"), n_layers=9, layer_kinds=("mla",) * 9, experts_held=32,
+            max_seq_len=seq, name=model,
+        )
     else:
         lanes, seq = 8, 4096
         cfg = dataclasses.replace(
@@ -424,6 +432,43 @@ def test_hybrid_step_holds_no_stack_sized_temporary_on_v5e(v5e, step):
     for name, s in stacks.items():  # and no copy or relayout of a whole stack anywhere in the program
         shape = ",".join(map(str, s.shape))
         assert not re.search(rf"\[{shape}\][^ ]* (copy|transpose)\(", text), name
+
+
+V5E_USABLE_BYTES = 15.75e9
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill"])
+def test_mistral4_step_fits_the_chip_and_reads_the_latent_stack_in_place_on_v5e(v5e, step):
+    """Mistral-Small-4's served share at its REAL size (9 layers, 32 of 128
+    experts, the whole vocabulary, 16 lanes of 16,384, int8 as served), its
+    kernels on: Mosaic accepts both latent kernels at a row of 320 values
+    stored as 384 (``[bk, 384]`` row blocks, a ``[16 · 32, 384]`` query tile;
+    Kimi-Linear's 640 is the only width they had run), the rotation in
+    adjacent pairs and the query's low-rank pair lower, the latent stack (1.81
+    GB) is donated in place and never copied or relaid out, a model with no
+    linear kind carries no state through the layer scan, and the step's live
+    bytes (arguments + temporaries - what is aliased) fit a v5e's 15.75 GB:
+    the configuration file's memory claim."""
+    cfg, cache, plan, steps = _hybrid_case("mistral4-9l", SingleDeviceSharding(v5e.devices[0]))
+    assert (plan.kda_decode, plan.mla_decode, plan.mla_prefill) == ("", "pallas_mla_decode", "pallas_mla_prefill")
+    assert cache.state is None and cache.conv is None and cache.latent.shape == (9, 16, 16_384, 384)
+    fn, args = steps[step]
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("mla_decode" if step == "decode" else "mla_prefill") in text
+    if step == "prefill":
+        assert not re.search(r"f32\[(1,)?32,256,16384\]", text)  # the chunk's scores stay in VMEM
+    mem = compiled.memory_analysis()
+    latent = cache.latent.size * cache.latent.dtype.itemsize
+    assert mem.alias_size_in_bytes >= latent
+    shape = ",".join(map(str, cache.latent.shape))
+    assert not re.search(rf"\[{shape}\][^ ]* (copy|transpose)\(", text)
+    live = mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    print(f"mistral4-9l {step}: args {mem.argument_size_in_bytes / 1e9:.2f} GB, temp {mem.temp_size_in_bytes / 1e9:.2f} GB, "
+          f"out {mem.output_size_in_bytes / 1e9:.2f} GB, aliased {mem.alias_size_in_bytes / 1e9:.2f} GB, live {live / 1e9:.2f} GB")
+    assert 10.0e9 < live < V5E_USABLE_BYTES
+    assert cfg.param_count() > 8.7e9  # the share, not a cut vocabulary
 
 
 @pytest.mark.parametrize("step", ["decode", "prefill"])

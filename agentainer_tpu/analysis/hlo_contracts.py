@@ -33,6 +33,7 @@ __all__ = [
     "DonationAliased",
     "ArenaRidesInCarry",
     "StacksRideInCarry",
+    "MixedStepOverStacks",
     "check",
     "compile_hlo",
     "op_result_elems",
@@ -189,6 +190,24 @@ def _tensor_dims(t: str) -> tuple[int, ...]:
     return tuple(int(d) for d in t[len("tensor<") : -1].split("x")[:-1])
 
 
+def _while_carries(text: str) -> list[list[tuple[int, ...]]]:
+    """The shapes each while loop of a lowered program carries."""
+    return [
+        [_tensor_dims(t) for t in re.findall(r"tensor<[^>]*>", m.group(1))]
+        for m in _WHILE.finditer(text)
+    ]
+
+
+def _writes(text: str) -> list[tuple[tuple[int, ...], tuple[int, ...]]]:
+    """``(operand shape, update shape)`` of every scatter and
+    dynamic_update_slice of a lowered program."""
+    return [
+        (_tensor_dims(m.group(1)), _tensor_dims(m.group(2)))
+        for rx in (_SCATTER, _DUS)
+        for m in rx.finditer(text)
+    ]
+
+
 @dataclass
 class ArenaRidesInCarry:
     """The stacked KV arena stays one buffer through a step program: over
@@ -245,12 +264,7 @@ class ArenaRidesInCarry:
                 f"loops carry {carrying} arena-shaped values each (want 2: the "
                 "K and V stacks) — a stacked scan output beside its input?"
             )
-        writes = [
-            (_tensor_dims(m.group(1)), _tensor_dims(m.group(2)))
-            for rx in (_SCATTER, _DUS)
-            for m in rx.finditer(text)
-        ]
-        updates = [upd for operand, upd in writes if operand == arena]
+        updates = [upd for operand, upd in _writes(text) if operand == arena]
         if len(updates) < 2 * len(groups):
             out.append(
                 f"found {len(updates)} writes into the arena (need K and V of "
@@ -293,15 +307,7 @@ class StacksRideInCarry:
 
     def failures(self, text: str) -> list[str]:
         out: list[str] = []
-        whiles = [
-            [_tensor_dims(t) for t in re.findall(r"tensor<[^>]*>", m.group(1))]
-            for m in _WHILE.finditer(text)
-        ]
-        writes = [
-            (_tensor_dims(m.group(1)), _tensor_dims(m.group(2)))
-            for rx in (_SCATTER, _DUS)
-            for m in rx.finditer(text)
-        ]
+        whiles, writes = _while_carries(text), _writes(text)
         for name, shape in self.stacks.items():
             shape = tuple(shape)
             carrying = [n for dims in whiles if (n := sum(d == shape for d in dims))]
@@ -320,6 +326,66 @@ class StacksRideInCarry:
                     f"writes into {name} of {sizes} elements: a step may update "
                     f"{self.updates[name]}, a layer is {math.prod(shape[1:])}"
                 )
+        return out
+
+
+@dataclass
+class MixedStepOverStacks:
+    """``ArenaRidesInCarry``'s two groups of rows for the hybrid block's cache
+    where it is positional rows alone (latent rows, or K and V; no linear
+    mixer): over the LOWERED (StableHLO) text of ``jit_prefill_with_decode``.
+
+    - Each stack is a carried value of exactly ONE while loop, once: the layer
+      scan. A second loop over the layers (a chunk's forward and then a decode
+      step's) reads every weight again, which is what the program exists to
+      avoid; a block with no linear mixer has no 0-or-1-trip loop either.
+    - A stack takes exactly two writes: the chunk's ``chunk`` rows and the
+      lanes' ``lanes`` rows, each row once, never a layer.
+    - No value is ``[chunk + lanes, vocab]`` or ``[chunk, vocab]``: the head
+      runs on the ``1 + lanes`` rows somebody reads (ask with a chunk that is
+      not the model's width: the head's own matrix is ``[dim, vocab]``).
+
+    That the donated cache and carry alias the outputs is ``DonationAliased``
+    on the compiled module; that the chip's compiler keeps one call of each
+    latent kernel and one grouped FFN in the loop's body, and copies no
+    stack, is the described-v5e compile in tests/test_tpu_compile.py.
+    """
+
+    stacks: dict  # name -> shape [n, B, S, ...]
+    chunk: int
+    lanes: int
+    vocab: int
+
+    def failures(self, text: str) -> list[str]:
+        out: list[str] = []
+        whiles, writes = _while_carries(text), _writes(text)
+        for name, shape in self.stacks.items():
+            shape = tuple(shape)
+            carrying = [n for dims in whiles if (n := sum(d == shape for d in dims))]
+            if not carrying:
+                out.append(f"{name} {list(shape)} is carried by no while loop: sliced and restacked around the layer loop")
+            if len(carrying) > 1:
+                out.append(
+                    f"{name} is carried by {len(carrying)} while loops (want exactly 1): "
+                    "a second loop over the layers reads the weights again"
+                )
+            if any(n != 1 for n in carrying):
+                out.append(f"loops carry {carrying} values of {name}'s shape each (want 1)")
+            row = math.prod(shape[3:])
+            sizes = sorted(math.prod(upd) for operand, upd in writes if operand == shape)
+            want = sorted((self.chunk * row, self.lanes * row))
+            if sizes != want:
+                out.append(
+                    f"writes into {name} of {sizes} elements: want the chunk's rows and the lanes' "
+                    f"{want}, a layer is {math.prod(shape[1:])}"
+                )
+        tall = {
+            t for t in re.findall(r"tensor<[^>]*>", text)
+            if "x" in t and (dims := _tensor_dims(t))[-1:] == (self.vocab,)
+            and math.prod(dims) in (self.chunk * self.vocab, (self.chunk + self.lanes) * self.vocab)
+        }
+        if tall:
+            out.append(f"values of shape {sorted(tall)}: the head runs on rows nobody reads (want 1 + {self.lanes})")
         return out
 
 

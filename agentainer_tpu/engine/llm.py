@@ -1575,15 +1575,17 @@ class LLMEngine:
         # (its page axis is pool-wide); close over it statically
         scratch_static = self.max_seq - 1
 
+        def real_rows(tokens, n_real):
+            # a recurrent state must not see the bucket's padding rows: the
+            # hybrid block is told which of a chunk's rows are real
+            if not self._recurrent:
+                return {}
+            return {"valid": jnp.arange(tokens.shape[1])[None, :] < n_real}
+
         def prefill(params, cache, slot, tokens, positions, n_real):
             # the prompt runs against the slot's row where it lies in the
-            # arena: no row sliced out, none written back. A recurrent state
-            # must not see the bucket's padding rows: say which are real
-            kw = (
-                {"valid": jnp.arange(tokens.shape[1])[None, :] < n_real}
-                if self._recurrent
-                else {}
-            )
+            # arena: no row sliced out, none written back
+            kw = real_rows(tokens, n_real)
             logits, cache = run_forward(params, tokens, positions, cache, slot=slot, **kw)
             last = lax.dynamic_slice_in_dim(logits, n_real - 1, 1, axis=1)[0, 0]
             return last, cache
@@ -1640,6 +1642,7 @@ class LLMEngine:
             logits, cache = run_forward(
                 params, tokens, positions, cache, slot=slot,
                 lanes=(lane_tok[:, None], lane_pos[:, None]), last=n_real - 1,
+                **real_rows(tokens, n_real),
             )
             nxt = sample_step(
                 logits[1:], keys[0], temps, topk, topp,
@@ -1700,18 +1703,21 @@ class LLMEngine:
         else:
             self._prefill = jax.jit(prefill, donate_argnums=(1,))
             self._decode_n = jax.jit(decode_n, donate_argnums=(1, 2, 3))
-        # Does this engine have the mixed step? Where the plan is the K/V
-        # block's dense arena on one chip under the per-chunk decode driver,
-        # with ``forward`` choosing the MoE path by row count. The hybrid
-        # block (two mixers side by side, a state that must not see the other
-        # group's rows), the page pool, the fused loop, a mesh and the
-        # ``routed`` dispatch (whose capacity a chunk's rows would share with
-        # the lanes') each need a body of their own: they keep two launches,
-        # as does a decode ladder without the one-step rung the program
-        # stands in for.
+        # Does this engine have the mixed step? Where the cache is positional
+        # rows alone on one chip under the per-chunk decode driver, with
+        # ``forward`` choosing the MoE path by row count: the K/V block's
+        # dense arena, or the hybrid block where no layer is a linear mixer
+        # (latent or K/V rows and the two controls: nothing of a lane that
+        # another group's rows could touch). A hybrid block WITH a linear
+        # mixer (a state and a conv that must not see the other group's
+        # rows), the page pool, the fused loop, a mesh and the ``routed``
+        # dispatch (whose capacity a chunk's rows would share with the
+        # lanes') each need a body of their own: they keep two launches, as
+        # does a decode ladder without the one-step rung the program stands
+        # in for.
         self._prefill_with_decode = None
         if self._decode_ladder[0] == 1 and not (
-            self._recurrent or self.paged or self.fused_decode
+            cfg.linear_kind is not None or self.paged or self.fused_decode
             or self.mesh is not None or moe_impl is not None
         ):
             self._prefill_with_decode = jax.jit(prefill_with_decode, donate_argnums=(1, 6, 7))
@@ -2177,7 +2183,7 @@ class LLMEngine:
                         jnp.asarray(np.arange(b, dtype=np.int32)[None]),
                         b,
                     )
-                jax.block_until_ready(self.cache.k)
+                jax.block_until_ready(self.cache)
         # warmup traffic is not serving telemetry: TTFT samples here include
         # compile time and would pollute p50s until the deque rolls over
         self.clear_sessions()

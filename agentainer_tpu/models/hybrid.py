@@ -62,6 +62,17 @@ not ``eos[lane]`` (a fed EOS closes the lane: ``stop = 0``). The engine sets
 both when it admits a request (``admit_lane``): the last token a request
 generates is never fed, so a parked or idle lane, and the steps a pipelined
 chunk runs past a request's end, cannot touch a session's state.
+
+**Two groups of rows in one launch** (``forward``'s ``lanes``, the engine's
+``jit_prefill_with_decode``): where NO layer is a linear mixer the cache is
+positional rows and the two controls, so a lane's chunk ``[1, T]`` and one
+decode step of every lane ``[B, 1]`` go through the layer scan's body
+together, ``[1, T + B]`` rows through everything that reads weights. A
+positional mixer writes both groups' rows, then attends each group as its
+own launch would (``_groups``, ``_put_groups``); the lanes' rows follow the
+controls as a ``T = 1`` step does. With a linear mixer among the layers a
+lane's state and conv would have to be kept from the other group's rows:
+``forward`` refuses ``lanes`` there.
 """
 
 from __future__ import annotations
@@ -528,6 +539,31 @@ def _put_rows(arena, value, idx, slot):
     return lax.dynamic_update_slice(arena, value[None].astype(arena.dtype), start)
 
 
+def _groups(n_lanes: int, *arrays):
+    """Each ``[1, T + B, ...]`` array of a launch with two groups of rows as
+    the pair (the chunk's ``[1, T, ...]``, the lanes' ``[B, 1, ...]``)."""
+    return [(a[:, :-n_lanes], a[0, -n_lanes:, None]) for a in arrays]
+
+
+def _put_groups(arena, rows, idx, slot, positions, n_lanes: int):
+    """Both groups' new rows into layer ``idx`` of a positional stack, each at
+    its own ``(lane, position)`` and before either group is read: the
+    chunk's at arena row ``slot``, the lanes' at rows ``0 .. B``."""
+    (rows_c, rows_l), (pos_c, pos_l) = _groups(n_lanes, rows.astype(arena.dtype), positions)
+    return arena.at[idx, slot, pos_c].set(rows_c).at[idx, jnp.arange(n_lanes)[:, None], pos_l].set(rows_l)
+
+
+def _by_group(attend, n_lanes: int, q, positions, valid, slot):
+    """``attend(q, positions, valid, slot)`` over a launch's rows: one call,
+    or with ``n_lanes`` one for each group, the chunk's over arena row ``slot``
+    and the lanes' over their own, joined as ``[1, T + B, ...]``."""
+    if not n_lanes:
+        return attend(q, positions, valid, slot)
+    (q_c, q_l), (pos_c, pos_l), (val_c, val_l) = _groups(n_lanes, q, positions, valid)
+    o_c, o_l = attend(q_c, pos_c, val_c, slot), attend(q_l, pos_l, val_l, None)
+    return jnp.concatenate([o_c, o_l.reshape(1, n_lanes, *o_l.shape[2:])], axis=1)
+
+
 def kda_mixer(h, lp, cfg: ModelConfig, state, conv, idx, slot, valid, plan: HybridPlan):
     """``h [B, T, d]`` (normed) → the mixer's output, and the state and conv
     stacks with layer ``idx``'s lanes stepped by the valid tokens."""
@@ -603,12 +639,12 @@ def gdn_mixer(h, lp, cfg: ModelConfig, state, conv, idx, slot, valid, plan: Hybr
     return _proj(o.reshape(b, t, nh * dv).astype(h.dtype), lp["wo"]), state, conv
 
 
-def full_mixer(h, lp, cfg: ModelConfig, ck, cv, idx, slot, positions, valid, plan: HybridPlan):
+def full_mixer(h, lp, cfg: ModelConfig, ck, cv, idx, slot, positions, valid, plan: HybridPlan, n_lanes: int = 0):
     """``h [B, T, d]`` → softmax attention's output and the K/V stacks with
     this step's rows written at their positions (rows past S drop). The rows
     hold :func:`stored_kv_heads` heads; the query is padded to match and the
     padding's output dropped. A lane that does not step attends to one row
-    instead of all S, like :func:`mla_mixer`'s."""
+    instead of all S, like :func:`mla_mixer`'s; ``n_lanes`` as there."""
     from ..ops import attention as attn_ops
 
     b, t, _ = h.shape
@@ -624,12 +660,20 @@ def full_mixer(h, lp, cfg: ModelConfig, ck, cv, idx, slot, positions, valid, pla
 
         q, k = apply_rope(q, positions, cfg.rope_theta), apply_rope(k, positions, cfg.rope_theta)
     heads = lambda a, n: jnp.pad(a.astype(h.dtype), [(0, 0), (0, 0), (0, n - a.shape[2]), (0, 0)])  # noqa: E731
-    lanes = jnp.arange(b)[:, None] + (0 if slot is None else slot)
-    ck = ck.at[idx, lanes, positions].set(heads(k, stored).astype(ck.dtype))
-    cv = cv.at[idx, lanes, positions].set(heads(v, stored).astype(cv.dtype))
-    seen = jnp.where(valid, positions, 0) if t == 1 else positions
     impl = attn_ops.pallas_dense if plan.full_decode.startswith("pallas:") else attn_ops._reference_dense
-    o = impl(heads(q, stored * group), ck, cv, seen, None, idx, slot)[:, :, :nh]
+
+    def attend(q, positions, valid, slot):
+        seen = jnp.where(valid, positions, 0) if positions.shape[1] == 1 else positions
+        return impl(heads(q, stored * group), ck, cv, seen, None, idx, slot)[:, :, :nh]
+
+    if n_lanes:
+        ck = _put_groups(ck, heads(k, stored), idx, slot, positions, n_lanes)
+        cv = _put_groups(cv, heads(v, stored), idx, slot, positions, n_lanes)
+    else:
+        lanes = jnp.arange(b)[:, None] + (0 if slot is None else slot)
+        ck = ck.at[idx, lanes, positions].set(heads(k, stored).astype(ck.dtype))
+        cv = cv.at[idx, lanes, positions].set(heads(v, stored).astype(cv.dtype))
+    o = _by_group(attend, n_lanes, q, positions, valid, slot)
     return _proj(o.reshape(b, t, nh * hd).astype(h.dtype), lp["wo"]), ck, cv
 
 
@@ -674,7 +718,7 @@ def _mla_query(h, lp, cfg: ModelConfig, positions):
     return q.astype(h.dtype)
 
 
-def mla_mixer(h, lp, cfg: ModelConfig, latent, idx, slot, positions, valid, plan: HybridPlan):
+def mla_mixer(h, lp, cfg: ModelConfig, latent, idx, slot, positions, valid, plan: HybridPlan, n_lanes: int = 0):
     """``h [B, T, d]`` (normed) → the mixer's output and the latent stack with
     this step's rows written at their positions (rows past S drop). A lane
     that does not step (``valid`` false: parked at the arena's last row)
@@ -683,7 +727,15 @@ def mla_mixer(h, lp, cfg: ModelConfig, latent, idx, slot, positions, valid, plan
     stack or writes scores to HBM. Under ``cfg.mla_rotary`` the row's shared
     key dims are written ROTATED by the row's position, and the query's
     matching dims by the query's: their product depends on the distance, and
-    the row absorbs like an unrotated one."""
+    the row absorbs like an unrotated one.
+
+    ``n_lanes``: ``h`` is ``[1, T + B, d]``, a chunk's T rows for arena row
+    ``slot`` and then one row for each of the arena's B lanes
+    (:func:`forward`'s ``lanes``). The projections, the norms and the
+    rotation run over all of them at once; both groups' rows are written
+    before either is read, and each group's attention is the call its own
+    launch would make: the chunk's over lane ``slot``, the lanes' over their
+    own."""
     b, t, _ = h.shape
     nh, rank, nope = cfg.n_heads, cfg.mla_kv_rank, cfg.mla_nope_dim
     q = _mla_query(h, lp, cfg, positions)
@@ -694,26 +746,33 @@ def mla_mixer(h, lp, cfg: ModelConfig, latent, idx, slot, positions, valid, plan
         with jax.named_scope("mla_rope"):
             k_s = _mla_rotate(k_s[:, :, None], positions, cfg)[:, :, 0]
     row = jnp.concatenate([c, k_s, pad], -1)
-    lanes = jnp.arange(b)[:, None] + (0 if slot is None else slot)
-    latent = latent.at[idx, lanes, positions].set(row.astype(latent.dtype))
+    if n_lanes:
+        latent = _put_groups(latent, row, idx, slot, positions, n_lanes)
+    else:
+        lanes = jnp.arange(b)[:, None] + (0 if slot is None else slot)
+        latent = latent.at[idx, lanes, positions].set(row.astype(latent.dtype))
     w_kvb = dequant(lp["wkvb"]).reshape(rank, nh, nope + cfg.mla_v_dim)
     q_full = mla_ops.absorb_query(q, w_kvb, nope)  # float32: rounded once, where the scores take it
     q_full = jnp.pad(q_full, [(0, 0)] * 3 + [(0, pad.shape[-1])])  # zeros against the padding
     scale = (nope + cfg.mla_rope_dim) ** -0.5 * cfg.softmax_mscale**2
-    if t == 1 and plan.mla_decode == "pallas_mla_decode":
-        from ..ops.pallas_mla import mla_decode
 
-        seen = jnp.where(valid[:, 0], positions[:, 0], 0)
-        o_lat = mla_decode(q_full[:, 0], latent, seen, idx, 0 if slot is None else slot,
-                           scale=scale, rank=rank)[:, None]
-    elif t > 1 and plan.mla_prefill == "pallas_mla_prefill":
-        from ..ops.pallas_mla import mla_prefill
+    def attend(q_full, positions, valid, slot):
+        b, t = positions.shape
+        if t == 1 and plan.mla_decode == "pallas_mla_decode":
+            from ..ops.pallas_mla import mla_decode
 
-        # a bucket's padding sees nothing: a tile of it costs nothing
-        seen = jnp.where(valid, positions, -1)
-        o_lat = mla_prefill(q_full, latent, seen, idx, 0 if slot is None else slot, scale=scale, rank=rank)
-    else:
-        o_lat = mla_ops.attend(q_full, _rows(latent, idx, slot, b), positions, scale, rank)
+            seen = jnp.where(valid[:, 0], positions[:, 0], 0)
+            return mla_decode(q_full[:, 0], latent, seen, idx, 0 if slot is None else slot,
+                              scale=scale, rank=rank)[:, None]
+        if t > 1 and plan.mla_prefill == "pallas_mla_prefill":
+            from ..ops.pallas_mla import mla_prefill
+
+            # a bucket's padding sees nothing: a tile of it costs nothing
+            seen = jnp.where(valid, positions, -1)
+            return mla_prefill(q_full, latent, seen, idx, 0 if slot is None else slot, scale=scale, rank=rank)
+        return mla_ops.attend(q_full, _rows(latent, idx, slot, b), positions, scale, rank)
+
+    o_lat = _by_group(attend, n_lanes, q_full, positions, valid, slot)
     o = jnp.einsum(
         "bthr,rhv->bthv", o_lat.astype(h.dtype), w_kvb[..., nope:], preferred_element_type=jnp.float32
     )
@@ -730,13 +789,26 @@ def forward(
     moe_impl=None,
     slot=None,
     valid: jnp.ndarray | None = None,
+    lanes: tuple[jnp.ndarray, jnp.ndarray] | None = None,
+    last: jnp.ndarray | None = None,
 ):
     """``models/llama.forward`` for a config with ``layer_kinds``: logits
     ``[B, T, V]`` and the updated cache. Without a cache: the full causal
     forward from zero state (positions have to be ``0 .. T − 1``). ``valid
     [B, T]``: the real rows, a prefix of each sequence (module docstring);
     absent, a ``T = 1`` call through a cache follows the cache's controls
-    and any other call steps every token."""
+    and any other call steps every token.
+
+    ``lanes = (tokens [B, 1], positions [B, 1])`` and ``last``, as
+    ``models/llama.forward`` takes them: ``tokens [1, T]`` is a lane's chunk
+    at arena row ``slot`` (``valid`` its real rows) and ``lanes`` one decode
+    step of each of the arena's B lanes, which follows the cache's controls
+    as a ``T = 1`` call does. The ``T + B`` rows go together through
+    everything that reads weights; a lane that does not step writes its row
+    where it stands (the arena's last row, where it is parked), attends to
+    one row and is routed to no expert. The logits are ``[1 + B, V]``: the
+    chunk's row ``last``, then the lanes'. Only where the model has no linear
+    mixer: a per-lane state and conv must not see the other group's rows."""
     from .llama import _moe_mlp, _moe_mlp_sorted, moe_sorts
 
     b, t = tokens.shape
@@ -745,14 +817,35 @@ def forward(
     if cache is None:
         cache = init_cache(cfg, b, t, params["final_norm"].dtype)
     stop = cache.stop
-    if valid is None:
+
+    def controls(tokens, positions, lanes):
+        """A one-token step's ``valid [n, 1]`` from the cache's controls, and
+        ``stop`` with the lanes a fed EOS closes."""
+        lane_stop, lane_eos = stop[lanes], cache.eos[lanes]
+        is_eos = tokens[:, 0] == lane_eos
+        open_ = positions[:, 0] < lane_stop
+        return (open_ & ~is_eos)[:, None], stop.at[lanes].set(jnp.where(open_ & is_eos, 0, lane_stop))
+
+    n_lanes = 0
+    if lanes is not None:
+        if cfg.linear_kind is not None:
+            raise ValueError(
+                f"lanes beside a chunk need a block with no linear mixer: a {cfg.linear_kind} layer's "
+                "state and conv must not see the other group's rows"
+            )
+        if not keep_cache or slot is None or b != 1:
+            raise ValueError("lanes ride one lane's chunk at ``slot`` of the cache")
+        lane_tokens, lane_positions = lanes
+        n_lanes = lane_tokens.shape[0]
+        lane_valid, stop = controls(lane_tokens, lane_positions, jnp.arange(n_lanes))
+        valid = jnp.ones((1, t), bool) if valid is None else valid
+        tokens, positions, valid = (
+            jnp.concatenate([a, g.reshape(1, n_lanes)], axis=1)
+            for a, g in ((tokens, lane_tokens), (positions, lane_positions), (valid, lane_valid))
+        )
+    elif valid is None:
         if keep_cache and t == 1:
-            lanes = jnp.arange(b) + (0 if slot is None else slot)
-            lane_stop, lane_eos = stop[lanes], cache.eos[lanes]
-            is_eos = tokens[:, 0] == lane_eos
-            open_ = positions[:, 0] < lane_stop
-            valid = (open_ & ~is_eos)[:, None]
-            stop = stop.at[lanes].set(jnp.where(open_ & is_eos, 0, lane_stop))
+            valid, stop = controls(tokens, positions, jnp.arange(b) + (0 if slot is None else slot))
         else:
             valid = jnp.ones((b, t), bool)
 
@@ -767,9 +860,14 @@ def forward(
     ffn_idx = np.where(dense, np.arange(cfg.n_layers), np.arange(cfg.n_layers) - cfg.n_dense_layers)
     moe_stack = params.get("moe")
     experts = None
-    if moe_stack is not None and moe_impl is None and moe_sorts(cfg, params, b * t):
+    only = None
+    if moe_stack is not None and moe_impl is None and moe_sorts(cfg, params, b * t + n_lanes):
         experts = stacked_experts(moe_stack)
         moe_stack = {k: v for k, v in moe_stack.items() if k not in EXPERT_WEIGHTS}
+        if n_lanes:
+            # the chunk's rows as a plain chunk routes them, and the lanes
+            # that step: any other lane's row is nobody's (``_moe_mlp_sorted``)
+            only = jnp.concatenate([jnp.ones(t, bool), valid[0, t:]])
     # what the shared MoE paths take dequantised (the router's logits and the
     # shared expert are computed here, from the int8 leaves)
     routed = {k: v for k, v in (moe_stack or {}).items() if k != "router" and not k.startswith("ws_")}
@@ -785,7 +883,7 @@ def forward(
 
         def positional(rows):
             fn = mla_mixer if pos_kind == "mla" else full_mixer
-            y, *rows = fn(h, _layer_of(params[pos_kind], idx), cfg, *rows, idx, slot, positions, valid, plan)
+            y, *rows = fn(h, _layer_of(params[pos_kind], idx), cfg, *rows, idx, slot, positions, valid, plan, n_lanes)
             return y, tuple(rows)
 
         if pos_kind is None:
@@ -817,7 +915,7 @@ def forward(
             lp = _layer_of(routed, idx, dense=True)
             logits = router_logits(h32, _layer_of({"router": moe_stack["router"]}, idx)["router"])
             if experts is not None:
-                y = _moe_mlp_sorted(h, lp, cfg, experts, idx, logits=logits)
+                y = _moe_mlp_sorted(h, lp, cfg, experts, idx, logits=logits, routed=only)
             else:
                 y = moe_impl(h, lp) if moe_impl is not None else _moe_mlp(h, lp, cfg, logits=logits)
             if cfg.n_shared_experts:
@@ -853,6 +951,9 @@ def forward(
         jnp.asarray(kinds), jnp.asarray(mixer_idx), jnp.asarray(dense), jnp.asarray(ffn_idx, jnp.int32),
     )
     (x, rows, state, conv), _ = lax.scan(layer_step, (x, cache.rows(), cache.state, cache.conv), xs)
+    if n_lanes:
+        # the head's rows: the chunk's ``last`` and the lanes', not T + B
+        x = jnp.concatenate([lax.dynamic_slice_in_dim(x[0], last, 1, 0), x[0, t:]], axis=0)
     x = rms_norm(x, params["final_norm"], cfg.norm_eps).astype(act)
     logits = _proj(x, params["lm_head"])
     if not keep_cache:

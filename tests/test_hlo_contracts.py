@@ -32,6 +32,7 @@ from agentainer_tpu.analysis.hlo_contracts import (
     DonationAliased,
     ExpertsSeeOnlyTheirRows,
     HasCrossReduction,
+    MixedStepOverStacks,
     NoLargeAllGather,
     check,
     compile_count,
@@ -433,6 +434,106 @@ def test_a_mixed_step_over_the_cut_sorts_the_lanes_rows_with_the_chunks(moe_engi
 
 
 # ---------------------------------------------------------------------------
+# the hybrid block's mixed step where it has no linear mixer (ISSUE 41), and
+# every program of the hybrid engines that is what the parent lowered
+
+
+HYBRID_OPTIONS = {
+    "max_batch": 4, "max_seq": 256, "decode_chunk": 8, "prefill_chunk": 128, "skip_warmup": True,
+}
+HYBRID_PARENT_PROGRAMS = json.load(
+    open(os.path.join(os.path.dirname(__file__), "data", "hybrid_step_programs_parent_pr40.json"))
+)
+
+
+@pytest.fixture(scope="module")
+def hybrid_engine():
+    """``hybrid_engine(model, weights)``, built on first use, as ``moe_engine``."""
+    built = {}
+
+    def get(model: str, weights: str):
+        if (model, weights) not in built:
+            quant = {"quant": "int8"} if weights == "int8" else {}
+            built[model, weights] = LLMEngine.create(model, options={**HYBRID_OPTIONS, **quant})
+        return built[model, weights]
+
+    yield get
+    for eng in built.values():
+        eng.shutdown()
+
+
+@pytest.mark.parametrize("key", sorted(HYBRID_PARENT_PROGRAMS))
+def test_hybrid_steps_without_lanes_lower_to_the_parents_programs(hybrid_engine, key):
+    """``jit_decode_n`` and two buckets of ``jit_prefill`` of the three hybrid
+    engines (KDA + MLA, GDN + full attention, MLA alone), float and int8,
+    lower to the StableHLO the parent commit (e076b59, PR 40) lowered on this
+    backend, byte for byte (sha256 of the text, taken there with these
+    lowering calls): ``hybrid.forward`` took ``lanes`` and the mixers a second
+    group of rows, and a call without them traces what it traced. On the chip
+    an edit above a Pallas call still re-compiles every program that holds
+    one (PERF.md section 7): that is a first start's cost, not this one's."""
+    model, weights, program = key.split(".", 2)
+    text = _moe_step_lowering(hybrid_engine(model, weights), program).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == HYBRID_PARENT_PROGRAMS[key]
+
+
+@pytest.mark.parametrize("weights", ["float", "int8"])
+def test_hybrid_mixed_step_rides_one_layer_loop_and_heads_few_rows(hybrid_engine, weights):
+    """``tiny-mistral4``'s ``jit_prefill_with_decode``: the latent stack in
+    the one layer loop's carry, two writes into it (the chunk's ``T`` rows and
+    the lanes' ``B``), no logits of ``T`` or ``T + B`` rows, and the donated
+    cache (latent, ``stop``) and carry (token, position) aliased. ``int8``:
+    128 + 4 rows are over the 121-row cut, so ONE grouped FFN takes both
+    groups' rows, as many grouped matmuls as the plain chunk's; the held
+    experts never appear as an all-experts activation of the lanes' rows."""
+    eng = hybrid_engine("tiny-mistral4", weights)
+    B, t, c = eng.max_batch, 128, eng.cache
+    assert eng._prefill_with_decode is not None and c.state is None and c.conv is None
+    lowered = _mixed_lowering(eng, t)
+    text = lowered.as_text()
+    assert "module @jit_prefill_with_decode" in text
+    check(text, MixedStepOverStacks({"latent": c.latent.shape}, chunk=t, lanes=B, vocab=eng.cfg.vocab_size))
+    check(lowered.compile().as_text(), DonationAliased(min_count=4))
+    if weights == "int8":
+        for rows in (t + B, t, B):
+            check(text, ExpertsSeeOnlyTheirRows(rows, eng.cfg.n_experts, eng.cfg.ffn_dim))
+        grouped = lambda fn, args: str(fn.trace(*args).jaxpr).count("ragged_dot")  # noqa: E731
+        assert grouped(eng._prefill_with_decode, _mixed_args(eng, t)) == grouped(eng._prefill, _prefill_args(eng, t)) > 0
+
+
+def test_hybrid_mixed_contract_refuses_two_forwards_and_a_head_on_every_row(hybrid_engine):
+    """What ``MixedStepOverStacks`` is for: a chunk's forward and a step's in
+    one program carry the stack through two loops over the layers (the weights
+    read twice) and head all ``T`` rows; the plain chunk's program writes one
+    group."""
+    eng = hybrid_engine("tiny-mistral4", "float")
+    B, t, shape = eng.max_batch, 128, eng.cache.latent.shape
+    contract = MixedStepOverStacks({"latent": shape}, chunk=t, lanes=B, vocab=eng.cfg.vocab_size)
+    run = eng._run_forward  # ``forward`` closed over the engine's plan
+
+    def two_forwards(params, cache, slot, tokens, positions, lane_tok, lane_pos):
+        logits, cache = run(params, tokens, positions, cache, slot=slot)
+        return logits, run(params, lane_tok[:, None], lane_pos[:, None], cache)
+
+    tokens, z = jnp.zeros((1, t), jnp.int32), jnp.zeros((B,), jnp.int32)
+    text = jax.jit(two_forwards).lower(eng.params, eng.cache, jnp.int32(1), tokens, tokens, z, z).as_text()
+    problems = contract.failures(text)
+    assert any("reads the weights again" in p for p in problems), problems
+    assert any("rows nobody reads" in p for p in problems), problems
+    chunk_alone = _moe_step_lowering(eng, f"jit_prefill.{t}").as_text()
+    assert any("want the chunk's rows and the lanes'" in p for p in contract.failures(chunk_alone))
+
+
+@pytest.mark.parametrize("model", ["tiny-kimi-linear", "tiny-olmo-hybrid"])
+def test_hybrid_engines_with_a_linear_mixer_build_no_mixed_program(hybrid_engine, model):
+    """The choice is the configuration's layer kinds: a KDA or GDN layer among
+    them keeps a per-lane state, and the engine keeps its two launches."""
+    eng = hybrid_engine(model, "float")
+    assert eng.cfg.linear_kind is not None and eng.cache.state is not None
+    assert eng._prefill_with_decode is None and "_prefill_with_decode" not in engine_jit_fns(eng)
+
+
+# ---------------------------------------------------------------------------
 # recompile budget over the scripted mixed workload
 
 
@@ -499,16 +600,19 @@ def compile_stats():
     return enable_compile_cache()
 
 
-@pytest.fixture(scope="module", params=["dense", "paged", "fused", "meshed"])
+@pytest.fixture(scope="module", params=["dense", "paged", "fused", "meshed", "latent"])
 def warmed(request):
+    """``latent``: the hybrid block with no linear mixer (``tiny-mistral4``),
+    whose warm-up compiles the mixed step's rungs like the dense engine's."""
     extra = {
         "dense": {},
         "paged": {"paged_kv": True},
         "fused": {"paged_kv": True, "fused_decode": True},
         "meshed": {"tp": 2},
+        "latent": {},
     }[request.param]
     eng = LLMEngine.create(
-        "tiny",
+        "tiny-mistral4" if request.param == "latent" else "tiny",
         options={"max_batch": 4, "max_seq": 256, "decode_chunk": 8, "prefill_chunk": 32, **extra},
     )
     yield eng
@@ -553,7 +657,8 @@ def test_serving_window_with_mixed_launches_lowers_nothing(warmed, compile_stats
     chunk pending beside a decoding lane: it runs the mixed step
     (``jit_prefill_with_decode``, ISSUE 31) explicitly, once per bucket a
     chunk can take. Multi-chunk prompts beside a steady generation then ride
-    on the dense engine without a lowering; the page pool, the fused loop and
+    on the dense engine, and on the hybrid block's where it keeps no per-lane
+    state (ISSUE 41), without a lowering; the page pool, the fused loop and
     the mesh have no such program, launch none, and lower nothing either."""
 
     async def contended():

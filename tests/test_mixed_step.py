@@ -5,10 +5,12 @@ lanes' step beside it.
 rows together through every layer (``models/llama.forward``'s ``lanes``), so
 a tick that used to launch ``jit_prefill`` and then the one-step
 ``jit_decode_n`` streams the weights once. Checked here on the CPU, on a
-dense, a Mixtral-shaped and an OLMoE-shaped tiny model: the program against
-the two it stands in for, the scheduler's rule (``_riders``) and the engines
-that have no such program, the counters, the two failpoints, and a session
-whose turns rode mixed launches resumed in a new engine.
+dense, a Mixtral-shaped and an OLMoE-shaped tiny model and (ISSUE 41) on the
+hybrid block where it has no linear mixer (``tiny-mistral4``: latent rows and
+the two per-lane controls): the program against the two it stands in for, the
+scheduler's rule (``_riders``) and the engines that have no such program, the
+counters, the two failpoints, and a session whose turns rode mixed launches
+resumed in a new engine.
 """
 
 import asyncio
@@ -22,7 +24,8 @@ from agentainer_tpu import faults
 from agentainer_tpu.engine.llm import GenRequest, LLMEngine
 from agentainer_tpu.models.llama import moe_sorts
 
-FAMILIES = ["tiny", "tiny-moe", "tiny-olmoe"]  # dense, Mixtral-shaped, OLMoE-shaped
+# dense, Mixtral-shaped, OLMoE-shaped; the hybrid block with latent attention in every layer
+FAMILIES = ["tiny", "tiny-moe", "tiny-olmoe", "tiny-mistral4"]
 OPTS = {"max_batch": 4, "max_seq": 256, "decode_chunk": 8, "prefill_chunk": 32,
         "speculative": False, "skip_warmup": True}
 LONG = "a document of many words that takes several prefill chunks to read "  # 68 bytes
@@ -36,6 +39,12 @@ def _copy(tree):
 # the program: one launch against the two it replaces
 
 
+def _rows_of(cache) -> list:
+    """The positional leaves of either block's cache: ``k`` and ``v``, or
+    what the hybrid block keeps (``latent`` alone for ``tiny-mistral4``)."""
+    return list(cache.rows()) if hasattr(cache, "rows") else [cache.k, cache.v]
+
+
 @pytest.mark.parametrize("weights", ["float", "int8"])
 @pytest.mark.parametrize("model", FAMILIES)
 def test_a_mixed_launch_is_the_chunk_then_the_one_step_decode(model, weights):
@@ -47,18 +56,23 @@ def test_a_mixed_launch_is_the_chunk_then_the_one_step_decode(model, weights):
     einsum (the matmuls' shapes differ, so a few sums round differently:
     2e-7). ``int8``: 132 rows are over the 121-row cut, so the launch's lanes
     go through the sorted grouped FFN where the decode step runs the einsum:
-    the same sum in another order. The lane parked at the arena's last row is
-    left out of the comparison where the FFN sorts: its row is routed to no
-    expert there (ISSUE 33: alone at an expert it would stream that expert
-    for one tile), so its token and the scratch row it rewrites are its own
-    — nobody reads either."""
+    the same sum in another order. A lane that does not step is left out of
+    the comparison where the FFN sorts: its row is routed to no expert there
+    (ISSUE 33: alone at an expert it would stream that expert for one tile),
+    so its token and the ONE row it rewrites, where it stands, are its own;
+    nobody reads either. On the K/V block that is the lane parked at the
+    arena's last row. The hybrid block reads it from the cache's controls as
+    its ``T = 1`` step does: the chunk's own lane (open, but parked past its
+    ``stop``), and a lane fed its EOS, which closes (``stop = 0``) in both."""
     quant = {"quant": "int8"} if weights == "int8" else {}
     eng = LLMEngine.create(model, options={**OPTS, "prefill_chunk": 128, **quant})
     try:
         B, S, T, n_real, lane = eng.max_batch, eng.max_seq, 128, 100, 2
+        hybrid = eng.cfg.is_hybrid
+        weights_of = eng.params if hybrid else eng.params["layers"]
         if eng.cfg.is_moe:
-            assert moe_sorts(eng.cfg, eng.params["layers"], T + B) is (weights == "int8")
-            assert not moe_sorts(eng.cfg, eng.params["layers"], B)
+            assert moe_sorts(eng.cfg, weights_of, T + B) is (weights == "int8")
+            assert not moe_sorts(eng.cfg, weights_of, B)
         rng = np.random.default_rng(7)
         draw = lambda *shape: jnp.asarray(rng.integers(1, eng.cfg.vocab_size, shape), jnp.int32)  # noqa: E731
         # three lanes hold a context of their own; lane 2 takes the chunk
@@ -70,6 +84,14 @@ def test_a_mixed_launch_is_the_chunk_then_the_one_step_decode(model, weights):
         tokens, positions = draw(1, T), (40 + jnp.arange(T, dtype=jnp.int32))[None]
         lane_tok = draw(B)
         lane_pos = jnp.asarray([context.get(i, S - 1) for i in range(B)], jnp.int32)  # lane 2 parked at scratch
+        idle = {lane: S - 1}  # the lanes that do not step, and the row each rewrites
+        if hybrid:
+            # every lane admitted, as the engine does it: open up to the end of
+            # its reply; lane 1 is fed the token that closes it
+            for idx in range(B):
+                eos = int(lane_tok[1]) if idx == 1 else -1
+                cache = eng._admit_state(cache, jnp.int32(idx), jnp.bool_(False), jnp.int32(200), jnp.int32(eos))
+            idle[1] = context[1]
         temps, topk, topp = jnp.zeros((B,), jnp.float32), jnp.zeros((B,), jnp.int32), jnp.ones((B,), jnp.float32)
         keys = jax.random.split(jax.random.PRNGKey(3), 1)
 
@@ -83,25 +105,108 @@ def test_a_mixed_launch_is_the_chunk_then_the_one_step_decode(model, weights):
         )
         assert toks_m.shape == toks_a.shape == (1, B)
         atol = 2e-5
-        stepping = sorted(context)  # every lane but the parked one
+        stepping = sorted(set(context) - set(idle))
 
-        def rows(arena):  # all but the parked lane's scratch row
-            return np.asarray(arena.at[:, lane, S - 1].set(0))
+        def rows(arena):  # all but the rows the idle lanes rewrite
+            for idx, at in idle.items():
+                arena = arena.at[:, idx, at].set(0)
+            return np.asarray(arena)
 
         np.testing.assert_allclose(np.asarray(last_m), np.asarray(last_a), atol=atol, rtol=0)
-        np.testing.assert_allclose(rows(cache_m.k), rows(cache_a.k), atol=atol, rtol=0)
-        np.testing.assert_allclose(rows(cache_m.v), rows(cache_a.v), atol=atol, rtol=0)
+        for got, want in zip(_rows_of(cache_m), _rows_of(cache_a)):
+            np.testing.assert_allclose(rows(got), rows(want), atol=atol, rtol=0)
+            assert np.isfinite(np.asarray(got)).all()
         assert np.asarray(toks_m)[:, stepping].tolist() == np.asarray(toks_a)[:, stepping].tolist()
         assert np.asarray(tok_m)[stepping].tolist() == np.asarray(tok_a)[stepping].tolist()
         assert np.asarray(pos_m).tolist() == np.asarray(pos_a).tolist()
-        assert np.isfinite(np.asarray(cache_m.k)).all() and np.isfinite(np.asarray(cache_m.v)).all()
-        if eng.cfg.is_moe and weights == "int8":  # the parked row went through no expert
-            assert not np.allclose(np.asarray(cache_m.k[1:, lane, S - 1]), np.asarray(cache_a.k[1:, lane, S - 1]), atol=atol)
+        if hybrid:  # the controls moved alike: the fed EOS closed lane 1 and no other
+            assert np.asarray(cache_m.stop).tolist() == np.asarray(cache_a.stop).tolist() == [200, 0, 200, 200]
+            assert np.asarray(cache_m.eos).tolist() == np.asarray(cache_a.eos).tolist()
+        first = _rows_of(cache_m)[0]
+        if eng.cfg.is_moe and weights == "int8":  # an idle lane's row went through no expert
+            want = _rows_of(cache_a)[0]
+            for idx, at in idle.items():
+                assert not np.allclose(np.asarray(first[1:, idx, at]), np.asarray(want[1:, idx, at]), atol=atol)
         # the chunk's rows were written at lane 2 and the lanes' at their own positions
-        assert float(jnp.abs(cache_m.k[:, lane, 40:40 + T]).max()) > 0
-        assert float(jnp.abs(cache_m.k[:, 0, context[0]]).max()) > 0
+        assert float(jnp.abs(first[:, lane, 40:40 + T]).max()) > 0
+        assert float(jnp.abs(first[:, 0, context[0]]).max()) > 0
     finally:
         eng.shutdown()
+
+
+def _hybrid_case(model: str):
+    """(configuration, float32 parameters, a cache of 4 lanes of 64 rows with
+    three contexts in it and every lane admitted) for the forward-level
+    checks: ``tiny-mistral4``, ``full-only`` (``tiny-olmo-hybrid``'s softmax
+    attention over K/V rows in every layer: the hybrid block's other
+    positional kind, no linear mixer either) or a model that has one."""
+    import dataclasses
+
+    from agentainer_tpu.models import hybrid
+    from agentainer_tpu.models.configs import get_config
+
+    if model == "full-only":
+        cfg = dataclasses.replace(get_config("tiny-olmo-hybrid"), layer_kinds=("full",) * 3, n_layers=3, n_dense_layers=3, name=model)
+    else:
+        cfg = get_config(model)
+    params = hybrid.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+    cache = hybrid.init_cache(cfg, 4, 64, jnp.float32, live=False)
+    rng = np.random.default_rng(11)
+    draw = lambda *shape: jnp.asarray(rng.integers(1, cfg.vocab_size, shape), jnp.int32)  # noqa: E731
+    plan = hybrid.plan_hybrid(cfg, use_pallas=False)
+    for idx, n in {0: 12, 1: 5, 3: 20}.items():
+        valid = jnp.arange(24)[None] < n
+        _, cache = hybrid.forward(params, cfg, draw(1, 24), jnp.arange(24)[None], cache, plan=plan, slot=idx, valid=valid)
+    for idx in range(4):
+        cache = hybrid.admit_lane(cache, idx, False, 60, -1)
+    return cfg, params, cache, plan, draw
+
+
+@pytest.mark.parametrize("model", ["tiny-mistral4", "full-only"])
+def test_the_hybrid_forward_with_lanes_is_its_two_calls(model):
+    """``hybrid.forward(lanes=...)`` against the chunk's call and then the
+    ``T = 1`` call, for both positional kinds: the head's ``1 + B`` rows, every
+    positional leaf (but the row of the lane that does not step) and ``stop``.
+    Lane 3 stands at its ``stop``: closed, it writes the row where it stands
+    and nothing else."""
+    from agentainer_tpu.models import hybrid
+
+    cfg, params, cache, plan, draw = _hybrid_case(model)
+    cache = hybrid.admit_lane(cache, 3, False, 20, -1)
+    T, n_real, slot = 16, 11, 2
+    tokens, positions = draw(1, T), (7 + jnp.arange(T, dtype=jnp.int32))[None]
+    valid = jnp.arange(T)[None] < n_real
+    lane_tok, lane_pos = draw(4, 1), jnp.asarray([12, 5, 63, 20], jnp.int32)[:, None]
+    kw = {"plan": plan, "slot": slot, "valid": valid}
+    chunk, two = hybrid.forward(params, cfg, tokens, positions, cache, **kw)
+    step, two = hybrid.forward(params, cfg, lane_tok, lane_pos, two, plan=plan)
+    logits, one = hybrid.forward(params, cfg, tokens, positions, cache, lanes=(lane_tok, lane_pos), last=n_real - 1, **kw)
+    assert logits.shape == (1 + 4, cfg.vocab_size)
+    np.testing.assert_allclose(np.asarray(logits[0]), np.asarray(chunk[0, n_real - 1]), atol=2e-5, rtol=0)
+    for i in (0, 1):  # the lanes that step
+        np.testing.assert_allclose(np.asarray(logits[1 + i]), np.asarray(step[i, 0]), atol=2e-5, rtol=0)
+    assert np.asarray(one.stop).tolist() == np.asarray(two.stop).tolist()
+    for got, want, before in zip(one.rows(), two.rows(), cache.rows()):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=0)
+        changed = np.argwhere(np.abs(np.asarray(got) - np.asarray(before)).reshape(*got.shape[:3], -1).max(-1) > 0)
+        assert {(int(b), int(p)) for _, b, p in changed if b != slot} == {(0, 12), (1, 5), (3, 20)}
+        assert {int(p) for _, b, p in changed if b == slot} == set(range(7, 7 + T)) | {63}
+
+
+def test_lanes_beside_a_chunk_are_refused_where_a_layer_is_a_linear_mixer():
+    """A state and a conv that must not see the other group's rows: that body
+    is not written, and ``forward`` says so instead of corrupting a lane."""
+    from agentainer_tpu.models import hybrid
+    from agentainer_tpu.models.llama import forward
+
+    for model in ("tiny-kimi-linear", "tiny-olmo-hybrid"):
+        cfg, params, cache, plan, draw = _hybrid_case(model)
+        tokens, positions = draw(1, 16), jnp.arange(16, dtype=jnp.int32)[None]
+        lanes = (draw(4, 1), jnp.full((4, 1), 63, jnp.int32))
+        with pytest.raises(ValueError, match="no linear mixer"):
+            hybrid.forward(params, cfg, tokens, positions, cache, plan=plan, slot=2, lanes=lanes, last=3)
+        with pytest.raises(ValueError, match="no linear mixer"):  # the door the engine comes through
+            forward(params, cfg, tokens, positions, cache, cache_attn_impl=plan, slot=2, lanes=lanes, last=3)
 
 
 # ---------------------------------------------------------------------------
@@ -258,7 +363,8 @@ def test_lanes_whose_budget_is_all_in_flight_do_not_ride(stopped):
 
 
 NO_PROGRAM = {
-    "hybrid": ("tiny-kimi-linear", {}),
+    "hybrid": ("tiny-kimi-linear", {}),  # KDA beside MLA
+    "hybrid_gdn": ("tiny-olmo-hybrid", {}),  # GDN beside full attention
     "paged": ("tiny", {"paged_kv": True}),
     "fused": ("tiny", {"paged_kv": True, "fused_decode": True}),
     "meshed": ("tiny", {"tp": 2}),
@@ -270,11 +376,12 @@ NO_PROGRAM = {
 @pytest.mark.parametrize("kind", sorted(NO_PROGRAM))
 def test_engines_without_the_program_keep_two_launches(kind):
     """Decided at build by what the engine is, never by a model's name: the
-    hybrid block, the page pool, the fused loop, a mesh, the ``routed``
-    dispatch and a ladder without the one-step rung have no mixed program;
-    under the traffic that makes the dense engine ride they launch none."""
+    hybrid block with a linear mixer among its layers (a per-lane state), the
+    page pool, the fused loop, a mesh, the ``routed`` dispatch and a ladder
+    without the one-step rung have no mixed program; under the traffic that
+    makes the dense engine ride they launch none."""
     model, extra = NO_PROGRAM[kind]
-    options = {k: v for k, v in OPTS.items() if not (kind == "hybrid" and k == "speculative")}
+    options = {k: v for k, v in OPTS.items() if not (kind.startswith("hybrid") and k == "speculative")}
     eng = LLMEngine.create(model, options={**options, **extra})
     try:
         assert eng._prefill_with_decode is None and eng._riders() is None

@@ -392,10 +392,25 @@ def _hybrid_case(model: str, where):
         logits, cache = forward(params, cfg, tokens, positions, cache, cache_attn_impl=plan, slot=slot, valid=valid)
         return logits[0, -1], cache
 
+    def mixed(params, cache, slot, tokens, positions, n_real, lane_tok, lane_pos):
+        # ``jit_prefill_with_decode``'s forward: the chunk and one step of every lane
+        valid = jnp.arange(tokens.shape[1])[None, :] < n_real
+        logits, cache = forward(
+            params, cfg, tokens, positions, cache, cache_attn_impl=plan, slot=slot, valid=valid,
+            lanes=(lane_tok[:, None], lane_pos[:, None]), last=n_real - 1,
+        )
+        nxt = jnp.argmax(logits[1:], -1).astype(jnp.int32)
+        return logits[0], nxt, jnp.minimum(lane_pos + 1, seq - 1), cache
+
     steps = {
         "decode": (jax.jit(decode_n, donate_argnums=(1, 2, 3)), (params, cache, i32(lanes), i32(lanes))),
         "prefill": (jax.jit(prefill, donate_argnums=(1,)), (params, cache, i32(), i32(1, 256), i32(1, 256), i32())),
     }
+    if cfg.linear_kind is None:  # the one launch for both, where no layer keeps a per-lane state
+        steps["mixed"] = (
+            jax.jit(mixed, donate_argnums=(1, 6, 7)),
+            (params, cache, i32(), i32(1, 256), i32(1, 256), i32(), i32(lanes), i32(lanes)),
+        )
     return cfg, cache, plan, steps
 
 
@@ -437,8 +452,8 @@ def test_hybrid_step_holds_no_stack_sized_temporary_on_v5e(v5e, step):
 V5E_USABLE_BYTES = 15.75e9
 
 
-@pytest.mark.parametrize("step", ["decode", "prefill"])
-def test_mistral4_step_fits_the_chip_and_reads_the_latent_stack_in_place_on_v5e(v5e, step):
+@pytest.mark.parametrize("step", ["decode", "prefill", "mixed"])
+def test_mistral4_step_fits_the_chip_and_reads_the_latent_stack_in_place_on_v5e(v5e, monkeypatch, step):
     """Mistral-Small-4's served share at its REAL size (9 layers, 32 of 128
     experts, the whole vocabulary, 16 lanes of 16,384, int8 as served), its
     kernels on: Mosaic accepts both latent kernels at a row of 320 values
@@ -448,7 +463,14 @@ def test_mistral4_step_fits_the_chip_and_reads_the_latent_stack_in_place_on_v5e(
     GB) is donated in place and never copied or relaid out, a model with no
     linear kind carries no state through the layer scan, and the step's live
     bytes (arguments + temporaries - what is aliased) fit a v5e's 15.75 GB:
-    the configuration file's memory claim."""
+    the configuration file's memory claim. ``mixed`` (ISSUE 41): the chunk's
+    256 rows and the 16 lanes' step through ONE layer loop with ONE call of
+    each kernel in its body (``mla_prefill`` over the chunk's rows,
+    ``mla_decode`` over the lanes', one ``moe_grouped_ffn`` for all 272 rows:
+    the held experts are read once), no all-experts einsum of the lanes' rows
+    left, and the head on 17 rows: no ``[256, V]`` or ``[272, V]`` logits."""
+    if step == "mixed":  # the grouped FFN's kernel is chosen by the backend, which is the CPU here
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg, cache, plan, steps = _hybrid_case("mistral4-9l", SingleDeviceSharding(v5e.devices[0]))
     assert (plan.kda_decode, plan.mla_decode, plan.mla_prefill) == ("", "pallas_mla_decode", "pallas_mla_prefill")
     assert cache.state is None and cache.conv is None and cache.latent.shape == (9, 16, 16_384, 384)
@@ -457,8 +479,16 @@ def test_mistral4_step_fits_the_chip_and_reads_the_latent_stack_in_place_on_v5e(
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert ("mla_decode" if step == "decode" else "mla_prefill") in text
-    if step == "prefill":
+    if step != "decode":
         assert not re.search(r"f32\[(1,)?32,256,16384\]", text)  # the chunk's scores stay in VMEM
+    if step == "mixed":
+        calls = re.findall(r'%([a-z_]+)[.\d]* = [^\n]*custom_call_target="tpu_custom_call"', text)
+        assert sorted(calls) == ["mla_decode", "mla_prefill", "moe_grouped_ffn"], calls
+        assert len(re.findall(r"\bwhile\(", text)) == 1  # the layer scan and nothing around or beside it
+        v = cfg.vocab_size
+        assert not re.search(rf"\[(1,)?(256|272),{v}\]", text) and re.search(rf"f32\[17,{v}\]", text)
+        # the lanes' all-experts einsum ([16, 32 held, F] activations) is gone with jit_decode_n's body
+        assert not re.search(rf"\[(1,)?16,(1,)?32,{cfg.ffn_dim}\]", text)
     mem = compiled.memory_analysis()
     latent = cache.latent.size * cache.latent.dtype.itemsize
     assert mem.alias_size_in_bytes >= latent

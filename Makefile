@@ -4,7 +4,7 @@
 
 PY ?= python
 
-.PHONY: all native test t1 test-native test-kernels bench overload spec decodeloop paged tiering fleet streaming chaos server dryrun verify clean analyze analyze-native
+.PHONY: all native test t1 test-native test-kernels bench chaos server dryrun verify clean analyze analyze-native
 
 all: native
 
@@ -21,7 +21,7 @@ t1:
 	bash -c 'set -o pipefail; rm -f /tmp/_t1.log; timeout -k 10 870 env JAX_PLATFORMS=cpu $(PY) -m pytest tests/ -q -m "not slow" --continue-on-collection-errors -p no:cacheprovider -p no:xdist -p no:randomly 2>&1 | tee /tmp/_t1.log; rc=$${PIPESTATUS[0]}; echo DOTS_PASSED=$$(grep -aE "^[.FEsx]+( *\[ *[0-9]+%\])?$$" /tmp/_t1.log | tr -cd . | wc -c); exit $$rc'
 
 # Invariant analysis plane (the merge gate next to t1 — docs/ANALYSIS.md):
-# 1. repo-custom AST lint (ATP001..ATP006) against the checked-in
+# 1. repo-custom AST lint (ATP001..ATP005) against the checked-in
 #    analysis/baseline.json ratchet — new violations fail, frozen ones
 #    carry per-site justifications;
 # 2. HLO contracts — never-all-gather sharding, donation aliasing, the
@@ -51,52 +51,6 @@ test-kernels:
 # one JSON line: {"metric":..., "value":..., "unit":..., "vs_baseline":...}
 bench: native
 	$(PY) bench.py
-
-# overload/deadline A/B in smoke mode (short duration, tiny model): goodput
-# with shedding on vs off at 2x saturation; full run drops ATPU_OVERLOAD_SMOKE
-overload:
-	JAX_PLATFORMS=cpu ATPU_OVERLOAD_SMOKE=1 $(PY) scripts/bench_overload.py
-
-# speculative-decoding A/B in smoke mode (short passes, tiny model): steady
-# decode ITL spec on vs off across json/chat/adversarial workloads; full
-# run drops ATPU_SPEC_SMOKE
-spec:
-	JAX_PLATFORMS=cpu ATPU_SPEC_SMOKE=1 $(PY) scripts/bench_spec.py
-
-# fused decode-loop A/B in smoke mode (short passes, tiny model): decode ITL
-# fused on vs off at batch 1/4/max, the raw per-step floor the loop must sit
-# within 1.2x of, and host syncs per token (strictly fewer on the natural-EOS
-# workload); writes BENCH_decode_loop.json. Full run drops ATPU_DECODELOOP_SMOKE
-decodeloop:
-	JAX_PLATFORMS=cpu ATPU_DECODELOOP_SMOKE=1 $(PY) scripts/bench_decode_loop.py
-
-# paged KV arena A/B (tiny model): resident-session capacity at the
-# dense-equivalent HBM budget, warm-prefix TTFT zero-copy page mapping vs
-# the PR-2 compiled fork, and the steady-ITL regression guard on the
-# gather/scatter attention path; writes BENCH_paged.json
-paged:
-	JAX_PLATFORMS=cpu $(PY) scripts/bench_paged.py
-
-# tiered KV hierarchy A/B (tiny model): context-retaining session capacity
-# at a fixed page-pool budget tiering on vs off, returning-turn TTFT for
-# parked sessions (never-parked control vs prewarmed vs cold promote),
-# and int8-vs-exact host-tier density; writes BENCH_tiering.json
-tiering:
-	JAX_PLATFORMS=cpu $(PY) scripts/bench_tiering.py
-
-# fleet bench (smoke): goodput + p99 TTFT at replicas 1/2/4 (echo), 2-replica
-# failover MTTR under steady probes, and mid-decode token-identical resume
-# on a surviving LLM replica; writes BENCH_fleet.json. Full run drops
-# ATPU_FLEET_SMOKE
-fleet:
-	JAX_PLATFORMS=cpu ATPU_FLEET_SMOKE=1 $(PY) scripts/bench_fleet.py
-
-# SSE streaming A/B (tiny model): streamed first-event latency vs the
-# buffered full-response wall under an admission burst, plus the
-# stream=false flag-parity guard (emission plumbing with no subscriber
-# must cost nothing); writes BENCH_streaming.json
-streaming:
-	JAX_PLATFORMS=cpu $(PY) scripts/bench_streaming.py
 
 # chaos soak: live daemon + engine subprocesses through the seeded fault
 # schedule (store blips, SIGKILLs, slow dispatch, torn AOF, poisoned

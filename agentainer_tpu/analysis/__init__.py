@@ -4,8 +4,8 @@ Three legs, one entry point (``make analyze``):
 
 1. **AST rules** (:mod:`.rules`): repo-specific invariants — exception
    discipline, hot-path host-sync bans, lock-hold discipline, failpoint
-   catalog parity, jit dispatch via warmed ladders, feature-flag quads —
-   checked as visitor rules with per-rule IDs (ATP001..ATP006) and a
+   catalog parity, jit dispatch via warmed ladders — checked as visitor
+   rules with per-rule IDs (ATP001..ATP005) and a
    checked-in ``baseline.json`` ratchet: pre-existing violations are
    frozen with per-site justifications, new ones fail the run.
 2. **HLO contracts** (:mod:`.hlo_contracts`): declarative assertions over

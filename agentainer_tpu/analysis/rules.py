@@ -1,4 +1,4 @@
-"""Repo-specific invariant rules (ATP001..ATP006).
+"""Repo-specific invariant rules (ATP001..ATP005).
 
 Each rule machine-checks a discipline that was once a real bug class in
 this codebase (see docs/ANALYSIS.md for the catalog and the war stories).
@@ -422,81 +422,10 @@ class JitDispatchDiscipline(Rule):
                         break
 
 
-# ---------------------------------------------------------------------------
-# ATP006 — feature-flag quad parity
-
-
-class FeatureFlagQuad(Rule):
-    """ATP006: every engine feature option ships its full quad.
-
-    A boolean engine option (an A/B-gated serving feature) must be
-    reachable all four ways, following the ``paged_kv``/``speculative``
-    pattern: (1) ``LLMEngine.__init__`` kwarg plumbed via
-    ``options.get(...)`` in ``create``, (2) a ``deploy`` CLI flag,
-    (3) the deployment-YAML ``options`` channel (same key as 1), and
-    (4) a fleet-default ``ATPU_*`` env read by both ``config.py``
-    (features) and the serving shim. Half-plumbed flags are how A/B
-    baselines silently stop being deployable.
-    """
-
-    rule_id = "ATP006"
-    title = "feature-flag quad parity"
-    scope = "project"
-
-    def check_project(self, mods: list[ModuleSource]) -> Iterable[Violation]:
-        by_path = {m.path: m for m in mods}
-        llm = by_path.get("agentainer_tpu/engine/llm.py")
-        cli = by_path.get("agentainer_tpu/cli.py")
-        serve = by_path.get("agentainer_tpu/engine/llm_serve.py")
-        config = by_path.get("agentainer_tpu/config.py")
-        if llm is None:
-            return
-
-        # discover: bool-defaulted LLMEngine.__init__ kwargs that are also
-        # options.get-plumbed — the definition of "engine feature option"
-        flags: list[str] = []
-        for node in ast.walk(llm.tree):
-            if not (isinstance(node, ast.ClassDef) and node.name == "LLMEngine"):
-                continue
-            for fn in node.body:
-                if not (isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)) and fn.name == "__init__"):
-                    continue
-                defaults = fn.args.defaults
-                names = [a.arg for a in fn.args.args][-len(defaults):] if defaults else []
-                for arg_name, default in zip(names, defaults):
-                    if isinstance(default, ast.Constant) and isinstance(default.value, bool):
-                        flags.append(arg_name)
-            break
-        plumbed = set(re.findall(r"options\.get\(\s*[\"'](\w+)[\"']", llm.text))
-        flags = [f for f in flags if f in plumbed]
-
-        for flag in flags:
-            kebab = flag.replace("_", "-")
-            env = f"ATPU_{flag.upper()}"
-            if cli is not None and f"--{kebab}" not in cli.text and f"--no-{kebab}" not in cli.text:
-                yield Violation(
-                    self.rule_id, cli.path, 1,
-                    f"engine option `{flag}` has no deploy CLI flag "
-                    f"(--{kebab} / --no-{kebab})",
-                )
-            if serve is not None and env not in serve.text:
-                yield Violation(
-                    self.rule_id, serve.path, 1,
-                    f"engine option `{flag}` has no fleet-default env read "
-                    f"({env} in _engine_options)",
-                )
-            if config is not None and env not in config.text:
-                yield Violation(
-                    self.rule_id, config.path, 1,
-                    f"engine option `{flag}` has no config/env bind ({env})",
-                )
-
-
 ALL_RULES: tuple[Rule, ...] = (
     ExceptDiscipline(),
     HotPathHostSync(),
     LockHoldDiscipline(),
     FailpointParity(),
     JitDispatchDiscipline(),
-    FeatureFlagQuad(),
 )

@@ -48,14 +48,30 @@ def _print(data) -> None:
     print(json.dumps(data, indent=2, default=str))
 
 
-def _parse_env(pairs: list[str]) -> dict[str, str]:
-    env = {}
+def _pairs(flag: str, pairs: list[str]):
     for pair in pairs or []:
         key, sep, val = pair.partition("=")
-        if not sep:
-            raise SystemExit(f"--env expects KEY=VALUE, got {pair!r}")
-        env[key] = val
-    return env
+        if not sep or not key:
+            raise SystemExit(f"{flag} expects KEY=VALUE, got {pair!r}")
+        yield key, val
+
+
+def _parse_env(pairs: list[str]) -> dict[str, str]:
+    return dict(_pairs("--env", pairs))
+
+
+def _parse_options(pairs: list[str]) -> dict[str, object]:
+    """``--option KEY=VALUE`` pairs as keys of the model spec's ``options``:
+    a value that reads as a JSON scalar is one (true, 4, 0.5, null), any
+    other is the string as typed."""
+    options: dict[str, object] = {}
+    for key, val in _pairs("--option", pairs):
+        try:
+            parsed = json.loads(val)
+        except ValueError:
+            parsed = val
+        options[key] = val if isinstance(parsed, (dict, list)) else parsed
+    return options
 
 
 # -- commands -------------------------------------------------------------
@@ -117,59 +133,13 @@ def cmd_deploy(args) -> None:
             print(f"  {line}")
         print(f"built artifact {art['name']!r}")
         model = {"engine": "llm", "artifact": art["name"]}
-    # engine-option flags need the dict form of the model spec; normalize
-    # a bare "engine:config" string once, then each flag just sets options
-    option_overrides: dict[str, object] = {}
-    if getattr(args, "no_speculative", False):
-        # A/B baseline deploy: pin this agent's engine to the plain decode
-        # path (options.speculative=false, same channel the deploy YAML uses)
-        option_overrides["speculative"] = False
-    if getattr(args, "paged_kv", False) or getattr(args, "no_paged_kv", False):
-        # paged KV arena per deployment: --paged-kv opts in (pool-bounded
-        # resident sessions), --no-paged-kv pins the dense A/B baseline
-        # even when the fleet default (features.paged_kv) flips on
-        option_overrides["paged_kv"] = bool(getattr(args, "paged_kv", False))
-    # the remaining engine A/B options follow the --no-speculative pattern:
-    # each flag pins this agent to its baseline via the same options
-    # channel the deployment YAML uses (quad checked by ATP006)
-    if getattr(args, "no_adaptive_decode", False):
-        option_overrides["adaptive_decode"] = False
-    if getattr(args, "no_prefix_cache", False):
-        option_overrides["prefix_cache"] = False
-    if getattr(args, "no_deadlines", False):
-        option_overrides["deadlines"] = False
-    if getattr(args, "fused_decode", False) or getattr(args, "no_fused_decode", False):
-        # fused on-device decode loop per deployment: --fused-decode opts
-        # in (one readback per loop), --no-fused-decode pins the per-chunk
-        # A/B baseline even when the fleet default (features.fused_decode)
-        # flips on
-        option_overrides["fused_decode"] = bool(getattr(args, "fused_decode", False))
-    if getattr(args, "inloop_spec", False) or getattr(args, "no_inloop_spec", False):
-        # in-loop device speculation per deployment: --inloop-spec opts in
-        # (n-gram draft + verify inside the fused loop), --no-inloop-spec
-        # pins the host-side prompt-lookup drafter as the A/B baseline
-        option_overrides["inloop_spec"] = bool(getattr(args, "inloop_spec", False))
-    if getattr(args, "approx_topk", False) or getattr(args, "no_approx_topk", False):
-        # segmented approx top-k sampler per deployment: --approx-topk opts
-        # in (lax.approx_max_k segment, NOT bit-exact for sampled lanes),
-        # --no-approx-topk pins the exact shared-sort sampler baseline
-        option_overrides["approx_topk"] = bool(getattr(args, "approx_topk", False))
-    if getattr(args, "kv_tiering", False) or getattr(args, "no_kv_tiering", False):
-        # tiered KV hierarchy per deployment: --kv-tiering opts in (idle
-        # sessions park to pinned host RAM/store and promote on return),
-        # --no-kv-tiering pins the resident-only arena as the A/B baseline
-        option_overrides["kv_tiering"] = bool(getattr(args, "kv_tiering", False))
-    if getattr(args, "streaming", False) or getattr(args, "no_streaming", False):
-        # SSE token streaming per deployment: --streaming opts the engine
-        # serve layer into stream=true handling (journaled offsets, crash-
-        # gapless failover splice), --no-streaming pins the buffered A/B
-        # baseline even when the fleet default (features.streaming) is on
-        option_overrides["streaming"] = bool(getattr(args, "streaming", False))
-    if option_overrides:
+    options = _parse_options(args.option)
+    if options:
+        # options live in the dict form of the model spec
         if isinstance(model, str):
             engine, _, config = model.partition(":")
             model = {"engine": engine or "echo", "config": config}
-        model.setdefault("options", {}).update(option_overrides)
+        model.setdefault("options", {}).update(options)
     body = {
         "name": args.name,
         "model": model,
@@ -462,127 +432,14 @@ def build_parser() -> argparse.ArgumentParser:
     s.add_argument("--hbm-bytes", type=int, default=8 * 1024**3)
     s.add_argument("--auto-restart", action="store_true")
     s.add_argument(
-        "--no-speculative",
-        action="store_true",
-        help="disable self-speculative decoding for this agent's engine "
-        "(the plain-decode A/B baseline; same as options.speculative: false "
-        "in a deployment YAML)",
-    )
-    paged_group = s.add_mutually_exclusive_group()
-    paged_group.add_argument(
-        "--paged-kv",
-        action="store_true",
-        help="serve this agent's engine from the paged KV arena (block "
-        "tables: resident sessions bounded by the page pool instead of "
-        "max_batch, zero-copy prefix sharing; same as options.paged_kv: "
-        "true in a deployment YAML)",
-    )
-    paged_group.add_argument(
-        "--no-paged-kv",
-        action="store_true",
-        help="pin this agent's engine to the dense KV arena (the A/B "
-        "baseline) even when the fleet default features.paged_kv is on",
-    )
-    s.add_argument(
-        "--no-adaptive-decode",
-        action="store_true",
-        help="pin this agent's engine to the fixed-cadence decode loop "
-        "(the pre-admission-aware A/B baseline; same as "
-        "options.adaptive_decode: false in a deployment YAML)",
-    )
-    s.add_argument(
-        "--no-prefix-cache",
-        action="store_true",
-        help="disable the cross-session prefix KV arena for this agent's "
-        "engine (every session prefills its full prompt; same as "
-        "options.prefix_cache: false in a deployment YAML)",
-    )
-    s.add_argument(
-        "--no-deadlines",
-        action="store_true",
-        help="disable engine-side deadline enforcement for this agent "
-        "(no fail-fast before prefill, no shed watermark; same as "
-        "options.deadlines: false in a deployment YAML)",
-    )
-    fused_group = s.add_mutually_exclusive_group()
-    fused_group.add_argument(
-        "--fused-decode",
-        action="store_true",
-        help="run this agent's engine with the fused on-device decode loop "
-        "(multi-step lax.while_loop with in-loop sampling and per-lane "
-        "early exit; one host readback per loop instead of per chunk; "
-        "same as options.fused_decode: true in a deployment YAML)",
-    )
-    fused_group.add_argument(
-        "--no-fused-decode",
-        action="store_true",
-        help="pin this agent's engine to the per-chunk decode dispatch "
-        "(the A/B baseline) even when the fleet default "
-        "features.fused_decode is on",
-    )
-    inloop_group = s.add_mutually_exclusive_group()
-    inloop_group.add_argument(
-        "--inloop-spec",
-        action="store_true",
-        help="run this agent's fused decode loop with in-loop device "
-        "speculation (n-gram draft + batched verify inside the "
-        "while_loop; lanes stay loop-resident while speculating; same as "
-        "options.inloop_spec: true in a deployment YAML)",
-    )
-    inloop_group.add_argument(
-        "--no-inloop-spec",
-        action="store_true",
-        help="pin this agent's engine to the host-side prompt-lookup "
-        "drafter (the A/B baseline) even when the fleet default "
-        "features.inloop_spec is on",
-    )
-    approx_group = s.add_mutually_exclusive_group()
-    approx_group.add_argument(
-        "--approx-topk",
-        action="store_true",
-        help="run this agent's sampler with the segmented approx top-k "
-        "path (jax.lax.approx_max_k over a fixed segment instead of the "
-        "full-vocab sort; NOT bit-exact for sampled lanes; same as "
-        "options.approx_topk: true in a deployment YAML)",
-    )
-    approx_group.add_argument(
-        "--no-approx-topk",
-        action="store_true",
-        help="pin this agent's engine to the exact shared-sort sampler "
-        "(the default baseline) even when the fleet default "
-        "features.approx_topk is on",
-    )
-    tiering_group = s.add_mutually_exclusive_group()
-    tiering_group.add_argument(
-        "--kv-tiering",
-        action="store_true",
-        help="enable the tiered KV hierarchy for this agent's engine "
-        "(idle sessions demote device → pinned host RAM → store and "
-        "promote back on their next turn; same as options.kv_tiering: "
-        "true in a deployment YAML)",
-    )
-    tiering_group.add_argument(
-        "--no-kv-tiering",
-        action="store_true",
-        help="pin this agent's engine to the resident-only KV arena "
-        "(the A/B baseline) even when the fleet default "
-        "features.kv_tiering is on",
-    )
-    streaming_group = s.add_mutually_exclusive_group()
-    streaming_group.add_argument(
-        "--streaming",
-        action="store_true",
-        help="enable SSE token streaming for this agent's engine "
-        "(stream=true chat bodies answer text/event-stream with every "
-        "token offset journaled; a mid-stream crash fails over with a "
-        "gapless splice; same as options.streaming: true in a "
-        "deployment YAML)",
-    )
-    streaming_group.add_argument(
-        "--no-streaming",
-        action="store_true",
-        help="pin this agent's engine to buffered responses (the A/B "
-        "baseline) even when the fleet default features.streaming is on",
+        "--option",
+        action="append",
+        default=[],
+        metavar="KEY=VALUE",
+        help="set a key of the model's options for this agent's engine, as "
+        "options: does in a deployment YAML (repeatable; the value is read "
+        "as a JSON scalar where it is one, e.g. --option paged_kv=true "
+        "--option spec_gamma_max=4)",
     )
     s.add_argument("--health-endpoint", default="")
     s.add_argument("--health-interval", type=float, default=30.0)

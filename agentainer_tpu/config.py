@@ -40,38 +40,6 @@ class FeatureFlags:
     # Serve /agent/* + the engine store socket from the C++ data plane when
     # the native library is available (falls back to the aiohttp proxy).
     native_dataplane: bool = True
-    # Default for engines' self-speculative decoding (prompt-lookup drafts
-    # + batched verify). Per-deployment model options override; false here
-    # pins the whole fleet to the plain decode path (the A/B baseline).
-    speculative: bool = True
-    # Default for engines' paged KV arena (block tables: pool-bounded
-    # resident sessions, zero-copy prefix sharing, page-tail speculative
-    # rewind). Off by default while the dense arena remains the
-    # hardware-burned-in baseline; per-deployment model options override
-    # (same plumbing pattern as ``speculative``).
-    paged_kv: bool = False
-    # Fleet defaults for the remaining engine A/B options, completing the
-    # feature-flag quad (engine kwarg <-> deploy CLI flag <-> YAML options
-    # <-> ATPU_* env — machine-checked by analysis rule ATP006):
-    # admission-aware decode chunking and the cross-session prefix arena.
-    adaptive_decode: bool = True
-    prefix_cache: bool = True
-    # Default for engines' fused on-device decode loop (multi-step
-    # lax.while_loop with in-loop sampling, per-lane early exit, and one
-    # readback per loop). Off by default while the per-chunk dispatch
-    # remains the A/B baseline; per-deployment model options override.
-    fused_decode: bool = False
-    # Default for engines' in-loop device speculation: the fused loop's
-    # n-gram drafter + batched verify branch, replacing the host-side
-    # prompt-lookup round-trip while a lane stays loop-resident. On by
-    # default — it only engages when the engine is fused+speculative and
-    # unmeshed, and greedy lanes are bit-exact with the host drafter.
-    inloop_spec: bool = True
-    # Default for engines' segmented approx top-k sampler
-    # (jax.lax.approx_max_k over a fixed-width segment instead of the
-    # full-vocab sort). Off by default: the exact shared-sort sampler is
-    # the baseline; approx is opt-in and NOT bit-exact for sampled lanes.
-    approx_topk: bool = False
     # Default for the tiered KV hierarchy (device → pinned host RAM →
     # store): idle sessions park off-device and promote back at their
     # next turn, with pool-pressure demotion converting 429s into
@@ -208,6 +176,19 @@ class Config:
 
 _SEARCH_PATHS = [".", "~/.agentainer_tpu", "/etc/agentainer_tpu"]
 
+# Engine switches that older config files carried under ``features:`` as
+# fleet defaults. They are keys of a deployment's ``model.options`` and of
+# nothing else; a file that still has one is refused, not half obeyed.
+ENGINE_SWITCHES = (
+    "speculative",
+    "paged_kv",
+    "adaptive_decode",
+    "prefix_cache",
+    "fused_decode",
+    "inloop_spec",
+    "approx_topk",
+)
+
 
 def load_config(path: str | None = None) -> Config:
     cfg = Config()
@@ -228,6 +209,15 @@ def load_config(path: str | None = None) -> Config:
     cfg.slice.name = sl.get("name", cfg.slice.name)
     cfg.slice.hosts = int(sl.get("hosts", cfg.slice.hosts))
     feats = doc.get("features", {})
+    moved = [k for k in ENGINE_SWITCHES if k in feats]
+    if moved:
+        raise ValueError(
+            "config.yaml: "
+            + ", ".join(f"features.{k}" for k in moved)
+            + " is not read: an engine switch is set on the deployment, as "
+            + ", ".join(f"model.options.{k}" for k in moved)
+            + " (deploy --option KEY=VALUE, or options: in a deployment YAML)"
+        )
     cfg.features.request_persistence = bool(
         feats.get("request_persistence", cfg.features.request_persistence)
     )
@@ -379,67 +369,6 @@ def load_config(path: str | None = None) -> Config:
     res_cfg.store_retry_base_s = _env_num(
         "ATPU_STORE_RETRY_BASE_S", float, res_cfg.store_retry_base_s
     )
-    cfg.features.speculative = bool(
-        feats.get("speculative", cfg.features.speculative)
-    )
-    if "ATPU_SPECULATIVE" in env:
-        cfg.features.speculative = env["ATPU_SPECULATIVE"].lower() in (
-            "1",
-            "true",
-            "yes",
-        )
-    cfg.features.paged_kv = bool(feats.get("paged_kv", cfg.features.paged_kv))
-    if "ATPU_PAGED_KV" in env:
-        cfg.features.paged_kv = env["ATPU_PAGED_KV"].lower() in (
-            "1",
-            "true",
-            "yes",
-        )
-    cfg.features.adaptive_decode = bool(
-        feats.get("adaptive_decode", cfg.features.adaptive_decode)
-    )
-    if "ATPU_ADAPTIVE_DECODE" in env:
-        cfg.features.adaptive_decode = env["ATPU_ADAPTIVE_DECODE"].lower() in (
-            "1",
-            "true",
-            "yes",
-        )
-    cfg.features.prefix_cache = bool(
-        feats.get("prefix_cache", cfg.features.prefix_cache)
-    )
-    if "ATPU_PREFIX_CACHE" in env:
-        cfg.features.prefix_cache = env["ATPU_PREFIX_CACHE"].lower() in (
-            "1",
-            "true",
-            "yes",
-        )
-    cfg.features.fused_decode = bool(
-        feats.get("fused_decode", cfg.features.fused_decode)
-    )
-    if "ATPU_FUSED_DECODE" in env:
-        cfg.features.fused_decode = env["ATPU_FUSED_DECODE"].lower() in (
-            "1",
-            "true",
-            "yes",
-        )
-    cfg.features.inloop_spec = bool(
-        feats.get("inloop_spec", cfg.features.inloop_spec)
-    )
-    if "ATPU_INLOOP_SPEC" in env:
-        cfg.features.inloop_spec = env["ATPU_INLOOP_SPEC"].lower() in (
-            "1",
-            "true",
-            "yes",
-        )
-    cfg.features.approx_topk = bool(
-        feats.get("approx_topk", cfg.features.approx_topk)
-    )
-    if "ATPU_APPROX_TOPK" in env:
-        cfg.features.approx_topk = env["ATPU_APPROX_TOPK"].lower() in (
-            "1",
-            "true",
-            "yes",
-        )
     cfg.features.kv_tiering = bool(
         feats.get("kv_tiering", cfg.features.kv_tiering)
     )

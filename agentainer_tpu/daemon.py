@@ -67,33 +67,19 @@ def build_services(
 ) -> Services:
     config = config or load_config()
     # engines inherit the daemon's environment (runtime/local.py builds
-    # their env from os.environ): exporting the speculative-decoding
-    # default here is what lets `features.speculative: false` in
-    # config.yaml pin every spawned engine to the plain-decode baseline
-    # without touching each deployment's model options. Written BOTH ways:
-    # load_config already folded any operator-set ATPU_SPECULATIVE into
-    # the flag, so this is a write-back of the resolved value — a second
-    # build_services with a different config must not inherit a stale latch
-    os.environ["ATPU_SPECULATIVE"] = "1" if config.features.speculative else "0"
-    # same write-back discipline for the paged-KV arena default: every
-    # spawned engine inherits the fleet's resolved choice unless its own
-    # deployment options say otherwise
-    os.environ["ATPU_PAGED_KV"] = "1" if config.features.paged_kv else "0"
-    # the rest of the engine A/B quad (ATP006): adaptive decode chunking,
-    # the prefix arena, and the engine-side deadline plumbing all ship the
-    # same fleet-default channel so `features.*: false` in config.yaml is
-    # deployable without per-agent option edits
-    os.environ["ATPU_ADAPTIVE_DECODE"] = "1" if config.features.adaptive_decode else "0"
-    os.environ["ATPU_PREFIX_CACHE"] = "1" if config.features.prefix_cache else "0"
-    os.environ["ATPU_FUSED_DECODE"] = "1" if config.features.fused_decode else "0"
-    os.environ["ATPU_INLOOP_SPEC"] = "1" if config.features.inloop_spec else "0"
-    os.environ["ATPU_APPROX_TOPK"] = "1" if config.features.approx_topk else "0"
+    # their env from os.environ). The three policies with a half in the
+    # proxy and a half in the engine reach the engine half this way, as a
+    # write-back of the resolved value: load_config already folded any
+    # operator-set variable into the config, and a second build_services
+    # with a different config must not inherit a stale latch. An engine
+    # switch is not a policy of the daemon's: it is set in the deployment's
+    # model.options and nowhere else.
     os.environ["ATPU_KV_TIERING"] = "1" if config.features.kv_tiering else "0"
     os.environ["ATPU_STREAMING"] = "1" if config.features.streaming else "0"
     os.environ["ATPU_DEADLINES"] = "1" if config.deadlines.enabled else "0"
     # Fault plane: the registry and the ATPU_FAULTS env the engines inherit
     # always reflect THIS config's schedule — same write-back-the-resolved-
-    # value discipline as ATPU_SPECULATIVE above: an empty spec must clear a
+    # value discipline as the policies above: an empty spec must clear a
     # previously armed registry and the stale env latch, or "faults
     # disabled" would keep firing in the daemon and every spawned engine.
     from . import faults as _faults

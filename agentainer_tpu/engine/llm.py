@@ -204,11 +204,11 @@ def _cache_off(cfg: ModelConfig) -> tuple[dict | None, str]:
 
 
 def fleet_default_applies(config_name: str, feature: str) -> bool:
-    """Whether a fleet-wide default (the daemon's ``ATPU_*`` environment)
-    reaches a deployment of ``config_name``: only for a feature its family's
-    cache can hold. A fleet default is nobody asking for the feature on this
-    model, so it falls away with its reason reported instead of failing the
-    build; ``model.options`` of the deployment itself still is an ask."""
+    """Whether a policy of the daemon's (its ``ATPU_*`` write-back, read by the
+    serving shim) reaches a deployment of ``config_name``. Of the three that
+    travel so, ``kv_tiering`` alone is something a cache can refuse: there the
+    daemon's default is nobody asking, so it falls away with its reason
+    reported; ``model.options`` of the deployment itself still is an ask."""
     try:
         cfg = get_config(config_name)
     except KeyError:
@@ -950,7 +950,7 @@ class LLMEngine:
             self._shlen,
         ) = self._alloc_carry()
         self._staged_lane: int | None = None
-        # instance toggle (not a constructor flag quad: injection is a
+        # instance toggle (not a constructor option: injection is a
         # fused-dispatch internal, A/B'd by tests flipping this directly)
         self._fused_inject = self.fused_decode
         self.fused_injections_total = 0
@@ -5895,8 +5895,8 @@ class LLMEngine:
                     return
             elif self.adaptive_decode:
                 # adaptive_decode=False is the FIXED-CADENCE baseline
-                # scheduler (A/B measurable: scripts/bench_admission.py) —
-                # it hard-blocks in processing like the round-5 engine did
+                # scheduler — it hard-blocks in processing like the round-5
+                # engine did
                 self._wait_admitting(arr)
                 if self._sentinel:
                     return

@@ -193,8 +193,8 @@ class LLMServeApp:
         self.drained_clean: bool | None = None
         self.drain_snapshots = 0
         # SSE streaming surface (stream=true on /chat, engine streaming
-        # option): keep-alive cadence is configurable per deployment; the
-        # env channel covers fleet-wide defaults like the flag quad
+        # option): keep-alive cadence is configurable per deployment, with
+        # the daemon's environment as the default
         try:
             self.stream_heartbeat_s = float(
                 self.model_options.get(
@@ -338,37 +338,18 @@ class LLMServeApp:
         from .llm import fleet_default_applies
 
         opts = dict(self.model_options)
-        # a fleet default never reaches a model whose cache cannot hold the
-        # feature (engine/llm.cache_features decides; the engine reports it off)
-        applies = lambda flag: fleet_default_applies(self.config_name, flag)  # noqa: E731
-        # fleet-wide speculative-decoding default (config features.speculative
-        # → daemon exports ATPU_SPECULATIVE → engine env): per-deployment
-        # model options still win
-        env_spec = os.environ.get("ATPU_SPECULATIVE")
-        if env_spec is not None and "speculative" not in opts and applies("speculative"):
-            opts["speculative"] = env_spec.lower() in ("1", "true", "yes")
-        # fleet-wide paged-KV-arena default (config features.paged_kv →
-        # daemon exports ATPU_PAGED_KV → engine env); per-deployment model
-        # options still win — same channel as speculative above
-        env_paged = os.environ.get("ATPU_PAGED_KV")
-        if env_paged is not None and "paged_kv" not in opts and applies("paged_kv"):
-            opts["paged_kv"] = env_paged.lower() in ("1", "true", "yes")
-        # remaining engine A/B options ride the identical fleet-default
-        # channel (daemon write-back -> engine env -> options, per-deploy
-        # model options always winning) — the full quad per flag is
-        # machine-checked by analysis rule ATP006
+        # the three policies with a half in the proxy (config.features /
+        # config.deadlines → the daemon's write-back → this process's env):
+        # the deployment's own model.options still win, and tiering never
+        # reaches a model whose cache cannot hold it (the engine reports it
+        # off with the reason)
         for flag, env_name in (
-            ("adaptive_decode", "ATPU_ADAPTIVE_DECODE"),
-            ("prefix_cache", "ATPU_PREFIX_CACHE"),
             ("deadlines", "ATPU_DEADLINES"),
-            ("fused_decode", "ATPU_FUSED_DECODE"),
-            ("inloop_spec", "ATPU_INLOOP_SPEC"),
-            ("approx_topk", "ATPU_APPROX_TOPK"),
             ("kv_tiering", "ATPU_KV_TIERING"),
             ("streaming", "ATPU_STREAMING"),
         ):
             raw = os.environ.get(env_name)
-            if raw is not None and flag not in opts and applies(flag):
+            if raw is not None and flag not in opts and fleet_default_applies(self.config_name, flag):
                 opts[flag] = raw.lower() in ("1", "true", "yes")
         if self.chips:
             # no tp injection: LLMEngine.create derives the parallelism

@@ -1,14 +1,21 @@
 """Loader for the native layer (libagentainer_native.so).
 
 Builds on first use via ``make -C native`` (g++ is part of the baked
-toolchain) and caches the result. Everything degrades gracefully: callers
+toolchain) and caches the result. A failed build degrades gracefully: callers
 check ``available()`` and fall back to the pure-Python store / aiohttp proxy
 when the library can't be built (e.g. no compiler on a user machine).
+
+Processes that start together on a fresh check-out (a daemon beside its
+engine hosts, the workers of a test run) all come through ``ensure_built``:
+one of them builds under an exclusive lock and the others wait for it, and
+the Makefile links to a temporary name and renames, so nobody ever opens a
+library that is still being written.
 """
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
 import subprocess
 import sys
@@ -18,12 +25,19 @@ from pathlib import Path
 
 _REPO_ROOT = Path(__file__).resolve().parent.parent
 _NATIVE_DIR = _REPO_ROOT / "native"
-_LIB_PATH = _NATIVE_DIR / "build" / "libagentainer_native.so"
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _load_error: str | None = None
 _load_s: float | None = None  # what the one real load() took, build included
+
+
+def lib_path() -> Path:
+    return _NATIVE_DIR / "build" / "libagentainer_native.so"
+
+
+def loadgen_path() -> Path:
+    return _NATIVE_DIR / "build" / "loadgen"
 
 
 def _build() -> bool:
@@ -43,7 +57,7 @@ def _build() -> bool:
                 "store/data plane):\n  " + "\n  ".join(tail),
                 file=sys.stderr,
             )
-        return proc.returncode == 0 and _LIB_PATH.exists()
+        return proc.returncode == 0 and lib_path().exists()
     except (OSError, subprocess.SubprocessError) as e:
         print(f"[atpu-native] build not attempted: {e}", file=sys.stderr)
         return False
@@ -107,14 +121,32 @@ def _bind(lib: ctypes.CDLL) -> None:
     ]
 
 
+def ensure_built() -> str | None:
+    """Bring ``native/build/`` up to date with the sources: the library and
+    ``loadgen``. Returns why it could not, or None. Between processes one
+    caller builds and the rest wait on the lock, then find the work done."""
+    build_dir = _NATIVE_DIR / "build"
+    try:
+        build_dir.mkdir(exist_ok=True)
+        lock = open(build_dir / ".build.lock", "w")
+    except OSError as e:
+        # a check-out this process cannot write to: nothing is built here,
+        # and a library someone else put there is loaded as it is
+        return f"native build not attempted: {e}" if _stale() else None
+    with lock:
+        fcntl.flock(lock, fcntl.LOCK_EX)  # released when the file closes
+        if _stale() and not _build():
+            return "native build failed (make -C native)"
+    return None
+
+
 def _load_once() -> ctypes.CDLL | None:
     global _load_error
-    if not _LIB_PATH.exists() or _stale():
-        if not _build():
-            _load_error = "native build failed (make -C native)"
-            return None
+    _load_error = ensure_built()
+    if _load_error is not None:
+        return None
     try:
-        lib = ctypes.CDLL(str(_LIB_PATH))
+        lib = ctypes.CDLL(str(lib_path()))
         _bind(lib)
         return lib
     except OSError as e:
@@ -134,16 +166,17 @@ def load() -> ctypes.CDLL | None:
 
 
 def _stale() -> bool:
-    """Rebuild when any source is newer than the library."""
+    """Rebuild when a target is missing or any source is newer than the
+    library."""
     try:
-        lib_mtime = _LIB_PATH.stat().st_mtime
-        for src in _NATIVE_DIR.glob("*.cc"):
-            if src.stat().st_mtime > lib_mtime:
-                return True
-        for src in _NATIVE_DIR.glob("*.h"):
-            if src.stat().st_mtime > lib_mtime:
-                return True
-        return False
+        if not loadgen_path().exists():
+            return True
+        lib_mtime = lib_path().stat().st_mtime
+        return any(
+            src.stat().st_mtime > lib_mtime
+            for pattern in ("*.cc", "*.h")
+            for src in _NATIVE_DIR.glob(pattern)
+        )
     except OSError:
         return True
 

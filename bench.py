@@ -104,20 +104,9 @@ def _loadgen_bin() -> str | None:
     the Python asyncio generators burn most of the CPU the data plane
     needs, capping the measured req/s at the GENERATOR's throughput. The
     C loop costs a few us/request, so the daemon gets the core."""
-    import pathlib
+    from agentainer_tpu import native
 
-    p = pathlib.Path(__file__).resolve().parent / "native" / "build" / "loadgen"
-    if p.exists():
-        return str(p)
-    import subprocess
-
-    try:
-        subprocess.run(
-            ["make", "-C", str(p.parent.parent)], capture_output=True, timeout=300
-        )
-    except Exception:
-        return None
-    return str(p) if p.exists() else None
+    return str(native.loadgen_path()) if native.ensure_built() is None else None
 
 
 def run_load_native(

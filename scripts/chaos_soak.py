@@ -71,12 +71,10 @@ import sys
 import tempfile
 import time
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO_ROOT)
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-from _benchlib import write_artifact  # noqa: E402
 
 from agentainer_tpu import faults  # noqa: E402
 from agentainer_tpu.config import Config  # noqa: E402
@@ -1637,7 +1635,11 @@ def main() -> int:
         **result,
         "wall_s": round(time.monotonic() - t0, 1),
     }
-    write_artifact("BENCH_chaos.json", doc)
+    # one JSON line on stdout, and the same line as the record at the root
+    line = json.dumps(doc)
+    print(line, flush=True)
+    with open(os.path.join(REPO_ROOT, "BENCH_chaos.json"), "w") as f:
+        f.write(line + "\n")
     if not ok:
         print(f"CHAOS SOAK FAILED: {result['violations']}", file=sys.stderr)
         return 1

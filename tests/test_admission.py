@@ -125,8 +125,8 @@ def test_ttft_phase_decomposition():
 
 def test_fixed_mode_keeps_legacy_cadence():
     """adaptive_decode=False is the A/B baseline: full chunks always, no
-    shrinks, no multi-tick prefill — scripts/bench_admission.py depends on
-    this being a faithful reproduction of the round-5 scheduler."""
+    shrinks, no multi-tick prefill — a faithful reproduction of the round-5
+    scheduler."""
     eng = _mk(adaptive_decode=False)
     try:
         async def scenario():

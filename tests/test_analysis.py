@@ -22,7 +22,6 @@ from agentainer_tpu.analysis.rules import (
     ALL_RULES,
     ExceptDiscipline,
     FailpointParity,
-    FeatureFlagQuad,
     HotPathHostSync,
     JitDispatchDiscipline,
     LockHoldDiscipline,
@@ -237,36 +236,6 @@ def test_atp005_flags_inline_and_looped_jit(tmp_path):
     assert "per evaluation" in v[0].message or "per evaluation" in v[1].message
     assert any("loop" in x.message for x in v)
     del lines
-
-
-# ---------------------------------------------------------------------------
-# ATP006
-
-
-def test_atp006_flags_half_plumbed_flag(tmp_path):
-    root = _repo(tmp_path, {
-        "agentainer_tpu/engine/llm.py": """
-            class LLMEngine:
-                def __init__(self, cfg, shiny_mode: bool = True):
-                    self.shiny_mode = shiny_mode
-
-                @classmethod
-                def create(cls, options):
-                    return cls(None, shiny_mode=bool(options.get("shiny_mode", True)))
-        """,
-        "agentainer_tpu/cli.py": "pass\n",
-        "agentainer_tpu/engine/llm_serve.py": "pass\n",
-        "agentainer_tpu/config.py": "pass\n",
-    })
-    msgs = [v.message for v in _run(FeatureFlagQuad(), root, roots=("agentainer_tpu",))]
-    assert any("no deploy CLI flag" in m for m in msgs)
-    assert any("ATPU_SHINY_MODE" in m and "fleet-default" in m for m in msgs)
-    assert any("config/env bind" in m for m in msgs)
-
-
-def test_atp006_real_repo_quads_complete():
-    violations, _ = run_rules([FeatureFlagQuad()], baseline=Baseline(entries={}))
-    assert violations == [], [v.format() for v in violations]
 
 
 # ---------------------------------------------------------------------------

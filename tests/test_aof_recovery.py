@@ -17,15 +17,7 @@ import os
 import pytest
 
 from agentainer_tpu.store import MemoryStore
-
-
-def _native_available() -> bool:
-    try:
-        from agentainer_tpu.native import available
-
-        return available()
-    except Exception:
-        return False
+from tests.conftest import _native_available
 
 
 pytestmark = pytest.mark.skipif(

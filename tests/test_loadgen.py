@@ -4,29 +4,18 @@ reuse, Content-Length framing, latency accounting."""
 
 import asyncio
 import json
-import pathlib
 import subprocess
 
 import pytest
 
+from agentainer_tpu import native
+
 from .test_e2e_local import AUTH, run, start_stack, teardown
 
-LOADGEN = pathlib.Path(__file__).resolve().parent.parent / "native" / "build" / "loadgen"
+LOADGEN = native.loadgen_path()
 
 
-def _ensure_built() -> bool:
-    if LOADGEN.exists():
-        return True
-    try:
-        subprocess.run(
-            ["make", "-C", str(LOADGEN.parent.parent)], capture_output=True, timeout=300
-        )
-    except Exception:
-        return False
-    return LOADGEN.exists()
-
-
-@pytest.mark.skipif(not _ensure_built(), reason="native loadgen not buildable")
+@pytest.mark.skipif(native.ensure_built() is not None, reason="native loadgen not buildable")
 def test_loadgen_drives_proxy_e2e(tmp_path):
     async def body():
         services, client = await start_stack(tmp_path)

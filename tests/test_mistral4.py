@@ -584,7 +584,7 @@ def test_cache_off_has_a_table_for_each_kind_of_cache():
     assert llm._cache_off(get_config("tiny-moe")) == (None, "")
     assert set(llm._LATENT_OFF) == set(llm._RECURRENT_OFF) == set(llm._WINDOW_OFF)
     assert not any("recurrent" in why or "state" in why.split("per-lane")[0] for why in llm._LATENT_OFF.values())
-    assert not llm.fleet_default_applies("tiny-mistral4", "speculative")
+    assert not llm.fleet_default_applies("tiny-mistral4", "kv_tiering")
 
 
 def test_metrics_name_the_latent_leaf_the_counters_the_rotary_kind_and_what_is_off(uninterrupted):

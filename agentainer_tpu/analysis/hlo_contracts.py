@@ -332,53 +332,68 @@ class StacksRideInCarry:
 @dataclass
 class MixedStepOverStacks:
     """``ArenaRidesInCarry``'s two groups of rows for the hybrid block's cache
-    where it is positional rows alone (latent rows, or K and V; no linear
-    mixer): over the LOWERED (StableHLO) text of ``jit_prefill_with_decode``.
+    (latent rows, or K and V; beside a linear mixer's state and conv where the
+    block has one): over the LOWERED (StableHLO) text of
+    ``jit_prefill_with_decode``.
 
-    - Each stack is a carried value of exactly ONE while loop, once: the layer
-      scan. A second loop over the layers (a chunk's forward and then a decode
-      step's) reads every weight again, which is what the program exists to
-      avoid; a block with no linear mixer has no 0-or-1-trip loop either.
-    - A stack takes exactly two writes: the chunk's ``chunk`` rows and the
-      lanes' ``lanes`` rows, each row once, never a layer.
+    - Each stack is a carried value, once, of exactly ``loops`` while loops:
+      the layer scan, and where the block has both kinds of mixer the
+      0-or-1-trip loop round the stack's own (``loops = 2``). One loop more is
+      a second pass over the layers (a chunk's forward and then a decode
+      step's), which reads every weight again: what the program exists to
+      avoid.
+    - A positional stack takes exactly two writes: the chunk's ``chunk`` rows
+      and the lanes' ``lanes`` rows, each row once, never a layer.
+    - A per-lane stack (``per_lane``: state, conv) takes exactly two too: the
+      chunk's ONE lane and the step's ``lanes`` lanes, a group at a time; or,
+      named in ``joined`` (conv: the chunk's window joins the B before they
+      step), the ``lanes`` lanes in ONE write.
     - No value is ``[chunk + lanes, vocab]`` or ``[chunk, vocab]``: the head
       runs on the ``1 + lanes`` rows somebody reads (ask with a chunk that is
       not the model's width: the head's own matrix is ``[dim, vocab]``).
 
     That the donated cache and carry alias the outputs is ``DonationAliased``
     on the compiled module; that the chip's compiler keeps one call of each
-    latent kernel and one grouped FFN in the loop's body, and copies no
-    stack, is the described-v5e compile in tests/test_tpu_compile.py.
+    kernel and one grouped FFN in the loop's body, and copies no stack, is
+    the described-v5e compile in tests/test_tpu_compile.py.
     """
 
     stacks: dict  # name -> shape [n, B, S, ...]
     chunk: int
     lanes: int
     vocab: int
+    per_lane: dict = field(default_factory=dict)  # name -> shape [n, B, ...]
+    loops: int = 1
+    joined: tuple = ()  # the per-lane stacks written once, all ``lanes`` lanes
 
     def failures(self, text: str) -> list[str]:
         out: list[str] = []
         whiles, writes = _while_carries(text), _writes(text)
-        for name, shape in self.stacks.items():
+        named = {**self.stacks, **self.per_lane}
+        for name, shape in named.items():
             shape = tuple(shape)
+            twins = sum(tuple(s) == shape for s in named.values())  # K and V: two stacks of one shape
             carrying = [n for dims in whiles if (n := sum(d == shape for d in dims))]
             if not carrying:
                 out.append(f"{name} {list(shape)} is carried by no while loop: sliced and restacked around the layer loop")
-            if len(carrying) > 1:
+            if len(carrying) > self.loops:
                 out.append(
-                    f"{name} is carried by {len(carrying)} while loops (want exactly 1): "
+                    f"{name} is carried by {len(carrying)} while loops (want exactly {self.loops}): "
                     "a second loop over the layers reads the weights again"
                 )
-            if any(n != 1 for n in carrying):
-                out.append(f"loops carry {carrying} values of {name}'s shape each (want 1)")
-            row = math.prod(shape[3:])
+            if any(n != twins for n in carrying):
+                out.append(f"loops carry {carrying} values of {name}'s shape each (want {twins})")
             sizes = sorted(math.prod(upd) for operand, upd in writes if operand == shape)
-            want = sorted((self.chunk * row, self.lanes * row))
+            if name in self.per_lane:
+                lane = math.prod(shape[2:])
+                want, what = sorted((lane, self.lanes * lane) * twins), "the chunk's lane and the step's lanes"
+                if name in self.joined:
+                    want, what = [self.lanes * lane] * twins, "the step's lanes, the chunk's among them"
+            else:
+                row = math.prod(shape[3:])
+                want, what = sorted((self.chunk * row, self.lanes * row) * twins), "the chunk's rows and the lanes'"
             if sizes != want:
-                out.append(
-                    f"writes into {name} of {sizes} elements: want the chunk's rows and the lanes' "
-                    f"{want}, a layer is {math.prod(shape[1:])}"
-                )
+                out.append(f"writes into {name} of {sizes} elements: want {what} {want}, a layer is {math.prod(shape[1:])}")
         tall = {
             t for t in re.findall(r"tensor<[^>]*>", text)
             if "x" in t and (dims := _tensor_dims(t))[-1:] == (self.vocab,)

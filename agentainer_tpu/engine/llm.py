@@ -1703,24 +1703,24 @@ class LLMEngine:
         else:
             self._prefill = jax.jit(prefill, donate_argnums=(1,))
             self._decode_n = jax.jit(decode_n, donate_argnums=(1, 2, 3))
-        # Does this engine have the mixed step? Where the cache is positional
-        # rows alone on one chip under the per-chunk decode driver, with
-        # ``forward`` choosing the MoE path by row count: the K/V block's
-        # dense arena, or the hybrid block where no layer is a linear mixer
-        # (latent or K/V rows and the two controls: nothing of a lane that
-        # another group's rows could touch). A hybrid block WITH a linear
-        # mixer (a state and a conv that must not see the other group's
-        # rows), the page pool, the fused loop, a mesh and the ``routed``
-        # dispatch (whose capacity a chunk's rows would share with the
-        # lanes') each need a body of their own: they keep two launches, as
-        # does a decode ladder without the one-step rung the program stands
-        # in for.
-        self._prefill_with_decode = None
+        # Does this engine have the mixed step? Where the cache is the dense
+        # arena of one chip under the per-chunk decode driver and ``forward``
+        # chooses the MoE path by row count: the K/V block, and the hybrid
+        # block whatever its layers' kinds (``models/hybrid``: a linear mixer
+        # steps the lanes' state and conv a group of rows at a time). The page
+        # pool, the fused loop, a mesh and the ``routed`` dispatch keep two
+        # launches, as does a ladder without the one-step rung. Its rungs are
+        # the two largest buckets a chunk can take, a trade for the boot: each
+        # rung is a program traced, lowered and read back at every start
+        # (1.7-2 s of a hybrid engine's), and what the trade costs is a ridden
+        # chunk of a quarter of the top bucket or less padded to half of it.
+        self._prefill_with_decode, self._mixed_buckets = None, ()
         if self._decode_ladder[0] == 1 and not (
-            cfg.linear_kind is not None or self.paged or self.fused_decode
-            or self.mesh is not None or moe_impl is not None
+            self.paged or self.fused_decode or self.mesh is not None or moe_impl is not None
         ):
             self._prefill_with_decode = jax.jit(prefill_with_decode, donate_argnums=(1, 6, 7))
+            top = self._bucket(min(self.prefill_chunk, max(1, self.max_seq - 2)))
+            self._mixed_buckets = tuple(b for b in PREFILL_BUCKETS if b <= top)[-2:]
         self._inject = jax.jit(inject, donate_argnums=(0, 1, 2, 3, 4, 5, 6))
         self._first_token = jax.jit(first_token)
         if self._recurrent:
@@ -2168,15 +2168,15 @@ class LLMEngine:
                 jax.block_until_ready(self.cache.k)
         # the mixed step (a prefill chunk that carries the decode lanes'
         # step): warm-up serves one request at a time and so never has a
-        # chunk pending beside a decoding lane. One program per bucket a
-        # chunk can take, against the live carry and cache like the verify
-        # ladder: every lane is parked, the chunk's rows land in slot 0,
-        # which ``clear_sessions`` below leaves cold.
+        # chunk pending beside a decoding lane. One program per rung a
+        # ridden chunk can take (``_mixed_buckets``: the plain ladder's two
+        # largest, the gate in ``_build_compiled`` says what that trades),
+        # against the live carry and cache like the verify ladder:
+        # every lane is parked, the chunk's rows land in slot 0, which
+        # ``clear_sessions`` below leaves cold.
         if self._prefill_with_decode is not None:
             with boot.span("boot.warmup_mixed"):
-                for b in PREFILL_BUCKETS:
-                    if b > top_bucket:
-                        break
+                for b in self._mixed_buckets:
                     self._launch_with_decode(
                         0,
                         jnp.asarray(np.zeros((1, b), np.int32)),
@@ -4994,7 +4994,7 @@ class LLMEngine:
         final = not slot.pending_prompt
         n = len(chunk)
         with span("engine.prefill_dispatch"):  # host prep + the call returning
-            bucket = self._bucket(n)
+            bucket = next(b for b in self._mixed_buckets if b >= n) if riders else self._bucket(n)
             padded = chunk + [0] * (bucket - n)
             # padding positions continue past the real tokens; every such
             # slot is rewritten by a later real token (next chunk or decode)

@@ -64,15 +64,19 @@ generates is never fed, so a parked or idle lane, and the steps a pipelined
 chunk runs past a request's end, cannot touch a session's state.
 
 **Two groups of rows in one launch** (``forward``'s ``lanes``, the engine's
-``jit_prefill_with_decode``): where NO layer is a linear mixer the cache is
-positional rows and the two controls, so a lane's chunk ``[1, T]`` and one
-decode step of every lane ``[B, 1]`` go through the layer scan's body
-together, ``[1, T + B]`` rows through everything that reads weights. A
-positional mixer writes both groups' rows, then attends each group as its
-own launch would (``_groups``, ``_put_groups``); the lanes' rows follow the
-controls as a ``T = 1`` step does. With a linear mixer among the layers a
-lane's state and conv would have to be kept from the other group's rows:
-``forward`` refuses ``lanes`` there.
+``jit_prefill_with_decode``): a lane's chunk ``[1, T]`` and one decode step
+of every lane ``[B, 1]`` go through the layer scan's body together, ``[1, T
++ B]`` rows through everything that reads weights. A positional mixer writes
+both groups' rows, then attends each group as its own launch would
+(``_groups``, ``_put_groups``). A linear mixer runs its projections and
+everything elementwise over all the rows at once, and what reads or writes a
+per-lane leaf a group at a time (``_short_conv``, ``_stack_by_group``): the
+chunk's rows through the conv window and the chunked rule on lane ``slot``'s
+state, the lanes' rows as a ``T = 1`` step of every lane on its own, so a
+lane's window never slides over the chunk's rows nor the chunk's over a
+lane's. The lanes' rows follow the controls as a ``T = 1`` step does; the
+chunk's own lane is among them, parked at or past its
+``stop``, so that step leaves what the chunk wrote as it is.
 """
 
 from __future__ import annotations
@@ -564,14 +568,77 @@ def _by_group(attend, n_lanes: int, q, positions, valid, slot):
     return jnp.concatenate([o_c, o_l.reshape(1, n_lanes, *o_l.shape[2:])], axis=1)
 
 
-def kda_mixer(h, lp, cfg: ModelConfig, state, conv, idx, slot, valid, plan: HybridPlan):
+def _cut_rows(n_lanes: int, a):
+    """A ``[1, T + B, ...]`` array as (the chunk's ``[1, T, ...]``, the lanes'
+    ``[B, 1, ...]``), cut as ROWS ``[T + B, ...]``; :func:`_join_rows` joins
+    them so. Cut or joined along the second of three dimensions, the chip's
+    compiler lays a ``[1, T + B, C]`` value out a row a tile and every
+    elementwise pass over it takes eight times its bytes (7 of a 53 ms launch
+    of Olmo-Hybrid's, my chip run, PR 48)."""
+    return a[0][None, :-n_lanes], a[0][-n_lanes:, None]
+
+
+def _join_rows(o_c, o_l):
+    return jnp.concatenate([o_c[0], o_l[:, 0]], axis=0)[None]
+
+
+def _stack_by_group(fn, n_lanes: int, slot, stack, *arrays):
+    """``fn(*arrays, slot, stack) -> (out, stack)`` over a launch's rows, for
+    what reads and writes a per-lane stack (a linear mixer's state): one call,
+    or with ``n_lanes`` one for each group, as its own launch would make it:
+    the chunk's ``[1, T]`` rows on lane ``slot``'s leaf, then the lanes' ``[B,
+    1]`` as a ``T = 1`` step of every lane on its own. Neither group's rows
+    reach the other's leaves; the chunk's own lane is among the B and does
+    not step (:func:`forward`). ``out`` comes back joined as ``[1, T + B,
+    ...]``."""
+    if not n_lanes:
+        return fn(*arrays, slot, stack)
+    chunk, lanes = zip(*(_cut_rows(n_lanes, a) for a in arrays))
+    o_c, stack = fn(*chunk, slot, stack)
+    o_l, stack = fn(*lanes, None, stack)
+    return _join_rows(o_c, o_l), stack
+
+
+def _short_conv(h, lp, cfg: ModelConfig, conv, idx, slot, valid, n_lanes: int):
+    """``h``'s input projection ``wqkv`` through the short causal conv, each
+    lane's rows continuing its window of layer ``idx``: the outputs ``[B, T,
+    C]``, the windows moved on by the valid tokens and the slot to put them
+    back at (:func:`_put_rows`, once the delta rule has run: a call without
+    ``n_lanes`` holds its operations in the order it always had, the pinned
+    programs of ``tests/test_hlo_contracts.py``). With ``n_lanes`` a group at
+    a time, as its own launch would: the chunk's ``[1, T]`` rows on lane
+    ``slot``'s window, then the lanes' ``[B, 1]`` as a ``T = 1`` step of every
+    lane on its own, the chunk's lane among them as the chunk leaves it (it
+    does not step: :func:`forward`), so the B windows are what goes back. A
+    lane's window never slides over the chunk's rows nor the chunk's over a
+    lane's."""
+
+    def window(valid, slot):  # the tokens that move a group's windows on, and the windows as they stand
+        b = valid.shape[0]
+        return jnp.sum(valid, axis=1).astype(jnp.int32), _rows(conv, idx, slot, b).reshape(b, cfg.kda_conv - 1, -1)
+
+    if not n_lanes:
+        n_valid, rows = window(valid, slot)
+        return *kda_ops.causal_conv(_proj(h, lp["wqkv"]), rows, lp["conv"], n_valid), slot
+    (valid_c, valid_l), (x_c, x_l) = (_cut_rows(n_lanes, a) for a in (valid, _proj(h, lp["wqkv"])))
+    n_valid, rows = window(valid_c, slot)
+    y_c, new_c = kda_ops.causal_conv(x_c, rows, lp["conv"], n_valid)
+    n_valid, rows = window(valid_l, None)
+    rows = lax.dynamic_update_slice_in_dim(rows, new_c.astype(rows.dtype), slot, axis=0)
+    y_l, new = kda_ops.causal_conv(x_l, rows, lp["conv"], n_valid)
+    return _join_rows(y_c, y_l), new, None
+
+
+def kda_mixer(h, lp, cfg: ModelConfig, state, conv, idx, slot, valid, plan: HybridPlan, n_lanes: int = 0):
     """``h [B, T, d]`` (normed) → the mixer's output, and the state and conv
-    stacks with layer ``idx``'s lanes stepped by the valid tokens."""
+    stacks with layer ``idx``'s lanes stepped by the valid tokens. ``n_lanes``
+    as :func:`mla_mixer` takes it: the projections and everything elementwise
+    run over the ``[1, T + B]`` rows once; the conv windows and the delta
+    rule, which read and write a lane's leaves, run a group at a time
+    (:func:`_short_conv`, :func:`_stack_by_group`)."""
     b, t, _ = h.shape
     nh, dk = cfg.kda_heads, cfg.kda_head_dim
-    n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
-    conv_rows = _rows(conv, idx, slot, b).reshape(b, cfg.kda_conv - 1, 3 * nh * dk)
-    qkv, new_conv = kda_ops.causal_conv(_proj(h, lp["wqkv"]), conv_rows, lp["conv"], n_valid)
+    qkv, window, put_at = _short_conv(h, lp, cfg, conv, idx, slot, valid, n_lanes)
     q, k, v = jnp.split(jax.nn.silu(qkv).reshape(b, t, 3, nh, dk), 3, axis=2)
     q, k, v = q[:, :, 0], k[:, :, 0], v[:, :, 0]
     q = _l2norm(q) * dk**-0.5
@@ -580,36 +647,39 @@ def kda_mixer(h, lp, cfg: ModelConfig, state, conv, idx, slot, valid, plan: Hybr
     g = -jnp.exp(lp["a_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(decay_in).reshape(b, t, nh, dk)
     beta = jax.nn.sigmoid(_proj(h, lp["w_beta"]))
     g, beta = kda_ops.mask_inputs(g, beta, valid)
-    if t == 1 and plan.kda_decode == "pallas_kda_decode" and slot is None:
-        from ..ops.pallas_kda import kda_decode
 
-        o, state = kda_decode(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state, idx)
-        o = o[:, None]
-    else:
+    def rule(q, k, v, g, beta, slot, state):
+        b, t = beta.shape[:2]
+        if t == 1 and plan.kda_decode == "pallas_kda_decode" and slot is None:
+            from ..ops.pallas_kda import kda_decode
+
+            o, state = kda_decode(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state, idx)
+            return o[:, None], state
         rows = _rows(state, idx, slot, b)
         if t == 1:
             o, rows = kda_ops.kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], rows)
             o = o[:, None]
         else:
             o, rows = kda_ops.kda_chunked(q, k, v, g, beta, rows)
-        state = _put_rows(state, rows, idx, slot)
-    conv = _put_rows(conv, new_conv.reshape(b, -1), idx, slot)
+        return o, _put_rows(state, rows, idx, slot)
+
+    o, state = _stack_by_group(rule, n_lanes, slot, state, q, k, v, g, beta)
+    conv = _put_rows(conv, window.reshape(window.shape[0], -1), idx, put_at)
     gate = jax.nn.sigmoid(_proj(_proj(h, lp["w_ga"]).astype(h.dtype), lp["w_gb"])).reshape(b, t, nh, dk)
     o = rms_norm(o, lp["o_norm"], cfg.norm_eps) * gate
     return _proj(o.reshape(b, t, nh * dk).astype(h.dtype), lp["wo"]), state, conv
 
 
-def gdn_mixer(h, lp, cfg: ModelConfig, state, conv, idx, slot, valid, plan: HybridPlan):
+def gdn_mixer(h, lp, cfg: ModelConfig, state, conv, idx, slot, valid, plan: HybridPlan, n_lanes: int = 0):
     """``h [B, T, d]`` → the Gated DeltaNet mixer's output, and the state
     ``[n, B, dk, H·dv]`` and conv stacks with layer ``idx``'s lanes stepped by
     the valid tokens. One decay a head; ``β = 2 · sigmoid`` under
-    ``cfg.delta_neg_eigval``; a full-rank SiLU gate on the normed output."""
+    ``cfg.delta_neg_eigval``; a full-rank SiLU gate on the normed output.
+    ``n_lanes`` as :func:`kda_mixer`."""
     b, t, _ = h.shape
     nh, dk, dv = cfg.kda_heads, cfg.kda_head_dim, cfg.delta_v_dim
     ck = nh * dk
-    n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)
-    conv_rows = _rows(conv, idx, slot, b).reshape(b, cfg.kda_conv - 1, conv_channels(cfg))
-    qkv, new_conv = kda_ops.causal_conv(_proj(h, lp["wqkv"]), conv_rows, lp["conv"], n_valid)
+    qkv, window, put_at = _short_conv(h, lp, cfg, conv, idx, slot, valid, n_lanes)
     qkv = jax.nn.silu(qkv)
     q = _l2norm(qkv[..., :ck].reshape(b, t, nh, dk)) * dk**-0.5
     k = _l2norm(qkv[..., ck : 2 * ck].reshape(b, t, nh, dk))
@@ -619,12 +689,14 @@ def gdn_mixer(h, lp, cfg: ModelConfig, state, conv, idx, slot, valid, plan: Hybr
     )  # [B, T, H]
     beta = jax.nn.sigmoid(_proj(h, lp["w_beta"])) * (2.0 if cfg.delta_neg_eigval else 1.0)
     g, beta = kda_ops.mask_inputs(g[..., None], beta, valid)
-    if t == 1 and plan.gdn_decode == "pallas_gdn_decode" and slot is None:
-        from ..ops.pallas_kda import gdn_decode
 
-        o, state = gdn_decode(q[:, 0], k[:, 0], v[:, 0], g[:, 0, :, 0], beta[:, 0], state, idx)
-        o = o[:, None]
-    else:
+    def rule(q, k, v, g, beta, slot, state):
+        b, t = beta.shape[:2]
+        if t == 1 and plan.gdn_decode == "pallas_gdn_decode" and slot is None:
+            from ..ops.pallas_kda import gdn_decode
+
+            o, state = gdn_decode(q[:, 0], k[:, 0], v[:, 0], g[:, 0, :, 0], beta[:, 0], state, idx)
+            return o[:, None], state
         # the stored tile [dk, H·dv] viewed a head at a time for the jnp forms
         rows = jnp.swapaxes(_rows(state, idx, slot, b).reshape(b, dk, nh, dv), 1, 2)
         if t == 1:
@@ -632,8 +704,10 @@ def gdn_mixer(h, lp, cfg: ModelConfig, state, conv, idx, slot, valid, plan: Hybr
             o = o[:, None]
         else:
             o, rows = kda_ops.kda_chunked(q, k, v, g, beta, rows)
-        state = _put_rows(state, jnp.swapaxes(rows, 1, 2).reshape(b, dk, nh * dv), idx, slot)
-    conv = _put_rows(conv, new_conv.reshape(b, -1), idx, slot)
+        return o, _put_rows(state, jnp.swapaxes(rows, 1, 2).reshape(b, dk, nh * dv), idx, slot)
+
+    o, state = _stack_by_group(rule, n_lanes, slot, state, q, k, v, g, beta)
+    conv = _put_rows(conv, window.reshape(window.shape[0], -1), idx, put_at)
     gate = jax.nn.silu(_proj(h, lp["w_g"])).reshape(b, t, nh, dv)
     o = rms_norm(o, lp["o_norm"], cfg.norm_eps) * gate
     return _proj(o.reshape(b, t, nh * dv).astype(h.dtype), lp["wo"]), state, conv
@@ -807,8 +881,10 @@ def forward(
     everything that reads weights; a lane that does not step writes its row
     where it stands (the arena's last row, where it is parked), attends to
     one row and is routed to no expert. The logits are ``[1 + B, V]``: the
-    chunk's row ``last``, then the lanes'. Only where the model has no linear
-    mixer: a per-lane state and conv must not see the other group's rows."""
+    chunk's row ``last``, then the lanes'. The chunk's own lane is one of the
+    B: the engine parks it at the arena's last row while it prefills, at or
+    past its ``stop``, so its step is not valid and a linear mixer's state and
+    conv of that lane are the chunk's alone."""
     from .llama import _moe_mlp, _moe_mlp_sorted, moe_sorts
 
     b, t = tokens.shape
@@ -828,11 +904,6 @@ def forward(
 
     n_lanes = 0
     if lanes is not None:
-        if cfg.linear_kind is not None:
-            raise ValueError(
-                f"lanes beside a chunk need a block with no linear mixer: a {cfg.linear_kind} layer's "
-                "state and conv must not see the other group's rows"
-            )
         if not keep_cache or slot is None or b != 1:
             raise ValueError("lanes ride one lane's chunk at ``slot`` of the cache")
         lane_tokens, lane_positions = lanes
@@ -879,7 +950,7 @@ def forward(
 
         def linear(state, conv):
             fn = kda_mixer if lin_kind == "kda" else gdn_mixer
-            return fn(h, _layer_of(params[lin_kind], idx), cfg, state, conv, idx, slot, valid, plan)
+            return fn(h, _layer_of(params[lin_kind], idx), cfg, state, conv, idx, slot, valid, plan, n_lanes)
 
         def positional(rows):
             fn = mla_mixer if pos_kind == "mla" else full_mixer

@@ -675,8 +675,8 @@ def forward(
     that reads weights, so a launch streams them once for both; each group's
     attention reads its own arena rows. The head runs on ``1 + B`` rows and
     the logits returned are ``[1 + B, V]``: the chunk's row ``last``, then
-    the lanes' (the dense arena, or the hybrid block's cache where the model
-    has no linear mixer; no page pool).
+    the lanes' (the dense arena, or the hybrid block's cache whatever its
+    layers' kinds; no page pool).
     Without: pure causal self-attention over the tokens given (what tests
     compare the cached path with).
     A config with ``window_layers`` / ``rope_layers`` hands each layer its
@@ -694,7 +694,7 @@ def forward(
 
         if block_table is not None:
             raise ValueError("the hybrid block has no paged cache")
-        # ``lanes``: only where the block has no linear mixer (hybrid.forward says)
+        # ``lanes``: for every ``layer_kinds`` (hybrid.forward's docstring says how)
         return hybrid.forward(
             params, cfg, tokens, positions, cache,
             plan=cache_attn_impl, moe_impl=moe_impl, slot=slot, valid=valid, lanes=lanes, last=last,

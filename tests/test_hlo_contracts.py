@@ -434,8 +434,8 @@ def test_a_mixed_step_over_the_cut_sorts_the_lanes_rows_with_the_chunks(moe_engi
 
 
 # ---------------------------------------------------------------------------
-# the hybrid block's mixed step where it has no linear mixer (ISSUE 41), and
-# every program of the hybrid engines that is what the parent lowered
+# the hybrid block's mixed step, without a linear mixer (ISSUE 41) and with
+# one (ISSUE 48), and every program without lanes that is what the parent lowered
 
 
 HYBRID_OPTIONS = {
@@ -468,10 +468,12 @@ def test_hybrid_steps_without_lanes_lower_to_the_parents_programs(hybrid_engine,
     engines (KDA + MLA, GDN + full attention, MLA alone), float and int8,
     lower to the StableHLO the parent commit (e076b59, PR 40) lowered on this
     backend, byte for byte (sha256 of the text, taken there with these
-    lowering calls): ``hybrid.forward`` took ``lanes`` and the mixers a second
-    group of rows, and a call without them traces what it traced. On the chip
-    an edit above a Pallas call still re-compiles every program that holds
-    one (PERF.md section 7): that is a first start's cost, not this one's."""
+    lowering calls): ``hybrid.forward`` took ``lanes`` (PR 41) and the linear
+    mixers a second group of rows (PR 48: ``_short_conv`` keeps a call
+    without them in the order it had, the window put back after the state's
+    update), and a call without them traces what it traced. On the chip an
+    edit above a Pallas call still re-compiles every program that holds one
+    (PERF.md section 7): that is a first start's cost, not this one's."""
     model, weights, program = key.split(".", 2)
     text = _moe_step_lowering(hybrid_engine(model, weights), program).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == HYBRID_PARENT_PROGRAMS[key]
@@ -525,12 +527,32 @@ def test_hybrid_mixed_contract_refuses_two_forwards_and_a_head_on_every_row(hybr
 
 
 @pytest.mark.parametrize("model", ["tiny-kimi-linear", "tiny-olmo-hybrid"])
-def test_hybrid_engines_with_a_linear_mixer_build_no_mixed_program(hybrid_engine, model):
-    """The choice is the configuration's layer kinds: a KDA or GDN layer among
-    them keeps a per-lane state, and the engine keeps its two launches."""
+def test_hybrid_mixed_step_with_a_linear_mixer_steps_each_stack_a_group_at_a_time(hybrid_engine, model):
+    """``jit_prefill_with_decode`` where a KDA or a GDN layer keeps a per-lane
+    state (ISSUE 48): every stack (latent or K and V, state, conv) rides in
+    the layer loop's carry and in the 0-or-1-trip loop round its own mixer and
+    in no third loop (the weights are read once), a positional stack takes the
+    chunk's rows and the lanes', the state the chunk's ONE lane and the
+    step's B, the conv the B windows in one write (the chunk's new window
+    among them), the head runs on ``1 + B`` rows, and the donated cache
+    and carry alias the outputs. The engine's gate reads nothing of the
+    mixer kinds: these engines are built as ``tiny-mistral4``'s is."""
     eng = hybrid_engine(model, "float")
-    assert eng.cfg.linear_kind is not None and eng.cache.state is not None
-    assert eng._prefill_with_decode is None and "_prefill_with_decode" not in engine_jit_fns(eng)
+    B, t, c = eng.max_batch, 128, eng.cache
+    assert eng.cfg.linear_kind is not None and c.state is not None
+    assert "_prefill_with_decode" in engine_jit_fns(eng)
+    lowered = _mixed_lowering(eng, t)
+    text = lowered.as_text()
+    assert "module @jit_prefill_with_decode" in text
+    rows = {n: a.shape for n, a in c.leaves().items() if n in c.POSITIONAL}
+    contract = MixedStepOverStacks(
+        rows, chunk=t, lanes=B, vocab=eng.cfg.vocab_size,
+        per_lane={"state": c.state.shape, "conv": c.conv.shape}, loops=2, joined=("conv",),
+    )
+    check(text, contract)
+    check(lowered.compile().as_text(), DonationAliased(min_count=4 + len(c.leaves())))
+    chunk_alone = _moe_step_lowering(eng, f"jit_prefill.{t}").as_text()
+    assert any("want the chunk's lane and the step's lanes" in p for p in contract.failures(chunk_alone))
 
 
 # ---------------------------------------------------------------------------
@@ -600,19 +622,22 @@ def compile_stats():
     return enable_compile_cache()
 
 
-@pytest.fixture(scope="module", params=["dense", "paged", "fused", "meshed", "latent"])
+@pytest.fixture(scope="module", params=["dense", "paged", "fused", "meshed", "latent", "recurrent"])
 def warmed(request):
     """``latent``: the hybrid block with no linear mixer (``tiny-mistral4``),
-    whose warm-up compiles the mixed step's rungs like the dense engine's."""
+    whose warm-up compiles the mixed step's rungs like the dense engine's;
+    ``recurrent``: with one (``tiny-olmo-hybrid``: a state and a conv window a
+    lane, ISSUE 48), likewise."""
     extra = {
         "dense": {},
         "paged": {"paged_kv": True},
         "fused": {"paged_kv": True, "fused_decode": True},
         "meshed": {"tp": 2},
         "latent": {},
+        "recurrent": {},
     }[request.param]
     eng = LLMEngine.create(
-        "tiny-mistral4" if request.param == "latent" else "tiny",
+        {"latent": "tiny-mistral4", "recurrent": "tiny-olmo-hybrid"}.get(request.param, "tiny"),
         options={"max_batch": 4, "max_seq": 256, "decode_chunk": 8, "prefill_chunk": 32, **extra},
     )
     yield eng
@@ -657,8 +682,8 @@ def test_serving_window_with_mixed_launches_lowers_nothing(warmed, compile_stats
     chunk pending beside a decoding lane: it runs the mixed step
     (``jit_prefill_with_decode``, ISSUE 31) explicitly, once per bucket a
     chunk can take. Multi-chunk prompts beside a steady generation then ride
-    on the dense engine, and on the hybrid block's where it keeps no per-lane
-    state (ISSUE 41), without a lowering; the page pool, the fused loop and
+    on the dense engine and on the hybrid block's, with a per-lane state
+    (ISSUE 48) or without one (ISSUE 41), without a lowering; the page pool, the fused loop and
     the mesh have no such program, launch none, and lower nothing either."""
 
     async def contended():
@@ -672,7 +697,8 @@ def test_serving_window_with_mixed_launches_lowers_nothing(warmed, compile_stats
 
     rides = warmed._prefill_with_decode is not None
     assert rides is not (warmed.paged or warmed.fused_decode or warmed.mesh is not None)
-    buckets = [b for b in PREFILL_BUCKETS if b <= warmed.prefill_chunk]  # one program each
+    buckets = warmed._mixed_buckets  # one program each: the two largest prefill buckets a chunk can take
+    assert (list(buckets) == [b for b in PREFILL_BUCKETS if b <= warmed.prefill_chunk][-2:]) is rides
     if rides:
         assert warmed._prefill_with_decode._cache_size() == len(buckets) >= 1
     before, launched = compile_stats.as_dict(), warmed.mixed_launches

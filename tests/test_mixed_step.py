@@ -5,10 +5,12 @@ lanes' step beside it.
 rows together through every layer (``models/llama.forward``'s ``lanes``), so
 a tick that used to launch ``jit_prefill`` and then the one-step
 ``jit_decode_n`` streams the weights once. Checked here on the CPU, on a
-dense, a Mixtral-shaped and an OLMoE-shaped tiny model and (ISSUE 41) on the
+dense, a Mixtral-shaped and an OLMoE-shaped tiny model, (ISSUE 41) on the
 hybrid block where it has no linear mixer (``tiny-mistral4``: latent rows and
-the two per-lane controls): the program against the two it stands in for, the
-scheduler's rule (``_riders``) and the engines that have no such program, the
+the two per-lane controls) and (ISSUE 48) where it has one (``tiny-kimi-linear``:
+KDA beside MLA; ``tiny-olmo-hybrid``: GDN beside full attention; a float32
+state and a conv window a lane, which the other group's rows must not reach):
+the program against the two it stands in for, the scheduler's rule (``_riders``) and the engines that have no such program, the
 counters, the two failpoints, and a session whose turns rode mixed launches
 resumed in a new engine.
 """
@@ -24,8 +26,10 @@ from agentainer_tpu import faults
 from agentainer_tpu.engine.llm import GenRequest, LLMEngine
 from agentainer_tpu.models.llama import moe_sorts
 
-# dense, Mixtral-shaped, OLMoE-shaped; the hybrid block with latent attention in every layer
-FAMILIES = ["tiny", "tiny-moe", "tiny-olmoe", "tiny-mistral4"]
+# dense, Mixtral-shaped, OLMoE-shaped; the hybrid block with latent attention
+# in every layer, with KDA beside it, and with GDN beside full attention
+LINEAR = ["tiny-kimi-linear", "tiny-olmo-hybrid"]
+FAMILIES = ["tiny", "tiny-moe", "tiny-olmoe", "tiny-mistral4", *LINEAR]
 OPTS = {"max_batch": 4, "max_seq": 256, "decode_chunk": 8, "prefill_chunk": 32,
         "speculative": False, "skip_warmup": True}
 LONG = "a document of many words that takes several prefill chunks to read "  # 68 bytes
@@ -43,6 +47,12 @@ def _rows_of(cache) -> list:
     """The positional leaves of either block's cache: ``k`` and ``v``, or
     what the hybrid block keeps (``latent`` alone for ``tiny-mistral4``)."""
     return list(cache.rows()) if hasattr(cache, "rows") else [cache.k, cache.v]
+
+
+def _lane_leaves_of(cache) -> list:
+    """The per-lane leaves: the hybrid block's ``state`` and ``conv`` where
+    it has a linear mixer, none anywhere else."""
+    return [a for a in (getattr(cache, "state", None), getattr(cache, "conv", None)) if a is not None]
 
 
 @pytest.mark.parametrize("weights", ["float", "int8"])
@@ -63,7 +73,10 @@ def test_a_mixed_launch_is_the_chunk_then_the_one_step_decode(model, weights):
     nobody reads either. On the K/V block that is the lane parked at the
     arena's last row. The hybrid block reads it from the cache's controls as
     its ``T = 1`` step does: the chunk's own lane (open, but parked past its
-    ``stop``), and a lane fed its EOS, which closes (``stop = 0``) in both."""
+    ``stop``), and a lane fed its EOS, which closes (``stop = 0``) in both.
+    Where the block has a linear mixer, every lane's state and conv window are
+    compared too, the lanes that do not step included: nothing of them moves
+    in either."""
     quant = {"quant": "int8"} if weights == "int8" else {}
     eng = LLMEngine.create(model, options={**OPTS, "prefill_chunk": 128, **quant})
     try:
@@ -116,6 +129,12 @@ def test_a_mixed_launch_is_the_chunk_then_the_one_step_decode(model, weights):
         for got, want in zip(_rows_of(cache_m), _rows_of(cache_a)):
             np.testing.assert_allclose(rows(got), rows(want), atol=atol, rtol=0)
             assert np.isfinite(np.asarray(got)).all()
+        assert len(_lane_leaves_of(cache_m)) == (2 if model in LINEAR else 0)
+        for got, want, before in zip(_lane_leaves_of(cache_m), _lane_leaves_of(cache_a), _lane_leaves_of(cache)):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=atol, rtol=0)
+            for idx in stepping + [lane]:  # the lanes that step and the chunk's own moved
+                assert not np.array_equal(np.asarray(got[:, idx]), np.asarray(before[:, idx]))
+            assert np.array_equal(np.asarray(got[:, 1]), np.asarray(before[:, 1]))  # the lane fed its EOS did not
         assert np.asarray(toks_m)[:, stepping].tolist() == np.asarray(toks_a)[:, stepping].tolist()
         assert np.asarray(tok_m)[stepping].tolist() == np.asarray(tok_a)[stepping].tolist()
         assert np.asarray(pos_m).tolist() == np.asarray(pos_a).tolist()
@@ -139,7 +158,8 @@ def _hybrid_case(model: str):
     three contexts in it and every lane admitted) for the forward-level
     checks: ``tiny-mistral4``, ``full-only`` (``tiny-olmo-hybrid``'s softmax
     attention over K/V rows in every layer: the hybrid block's other
-    positional kind, no linear mixer either) or a model that has one."""
+    positional kind, no linear mixer either) or a model that has one
+    (``LINEAR``)."""
     import dataclasses
 
     from agentainer_tpu.models import hybrid
@@ -162,13 +182,14 @@ def _hybrid_case(model: str):
     return cfg, params, cache, plan, draw
 
 
-@pytest.mark.parametrize("model", ["tiny-mistral4", "full-only"])
+@pytest.mark.parametrize("model", ["tiny-mistral4", "full-only", *LINEAR])
 def test_the_hybrid_forward_with_lanes_is_its_two_calls(model):
     """``hybrid.forward(lanes=...)`` against the chunk's call and then the
-    ``T = 1`` call, for both positional kinds: the head's ``1 + B`` rows, every
-    positional leaf (but the row of the lane that does not step) and ``stop``.
-    Lane 3 stands at its ``stop``: closed, it writes the row where it stands
-    and nothing else."""
+    ``T = 1`` call, for both positional kinds alone and each beside its linear
+    kind: the head's ``1 + B`` rows, every positional leaf (but the row of the
+    lane that does not step), the state and conv where there are any, and
+    ``stop``. Lane 3 stands at its ``stop``: closed, it writes the row where it
+    stands and nothing else."""
     from agentainer_tpu.models import hybrid
 
     cfg, params, cache, plan, draw = _hybrid_case(model)
@@ -191,22 +212,133 @@ def test_the_hybrid_forward_with_lanes_is_its_two_calls(model):
         changed = np.argwhere(np.abs(np.asarray(got) - np.asarray(before)).reshape(*got.shape[:3], -1).max(-1) > 0)
         assert {(int(b), int(p)) for _, b, p in changed if b != slot} == {(0, 12), (1, 5), (3, 20)}
         assert {int(p) for _, b, p in changed if b == slot} == set(range(7, 7 + T)) | {63}
+    for got, want, before in zip(_lane_leaves_of(one), _lane_leaves_of(two), _lane_leaves_of(cache)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5, rtol=0)
+        moved = [bool((np.asarray(got[:, i]) != np.asarray(before[:, i])).any()) for i in range(4)]
+        assert moved == [True, True, True, False]  # lanes 0 and 1 stepped, lane 2 took the chunk, lane 3 is closed
 
 
-def test_lanes_beside_a_chunk_are_refused_where_a_layer_is_a_linear_mixer():
-    """A state and a conv that must not see the other group's rows: that body
-    is not written, and ``forward`` says so instead of corrupting a lane."""
+# ---------------------------------------------------------------------------
+# a linear mixer's per-lane leaves: neither group's rows reach the other's
+
+
+def _mixer_case(model: str, T: int = 16, slot: int = 2):
+    """One linear layer of ``model`` taken alone, so that both sides of a
+    comparison read the same normed rows and a difference can only be the
+    mixer's: ``(mixer(h, state, conv, slot, valid, n_lanes) -> (o, state,
+    conv), h [1, T + 4, d], the chunk's valid [1, T], a cache with three
+    contexts in its state and conv)``."""
     from agentainer_tpu.models import hybrid
-    from agentainer_tpu.models.llama import forward
 
-    for model in ("tiny-kimi-linear", "tiny-olmo-hybrid"):
-        cfg, params, cache, plan, draw = _hybrid_case(model)
-        tokens, positions = draw(1, 16), jnp.arange(16, dtype=jnp.int32)[None]
-        lanes = (draw(4, 1), jnp.full((4, 1), 63, jnp.int32))
-        with pytest.raises(ValueError, match="no linear mixer"):
-            hybrid.forward(params, cfg, tokens, positions, cache, plan=plan, slot=2, lanes=lanes, last=3)
-        with pytest.raises(ValueError, match="no linear mixer"):  # the door the engine comes through
-            forward(params, cfg, tokens, positions, cache, cache_attn_impl=plan, slot=2, lanes=lanes, last=3)
+    cfg, params, cache, plan, _ = _hybrid_case(model)
+    fn = hybrid.kda_mixer if cfg.linear_kind == "kda" else hybrid.gdn_mixer
+    lp = hybrid._layer_of(params[cfg.linear_kind], 1)
+
+    def mixer(h, state, conv, slot, valid, n_lanes=0):
+        return fn(h, lp, cfg, state, conv, 1, slot, valid, plan, n_lanes)
+
+    h = jax.random.normal(jax.random.PRNGKey(5), (1, T + 4, cfg.dim), jnp.float32)
+    return mixer, h, jnp.arange(T)[None] < T - 5, cache
+
+
+def _lanes_first(fn, n_lanes, slot, stack, *arrays):
+    """``hybrid._stack_by_group`` with the two groups in the other order."""
+    from agentainer_tpu.models import hybrid
+
+    if not n_lanes:
+        return fn(*arrays, slot, stack)
+    chunk, lanes = zip(*hybrid._groups(n_lanes, *arrays))
+    o_l, stack = fn(*lanes, None, stack)
+    o_c, stack = fn(*chunk, slot, stack)
+    return jnp.concatenate([o_c[0], o_l[:, 0]], axis=0)[None], stack
+
+
+@pytest.mark.parametrize("order", ["chunk_first", "lanes_first"])
+@pytest.mark.parametrize("model", LINEAR)
+def test_the_chunks_own_lane_is_not_stepped(model, order, monkeypatch):
+    """The chunk's own lane is one of the B lanes of the step and its row is
+    not valid (the engine parks it at or past its ``stop``): after a mixed
+    launch its state and conv window are BITWISE the chunk-alone launch's, and
+    every other lane's the step-alone launch's, whichever group the delta
+    rule runs first (the step of a lane that is not valid writes back what it
+    read; the conv's B windows go back in one write, the chunk's among them). At
+    the forward, where ``valid`` comes from the cache's controls, the token
+    the carry happens to hold for that lane changes nothing of it."""
+    from agentainer_tpu.models import hybrid
+
+    if order == "lanes_first":
+        monkeypatch.setattr(hybrid, "_stack_by_group", _lanes_first)
+    T, slot = 16, 2
+    mixer, h, valid_c, cache = _mixer_case(model, T, slot)
+    valid_l = jnp.asarray([[True, True, False, True]])
+    _, s_chunk, c_chunk = mixer(h[:, :T], cache.state, cache.conv, slot, valid_c)
+    _, s_step, c_step = mixer(h[0, T:, None], cache.state, cache.conv, None, valid_l.T)
+    _, s_mixed, c_mixed = mixer(h, cache.state, cache.conv, slot, jnp.concatenate([valid_c, valid_l], axis=1), 4)
+    for mixed, chunk, step, before in ((s_mixed, s_chunk, s_step, cache.state), (c_mixed, c_chunk, c_step, cache.conv)):
+        assert np.array_equal(np.asarray(mixed[:, slot]), np.asarray(chunk[:, slot]))
+        assert not np.array_equal(np.asarray(mixed[1, slot]), np.asarray(before[1, slot]))
+        for lane in (0, 1, 3):
+            assert np.array_equal(np.asarray(mixed[:, lane]), np.asarray(step[:, lane]))
+            assert not np.array_equal(np.asarray(mixed[1, lane]), np.asarray(before[1, lane]))
+
+    cfg, params, cache, plan, draw = _hybrid_case(model)
+    tokens, positions = draw(1, T), (7 + jnp.arange(T, dtype=jnp.int32))[None]
+    lane_pos = jnp.asarray([12, 5, 63, 20], jnp.int32)[:, None]  # lane 2 parked past its stop of 60
+    kw = {"plan": plan, "slot": slot, "valid": valid_c, "last": T - 6}
+    lane_tok = draw(4, 1)
+    _, one = hybrid.forward(params, cfg, tokens, positions, cache, lanes=(lane_tok, lane_pos), **kw)
+    _, other = hybrid.forward(params, cfg, tokens, positions, cache, lanes=(lane_tok.at[slot].add(1), lane_pos), **kw)
+    for a, b in zip(_lane_leaves_of(one), _lane_leaves_of(other)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("model", LINEAR)
+def test_a_lanes_conv_window_never_slides_over_the_chunks_rows(model):
+    """Two mixed launches whose chunks differ (their last rows too: what a
+    window slid over ``[1, T + B]`` rows as one sequence would hold) and whose
+    lanes' rows are the same: every stepping lane's output, state and conv
+    window are bitwise the same in both, and the one-step launch's."""
+    T, slot = 16, 2
+    mixer, h, valid_c, cache = _mixer_case(model, T, slot)
+    valid_l = jnp.asarray([[True, True, False, True]])
+    valid = jnp.concatenate([valid_c | True, valid_l], axis=1)  # every row of the chunk real, its last ones too
+    other = h.at[:, :T].multiply(-1.5)
+    o_step, s_step, c_step = mixer(h[0, T:, None], cache.state, cache.conv, None, valid_l.T)
+    o_a, s_a, c_a = mixer(h, cache.state, cache.conv, slot, valid, 4)
+    o_b, s_b, c_b = mixer(other, cache.state, cache.conv, slot, valid, 4)
+    assert not np.array_equal(np.asarray(c_a[1, slot]), np.asarray(c_b[1, slot]))  # the chunk's own window differs
+    for lane in (0, 1, 3):
+        for a, b, step in ((s_a, s_b, s_step), (c_a, c_b, c_step)):
+            assert np.array_equal(np.asarray(a[:, lane]), np.asarray(b[:, lane]))
+            assert np.array_equal(np.asarray(a[:, lane]), np.asarray(step[:, lane]))
+        assert np.array_equal(np.asarray(o_a[0, T + lane]), np.asarray(o_b[0, T + lane]))
+        assert np.array_equal(np.asarray(o_a[0, T + lane]), np.asarray(o_step[lane, 0]))
+
+
+@pytest.mark.parametrize("model", LINEAR)
+def test_a_closed_lane_riding_a_chunk_keeps_its_state(model):
+    """Lane 1 is closed (``stop = 0``: its request ended, its session's state
+    is what the next turn resumes from) and lane 0 is fed the token that
+    closes it: a mixed launch leaves the state and conv window of both bitwise
+    as they were and closes lane 0, as the one-step launch does; lane 3 steps."""
+    from agentainer_tpu.models import hybrid
+
+    cfg, params, cache, plan, draw = _hybrid_case(model)
+    T, slot = 16, 2
+    lane_tok, lane_pos = draw(4, 1), jnp.asarray([12, 5, 63, 20], jnp.int32)[:, None]
+    cache = hybrid.admit_lane(cache, 0, False, 60, int(lane_tok[0, 0]))
+    cache = cache._replace(stop=cache.stop.at[1].set(0))
+    tokens, positions = draw(1, T), (7 + jnp.arange(T, dtype=jnp.int32))[None]
+    _, after = hybrid.forward(
+        params, cfg, tokens, positions, cache, plan=plan, slot=slot, valid=jnp.arange(T)[None] < 11,
+        lanes=(lane_tok, lane_pos), last=10,
+    )
+    assert np.asarray(after.stop).tolist() == [0, 0, 60, 60]
+    for got, before in zip(_lane_leaves_of(after), _lane_leaves_of(cache)):
+        for lane in (0, 1):
+            assert np.array_equal(np.asarray(got[:, lane]), np.asarray(before[:, lane]))
+        for lane in (slot, 3):
+            assert not np.array_equal(np.asarray(got[:, lane]), np.asarray(before[:, lane]))
 
 
 # ---------------------------------------------------------------------------
@@ -362,9 +494,47 @@ def test_lanes_whose_budget_is_all_in_flight_do_not_ride(stopped):
     assert [s.idx for s, _, _ in eng._riders()] == [0, 2]  # one launch steps every live lane, as decode_n does
 
 
+LADDER = {
+    # name: (model, options, the mixed step's rungs)
+    "the_two_largest_of_a_256_row_chunk": ("tiny", {"quant": "int8", "prefill_chunk": 256}, (128, 256)),
+    "whatever_the_weights_are_stored_as": ("tiny", {"prefill_chunk": 256}, (128, 256)),
+    "a_chunk_of_the_smallest_bucket_has_one": ("tiny", {"quant": "int8", "prefill_chunk": 32}, (32,)),
+    "a_linear_mixer_changes_nothing_of_it": ("tiny-olmo-hybrid", {"quant": "int8", "prefill_chunk": 256}, (128, 256)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LADDER))
+def test_the_mixed_ladder_is_the_two_largest_buckets_a_chunk_can_take(case, monkeypatch):
+    """A trade for the boot, stated as one: each rung of the mixed step is a
+    program traced, lowered and read back at every start, so it has the two
+    largest prefill buckets a chunk can take and no smaller rung. A ridden
+    chunk of 20 tokens takes the first of them, a plain one its own bucket,
+    and warm-up builds exactly those rungs."""
+    model, extra, want = LADDER[case]
+    eng = LLMEngine.create(model, options={**OPTS, **extra, "max_seq": 512})
+    try:
+        eng.shutdown()
+        assert eng._mixed_buckets == want
+        for s in eng.slots:
+            eng._reset_slot(s)
+        riders = [(s, s.request, s.dev_position) for s in (_decoding(eng, 0),)]
+        slot, seen = _prefilling(eng, 1, 20 + 2 * eng.prefill_chunk), []
+        logits, toks = jnp.zeros((eng.cfg.vocab_size,), jnp.float32), jnp.zeros((1, eng.max_batch), jnp.int32)
+        monkeypatch.setattr(eng, "_launch_with_decode", lambda idx, tokens, pos, n: seen.append((tokens.shape, n)) or (logits, toks))
+        monkeypatch.setattr(eng, "_prefill", lambda params, cache, idx, tokens, pos, n: seen.append((tokens.shape, int(n))) or (logits, cache))
+        eng._prefill_chunk(slot, riders)  # a whole chunk, ridden
+        eng._prefill_chunk(slot)  # a whole chunk alone
+        eng._prefill_chunk(slot, riders)  # the 20 tokens left, ridden
+        chunk = eng.prefill_chunk
+        assert seen == [((1, chunk), chunk), ((1, chunk), chunk), ((1, want[0]), 20)]
+        slot.pending_prompt = [5] * 20
+        eng._prefill_chunk(slot)  # and alone: the plain ladder's own bucket
+        assert seen[-1] == ((1, 32), 20)
+    finally:
+        eng.shutdown()
+
+
 NO_PROGRAM = {
-    "hybrid": ("tiny-kimi-linear", {}),  # KDA beside MLA
-    "hybrid_gdn": ("tiny-olmo-hybrid", {}),  # GDN beside full attention
     "paged": ("tiny", {"paged_kv": True}),
     "fused": ("tiny", {"paged_kv": True, "fused_decode": True}),
     "meshed": ("tiny", {"tp": 2}),
@@ -376,13 +546,11 @@ NO_PROGRAM = {
 @pytest.mark.parametrize("kind", sorted(NO_PROGRAM))
 def test_engines_without_the_program_keep_two_launches(kind):
     """Decided at build by what the engine is, never by a model's name: the
-    hybrid block with a linear mixer among its layers (a per-lane state), the
     page pool, the fused loop, a mesh, the ``routed`` dispatch and a ladder
     without the one-step rung have no mixed program; under the traffic that
     makes the dense engine ride they launch none."""
     model, extra = NO_PROGRAM[kind]
-    options = {k: v for k, v in OPTS.items() if not (kind.startswith("hybrid") and k == "speculative")}
-    eng = LLMEngine.create(model, options={**options, **extra})
+    eng = LLMEngine.create(model, options={**OPTS, **extra})
     try:
         assert eng._prefill_with_decode is None and eng._riders() is None
         replies = asyncio.run(_traffic(eng, n_long=2, reply=6))

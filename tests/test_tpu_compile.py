@@ -340,9 +340,11 @@ def test_decode_step_with_the_clamped_map_copies_no_arena_on_v5e(v5e, monkeypatc
 def _hybrid_case(model: str, where):
     """``kimi-5l`` (Kimi-Linear's dense layer and one period, K K K M K, 8
     lanes of 1024) or ``olmo-hybrid-4l`` (Olmo-Hybrid's one period, L L L F, 8
-    lanes of 4096), at published widths, int8 as served, the kernels on: the
-    configuration, the abstract parameters and cache placed on ``where``, the
-    plan, and the decode and prefill steps as the engine jits them."""
+    lanes of 4096), the served shares whole (``mistral4-9l``, ``kimi-27l``,
+    ``olmo-hybrid-32l``: their cells' layers, vocabulary and lanes), at
+    published widths, int8 as served, the kernels on: the configuration, the
+    abstract parameters and cache placed on ``where``, the plan, and the
+    decode, prefill and mixed steps as the engine jits them."""
     import dataclasses
 
     from jax import lax
@@ -358,6 +360,15 @@ def _hybrid_case(model: str, where):
             get_config("kimi-linear-48b"), n_layers=5, layer_kinds=kimi_linear_kinds(27)[:5],
             experts_held=32, vocab_size=8192, name=model,
         )
+    elif model == "kimi-27l":
+        # the served share whole: 27 layers, 32 of 256 experts, the whole
+        # vocabulary, 64 lanes of 4,096 (benchmark/configs/kimi-linear-48b-ep8-1chip.json)
+        lanes, seq = 64, 4096
+        cfg = dataclasses.replace(get_config("kimi-linear-48b"), experts_held=32, name=model)
+    elif model == "olmo-hybrid-32l":
+        # the served model whole: 8 lanes of 4,096 (benchmark/configs/olmo-hybrid-7b-1chip.json)
+        lanes, seq = 8, 4096
+        cfg = dataclasses.replace(get_config("olmo-hybrid-7b"), name=model)
     elif model == "mistral4-9l":
         # the served share whole: 9 layers, 32 of 128 experts, the whole
         # vocabulary, 16 lanes of 16,384 (benchmark/configs/mistral-small-4-119b-ep4-1chip.json)
@@ -406,16 +417,47 @@ def _hybrid_case(model: str, where):
         "decode": (jax.jit(decode_n, donate_argnums=(1, 2, 3)), (params, cache, i32(lanes), i32(lanes))),
         "prefill": (jax.jit(prefill, donate_argnums=(1,)), (params, cache, i32(), i32(1, 256), i32(1, 256), i32())),
     }
-    if cfg.linear_kind is None:  # the one launch for both, where no layer keeps a per-lane state
-        steps["mixed"] = (
-            jax.jit(mixed, donate_argnums=(1, 6, 7)),
-            (params, cache, i32(), i32(1, 256), i32(1, 256), i32(), i32(lanes), i32(lanes)),
-        )
+    steps["mixed"] = (
+        jax.jit(mixed, donate_argnums=(1, 6, 7)),
+        (params, cache, i32(), i32(1, 256), i32(1, 256), i32(), i32(lanes), i32(lanes)),
+    )
     return cfg, cache, plan, steps
 
 
-@pytest.mark.parametrize("step", ["decode", "prefill"])
-def test_hybrid_step_holds_no_stack_sized_temporary_on_v5e(v5e, step):
+V5E_USABLE_BYTES = 15.75e9
+
+
+def _check_mixed_with_a_linear_mixer(compiled, cfg, cache, lanes: int, kernels: list) -> None:
+    """What the served-size mixed step of a block WITH a linear mixer (ISSUE
+    48) has to show on the chip's compiler: ONE call of each kernel (the state
+    kernel over the lanes, the decode attention beside the prefill attention,
+    one grouped FFN where the model has experts), every stack carried by the
+    layer loop and the 0-or-1-trip loop round its own mixer and by no third
+    loop (a second pass over the layers reads the weights again), every leaf
+    donated in place with no copy, relayout or padding of a whole stack, the
+    head on ``1 + lanes`` rows, and live bytes (arguments + temporaries - what
+    is aliased) that fit a v5e's 15.75 GB."""
+    text = compiled.as_text()
+    calls = re.findall(r'%([a-z_]+)[.\d]* = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert sorted(calls) == sorted(kernels), calls
+    whiles = [ln for ln in text.splitlines() if re.search(r"\bwhile\(", ln)]
+    stacks = cache.leaves()
+    for name, s in stacks.items():
+        shape = ",".join(map(str, s.shape))
+        assert sum(f"[{shape}]" in ln for ln in whiles) == 2, (name, len(whiles))
+        assert not re.search(rf"\[{shape}\][^ ]* (copy|transpose|pad)\(", text), name
+    v = cfg.vocab_size
+    assert not re.search(rf"\[(1,)?(256|{256 + lanes}),{v}\]", text) and re.search(rf"f32\[{1 + lanes},{v}\]", text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(s.size * s.dtype.itemsize for s in stacks.values())
+    live = mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    print(f"{cfg.name} mixed: args {mem.argument_size_in_bytes / 1e9:.2f} GB, temp {mem.temp_size_in_bytes / 1e9:.3f} GB, "
+          f"aliased {mem.alias_size_in_bytes / 1e9:.2f} GB, live {live / 1e9:.2f} GB")
+    assert mem.temp_size_in_bytes < 0.25e9 and 10.0e9 < live < V5E_USABLE_BYTES
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill", "mixed"])
+def test_hybrid_step_holds_no_stack_sized_temporary_on_v5e(v5e, monkeypatch, step):
     """Kimi-Linear's block at published widths (the dense layer and one
     period, K K K M K, 8 lanes of 1024), int8 as served, its kernels on: the
     KDA decode kernel updates the layer of the float32 state stack in place
@@ -428,11 +470,25 @@ def test_hybrid_step_holds_no_stack_sized_temporary_on_v5e(v5e, step):
     copied the stack a branch only passed through, every layer (2.7 GB of
     state in each MLA layer at 64 lanes); a 576-wide latent row made the
     chip keep the arena position-minor and relayout it into and out of every
-    launch; a conv state ``[.., 3, 12288]`` was padded 42-fold."""
-    _, cache, plan, steps = _hybrid_case("kimi-5l", SingleDeviceSharding(v5e.devices[0]))
+    launch; a conv state ``[.., 3, 12288]`` was padded 42-fold. ``mixed``
+    (ISSUE 48): the served share WHOLE (27 layers, 32 of 256 experts, the whole
+    vocabulary, 64 lanes of 4,096 as ``kimi.decode`` serves them), the chunk's
+    256 rows and the 64 lanes' step in one launch: ``kda_decode`` over the
+    lanes beside the chunked rule on the chunk's lane, ``mla_decode`` beside
+    ``mla_prefill``, ONE ``moe_grouped_ffn`` for the 320 rows, the 2.7 GB
+    state stack updated in place, and the head on 65 rows."""
+    if step == "mixed":  # the grouped FFN's kernel is chosen by the backend, which is the CPU here
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    where = SingleDeviceSharding(v5e.devices[0])
+    cfg, cache, plan, steps = _hybrid_case("kimi-27l" if step == "mixed" else "kimi-5l", where)
     assert (plan.kda_decode, plan.mla_decode, plan.mla_prefill) == ("pallas_kda_decode", "pallas_mla_decode", "pallas_mla_prefill")
     fn, args = steps[step]
     compiled = fn.lower(*args).compile()
+    if step == "mixed":
+        assert cache.state.shape == (20, 64, 32, 128, 128) and cache.latent.shape == (7, 64, 4096, 640)
+        return _check_mixed_with_a_linear_mixer(
+            compiled, cfg, cache, 64, ["kda_decode", "mla_decode", "mla_prefill", "moe_grouped_ffn"]
+        )
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "InvertDiagBlocks" not in text  # what a triangular_solve becomes here
     if step == "decode":
@@ -447,9 +503,6 @@ def test_hybrid_step_holds_no_stack_sized_temporary_on_v5e(v5e, step):
     for name, s in stacks.items():  # and no copy or relayout of a whole stack anywhere in the program
         shape = ",".join(map(str, s.shape))
         assert not re.search(rf"\[{shape}\][^ ]* (copy|transpose)\(", text), name
-
-
-V5E_USABLE_BYTES = 15.75e9
 
 
 @pytest.mark.parametrize("step", ["decode", "prefill", "mixed"])
@@ -501,7 +554,7 @@ def test_mistral4_step_fits_the_chip_and_reads_the_latent_stack_in_place_on_v5e(
     assert cfg.param_count() > 8.7e9  # the share, not a cut vocabulary
 
 
-@pytest.mark.parametrize("step", ["decode", "prefill"])
+@pytest.mark.parametrize("step", ["decode", "prefill", "mixed"])
 def test_olmo_hybrid_step_copies_no_stack_and_pads_no_arena_on_v5e(v5e, step):
     """Olmo-Hybrid's block at published widths (one period, L L L F, 8 lanes
     of 4096 as served), int8, its kernels on. The GDN decode kernel updates
@@ -513,11 +566,20 @@ def test_olmo_hybrid_step_copies_no_stack_and_pads_no_arena_on_v5e(v5e, step):
     it whole into the kernels' layout before each call (0.54 GB of temporaries
     in this one-full-layer program, found by this compile). Here no stack is
     copied, transposed or padded, every leaf is donated in place, and the
-    temporaries are a few megabytes."""
-    _, cache, plan, steps = _hybrid_case("olmo-hybrid-4l", SingleDeviceSharding(v5e.devices[0]))
+    temporaries are a few megabytes. ``mixed`` (ISSUE 48): the served model
+    WHOLE (32 layers, the whole vocabulary, 8 lanes of 4,096 as
+    ``olmo-hybrid.sessions`` serves them), the chunk's 256 rows and the 8
+    lanes' step in one launch: ``gdn_decode`` over the lanes beside the
+    chunked rule on the chunk's lane, ``flash_decode`` beside
+    ``flash_prefill``, and the head on 9 rows."""
+    where = SingleDeviceSharding(v5e.devices[0])
+    cfg, cache, plan, steps = _hybrid_case("olmo-hybrid-32l" if step == "mixed" else "olmo-hybrid-4l", where)
     assert (plan.gdn_decode, plan.full_decode) == ("pallas_gdn_decode", "pallas:flash_decode")
     fn, args = steps[step]
     compiled = fn.lower(*args).compile()
+    if step == "mixed":
+        assert cache.state.shape == (24, 8, 96, 5760) and cache.k.shape == (8, 8, 4096, 32, 128)
+        return _check_mixed_with_a_linear_mixer(compiled, cfg, cache, 8, ["flash_decode", "flash_prefill", "gdn_decode"])
     text = compiled.as_text()
     if step == "decode":
         assert "gdn_decode" in text and text.count("tpu_custom_call") == 2  # the state kernel and flash_decode

@@ -1433,6 +1433,15 @@ class LLMEngine:
         self.attention.update(
             decode_block_positions=self._decode_bk, decode_blocks_live=0, decode_blocks_stored=0
         )
+        if k is not None and self.attention["prefill"] == "pallas:flash_prefill":
+            # the plan a full chunk's call compiles to (a function of its shapes
+            # alone): q tile rows, K/V block positions, the MXU's operand dtype
+            from ..ops.pallas_attention import prefill_tile
+
+            stored = k.shape[3]  # the hybrid block stores 32 heads for a model's 30
+            self.attention["prefill_tile"] = prefill_tile(
+                self.prefill_chunk, stored * (cfg.n_heads // cfg.n_kv_heads), stored, k.shape[4], k.shape[2], k.dtype, k.dtype
+            )
         # the same count for a latent leaf (``mla_decode``'s index map: a
         # stepping lane at position p fetches ``p // bk + 1`` blocks of its
         # row, a layer counted once); absent where the cache has no such leaf

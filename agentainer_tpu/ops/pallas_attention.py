@@ -17,10 +17,18 @@ Design notes (why this shape, not a torch translation):
   *is* decode when T == 1. So both kernels take ``q_positions`` and build
   the mask in-register from a 2-D iota — no ``[B, T, S]`` mask tensor ever
   touches HBM.
-- **Online softmax, f32 accumulators, bf16 operands.** Scores and the
-  running (m, l, acc) state live in VMEM scratch that persists across the
+- **Online softmax, f32 state, operands as stored.** Scores and the running
+  (m, l, acc) state are float32 in VMEM scratch that persists across the
   innermost KV-block grid dimension; softmax rescaling follows the standard
-  flash recurrence. MXU matmuls get f32 ``preferred_element_type``.
+  flash recurrence. ``flash_prefill`` hands the MXU q, K, V and the
+  probabilities in the arena's dtype (bfloat16 as served; float32 arrays
+  run float32, which is what the interpret-mode parity tests use) with a
+  float32 ``preferred_element_type``, keeps m and l a row to a sublane
+  (``[rows, 1]``, no move across lanes in the loop) and tiles its rows and
+  keys by a plan of the call's shapes (``prefill_plan``). ``flash_decode``
+  and the page pool's kernels convert to float32 first; at Mosaic's default
+  precision the MXU rounds those operands to bfloat16 in one pass all the
+  same (measured: PERF.md section 5, PR 49).
 - **GQA without materializing repeated K/V.** A K/V block holds a block of
   kv heads of a run of positions; the kernel loops over those heads and the
   G = H/KV query heads of each group run against the same head of the
@@ -72,15 +80,27 @@ def _round_up(x: int, m: int) -> int:
 # last two dims, so that reshape is a relayout of the whole arena.)
 #
 # A block with every head grows with the model's head count where the old
-# per-head blocks did not, so the blocks are SIZED, not fixed: a call plans
-# its VMEM from the 16 MiB a Mosaic kernel gets by default. K and V blocks
-# (double-buffered) get half of it in decode, where they are all the
-# traffic, and a quarter in prefill, whose q, output and accumulator tiles
-# (every query head of the block's KV heads × ``block_q`` rows) get 7 MiB.
+# per-head blocks did not, so the blocks are SIZED, not fixed. K and V blocks
+# (double-buffered) get 8 MiB in decode, where they are all the traffic and the
+# 16 MiB a Mosaic kernel gets by default hold them, and 4 MiB in prefill. A
+# prefill q tile holds every query head of the block's KV heads × ``block_q``
+# rows, and everything that has its rows: q and the output in two buffers
+# each, the float32 accumulator, the softmax's m and l (a float32 a row, each
+# padded to a lane tile: on the row's sublane, so no step moves them across
+# lanes; kept along the lanes they cost a relayout a head and a block, two
+# thirds of the call at GQA-7: PERF.md section 5, PR 49), the positions, and
+# one head's float32 scores with their exponentials. The tile is the largest
+# ``block_q`` whose rows fit ``_PREFILL_Q_VMEM`` (256 rows of 28 / 4 heads: 28
+# MiB; a chunk's K/V blocks are then read once, not once a 128-row tile), and
+# the call asks Mosaic for what the two plans need, no more
+# (``prefill_plan``). The MXU's operands are what the caller stores: q, K, V
+# and the probabilities in the arena's dtype (bfloat16 as served, which is
+# what a float32 matmul at Mosaic's default precision rounds them to anyway;
+# float32 arrays run float32), scores and state in float32.
 
 _DECODE_KV_VMEM = 8 << 20
 _PREFILL_KV_VMEM = 4 << 20
-_PREFILL_Q_VMEM = 7 << 20
+_PREFILL_Q_VMEM = 32 << 20
 
 
 def _kv_block(kv: int, hd: int, dtype, s: int, block_k: int, vmem: int) -> tuple[int, int]:
@@ -92,10 +112,15 @@ def _kv_block(kv: int, hd: int, dtype, s: int, block_k: int, vmem: int) -> tuple
     Positions: ``block_k``, or as many 128s as ``vmem`` holds of K and V
     blocks, two buffers each, the heads padded to whole tiles."""
     heads = 16 if kv % 16 == 0 else kv
-    itemsize = jnp.dtype(dtype).itemsize
-    per_position = 4 * _round_up(heads, 32 // itemsize) * hd * itemsize
-    fit = max(128, vmem // per_position // 128 * 128)
+    fit = max(128, vmem // _kv_position_bytes(heads, hd, dtype) // 128 * 128)
     return heads, min(block_k, _round_up(s, 128), fit)
+
+
+def _kv_position_bytes(heads: int, hd: int, dtype) -> int:
+    """VMEM bytes a position of a K/V block costs: K and V, two buffers each,
+    the heads padded to whole sublane tiles."""
+    itemsize = jnp.dtype(dtype).itemsize
+    return 4 * _round_up(heads, 32 // itemsize) * hd * itemsize
 
 
 def decode_kv_block(kv: int, hd: int, dtype, s: int, block_k: int = 512) -> tuple[int, int]:
@@ -191,7 +216,7 @@ def _prefill_kernel(
     # q_ref [heads, G, bq, hd] (VMEM) heads: this block of KV heads
     # k_ref, v_ref [1, 1, bk, heads, hd] (VMEM)
     # o_ref [heads, G, bq, hd] (VMEM)
-    # m_ref, l_ref [heads, G * bq] f32 scratch; acc_ref [heads, G * bq, hd] f32 scratch
+    # m_ref, l_ref [heads, G * bq, 1] f32 scratch; acc_ref [heads, G * bq, hd] f32 scratch
     block_k: int,
     seq_len_k: int,
     scale: float,
@@ -218,9 +243,6 @@ def _prefill_kernel(
         last = last_ref[pl.program_id(0)]
         blk, live = ring_block_index(ik, nk, lo_ref[pl.program_id(0)], last, block_k)
         held = ring_positions(blk, last, block_k, seq_len_k)  # [1, bk]
-        # (``held + window``, never ``pos - window``: a q tile's padding rows
-        # carry whatever positions the block's padding holds)
-        mask = (held <= pos) & (held + window > pos) & (held >= 0)  # [G * bq, bk]
         # skip the steps before the first block, and blocks wholly in the
         # future or wholly behind the window of every row in this q tile
         run = live & (jnp.min(held) <= jnp.max(pos)) & (jnp.max(held) + window > jnp.min(pos))
@@ -228,21 +250,33 @@ def _prefill_kernel(
     else:
         blk = kv_block_index(ik, nk, last_ref[pl.program_id(0)], block_k)
         k_start = blk * block_k
-        col = k_start + lax.broadcasted_iota(jnp.int32, (rows, block_k), 1)
-        mask = (col <= pos) & (col < seq_len_k)  # [G * bq, bk]
         # skip the steps before the sequence's first block, and KV blocks
         # strictly in the future of every row in this q tile
         run = (blk >= 0) & (k_start <= jnp.max(pos))
 
     @pl.when(run)
     def _compute():
+        # the mask is built HERE, inside the step: built beside ``run`` it is a
+        # [G * bq, bk] value that crosses into this body through VMEM, written
+        # once and read back by every head (+ 38 % of a deep GQA-7 call)
+        if window:
+            # (``held + window``, never ``pos - window``: a q tile's padding
+            # rows carry whatever positions the block's padding holds)
+            mask = (held <= pos) & (held + window > pos) & (held >= 0)  # [G * bq, bk]
+        else:
+            col = k_start + lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+            mask = (col <= pos) & (col < seq_len_k)  # [G * bq, bk]
         # rows past the arena end are padded garbage (can be NaN): zero them,
         # since 0 * NaN from the masked-out probabilities would poison acc
         col_valid = k_start + lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
+        operand = jnp.promote_types(q_ref.dtype, k_ref.dtype)
         for h in range(kv_heads):
-            qb = q_ref[h].astype(jnp.float32).reshape(rows, hd)
-            kb = _head(k_ref, h)  # [bk, hd]
-            vb = jnp.where(col_valid < seq_len_k, _head(v_ref, h), 0.0)
+            # the MXU takes q, K, V and the probabilities in the arena's dtype
+            # (``_head``'s float32 of a bfloat16 head rounds back exactly);
+            # scores, the softmax's state and the accumulator are float32
+            qb = q_ref[h].reshape(rows, hd).astype(operand)
+            kb = _head(k_ref, h).astype(operand)  # [bk, hd]
+            vb = jnp.where(col_valid < seq_len_k, _head(v_ref, h), 0.0).astype(v_ref.dtype)
             s = lax.dot_general(
                 qb,
                 kb,
@@ -250,25 +284,57 @@ def _prefill_kernel(
                 preferred_element_type=jnp.float32,
             )  # [G * bq, bk]
             s = jnp.where(mask, s * scale, NEG_INF)
-            m_prev = m_ref[h, :]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+            # m, l ``[G * bq, 1]``: a row's state stays on the row's sublane
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new[:, None])
-            l_ref[h, :] = l_ref[h, :] * alpha + jnp.sum(p, axis=-1)
-            acc_ref[h] = acc_ref[h] * alpha[:, None] + lax.dot_general(
-                p,
+            p = jnp.exp(s - m_new)
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + lax.dot_general(
+                p.astype(vb.dtype),
                 vb,
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
             )
-            m_ref[h, :] = m_new
+            m_ref[h] = m_new
 
     @pl.when(ik == nk - 1)
     def _finish():
         l = l_ref[...]
         l = jnp.where(l == 0.0, 1.0, l)  # fully-masked (padding) rows
-        out = acc_ref[...] / l[..., None]
+        out = acc_ref[...] / l
         o_ref[...] = out.reshape(kv_heads, groups, bq, hd).astype(o_ref.dtype)
+
+
+def prefill_plan(
+    t: int, h: int, kv: int, hd: int, s: int, q_dtype, kv_dtype, block_q: int = 256, block_k: int = 256
+) -> tuple[int, int, int, int]:
+    """``flash_prefill``'s tiles for ``t`` rows of ``h`` query heads over an
+    arena of ``s`` positions of ``kv`` heads: ``(heads, bq, bk, vmem_bytes)``,
+    a function of the call's shapes and dtypes alone. ``heads, bk``: the K/V
+    block (``_kv_block``). ``bq``: ``block_q`` rows, halved until a tile's
+    rows fit ``_PREFILL_Q_VMEM``. ``vmem_bytes``: both plans, what the call
+    asks Mosaic for (less its margin)."""
+    g = h // kv
+    heads, bk = _kv_block(kv, hd, kv_dtype, s, block_k, _PREFILL_KV_VMEM)
+    q_item, kv_item = jnp.dtype(q_dtype).itemsize, jnp.dtype(kv_dtype).itemsize
+    # a q row across the block's heads and their G query heads: q and the
+    # output in two buffers each and the f32 accumulator; m and l, a lane tile
+    # each; the positions (an int32 a row and group, a lane tile, two
+    # buffers); the scores, their exponentials and those rounded for the
+    # value matmul, of two heads in flight
+    per_row = g * (heads * (hd * (4 * q_item + 4) + 2 * 128 * 4) + 2 * 128 * 4 + 2 * bk * (8 + kv_item))
+    bq = min(block_q, _round_up(t, 8))
+    while bq > 8 and bq * per_row > _PREFILL_Q_VMEM:
+        bq = _round_up(bq // 2, 8)
+    return heads, bq, bk, bq * per_row + bk * _kv_position_bytes(heads, hd, kv_dtype)
+
+
+def prefill_tile(t: int, h: int, kv: int, hd: int, s: int, q_dtype, kv_dtype) -> dict:
+    """The plan in words, for an engine's ``/metrics``: the q tile's rows, the
+    K/V block's positions and the dtype of the MXU's operands."""
+    _, bq, bk, _ = prefill_plan(t, h, kv, hd, s, q_dtype, kv_dtype)
+    return {"bq": bq, "bk": bk, "operands": str(jnp.promote_types(q_dtype, kv_dtype))}
 
 
 @functools.partial(
@@ -281,7 +347,7 @@ def flash_prefill(
     q_positions: jnp.ndarray,  # [B, T] int32
     layer,  # int32 scalar: the layer of the stack to read
     slot=0,  # int32 scalar: sequence b reads arena row slot + b
-    block_q: int = 128,
+    block_q: int = 256,
     block_k: int = 256,
     interpret: bool = False,
     window: int = 0,
@@ -293,14 +359,7 @@ def flash_prefill(
     b, t, h, hd = q.shape
     s, kv = k.shape[2], k.shape[3]
     g = h // kv
-    heads, bk = _kv_block(kv, hd, k.dtype, s, block_k, _PREFILL_KV_VMEM)
-    # a q tile holds the block's heads × G query heads × bq rows: q and the
-    # output in two buffers each, the f32 accumulator, and the positions
-    # (one int32 a row and group, padded to a lane tile, two buffers)
-    bq = min(block_q, _round_up(t, 8))
-    per_row = g * (heads * hd * (4 * q.dtype.itemsize + 4) + 2 * 128 * 4)
-    while bq > 8 and bq * per_row > _PREFILL_Q_VMEM:
-        bq = _round_up(bq // 2, 8)
+    heads, bq, bk, vmem_bytes = prefill_plan(t, h, kv, hd, s, q.dtype, k.dtype, block_q, block_k)
 
     qh = q.reshape(b, t, kv, g, hd).transpose(0, 2, 3, 1, 4)  # [B,KV,G,T,hd]
     # positions once per group, so a q tile's [G, bq] rows carry their own
@@ -350,8 +409,8 @@ def flash_prefill(
         ],
         out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((heads, g * bq), jnp.float32),
-            pltpu.VMEM((heads, g * bq), jnp.float32),
+            pltpu.VMEM((heads, g * bq, 1), jnp.float32),
+            pltpu.VMEM((heads, g * bq, 1), jnp.float32),
             pltpu.VMEM((heads, g * bq, hd), jnp.float32),
         ],
     )
@@ -359,6 +418,7 @@ def flash_prefill(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_bytes + (8 << 20)),
         interpret=interpret,
     )(*scalars, pos, qh, k, v)
     return out.transpose(0, 3, 1, 2, 4).reshape(b, t, h, hd)

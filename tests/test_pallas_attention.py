@@ -568,3 +568,90 @@ def test_ring_mask_is_the_window_over_positions():
         seen = sorted(j for j in range(max(0, i - window + 1), i + 1))
         assert sorted(np.flatnonzero(mask)) == sorted(j % ring for j in seen)
         assert mask.sum() == min(i + 1, window)
+
+
+# ---- the prefill tile as served (ISSUE 49) -----------------------------------
+#
+# bfloat16 q and arena, the MXU's operands in that dtype (the probabilities
+# rounded to it before the value matmul), float32 scores, state and
+# accumulator; the tile a function of the call's shapes (``prefill_plan``):
+# 256 query rows against K/V blocks of 256 at every shape a cell serves.
+
+SERVED = {
+    # name: (query heads, stored KV heads, arena rows, window, chunk start, real rows)
+    "smallthinker_window_lapped": (28, 4, 4608, 4096, 9100, 213),  # 9100 mod 4608 = 4492: the chunk laps the ring's end
+    "smallthinker_global_past_4096": (28, 4, 4400, 0, 4200, 190),  # the padding runs over the last row and past the arena
+    "mixtral": (32, 8, 2048, 0, 1536, 256),
+    "olmoe": (16, 16, 2048, 0, 1536, 256),
+    "olmo_hybrid_32_stored_for_30": (32, 32, 2048, 0, 1536, 256),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(SERVED))
+def test_prefill_in_bfloat16_at_the_served_tile(shape):
+    """A 256-row chunk on slot 1, layer 1, bfloat16 as served, against the
+    float32 reference over the same values: the real rows agree to bfloat16's
+    rounding; a bucket's padding rows carry positions that run on past the
+    real tokens (over the arena's last row, where lanes are parked, and past
+    its end in the global case) and poison nothing."""
+    from agentainer_tpu.ops.attention import _reference_dense
+    from agentainer_tpu.ops.pallas_attention import prefill_plan
+
+    h, kv, s, window, start, real = SERVED[shape]
+    t, hd = 256, 128
+    assert prefill_plan(t, h, kv, hd, s, jnp.bfloat16, jnp.bfloat16)[1:3] == (256, 256)
+    keys = jax.random.split(jax.random.PRNGKey(49), 3)
+    ck = _rand(keys[0], 2, 2, s, kv, hd).astype(jnp.bfloat16)
+    cv = _rand(keys[1], 2, 2, s, kv, hd).astype(jnp.bfloat16)
+    q = _rand(keys[2], 1, t, h, hd).astype(jnp.bfloat16)
+    if shape.startswith("olmo_hybrid"):
+        q = q.at[:, :, 30:].set(0)  # the hybrid block pads the model's 30 heads with zero heads
+    positions = (start + jnp.arange(t, dtype=jnp.int32))[None]
+    kw = {"window": window} if window else {}
+    got = flash_prefill(q, ck, cv, positions, 1, 1, interpret=True, **kw)
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    want = _reference_dense(f32(q), f32(ck), f32(cv), positions, None, jnp.int32(1), jnp.int32(1), **kw)
+    assert got.dtype == jnp.bfloat16 and bool(jnp.isfinite(f32(got)).all())
+    np.testing.assert_allclose(np.asarray(f32(got))[0, :real], np.asarray(want)[0, :real], rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("shape", sorted(SERVED))
+def test_prefill_plan_fits_what_the_call_asks_for(shape):
+    """The q tile's rows and the K/V blocks, counted here from the kernel's
+    buffers, fit the plan's bytes; the call asks Mosaic for those plus 8 MiB,
+    a third of a v5e's 128 MiB of VMEM at most; a short chunk is one tile."""
+    from agentainer_tpu.ops.pallas_attention import _PREFILL_KV_VMEM, _PREFILL_Q_VMEM, prefill_plan, prefill_tile
+
+    h, kv, s = SERVED[shape][:3]
+    heads, bq, bk, vmem = prefill_plan(256, h, kv, 128, s, jnp.bfloat16, jnp.bfloat16)
+    g, rows = h // kv, h // kv * bq
+    tile = 2 * 2 * heads * rows * 128 * 2  # q and the output, two buffers each
+    tile += heads * rows * 128 * 4 + 2 * heads * rows * 128 * 4  # acc; m and l, a lane tile a row
+    tile += 2 * g * bq * 128 * 4  # positions
+    tile += 2 * rows * bk * (4 + 4 + 2)  # two heads' scores, exponentials, rounded probabilities
+    blocks = 4 * bk * max(heads, 16) * 128 * 2
+    assert tile <= _PREFILL_Q_VMEM and blocks <= _PREFILL_KV_VMEM
+    assert tile + blocks <= vmem <= 40 << 20
+    assert kv % heads == 0 and bk % 128 == 0 and bq % 8 == 0
+    assert prefill_tile(256, h, kv, 128, s, jnp.bfloat16, jnp.bfloat16) == {"bq": bq, "bk": bk, "operands": "bfloat16"}
+    assert prefill_plan(40, h, kv, 128, s, jnp.float32, jnp.float32)[1] == 40
+    # 64 query heads over 8: a 256-row tile would not fit, so the plan halves it
+    assert prefill_plan(256, 64, 8, 128, 2048, jnp.bfloat16, jnp.bfloat16)[1] == 128
+
+
+def test_ring_block_is_what_every_registered_configuration_had():
+    """The ring of a window layer is whole multiples of ``ring_block()``: the
+    cache's layout. The prefill plan moved in PR 49; this did not."""
+    from agentainer_tpu.models.configs import get_config, list_configs
+    from agentainer_tpu.ops.pallas_attention import kernel_supported, ring_block
+
+    had = {  # (bfloat16, float32) at c5c67e8
+        "bench-1b": (512, 512), "llama3-8b": (512, 512), "mistral-small-4-119b": (512, 256),
+        "mixtral-8x7b": (512, 512), "olmoe-1b-7b": (512, 256), "smallthinker-21b": (512, 512),
+    }
+    now = {}
+    for name in list_configs():
+        cfg = get_config(name)
+        if kernel_supported(cfg.n_heads, cfg.n_kv_heads, cfg.head_dim):
+            now[name] = tuple(ring_block(cfg.n_kv_heads, cfg.head_dim, d) for d in (jnp.bfloat16, jnp.float32))
+    assert now == had

@@ -494,6 +494,7 @@ def test_decode_block_counters_follow_the_lanes_positions():
 
         att0, steps0 = snap()
         assert att0["decode_block_positions"] == 512
+        assert "prefill_tile" not in att0  # the flash kernel's plan: named where that kernel serves (a TPU)
         assert att0["decode_blocks_live"] == att0["decode_blocks_stored"] == 0
         asyncio.run(eng.generate("word " * 120, max_tokens=8, ignore_eos=True))  # 601 tokens
         att1, steps1 = snap()
@@ -628,3 +629,27 @@ def test_an_olmo_hybrid_engine_names_its_kinds_its_leaves_and_what_a_snapshot_sh
         sizes = dict(part.split("=") for part in text.split(","))
         assert sorted(sizes) == ["conv", "k", "state", "v"] and all(int(v) > 0 for v in sizes.values())
     assert int(dict(p.split("=") for p in carried["engine.restore"].split(","))["state"]) == 6 * 12 * 6 * 24 * 4
+
+
+def test_attention_names_the_prefill_tile_where_the_flash_kernel_serves(monkeypatch):
+    """``/metrics`` ``attention.prefill_tile`` (ISSUE 49): where the plan says
+    ``pallas:flash_prefill`` over the dense arena (a TPU engine; here the plan
+    is handed the kernel's name over the XLA function), the engine names the
+    tile a full chunk's call compiles to: ``prefill_plan`` of the chunk's rows,
+    the model's heads and the arena's rows and dtype."""
+    from agentainer_tpu.engine import llm
+    from agentainer_tpu.ops.attention import CacheAttention, _reference_dense
+    from agentainer_tpu.ops.pallas_attention import prefill_plan
+
+    named = CacheAttention(_reference_dense, "pallas:flash_prefill", "pallas:flash_decode", "named for the test", "stack+layer")
+    monkeypatch.setattr(llm, "plan_cache_attention", lambda *a, **kw: named)
+    eng = LLMEngine.create(
+        "tiny", options={"max_batch": 2, "max_seq": 1024, "prefill_chunk": 256, "speculative": False, "skip_warmup": True},
+    )
+    try:
+        att = eng.metrics()["attention"]
+        k = eng.cache.k
+    finally:
+        eng.shutdown()
+    _, bq, bk, _ = prefill_plan(256, 4, 2, 16, 1024, k.dtype, k.dtype)
+    assert att["prefill_tile"] == {"bq": bq, "bk": bk, "operands": str(k.dtype)} and (bq, bk) == (256, 256)

@@ -680,6 +680,12 @@ def test_windowed_step_keeps_both_leaves_in_place_on_v5e(v5e, monkeypatch, step,
     for leaf in (cache.k, cache.wk):
         shape = ",".join(map(str, leaf.shape))
         assert not re.search(rf"\[{shape}\][^ ]* (copy|transpose)\(", text), shape
+    if step != "decode":
+        # the chunk's attention is the flash kernel, by name, at the published
+        # 28 / 4 x 128: no float32 scores of 28 heads x 256 rows against
+        # either leaf's rows stand in the program (ISSUE 49)
+        assert "flash_prefill" in text
+        assert not re.search(r"f32\[(1,)?28,256,(4608|16384)\]", text)
     # nothing the size of a layer's held experts or of a leaf's layer
     layer_matrix = cfg.n_held * cfg.dim * cfg.ffn_dim
     assert mem.temp_size_in_bytes < min(layer_matrix, 2 * math.prod(cache.wk.shape[1:])) // 8, mem.temp_size_in_bytes

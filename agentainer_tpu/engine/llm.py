@@ -193,13 +193,15 @@ def cache_features(cfg: ModelConfig, asked: dict) -> tuple[dict, dict]:
 
 
 def _cache_off(cfg: ModelConfig) -> tuple[dict | None, str]:
-    """The features a family's cache turns off, with what the cache is."""
-    if cfg.is_hybrid and cfg.linear_kind is None:
-        return _LATENT_OFF, "keeps latent rows in a leaf the k/v mechanisms do not take"
-    if cfg.is_hybrid:
-        return _RECURRENT_OFF, "keeps a recurrent state in its cache"
+    """The features a family's cache turns off, with what the cache is: chosen
+    by the cache's leaves (a ring, a per-lane state, a latent leaf), whichever
+    block builds them."""
     if cfg.n_window:
         return _WINDOW_OFF, "keeps its window layers' rows in a ring"
+    if cfg.linear_kind is not None:
+        return _RECURRENT_OFF, "keeps a recurrent state in its cache"
+    if cfg.is_hybrid:
+        return _LATENT_OFF, "keeps latent rows in a leaf the k/v mechanisms do not take"
     return None, ""
 
 
@@ -622,17 +624,24 @@ class LLMEngine:
             },
         )
         speculative, prefix_cache = feats["speculative"], feats["prefix_cache"]
-        self._recurrent = cfg.is_hybrid
+        # the hybrid block: its own plan, cache pytree and per-lane decode
+        # controls (``stop``, ``eos``), whatever its layers' kinds
+        self._hybrid = cfg.is_hybrid
+        # a per-lane state that only moves forward (a linear mixer's): what
+        # admission zeroes for a fresh context
+        self._recurrent = cfg.linear_kind is not None
         # window layers beside global ones: a ring leaf beside the arena
+        # (the K/V block's ``WindowKVCache`` or the hybrid block's ``wk``, ``wv``)
         self._windowed = bool(cfg.n_window)
         # a cache of NAMED leaves (the hybrid block's, or k, v + the ring):
         # snapshots and restores move a dict of them, not the pair (k, v)
-        self._named_leaves = self._recurrent or self._windowed
+        self._named_leaves = self._hybrid or self._windowed
         if self._cache_off:
             kinds = (
                 f"kinds={'+'.join(sorted(set(cfg.layer_kinds)))} "
-                + ("(positional rows + per-lane state)" if cfg.linear_kind else "(positional rows, no per-lane state)")
-                if self._recurrent
+                + ("(positional rows + per-lane state)" if self._recurrent else "(positional rows, no per-lane state)")
+                + (f" + a ring of the last rows x{cfg.n_window} (window {cfg.window})" if self._windowed else "")
+                if self._hybrid
                 else f"global rows x{cfg.n_global} + a ring of the last rows x{cfg.n_window} (window {cfg.window})"
             )
             print(
@@ -1059,20 +1068,22 @@ class LLMEngine:
         # decode step streams the weights once plus each active lane's KV
         # prefix; prefill streams the weights once per chunk.
         self.hbm_bytes_read = 0.0
-        if self._recurrent:
+        if self._hybrid:
             # positional bytes a token adds (the latent rows, or k and v as
             # stored); the per-lane state is read and written whole each step
             # whatever the context
-            self._kv_bytes_per_pos = sum(
-                a.shape[0] * int(np.prod(a.shape[3:])) * a.dtype.itemsize for a in cache.rows()
+            per_pos = lambda leaves: sum(  # noqa: E731
+                a.shape[0] * int(np.prod(a.shape[3:])) * a.dtype.itemsize for a in leaves
             )
+            # of it, what the window layers add: read for the last ``window``
+            # positions only (``_kv_read_bytes``)
+            self._kv_bytes_per_pos_window = per_pos(cache.ring())
+            self._kv_bytes_per_pos = per_pos(cache.rows()) + self._kv_bytes_per_pos_window
         else:
             self._kv_bytes_per_pos = (
                 2 * cfg.n_layers * cfg.n_kv_heads * cfg.head_dim * cache.k.dtype.itemsize
             )
-        # of it, what the window layers add: read for the last ``window``
-        # positions only (``_kv_read_bytes``)
-        self._kv_bytes_per_pos_window = self._kv_bytes_per_pos * cfg.n_window // cfg.n_layers
+            self._kv_bytes_per_pos_window = self._kv_bytes_per_pos * cfg.n_window // cfg.n_layers
         # cache-manager counters of the per-lane state (``cache`` in /metrics)
         self.state_resets = 0
         self.state_snapshots = 0
@@ -1397,7 +1408,7 @@ class LLMEngine:
         # (parallel/flash_mesh.py). A meshed page pool stays on the einsum
         # path (it needs the partitioning XLA derives).
         page_size = self.page_size if self.paged else 0
-        if self._recurrent:
+        if self._hybrid:
             # the hybrid block's plan: a kernel per mechanism and call shape
             # (models/hybrid.plan_hybrid), passed to ``forward`` in the same
             # seat as the arena attention
@@ -1480,8 +1491,13 @@ class LLMEngine:
                 window_decode_blocks_stored=0, window_wraps=0,
                 global_decode_rows=0, window_decode_rows=0,
             )
-        self.meshed_flash = (not self._recurrent) and "shard_map" in attn.decode
-        if self._recurrent:
+        if self._windowed and self._hybrid:
+            # what differs by kind of layer: query heads, the gate, the rotary
+            from ..models.hybrid import attention_by_kind
+
+            self.attention.update(attention_by_kind(cfg))
+        self.meshed_flash = (not self._hybrid) and "shard_map" in attn.decode
+        if self._hybrid:
             print(
                 "[llm-engine] attention: "
                 + "; ".join(f"{k} prefill={p} decode={d}" for k, (p, d) in attn.kinds().items())
@@ -1494,7 +1510,7 @@ class LLMEngine:
                 f"decode={attn.decode} arena={attn.arena} ({attn.reason})",
                 flush=True,
             )
-        cache_attn_impl = attn if self._recurrent else attn.fn
+        cache_attn_impl = attn if self._hybrid else attn.fn
 
         # the MoE FFN the steps trace. One chip, no option: ``forward`` itself
         # splits at the chip's ridge (ops/moe.sorted_from_rows) — calls under
@@ -1525,7 +1541,7 @@ class LLMEngine:
         )
         # rows a call needs to take the sorted FFN (no call under a pinned path)
         self._moe_sorted_from = (
-            moe_sorted_from(cfg, self.params if self._recurrent else self.params["layers"])
+            moe_sorted_from(cfg, self.params if self._hybrid else self.params["layers"])
             if moe_impl is None
             else None
         )
@@ -1587,7 +1603,7 @@ class LLMEngine:
         def real_rows(tokens, n_real):
             # a recurrent state must not see the bucket's padding rows: the
             # hybrid block is told which of a chunk's rows are real
-            if not self._recurrent:
+            if not self._hybrid:
                 return {}
             return {"valid": jnp.arange(tokens.shape[1])[None, :] < n_real}
 
@@ -1732,7 +1748,7 @@ class LLMEngine:
             self._mixed_buckets = tuple(b for b in PREFILL_BUCKETS if b <= top)[-2:]
         self._inject = jax.jit(inject, donate_argnums=(0, 1, 2, 3, 4, 5, 6))
         self._first_token = jax.jit(first_token)
-        if self._recurrent:
+        if self._hybrid:
             from ..models import hybrid
 
             # the cache manager's moves on a lane's state, as the model
@@ -3773,7 +3789,7 @@ class LLMEngine:
         }
 
     def _cache_metrics(self) -> dict:
-        if self._windowed:
+        if self._windowed and not self._hybrid:
             sizes = {
                 "kv_bytes": self.cache.k.nbytes + self.cache.v.nbytes,
                 "kv_ring_bytes": self.cache.wk.nbytes + self.cache.wv.nbytes,
@@ -3786,7 +3802,7 @@ class LLMEngine:
                 "state_restores": self.state_restores,
                 "off": dict(self._cache_off),
             }
-        if not self._recurrent:
+        if not self._hybrid:
             return {
                 "kinds": ["kv"],
                 "kv_bytes": self.kv_arena_bytes,
@@ -4596,7 +4612,7 @@ class LLMEngine:
             slot.prefix_ctx = list(prompt)
         else:
             slot.prefix_ctx = None
-        if self._recurrent:
+        if self._hybrid:
             # open the lane's per-lane state for this request, zeroed for a
             # fresh context and carried on for a continuing one. Decode steps
             # it while it feeds tokens the reply keeps: positions up to the
@@ -4610,7 +4626,7 @@ class LLMEngine:
                     jnp.int32(slot.position + len(prompt) + req.max_tokens - 1),
                     jnp.int32(-1 if req.ignore_eos else self.tokenizer.eos_id),
                 )
-            self.state_resets += int(fresh and self.cache.state is not None)
+            self.state_resets += int(fresh and self._recurrent)
         # admit: the slot is busy from here; the worker's prefill tick feeds
         # the prompt through chunk-by-chunk, interleaved with decode steps
         slot.request = req
@@ -6033,7 +6049,7 @@ class LLMEngine:
             # are real compute but wasted — MFU should show that, not hide it
             self.flops_done += used * self.cfg.flops_per_token(start + used // 2)
             finished = hit_eos or len(req.generated) >= req.max_tokens
-            if finished and self._recurrent:
+            if finished and self._hybrid:
                 # a recurrent state cannot be rewound, so the device never
                 # feeds a reply's last token (the lane's stop / EOS mask):
                 # the state stands at the tokens before it, wherever in the

@@ -9,7 +9,10 @@ models/hybrid.py); Olmo-Hybrid-7B is the same block's other pair of mixers
 (a gated delta rule with one decay a head beside full softmax attention, a
 dense SwiGLU in every layer, the OLMo-2 norm placement); Mistral-Small-4-119B
 is that block with latent attention in every layer and no linear mixer (a
-low-rank query, a YaRN rotary embedding on the shared key dims). Tiny variants exist for CI and the virtual CPU mesh —
+low-rank query, a YaRN rotary embedding on the shared key dims); Laguna-XS.2
+is that block with full attention beside sliding-window attention (two head
+counts, two rotary embeddings, a gate a head; the window layers' rows a
+ring). Tiny variants exist for CI and the virtual CPU mesh —
 same code path, small shapes.
 
 All dims are chosen TPU-aware: head_dim and hidden sizes are multiples of
@@ -24,6 +27,9 @@ from dataclasses import dataclass, field
 
 LINEAR_KINDS = ("kda", "gdn")
 POSITIONAL_KINDS = ("mla", "full")
+# softmax attention over a RING of the last rows (``window``): the one kind
+# that may stand beside another positional kind ("full") in one model
+WINDOW_KIND = "swa"
 
 
 @dataclass(frozen=True)
@@ -57,7 +63,11 @@ class ModelConfig:
     # attention without rotary embedding: one shared latent row per token) or
     # "full" (softmax attention over K/V rows, QK-norm under ``qk_norm``, no
     # rotary embedding where ``rope_theta`` is 0). A model has at most one
-    # kind of each. Empty: every layer is RoPE GQA attention over a K/V arena
+    # kind of each, and beside "full" it may have "swa" (Laguna): the same
+    # softmax attention over the last ``window`` positions, its rows a ring
+    # (``models/llama.ring_rows``), with its own count of query heads
+    # (``swa_heads``) and its own rotary embedding (``swa_rope_theta``).
+    # Empty: every layer is RoPE GQA attention over a K/V arena
     layer_kinds: tuple[str, ...] = ()
     kda_heads: int = 0
     kda_head_dim: int = 0  # of keys (and of values where ``kda_v_dim`` is 0)
@@ -96,6 +106,22 @@ class ModelConfig:
     # Llama-4's query scale by position: q · (1 + this · ln(1 + ⌊p /
     # rope_original_max⌋)), 1 below ``rope_original_max``. 0: none
     q_pos_scale_beta: float = 0.0
+    # -- "full" beside "swa" (Laguna); every default is "as Olmo-Hybrid" ------
+    # query heads of a "swa" layer (a "full" layer has ``n_heads``; both kinds
+    # share ``n_kv_heads`` and ``head_dim``). 0: ``n_heads``
+    swa_heads: int = 0
+    # a "swa" layer's rotary embedding: plain rotate-half over the whole head
+    # with this base. 0: none
+    swa_rope_theta: float = 0.0
+    # a "full" layer rotates the FIRST ``rope_partial`` share of a head's dims
+    # (rotate-half inside them; the rest carry no position), with
+    # ``rope_theta`` and the ``rope_*`` fields above (YaRN where
+    # ``rope_factor`` > 1), cos and sin both times ``rope_attention_factor``
+    rope_partial: float = 1.0
+    rope_attention_factor: float = 1.0
+    # every attention head's output times a sigmoid of its own, a linear
+    # function of the layer's normed input (``wg [d, heads]``)
+    attn_gate: bool = False
     # the first ``n_dense_layers`` have a dense SwiGLU of ``dense_ffn_dim``;
     # the rest are MoE with ``ffn_dim`` wide experts
     n_dense_layers: int = 0
@@ -148,6 +174,10 @@ class ModelConfig:
             raise ValueError("rope_factor and q_pos_scale_beta are read against rope_original_max, which is 0")
         if self.mla_rotary and (self.mla_rope_dim % 2 or not self.rope_theta):
             raise ValueError("mla_rotary rotates an even mla_rope_dim by a rope_theta that is not 0")
+        if WINDOW_KIND in self.layer_kinds and (self.window <= 0 or "full" not in self.layer_kinds):
+            raise ValueError('"swa" layers see a window > 0 and stand beside "full" layers (the arena\'s length is theirs)')
+        if self.rope_partial != 1.0 and self.rotary_dim % 2:
+            raise ValueError("rope_partial rotates an even number of a head's dims")
 
     @property
     def head_dim(self) -> int:
@@ -157,8 +187,23 @@ class ModelConfig:
 
     @property
     def n_window(self) -> int:
-        """Layers whose attention is windowed (their cache is a ring)."""
-        return sum(self.window_layers)
+        """Layers whose attention is windowed (their cache is a ring): the
+        K/V block's flagged layers, or the hybrid block's "swa" layers."""
+        return sum(self.window_layers) or self.layer_kinds.count(WINDOW_KIND)
+
+    @property
+    def window_heads(self) -> int:
+        """Query heads of a window layer."""
+        return self.swa_heads or self.n_heads
+
+    def heads_of(self, kind: str) -> int:
+        """Query heads of a layer of ``kind``."""
+        return self.window_heads if kind == WINDOW_KIND else self.n_heads
+
+    @property
+    def rotary_dim(self) -> int:
+        """Dims of a "full" layer's head that are rotated (the first ones)."""
+        return int(self.head_dim * self.rope_partial)
 
     @property
     def n_global(self) -> int:
@@ -196,6 +241,7 @@ class ModelConfig:
 
     @property
     def n_positional(self) -> int:
+        """Layers whose rows are kept from position 0 (not the ring's)."""
         return sum(k in POSITIONAL_KINDS for k in self.layer_kinds)
 
     @property
@@ -246,10 +292,13 @@ class ModelConfig:
             + 2 * h + self.delta_v_dim  # A_log, dt_bias, head norm
         )
         hd = self.head_dim
-        full = 2 * d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd
-        if self.qk_norm:
-            full += (self.n_heads + self.n_kv_heads) * hd
-        return {"kda": kda, "mla": mla, "gdn": gdn, "full": full, "expert": expert, "moe_fixed": moe,
+
+        def attention(heads: int) -> int:  # q, o, k, v, the gate a head, the two norms
+            n = 2 * d * heads * hd + 2 * d * self.n_kv_heads * hd + (d * heads if self.attn_gate else 0)
+            return n + ((heads + self.n_kv_heads) * hd if self.qk_norm else 0)
+
+        return {"kda": kda, "mla": mla, "gdn": gdn, "full": attention(self.n_heads),
+                WINDOW_KIND: attention(self.window_heads), "expert": expert, "moe_fixed": moe,
                 "dense": 3 * d * self.dense_ffn_dim}
 
     @property
@@ -317,7 +366,9 @@ class ModelConfig:
             )
             full = 4.0 * self.n_heads * self.head_dim * context_len
             positional = {"mla": mla, "full": full}.get(self.positional_kind, 0.0)
-            return matmul + self.n_linear * delta + self.n_positional * positional
+            # a window layer sees at most its window of the context
+            swa = 4.0 * self.window_heads * self.head_dim * min(context_len, self.window)
+            return matmul + self.n_linear * delta + self.n_positional * positional + self.n_window * swa
         # attention scores + value combine: q·K^T and p·V, each
         # 2 * heads * head_dim * context MACs → 4 FLOPs per context slot
         # (a window layer sees at most its window of them)
@@ -603,6 +654,99 @@ TINY_MISTRAL4 = register(
         q_pos_scale_beta=0.1,
         n_shared_experts=1,
         moe_router="softmax",
+    )
+)
+
+def laguna_kinds(n_layers: int, period: int = 4) -> tuple[str, ...]:
+    """Laguna's published ``layer_types``: every ``period``-th layer from
+    layer 0 attends to its whole context, the rest to a sliding window."""
+    return tuple("full" if i % period == 0 else WINDOW_KIND for i in range(n_layers))
+
+
+# Laguna-XS.2 (poolside/Laguna-XS.2 config.json, ``model_type: laguna``: 40
+# layers, hidden 2048, heads of 128 over 8 KV heads in every layer, (full,
+# sliding, sliding, sliding) x 10 with a window of 512; a full layer has 48
+# query heads and rotates the first 64 dims of a head with YaRN's frequencies
+# (theta 5e5, factor 64 over an original 4,096, beta_fast 64, cos and sin x
+# 1.4158883), a sliding layer 64 query heads and plain RoPE (theta 1e4) over
+# the whole head; a sigmoid gate a head on the attention's output; a dense
+# SwiGLU of 8192 in layer 0, then 256 experts of 512, top-8 of a softmax
+# renormalised over the chosen eight x 2.5, one shared expert of 512;
+# vocabulary 100,352, untied). All 256 experts: 33.44 B parameters — a chip
+# serves its share (``experts_held``; benchmark/configs).
+LAGUNA_XS2 = register(
+    ModelConfig(
+        name="laguna-xs.2",
+        vocab_size=100_352,
+        dim=2048,
+        n_layers=40,
+        n_heads=48,
+        n_kv_heads=8,
+        head_size=128,
+        ffn_dim=512,
+        max_seq_len=262_144,
+        rope_theta=500_000.0,
+        norm_eps=1e-6,
+        n_experts=256,
+        experts_per_token=8,
+        moe_renormalize=True,
+        layer_kinds=laguna_kinds(40),
+        window=512,
+        swa_heads=64,
+        swa_rope_theta=10_000.0,
+        rope_partial=0.5,
+        rope_factor=64.0,
+        rope_original_max=4096,
+        rope_beta_fast=64.0,
+        rope_beta_slow=1.0,
+        rope_attention_factor=1.4158883083359672,
+        attn_gate=True,
+        n_dense_layers=1,
+        dense_ffn_dim=8192,
+        n_shared_experts=1,
+        moe_router="softmax",
+        moe_scale=2.5,
+    )
+)
+
+# The same block at CI shapes with the published RATIOS: two periods (F S S S
+# F S S S), 6 query heads in a full layer and 8 in a sliding one over 2 KV
+# heads of 16, a window of 16, an original context of 32 stretched 8 times
+# (a 256-token test passes it; with theta 100 over the 8 rotated dims the
+# ramp has a pair strictly inside it), half of a head rotated, a dense first
+# layer, 8 experts top-2 x 2.5 beside a shared one.
+TINY_LAGUNA = register(
+    ModelConfig(
+        name="tiny-laguna",
+        vocab_size=512,
+        dim=64,
+        n_layers=8,
+        n_heads=6,
+        n_kv_heads=2,
+        head_size=16,
+        ffn_dim=32,
+        max_seq_len=256,
+        rope_theta=100.0,
+        norm_eps=1e-6,
+        n_experts=8,
+        experts_per_token=2,
+        moe_renormalize=True,
+        layer_kinds=laguna_kinds(8),
+        window=16,
+        swa_heads=8,
+        swa_rope_theta=10_000.0,
+        rope_partial=0.5,
+        rope_factor=8.0,
+        rope_original_max=32,
+        rope_beta_fast=32.0,
+        rope_beta_slow=1.0,
+        rope_attention_factor=1.2079441541679836,
+        attn_gate=True,
+        n_dense_layers=1,
+        dense_ffn_dim=128,
+        n_shared_experts=1,
+        moe_router="softmax",
+        moe_scale=2.5,
     )
 )
 

@@ -1,6 +1,7 @@
-"""The hybrid block (Kimi-Linear, Olmo-Hybrid, Mistral-Small-4): a linear
-mixer beside a positional one, or a positional one alone, and two FFNs,
-through the one forward.
+"""The hybrid block (Kimi-Linear, Olmo-Hybrid, Mistral-Small-4, Laguna): a
+linear mixer beside a positional one, a positional one alone, or full
+attention beside sliding-window attention, and two FFNs, through the one
+forward.
 
 ``models/llama.forward`` hands a config with ``layer_kinds`` to
 :func:`forward` here; the engine calls one ``forward`` and never learns a
@@ -28,6 +29,16 @@ with
   gate) or **full** (softmax attention over K/V rows read by the dense flash
   kernels, QK-norm over the whole projections, no rotary embedding where the
   model has none). A model has one linear kind and one positional kind;
+- or, with no linear mixer, **full** beside **swa** (Laguna: the same softmax
+  attention over the last ``cfg.window`` positions, its K/V rows a RING,
+  ``wk``, ``wv`` ``[n_swa, B, R, KV, hd]``, whose arithmetic is
+  ``models/llama.py``'s: ``ring_rows``, ``_ring_index``, the kernels'
+  ``window=``). The two kinds have their own ``wq`` / ``wo`` / ``wg`` stacks,
+  so their own count of query heads (48 and 64 over 8 K/V heads), and their
+  own rotary embedding (:func:`_attn_rotate`: YaRN over the first half of a
+  head, cos and sin scaled, against plain RoPE over the whole head); every
+  head's output is gated by a sigmoid of the layer's normed input
+  (``cfg.attn_gate``);
 - the FFN a dense SwiGLU (the first ``n_dense_layers``) or the MoE of
   ``models/llama.py`` with the sigmoid router rule (or the softmax rules
   of ``llama.moe_gates``), a shared expert and the experts this chip holds;
@@ -94,7 +105,7 @@ from ..ops.moe import EXPERT_WEIGHTS, stacked_experts
 from ..ops.norms import rms_norm
 from ..ops.quant import QTensor, dequant, embed_lookup
 from ..ops.rope import apply_rope, yarn_frequencies
-from .configs import LINEAR_KINDS, POSITIONAL_KINDS, ModelConfig
+from .configs import LINEAR_KINDS, POSITIONAL_KINDS, WINDOW_KIND, ModelConfig
 
 NO_STOP = np.iinfo(np.int32).max
 L2_EPS = 1e-6
@@ -113,16 +124,26 @@ class HybridCache(NamedTuple):
     eos: jnp.ndarray  # [B] int32 (-1: no token closes the lane)
     k: jnp.ndarray | None = None  # [n_full, B, S, stored_kv_heads, hd]
     v: jnp.ndarray | None = None
+    # the "swa" layers' rows as a ring: the row of position p is ``p mod R``
+    # (``models/llama.ring_rows``); a lane's R rows are its last R positions
+    wk: jnp.ndarray | None = None  # [n_swa, B, R, stored_kv_heads, hd]
+    wv: jnp.ndarray | None = None
 
     POSITIONAL = ("latent", "k", "v")
+    RING = ("wk", "wv")
 
     def rows(self) -> tuple:
         """The positional leaves this cache has, in ``POSITIONAL``'s order."""
         return tuple(a for a in (self.latent, self.k, self.v) if a is not None)
 
+    def ring(self) -> tuple:
+        """The ring leaves this cache has (both or neither)."""
+        return tuple(a for a in (self.wk, self.wv) if a is not None)
+
     def leaves(self) -> dict:
         """The leaves a slot is made of, by name (the controls left out)."""
-        named = {"latent": self.latent, "k": self.k, "v": self.v, "state": self.state, "conv": self.conv}
+        named = {"latent": self.latent, "k": self.k, "v": self.v, "wk": self.wk, "wv": self.wv,
+                 "state": self.state, "conv": self.conv}
         return {n: a for n, a in named.items() if a is not None}
 
 
@@ -153,15 +174,24 @@ def latent_width(cfg: ModelConfig) -> int:
 
 
 def init_cache(
-    cfg: ModelConfig, lanes: int, max_seq: int, dtype=jnp.bfloat16, live: bool = True
+    cfg: ModelConfig, lanes: int, max_seq: int, dtype=jnp.bfloat16, live: bool = True,
+    launch_rows: int | None = None, block: int = 1,
 ) -> HybridCache:
     """A zeroed cache. ``live``: every lane steps (direct callers, tests);
     an engine starts its lanes closed (``stop = 0``) and opens one when it
-    admits a request."""
+    admits a request. ``launch_rows``, ``block``: the ring's size, as
+    ``models/llama.ring_rows`` takes them (``llama.ring_plan`` gives an
+    engine's)."""
+    from .llama import ring_rows
+
     h, dk, dv, nl = cfg.kda_heads, cfg.kda_head_dim, cfg.delta_v_dim, cfg.n_linear
     if any(sum(k in cfg.layer_kinds for k in kinds) > 1 for kinds in (LINEAR_KINDS, POSITIONAL_KINDS)):
         raise ValueError("the hybrid block has one linear kind and one positional kind of mixer")
-    arena = (cfg.n_positional, lanes, max_seq, stored_kv_heads(cfg.n_kv_heads), cfg.head_dim)
+    if cfg.n_window and (nl or cfg.positional_kind != "full"):
+        raise ValueError('the hybrid block runs "swa" layers beside "full" layers and no other kind')
+    stored = stored_kv_heads(cfg.n_kv_heads)
+    arena = (cfg.n_positional, lanes, max_seq, stored, cfg.head_dim)
+    ring = (cfg.n_window, lanes, ring_rows(cfg.window, max_seq, launch_rows, block), stored, cfg.head_dim)
     full = cfg.positional_kind == "full"
     state_shape = (nl, lanes, dk, h * dv) if cfg.linear_kind == "gdn" else (nl, lanes, h, dk, dv)
     return HybridCache(
@@ -175,6 +205,8 @@ def init_cache(
         eos=jnp.full((lanes,), -1, jnp.int32),
         k=jnp.zeros(arena, dtype) if full else None,
         v=jnp.zeros(arena, dtype) if full else None,
+        wk=jnp.zeros(ring, dtype) if cfg.n_window else None,
+        wv=jnp.zeros(ring, dtype) if cfg.n_window else None,
     )
 
 
@@ -201,15 +233,17 @@ def admit_lane(cache: HybridCache, lane, fresh, stop, eos) -> HybridCache:
 def snapshot_lane(cache: HybridCache, lane, bucket: int, n_kv_heads: int | None = None) -> dict:
     """A lane's leaves by name: the positional rows ``[:, :bucket]`` (of
     ``k`` and ``v`` the model's ``n_kv_heads`` heads, not the padding they
-    are stored with), the per-lane state whole."""
+    are stored with), the per-lane state whole, and the ring whole (its R
+    rows are the last R positions wherever the lane stands, as
+    ``models/llama.snapshot_lane`` ships it)."""
     lane_of = lambda a: lax.dynamic_index_in_dim(a, lane, axis=1, keepdims=False)  # noqa: E731
     out = {}
     for name, a in cache.leaves().items():
         a = lane_of(a)
         if name in cache.POSITIONAL:
             a = a[:, :bucket]
-            if name != "latent":
-                a = a[:, :, :n_kv_heads]
+        if name in ("k", "v") + cache.RING:
+            a = a[:, :, :n_kv_heads]
         out[name] = a
     return out
 
@@ -247,6 +281,9 @@ class HybridPlan(NamedTuple):
     full_prefill: str = ""
     # the rotary embedding of the MLA layers' shared key dims ("": none)
     mla_rotary: str = ""
+    # the window layers beside the full ones (the same kernels, ``window=``)
+    swa_decode: str = ""
+    swa_prefill: str = ""
 
     def describe(self) -> dict:
         mine = {k: v for k, v in self._asdict().items() if v}
@@ -258,7 +295,10 @@ class HybridPlan(NamedTuple):
 
     def kinds(self) -> dict:
         """``kind -> (prefill, decode)`` of the kinds the model has."""
-        pairs = {k: (getattr(self, k + "_prefill"), getattr(self, k + "_decode")) for k in ("kda", "gdn", "mla", "full")}
+        pairs = {
+            k: (getattr(self, k + "_prefill"), getattr(self, k + "_decode"))
+            for k in ("kda", "gdn", "mla", "full", WINDOW_KIND)
+        }
         return {k: v for k, v in pairs.items() if v[0]}
 
 
@@ -310,17 +350,36 @@ def _plan_gdn_full(cfg: ModelConfig, use_pallas: bool) -> HybridPlan:
     full = ("xla:attention_reference",) * 2
     if use_pallas:
         kv = stored_kv_heads(cfg.n_kv_heads)
-        if kernel_supported(kv * (cfg.n_heads // cfg.n_kv_heads), kv, cfg.head_dim):
+        counts = {cfg.n_heads, cfg.window_heads} if cfg.n_window else {cfg.n_heads}
+        if all(kernel_supported(kv * (n // cfg.n_kv_heads), kv, cfg.head_dim) for n in counts):
             full = ("pallas:flash_prefill", "pallas:flash_decode")
         else:
-            why.append(f"heads {cfg.n_heads}/{kv} stored x {cfg.head_dim}: not the flash kernels' shapes")
+            why.append(f"heads {sorted(counts)}/{kv} stored x {cfg.head_dim}: not the flash kernels' shapes")
     if not why:
         why.append("tpu backend; state and K/V stacks read where they lie")
     return HybridPlan(
         "", "", "", "", "; ".join(why),
         gdn_decode=gdn if cfg.linear_kind else "", gdn_prefill="xla_chunked" if cfg.linear_kind else "",
         full_decode=full[1] if cfg.positional_kind else "", full_prefill=full[0] if cfg.positional_kind else "",
+        swa_decode=full[1] if cfg.n_window else "", swa_prefill=full[0] if cfg.n_window else "",
     )
+
+
+def attention_by_kind(cfg: ModelConfig) -> dict:
+    """What differs by kind of attention layer in a model with "full" beside
+    "swa" layers, in words, for an engine's ``/metrics``: the query heads, the
+    output gate and each kind's rotary embedding."""
+    freqs = f"yarn x{cfg.rope_factor:g} past {cfg.rope_original_max}" if cfg.rope_factor > 1.0 else "rope"
+    full = (
+        f"{freqs}, theta {cfg.rope_theta:g}, first {cfg.rotary_dim} of {cfg.head_dim} dims, "
+        f"cos and sin x{cfg.rope_attention_factor:.7g}" if cfg.rope_theta else "none"
+    )
+    swa = f"rope, theta {cfg.swa_rope_theta:g}, all {cfg.head_dim} dims" if cfg.swa_rope_theta else "none"
+    return {
+        "heads": {"full": cfg.n_heads, WINDOW_KIND: cfg.window_heads},
+        "gate": "per_head" if cfg.attn_gate else "none",
+        "rotary": {"full": full, WINDOW_KIND: swa},
+    }
 
 
 # -- parameters ----------------------------------------------------------------
@@ -337,14 +396,20 @@ def param_shapes(cfg: ModelConfig) -> dict:
     fs = cfg.n_shared_experts * cfg.ffn_dim
     ng, nf = cfg.layer_kinds.count("gdn"), cfg.layer_kinds.count("full")
     cc, cv, hd = conv_channels(cfg), h * cfg.delta_v_dim, cfg.head_dim
-    full = {
-        "wq": ((nf, d, cfg.n_heads * hd), True),
-        "wk": ((nf, d, cfg.n_kv_heads * hd), True),
-        "wv": ((nf, d, cfg.n_kv_heads * hd), True),
-        "wo": ((nf, cfg.n_heads * hd, d), True),
-    }
-    if cfg.qk_norm:
-        full.update(q_norm=((nf, cfg.n_heads * hd), False), k_norm=((nf, cfg.n_kv_heads * hd), False))
+
+    def attention(n: int, heads: int) -> dict:  # a kind's own stacks: its count of query heads
+        out = {
+            "wq": ((n, d, heads * hd), True),
+            "wk": ((n, d, cfg.n_kv_heads * hd), True),
+            "wv": ((n, d, cfg.n_kv_heads * hd), True),
+            "wo": ((n, heads * hd, d), True),
+        }
+        if cfg.attn_gate:
+            out["wg"] = ((n, d, heads), True)
+        if cfg.qk_norm:
+            out.update(q_norm=((n, heads * hd), False), k_norm=((n, cfg.n_kv_heads * hd), False))
+        return out
+
     shapes = {
         "layers": {"attn_norm": ((cfg.n_layers, d), False), "mlp_norm": ((cfg.n_layers, d), False)},
         "kda": {
@@ -371,7 +436,8 @@ def param_shapes(cfg: ModelConfig) -> dict:
             "o_norm": ((ng, cfg.delta_v_dim), False),
             "wo": ((ng, cv, d), True),
         },
-        "full": full,
+        "full": attention(nf, cfg.n_heads),
+        WINDOW_KIND: attention(cfg.n_window, cfg.window_heads),
         "mla": {
             **(
                 {
@@ -549,12 +615,13 @@ def _groups(n_lanes: int, *arrays):
     return [(a[:, :-n_lanes], a[0, -n_lanes:, None]) for a in arrays]
 
 
-def _put_groups(arena, rows, idx, slot, positions, n_lanes: int):
+def _put_groups(arena, rows, idx, slot, positions, n_lanes: int, at=lambda p: p):
     """Both groups' new rows into layer ``idx`` of a positional stack, each at
     its own ``(lane, position)`` and before either group is read: the
-    chunk's at arena row ``slot``, the lanes' at rows ``0 .. B``."""
+    chunk's at arena row ``slot``, the lanes' at rows ``0 .. B``. ``at``: the
+    row of a position (a ring's ``p mod R``)."""
     (rows_c, rows_l), (pos_c, pos_l) = _groups(n_lanes, rows.astype(arena.dtype), positions)
-    return arena.at[idx, slot, pos_c].set(rows_c).at[idx, jnp.arange(n_lanes)[:, None], pos_l].set(rows_l)
+    return arena.at[idx, slot, at(pos_c)].set(rows_c).at[idx, jnp.arange(n_lanes)[:, None], at(pos_l)].set(rows_l)
 
 
 def _by_group(attend, n_lanes: int, q, positions, valid, slot):
@@ -713,41 +780,82 @@ def gdn_mixer(h, lp, cfg: ModelConfig, state, conv, idx, slot, valid, plan: Hybr
     return _proj(o.reshape(b, t, nh * dv).astype(h.dtype), lp["wo"]), state, conv
 
 
-def full_mixer(h, lp, cfg: ModelConfig, ck, cv, idx, slot, positions, valid, plan: HybridPlan, n_lanes: int = 0):
+def _attn_rotate(x, positions, cfg: ModelConfig, kind: str):
+    """``x [B, T, n, hd]`` float32 with the rotary embedding of a ``kind``
+    layer: a "swa" layer's plain rotate-half over the whole head
+    (``cfg.swa_rope_theta``); a "full" layer's over the first
+    ``cfg.rotary_dim`` dims, with YaRN's frequencies for a head that wide and
+    cos and sin scaled (``ops/rope.apply_rope``). Unrotated where the kind's
+    base is 0."""
+    if kind == WINDOW_KIND:
+        return apply_rope(x, positions, cfg.swa_rope_theta) if cfg.swa_rope_theta else x
+    if not cfg.rope_theta:
+        return x
+    if cfg.rope_factor == 1.0 and cfg.rope_partial == 1.0 and cfg.rope_attention_factor == 1.0:
+        return apply_rope(x, positions, cfg.rope_theta)
+    with jax.named_scope("rope_partial"):
+        inv_freq = None
+        if cfg.rope_factor > 1.0:
+            inv_freq = yarn_frequencies(
+                cfg.rotary_dim, cfg.rope_theta, cfg.rope_factor, cfg.rope_original_max,
+                cfg.rope_beta_fast, cfg.rope_beta_slow,
+            )
+        return apply_rope(
+            x, positions, cfg.rope_theta, inv_freq=inv_freq, rotary_dim=cfg.rotary_dim,
+            scale=cfg.rope_attention_factor,
+        )
+
+
+def full_mixer(
+    h, lp, cfg: ModelConfig, ck, cv, idx, slot, positions, valid, plan: HybridPlan, n_lanes: int = 0,
+    kind: str = "full", arena_len: int | None = None,
+):
     """``h [B, T, d]`` → softmax attention's output and the K/V stacks with
     this step's rows written at their positions (rows past S drop). The rows
     hold :func:`stored_kv_heads` heads; the query is padded to match and the
     padding's output dropped. A lane that does not step attends to one row
-    instead of all S, like :func:`mla_mixer`'s; ``n_lanes`` as there."""
+    instead of all S, like :func:`mla_mixer`'s; ``n_lanes`` as there.
+
+    ``kind == "swa"``: ``ck``, ``cv`` are the ring leaves, a position's row is
+    ``models/llama._ring_index`` (``arena_len``: the global leaf's length,
+    where parked lanes sit and whose writes a ring drops) and a row sees its
+    last ``cfg.window`` positions (the kernels' and the reference's
+    ``window=``). The kind's own head count, rotary embedding and, under
+    ``cfg.attn_gate``, a sigmoid gate a head on the output."""
     from ..ops import attention as attn_ops
+    from .llama import _ring_index
 
     b, t, _ = h.shape
-    nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    nh, nkv, hd = cfg.heads_of(kind), cfg.n_kv_heads, cfg.head_dim
     group, stored = nh // nkv, ck.shape[3]
     q, k, v = _proj(h, lp["wq"]), _proj(h, lp["wk"]), _proj(h, lp["wv"])
     if cfg.qk_norm:  # over the whole projection, before the heads are split
         q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
         k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
     q, k, v = q.reshape(b, t, nh, hd), k.reshape(b, t, nkv, hd), v.reshape(b, t, nkv, hd)
-    if cfg.rope_theta:
-        from ..ops.rope import apply_rope
-
-        q, k = apply_rope(q, positions, cfg.rope_theta), apply_rope(k, positions, cfg.rope_theta)
+    q, k = _attn_rotate(q, positions, cfg, kind), _attn_rotate(k, positions, cfg, kind)
     heads = lambda a, n: jnp.pad(a.astype(h.dtype), [(0, 0), (0, 0), (0, n - a.shape[2]), (0, 0)])  # noqa: E731
-    impl = attn_ops.pallas_dense if plan.full_decode.startswith("pallas:") else attn_ops._reference_dense
+    pallas = getattr(plan, kind + "_decode").startswith("pallas:")
+    impl = attn_ops.pallas_dense if pallas else attn_ops._reference_dense
+    at, kw = (lambda p: p), {}
+    if kind == WINDOW_KIND:
+        at, kw = (lambda p: _ring_index(p, ck.shape[2], arena_len)), {"window": cfg.window}
 
     def attend(q, positions, valid, slot):
         seen = jnp.where(valid, positions, 0) if positions.shape[1] == 1 else positions
-        return impl(heads(q, stored * group), ck, cv, seen, None, idx, slot)[:, :, :nh]
+        return impl(heads(q, stored * group), ck, cv, seen, None, idx, slot, **kw)[:, :, :nh]
 
     if n_lanes:
-        ck = _put_groups(ck, heads(k, stored), idx, slot, positions, n_lanes)
-        cv = _put_groups(cv, heads(v, stored), idx, slot, positions, n_lanes)
+        ck = _put_groups(ck, heads(k, stored), idx, slot, positions, n_lanes, at)
+        cv = _put_groups(cv, heads(v, stored), idx, slot, positions, n_lanes, at)
     else:
         lanes = jnp.arange(b)[:, None] + (0 if slot is None else slot)
-        ck = ck.at[idx, lanes, positions].set(heads(k, stored).astype(ck.dtype))
-        cv = cv.at[idx, lanes, positions].set(heads(v, stored).astype(cv.dtype))
+        ck = ck.at[idx, lanes, at(positions)].set(heads(k, stored).astype(ck.dtype))
+        cv = cv.at[idx, lanes, at(positions)].set(heads(v, stored).astype(cv.dtype))
     o = _by_group(attend, n_lanes, q, positions, valid, slot)
+    if cfg.attn_gate:
+        with jax.named_scope("attn_gate"):
+            o = o.astype(jnp.float32) * jax.nn.sigmoid(_proj(h, lp["wg"]))[..., None]
     return _proj(o.reshape(b, t, nh * hd).astype(h.dtype), lp["wo"]), ck, cv
 
 
@@ -885,14 +993,18 @@ def forward(
     B: the engine parks it at the arena's last row while it prefills, at or
     past its ``stop``, so its step is not valid and a linear mixer's state and
     conv of that lane are the chunk's alone."""
-    from .llama import _moe_mlp, _moe_mlp_sorted, moe_sorts
+    from .llama import _moe_mlp, _moe_mlp_sorted, check_ring_launch, moe_sorts
 
     b, t = tokens.shape
     plan = plan if plan is not None else plan_hybrid(cfg)
     keep_cache = cache is not None
     if cache is None:
-        cache = init_cache(cfg, b, t, params["final_norm"].dtype)
+        # a ring drops what is written at the arena's last row (a parked
+        # lane's): the arena of a model with one is a row longer than the tokens
+        cache = init_cache(cfg, b, t + 1 if cfg.n_window else t, params["final_norm"].dtype)
     stop = cache.stop
+    if cache.wk is not None:
+        check_ring_launch(cache.wk.shape[2], cache.k.shape[2], cfg.window, t)
 
     def controls(tokens, positions, lanes):
         """A one-token step's ``valid [n, 1]`` from the cache's controls, and
@@ -925,6 +1037,8 @@ def forward(
     # run, PR 30); what a matmul takes is rounded to the weights' dtype once
     act = params["final_norm"].dtype
     x = embed_lookup(params["embed"], tokens).astype(jnp.float32)
+    # a layer's index within its kind's stacks and leaves ("swa" layers are
+    # the ones that are not positional where the model has no linear mixer)
     kinds = np.array([k in POSITIONAL_KINDS for k in cfg.layer_kinds])
     mixer_idx = np.where(kinds, np.cumsum(kinds) - 1, np.cumsum(~kinds) - 1).astype(np.int32)
     dense = np.arange(cfg.n_layers) < cfg.n_dense_layers
@@ -945,8 +1059,9 @@ def forward(
     shared = {k: v for k, v in (moe_stack or {}).items() if k.startswith("ws_")}
     lin_kind, pos_kind = cfg.linear_kind, cfg.positional_kind
 
-    def mixer(h, rows, state, conv, is_pos, idx):
-        """``rows``: the positional leaves (``(latent,)`` or ``(k, v)``)."""
+    def mixer(h, rows, ring, state, conv, is_pos, idx):
+        """``rows``: the positional leaves (``(latent,)`` or ``(k, v)``);
+        ``ring``: the window layers' (``(wk, wv)`` or nothing)."""
 
         def linear(state, conv):
             fn = kda_mixer if lin_kind == "kda" else gdn_mixer
@@ -957,8 +1072,28 @@ def forward(
             y, *rows = fn(h, _layer_of(params[pos_kind], idx), cfg, *rows, idx, slot, positions, valid, plan, n_lanes)
             return y, tuple(rows)
 
+        def windowed(ring):
+            with jax.named_scope("attn_window"):
+                y, *ring = full_mixer(
+                    h, _layer_of(params[WINDOW_KIND], idx), cfg, *ring, idx, slot, positions, valid, plan,
+                    n_lanes, kind=WINDOW_KIND, arena_len=rows[0].shape[2],
+                )
+            return y, tuple(ring)
+
+        def on_global(rows):
+            with jax.named_scope("attn_global"):
+                return positional(rows)
+
         if pos_kind is None:
             y, state, conv = linear(state, conv)
+        elif ring:
+            # full beside swa: either kind as a loop of 0 or 1 trips over the
+            # leaves it updates, as a linear mixer beside a positional one below
+            trips = is_pos.astype(jnp.int32)
+            y, rows = lax.fori_loop(
+                0, trips, lambda _, c: on_global(c[1]), (jnp.zeros(h.shape, jnp.float32), rows)
+            )
+            y, ring = lax.fori_loop(0, 1 - trips, lambda _, c: windowed(c[1]), (y, ring))
         elif lin_kind is None:
             y, rows = positional(rows)
         else:
@@ -973,7 +1108,7 @@ def forward(
                 0, 1 - trips, lambda _, c: linear(c[1], c[2]), (jnp.zeros(h.shape, jnp.float32), state, conv)
             )
             y, rows = lax.fori_loop(0, trips, lambda _, c: positional(c[1]), (y, rows))
-        return y, rows, state, conv
+        return y, rows, ring, state, conv
 
     def ffn(h32, is_dense, idx):
         def dense_ffn(h32):
@@ -1002,26 +1137,28 @@ def forward(
         return lax.cond(is_dense, dense_ffn, moe_ffn, h32)
 
     def layer_step(carry, xs):
-        x, rows, state, conv = carry
+        x, rows, ring, state, conv = carry
         attn_norm, mlp_norm, is_pos, m_idx, is_dense, f_idx = xs
         if cfg.post_norm:
             # the OLMo-2 placement: each sublayer reads the stream as it is
             # and the residual adds its NORMED output
-            y, rows, state, conv = mixer(x.astype(act), rows, state, conv, is_pos, m_idx)
+            y, rows, ring, state, conv = mixer(x.astype(act), rows, ring, state, conv, is_pos, m_idx)
             x = x + rms_norm(y.astype(jnp.float32), attn_norm, cfg.norm_eps)
             x = x + rms_norm(ffn(x, is_dense, f_idx).astype(jnp.float32), mlp_norm, cfg.norm_eps)
-            return (x, rows, state, conv), None
+            return (x, rows, ring, state, conv), None
         h = rms_norm(x, attn_norm, cfg.norm_eps).astype(act)
-        y, rows, state, conv = mixer(h, rows, state, conv, is_pos, m_idx)
+        y, rows, ring, state, conv = mixer(h, rows, ring, state, conv, is_pos, m_idx)
         x = x + y.astype(jnp.float32)
         x = x + ffn(rms_norm(x, mlp_norm, cfg.norm_eps), is_dense, f_idx).astype(jnp.float32)
-        return (x, rows, state, conv), None
+        return (x, rows, ring, state, conv), None
 
     xs = (
         params["layers"]["attn_norm"], params["layers"]["mlp_norm"],
         jnp.asarray(kinds), jnp.asarray(mixer_idx), jnp.asarray(dense), jnp.asarray(ffn_idx, jnp.int32),
     )
-    (x, rows, state, conv), _ = lax.scan(layer_step, (x, cache.rows(), cache.state, cache.conv), xs)
+    (x, rows, ring, state, conv), _ = lax.scan(
+        layer_step, (x, cache.rows(), cache.ring(), cache.state, cache.conv), xs
+    )
     if n_lanes:
         # the head's rows: the chunk's ``last`` and the lanes', not T + B
         x = jnp.concatenate([lax.dynamic_slice_in_dim(x[0], last, 1, 0), x[0, t:]], axis=0)
@@ -1030,4 +1167,5 @@ def forward(
     if not keep_cache:
         return logits, None
     named = dict(zip([n for n in cache.POSITIONAL if getattr(cache, n) is not None], rows))
+    named.update(zip(cache.RING, ring))
     return logits, cache._replace(**named, state=state, conv=conv, stop=stop)

@@ -105,6 +105,17 @@ def ring_rows(window: int, max_seq: int, launch_rows: int | None = None, block: 
     return min(up(window + launch_rows), up(max_seq))
 
 
+def check_ring_launch(ring_len: int, arena_len: int, window: int, run: int) -> None:
+    """Refuse a launch that carries ``run`` rows of one lane into a ring of
+    ``ring_len`` rows sized for fewer (:func:`ring_rows`'s bound; a ring as
+    long as the arena never wraps and takes any launch)."""
+    if ring_len < arena_len and run > ring_len - window + 1:
+        raise ValueError(
+            f"a launch of {run} rows a lane would write over rows its own queries see: "
+            f"the ring holds {ring_len} rows for a window of {window} (ring_rows)"
+        )
+
+
 def ring_plan(cfg: ModelConfig, dtype, launch_rows: int) -> dict:
     """``init_cache``'s keywords for a caller whose launches carry at most
     ``launch_rows`` rows of one lane (an engine's prefill chunk, bucket padding
@@ -116,7 +127,7 @@ def ring_plan(cfg: ModelConfig, dtype, launch_rows: int) -> dict:
     from ..ops.attention import pallas_available
     from ..ops.pallas_attention import ring_block
 
-    kernels = pallas_available(cfg.n_heads, cfg.n_kv_heads, cfg.head_dim)[0]
+    kernels = pallas_available(cfg.window_heads, cfg.n_kv_heads, cfg.head_dim)[0]
     return {
         "launch_rows": launch_rows,
         "block": ring_block(cfg.n_kv_heads, cfg.head_dim, dtype) if kernels else 1,
@@ -162,7 +173,7 @@ def init_cache(
     if cfg.is_hybrid:
         from . import hybrid
 
-        return hybrid.init_cache(cfg, lanes, max_seq, dtype, live=live)
+        return hybrid.init_cache(cfg, lanes, max_seq, dtype, live=live, launch_rows=launch_rows, block=block)
     if cfg.n_window:
         return WindowKVCache.create(cfg, lanes, max_seq, dtype, launch_rows, block)
     return KVCache.create(cfg, lanes, max_seq, dtype=dtype)
@@ -248,7 +259,9 @@ def moe_gates(
     """The router rule, once for every MoE path: ``(gates, chosen)``, both
     ``[..., k]``, from router logits ``[..., E]``. ``moe_renormalize``
     (Mixtral): top-k of the logits, float32 softmax over the chosen k, so a
-    token's gates sum to 1. Otherwise (OLMoE, ``norm_topk_prob: false``):
+    token's gates sum to 1 (times ``moe_scale`` where the model has one: the
+    softmax over all E, its top k renormalised, is the same numbers).
+    Otherwise (OLMoE, ``norm_topk_prob: false``):
     float32 softmax over all E experts, the top k kept as they are.
     ``moe_router == "sigmoid"`` (Kimi-Linear): float32 sigmoid scores; the
     top k of score + ``bias`` (the selection bias chooses and never weighs);
@@ -264,7 +277,10 @@ def moe_gates(
         return (gates * cfg.moe_scale).astype(dtype), chosen
     if cfg.moe_renormalize:
         top, chosen = lax.top_k(logits, k)
-        return jax.nn.softmax(top.astype(jnp.float32), axis=-1).astype(dtype), chosen
+        gates = jax.nn.softmax(top.astype(jnp.float32), axis=-1)
+        if cfg.moe_scale != 1.0:  # Laguna: the renormalised gates x 2.5
+            gates = gates * cfg.moe_scale
+        return gates.astype(dtype), chosen
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     gates, chosen = lax.top_k(probs, k)
     return gates.astype(dtype), chosen
@@ -730,12 +746,7 @@ def forward(
     if switched and block_table is not None:
         raise ValueError("window and no-rope layers are served from the dense arena, not the page pool")
     if cfg.n_window and cache is not None:
-        ring_len, run = cache.wk.shape[2], tokens.shape[1]
-        if ring_len < cache.k.shape[2] and run > ring_len - cfg.window + 1:
-            raise ValueError(
-                f"a launch of {run} rows a lane would write over rows its own queries see: "
-                f"the ring holds {ring_len} rows for a window of {cfg.window} (ring_rows)"
-            )
+        check_ring_launch(cache.wk.shape[2], cache.k.shape[2], cfg.window, tokens.shape[1])
 
     def block(x, ck, cv, lp, layer, ring=None, kind=None):
         # int8-quantized weights (engine/quant.py) dequantize per layer

@@ -50,20 +50,32 @@ def yarn_frequencies(
 
 
 def apply_rope(
-    x: jnp.ndarray, positions: jnp.ndarray, theta: float, *, interleave: bool = False, inv_freq=None
+    x: jnp.ndarray, positions: jnp.ndarray, theta: float, *, interleave: bool = False, inv_freq=None,
+    rotary_dim: int | None = None, scale: float = 1.0,
 ) -> jnp.ndarray:
     """Rotate ``x`` [..., T, n_heads, head_dim] by per-token ``positions`` [..., T].
 
     Computed in float32 regardless of input dtype (bf16 angles lose precision
     at long context), cast back on return. ``inv_freq``: the frequencies where
     they are not Llama's (:func:`yarn_frequencies`); ``interleave``: adjacent
-    pairs (module docstring).
+    pairs (module docstring). ``rotary_dim``: only the FIRST so many dims of a
+    head are rotated (a partial rotary embedding: the frequencies and the
+    pairing are those of a head that wide) and the rest pass as they are;
+    ``scale`` multiplies cos and sin (YaRN's ``attention_factor``: a product
+    of two rotated halves takes it squared, the unrotated dims' not at all).
     """
+    if rotary_dim is not None and rotary_dim < x.shape[-1]:
+        rotated = apply_rope(
+            x[..., :rotary_dim], positions, theta, interleave=interleave, inv_freq=inv_freq, scale=scale
+        )
+        return jnp.concatenate([rotated, x[..., rotary_dim:]], axis=-1)
     head_dim = x.shape[-1]
     freqs = rope_frequencies(head_dim, theta) if inv_freq is None else inv_freq  # [hd/2]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., T, hd/2]
     cos = jnp.cos(angles)[..., None, :]  # [..., T, 1, hd/2]
     sin = jnp.sin(angles)[..., None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     if interleave:
         pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (head_dim // 2, 2))
         x1, x2 = pairs[..., 0], pairs[..., 1]

@@ -555,6 +555,36 @@ def test_hybrid_mixed_step_with_a_linear_mixer_steps_each_stack_a_group_at_a_tim
     assert any("want the chunk's lane and the step's lanes" in p for p in contract.failures(chunk_alone))
 
 
+@pytest.mark.parametrize("weights", ["float", "int8"])
+def test_hybrid_mixed_step_over_two_kinds_of_attention_carries_four_leaves(hybrid_engine, weights):
+    """``tiny-laguna``'s ``jit_prefill_with_decode`` (ISSUE 50: full attention
+    beside sliding-window attention, no linear mixer): ``k`` and ``v`` and the
+    ring's ``wk`` and ``wv`` ride in the layer loop's carry and in the
+    0-or-1-trip loop round their own kind and in no third loop (the weights,
+    the gate's and both kinds' ``wq`` / ``wo`` stacks among them, are read
+    once); each leaf takes the chunk's rows and the lanes' (the ring's at ``p
+    mod R``, a parked lane's nowhere), the head runs on ``1 + B`` rows, and the
+    donated cache and carry alias the outputs. ``int8``: 128 + 4 rows are over
+    the 121-row cut, so ONE grouped FFN takes both groups' rows."""
+    eng = hybrid_engine("tiny-laguna", weights)
+    B, t, c = eng.max_batch, 128, eng.cache
+    assert eng._prefill_with_decode is not None and c.state is None and c.latent is None
+    assert c.wk.shape[2] == eng.cfg.window + HYBRID_OPTIONS["prefill_chunk"] < c.k.shape[2]
+    lowered = _mixed_lowering(eng, t)
+    text = lowered.as_text()
+    assert "module @jit_prefill_with_decode" in text
+    stacks = {n: a.shape for n, a in c.leaves().items()}
+    assert sorted(stacks) == ["k", "v", "wk", "wv"]
+    contract = MixedStepOverStacks(stacks, chunk=t, lanes=B, vocab=eng.cfg.vocab_size, loops=2)
+    check(text, contract)
+    check(lowered.compile().as_text(), DonationAliased(min_count=4 + len(stacks)))
+    chunk_alone = _moe_step_lowering(eng, f"jit_prefill.{t}").as_text()
+    assert contract.failures(chunk_alone)  # the contract tells the mixed step from a chunk alone
+    if weights == "int8":
+        grouped = lambda fn, args: str(fn.trace(*args).jaxpr).count("ragged_dot")  # noqa: E731
+        assert grouped(eng._prefill_with_decode, _mixed_args(eng, t)) == grouped(eng._prefill, _prefill_args(eng, t)) > 0
+
+
 # ---------------------------------------------------------------------------
 # recompile budget over the scripted mixed workload
 

@@ -88,6 +88,9 @@ ASSIGNED = {
     # the hybrid block with latent attention alone is the hybrid block (PR 40)
     "mistral-small-4-119b": [(1, 1), (1, 1), (1, 1), (1, 1)],
     "tiny-mistral4": [(1, 1), (1, 1), (1, 1), (1, 1)],
+    # and with two kinds of attention and a ring in its cache (PR 50)
+    "laguna-xs.2": [(1, 1), (1, 1), (1, 1), (1, 1)],
+    "tiny-laguna": [(1, 1), (1, 1), (1, 1), (1, 1)],
 }
 
 

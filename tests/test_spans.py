@@ -631,6 +631,62 @@ def test_an_olmo_hybrid_engine_names_its_kinds_its_leaves_and_what_a_snapshot_sh
     assert int(dict(p.split("=") for p in carried["engine.restore"].split(","))["state"]) == 6 * 12 * 6 * 24 * 4
 
 
+# -- two kinds of attention in one model: k, v and a ring in one slot (Laguna) -------
+def test_a_laguna_engine_names_both_kinds_the_gate_the_rotaries_and_the_rings_bytes():
+    """Names the benchmark's readers and a reader of a capture rely on:
+    ``model_arch.layer_kinds`` with ``full`` and ``swa``; ``attention.heads``
+    and ``attention.rotary`` by kind, ``attention.gate``, ``attention.{full,
+    swa}_{prefill,decode}``; ``cache`` bytes by leaf with the ring (``wk``,
+    ``wv``) beside ``k`` and ``v`` and no state; the window and position
+    counters and the ``moe`` block counted by this engine; ``engine.snapshot``
+    / ``engine.restore`` spans carrying ``bytes=`` for the ring, which ships
+    whole; the ``jax.named_scope``s of the layer body in the step's metadata."""
+    options = {"max_batch": 2, "max_seq": 256, "prefill_chunk": 32}
+    eng = LLMEngine.create("tiny-laguna", options=options)
+    seen = []
+    span = eng._spans.span
+    eng._spans.span = lambda name, **attrs: (seen.append((name, attrs)), span(name, **attrs))[1]
+    try:
+        async def drive():
+            await eng.chat("s", "a session whose context laps the ring of a window layer before it is snapshotted", max_tokens=9)
+            eng.snapshot_min_gap_s = eng.snapshot_busy_gap_s = 0.0
+            blob = await eng.snapshot_session("s")
+            return await eng.restore_session("t", blob)
+
+        assert asyncio.run(drive()) is True
+        time.sleep(0.2)
+        m = eng.metrics()
+        tokens = jnp.zeros((1, 32), jnp.int32)
+        text = eng._prefill.lower(eng.params, eng.cache, jnp.int32(0), tokens, tokens, jnp.int32(4)).as_text(debug_info=True)
+    finally:
+        eng.shutdown()
+    a, cache = m["attention"], m["cache"]
+    assert m["model_arch"]["layer_kinds"] == {"full": 2, "swa": 6}
+    assert a["heads"] == {"full": 6, "swa": 8} and a["gate"] == "per_head" and sorted(a["rotary"]) == ["full", "swa"]
+    for key in ("full_prefill", "full_decode", "swa_prefill", "swa_decode", "reason"):
+        assert a[key], key
+    assert cache["kinds"] == ["k", "v", "wk", "wv"] and all(cache[leaf + "_bytes"] > 0 for leaf in cache["kinds"])
+    for key in ("window_wraps", "window_decode_blocks_live", "window_decode_blocks_unbounded", "global_decode_rows",
+                "window_decode_rows", "rows_positioned", "rows_past_original_max", "decode_blocks_live", "decode_blocks_stored"):
+        assert a[key] > 0, key
+    for key in ("experts_held", "shared_experts", "router", "assignments", "rows_routed", "rows_all_experts"):
+        assert key in m["moe"], key
+    for name in ("engine.snapshot", "engine.restore", "engine.state_reset"):
+        assert m["phases"][name]["n"] >= 1, (name, sorted(m["phases"]))
+    assert cache["state_resets"] == 0  # the lane was opened for its request; there is no state to zero
+    carried = {name: attrs["bytes"] for name, attrs in seen if name in ("engine.snapshot", "engine.restore") and "bytes" in attrs}
+    assert set(carried) == {"engine.snapshot", "engine.restore"}
+    for text_ in carried.values():
+        sizes = {k: int(v) for k, v in (part.split("=") for part in text_.split(","))}
+        assert sorted(sizes) == ["k", "v", "wk", "wv"]
+        assert sizes["wk"] == sizes["wv"] == 6 * (16 + 32) * 2 * 16 * 4  # the ring whole: 6 layers x R rows, float32 here
+        assert sizes["k"] == sizes["v"] and sizes["k"] % (2 * 2 * 16 * 4) == 0  # rows of 2 layers x 2 heads of 16
+    rows = {name: int(dict(p.split("=") for p in text_.split(","))["k"]) // (2 * 2 * 16 * 4) for name, text_ in carried.items()}
+    assert rows["engine.snapshot"] == 128 and 48 < rows["engine.restore"] <= 128  # the position's bucket; the rows kept
+    for scope in ("attn_global", "attn_window", "attn_gate", "rope_partial", "moe_shared_expert"):
+        assert scope in text, scope
+
+
 def test_attention_names_the_prefill_tile_where_the_flash_kernel_serves(monkeypatch):
     """``/metrics`` ``attention.prefill_tile`` (ISSUE 49): where the plan says
     ``pallas:flash_prefill`` over the dense arena (a TPU engine; here the plan

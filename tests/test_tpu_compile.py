@@ -377,6 +377,11 @@ def _hybrid_case(model: str, where):
             get_config("mistral-small-4-119b"), n_layers=9, layer_kinds=("mla",) * 9, experts_held=32,
             max_seq_len=seq, name=model,
         )
+    elif model == "laguna-40l":
+        # the served share whole: 40 layers, 32 of 256 experts, the whole
+        # vocabulary, 8 lanes of 16,384 (benchmark/configs/laguna-xs2-33b-ep8-1chip.json)
+        lanes, seq = 8, 16_384
+        cfg = dataclasses.replace(get_config("laguna-xs.2"), experts_held=32, max_seq_len=seq, name=model)
     else:
         lanes, seq = 8, 4096
         cfg = dataclasses.replace(
@@ -385,7 +390,14 @@ def _hybrid_case(model: str, where):
         )
     place = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=where)  # noqa: E731
     params = jax.tree.map(place, jax.eval_shape(lambda: synthetic_quantized_params(cfg, jnp.bfloat16)))
-    cache = jax.tree.map(place, jax.eval_shape(lambda: init_cache(cfg, lanes, seq, jnp.bfloat16, live=False)))
+    ring = {}
+    if cfg.n_window:
+        # the engine's ring (``llama.ring_plan`` where the kernels serve): a
+        # chunk of 256 on top of the window, whole K/V blocks
+        from agentainer_tpu.ops.pallas_attention import ring_block
+
+        ring = {"launch_rows": 256, "block": ring_block(cfg.n_kv_heads, cfg.head_dim, jnp.bfloat16)}
+    cache = jax.tree.map(place, jax.eval_shape(lambda: init_cache(cfg, lanes, seq, jnp.bfloat16, live=False, **ring)))
     plan = hybrid.plan_hybrid(cfg, use_pallas=True)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=where)  # noqa: E731
 
@@ -552,6 +564,61 @@ def test_mistral4_step_fits_the_chip_and_reads_the_latent_stack_in_place_on_v5e(
           f"out {mem.output_size_in_bytes / 1e9:.2f} GB, aliased {mem.alias_size_in_bytes / 1e9:.2f} GB, live {live / 1e9:.2f} GB")
     assert 10.0e9 < live < V5E_USABLE_BYTES
     assert cfg.param_count() > 8.7e9  # the share, not a cut vocabulary
+
+
+LAGUNA_CONFIG = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmark", "configs", "laguna-xs2-33b-ep8-1chip.json")
+
+
+@pytest.mark.parametrize("step, kernels", [("decode", 2), ("prefill", 3), ("mixed", 5)])
+def test_laguna_step_fits_the_chip_and_keeps_every_leaf_in_place_on_v5e(v5e, monkeypatch, step, kernels):
+    """Laguna-XS.2's served share at its REAL size (40 layers, 32 of 256
+    experts, the whole vocabulary, 8 lanes of 16,384, int8 as served), its
+    kernels on: Mosaic accepts ``flash_prefill`` / ``flash_decode`` at 48 / 8
+    heads (GQA-6, the full layers' global leaf ``[10, 8, 16384, 8, 128]``) and
+    at 64 / 8 with ``window=`` (GQA-8, the sliding layers' ring ``[30, 8, 1024,
+    8, 128]``), both kinds in ONE layer loop, each as a loop of 0 or 1 trips
+    over the leaf pair it updates; the four leaves (5.37 + 1.01 GB) are
+    donated in place and none is copied or relaid out, no weight stack is
+    copied, and the step's live bytes fit a v5e's 15.75 GB and are what the
+    configuration file states (``compiled_live_bytes``). ``mixed``: the
+    chunk's 256 rows and the 8 lanes' step through one layer loop holding one
+    call of each kernel a kind (2 + 2) and ONE grouped FFN for all 264 rows;
+    the head on 9 rows."""
+    import json
+
+    if step != "decode":  # the grouped FFN's kernel is chosen by the backend, which is the CPU here
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, cache, plan, steps = _hybrid_case("laguna-40l", SingleDeviceSharding(v5e.devices[0]))
+    assert (plan.full_prefill, plan.swa_decode) == ("pallas:flash_prefill", "pallas:flash_decode")
+    assert cache.k.shape == (10, 8, 16_384, 8, 128) and cache.wk.shape == (30, 8, 1024, 8, 128)
+    assert cache.state is None and cache.conv is None and cache.latent is None
+    fn, args = steps[step]
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    calls = re.findall(r'%([a-z_]+)[.\d]* = [^\n]*custom_call_target="tpu_custom_call"', text)
+    want = {"decode": ["flash_decode"] * 2, "prefill": ["flash_prefill"] * 2 + ["moe_grouped_ffn"],
+            "mixed": ["flash_decode"] * 2 + ["flash_prefill"] * 2 + ["moe_grouped_ffn"]}[step]
+    assert sorted(calls) == want and len(calls) == kernels, calls
+    mem = compiled.memory_analysis()
+    leaves = {n: a for n, a in cache.leaves().items()}
+    assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize for a in leaves.values())
+    for name, a in leaves.items():
+        shape = ",".join(map(str, a.shape))
+        assert not re.search(rf"\[{shape}\][^ ]* (copy|transpose)\(", text), name
+    # no weight stack copied either: the two kinds' projections and the held experts
+    for shape in ("10,2048,6144", "30,2048,8192", "30,8192,2048", "39,32,2048,512", "39,32,512,2048"):
+        assert not re.search(rf"\[{shape}\][^ ]* (copy|transpose)\(", text), shape
+    if step == "mixed":
+        v = cfg.vocab_size
+        assert not re.search(rf"\[(1,)?(256|264),{v}\]", text) and re.search(rf"f32\[9,{v}\]", text)
+    live = mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    print(f"laguna-40l {step}: args {mem.argument_size_in_bytes / 1e9:.2f} GB, temp {mem.temp_size_in_bytes / 1e9:.2f} GB, "
+          f"out {mem.output_size_in_bytes / 1e9:.2f} GB, aliased {mem.alias_size_in_bytes / 1e9:.2f} GB, live {live / 1e9:.2f} GB")
+    assert 12.0e9 < live < V5E_USABLE_BYTES
+    with open(LAGUNA_CONFIG) as f:
+        stated = json.load(f)["memory"]["compiled_live_bytes"][step]
+    assert abs(live - stated) < 0.02 * stated, (live, stated)
+    assert abs(cfg.param_count() - 5_961_517_056) == 0  # the share: 5.96 GB of int8 weights
 
 
 @pytest.mark.parametrize("step", ["decode", "prefill", "mixed"])

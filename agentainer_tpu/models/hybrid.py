@@ -92,6 +92,7 @@ chunk's own lane is among them, parked at or past its
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -821,14 +822,22 @@ def full_mixer(
     where parked lanes sit and whose writes a ring drops) and a row sees its
     last ``cfg.window`` positions (the kernels' and the reference's
     ``window=``). The kind's own head count, rotary embedding and, under
-    ``cfg.attn_gate``, a sigmoid gate a head on the output."""
+    ``cfg.attn_gate``, a sigmoid gate a head on the output.
+
+    Every model that has this mixer runs it as the body of its kind's
+    0-or-1-trip loop (:func:`forward`'s ``of_kind``), where the projections
+    stay beside what reads them: the barrier after them keeps the compiler
+    from fusing the norm and the head split into the matmul's output and then
+    wanting the weights the other way round (compiled for a described v5e,
+    PR 51: it transposes the whole ``wq`` stack once a launch, 629 MB for
+    Laguna's two)."""
     from ..ops import attention as attn_ops
     from .llama import _ring_index
 
     b, t, _ = h.shape
     nh, nkv, hd = cfg.heads_of(kind), cfg.n_kv_heads, cfg.head_dim
     group, stored = nh // nkv, ck.shape[3]
-    q, k, v = _proj(h, lp["wq"]), _proj(h, lp["wk"]), _proj(h, lp["wv"])
+    q, k, v = lax.optimization_barrier((_proj(h, lp["wq"]), _proj(h, lp["wk"]), _proj(h, lp["wv"])))
     if cfg.qk_norm:  # over the whole projection, before the heads are split
         q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
         k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
@@ -870,7 +879,7 @@ def _mla_rotate(x, positions, cfg: ModelConfig):
     return apply_rope(x, positions, cfg.rope_theta, interleave=cfg.rope_interleave, inv_freq=inv_freq)
 
 
-def _mla_query(h, lp, cfg: ModelConfig, positions):
+def _mla_query(h, lp, cfg: ModelConfig, positions, in_loop: bool = False):
     """The layer's queries ``[B, T, H, nope + r]`` in the weights' dtype, as
     the absorption takes them. A full-rank ``wq`` with nothing after it
     (Kimi-Linear) is rounded where it leaves the matmul; otherwise the
@@ -884,6 +893,8 @@ def _mla_query(h, lp, cfg: ModelConfig, positions):
             q = _proj(c_q, lp["wq_b"])
     else:
         q = _proj(h, lp["wq"])
+    if in_loop:
+        q = lax.optimization_barrier(q)
     if not (cfg.mla_rotary or cfg.q_pos_scale_beta):
         return q.astype(h.dtype).reshape(shape)
     q = q.reshape(shape)
@@ -900,7 +911,10 @@ def _mla_query(h, lp, cfg: ModelConfig, positions):
     return q.astype(h.dtype)
 
 
-def mla_mixer(h, lp, cfg: ModelConfig, latent, idx, slot, positions, valid, plan: HybridPlan, n_lanes: int = 0):
+def mla_mixer(
+    h, lp, cfg: ModelConfig, latent, idx, slot, positions, valid, plan: HybridPlan, n_lanes: int = 0,
+    in_loop: bool = False,
+):
     """``h [B, T, d]`` (normed) → the mixer's output and the latent stack with
     this step's rows written at their positions (rows past S drop). A lane
     that does not step (``valid`` false: parked at the arena's last row)
@@ -917,10 +931,13 @@ def mla_mixer(h, lp, cfg: ModelConfig, latent, idx, slot, positions, valid, plan
     rotation run over all of them at once; both groups' rows are written
     before either is read, and each group's attention is the call its own
     launch would make: the chunk's over lane ``slot``, the lanes' over their
-    own."""
+    own. ``in_loop``: the call is the body of this kind's 0-or-1-trip loop
+    (:func:`forward`'s ``of_kind``) and takes :func:`full_mixer`'s barrier
+    after the query's ``wq``; a model with this mixer alone (Mistral-Small-4)
+    has no such loop, and its programs stay what they were."""
     b, t, _ = h.shape
     nh, rank, nope = cfg.n_heads, cfg.mla_kv_rank, cfg.mla_nope_dim
-    q = _mla_query(h, lp, cfg, positions)
+    q = _mla_query(h, lp, cfg, positions, in_loop)
     ckv = _proj(h, lp["wkva"])
     pad = jnp.zeros((b, t, latent.shape[-1] - ckv.shape[-1]), ckv.dtype)
     c, k_s = rms_norm(ckv[..., :rank], lp["kv_norm"], cfg.norm_eps), ckv[..., rank:]
@@ -1063,52 +1080,65 @@ def forward(
         """``rows``: the positional leaves (``(latent,)`` or ``(k, v)``);
         ``ring``: the window layers' (``(wk, wv)`` or nothing)."""
 
-        def linear(state, conv):
+        def linear(h, idx, state, conv):
             fn = kda_mixer if lin_kind == "kda" else gdn_mixer
             return fn(h, _layer_of(params[lin_kind], idx), cfg, state, conv, idx, slot, valid, plan, n_lanes)
 
-        def positional(rows):
-            fn = mla_mixer if pos_kind == "mla" else full_mixer
-            y, *rows = fn(h, _layer_of(params[pos_kind], idx), cfg, *rows, idx, slot, positions, valid, plan, n_lanes)
-            return y, tuple(rows)
+        def positional(h, idx, *rows, in_loop=False):
+            lp = _layer_of(params[pos_kind], idx)
+            if pos_kind == "mla":
+                return mla_mixer(h, lp, cfg, *rows, idx, slot, positions, valid, plan, n_lanes, in_loop=in_loop)
+            return full_mixer(h, lp, cfg, *rows, idx, slot, positions, valid, plan, n_lanes)
 
-        def windowed(ring):
+        def windowed(h, idx, *ring):
             with jax.named_scope("attn_window"):
-                y, *ring = full_mixer(
+                return full_mixer(
                     h, _layer_of(params[WINDOW_KIND], idx), cfg, *ring, idx, slot, positions, valid, plan,
                     n_lanes, kind=WINDOW_KIND, arena_len=rows[0].shape[2],
                 )
-            return y, tuple(ring)
 
-        def on_global(rows):
+        def on_global(h, idx, *rows):
             with jax.named_scope("attn_global"):
-                return positional(rows)
+                return positional(h, idx, *rows)
+
+        def of_kind(fn, trips, y, *leaves):
+            """``fn``, one kind's mixer, as a loop of 0 or 1 trips over the
+            leaves it updates; where it does not trip, ``(y, *leaves)`` as
+            they came. A ``lax.cond`` would do, but XLA copies what a branch
+            passes through untouched: the other kind's whole stack, every
+            layer (compiled for a described v5e: 2.7 GB of state copied in
+            each MLA layer). A while loop's carry stays one buffer whether it
+            trips or not, like the layer scan's own. What the body computes
+            from ``h`` and from layer ``idx`` of its kind's weight stacks
+            depends on nothing the loop carries, so XLA lifts it out of the
+            loop into the scan's body, where it runs in the OTHER kind's
+            layers too (compiled for a described v5e, PR 51: both kinds'
+            input projections and a copy of both ``wo`` slices in every layer
+            of three models). The barrier ties ``h`` and ``idx`` to the carry,
+            so a kind's work stays in its loop; it changes no value
+            (``tests/test_tpu_compile.py`` holds the first,
+            ``tests/test_hybrid_values.py`` the second)."""
+
+            def body(_, carry):
+                leaves, h_in, i = lax.optimization_barrier((carry[1:], h, idx))
+                return fn(h_in, i, *leaves)
+
+            return lax.fori_loop(0, trips, body, (y, *leaves))
 
         if pos_kind is None:
-            y, state, conv = linear(state, conv)
-        elif ring:
-            # full beside swa: either kind as a loop of 0 or 1 trips over the
-            # leaves it updates, as a linear mixer beside a positional one below
-            trips = is_pos.astype(jnp.int32)
-            y, rows = lax.fori_loop(
-                0, trips, lambda _, c: on_global(c[1]), (jnp.zeros(h.shape, jnp.float32), rows)
-            )
-            y, ring = lax.fori_loop(0, 1 - trips, lambda _, c: windowed(c[1]), (y, ring))
-        elif lin_kind is None:
-            y, rows = positional(rows)
+            y, state, conv = linear(h, idx, state, conv)
+        elif lin_kind is None and not ring:
+            y, *rows = positional(h, idx, *rows)
         else:
-            # Either mixer as a loop of 0 or 1 trips over the stacks it
-            # updates. A ``lax.cond`` would do, but XLA copies what a branch
-            # passes through untouched: the other kind's whole stack, every
-            # layer (compiled for a described v5e: 2.7 GB of state copied in
-            # each MLA layer). A while loop's carry stays one buffer whether
-            # it trips or not, like the layer scan's own.
+            # two kinds of mixer: full beside swa, or a linear one beside a positional one
             trips = is_pos.astype(jnp.int32)
-            y, state, conv = lax.fori_loop(
-                0, 1 - trips, lambda _, c: linear(c[1], c[2]), (jnp.zeros(h.shape, jnp.float32), state, conv)
-            )
-            y, rows = lax.fori_loop(0, trips, lambda _, c: positional(c[1]), (y, rows))
-        return y, rows, ring, state, conv
+            if ring:
+                y, *rows = of_kind(on_global, trips, jnp.zeros(h.shape, jnp.float32), *rows)
+                y, *ring = of_kind(windowed, 1 - trips, y, *ring)
+            else:
+                y, state, conv = of_kind(linear, 1 - trips, jnp.zeros(h.shape, jnp.float32), state, conv)
+                y, *rows = of_kind(functools.partial(positional, in_loop=True), trips, y, *rows)
+        return y, tuple(rows), tuple(ring), state, conv
 
     def ffn(h32, is_dense, idx):
         def dense_ffn(h32):

@@ -79,6 +79,44 @@ def _jit_map_guard():
         gc.collect()
 
 
+@pytest.fixture
+def barrier_is_identity(monkeypatch):
+    """``lax.optimization_barrier`` as the identity it computes, for a test
+    that shows a barrier is ALL a change added to a program: what is traced
+    under it has to be the program that was there before. Traces are cached
+    by function and shapes, so the caches are dropped on both sides."""
+    jax.clear_caches()
+    monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
+    yield
+    jax.clear_caches()
+
+
+@pytest.fixture
+def where_llvm_may_not_reorder():
+    """``run(code)``: Python ``code`` in a process of its own (the suite's
+    platform and flags, ``tests.conftest`` imported first) in which XLA:CPU
+    cannot round one arithmetic two ways: SSE4.2 has no fused multiply-add
+    for LLVM to contract ``a * b + c`` into, and at -O0 LLVM reorders none of
+    the sums XLA marks ``reassoc``. Flags are read once a process, hence the
+    process; it has to exit 0."""
+    import platform
+    import subprocess
+    import sys
+
+    if platform.machine() not in ("x86_64", "AMD64"):
+        pytest.skip(f"--xla_cpu_max_isa names x86 instruction sets; this host is {platform.machine()}")
+
+    def run(code: str):
+        flags = f"{os.environ['XLA_FLAGS']} --xla_cpu_max_isa=SSE4_2 --xla_backend_optimization_level=0"
+        done = subprocess.run(
+            [sys.executable, "-c", "import tests.conftest\n" + code], cwd=os.path.dirname(os.path.dirname(__file__)),
+            env={**os.environ, "XLA_FLAGS": flags}, capture_output=True, text=True, timeout=600,
+        )
+        assert done.returncode == 0, done.stderr[-3000:]
+
+    return run
+
+
 def _native_available() -> bool:
     try:
         from agentainer_tpu.native import available

@@ -444,6 +444,10 @@ HYBRID_OPTIONS = {
 HYBRID_PARENT_PROGRAMS = json.load(
     open(os.path.join(os.path.dirname(__file__), "data", "hybrid_step_programs_parent_pr40.json"))
 )
+# the two-kind engines' programs since PR 51 (the parent's and a barrier in each kind's loop)
+HYBRID_MOVED_PROGRAMS = json.load(
+    open(os.path.join(os.path.dirname(__file__), "data", "step_programs_pr51.json"))
+)["without_lanes"]
 
 
 @pytest.fixture(scope="module")
@@ -473,9 +477,28 @@ def test_hybrid_steps_without_lanes_lower_to_the_parents_programs(hybrid_engine,
     without them in the order it had, the window put back after the state's
     update), and a call without them traces what it traced. On the chip an
     edit above a Pallas call still re-compiles every program that holds one
-    (PERF.md section 7): that is a first start's cost, not this one's."""
+    (PERF.md section 7): that is a first start's cost, not this one's.
+
+    Since PR 51 the two engines with TWO kinds of mixer (12 of the 18) compare
+    with ``tests/data/step_programs_pr51.json``, taken on that PR's tree: each
+    kind's 0-or-1-trip loop gained an ``optimization_barrier`` (``models/hybrid.py``
+    ``of_kind``), and nothing else, as the next test holds. ``tiny-mistral4``
+    (one kind, no such loop) is still PR 40's."""
     model, weights, program = key.split(".", 2)
     text = _moe_step_lowering(hybrid_engine(model, weights), program).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == {**HYBRID_PARENT_PROGRAMS, **HYBRID_MOVED_PROGRAMS}[key]
+
+
+@pytest.mark.parametrize("key", sorted(HYBRID_MOVED_PROGRAMS))
+def test_a_two_kind_step_less_its_barriers_lowers_to_the_parents_program(hybrid_engine, barrier_is_identity, key):
+    """What PR 51 did to a two-kind step program is the barriers and nothing
+    besides: traced with ``lax.optimization_barrier`` as the identity (which
+    is what it computes), each of the 12 moved programs is PR 40's text byte
+    for byte. So every value is the parent's; where the work runs is the
+    compiler's, and ``tests/test_tpu_compile.py`` reads that."""
+    model, weights, program = key.split(".", 2)
+    text = _moe_step_lowering(hybrid_engine(model, weights), program).as_text()
+    assert "optimization_barrier" not in text
     assert hashlib.sha256(text.encode()).hexdigest() == HYBRID_PARENT_PROGRAMS[key]
 
 

@@ -596,6 +596,8 @@ def test_the_new_parts_of_the_layer_body_are_named_in_the_lowered_step():
 # -- the six older configurations' step programs are the parent's ------------------
 
 OLDER = json.load(open(os.path.join(os.path.dirname(__file__), "data", "step_programs_parent_pr49.json")))
+# Kimi-Linear's and Olmo-Hybrid's since PR 51: the parent's and a barrier in each kind's loop
+OLDER_MOVED = json.load(open(os.path.join(os.path.dirname(__file__), "data", "step_programs_pr51.json")))["older"]
 OLDER_OPTIONS = {"max_batch": 4, "max_seq": 256, "decode_chunk": 8, "prefill_chunk": 128, "skip_warmup": True, "quant": "int8"}
 
 
@@ -613,18 +615,7 @@ def older_engine():
         eng.shutdown()
 
 
-@pytest.mark.parametrize("key", sorted(OLDER))
-def test_an_older_configurations_step_program_lowers_to_the_parents_text(older_engine, key):
-    """``jit_decode_n``, ``jit_prefill`` at bucket 128 and the mixed step of
-    the six blocks the benchmark had (Mixtral's and OLMoE's K/V block,
-    SmallThinker's ring, Kimi-Linear, Olmo-Hybrid, Mistral-Small-4), int8 as
-    served, lower to the StableHLO the parent commit (c00855d, PR 49) lowered
-    on this backend, byte for byte: sha256 of the text, taken there with
-    these lowering calls. The second positional kind, the ring in
-    ``HybridCache``, ``moe_scale`` in the softmax rule and ``apply_rope``'s
-    two keywords leave every call without them what it was."""
-    model, program = key.split(".", 1)
-    eng = older_engine(model)
+def _older_program(eng, program: str) -> str:
     B = eng.max_batch
     z = lambda dt: jnp.zeros((B,), dt)  # noqa: E731
     lanes = (z(jnp.int32), z(jnp.int32), z(jnp.float32), z(jnp.int32), z(jnp.float32))
@@ -637,4 +628,37 @@ def test_an_older_configurations_step_program_lowers_to_the_parents_text(older_e
         lowered = eng._prefill_with_decode.lower(
             eng.params, eng.cache, jnp.int32(1), tokens, tokens, jnp.int32(4), *lanes,
             jax.random.split(jax.random.PRNGKey(0), 1))
-    assert hashlib.sha256(lowered.as_text().encode()).hexdigest() == OLDER[key]
+    return lowered.as_text()
+
+
+@pytest.mark.parametrize("key", sorted(OLDER))
+def test_an_older_configurations_step_program_lowers_to_the_parents_text(older_engine, key):
+    """``jit_decode_n``, ``jit_prefill`` at bucket 128 and the mixed step of
+    the six blocks the benchmark had (Mixtral's and OLMoE's K/V block,
+    SmallThinker's ring, Kimi-Linear, Olmo-Hybrid, Mistral-Small-4), int8 as
+    served, lower to the StableHLO the parent commit (c00855d, PR 49) lowered
+    on this backend, byte for byte: sha256 of the text, taken there with
+    these lowering calls. The second positional kind, the ring in
+    ``HybridCache``, ``moe_scale`` in the softmax rule and ``apply_rope``'s
+    two keywords leave every call without them what it was.
+
+    Since PR 51 the 12 programs of the four configurations with ONE kind of
+    mixer are still PR 49's: that equality is why ``mistral4.docs``,
+    ``smallthinker.mixed`` and the ``mixtral`` and ``olmoe`` cells cannot
+    move. Kimi-Linear's and Olmo-Hybrid's 6 compare with
+    ``tests/data/step_programs_pr51.json`` (a barrier in each kind's loop, and
+    nothing else: the next test)."""
+    model, program = key.split(".", 1)
+    text = _older_program(older_engine(model), program)
+    assert hashlib.sha256(text.encode()).hexdigest() == {**OLDER, **OLDER_MOVED}[key]
+
+
+@pytest.mark.parametrize("key", sorted(OLDER_MOVED))
+def test_a_two_kind_configurations_step_less_its_barriers_lowers_to_the_parents_text(older_engine, barrier_is_identity, key):
+    """The mixed step included: with ``lax.optimization_barrier`` as the
+    identity, Kimi-Linear's and Olmo-Hybrid's three programs are PR 49's text
+    byte for byte, so PR 51 added the barriers and changed no arithmetic."""
+    model, program = key.split(".", 1)
+    text = _older_program(older_engine(model), program)
+    assert "optimization_barrier" not in text
+    assert hashlib.sha256(text.encode()).hexdigest() == OLDER[key]

@@ -76,7 +76,39 @@ def test_a_mixed_launch_is_the_chunk_then_the_one_step_decode(model, weights):
     ``stop``), and a lane fed its EOS, which closes (``stop = 0``) in both.
     Where the block has a linear mixer, every lane's state and conv window are
     compared too, the lanes that do not step included: nothing of them moves
-    in either."""
+    in either.
+
+    One case is held wider, Olmo-Hybrid with int8 weights (1e-4), and by
+    LLVM's doing, not the program's (PR 51; the next test). XLA:CPU
+    marks a reduce's adds ``reassoc``, and LLVM at -O2 orders them by the
+    loop it finds around them. Since PR 51 the gated delta rule's projections
+    and their int8 scale stay inside the kind's loop, and in ``jit_prefill``
+    alone (128 rows) LLVM then sums ``_l2norm``'s twelve squares of a key as
+    three 4-wide partial sums where it had added them one after another
+    (``add_rsqrt_fusion.1`` in ``--xla_dump_to``'s ``ir-with-opt.ll``;
+    ``jit_decode_n`` and the mixed step keep the order, and with float
+    weights all three do): the chunk's keys move by an ulp (1.2e-7 in layer
+    0), and eight layers whose residual adds a sublayer's NORMED output carry
+    that to the last full layer's V rows. Read on this host, mixed against
+    chunk-then-step, the largest difference of any leaf, under
+    ``--xla_cpu_max_isa=`` SSE4_2 / AVX / AVX2 / AVX512: int8 4.02e-5 /
+    3.66e-5 / 3.58e-5 / 3.58e-5 (3.3e-6 at the parent); float 3.3e-6 / 3.1e-6
+    / 4.6e-6 / 4.6e-6, which 2e-5 holds as before. Kimi-Linear, whose chunk
+    reorders the same sum, reads 8.6e-6 at most and stays at 2e-5 too."""
+    _mixed_against_chunk_then_step(model, weights, 1e-4 if (model, weights) == ("tiny-olmo-hybrid", "int8") else 2e-5)
+
+
+def test_olmo_hybrid_int8_meets_the_others_tolerance_where_llvm_may_not_reorder(where_llvm_may_not_reorder):
+    """The one case the test above holds wider, under ``conftest``'s flags:
+    the same programs, the same barriers, 2e-5 (5.9e-6 read, PR 51, which is
+    what the parent's programs read under these flags)."""
+    where_llvm_may_not_reorder(
+        "from tests.test_mixed_step import _mixed_against_chunk_then_step as check\n"
+        "check('tiny-olmo-hybrid', 'int8', 2e-5)\n"
+    )
+
+
+def _mixed_against_chunk_then_step(model: str, weights: str, atol: float):
     quant = {"quant": "int8"} if weights == "int8" else {}
     eng = LLMEngine.create(model, options={**OPTS, "prefill_chunk": 128, **quant})
     try:
@@ -117,7 +149,6 @@ def test_a_mixed_launch_is_the_chunk_then_the_one_step_decode(model, weights):
             _copy(lane_tok), _copy(lane_pos), temps, topk, topp, keys,
         )
         assert toks_m.shape == toks_a.shape == (1, B)
-        atol = 2e-5
         stepping = sorted(set(context) - set(idle))
 
         def rows(arena):  # all but the rows the idle lanes rewrite

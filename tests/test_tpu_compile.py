@@ -664,6 +664,142 @@ def test_olmo_hybrid_step_copies_no_stack_and_pads_no_arena_on_v5e(v5e, step):
     assert mem.temp_size_in_bytes < arena_layer // 4, mem.temp_size_in_bytes
 
 
+def _computations(text: str) -> dict:
+    """``{name: [instruction lines]}`` of a compiled module's text."""
+    comps, name = {}, None
+    for ln in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%([^ ]+) \(.*\{$", ln)
+        if m:
+            name = m.group(1)
+            comps[name] = []
+        elif ln.startswith("}"):
+            name = None
+        elif name is not None and ln.strip():
+            comps[name].append(ln)
+    return comps
+
+
+def _called(ln: str) -> list:
+    """The computations an instruction line names: a fusion's, a loop's body
+    and condition, a conditional's branches."""
+    names = re.findall(r"(?:calls|to_apply|body|condition)=%([^ ,)}]+)", ln)
+    for group in re.findall(r"branch_computations=\{([^}]*)\}", ln):
+        names += [b.strip().lstrip("%") for b in group.split(",")]
+    return names
+
+
+def _reached(comps: dict, start, stop=()) -> set:
+    """The computations those of ``start`` run, themselves included, not
+    entering those of ``stop``."""
+    seen, todo = set(), list(start)
+    while todo:
+        c = todo.pop()
+        if c not in seen and c not in stop:
+            seen.add(c)
+            todo += [name for ln in comps.get(c, ()) for name in _called(ln)]
+    return seen
+
+
+def _work_lifted_out_of_a_kinds_loop(text: str, leaves: dict, rows: int, own: dict) -> list:
+    """What a two-kind step program compiled for the chip runs OUTSIDE the
+    0-or-1-trip loop its source put it in (``models/hybrid.py`` ``of_kind``).
+
+    The kinds' loops are the innermost ``while``s that carry a cache leaf, the
+    layer scan's body the one computation that holds both. An instruction of
+    that body whose ``op_name`` lies under a kind's loop (``<the loop's>/body/``)
+    was lifted there by the compiler and runs in every layer, whatever the
+    trip count: reported where it is a matmul (a ``convolution`` or ``dot``,
+    alone or fused) or makes an ``s8[...]`` value (a slice of the kind's weight
+    stack, copied). ``own``: ``{kind: widths}``, input projections only that
+    kind has; ``f32[rows, width]`` may appear in ONE kind's loop and nowhere
+    else in the layer's work (the FFN's branches apart: Laguna's dense layer
+    is 8192 wide like its window kind's queries)."""
+    comps = _computations(text)
+    shapes = [f"[{','.join(map(str, a.shape))}]" for a in leaves.values()]
+    whiles = [
+        (c, ln, re.search(r"body=%([^ ,)]+)", ln).group(1))
+        for c, lines in comps.items() for ln in lines
+        if re.search(r" while\(", ln) and any(s in ln for s in shapes)
+    ]
+    holders = {c for c, _, _ in whiles}
+    kinds = [(c, ln, body) for c, ln, body in whiles if body not in holders]
+    assert len(kinds) == 2 and len({c for c, _, _ in kinds}) == 1, [ln[:80] for _, ln, _ in kinds]
+    layer_body = kinds[0][0]
+    under = [re.search(r'op_name="([^"]+)"', ln).group(1) + "/body/" for _, ln, _ in kinds]
+    found = []
+    for ln in comps[layer_body]:
+        name = re.search(r'op_name="([^"]+)"', ln)
+        if not name or not name.group(1).startswith(tuple(under)):
+            continue
+        what = ln.strip().split(" = ", 1)[1]
+        lines = [ln] + [x for c in _called(ln) for x in comps.get(c, ())]
+        if what.startswith("s8[") or any(re.search(r" (convolution|dot)\(", x) for x in lines):
+            found.append(f"{what[:48]} <- {name.group(1)}")
+    loops = [_reached(comps, [body, re.search(r"condition=%([^ ,)]+)", ln).group(1)]) for _, ln, body in kinds]
+    branches = _reached(comps, [b for ln in comps[layer_body] if "branch_computations=" in ln for b in _called(ln)])
+    rest = _reached(comps, [layer_body], stop=loops[0] | loops[1] | branches)
+    seen_somewhere = False
+    for kind, widths in own.items():
+        for width in widths:
+            value = re.compile(rf"f32\[(1,)?{rows},{width}\]")
+            has = lambda cs: any(value.search(ln) for c in cs for ln in comps.get(c, ()))  # noqa: E731
+            if has(rest):
+                found.append(f"{kind}'s f32[{rows},{width}] in the layer scan's body")
+            if has(loops[0]) and has(loops[1]):
+                found.append(f"{kind}'s f32[{rows},{width}] in both kinds' loops")
+            seen_somewhere |= has(loops[0]) or has(loops[1])
+    assert seen_somewhere, own  # the widths are this program's: the check is not blind
+    return found
+
+
+# a served share's lanes (a decode step's rows; the mixed step has 256 more) and
+# the input projections ONE kind has: widths no other value of the layer's work shares
+TWO_KINDS = {
+    "laguna-40l": (8, {"full": [48 * 128], "swa": [64 * 128]}),
+    "kimi-27l": (64, {"kda": [3 * 32 * 128], "mla": [32 * 192, 512 + 64]}),
+    "olmo-hybrid-32l": (8, {"gdn": [30 * (96 + 96 + 192), 30 * 192]}),
+}
+
+
+@pytest.mark.parametrize("step", ["decode", "mixed"])
+@pytest.mark.parametrize("model", sorted(TWO_KINDS))
+def test_a_kinds_work_stays_inside_its_loop_on_v5e(v5e, monkeypatch, model, step):
+    """The hybrid block with two kinds of mixer, served shares whole (Laguna
+    full + window, Kimi-Linear KDA + MLA, Olmo-Hybrid gated delta rule + full),
+    the decode step and the mixed step compiled for the described v5e: a
+    layer runs, slices and copies only its own kind's projections. Before PR
+    51 XLA's loop-invariant code motion lifted both kinds' input projections
+    (and a copy of both ``wo`` slices) out of the 0-or-1-trip loops into the
+    layer scan's body: 8 to 12 such instructions in each of these six
+    programs, run in all 40, 27 or 32 layers (a quarter to a half of them for
+    the kind the layer is not). The barrier in ``of_kind`` keeps them in, the
+    one after ``full_mixer``'s and ``_mla_query``'s projections keeps the
+    compiler from transposing a whole ``wq`` stack instead; nothing the size
+    of a weight stack is copied anywhere in the program."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the grouped FFN's kernel, as on the chip
+    cfg, cache, plan, steps = _hybrid_case(model, SingleDeviceSharding(v5e.devices[0]))
+    lanes, own = TWO_KINDS[model]
+    fn, args = steps[step]
+    text = fn.lower(*args).compile().as_text()
+    assert _work_lifted_out_of_a_kinds_loop(text, cache.leaves(), 256 + lanes if step == "mixed" else lanes, own) == []
+    for kind, stack in args[0].items():  # every mixer kind's large projections, whatever the kind is called
+        for name in {"wq", "wo", "wqkv"} & set(stack if isinstance(stack, dict) else ()):
+            shape = ",".join(map(str, stack[name].q.shape))
+            assert not re.search(rf"s8\[{shape}\][^ ]* (copy|transpose)\(", text), (kind, name)
+
+
+def test_the_lifted_work_is_found_where_a_loop_lacks_its_barrier(v5e, monkeypatch):
+    """The check is not blind: Olmo-Hybrid's decode step traced with the
+    barrier as the identity (the parent's program) has both kinds'
+    projections and both ``wo`` slices in the layer scan's body."""
+    monkeypatch.setattr(jax.lax, "optimization_barrier", lambda x: x)
+    cfg, cache, plan, steps = _hybrid_case("olmo-hybrid-32l", SingleDeviceSharding(v5e.devices[0]))
+    fn, args = steps["decode"]
+    text = fn.lower(*args).compile().as_text()
+    found = _work_lifted_out_of_a_kinds_loop(text, cache.leaves(), 8, TWO_KINDS["olmo-hybrid-32l"][1])
+    assert len(found) >= 9 and any("s8[1,5760,3840]" in f for f in found) and any("f32[8,11520]" in f for f in found), found
+
+
 def _windowed_case(where, lanes=8, periods=1):
     """SmallThinker's block at published widths (``periods`` whole periods
     G W W W), the chip's share of ep = 4 (16 of 64 experts), int8 as served,

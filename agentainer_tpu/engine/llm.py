@@ -69,11 +69,27 @@ from ..models.llama import (
 )
 from ..ops.attention import pages_to_rows, plan_cache_attention, rows_to_pages
 from ..ops.moe import row_tile, sorted_rows
+from ..utils.launches import Launches
 from ..utils.spans import Spans
 from .sampling import NEG_INF, sample, sample_step
 from .tokenizer import load_tokenizer
 
 PREFILL_BUCKETS = (32, 64, 128, 256, 512, 1024)
+# The step programs' XLA module names, as a device trace shows them. They
+# live here once: ``_step_jit`` names each program by them, the launch ledger
+# (``utils/launches.py``, ``/metrics`` ``launches``) counts under them, so
+# ledger and trace join by name, and ``tests/test_spans.py`` pins them
+# against the lowered programs.
+JIT_PREFILL = "jit_prefill"
+JIT_DECODE_N = "jit_decode_n"
+JIT_PREFILL_WITH_DECODE = "jit_prefill_with_decode"
+JIT_FIRST_TOKEN = "jit_first_token"
+JIT_VERIFY = "jit_verify"
+JIT_FUSED = "jit_fused_body"
+# the programs whose launch steps the decode lanes (``decode_steps`` counts
+# them) and those that feed a prompt's chunk (``prefill_launches``)
+_DECODE_PROGRAMS = (JIT_DECODE_N, JIT_PREFILL_WITH_DECODE, JIT_VERIFY, JIT_FUSED)
+_PREFILL_PROGRAMS = (JIT_PREFILL, JIT_PREFILL_WITH_DECODE)
 
 # Paged KV arena (block tables): the pool's page granularity in tokens.
 # 64 keeps every PREFILL_BUCKET level ≥ 64 page-aligned (zero-copy prefix
@@ -310,6 +326,13 @@ def _as_prefill_failure(e: Exception) -> Exception:
     if isinstance(e, (RequestAborted, EngineOverloaded, EngineShutdown)):
         return e
     return PrefillFailed(f"{type(e).__name__}: {e}")
+
+
+def _step_jit(module: str, fn, **jit_kwargs):
+    """``jax.jit(fn)`` as the XLA module ``module`` (JAX names a module
+    ``jit_`` + the function's name), whichever variant of the step ``fn`` is."""
+    fn.__name__ = module.removeprefix("jit_")
+    return jax.jit(fn, **jit_kwargs)
 
 
 def _phase(name: str, attrs=None):
@@ -847,7 +870,6 @@ class LLMEngine:
         for i in range(max_batch):
             self._bt[i, :] = self._scratch_page(i)
         self.page_exhausted_total = 0
-        self.pages_truncated = 0
         self.prefix_pages_shared = 0
         self._snap_paged_fns: dict[int, Any] = {}
         self._restore_paged_fns: dict[int, Any] = {}
@@ -886,7 +908,6 @@ class LLMEngine:
         self.tier_prewarm_hits_total = 0
         self.tier_demote_failures_total = 0
         self.tier_promote_failures_total = 0
-        self.tier_promote_overlap_ms_total = 0.0
         # promote-start instants by session, consumed when the promoted
         # session's next request dispatches its first prefill chunk — the
         # interval is restore latency HIDDEN behind the queue-wait phase
@@ -964,9 +985,11 @@ class LLMEngine:
         self._fused_inject = self.fused_decode
         self.fused_injections_total = 0
         self.fused_inject_fallbacks_total = 0
-        # FIFO of lagged readbacks: ("first", slot, req, first_dev, t) and
-        # ("chunk", [(slot, req, start_pos)...], toks_dev, t); staleness is
-        # detected by `slot.request is not req` identity at processing time
+        # FIFO of lagged readbacks: ("first", slot, req, first_dev, launch),
+        # ("chunk", [(slot, req, start_pos)...], toks_dev, launch) and
+        # ("fused", [...], packed_dev, chunk, launch), each with its launch's
+        # ledger record last; staleness is detected by `slot.request is not
+        # req` identity at processing time
         self._readbacks: collections.deque = collections.deque()
 
         self._queue: queue.Queue[GenRequest | None] = queue.Queue()
@@ -988,19 +1011,19 @@ class LLMEngine:
         # totals for metrics(), and the same spans on the profiler's clock
         # while a /profile capture runs
         self._spans = Spans()
+        # every launch of a step program, counted where it is dispatched and
+        # timed where its output is read back (utils/launches.py): the
+        # launch counters of metrics() are sums over it
+        self._launches = Launches()
+        # the ledger at the two edges of the newest /profile capture
+        # (``h_profile`` sets it): what pairs a trace's modules with the
+        # launches that were really in it
+        self.last_capture: dict | None = None
 
         # counters
         self.tokens_generated = 0
         self.prefills = 0
-        # launches of the prefill step and the real tokens they carried
-        # (bucket padding excluded), and requests that got their reply
-        self.prefill_launches = 0
-        self.prefill_tokens = 0
         self.requests_finished = 0
-        # passes through the model's layers launched so far, every step
-        # program counted (a decode launch of n steps is n): what a device
-        # trace's size follows (``h_profile`` bounds a capture by it)
-        self.forward_passes = 0
         self.ttft_ms_recent: collections.deque[float] = collections.deque(maxlen=256)
         self.itl_ms_recent: collections.deque[float] = collections.deque(maxlen=256)
         # TTFT phase decomposition: queue-wait (admission → first prefill
@@ -1013,14 +1036,8 @@ class LLMEngine:
         self.first_readback_ms_recent: collections.deque[float] = collections.deque(
             maxlen=256
         )
-        # adaptive-chunk observability: dispatched chunk-size histogram and
-        # how often contention shrank below the configured chunk
-        self.decode_chunk_hist: dict[int, int] = {}
+        # how often contention shrank a decode chunk below the configured one
         self.decode_chunks_shrunk = 0
-        # prefill launches that carried the decode lanes' step with them
-        # (``jit_prefill_with_decode``), and the live lanes that rode
-        self.mixed_launches = 0
-        self.mixed_decode_lanes = 0
         self.worker_errors = 0
         self.last_worker_error = ""
         self.cache_resets = 0
@@ -1090,8 +1107,6 @@ class LLMEngine:
         self.state_restores = 0
         self._restore_fns: dict[int, Any] = {}
         self._staged_bytes_by_bucket: dict[int, str] = {}
-        self.decode_steps = 0
-        self._occupancy_sum = 0.0
         self._last_decode_end: float | None = None
         self._started_at = time.monotonic()
 
@@ -1188,14 +1203,13 @@ class LLMEngine:
         self.spec_rounds = 0
         self.spec_drafted = 0
         self.spec_accepted = 0
-        self.spec_rejected = 0
-        self.spec_verify_hist: dict[int, int] = {}
         # fused-loop observability (ISSUE 10): loops dispatched, on-device
         # steps actually executed (early exits run fewer than the rung),
-        # loops that exited before the rung bound, exit-reason histogram,
-        # and host syncs — every host materialization of device decode
-        # output bumps host_syncs_total, so syncs/token quantifies the
-        # one-readback-per-loop claim against the per-chunk baseline.
+        # loops that exited before the rung bound, exit-reason histogram.
+        # Host syncs are the launch ledger's readbacks (every host
+        # materialization of device decode output is one ``ready``), so
+        # syncs/token quantifies the one-readback-per-loop claim against
+        # the per-chunk baseline.
         self._fused_fns: dict[int, Any] = {}
         # dynamic-rung cap: the single compiled loop's static sizing bound
         # (emitted buffer, key ladder); the runtime loop bound `nsteps` is
@@ -1206,7 +1220,6 @@ class LLMEngine:
         self.fused_steps_total = 0
         self.fused_early_exits_total = 0
         self.fused_exit_reason_hist: dict[str, int] = {}
-        self.host_syncs_total = 0
         self._n_chips = self.tp * self.ep
         # the devices this engine computes on, as JAX reports them — what
         # /metrics names, so a number can always be traced to its device
@@ -1561,7 +1574,7 @@ class LLMEngine:
             "prefill_impl": impl if self._moe_sorted_from is None else "sorted_grouped_ffn",
             "routed_from_rows": self._moe_sorted_from,
             # cumulative, counted on the host at each launch from its static
-            # shapes (``_count_forward``): Σ N·k; Σ N·E over launches under the
+            # shapes (``_count_moe_rows``): Σ N·k; Σ N·E over launches under the
             # cut; Σ rows the grouped FFN was given, tile padding included
             "assignments": 0,
             "rows_all_experts": 0,
@@ -1723,11 +1736,11 @@ class LLMEngine:
             return rng, first, first[0]
 
         if self.paged:
-            self._prefill = jax.jit(prefill_paged, donate_argnums=(1,))
-            self._decode_n = jax.jit(decode_n_paged, donate_argnums=(1, 3, 4))
+            self._prefill = _step_jit(JIT_PREFILL, prefill_paged, donate_argnums=(1,))
+            self._decode_n = _step_jit(JIT_DECODE_N, decode_n_paged, donate_argnums=(1, 3, 4))
         else:
-            self._prefill = jax.jit(prefill, donate_argnums=(1,))
-            self._decode_n = jax.jit(decode_n, donate_argnums=(1, 2, 3))
+            self._prefill = _step_jit(JIT_PREFILL, prefill, donate_argnums=(1,))
+            self._decode_n = _step_jit(JIT_DECODE_N, decode_n, donate_argnums=(1, 2, 3))
         # Does this engine have the mixed step? Where the cache is the dense
         # arena of one chip under the per-chunk decode driver and ``forward``
         # chooses the MoE path by row count: the K/V block, and the hybrid
@@ -1743,11 +1756,13 @@ class LLMEngine:
         if self._decode_ladder[0] == 1 and not (
             self.paged or self.fused_decode or self.mesh is not None or moe_impl is not None
         ):
-            self._prefill_with_decode = jax.jit(prefill_with_decode, donate_argnums=(1, 6, 7))
+            self._prefill_with_decode = _step_jit(
+                JIT_PREFILL_WITH_DECODE, prefill_with_decode, donate_argnums=(1, 6, 7)
+            )
             top = self._bucket(min(self.prefill_chunk, max(1, self.max_seq - 2)))
             self._mixed_buckets = tuple(b for b in PREFILL_BUCKETS if b <= top)[-2:]
         self._inject = jax.jit(inject, donate_argnums=(0, 1, 2, 3, 4, 5, 6))
-        self._first_token = jax.jit(first_token)
+        self._first_token = _step_jit(JIT_FIRST_TOKEN, first_token)
         if self._hybrid:
             from ..models import hybrid
 
@@ -2025,12 +2040,12 @@ class LLMEngine:
                     armed, live, budgets, ign, keys, nsteps, bt,
                 )
 
-            fn = self._fused_fns[self._fused_cap] = jax.jit(
-                fused_paged, donate_argnums=(1, 3, 4, 5, 6, 7, 8, 9)
+            fn = self._fused_fns[self._fused_cap] = _step_jit(
+                JIT_FUSED, fused_paged, donate_argnums=(1, 3, 4, 5, 6, 7, 8, 9)
             )
         else:
-            fn = self._fused_fns[self._fused_cap] = jax.jit(
-                fused_body, donate_argnums=(1, 2, 3, 4, 5, 6, 7, 8)
+            fn = self._fused_fns[self._fused_cap] = _step_jit(
+                JIT_FUSED, fused_body, donate_argnums=(1, 2, 3, 4, 5, 6, 7, 8)
             )
         return fn
 
@@ -2217,10 +2232,8 @@ class LLMEngine:
         self.admission_ms_recent.clear()
         self.prefill_ms_recent.clear()
         self.first_readback_ms_recent.clear()
-        self.decode_chunk_hist = {}
+        self._launches.reset()
         self.decode_chunks_shrunk = 0
-        self.mixed_launches = 0
-        self.mixed_decode_lanes = 0
         self.fused_loops_total = 0
         self.fused_steps_total = 0
         self.fused_early_exits_total = 0
@@ -2229,7 +2242,6 @@ class LLMEngine:
         self.fused_inject_fallbacks_total = 0
         self.inloop_spec_drafted = 0
         self.inloop_spec_accepted = 0
-        self.host_syncs_total = 0
         self._prefix_entries.clear()
         self._prefix_bytes = 0
         self.prefix_hits = 0
@@ -2241,11 +2253,7 @@ class LLMEngine:
         self.prefix_eviction_idle_s_recent.clear()
         self.tokens_generated = 0
         self.prefills = 0
-        self.prefill_launches = 0
-        self.prefill_tokens = 0
         self.requests_finished = 0
-        self.decode_steps = 0
-        self._occupancy_sum = 0.0
         self.flops_done = 0.0
         self.hbm_bytes_read = 0.0
         self._last_decode_end = None
@@ -2255,7 +2263,6 @@ class LLMEngine:
             # pool-telemetry counters so serving starts from a clean gauge
             self._release_quarantine()
             self.page_exhausted_total = 0
-            self.pages_truncated = 0
             self.prefix_pages_shared = 0
         self._started_at = time.monotonic()
 
@@ -2953,9 +2960,6 @@ class LLMEngine:
             "tier_prewarm_hits_total": self.tier_prewarm_hits_total,
             "tier_demote_failures_total": self.tier_demote_failures_total,
             "tier_promote_failures_total": self.tier_promote_failures_total,
-            "tier_promote_overlap_ms_total": round(
-                self.tier_promote_overlap_ms_total, 2
-            ),
             "tier_promote_overlap_ms_p50": (
                 round(overlap[len(overlap) // 2], 2) if overlap else None
             ),
@@ -3173,7 +3177,6 @@ class LLMEngine:
                 # un-map the freed blocks from the live lane: a stale table
                 # entry is read-masked but must never be WRITTEN through
                 self._bt[sess.lane, keep:] = self._scratch_page(sess.lane)
-            self.pages_truncated += len(tail)
             for pid in tail:
                 self._decref_page(pid)
 
@@ -3582,8 +3585,58 @@ class LLMEngine:
                     slot.position = 0
                     slot.epoch += 1
 
+    # -- the launch counters: sums over the ledger, under their old names ----
+    @property
+    def prefill_launches(self) -> int:
+        """Launches that fed a prompt's chunk, a mixed launch among them."""
+        return self._launches.total("n", *_PREFILL_PROGRAMS)
+
+    @property
+    def prefill_tokens(self) -> int:
+        """The real tokens those launches carried (bucket padding excluded)."""
+        return self._launches.total("rows", *_PREFILL_PROGRAMS) - self._launches.total("lanes", *_PREFILL_PROGRAMS)
+
+    @property
+    def decode_steps(self) -> int:
+        """Launches that stepped the decode lanes: a rung, a fused loop, a
+        verify round, a prefill chunk that carried the step."""
+        return self._launches.total("n", *_DECODE_PROGRAMS)
+
+    @property
+    def decode_chunk_hist(self) -> dict[int, int]:
+        """The decode driver's launches by rung (``jit_decode_n``'s, or the
+        fused loop's by its bound): never a mixed launch."""
+        return {int(k): n for k, n in self._launches.by_key(JIT_DECODE_N, JIT_FUSED).items()}
+
+    @property
+    def spec_verify_hist(self) -> dict[int, int]:
+        return {int(k): n for k, n in self._launches.by_key(JIT_VERIFY).items()}
+
+    @property
+    def mixed_launches(self) -> int:
+        """Prefill launches that carried the decode lanes' step with them
+        (``jit_prefill_with_decode``)."""
+        return self._launches.total("n", JIT_PREFILL_WITH_DECODE)
+
+    @property
+    def mixed_decode_lanes(self) -> int:
+        """The live lanes that rode those launches."""
+        return self._launches.total("lanes", JIT_PREFILL_WITH_DECODE)
+
+    @property
+    def forward_passes(self) -> int:
+        """Passes through the model's layers launched so far, every step
+        program counted (a decode launch of n steps is n): what a device
+        trace's size follows (``h_profile`` bounds a capture by it)."""
+        return self._launches.total("steps")
+
+    def launches(self) -> dict:
+        """The launch ledger as ``/metrics`` carries it (utils/launches.py)."""
+        return self._launches.snapshot()
+
     def metrics(self) -> dict:
         elapsed = max(1e-6, time.monotonic() - self._started_at)
+        decode_steps = self.decode_steps
         recent = sorted(self.ttft_ms_recent)
         itl = sorted(self.itl_ms_recent)
         adm = sorted(self.admission_ms_recent)
@@ -3594,14 +3647,24 @@ class LLMEngine:
             # (read as differences): per phase n / self_s / total_s, and
             # loop_s, the wall time its spans tile (utils/spans.py)
             **self._spans.snapshot(),
+            # every launch of a step program by XLA module name and key
+            # (rung, bucket, K): how many, what they carried, and the seconds
+            # in service of those read back alone (utils/launches.py;
+            # cumulative, read as differences); ``last_capture`` is the same
+            # document at the two edges of the newest /profile capture
+            "launches": self.launches(),
+            "last_capture": self.last_capture,
             "tokens_generated": self.tokens_generated,
             "tokens_per_s": round(self.tokens_generated / elapsed, 2),
             "prefills": self.prefills,
             "prefill_launches": self.prefill_launches,
             "prefill_tokens": self.prefill_tokens,
             "requests_finished": self.requests_finished,
-            "decode_steps": self.decode_steps,
-            "batch_occupancy": round(self._occupancy_sum / max(1, self.decode_steps), 3),
+            "decode_steps": decode_steps,
+            # mean share of the lanes that stepped, over those launches
+            "batch_occupancy": round(
+                self._launches.total("lanes", *_DECODE_PROGRAMS) / self.max_batch / max(1, decode_steps), 3
+            ),
             "ttft_ms_p50": round(recent[len(recent) // 2], 2) if recent else None,
             "itl_ms_p50": round(itl[len(itl) // 2], 2) if itl else None,
             # TTFT phase decomposition: queue-wait (admission_ms, submit →
@@ -3609,7 +3672,6 @@ class LLMEngine:
             # first-token injection) + first-readback (injection → token on
             # host) ≈ ttft_ms per request
             "admission_ms_p50": round(adm[len(adm) // 2], 2) if adm else None,
-            "admission_ms_max": round(adm[-1], 2) if adm else None,
             "admission_samples": [round(x, 2) for x in self.admission_ms_recent],
             "ttft_prefill_ms_p50": round(pre[len(pre) // 2], 2) if pre else None,
             "ttft_first_readback_ms_p50": round(frb[len(frb) // 2], 2) if frb else None,
@@ -3621,12 +3683,7 @@ class LLMEngine:
             # chunk-size histogram, and how often contention shrank it
             "decode_chunk": self.decode_chunk,
             "adaptive_decode": self.adaptive_decode,
-            # .copy() first: the worker thread inserts a NEW key on the
-            # first dispatch of each chunk size — iterating the live dict
-            # from the metrics thread could raise mid-scrape
-            "decode_chunk_hist": {
-                str(k): v for k, v in sorted(self.decode_chunk_hist.copy().items())
-            },
+            "decode_chunk_hist": {str(k): v for k, v in sorted(self.decode_chunk_hist.items())},
             "decode_chunks_shrunk": self.decode_chunks_shrunk,
             # prefill launches that carried the decode lanes' step (they
             # count in prefill_launches AND decode_steps, never in
@@ -3636,24 +3693,15 @@ class LLMEngine:
             # cost no weight stream of their own
             "mixed_launches": self.mixed_launches,
             "mixed_decode_lanes": self.mixed_decode_lanes,
-            # self-speculative decoding: drafted/accepted/rejected token
-            # counters, verify-bucket histogram (.copy() for the same
-            # mid-scrape reason as decode_chunk_hist), and each slot's live
+            # self-speculative decoding: drafted/accepted token
+            # counters, verify-bucket histogram, and each slot's live
             # acceptance EMA — a collapsed gamma shows up as EMAs pinned
             # under the floor while spec_rounds stops advancing
             "speculative": self.speculative,
             "spec_rounds": self.spec_rounds,
             "spec_drafted": self.spec_drafted,
             "spec_accepted": self.spec_accepted,
-            "spec_rejected": self.spec_rejected,
-            "spec_acceptance_rate": (
-                round(self.spec_accepted / self.spec_drafted, 4)
-                if self.spec_drafted
-                else None
-            ),
-            "spec_verify_hist": {
-                str(k): v for k, v in sorted(self.spec_verify_hist.copy().items())
-            },
+            "spec_verify_hist": {str(k): v for k, v in sorted(self.spec_verify_hist.items())},
             "spec_slot_acceptance": [round(s.spec_ema, 3) for s in self.slots],
             # fused on-device decode loop: loops dispatched, device steps
             # executed (early exits run fewer than the rung), early-exit
@@ -3678,9 +3726,8 @@ class LLMEngine:
             "inloop_spec_drafted": self.inloop_spec_drafted,
             "inloop_spec_accepted": self.inloop_spec_accepted,
             "approx_topk": self.approx_topk,
-            "host_syncs_total": self.host_syncs_total,
             "host_syncs_per_token": (
-                round(self.host_syncs_total / self.tokens_generated, 4)
+                round(self._launches.reads() / self.tokens_generated, 4)
                 if self.tokens_generated
                 else None
             ),
@@ -3872,7 +3919,6 @@ class LLMEngine:
             "resident_sessions": len(self.paged_sessions),
             "kv_fragmentation_pct": frag,
             "page_exhausted_total": self.page_exhausted_total,
-            "pages_truncated_total": self.pages_truncated,
             "prefix_pages_shared_total": self.prefix_pages_shared,
         }
 
@@ -4305,6 +4351,9 @@ class LLMEngine:
         self.worker_errors += 1
         self.last_worker_error = f"{type(e).__name__}: {e}"
         print(f"[llm-engine] worker error: {self.last_worker_error}", flush=True)
+        # what was in flight may never be read back, and a reallocated arena
+        # starts the device's queue anew: the ledger's next launch stands alone
+        self._launches.cut()
 
     def _reset_slot(self, slot: Slot, rollback: bool = False) -> None:
         """Return a slot to cold idle after its request failed: KV prefix is
@@ -4849,13 +4898,24 @@ class LLMEngine:
             self.sessions[session] = slot.idx
         return slot
 
-    def _count_forward(self, rows: int, passes: int = 1) -> None:
+    def _launch(self, program: str, key: int, **facts):
+        """Count a launch of a step program when its jitted call has
+        returned, i.e. the host has handed it to the device: the one place a
+        launch is counted (utils/launches.py). ``facts`` are the ledger's
+        (``steps`` passes through the layers, ``rows`` real rows a pass,
+        ``lanes`` stepping); the dispatch span open around the call carries
+        them on its trace event. Returns the record whoever reads the output
+        back hands to ``self._launches.ready``."""
+        opened = self._launches.dispatched(program, key, **facts)
+        self._spans.note(**opened.attrs())
+        return opened
+
+    def _count_moe_rows(self, rows: int, passes: int = 1) -> None:
         """A launch of ``passes`` passes through the layers, ``rows = B·T``
-        rows each, counted from static shapes only: ``forward_passes``, and
-        for an MoE model the ``moe`` block's counters — what the algorithm
-        asked for and what the path that served it executed (the ``routed``
-        option's buffers are not counted)."""
-        self.forward_passes += passes
+        rows each, counted from static shapes only: an MoE model's
+        ``moe`` block counters — what the algorithm asked for and what the
+        path that served it executed (the ``routed`` option's buffers are not
+        counted)."""
         if not self.cfg.is_moe:
             return
         e, k = self.cfg.n_experts, self.cfg.experts_per_token
@@ -5012,7 +5072,6 @@ class LLMEngine:
             )
             if t0 is not None:
                 hidden = 1000 * (req.prefill_started_at - t0)
-                self.tier_promote_overlap_ms_total += hidden
                 self.tier_promote_overlap_ms_recent.append(hidden)
         chunk = slot.pending_prompt[: self.prefill_chunk]
         slot.pending_prompt = slot.pending_prompt[self.prefill_chunk :]
@@ -5054,17 +5113,22 @@ class LLMEngine:
                     pos,
                     jnp.int32(n),
                 )
+                # nobody reads a plain chunk back (a last chunk's logits feed
+                # ``jit_first_token`` on the device, and THAT launch is read)
+                self._launch(JIT_PREFILL, bucket, rows=n)
             elif riders:
-                with span("engine.mixed_dispatch", lanes=len(riders), tokens=n):
+                with span("engine.mixed_dispatch"):
                     last_logits, toks = self._launch_with_decode(slot.idx, tokens, pos, n)
+                    opened = self._launch(
+                        JIT_PREFILL_WITH_DECODE, bucket, rows=n + len(riders), lanes=len(riders)
+                    )
             else:
                 last_logits, self.cache = self._prefill(
                     self.params, self.cache, jnp.int32(slot.idx), tokens, pos, jnp.int32(n)
                 )
-        self.prefill_launches += 1
-        self.prefill_tokens += n
+                self._launch(JIT_PREFILL, bucket, rows=n)
         self._count_positioned(positions[:n])
-        self._count_forward(bucket + self.max_batch if riders else bucket)
+        self._count_moe_rows(bucket + self.max_batch if riders else bucket)
         # n real tokens, each attending ~its own position of context
         self.flops_done += n * self.cfg.flops_per_token(slot.position + n // 2)
         self.hbm_bytes_read += self.param_hbm_bytes + (
@@ -5073,7 +5137,7 @@ class LLMEngine:
         slot.position += n
         slot.last_used = time.monotonic()
         if riders:
-            self._count_decode_step(riders, toks, rode=True)
+            self._count_decode_step(riders, toks, opened)
         if not final:
             return bool(riders)
         # whole fresh context now in KV: register its bucket-prefixes in
@@ -5091,6 +5155,7 @@ class LLMEngine:
                 np.int32(req.top_k),
                 np.float32(req.top_p),
             )
+            sampled = self._launch(JIT_FIRST_TOKEN, 1, steps=0, rows=1)
         hist_row = None
         hist_n = 0
         if self.inloop_spec:
@@ -5160,7 +5225,7 @@ class LLMEngine:
             first.copy_to_host_async()
         except Exception:
             pass
-        self._readbacks.append(("first", slot, req, first, time.monotonic()))
+        self._readbacks.append(("first", slot, req, first, sampled))
         return bool(riders)
 
     def _launch_with_decode(self, idx: int, tokens, pos, n: int):
@@ -5267,7 +5332,7 @@ class LLMEngine:
         if any(r.id for _, r, _ in snapshot):
             faults.fire("engine.decode_step")
         chunk = self._pick_chunk(needed)
-        with self._spans.span("engine.decode_dispatch", lanes=len(snapshot), chunk=chunk):
+        with self._spans.span("engine.decode_dispatch"):
             self._dispatch_chunk(snapshot, chunk)
 
     def _dispatch_chunk(self, snapshot: list, chunk: int) -> None:
@@ -5302,28 +5367,22 @@ class LLMEngine:
             self._dtopp,
             keys,
         )
-        self.decode_chunk_hist[chunk] = self.decode_chunk_hist.get(chunk, 0) + 1
-        self._count_forward(self.max_batch, chunk)
-        self._count_decode_step(snapshot, toks)
+        opened = self._launch(JIT_DECODE_N, chunk, steps=chunk, rows=len(snapshot), lanes=len(snapshot))
+        self._count_moe_rows(self.max_batch, chunk)
+        self._count_decode_step(snapshot, toks, opened)
 
-    def _count_decode_step(self, snapshot: list, toks, rode: bool = False) -> None:
+    def _count_decode_step(self, snapshot: list, toks, opened) -> None:
         """The bookkeeping of a dispatched decode chunk ``toks [chunk, B]``
-        over ``snapshot``'s lanes, and its readback entry. ``rode``: the
-        step went with a prefill chunk's launch (``jit_prefill_with_decode``):
-        that launch streamed the weights and counted its forward pass, and
-        ``decode_chunk_hist`` stays ``jit_decode_n``'s launches alone (the
-        benchmark's decode roofline multiplies it by that module's launches
-        in a trace)."""
+        over ``snapshot``'s lanes, and its readback entry with the launch's
+        ledger record ``opened``. Where the step went with a prefill chunk's
+        launch (``jit_prefill_with_decode``), that launch streamed the
+        weights and counted its forward pass."""
         chunk = toks.shape[0]
+        rode = opened.program == JIT_PREFILL_WITH_DECODE
         for s, r, _ in snapshot:
             s.dev_position += chunk
             r.dispatched += chunk
-        self.decode_steps += 1
-        self._occupancy_sum += len(snapshot) / self.max_batch
         self._count_decode_blocks([p for _, _, p in snapshot], chunk)
-        if rode:
-            self.mixed_launches += 1
-            self.mixed_decode_lanes += len(snapshot)
         # weights stream once per scan step; each live lane streams its KV
         # prefix (parked lanes re-read the scratch row — not useful traffic)
         self.hbm_bytes_read += (0 if rode else chunk * self.param_hbm_bytes) + sum(
@@ -5333,7 +5392,7 @@ class LLMEngine:
             toks.copy_to_host_async()
         except Exception:
             pass
-        self._readbacks.append(("chunk", snapshot, toks, time.monotonic()))
+        self._readbacks.append(("chunk", snapshot, toks, opened))
 
     def _fused_dispatch(self) -> None:  # atp: hot
         """Dispatch one fused on-device decode loop (fused_decode=True's
@@ -5366,7 +5425,7 @@ class LLMEngine:
         if any(r.id for _, r, _ in base):
             faults.fire("engine.fused_decode")
         chunk = self._pick_fused_chunk()
-        with self._spans.span("engine.decode_dispatch", lanes=len(base), chunk=chunk):
+        with self._spans.span("engine.decode_dispatch"):
             self._dispatch_fused(base, chunk)
 
     def _dispatch_fused(self, base: list, chunk: int) -> None:  # atp: hot
@@ -5445,6 +5504,8 @@ class LLMEngine:
             keys,
             jnp.int32(chunk),
         )
+        # ``steps`` is the loop's cap: it may stop early, and an in-loop verify is wider
+        opened = self._launch(JIT_FUSED, chunk, steps=chunk, rows=len(base), lanes=len(base))
         if self._staged_lane is not None:
             # the loop just dispatched absorbs the staged lane at entry
             self._staged_lane = None
@@ -5456,17 +5517,13 @@ class LLMEngine:
             s.dev_position += adv
             r.dispatched += chunk
         self.fused_loops_total += 1
-        self.decode_chunk_hist[chunk] = self.decode_chunk_hist.get(chunk, 0) + 1
-        self.decode_steps += 1
-        # the loop's cap: it may stop early, and an in-loop verify is wider
-        self._count_forward(self.max_batch, chunk)
+        self._count_moe_rows(self.max_batch, chunk)
         self._count_decode_blocks([p for _, _, p, _ in snapshot], chunk)
-        self._occupancy_sum += len(snapshot) / self.max_batch
         try:
             packed.copy_to_host_async()
         except Exception:
             pass
-        self._readbacks.append(("fused", snapshot, packed, chunk, time.monotonic()))
+        self._readbacks.append(("fused", snapshot, packed, chunk, opened))
 
     def _pick_fused_chunk(self) -> int:  # atp: hot
         """Loop-bound policy for the fused dispatcher. ``nsteps`` is a
@@ -5638,8 +5695,8 @@ class LLMEngine:
                         params, cache, tok, pos, temps, topk, topp, drafts, dlen, key, bt
                     )
 
-                fn = self._verify_fns[K] = jax.jit(
-                    verify_paged, donate_argnums=(1, 3, 4)
+                fn = self._verify_fns[K] = _step_jit(
+                    JIT_VERIFY, verify_paged, donate_argnums=(1, 3, 4)
                 )
             else:
 
@@ -5650,7 +5707,7 @@ class LLMEngine:
                         params, cache, tok, pos, temps, topk, topp, drafts, dlen, key
                     )
 
-                fn = self._verify_fns[K] = jax.jit(verify, donate_argnums=(1, 2, 3))
+                fn = self._verify_fns[K] = _step_jit(JIT_VERIFY, verify, donate_argnums=(1, 2, 3))
         return fn
 
     def _spec_gamma(self, slot: Slot) -> int:
@@ -5814,20 +5871,18 @@ class LLMEngine:
                     key,
                 )
             )
-        self._count_forward(self.max_batch * (K + 1))
+            opened = self._launch(JIT_VERIFY, K, rows=int(dlen.sum()) + len(plan), lanes=len(plan))
+        self._count_moe_rows(self.max_batch * (K + 1))
         with self._spans.span("engine.verify_readback"):
-            self._verify_readback(plan, K, dlen, emitted_dev, count_dev)
+            self._verify_readback(plan, K, dlen, emitted_dev, count_dev, opened)
 
-    def _verify_readback(self, plan: list, K: int, dlen, emitted_dev, count_dev) -> None:
+    def _verify_readback(self, plan: list, K: int, dlen, emitted_dev, count_dev, opened) -> None:
         with self._spans.span("engine.wait_device"):
             emitted = np.asarray(emitted_dev)  # sync readback: spec rounds don't pipeline
             count = np.asarray(count_dev)
-        self.host_syncs_total += 1
+        self._launches.ready(opened)
         end = time.monotonic()
         self.spec_rounds += 1
-        self.spec_verify_hist[K] = self.spec_verify_hist.get(K, 0) + 1
-        self.decode_steps += 1
-        self._occupancy_sum += len(plan) / self.max_batch
         # the whole k+1-token verify streams the weights ONCE (that is the
         # point of batching the verification) plus each live lane's prefix
         self.hbm_bytes_read += self.param_hbm_bytes + sum(
@@ -5842,7 +5897,6 @@ class LLMEngine:
             l = int(dlen[slot.idx])
             self.spec_drafted += l
             self.spec_accepted += c - 1
-            self.spec_rejected += l - (c - 1)
             if l:
                 slot.spec_ema = (
                     1 - SPEC_EMA_ALPHA
@@ -5982,16 +6036,16 @@ class LLMEngine:
             self._waiting.append(item)
 
     def _process_first(self, entry) -> None:
-        _, slot, req, first, _ = entry
+        _, slot, req, first, sampled = entry
         if slot.request is not req:
             return  # request failed/superseded while the copy was in flight
         with self._spans.span("engine.process_readback", request_id=req.id):
-            self._deliver_first(slot, req, first)
+            self._deliver_first(slot, req, first, sampled)
 
-    def _deliver_first(self, slot: Slot, req: GenRequest, first) -> None:
+    def _deliver_first(self, slot: Slot, req: GenRequest, first, sampled) -> None:
         with self._spans.span("engine.wait_device"):
             first_id = int(np.asarray(first)[0])
-        self.host_syncs_total += 1
+        self._launches.ready(sampled)
         now = time.monotonic()
         req.ttft_ms = 1000 * (now - req.submitted_at)
         self.ttft_ms_recent.append(req.ttft_ms)
@@ -6013,10 +6067,10 @@ class LLMEngine:
 
     @_phase("engine.process_readback")
     def _process_chunk(self, entry) -> None:
-        _, snapshot, toks_dev, _ = entry
+        _, snapshot, toks_dev, opened = entry
         with self._spans.span("engine.wait_device"):
             toks = np.asarray(toks_dev)  # [chunk, B]
-        self.host_syncs_total += 1
+        self._launches.ready(opened)
         chunk = toks.shape[0]
         # ITL = wall time between consecutive chunk completions (including
         # any interleaved prefill chunk) per generated token
@@ -6077,12 +6131,12 @@ class LLMEngine:
         bookkeeping. A finished lane parked in-loop, so its finishing token
         was never fed: ``pending_last=True`` for every fused finish, and
         slot.position lands at start+used (no overshoot feed to roll back)."""
-        _, snapshot, packed_dev, chunk, _ = entry
+        _, snapshot, packed_dev, chunk, opened = entry
         cap_rows = self._fused_cap + 1
         # [cap_rows+5, B]: tokens / counts / reasons / steps / nacc / ndr
         with self._spans.span("engine.wait_device"):
             packed = np.asarray(packed_dev)
-        self.host_syncs_total += 1
+        self._launches.ready(opened)
         steps = int(packed[cap_rows + 2, 0])
         self.fused_steps_total += steps
         if steps < chunk:

@@ -1376,7 +1376,10 @@ class LLMServeApp:
         with the control plane so the management API can return its path.
         The host planes hold the engine's phase spans (utils/spans.py) and
         JAX's own events; ``python_tracer: true`` adds every Python frame,
-        which slows the worker that is being watched."""
+        which slows the worker that is being watched. The answer, and the
+        engine's ``/metrics`` as ``last_capture`` until the next capture,
+        carry the launch ledger at the capture's own two edges: the launches
+        that were really in it."""
         self.requests_total += 1
         err = await self._ensure_engine()
         if err is not None:
@@ -1423,6 +1426,7 @@ class LLMServeApp:
             # PROFILE_LAYER_STEPS (half that wait), whichever comes first;
             # ``duration_s`` in the answer is what was captured.
             t0 = time.monotonic()
+            launches_before = self.engine.launches()
             first = self.engine.forward_passes
             # a layer-step of the hybrid block is about four of the K/V
             # block's in device events (two 0-or-1-trip loops, two kernels, a
@@ -1438,6 +1442,11 @@ class LLMServeApp:
                     await asyncio.sleep(min(0.05, duration))
             finally:
                 duration = time.monotonic() - t0
+                self.engine.last_capture = capture = {
+                    "captured_s": duration,
+                    "launches_before": launches_before,
+                    "launches_after": self.engine.launches(),
+                }
                 await asyncio.to_thread(jax.profiler.stop_trace)
         except Exception as e:
             return web.json_response(
@@ -1449,6 +1458,7 @@ class LLMServeApp:
             {
                 "trace_dir": trace_dir,
                 "duration_s": duration,
+                **capture,
                 "agent_id": self.agent_id,
                 "python_tracer": bool(options.python_tracer_level),
             }

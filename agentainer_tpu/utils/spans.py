@@ -139,6 +139,14 @@ class Spans:
         request-scoped span, ``lanes=``/``tokens=`` on a batch-scoped one."""
         return _Span(self._thread(), name, attrs)
 
+    def note(self, **attrs) -> None:
+        """Attributes for the trace event of the innermost span open on the
+        calling thread, known only once it is under way (a launch's facts,
+        at its dispatch)."""
+        stack = self._thread().stack
+        if stack:
+            stack[-1]._trace.set_metadata(**attrs)
+
     def span_since(self, name: str, since_ns: int, **attrs) -> _Span:
         """A span counted from ``since_ns``, an earlier reading of
         ``time.perf_counter_ns`` on the calling thread."""

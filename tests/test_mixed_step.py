@@ -440,14 +440,13 @@ def test_a_mixed_launch_counts_as_a_prefill_and_a_decode_step_and_no_decode_chun
 
 
 def test_the_moe_counters_count_a_mixed_launch_as_one_pass_of_its_rows():
-    """``_count_forward(bucket + max_batch)``: one pass, ``T + B`` rows."""
+    """``_count_moe_rows(bucket + max_batch)``: one pass, ``T + B`` rows."""
     eng = LLMEngine.create("tiny-moe", options=dict(OPTS))
     try:
         eng.shutdown()  # the worker is gone; the counters are host arithmetic
         k, e = eng.cfg.experts_per_token, eng.cfg.n_experts
-        passes, before = eng.forward_passes, dict(eng.moe)
-        eng._count_forward(32 + eng.max_batch)
-        assert eng.forward_passes == passes + 1
+        before = dict(eng.moe)
+        eng._count_moe_rows(32 + eng.max_batch)
         assert eng.moe["assignments"] - before["assignments"] == 36 * k
         assert eng.moe["rows_all_experts"] - before["rows_all_experts"] == 36 * e  # under this CPU's cut
     finally:

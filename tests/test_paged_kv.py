@@ -143,7 +143,7 @@ def test_prefix_hit_admission_is_zero_copy(pair):
 def test_spec_rewind_is_page_tail_truncation_and_bit_exact(pair):
     """Forced all-reject speculation: the greedy stream stays identical to
     the never-speculating paged AND dense engines, rejected drafts' pages
-    return to the pool (pages_truncated advances), and a post-rejection
+    return to the pool, and a post-rejection
     snapshot restores token-identically."""
     base, dense = pair
     # gamma_max 2 compiles ONE verify bucket (the forced drafts are len 2);
@@ -164,7 +164,7 @@ def test_spec_rewind_is_page_tail_truncation_and_bit_exact(pair):
         r1b, _, r2b = asyncio.run(turns(base))
         r1d, _, r2d = asyncio.run(turns(dense))
         assert r1s["tokens"] == r1b["tokens"] == r1d["tokens"]
-        assert spec.spec_rejected > 0, spec.metrics()
+        assert spec.spec_drafted > spec.spec_accepted, spec.metrics()  # drafts were rejected
         assert r2s["tokens"] == r2b["tokens"] == r2d["tokens"]
         assert blob_s is not None
 

@@ -20,6 +20,7 @@ import pytest
 from aiohttp.test_utils import TestClient, TestServer
 
 from agentainer_tpu.core.protocol import ACCEPTED_NS_HEADER, REQUEST_ID_HEADER
+from agentainer_tpu.engine import llm
 from agentainer_tpu.engine.llm import LLMEngine
 from agentainer_tpu.engine.llm_serve import LLMServeApp
 from agentainer_tpu.utils.compile_cache import enable_compile_cache
@@ -210,12 +211,12 @@ def test_fresh_prompts_prefill_exactly_what_the_arena_did_not_serve():
 
 
 # -- the same spans on the profiler's clock --------------------------------
-def _host_events(trace_dir: str) -> list[tuple[str, int, int]]:
+def _host_events(trace_dir: str, stats: bool = False) -> list[tuple]:
     files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"), recursive=True)
     assert files, f"no .xplane.pb under {trace_dir}"
     data = jax.profiler.ProfileData.from_file(max(files, key=os.path.getmtime))
     return [
-        (ev.name, int(ev.start_ns), int(ev.start_ns + ev.duration_ns))
+        (ev.name, int(ev.start_ns), int(ev.start_ns + ev.duration_ns)) + ((dict(ev.stats),) if stats else ())
         for plane in data.planes if plane.name.startswith("/host:")
         for ln in plane.lines for ev in ln.events
     ]
@@ -265,6 +266,66 @@ def test_profile_capture_holds_the_engine_spans(tmp_path, monkeypatch, python_tr
         assert frames  # the operator asked for Python frames and got them
     else:
         assert not frames, frames[:5]
+
+
+def test_dispatch_spans_carry_their_launch_and_profile_answers_its_edges(tmp_path, monkeypatch):
+    """Each dispatch span's trace event names the launch it made (ISSUE 52):
+    ``program`` (the XLA module's name, as the device plane shows it), ``key``
+    (bucket, rung, K), ``steps``, ``rows``, ``lanes``, from the one
+    record the launch ledger counted. ``/profile`` answers the ledger at the
+    capture's own two edges, and ``/metrics`` keeps them as ``last_capture``:
+    the launches between them are the dispatch spans the capture holds."""
+    monkeypatch.setenv("AGENTAINER_PROFILE_DIR", str(tmp_path))
+
+    async def body():
+        eng = LLMEngine.create("tiny", options=dict(TINY))
+        serve = LLMServeApp(env={"AGENTAINER_AGENT_ID": "launches"})
+        serve.engine = eng
+        client = TestClient(TestServer(serve.app()))
+        await client.start_server()
+        try:
+            for message in ("warm", "warm " * 40):  # compile outside the capture
+                resp = await client.post("/chat", json={"message": message, "session": "w", "max_tokens": 20})
+                assert resp.status == 200, await resp.text()
+            assert (await (await client.get("/metrics")).json())["last_capture"] is None
+            capture = asyncio.ensure_future(client.post("/profile", json={"duration_s": 1.5}))
+            await asyncio.sleep(0.4)
+            # two chunks of 32, then the ladder (and a verify round, if a lane drafts)
+            resp = await client.post("/chat", json={"message": "hello spans " * 10, "session": "s", "max_tokens": 20})
+            assert resp.status == 200, await resp.text()
+            prof = await capture
+            assert prof.status == 200, await prof.text()
+            return await prof.json(), await (await client.get("/metrics")).json()
+        finally:
+            await client.close()
+            eng.shutdown()
+
+    doc, metrics = asyncio.run(body())
+    assert metrics["last_capture"] == {k: doc[k] for k in ("captured_s", "launches_before", "launches_after")}
+    assert doc["captured_s"] == doc["duration_s"]
+
+    def launched(ledger, program):
+        return sum(row["n"] for row in ledger.get(program, {}).values())
+
+    spans = {
+        "engine.prefill_dispatch": llm.JIT_PREFILL, "engine.decode_dispatch": llm.JIT_DECODE_N,
+        "engine.first_token_sample": llm.JIT_FIRST_TOKEN, "engine.verify_dispatch": llm.JIT_VERIFY,
+    }
+    events = [(name, st) for name, _, _, st in _host_events(doc["trace_dir"], stats=True) if name in spans]
+    for name, program in spans.items():
+        mine = [st for n, st in events if n == name]
+        assert len(mine) == launched(doc["launches_after"], program) - launched(doc["launches_before"], program)
+        if name != "engine.verify_dispatch":
+            assert mine, name
+        for st in mine:
+            assert set(st) >= {"program", "key", "steps", "rows", "lanes"}, (name, st)
+            assert st["program"] == program
+    chunks = [st for n, st in events if n == "engine.prefill_dispatch"]
+    assert [(st["key"], st["steps"], st["lanes"]) for st in chunks] == [(32, 1, 0)] * len(chunks)
+    assert sorted(st["rows"] for st in chunks)[-1] == 32
+    rungs = [st for n, st in events if n == "engine.decode_dispatch"]
+    assert all(st["steps"] == st["key"] and st["rows"] == st["lanes"] == 1 for st in rungs)
+    assert [(st["steps"], st["rows"]) for n, st in events if n == "engine.first_token_sample"] == [(0, 1)]
 
 
 def test_profile_capture_ends_with_the_layer_steps_it_may_hold(tmp_path, monkeypatch):
@@ -447,28 +508,49 @@ def _lower_step(eng: LLMEngine, step: str):
     if step == "decode_n":
         keys = jax.random.split(jax.random.PRNGKey(0), eng.decode_chunk)
         return eng._decode_n.lower(eng.params, eng.cache, *eng._bt_arg(), *lanes, keys)
+    if step == "prefill_with_decode":
+        if eng._prefill_with_decode is None:
+            return None
+        return eng._prefill_with_decode.lower(
+            eng.params, eng.cache, jnp.int32(0), jnp.zeros((1, 32), jnp.int32), jnp.zeros((1, 32), jnp.int32),
+            jnp.int32(5), *lanes, jax.random.split(jax.random.PRNGKey(0), 1),
+        )
+    if step == "fused":
+        live = jnp.zeros((B,), bool)
+        return eng._fused_fn().lower(
+            eng.params, eng.cache, *eng._bt_arg(), *lanes, eng._dhist, eng._dhlen,
+            eng._stok, eng._spos, eng._stemps, eng._stopk, eng._stopp, eng._shist, eng._shlen,
+            live, live, jnp.zeros((B,), jnp.int32), live,
+            jax.random.split(jax.random.PRNGKey(0), eng._fused_cap), jnp.int32(1),
+        )
     return eng._verify_fn(2).lower(
         eng.params, eng.cache, *eng._bt_arg(), *lanes,
         jnp.zeros((B, 2), jnp.int32), jnp.zeros((B,), jnp.int32), jax.random.PRNGKey(0),
     )
 
 
-STEP_MODULES = {"prefill": "jit_prefill", "first_token": "jit_first_token", "decode_n": "jit_decode_n", "verify": "jit_verify"}
+STEP_MODULES = {
+    "prefill": llm.JIT_PREFILL, "first_token": llm.JIT_FIRST_TOKEN, "decode_n": llm.JIT_DECODE_N,
+    "verify": llm.JIT_VERIFY, "prefill_with_decode": llm.JIT_PREFILL_WITH_DECODE, "fused": llm.JIT_FUSED,
+}
 
 
-@pytest.mark.parametrize("step, prefix", sorted(STEP_MODULES.items()))
-def test_step_module_names_the_benchmark_matches(step_engine, step, prefix):
+@pytest.mark.parametrize("step, name", sorted(STEP_MODULES.items()))
+def test_step_module_names_the_benchmark_matches(step_engine, step, name):
     """``benchmark/layer_metrics/{decode,prefill}_step_roofline.py`` and
-    ``prefill_dev_share.py`` find the steps' device time by these prefixes of
-    the XLA module name; a renamed step function must fail here, not turn a
-    roofline into ``None``. ``jit_first_token`` is the sampler after the
-    final prefill chunk: a module of its own, so that ``jit_prefill``'s
-    device time stays the forward pass alone."""
-    text = _lower_step(step_engine, step).as_text()
-    module = re.search(r"module @(\w+)", text).group(1)
-    assert module.startswith(prefix), module
-    others = set(STEP_MODULES.values()) - {prefix}
-    assert not any(module.startswith(o) for o in others), module
+    ``prefill_dev_share.py`` find the steps' device time by prefixes of the XLA
+    module name, and the launch ledger's rows (``/metrics`` ``launches``) join
+    a trace's modules by the whole name: the engine's constants are the
+    lowered programs' names, on the dense arena and on the page pool; a
+    renamed step function must fail here, not turn a roofline into ``None``.
+    ``jit_first_token`` is the sampler after the final prefill chunk: a module
+    of its own, so that ``jit_prefill``'s device time stays the forward pass
+    alone."""
+    lowered = _lower_step(step_engine, step)
+    if lowered is None:
+        assert step == "prefill_with_decode" and step_engine.paged  # the page pool keeps two launches
+        return
+    assert re.search(r"module @(\w+)", lowered.as_text()).group(1) == name
 
 
 # -- the ``attention`` block's fetch counters (ISSUE 33) -----------------------

@@ -72,7 +72,7 @@ def test_greedy_bit_exact_with_and_without_speculation():
         assert m["spec_rounds"] > 0, m
         assert m["spec_drafted"] > 0 and m["spec_accepted"] > 0, m
         assert m["spec_verify_hist"], m
-        assert m["spec_acceptance_rate"] is not None
+        assert 0 < m["spec_accepted"] <= m["spec_drafted"]
         after = {b: spec._verify_fns[b]._cache_size() for b in spec._spec_buckets}
         assert after == sizes, (sizes, after)
         bm = base.metrics()
@@ -160,7 +160,7 @@ def test_rejected_drafts_then_restore_round_trip_matches_plain():
         # drafts were really scored, and not all of them accepted — the
         # rewind path (position pulled back past rejected tokens) ran
         assert spec.spec_drafted > 0
-        assert spec.spec_rejected > 0, spec.metrics()
+        assert spec.spec_drafted > spec.spec_accepted, spec.metrics()  # drafts were rejected
         # (a) direct continuation after rewinds is token-identical
         assert r2s["tokens"] == r2b["tokens"], (r2s["tokens"], r2b["tokens"])
         # (b) the speculated engine's snapshot restores into a
@@ -227,7 +227,7 @@ def test_acceptance_ema_collapses_on_rejecting_traffic():
         assert r["completion_tokens"] == 120
         m = eng.metrics()
         assert m["spec_drafted"] > 0, m
-        assert m["spec_rejected"] > 0, m
+        assert m["spec_drafted"] > m["spec_accepted"], m  # drafts were rejected
         # the lane's EMA fell below the collapse floor → gamma 0 → later
         # tokens came from the plain decode path (visible per slot)
         assert min(m["spec_slot_acceptance"]) < SPEC_EMA_FLOOR, m

@@ -83,12 +83,11 @@ def test_admission_burst_fairness():
         adm = m["admission_samples"]
         assert len(adm) == 8  # one per admitted prompt
         assert m["admission_ms_p50"] is not None
-        assert m["admission_ms_max"] is not None
         # every session's TTFT includes its admission wait; the histogram
         # separating them is the point — sanity-check the ordering holds
         assert m["admission_ms_p50"] <= (m["ttft_ms_p50"] or float("inf"))
         # generous absolute bound: the whole burst is 8 tiny prefills; a
         # serialized pathological scheduler would blow far past this
-        assert m["admission_ms_max"] < 5000
+        assert max(adm) < 5000
     finally:
         engine.shutdown()

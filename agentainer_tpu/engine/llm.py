@@ -68,7 +68,7 @@ from ..models.llama import (
     ring_plan,
 )
 from ..ops.attention import pages_to_rows, plan_cache_attention, rows_to_pages
-from ..ops.moe import row_tile, sorted_rows
+from ..ops.moe import kernel_by_default, row_tile, sorted_rows
 from ..utils.launches import Launches
 from ..utils.spans import Spans
 from .sampling import NEG_INF, sample, sample_step
@@ -1575,11 +1575,16 @@ class LLMEngine:
             "routed_from_rows": self._moe_sorted_from,
             # cumulative, counted on the host at each launch from its static
             # shapes (``_count_moe_rows``): Σ N·k; Σ N·E over launches under the
-            # cut; Σ rows the grouped FFN was given, tile padding included
+            # cut; Σ rows of the grouped FFN's planned grid, tile padding and
+            # the tiles no routing needs included; Σ N over launches over the
+            # cut whose rows reach their tiles and come back inside the
+            # kernel (all of them on a TPU, none where the plain path serves)
             "assignments": 0,
             "rows_all_experts": 0,
             "rows_routed": 0,
+            "rows_gathered_in_kernel": 0,
         }
+        self._moe_in_kernel = kernel_by_default()
         if cfg.is_moe:
             served = (
                 "every call" if self._moe_sorted_from is None
@@ -4925,6 +4930,8 @@ class LLMEngine:
             self.moe["rows_routed"] += passes * sorted_rows(
                 rows, self.cfg.n_held, k, row_tile(rows, e, k)
             )
+            if self._moe_in_kernel:
+                self.moe["rows_gathered_in_kernel"] += passes * rows
         elif not self.routed_moe:
             self.moe["rows_all_experts"] += passes * rows * self.cfg.n_held
 

@@ -8,17 +8,28 @@ that happens on the device at hand; from there on the FFN computes only the
 (token, chosen expert) pairs:
 
 1. the call's ``N·k`` assignments are sorted by expert;
-2. each expert's rows are copied into a buffer whose groups start on a
-   row tile, so a tile belongs to one expert (:func:`route`);
-3. one grouped SwiGLU FFN runs every tile against its expert's three
-   matrices, read from the stacked weights as stored (int8 included) —
-   ``ops/pallas_moe.grouped_ffn`` on a TPU, ``lax.ragged_dot`` elsewhere —
-   and weights each row by its gate;
-4. a token's k rows are summed in float32.
+2. each expert's rows get places in a plan of row tiles whose groups start
+   on a tile, so a tile belongs to one expert (:func:`route`); the plan is
+   sized for the worst routing, and is a table of ``N·k`` row numbers and
+   one of a tile's expert — no array has the plan's rows;
+3. one grouped SwiGLU FFN (``ops/pallas_moe.grouped_ffn`` on a TPU) takes
+   ``x [N, d]`` as it comes, gathers a stage of the plan's rows at a time
+   inside its VMEM, runs every tile that holds rows against its expert's
+   three matrices, read from the stacked weights as stored (int8 and their
+   scales included), weights each row by its gate and
+4. sums a token's k rows in float32 into one ``[N, d]`` accumulator, still
+   in VMEM (since PR 53; before, rows went to an HBM buffer of the plan's
+   size and came back through a 0/1 matrix, and everything XLA did around
+   the kernel was sized by the worst routing: 3,104 rows for the 264 real
+   ones of a Laguna launch).
 
-Nothing is dropped and there is no capacity: the buffer is sized for the
-worst routing (every tile count a group can need), an expert with all N
-rows included, and tiles no group needs are skipped by the kernel.
+Off the TPU the plain form serves and is what the kernel is tested against:
+the row buffer, the 0/1 spread matrix on both sides and ``lax.ragged_dot``
+(:func:`_grouped_ffn_xla`).
+
+Nothing is dropped and there is no capacity: the plan holds every tile
+count a group can need, an expert with all N rows included, and tiles no
+group needs are skipped by the kernel.
 """
 
 from __future__ import annotations
@@ -64,6 +75,12 @@ def sorted_from_rows(
     return int(ridge) + 1
 
 
+def kernel_by_default() -> bool:
+    """Whether a sorted call takes the Pallas kernel where nobody says: on a
+    TPU backend (what the engine's ``moe.rows_gathered_in_kernel`` counts by)."""
+    return jax.default_backend() == "tpu"
+
+
 def row_tile(n_rows: int, n_experts: int, k: int) -> int:
     """Rows of a tile: the power of two at or over ONE AND A HALF times an
     expert's fair share of the assignments, between 32 and 128. An expert
@@ -83,10 +100,11 @@ def row_tile(n_rows: int, n_experts: int, k: int) -> int:
 
 
 def sorted_rows(n_rows: int, n_experts: int, k: int, tile: int | None = None) -> int:
-    """Rows of the buffer the grouped FFN is given for a call of ``n_rows``:
-    every tile the worst routing can need. A group of c rows takes
-    ``ceil(c / tile)`` tiles; the groups hold ``n_rows·k`` rows between
-    them and none more than ``n_rows`` (a token's choices are distinct).
+    """Rows of the tile plan of a call of ``n_rows`` (the kernel's grid; off
+    the TPU the plain path's row buffer): every tile the worst routing can
+    need. A group of c rows takes ``ceil(c / tile)`` tiles; the groups hold
+    ``n_rows·k`` rows between them and none more than ``n_rows`` (a token's
+    choices are distinct).
     ``n_experts`` is what the stack holds (a token has at most that many of
     its choices here)."""
     tile = tile if tile is not None else row_tile(n_rows, n_experts, k)
@@ -98,7 +116,7 @@ def sorted_rows(n_rows: int, n_experts: int, k: int, tile: int | None = None) ->
 class Routing(NamedTuple):
     tile_expert: jnp.ndarray  # [tiles] the expert of each row tile
     n_active: jnp.ndarray  # tiles that hold rows; they come first
-    row_of: jnp.ndarray  # [N·k] the buffer row of each assignment (token·k + choice)
+    row_of: jnp.ndarray  # [N·k] the plan's row of each assignment (token·k + choice)
 
 
 def route(chosen: jnp.ndarray, n_experts: int, tile: int, n_rows_out: int) -> Routing:
@@ -127,13 +145,15 @@ def route(chosen: jnp.ndarray, n_experts: int, tile: int, n_rows_out: int) -> Ro
 
 def stacked_experts(layers: dict) -> dict:
     """The expert weights of a stacked ``layers`` pytree as the grouped FFN
-    takes them: ``name -> (weights [L, E, ·, ·] as stored, float32 scales
-    [L, E, 1, ·])``; float weights get scales of one."""
+    takes them: ``name -> (weights [L, E, ·, ·], scales [L, E, 1, ·])``, both
+    as stored: nothing the size of a stack is converted in a traced step (the
+    kernel converts a block's scales in VMEM); float weights get scales of
+    one."""
     out = {}
     for name in EXPERT_WEIGHTS:
         w = layers[name]
         if isinstance(w, QTensor):
-            out[name] = (w.q, w.scale.astype(jnp.float32))
+            out[name] = (w.q, w.scale)
         else:
             out[name] = (w, jnp.ones(w.shape[:2] + (1, w.shape[3]), jnp.float32))
     return out
@@ -156,7 +176,7 @@ def _grouped_ffn_xla(x_rows, row_gate, routing, experts, layer, tile, act: str =
 
     def dense(name):
         w, s = (lax.dynamic_index_in_dim(t, layer, 0, keepdims=False) for t in experts[name])
-        return (w.astype(jnp.float32) * s).astype(x_rows.dtype)
+        return (w.astype(jnp.float32) * s.astype(jnp.float32)).astype(x_rows.dtype)
 
     gate = gate_act(act)(lax.ragged_dot(x_rows, dense("w_gate"), sizes))
     up = lax.ragged_dot(x_rows, dense("w_up"), sizes)
@@ -183,17 +203,29 @@ def sorted_moe_ffn(
     ``[offset, offset + E)`` of ``n_total`` experts and ``chosen`` indexes
     all of them; assignments to absent experts (a negative choice, a row
     routed nowhere, among them) are dropped before the sort (they sort last,
-    get no buffer row and add nothing), and the row tile follows the share
-    of a token's choices that lands here.
+    get no row and add nothing), and the row tile follows the share of a
+    token's choices that lands here.
 
-    Rows go to the buffer and come back through one 0/1 matrix ``[M, N]``
-    (row r holds token n) on the MXU: exact — a row is one token's values,
-    a token's output the float32 sum of its k rows — and N·M·d FLOPs, 2 % of
-    the grouped FFN's at 256 rows, where row gathers measured three times
-    that. (From about 1,024 rows a launch the gathers are cheaper: N·M
-    grows with N², a gather with N.)"""
+    With the kernel the rows' trip to their tiles and back happens in its
+    VMEM (ops/pallas_moe.py): what is traced here is the routing's tables of
+    ``N·k`` and ``tiles`` integers, and nothing has ``sorted_rows`` rows.
+    Without it rows go to a buffer of that many and come back through one 0/1
+    matrix ``[M, N]`` (row r holds token n): exact — a row is one token's
+    values, a token's output the float32 sum of its k rows — and the plain
+    form the kernel is tested against."""
     n, k = chosen.shape
     n_experts = experts["w_gate"][0].shape[1]
+    in_kernel = interpret or (kernel_by_default() if kernel is None else kernel)
+    if in_kernel:
+        from .pallas_moe import grouped_ffn, resident_rows
+
+        most = resident_rows(x.shape[1], x.dtype.itemsize)
+        if n > most:  # more rows than the kernel keeps in VMEM: a piece at a time
+            return jnp.concatenate([
+                sorted_moe_ffn(x[at : at + most], gates[at : at + most], chosen[at : at + most], experts, layer,
+                               kernel=kernel, interpret=interpret, held=held, act=act)
+                for at in range(0, n, most)
+            ])
     if held is None:
         tile = row_tile(n, n_experts, k)
         m = sorted_rows(n, n_experts, k)
@@ -211,26 +243,20 @@ def sorted_moe_ffn(
             row_of=jnp.where(here.reshape(-1), routing.row_of, m),
             n_active=jnp.maximum(routing.n_active, 1),
         )
-    # holds[r, a]: buffer row r is assignment a (token a // k). M·N·k compares,
-    # 80 µs of a 0.73 ms OLMoE layer at 256 rows; with the tokens minor
-    # (``[M, k, N]``) the gate's reduction measured 12 x slower
+    if in_kernel:
+        (wg, sg), (wu, su), (wd, sd) = (experts[name] for name in EXPERT_WEIGHTS)
+        out = grouped_ffn(
+            x, gates, routing.row_of, routing.tile_expert, routing.n_active,
+            wg, sg, wu, su, wd, sd, layer, tile=tile, interpret=interpret,
+            **({"act": act} if act != "silu" else {}),
+        )
+        return out.astype(x.dtype)
+    # holds[r, a]: buffer row r is assignment a (token a // k)
     holds = routing.row_of[None, :] == jnp.arange(m)[:, None]
     row_gate = jnp.sum(
         jnp.where(holds, gates.reshape(1, n * k).astype(jnp.float32), 0.0), axis=1, keepdims=True
     )  # 0 for a group's padding rows
     spread = jnp.any(holds.reshape(m, n, k), axis=2).astype(x.dtype)  # [M, N]
     x_rows = jnp.dot(spread, x, preferred_element_type=jnp.float32).astype(x.dtype)
-    if kernel is None:
-        kernel = jax.default_backend() == "tpu"
-    if kernel or interpret:
-        from .pallas_moe import grouped_ffn
-
-        (wg, sg), (wu, su), (wd, sd) = (experts[name] for name in EXPERT_WEIGHTS)
-        y = grouped_ffn(
-            x_rows, row_gate, routing.tile_expert, routing.n_active,
-            wg, sg, wu, su, wd, sd, layer, tile=tile, interpret=interpret,
-            **({"act": act} if act != "silu" else {}),
-        )
-    else:
-        y = _grouped_ffn_xla(x_rows, row_gate, routing, experts, layer, tile, act)
+    y = _grouped_ffn_xla(x_rows, row_gate, routing, experts, layer, tile, act)
     return jnp.einsum("mn,md->nd", spread, y, preferred_element_type=jnp.float32).astype(x.dtype)

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.lax import top_k as lax_top_k
 
 from agentainer_tpu.engine.llm import LLMEngine
 from agentainer_tpu.models.configs import get_config
@@ -278,6 +279,35 @@ def test_the_moe_counters_follow_the_launches():
     # the routed share the counters give: near 1 for the long prompt's prefill
     share = d1["rows_routed"] / (d1["rows_routed"] + d1["rows_all_experts"] / (e / k))
     assert share > 0.9 or decode1 > 256
+    assert moe2["rows_gathered_in_kernel"] == 0  # this backend's sorted calls take the plain path
+
+
+@pytest.mark.parametrize("in_kernel", [False, True], ids=["plain_path", "kernel"])
+def test_rows_gathered_in_kernel_counts_the_launches_over_the_cut(monkeypatch, in_kernel):
+    """``moe.rows_gathered_in_kernel``: the rows of the launches over the cut
+    where the sorted FFN's default is the kernel (a TPU backend), so that
+    over (assignments ÷ k − rows_all_experts ÷ experts held) is the share of
+    sorted rows whose trip happened in VMEM: 1 on the chip, 0 here.
+    ``rows_routed`` stays the planned grid's rows either way."""
+    import agentainer_tpu.engine.llm as llm
+
+    monkeypatch.setattr(llm, "kernel_by_default", lambda: in_kernel)
+    eng = LLMEngine.create(
+        "tiny-moe",
+        options={"max_batch": 4, "max_seq": 256, "decode_chunk": 8, "prefill_chunk": 256, "quant": "int8",
+                 "skip_warmup": True},
+    )
+    try:
+        e, k = eng.cfg.n_experts, eng.cfg.experts_per_token
+        eng._count_moe_rows(256 + 4)  # a mixed launch: over the cut
+        eng._count_moe_rows(4, 8)  # a decode chunk: under it
+        moe = eng.metrics()["moe"]
+    finally:
+        eng.shutdown()
+    assert moe["rows_routed"] == sorted_rows(260, e, k) and moe["rows_all_experts"] == 8 * 4 * e
+    assert moe["rows_gathered_in_kernel"] == (260 if in_kernel else 0)
+    over_the_cut = moe["assignments"] // k - moe["rows_all_experts"] // e
+    assert over_the_cut == 260
 
 
 def test_a_tp_mesh_keeps_the_einsum():
@@ -294,3 +324,112 @@ def test_a_tp_mesh_keeps_the_einsum():
         eng.shutdown()
     assert moe["impl"] == moe["prefill_impl"] == "all_experts_einsum"
     assert moe["routed_from_rows"] is None
+
+
+# the (rows a launch, experts held, experts, top-k) of the benchmark's six MoE cells
+CELL_SHAPES = {
+    "laguna.docs": (264, 32, 256, 8), "kimi.decode": (320, 32, 256, 8), "mistral4.docs": (272, 32, 128, 4),
+    "smallthinker.mixed": (264, 16, 64, 6), "olmoe.longprompt": (272, 64, 64, 8), "mixtral.longprompt": (264, 8, 8, 2),
+}
+
+
+def _served_experts(e_held: int, d: int, f: int, layers: int = 2) -> dict:
+    """Stacked int8 experts with bfloat16 per-channel scales, as the engine stores them."""
+    keys = jax.random.split(jax.random.PRNGKey(53), 6)
+    out = {}
+    for i, (name, (rows, cols)) in enumerate(zip(EXPERT_WEIGHTS, ((d, f), (d, f), (f, d)))):
+        q = jax.random.randint(keys[i], (layers, e_held, rows, cols), -127, 128, jnp.int8)
+        scale = jax.random.uniform(keys[3 + i], (layers, e_held, 1, cols), jnp.float32, 0.5, 1.5) / (127 * rows**0.5)
+        out[name] = QTensor(q, scale.astype(jnp.bfloat16))
+    return stacked_experts(out)
+
+
+@pytest.mark.parametrize("routing", ["uniform", "one_expert", "none_here", "parked_lanes", "reglu"])
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_the_kernel_gathers_and_sums_what_the_plain_path_does(cell, routing):
+    """The grouped kernel (interpret mode) against the plain path — the row
+    buffer, the 0/1 spread matrix and ``ragged_dot`` — at the six cells'
+    launch shapes with small d and F, int8 experts with bfloat16 scales as
+    stored, the second layer of the stack: the rows reach their tiles and a
+    token's k terms come back summed inside the kernel, a stage of 128 rows at
+    a time (every shape here has more than one). ``uniform``: random distinct
+    choices; ``one_expert``: every row on the same k experts, so each gets all
+    N rows in several tiles; ``none_here``: no choice lands in the held share
+    (a whole share: every row routed nowhere), and the result is 0 exactly;
+    ``parked_lanes``: the launch's last rows choose no expert; ``reglu``:
+    SmallThinker's gate."""
+    n, e_held, e, k = CELL_SHAPES[cell]
+    d, f, lanes = 128, 256, n - 256
+    experts = _served_experts(e_held, d, f)
+    kx, kl = jax.random.split(jax.random.PRNGKey(n + e))
+    x = jax.random.normal(kx, (n, d), jnp.float32)
+    offset = 0 if e_held == e else e_held  # a share in the middle of the router's experts
+    gates, chosen = lax_top_k(jax.nn.softmax(jax.random.normal(kl, (n, e))), k)
+    held = (offset, e) if e_held != e else None
+    if routing == "one_expert":
+        chosen = jnp.broadcast_to(offset + jnp.arange(k) % e_held, (n, k)) if k <= e_held else chosen
+    elif routing == "none_here":
+        chosen = (offset + e_held + chosen % (e - e_held)) % e if e_held != e else jnp.full((n, k), -1)
+        held = (offset, e)
+    elif routing == "parked_lanes":
+        chosen = jnp.where((jnp.arange(n) < n - lanes // 2)[:, None], chosen, -1)
+        held = (offset, e)
+    act = {"act": "relu"} if routing == "reglu" else {}
+    want = sorted_moe_ffn(x, gates, chosen, experts, jnp.int32(1), kernel=False, held=held, **act)
+    got = sorted_moe_ffn(x, gates, chosen, experts, jnp.int32(1), interpret=True, held=held, **act)
+    scale = float(jnp.max(jnp.abs(want)))
+    if routing == "none_here":
+        assert scale == 0.0 and not np.asarray(got).any()
+        return
+    assert scale > 0
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=TOL["int8"] * scale)
+    if routing == "parked_lanes":
+        assert not np.asarray(got[n - lanes // 2 :]).any()
+
+
+def test_the_kernel_sums_over_several_blocks_of_f_and_cuts_a_long_call(monkeypatch):
+    """Two plans the served shapes reach only at published widths: an F cut
+    into blocks (Mixtral's 14,336 columns; here a weight plan of 300 KiB cuts
+    256 into two), where a stage's rows are gathered at its first tile's first
+    block and combined at its last tile's last; and a call of more rows than
+    the kernel keeps in VMEM (here a plan of 200 rows), cut into pieces."""
+    from agentainer_tpu.ops import pallas_moe
+
+    n, e, k, d, f = 300, 8, 2, 128, 256
+    experts = _served_experts(e, d, f)
+    x = jax.random.normal(jax.random.PRNGKey(1), (n, d), jnp.float32)
+    gates, chosen = lax_top_k(jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(2), (n, e))), k)
+    want = sorted_moe_ffn(x, gates, chosen, experts, jnp.int32(0), kernel=False)
+    monkeypatch.setattr(pallas_moe, "_WEIGHT_VMEM", 300 << 10)
+    assert pallas_moe.ffn_block(d, f, 1, 4) == 128
+    pallas_moe.grouped_ffn.clear_cache()
+    got = sorted_moe_ffn(x, gates, chosen, experts, jnp.int32(0), interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=TOL["int8"] * float(jnp.max(jnp.abs(want))))
+    monkeypatch.setattr(pallas_moe, "_ROWS_VMEM", 200 * d * 8)
+    assert pallas_moe.resident_rows(d, 4) == 200
+    got = sorted_moe_ffn(x, gates, chosen, experts, jnp.int32(0), interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=TOL["int8"] * float(jnp.max(jnp.abs(want))))
+    pallas_moe.grouped_ffn.clear_cache()
+
+
+def test_a_dropped_choice_adds_nothing_where_the_last_stage_outruns_the_plan(monkeypatch):
+    """A choice with no row carries the plan's row count ``m`` as its row.
+    136 rows, 4 of 16 experts held, top-2: ``m`` is 384, so the second stage of
+    256 rows (the stage set to that here) outruns the plan by 128 — whose places in the stage still hold the
+    first stage's results. Nearly every row chooses held experts 0 and 1 (ten
+    tiles: both stages run); the last four choose absent ones and get 0."""
+    from agentainer_tpu.ops import pallas_moe
+
+    monkeypatch.setattr(pallas_moe, "_STAGE_ROWS", 256)
+    pallas_moe.grouped_ffn.clear_cache()
+    n, e_held, e, k, d, f = 136, 4, 16, 2, 128, 256
+    assert row_tile(n, e, k) == 32 and sorted_rows(n, e_held, k, 32) == 384
+    experts = _served_experts(e_held, d, f)
+    x = jax.random.normal(jax.random.PRNGKey(3), (n, d), jnp.float32)
+    chosen = jnp.where((jnp.arange(n) < n - 4)[:, None], jnp.array([0, 1]), jnp.array([9, 12]))
+    gates = jnp.full((n, k), 0.5, jnp.float32)
+    want = sorted_moe_ffn(x, gates, chosen, experts, jnp.int32(1), kernel=False, held=(0, e))
+    got = sorted_moe_ffn(x, gates, chosen, experts, jnp.int32(1), interpret=True, held=(0, e))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=TOL["int8"] * float(jnp.max(jnp.abs(want))))
+    assert np.asarray(want[: n - 4]).any() and not np.asarray(got[n - 4 :]).any()
+    pallas_moe.grouped_ffn.clear_cache()

@@ -623,7 +623,7 @@ def test_moe_block_names_no_path_for_a_dense_model(served):
             "impl": "none", "experts": 0, "top_k": 0, "renormalize": False,
             "experts_held": 0, "shared_experts": 0, "router": None,
             "prefill_impl": "none", "routed_from_rows": None,
-            "assignments": 0, "rows_all_experts": 0, "rows_routed": 0,
+            "assignments": 0, "rows_all_experts": 0, "rows_routed": 0, "rows_gathered_in_kernel": 0,
         }
         assert doc["model_arch"]["qk_norm"] is False
 
@@ -650,7 +650,7 @@ def test_moe_block_names_the_path_the_steps_trace(config, options, impl, prefill
         # every expert is in the stack, none is shared, the router is a softmax (ISSUE 30's three keys)
         "experts_held": cfg.n_experts, "shared_experts": 0, "router": "softmax",
         "prefill_impl": prefill_impl, "routed_from_rows": cut,
-        "assignments": 0, "rows_all_experts": 0, "rows_routed": 0,
+        "assignments": 0, "rows_all_experts": 0, "rows_routed": 0, "rows_gathered_in_kernel": 0,
     }
     assert m["model_arch"]["qk_norm"] is cfg.qk_norm and m["model_arch"]["head_dim"] == cfg.head_dim
 
@@ -751,7 +751,7 @@ def test_a_laguna_engine_names_both_kinds_the_gate_the_rotaries_and_the_rings_by
     for key in ("window_wraps", "window_decode_blocks_live", "window_decode_blocks_unbounded", "global_decode_rows",
                 "window_decode_rows", "rows_positioned", "rows_past_original_max", "decode_blocks_live", "decode_blocks_stored"):
         assert a[key] > 0, key
-    for key in ("experts_held", "shared_experts", "router", "assignments", "rows_routed", "rows_all_experts"):
+    for key in ("experts_held", "shared_experts", "router", "assignments", "rows_routed", "rows_all_experts", "rows_gathered_in_kernel"):
         assert key in m["moe"], key
     for name in ("engine.snapshot", "engine.restore", "engine.state_reset"):
         assert m["phases"][name]["n"] >= 1, (name, sorted(m["phases"]))

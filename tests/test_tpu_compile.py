@@ -26,7 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSh
 
 from agentainer_tpu.models.configs import get_config
 from agentainer_tpu.models.llama import KVCache, forward, init_params
-from agentainer_tpu.ops.moe import sorted_moe_ffn, sorted_rows
+from agentainer_tpu.ops.moe import row_tile, sorted_moe_ffn, sorted_rows
 from agentainer_tpu.ops.quant import QTensor
 from agentainer_tpu.ops.pallas_attention import (
     flash_decode,
@@ -161,40 +161,80 @@ def test_meshed_flash_compiles_for_v5e_2x2(v5e, t):
 
 
 # ---------------------------------------------------------------------------
-# the sorted grouped MoE FFN (ISSUE 29) at the two published expert shapes,
-# int8 as served: (layers of the stack, experts, d, F, top-k)
-EXPERT_SHAPES = {"mixtral": (6, 8, 4096, 14336, 2), "olmoe": (16, 64, 2048, 1024, 8)}
+# the sorted grouped MoE FFN (ISSUE 29) at the six published expert shapes the
+# benchmark serves, int8 with bfloat16 scales as stored: (layers of the stack,
+# experts held, experts, d, F, top-k, the gate, rows of the cell's mixed launch)
+EXPERT_SHAPES = {
+    "mixtral": (6, 8, 8, 4096, 14336, 2, "silu", 264),
+    "olmoe": (16, 64, 64, 2048, 1024, 8, "silu", 272),
+    "smallthinker": (8, 16, 64, 2560, 768, 6, "relu", 264),
+    "mistral4": (4, 32, 128, 4096, 2048, 4, "silu", 272),
+    "kimi": (8, 32, 256, 2304, 1024, 8, "silu", 320),
+    "laguna": (8, 32, 256, 2048, 512, 8, "silu", 264),
+}
 
 
-@pytest.mark.parametrize("rows", [128, 256, 512, 1024])
+@pytest.mark.parametrize("rows", [128, "launch", 512, 1024])
 @pytest.mark.parametrize("shape", sorted(EXPERT_SHAPES))
 def test_sorted_moe_ffn_compiles_for_v5e(v5e, shape, rows):
-    """Routing, the Mosaic grouped FFN and the combine, over the stacked int8
-    experts with the layer as a scalar: the kernel is there, and its
-    temporaries are the row buffer's (rows in, rows out, the 0/1 spread
-    matrix), under one int8 matrix of a layer's experts — no slice, relayout
-    or dequantised copy of them."""
-    layers, e, d, f, k = EXPERT_SHAPES[shape]
+    """Routing and the Mosaic grouped FFN over the stacked int8 experts with
+    the layer as a scalar, a chip's share of the experts where the cell serves
+    one, at the prefill buckets and at the cell's own mixed launch (256 rows +
+    its lanes: a row count that is no multiple of 128). The kernel is there
+    with ``x [N, d]`` and the float32 ``[N, d]`` accumulator resident in its
+    VMEM (the 1,024-row bucket at d = 4,096 included: 8 + 16 MB beside 40 MB of
+    weight blocks), and what the program holds in HBM beside it is under ONE
+    such accumulator plus the routing's tables: nothing with ``sorted_rows``
+    rows (before PR 53 three row buffers of that many), no slice, relayout or
+    dequantised copy of the experts, no scale stack converted or relaid."""
+    layers, e, total, d, f, k, act, launch = EXPERT_SHAPES[shape]
+    rows = launch if rows == "launch" else rows
     where = SingleDeviceSharding(v5e.devices[0])
 
     def s(sh, dt):
         return jax.ShapeDtypeStruct(sh, dt, sharding=where)
 
     experts = {
-        name: (s((layers, e) + wsh, jnp.int8), s((layers, e, 1, wsh[1]), jnp.float32))
+        name: (s((layers, e) + wsh, jnp.int8), s((layers, e, 1, wsh[1]), jnp.bfloat16))
         for name, wsh in (("w_gate", (d, f)), ("w_up", (d, f)), ("w_down", (f, d)))
     }
-    fn = lambda x, g, c, ex, l: sorted_moe_ffn(x, g, c, ex, l, kernel=True)  # noqa: E731
+    held = None if e == total else (e, total)
+    fn = lambda x, g, c, ex, l: sorted_moe_ffn(x, g, c, ex, l, kernel=True, held=held, act=act)  # noqa: E731
     compiled = (
         jax.jit(fn)
         .lower(s((rows, d), jnp.bfloat16), s((rows, k), jnp.bfloat16), s((rows, k), jnp.int32),
                experts, s((), jnp.int32))
         .compile()
     )
-    assert "moe_grouped_ffn" in compiled.as_text()
+    text = compiled.as_text()
+    assert "moe_grouped_ffn" in text
     temp = compiled.memory_analysis().temp_size_in_bytes
-    row_buffer = sorted_rows(rows, e, k) * d * 2
-    assert temp < min(3 * row_buffer + (8 << 20), e * d * f), (temp, row_buffer)
+    assert temp < rows * d * 4 + (1 << 20), (temp, rows * d * 4)
+    m = sorted_rows(rows, e, k, row_tile(rows, total, k))
+    assert m > rows and _stack_or_buffer_sized(text, m) == []
+
+
+def _stack_or_buffer_sized(text: str, buffer_rows: int, moe_layers: int = 0, held: int = 0) -> list:
+    """Instructions of a compiled program that MAKE an array with
+    ``buffer_rows`` rows (the row buffer the sorted MoE FFN laid out in HBM
+    before PR 53, sized for the worst routing) or a whole expert scale stack
+    (``[L, E held, 1, ·]`` or ``[L, E held, ·]`` in bfloat16 or float32: a
+    convert, a copy into another layout or memory, a ``ConcatBitcast`` of
+    slices), anywhere in it — in the layer body such an instruction runs in
+    every layer. Passing a stack on (a parameter, a tuple's element, a
+    bitcast) makes nothing. ``moe_layers = 0``: the buffer alone (a program
+    with no layer loop may fetch a stack once, whole, and loses nothing)."""
+    found = []
+    for ln in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\]\S* ([\w\-]+)\(", ln)
+        if not m or m.group(4) in ("parameter", "get-tuple-element", "bitcast"):
+            continue
+        name, dtype, dims, op = m.groups()
+        dims = dims.split(",")
+        stack = dtype in ("bf16", "f32") and dims[:2] == [str(moe_layers), str(held)] and len(dims) in (3, 4)
+        if dims[0] == str(buffer_rows) or stack:
+            found.append(f"{name} = {dtype}[{','.join(dims)}] {op}")
+    return found
 
 
 def _served_shapes(cfg, where):
@@ -301,6 +341,8 @@ def test_mixed_step_reads_weights_and_arena_in_place_on_v5e(v5e, monkeypatch, mo
     arena_layer = 2 * math.prod(cache.k.shape[1:])  # a layer of the bf16 K stack
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < min(layer_matrix, arena_layer) // 16, temp
+    # ... nor of the grouped FFN's plan for the worst routing (6,208 rows for OLMoE's 272)
+    assert _stack_or_buffer_sized(text, sorted_rows(256 + lanes, cfg.n_experts, cfg.experts_per_token)) == []
 
 
 def test_decode_step_with_the_clamped_map_copies_no_arena_on_v5e(v5e, monkeypatch):
@@ -498,6 +540,9 @@ def test_hybrid_step_holds_no_stack_sized_temporary_on_v5e(v5e, monkeypatch, ste
     compiled = fn.lower(*args).compile()
     if step == "mixed":
         assert cache.state.shape == (20, 64, 32, 128, 128) and cache.latent.shape == (7, 64, 4096, 640)
+        # the 320 rows reach their tiles inside the kernel: nothing has the 3,552 rows of the worst
+        # routing's plan, and none of the 26 MoE layers' scale stacks is relaid, fetched or converted whole
+        assert _stack_or_buffer_sized(compiled.as_text(), sorted_rows(320, 32, 8, row_tile(320, 256, 8)), 26, 32) == []
         return _check_mixed_with_a_linear_mixer(
             compiled, cfg, cache, 64, ["kda_decode", "mla_decode", "mla_prefill", "moe_grouped_ffn"]
         )
@@ -611,6 +656,11 @@ def test_laguna_step_fits_the_chip_and_keeps_every_leaf_in_place_on_v5e(v5e, mon
     if step == "mixed":
         v = cfg.vocab_size
         assert not re.search(rf"\[(1,)?(256|264),{v}\]", text) and re.search(rf"f32\[9,{v}\]", text)
+        # the MoE branch of the layer body's conditional (PR 53): no array of the plan's 3,104 rows
+        # (before: the 0/1 matrix ``[3104, 2112]``, ``x_rows`` and ``y``), and the three scale stacks
+        # of the 39 MoE layers passed on as stored (before: three ``ConcatBitcast``s, 15 MB a layer)
+        assert sorted_rows(264, 32, 8, row_tile(264, 256, 8)) == 3104
+        assert _stack_or_buffer_sized(text, 3104, 39, 32) == []
     live = mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes
     print(f"laguna-40l {step}: args {mem.argument_size_in_bytes / 1e9:.2f} GB, temp {mem.temp_size_in_bytes / 1e9:.2f} GB, "
           f"out {mem.output_size_in_bytes / 1e9:.2f} GB, aliased {mem.alias_size_in_bytes / 1e9:.2f} GB, live {live / 1e9:.2f} GB")

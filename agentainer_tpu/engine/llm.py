@@ -1509,6 +1509,30 @@ class LLMEngine:
             from ..models.hybrid import attention_by_kind
 
             self.attention.update(attention_by_kind(cfg))
+        # block-sparse layers: what their selection reads, counted on the host
+        # at each launch from the rows' positions (``ops/sparse_attention.
+        # block_counts``), a layer counted once, cumulative: query rows under
+        # and past ``dense_len``, the blocks those past it could see, chose
+        # and were made to choose, the rows every query could see against the
+        # rows read, and the pooled keys scored
+        self._sparse_sizes = None
+        if cfg.n_sparse:
+            from ..ops.sparse_attention import SparseSizes, block_counts
+
+            self._sparse_sizes = SparseSizes.of(cfg)
+            self._block_counts = block_counts
+            self.attention["sparse"] = {
+                "layers": cfg.n_sparse, **self._sparse_sizes._asdict(), **block_counts([], self._sparse_sizes)
+            }
+        # the linear mixer's per-lane state: rows a chunked launch stepped it
+        # by and one-token steps of a lane, a layer counted once, cumulative
+        self.linear = None
+        if self._recurrent:
+            self.linear = {
+                "kind": cfg.linear_kind, "layers": cfg.n_linear,
+                "state_bytes_lane": int(self.cache.state.nbytes // self.max_batch),
+                "conv": self.cache.conv is not None, "rows_chunked": 0, "steps": 0,
+            }
         self.meshed_flash = (not self._hybrid) and "shard_map" in attn.decode
         if self._hybrid:
             print(
@@ -3812,6 +3836,7 @@ class LLMEngine:
             "attention": dict(self.attention),
             # which MoE path the compiled steps trace, and the block's shape
             "moe": dict(self.moe),
+            **({"linear": dict(self.linear)} if self.linear is not None else {}),
             "model_arch": {
                 "layers": self.cfg.n_layers,
                 "dim": self.cfg.dim,
@@ -4521,12 +4546,14 @@ class LLMEngine:
         bucket, so the write is one of a handful of compiled programs, on
         the donated cache: nothing the size of a state stack is copied."""
         leaves = cmd.leaves or {}
-        want = jax.eval_shape(
-            lambda c: self._lane_leaves(c, 0, self.max_seq), self.cache
-        )
-        if set(leaves) != set(want) or not 0 < cmd.position < self.max_seq - 1:
+        if not 0 < cmd.position < self.max_seq - 1:
             return False
+        # at the snapshot's bucket: a leaf with a row every few positions (a
+        # sparse layer's pooled keys) ships that many rows of it
         bucket = self._snap_bucket(cmd.position)
+        want = jax.eval_shape(lambda c: self._lane_leaves(c, 0, bucket), self.cache)
+        if set(leaves) != set(want):
+            return False
         staged = {}
         for name, spec in want.items():
             a = np.asarray(leaves[name])
@@ -4939,6 +4966,10 @@ class LLMEngine:
         """Bytes of cache a row at ``position`` reads (the host's MBU model):
         every layer's rows up to it, a window layer's for its window only."""
         past = max(0.0, position - self.cfg.window) if self._windowed else 0.0
+        if self._sparse_sizes is not None and position > self._sparse_sizes.dense_len:
+            # the chosen blocks' rows, and a pooled key (half a K and V pair) every ``stride`` rows
+            sp = self._sparse_sizes
+            position = min(position, sp.topk * sp.block) + position / sp.stride / 2
         return position * self._kv_bytes_per_pos - past * self._kv_bytes_per_pos_window
 
     def _count_decode_blocks(self, positions: list[int], steps: int) -> None:
@@ -4949,11 +4980,14 @@ class LLMEngine:
         rows hold, a head block and a layer counted once. From what the
         worker knows at dispatch, never from the device."""
         bk = self._decode_bk
-        if not (bk or self._latent_bk or self.cfg.rope_original_max):
+        if not (bk or self._latent_bk or self.cfg.rope_original_max or self.linear is not None):
             return
         pos = np.asarray(positions, np.int64).reshape(-1, 1) + np.arange(steps)
         self._count_positioned(pos)
         pos = np.where(pos >= self.max_seq - 1, 0, pos)
+        if self._sparse_sizes is not None:
+            # a lane past ``dense_len`` hands the dense kernel one row and gathers its blocks
+            pos = np.where(pos >= self._sparse_sizes.dense_len, 0, pos)
         parked = steps * (self.max_batch - len(positions))
         if self._latent_bk:
             lb = self._latent_bk
@@ -4979,13 +5013,21 @@ class LLMEngine:
             a["global_decode_rows"] += int((pos + 1).sum())
             a["window_decode_rows"] += int(np.minimum(pos + 1, self.cfg.window).sum())
 
-    def _count_positioned(self, positions: np.ndarray) -> None:
-        """Rows a launch gives these positions (a chunk's real tokens, or the
-        stepping lanes' steps), and those of them at or past
-        ``rope_original_max``; nothing where the model has no such boundary."""
+    def _count_positioned(self, positions: np.ndarray, chunked: bool = False) -> None:
+        """Rows a launch gives these positions (``chunked``: a chunk's real
+        tokens; else the stepping lanes' steps): those at or past
+        ``rope_original_max`` where the model has such a boundary, what a
+        sparse layer's selection reads for them, and the rows that step a
+        linear mixer's state."""
         if self.cfg.rope_original_max:
             self.attention["rows_positioned"] += int(positions.size)
             self.attention["rows_past_original_max"] += int((positions >= self.cfg.rope_original_max).sum())
+        if self._sparse_sizes is not None:
+            sparse = self.attention["sparse"]
+            for k, v in self._block_counts(positions, self._sparse_sizes).items():
+                sparse[k] += v
+        if self.linear is not None:
+            self.linear["rows_chunked" if chunked else "steps"] += int(np.size(positions))
 
     def _bucket(self, n: int) -> int:
         for b in PREFILL_BUCKETS:
@@ -5134,7 +5176,7 @@ class LLMEngine:
                     self.params, self.cache, jnp.int32(slot.idx), tokens, pos, jnp.int32(n)
                 )
                 self._launch(JIT_PREFILL, bucket, rows=n)
-        self._count_positioned(positions[:n])
+        self._count_positioned(positions[:n], chunked=True)
         self._count_moe_rows(bucket + self.max_batch if riders else bucket)
         # n real tokens, each attending ~its own position of context
         self.flops_done += n * self.cfg.flops_per_token(slot.position + n // 2)

@@ -161,6 +161,7 @@ class LLMServeApp:
         self.kv_park_errors = 0
         self.kv_prewarm_errors = 0
         self.kv_snapshots = 0
+        self.kv_snapshot_bytes = 0  # the stored blobs' sizes, summed
         self.kv_snapshots_deferred = 0
         self.kv_snapshot_errors = 0
         self.last_kv_snapshot_error = ""
@@ -323,6 +324,7 @@ class LLMServeApp:
                 self._kv_last_snap[session] = time.monotonic()
                 await self.store.set_bytes(self._kv_key(session), blob, ttl=24 * 3600)
                 self.kv_snapshots += 1
+                self.kv_snapshot_bytes += len(blob)
         except SnapshotDeferred:
             # engine busy / limiter saturated: not an error — the next turn
             # retries, and the engine's snapshot_force_s bounds how long a
@@ -1487,6 +1489,7 @@ class LLMServeApp:
             "model_loaded": self.engine is not None,
             "engine_error": self.engine_error or None,
             "kv_snapshots": self.kv_snapshots,
+            "kv_snapshot_bytes": self.kv_snapshot_bytes,
             "kv_snapshots_deferred": self.kv_snapshots_deferred,
             "kv_restores": self.kv_restores,
             "prefix_prewarms": self.prefix_prewarms,

@@ -25,8 +25,8 @@ import math
 from dataclasses import dataclass, field
 
 
-LINEAR_KINDS = ("kda", "gdn")
-POSITIONAL_KINDS = ("mla", "full")
+LINEAR_KINDS = ("kda", "gdn", "lightning")
+POSITIONAL_KINDS = ("mla", "full", "sparse")
 # softmax attention over a RING of the last rows (``window``): the one kind
 # that may stand beside another positional kind ("full") in one model
 WINDOW_KIND = "swa"
@@ -67,6 +67,14 @@ class ModelConfig:
     # softmax attention over the last ``window`` positions, its rows a ring
     # (``models/llama.ring_rows``), with its own count of query heads
     # (``swa_heads``) and its own rotary embedding (``swa_rope_theta``).
+    # "sparse" (MiniCPM-SALA's ``minicpm4`` mixer, InfLLM v2) is "full" whose
+    # rows past ``sparse_dense_len`` read a CHOSEN set of key blocks: the
+    # ``sparse_*`` fields below, a pooled-key leaf beside ``k`` and ``v``, a
+    # norm a head and a full-width sigmoid gate on the output. "lightning"
+    # (Lightning Attention-2) is a linear kind with no conv, no β and no
+    # erase term: ``S_t = λ_h S_{t−1} + k_tᵀ v_t`` with a constant decay a
+    # head, ``kda_heads`` heads of ``kda_head_dim``, rotated by
+    # ``lightning_rope_theta``.
     # Empty: every layer is RoPE GQA attention over a K/V arena
     layer_kinds: tuple[str, ...] = ()
     kda_heads: int = 0
@@ -122,6 +130,28 @@ class ModelConfig:
     # every attention head's output times a sigmoid of its own, a linear
     # function of the layer's normed input (``wg [d, heads]``)
     attn_gate: bool = False
+    # -- "sparse" beside "lightning" (MiniCPM-SALA); 0: the model has no such layer --
+    # a pooled key is the mean of ``sparse_kernel`` consecutive keys, one
+    # every ``sparse_stride``; a key block is ``sparse_block`` rows; a query
+    # reads the first ``sparse_init_blocks`` blocks, the blocks of its last
+    # ``sparse_window`` rows and, ``sparse_topk`` in all, those whose pooled
+    # keys score highest. A query whose context is at most
+    # ``sparse_dense_len`` rows reads all of it
+    sparse_kernel: int = 0
+    sparse_stride: int = 0
+    sparse_block: int = 0
+    sparse_init_blocks: int = 0
+    sparse_window: int = 0
+    sparse_topk: int = 0
+    sparse_dense_len: int = 0
+    # a "lightning" layer rotates q and k (rotate-half, the whole head). 0: no rotation
+    lightning_rope_theta: float = 0.0
+    # MiniCPM's µP scalings: the embedding times ``embed_scale``, every
+    # sublayer's output times ``residual_scale`` before the residual adds it,
+    # the final normed stream over ``logit_divisor`` before the head. 1: none
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_divisor: float = 1.0
     # the first ``n_dense_layers`` have a dense SwiGLU of ``dense_ffn_dim``;
     # the rest are MoE with ``ffn_dim`` wide experts
     n_dense_layers: int = 0
@@ -178,6 +208,14 @@ class ModelConfig:
             raise ValueError('"swa" layers see a window > 0 and stand beside "full" layers (the arena\'s length is theirs)')
         if self.rope_partial != 1.0 and self.rotary_dim % 2:
             raise ValueError("rope_partial rotates an even number of a head's dims")
+        if "sparse" in self.layer_kinds:
+            k, s, b = self.sparse_kernel, self.sparse_stride, self.sparse_block
+            if min(k, s, b, self.sparse_topk) <= 0 or k % s or b % s or self.sparse_window % b or self.sparse_dense_len % b:
+                raise ValueError("sparse layers: kernel and block are whole strides, window and dense_len whole blocks, topk > 0")
+            if self.sparse_topk < self.sparse_forced_blocks:
+                raise ValueError("sparse_topk is under the blocks every query is made to read (init + window)")
+            if self.sparse_dense_len < self.sparse_topk * b:
+                raise ValueError("sparse_dense_len is under sparse_topk blocks: a sparse query would have fewer blocks than it selects")
 
     @property
     def head_dim(self) -> int:
@@ -208,6 +246,15 @@ class ModelConfig:
     @property
     def n_global(self) -> int:
         return self.n_layers - self.n_window
+
+    @property
+    def n_sparse(self) -> int:
+        return self.layer_kinds.count("sparse")
+
+    @property
+    def sparse_forced_blocks(self) -> int:
+        """Blocks a query past ``sparse_dense_len`` reads whatever they score."""
+        return self.sparse_init_blocks + self.sparse_window // self.sparse_block
 
     @property
     def is_hybrid(self) -> bool:
@@ -297,9 +344,13 @@ class ModelConfig:
             n = 2 * d * heads * hd + 2 * d * self.n_kv_heads * hd + (d * heads if self.attn_gate else 0)
             return n + ((heads + self.n_kv_heads) * hd if self.qk_norm else 0)
 
+        # q, o, the full-width gate, k, v, and a norm vector a head for q and k
+        sparse = 3 * d * self.n_heads * hd + 2 * d * self.n_kv_heads * hd + 2 * hd
+        # q, k, v, the gate, o; the q and k norms a head, the output norm, the decays
+        lightning = 5 * d * h * hk + 2 * hk + h * hk + h
         return {"kda": kda, "mla": mla, "gdn": gdn, "full": attention(self.n_heads),
                 WINDOW_KIND: attention(self.window_heads), "expert": expert, "moe_fixed": moe,
-                "dense": 3 * d * self.dense_ffn_dim}
+                "dense": 3 * d * self.dense_ffn_dim, "sparse": sparse, "lightning": lightning}
 
     @property
     def is_moe(self) -> bool:
@@ -361,11 +412,19 @@ class ModelConfig:
             # (4 passes over H·dk·dv: decay, k·S, rank-1 update, q·S); an MLA
             # layer scores 192 dims and combines 128 per head and slot
             delta = 8.0 * self.kda_heads * self.kda_head_dim * self.delta_v_dim
+            if self.linear_kind == "lightning":  # kᵀv into the state and q·S out of it: no erase term
+                delta = 4.0 * self.kda_heads * self.kda_head_dim * self.delta_v_dim
             mla = 2.0 * self.n_heads * context_len * (
                 self.mla_nope_dim + self.mla_rope_dim + self.mla_v_dim
             )
             full = 4.0 * self.n_heads * self.head_dim * context_len
-            positional = {"mla": mla, "full": full}.get(self.positional_kind, 0.0)
+            # past ``sparse_dense_len`` a sparse layer scores every pooled key
+            # and attends to ``sparse_topk`` blocks of rows
+            sparse = full
+            if self.n_sparse and context_len > self.sparse_dense_len:
+                read = min(context_len, self.sparse_topk * self.sparse_block)
+                sparse = self.n_heads * self.head_dim * (4.0 * read + 2.0 * context_len / self.sparse_stride)
+            positional = {"mla": mla, "full": full, "sparse": sparse}.get(self.positional_kind, 0.0)
             # a window layer sees at most its window of the context
             swa = 4.0 * self.window_heads * self.head_dim * min(context_len, self.window)
             return matmul + self.n_linear * delta + self.n_positional * positional + self.n_window * swa
@@ -808,6 +867,93 @@ TINY_OLMO_HYBRID = register(
         kda_conv=4,
         delta_neg_eigval=True,
         post_norm=True,
+        n_dense_layers=8,
+        dense_ffn_dim=128,
+    )
+)
+
+# MiniCPM-SALA (openbmb/MiniCPM-SALA config.json, ``model_type: minicpm_sala``:
+# 32 layers, hidden 4096, dense SwiGLU of 16384; ``mixer_types`` names
+# ``minicpm4`` at layers 0, 9, 16, 17, 22, 29, 30, 31 (InfLLM v2's block-sparse
+# attention: 32 query / 2 K/V heads of 128, no rotary embedding, a norm a
+# head, an output gate) and ``lightning-attn`` at the other 24 (Lightning
+# Attention-2: 32 heads of 128, rotate-half RoPE at theta 1e4, a constant
+# decay a head, an output norm and gate); MiniCPM's µP scalings (``scale_emb``
+# 12, ``scale_depth`` 1.4 over √32, ``dim_model_base`` 256); vocabulary
+# 73,448, untied, context 524,288). The sparse sizes are the ``sparse_config``
+# of openbmb/MiniCPM4-8B, which ``minicpm4`` names. 9.48 B parameters.
+SALA_SPARSE_LAYERS = (0, 9, 16, 17, 22, 29, 30, 31)
+
+
+def sala_kinds(n_layers: int = 32, sparse_at: tuple[int, ...] = SALA_SPARSE_LAYERS) -> tuple[str, ...]:
+    return tuple("sparse" if i in sparse_at else "lightning" for i in range(n_layers))
+
+
+MINICPM_SALA = register(
+    ModelConfig(
+        name="minicpm-sala",
+        vocab_size=73_448,
+        dim=4096,
+        n_layers=32,
+        n_heads=32,
+        n_kv_heads=2,
+        ffn_dim=16_384,
+        max_seq_len=524_288,
+        rope_theta=0.0,  # ``attn_use_rope: false``: the sparse layers carry no position
+        norm_eps=1e-6,
+        qk_norm=True,
+        head_size=128,
+        layer_kinds=sala_kinds(),
+        kda_heads=32,
+        kda_head_dim=128,
+        lightning_rope_theta=10_000.0,
+        sparse_kernel=32,
+        sparse_stride=16,
+        sparse_block=64,
+        sparse_init_blocks=1,
+        sparse_window=2048,
+        sparse_topk=64,
+        sparse_dense_len=8192,
+        embed_scale=12.0,
+        residual_scale=1.4 / 32**0.5,
+        logit_divisor=4096 / 256,
+        n_dense_layers=32,
+        dense_ffn_dim=16_384,
+    )
+)
+
+# The same block at CI shapes: both kinds at every junction (S L L S S L L S),
+# every sparse size scaled down with the widths: pooled keys of 8 rows every
+# 4, blocks of 16, one initial block and a window of two forced, 6 of at
+# least 7 blocks chosen past 96 rows.
+TINY_MINICPM_SALA = register(
+    ModelConfig(
+        name="tiny-minicpm-sala",
+        vocab_size=512,
+        dim=64,
+        n_layers=8,
+        n_heads=8,
+        n_kv_heads=2,
+        ffn_dim=128,
+        max_seq_len=512,
+        rope_theta=0.0,
+        norm_eps=1e-6,
+        qk_norm=True,
+        head_size=16,
+        layer_kinds=sala_kinds(8, (0, 3, 4, 7)),
+        kda_heads=4,
+        kda_head_dim=16,
+        lightning_rope_theta=10_000.0,
+        sparse_kernel=8,
+        sparse_stride=4,
+        sparse_block=16,
+        sparse_init_blocks=1,
+        sparse_window=32,
+        sparse_topk=6,
+        sparse_dense_len=96,
+        embed_scale=12.0,
+        residual_scale=1.4 / 8**0.5,
+        logit_divisor=2.0,
         n_dense_layers=8,
         dense_ffn_dim=128,
     )

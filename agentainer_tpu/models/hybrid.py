@@ -1,7 +1,7 @@
-"""The hybrid block (Kimi-Linear, Olmo-Hybrid, Mistral-Small-4, Laguna): a
-linear mixer beside a positional one, a positional one alone, or full
-attention beside sliding-window attention, and two FFNs, through the one
-forward.
+"""The hybrid block (Kimi-Linear, Olmo-Hybrid, Mistral-Small-4, Laguna,
+MiniCPM-SALA): a linear mixer beside a positional one, a positional one alone,
+or full attention beside sliding-window attention, and two FFNs, through the
+one forward.
 
 ``models/llama.forward`` hands a config with ``layer_kinds`` to
 :func:`forward` here; the engine calls one ``forward`` and never learns a
@@ -29,6 +29,16 @@ with
   gate) or **full** (softmax attention over K/V rows read by the dense flash
   kernels, QK-norm over the whole projections, no rotary embedding where the
   model has none). A model has one linear kind and one positional kind;
+- or the mixer **lightning** (``ops/lightning.py``: linear attention with a
+  constant decay a head, no conv, no β, no erase term; a norm a head on q and
+  k, rotate-half RoPE, one RMSNorm over the concatenated output and a
+  full-width sigmoid gate) or **sparse** (``ops/sparse_attention.py``: softmax
+  attention whose rows past ``cfg.sparse_dense_len`` choose ``cfg.sparse_topk``
+  key blocks from pooled keys and read nothing else; the pooled keys are a
+  leaf of their own, ``ck [n_sparse, B, S / stride, KV, hd]``, beside ``k``
+  and ``v``; a norm a head, no rotary embedding, a full-width sigmoid gate):
+  MiniCPM-SALA, whose every sublayer's output is scaled before the residual
+  adds it (``cfg.residual_scale``; ``cfg.embed_scale``, ``cfg.logit_divisor``);
 - or, with no linear mixer, **full** beside **swa** (Laguna: the same softmax
   attention over the last ``cfg.window`` positions, its K/V rows a RING,
   ``wk``, ``wv`` ``[n_swa, B, R, KV, hd]``, whose arithmetic is
@@ -57,7 +67,8 @@ by the layer's index within its kind.
 values and padding to whole lane tiles) or ``k``, ``v`` ``[n_full, B, S, KV
 stored, hd]`` (:func:`stored_kv_heads`), read up to a lane's position like a
 K/V arena — and per-lane state ``state`` float32 (``[n_kda, B, H, dk, dv]``,
-or for GDN ``[n_gdn, B, dk, H·dv]``: whole tiles at 96 × 192 a head) and
+or for GDN ``[n_gdn, B, dk, H·dv]``: whole tiles at 96 × 192 a head; for
+lightning ``[n, B, H, dk, dv]`` with NO conv: ``conv`` is ``None``) and
 ``conv [n, B, (W − 1)·channels]`` — which cannot be truncated, rewound or
 overwritten harmlessly. A leaf the model has no layer for is ``None`` (a
 model with no linear kind has no ``state`` and no ``conv``: its cache is
@@ -101,7 +112,9 @@ import numpy as np
 from jax import lax
 
 from ..ops import kda as kda_ops
+from ..ops import lightning as lightning_ops
 from ..ops import mla as mla_ops
+from ..ops import sparse_attention as sparse_ops
 from ..ops.moe import EXPERT_WEIGHTS, stacked_experts
 from ..ops.norms import rms_norm
 from ..ops.quant import QTensor, dequant, embed_lookup
@@ -114,13 +127,15 @@ L2_EPS = 1e-6
 
 class HybridCache(NamedTuple):
     """``latent``, ``k`` and ``v`` are positional (rows up to a lane's
-    position; ``None`` where the model has no such layer); ``state`` and
-    ``conv`` are per-lane; ``stop`` and ``eos`` are the per-lane decode
-    controls (module docstring)."""
+    position; ``None`` where the model has no such layer), ``ck`` follows
+    ``k`` a row every ``stride`` positions; ``state`` and ``conv`` are
+    per-lane (``conv`` is ``None`` for a linear kind without one); ``stop``
+    and ``eos`` are the per-lane decode controls (module docstring)."""
 
     latent: jnp.ndarray | None  # [n_mla, B, S, latent_width]: R + r values, zero padding
-    state: jnp.ndarray | None  # [n_kda, B, H, dk, dv] or [n_gdn, B, dk, H·dv], float32
-    conv: jnp.ndarray | None  # [n, B, (W - 1)·channels]: the last W − 1 conv inputs, row after row
+    state: jnp.ndarray | None  # [n_kda | n_lightning, B, H, dk, dv] or [n_gdn, B, dk, H·dv], float32
+    # [n, B, (W - 1)·channels]: the last W − 1 conv inputs, row after row (None: no conv, "lightning")
+    conv: jnp.ndarray | None
     stop: jnp.ndarray  # [B] int32
     eos: jnp.ndarray  # [B] int32 (-1: no token closes the lane)
     k: jnp.ndarray | None = None  # [n_full, B, S, stored_kv_heads, hd]
@@ -129,13 +144,26 @@ class HybridCache(NamedTuple):
     # (``models/llama.ring_rows``); a lane's R rows are its last R positions
     wk: jnp.ndarray | None = None  # [n_swa, B, R, stored_kv_heads, hd]
     wv: jnp.ndarray | None = None
+    # the "sparse" layers' pooled keys: row j is the mean of the lane's ``k``
+    # rows ``stride·j .. stride·j + kernel − 1`` (``ops/sparse_attention.py``)
+    ck: jnp.ndarray | None = None  # [n_sparse, B, S / stride, KV, hd]
 
     POSITIONAL = ("latent", "k", "v")
     RING = ("wk", "wv")
+    # one row every ``stride`` positions: shipped up to a snapshot's bucket
+    # like a positional leaf, not trimmed to the position (rows past it are
+    # never read and are rewritten as the lane goes on)
+    POOLED = ("ck",)
 
     def rows(self) -> tuple:
         """The positional leaves this cache has, in ``POSITIONAL``'s order."""
         return tuple(a for a in (self.latent, self.k, self.v) if a is not None)
+
+    def carried(self) -> dict:
+        """The leaves a positional mixer reads and writes, by name: the
+        positional rows and, where the model has them, the pooled keys."""
+        named = {n: getattr(self, n) for n in self.POSITIONAL + self.POOLED}
+        return {n: a for n, a in named.items() if a is not None}
 
     def ring(self) -> tuple:
         """The ring leaves this cache has (both or neither)."""
@@ -143,7 +171,7 @@ class HybridCache(NamedTuple):
 
     def leaves(self) -> dict:
         """The leaves a slot is made of, by name (the controls left out)."""
-        named = {"latent": self.latent, "k": self.k, "v": self.v, "wk": self.wk, "wv": self.wv,
+        named = {"latent": self.latent, "k": self.k, "v": self.v, "ck": self.ck, "wk": self.wk, "wv": self.wv,
                  "state": self.state, "conv": self.conv}
         return {n: a for n, a in named.items() if a is not None}
 
@@ -186,28 +214,38 @@ def init_cache(
     from .llama import ring_rows
 
     h, dk, dv, nl = cfg.kda_heads, cfg.kda_head_dim, cfg.delta_v_dim, cfg.n_linear
-    if any(sum(k in cfg.layer_kinds for k in kinds) > 1 for kinds in (LINEAR_KINDS, POSITIONAL_KINDS)):
-        raise ValueError("the hybrid block has one linear kind and one positional kind of mixer")
+    for kinds in (LINEAR_KINDS, POSITIONAL_KINDS):
+        have = [k for k in kinds if k in cfg.layer_kinds]
+        if len(have) > 1:
+            raise ValueError(
+                f"the hybrid block has at most one of {kinds} in a model and this one has {have}: a cache holds one "
+                'linear kind\'s state and one positional kind\'s rows ("swa" may stand beside "full")'
+            )
     if cfg.n_window and (nl or cfg.positional_kind != "full"):
         raise ValueError('the hybrid block runs "swa" layers beside "full" layers and no other kind')
     stored = stored_kv_heads(cfg.n_kv_heads)
     arena = (cfg.n_positional, lanes, max_seq, stored, cfg.head_dim)
     ring = (cfg.n_window, lanes, ring_rows(cfg.window, max_seq, launch_rows, block), stored, cfg.head_dim)
-    full = cfg.positional_kind == "full"
+    full = cfg.positional_kind in ("full", "sparse")
     state_shape = (nl, lanes, dk, h * dv) if cfg.linear_kind == "gdn" else (nl, lanes, h, dk, dv)
+    has_conv = nl and cfg.linear_kind != "lightning"
+    if cfg.n_sparse and max_seq % cfg.sparse_block:
+        raise ValueError(f"a sparse layer's arena is whole key blocks of {cfg.sparse_block} rows, not {max_seq}")
+    pooled = (cfg.n_sparse, lanes, max_seq // max(cfg.sparse_stride, 1), cfg.n_kv_heads, cfg.head_dim)
     return HybridCache(
         latent=jnp.zeros((cfg.n_mla, lanes, max_seq, latent_width(cfg)), dtype) if cfg.n_mla else None,
         state=jnp.zeros(state_shape, jnp.float32) if nl else None,
         # the W − 1 rows of a lane side by side: a dimension of 3 next to the
         # channels would be padded to a whole sublane tile (or, minor-most,
         # to 128 lanes: compiled for a described v5e, 94 MB became 3.75 GB)
-        conv=jnp.zeros((nl, lanes, (cfg.kda_conv - 1) * conv_channels(cfg)), dtype) if nl else None,
+        conv=jnp.zeros((nl, lanes, (cfg.kda_conv - 1) * conv_channels(cfg)), dtype) if has_conv else None,
         stop=jnp.full((lanes,), NO_STOP if live else 0, jnp.int32),
         eos=jnp.full((lanes,), -1, jnp.int32),
         k=jnp.zeros(arena, dtype) if full else None,
         v=jnp.zeros(arena, dtype) if full else None,
         wk=jnp.zeros(ring, dtype) if cfg.n_window else None,
         wv=jnp.zeros(ring, dtype) if cfg.n_window else None,
+        ck=jnp.zeros(pooled, dtype) if cfg.n_sparse else None,
     )
 
 
@@ -225,24 +263,27 @@ def admit_lane(cache: HybridCache, lane, fresh, stop, eos) -> HybridCache:
     keep = jnp.where(fresh, 0.0, 1.0)
     lane_of = lambda a: lax.dynamic_slice_in_dim(a, lane, 1, axis=1)  # noqa: E731
     put = lambda a, v: lax.dynamic_update_slice_in_dim(a, v, lane, axis=1)  # noqa: E731
-    return cache._replace(
-        state=put(cache.state, lane_of(cache.state) * keep),
-        conv=put(cache.conv, lane_of(cache.conv) * keep.astype(cache.conv.dtype)),
-    )
+    cache = cache._replace(state=put(cache.state, lane_of(cache.state) * keep))
+    if cache.conv is None:  # a linear kind without a conv
+        return cache
+    return cache._replace(conv=put(cache.conv, lane_of(cache.conv) * keep.astype(cache.conv.dtype)))
 
 
 def snapshot_lane(cache: HybridCache, lane, bucket: int, n_kv_heads: int | None = None) -> dict:
     """A lane's leaves by name: the positional rows ``[:, :bucket]`` (of
     ``k`` and ``v`` the model's ``n_kv_heads`` heads, not the padding they
-    are stored with), the per-lane state whole, and the ring whole (its R
-    rows are the last R positions wherever the lane stands, as
-    ``models/llama.snapshot_lane`` ships it)."""
+    are stored with), the pooled keys of those rows (``[:, :bucket / stride]``),
+    the per-lane state whole, and the ring whole (its R rows are the last R
+    positions wherever the lane stands, as ``models/llama.snapshot_lane``
+    ships it)."""
     lane_of = lambda a: lax.dynamic_index_in_dim(a, lane, axis=1, keepdims=False)  # noqa: E731
     out = {}
     for name, a in cache.leaves().items():
         a = lane_of(a)
         if name in cache.POSITIONAL:
             a = a[:, :bucket]
+        if name in cache.POOLED:
+            a = a[:, : -(-bucket * a.shape[1] // cache.k.shape[2])]
         if name in ("k", "v") + cache.RING:
             a = a[:, :, :n_kv_heads]
         out[name] = a
@@ -285,11 +326,18 @@ class HybridPlan(NamedTuple):
     # the window layers beside the full ones (the same kernels, ``window=``)
     swa_decode: str = ""
     swa_prefill: str = ""
+    # block-sparse attention beside lightning attention: a sparse layer's
+    # rows under ``cfg.sparse_dense_len`` take the dense kernels named first
+    sparse_decode: str = ""
+    sparse_prefill: str = ""
+    lightning_decode: str = ""
+    lightning_prefill: str = ""
 
     def describe(self) -> dict:
         mine = {k: v for k, v in self._asdict().items() if v}
-        prefill, decode = (
-            (self.full_prefill, self.full_decode) if self.full_decode else (self.mla_prefill, self.mla_decode)
+        prefill, decode = next(
+            (pair for pair in ((self.full_prefill, self.full_decode), (self.sparse_prefill, self.sparse_decode)) if pair[1]),
+            (self.mla_prefill, self.mla_decode),
         )
         arena = "layer_slice" if decode.startswith("xla:") else "stack+layer"
         return {**mine, "prefill": prefill, "decode": decode, "arena": arena}
@@ -298,7 +346,7 @@ class HybridPlan(NamedTuple):
         """``kind -> (prefill, decode)`` of the kinds the model has."""
         pairs = {
             k: (getattr(self, k + "_prefill"), getattr(self, k + "_decode"))
-            for k in ("kda", "gdn", "mla", "full", WINDOW_KIND)
+            for k in ("kda", "gdn", "lightning", "mla", "full", "sparse", WINDOW_KIND)
         }
         return {k: v for k, v in pairs.items() if v[0]}
 
@@ -306,6 +354,8 @@ class HybridPlan(NamedTuple):
 def plan_hybrid(cfg: ModelConfig, use_pallas: bool | None = None) -> HybridPlan:
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
+    if cfg.linear_kind == "lightning" or cfg.positional_kind == "sparse":
+        return _plan_lightning_sparse(cfg, use_pallas)
     if cfg.linear_kind == "gdn" or cfg.positional_kind == "full":
         return _plan_gdn_full(cfg, use_pallas)
     if cfg.linear_kind is None:
@@ -366,6 +416,32 @@ def _plan_gdn_full(cfg: ModelConfig, use_pallas: bool) -> HybridPlan:
     )
 
 
+def _plan_lightning_sparse(cfg: ModelConfig, use_pallas: bool) -> HybridPlan:
+    """Lightning beside block-sparse attention. The state's rule and what a
+    sparse row does with its chosen blocks are XLA's on every backend (a
+    gather of the listed blocks for a lane's step, a row-by-block mask over
+    runs of the lane's rows for a chunk); a lane's step under
+    ``cfg.sparse_dense_len`` takes the dense flash kernel where it takes the
+    head counts."""
+    from ..ops.pallas_attention import kernel_supported
+
+    why, dense = [], "xla:attention_reference"
+    if not use_pallas:
+        why.append("no tpu backend")
+    elif kernel_supported(cfg.n_heads, stored_kv_heads(cfg.n_kv_heads), cfg.head_dim):
+        dense = "pallas:flash_decode"
+        why.append("tpu backend; a dense row's K/V read where it lies, a sparse row's blocks gathered by XLA")
+    else:
+        why.append(f"heads {cfg.n_heads}/{cfg.n_kv_heads} x {cfg.head_dim}: not the flash kernels' shapes")
+    sparse, lightning = bool(cfg.n_sparse), cfg.linear_kind == "lightning"
+    return HybridPlan(
+        "", "", "", "", "; ".join(why),
+        sparse_decode=f"{dense}+xla:block_gather" if sparse else "",
+        sparse_prefill="xla:block_mask" if sparse else "",
+        lightning_decode="xla_step" if lightning else "", lightning_prefill="xla_chunked" if lightning else "",
+    )
+
+
 def attention_by_kind(cfg: ModelConfig) -> dict:
     """What differs by kind of attention layer in a model with "full" beside
     "swa" layers, in words, for an engine's ``/metrics``: the query heads, the
@@ -395,7 +471,7 @@ def param_shapes(cfg: ModelConfig) -> dict:
     nd, ne = cfg.n_dense_layers, cfg.n_layers - cfg.n_dense_layers
     qk = cfg.mla_nope_dim + cfg.mla_rope_dim
     fs = cfg.n_shared_experts * cfg.ffn_dim
-    ng, nf = cfg.layer_kinds.count("gdn"), cfg.layer_kinds.count("full")
+    ng, nf, nlt = (cfg.layer_kinds.count(k) for k in ("gdn", "full", "lightning"))
     cc, cv, hd = conv_channels(cfg), h * cfg.delta_v_dim, cfg.head_dim
 
     def attention(n: int, heads: int) -> dict:  # a kind's own stacks: its count of query heads
@@ -439,6 +515,29 @@ def param_shapes(cfg: ModelConfig) -> dict:
         },
         "full": attention(nf, cfg.n_heads),
         WINDOW_KIND: attention(cfg.n_window, cfg.window_heads),
+        # a norm a head on q and k, a sigmoid gate as wide as the output
+        "sparse": {
+            "wq": ((cfg.n_sparse, d, cfg.n_heads * hd), True),
+            "wk": ((cfg.n_sparse, d, cfg.n_kv_heads * hd), True),
+            "wv": ((cfg.n_sparse, d, cfg.n_kv_heads * hd), True),
+            "wg": ((cfg.n_sparse, d, cfg.n_heads * hd), True),
+            "wo": ((cfg.n_sparse, cfg.n_heads * hd, d), True),
+            "q_norm": ((cfg.n_sparse, hd), False),
+            "k_norm": ((cfg.n_sparse, hd), False),
+        },
+        "lightning": {
+            "wq": ((nlt, d, c), True),
+            "wk": ((nlt, d, c), True),
+            "wv": ((nlt, d, c), True),
+            "wg": ((nlt, d, c), True),
+            "wo": ((nlt, c, d), True),
+            "q_norm": ((nlt, dk), False),
+            "k_norm": ((nlt, dk), False),
+            "o_norm": ((nlt, c), False),
+            # the decay a head as its slope, λ = exp(−slope): a vector a
+            # layer, so that a checkpoint's own drops in
+            "slope": ((nlt, h), False),
+        },
         "mla": {
             **(
                 {
@@ -491,6 +590,12 @@ def vector_values(name: str, shape: tuple, key, dtype, group: str = "kda"):
     ``I − β k kᵀ`` transitions like the square root of the length, and the
     reference with bfloat16 matmul inputs itself read 2.07 % at 200 tokens,
     my chip run, PR 32.)"""
+    if name == "slope":
+        # Lightning Attention-2's slopes, the same in every layer: 2^(−8 (h + 1) / H),
+        # so a head's memory runs from a token or two to a few hundred. float32:
+        # a bfloat16 slope is another decay
+        h = shape[-1]
+        return jnp.broadcast_to(2.0 ** (-8.0 * (jnp.arange(h, dtype=jnp.float32) + 1.0) / h), shape)
     if group == "gdn" and name == "a_log":
         return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0 / 16.0, 16.0)).astype(dtype)
     if group == "gdn" and name == "dt_bias":
@@ -868,6 +973,113 @@ def full_mixer(
     return _proj(o.reshape(b, t, nh * hd).astype(h.dtype), lp["wo"]), ck, cv
 
 
+def sparse_mixer(
+    h, lp, cfg: ModelConfig, ck, cv, pooled, idx, slot, positions, valid, plan: HybridPlan, n_lanes: int = 0,
+):
+    """``h [B, T, d]`` → block-sparse attention's output, and the ``k``, ``v``
+    and pooled-key stacks with this step's rows written (``ops/
+    sparse_attention.py``). q and k take an RMSNorm a head, and the rotary
+    embedding a "full" layer would (MiniCPM-SALA: none). A query whose
+    context is at most ``cfg.sparse_dense_len`` rows reads all of it; past
+    that it scores the lane's visible pooled keys (``sparse_index``), takes
+    ``cfg.sparse_topk`` blocks (``sparse_select``) and reads those alone
+    (``sparse_attend``): a lane's step gathers the listed blocks, a chunk's
+    rows see their blocks through a row-by-block mask. The output is gated by
+    a sigmoid of the normed input as wide as itself. ``n_lanes`` as
+    :func:`mla_mixer`: both groups' rows and pooled keys are written before
+    either group is read."""
+    from ..ops import attention as attn_ops
+
+    b, t, _ = h.shape
+    nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    stored, sizes = ck.shape[3], sparse_ops.SparseSizes.of(cfg)
+    q, k, v = lax.optimization_barrier((_proj(h, lp["wq"]), _proj(h, lp["wk"]), _proj(h, lp["wv"])))
+    q = rms_norm(q.reshape(b, t, nh, hd), lp["q_norm"], cfg.norm_eps)
+    k = rms_norm(k.reshape(b, t, nkv, hd), lp["k_norm"], cfg.norm_eps)
+    v = v.reshape(b, t, nkv, hd)
+    q, k = _attn_rotate(q, positions, cfg, "full"), _attn_rotate(k, positions, cfg, "full")
+    heads = lambda a, n: jnp.pad(a.astype(h.dtype), [(0, 0), (0, 0), (0, n - a.shape[2]), (0, 0)])  # noqa: E731
+    dense = attn_ops.pallas_dense if plan.sparse_decode.startswith("pallas:") else attn_ops._reference_dense
+
+    def append(pooled, positions, valid, slot):  # the kernels a group's rows completed
+        lanes = jnp.arange(positions.shape[0]) + (0 if slot is None else slot)
+        return sparse_ops.append_pooled(
+            pooled, ck, idx, lanes, positions[:, 0], jnp.sum(valid, axis=1), positions.shape[1], sizes)
+
+    if n_lanes:
+        ck = _put_groups(ck, heads(k, stored), idx, slot, positions, n_lanes)
+        cv = _put_groups(cv, heads(v, stored), idx, slot, positions, n_lanes)
+        (pos_c, pos_l), (val_c, val_l) = _groups(n_lanes, positions, valid)
+        pooled = append(append(pooled, pos_c, val_c, slot), pos_l, val_l, None)
+    else:
+        lanes = jnp.arange(b)[:, None] + (0 if slot is None else slot)
+        ck = ck.at[idx, lanes, positions].set(heads(k, stored).astype(ck.dtype))
+        cv = cv.at[idx, lanes, positions].set(heads(v, stored).astype(cv.dtype))
+        pooled = append(pooled, positions, valid, slot)
+
+    def attend(q, positions, valid, slot):
+        b, t = positions.shape
+        lane = 0 if slot is None else slot
+        with jax.named_scope("sparse_index"):
+            scores = sparse_ops.block_scores(q, _rows(pooled, idx, slot, b), positions, sizes)
+        with jax.named_scope("sparse_select"):
+            blocks = sparse_ops.select_blocks(scores, positions, sizes)
+        with jax.named_scope("sparse_attend"):
+            if t > 1:
+                mask = sparse_ops.rows_by_blocks(blocks, positions, scores.shape[-1], sizes.dense_len)
+                last = jnp.max(jnp.where(valid, positions, 0))
+                return sparse_ops.attend_masked(q, ck, cv, idx, lane, positions, last, mask, nkv, sizes.block)
+            # a lane under ``dense_len`` reads its rows through the dense
+            # kernel (the others hand it one row); a lane past it its blocks
+            under = positions[:, 0] < sizes.dense_len
+            seen = jnp.where(valid & under[:, None], positions, 0)
+            o_dense = dense(heads(q, stored * (nh // nkv)), ck, cv, seen, None, idx, slot)[:, :, :nh]
+            o_blocks = sparse_ops.attend_blocks(
+                q[:, 0], ck, cv, idx, jnp.arange(b) + lane, blocks[:, 0], positions[:, 0], nkv, sizes.block)
+            return jnp.where(under[:, None, None, None], o_dense.astype(jnp.float32), o_blocks[:, None])
+
+    o = _by_group(attend, n_lanes, q, positions, valid, slot)
+    with jax.named_scope("attn_gate"):
+        o = o.astype(jnp.float32).reshape(b, t, nh * hd) * jax.nn.sigmoid(_proj(h, lp["wg"]))
+    return _proj(o.astype(h.dtype), lp["wo"]), ck, cv, pooled
+
+
+def lightning_mixer(h, lp, cfg: ModelConfig, state, idx, slot, positions, valid, plan: HybridPlan, n_lanes: int = 0):
+    """``h [B, T, d]`` → lightning attention's output, and the state stack
+    ``[n, B, H, dk, dv]`` with layer ``idx``'s lanes stepped by the valid
+    tokens (``ops/lightning.py``): an RMSNorm a head on q and k, rotate-half
+    RoPE (``cfg.lightning_rope_theta``), q scaled by ``dk^-½``, a constant
+    decay a head from the layer's ``slope``; one RMSNorm over the concatenated
+    heads' output and a sigmoid gate as wide. No conv: the cache's ``conv`` is
+    ``None``. ``n_lanes`` as :func:`kda_mixer`."""
+    b, t, _ = h.shape
+    nh, dk = cfg.kda_heads, cfg.kda_head_dim
+    q, k, v = lax.optimization_barrier((_proj(h, lp["wq"]), _proj(h, lp["wk"]), _proj(h, lp["wv"])))
+    q = rms_norm(q.reshape(b, t, nh, dk), lp["q_norm"], cfg.norm_eps)
+    k = rms_norm(k.reshape(b, t, nh, dk), lp["k_norm"], cfg.norm_eps)
+    v = v.reshape(b, t, nh, dk)
+    if cfg.lightning_rope_theta:
+        q, k = (apply_rope(a, positions, cfg.lightning_rope_theta) for a in (q, k))
+    g = jnp.broadcast_to(-lp["slope"].astype(jnp.float32), (b, t, nh))
+    g, k = lightning_ops.mask_inputs(g, k, valid)
+
+    def rule(q, k, v, g, slot, state):
+        b, t = g.shape[:2]
+        rows = _rows(state, idx, slot, b)
+        if t == 1:
+            with jax.named_scope("lightning_step"):
+                o, rows = lightning_ops.lightning_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], rows)
+                o = o[:, None]
+        else:
+            with jax.named_scope("lightning_chunk"):
+                o, rows = lightning_ops.lightning_chunked(q, k, v, g, rows)
+        return o, _put_rows(state, rows, idx, slot)
+
+    o, state = _stack_by_group(rule, n_lanes, slot, state, q * dk**-0.5, k, v, g)
+    o = rms_norm(o.reshape(b, t, nh * dk), lp["o_norm"], cfg.norm_eps) * jax.nn.sigmoid(_proj(h, lp["wg"]))
+    return _proj(o.astype(h.dtype), lp["wo"]), state
+
+
 def _mla_rotate(x, positions, cfg: ModelConfig):
     """``x [B, T, n, r]`` float32 rotated by its tokens' positions with the
     model's frequencies and pairing (``ops/rope.py``)."""
@@ -1018,7 +1230,8 @@ def forward(
     if cache is None:
         # a ring drops what is written at the arena's last row (a parked
         # lane's): the arena of a model with one is a row longer than the tokens
-        cache = init_cache(cfg, b, t + 1 if cfg.n_window else t, params["final_norm"].dtype)
+        rows = t + 1 if cfg.n_window else -(-t // cfg.sparse_block) * cfg.sparse_block if cfg.n_sparse else t
+        cache = init_cache(cfg, b, rows, params["final_norm"].dtype)
     stop = cache.stop
     if cache.wk is not None:
         check_ring_launch(cache.wk.shape[2], cache.k.shape[2], cfg.window, t)
@@ -1054,6 +1267,8 @@ def forward(
     # run, PR 30); what a matmul takes is rounded to the weights' dtype once
     act = params["final_norm"].dtype
     x = embed_lookup(params["embed"], tokens).astype(jnp.float32)
+    if cfg.embed_scale != 1.0:
+        x = x * cfg.embed_scale
     # a layer's index within its kind's stacks and leaves ("swa" layers are
     # the ones that are not positional where the model has no linear mixer)
     kinds = np.array([k in POSITIONAL_KINDS for k in cfg.layer_kinds])
@@ -1077,18 +1292,23 @@ def forward(
     lin_kind, pos_kind = cfg.linear_kind, cfg.positional_kind
 
     def mixer(h, rows, ring, state, conv, is_pos, idx):
-        """``rows``: the positional leaves (``(latent,)`` or ``(k, v)``);
+        """``rows``: the positional leaves (``(latent,)``, ``(k, v)`` or, with
+        the pooled keys, ``(k, v, ck)``);
         ``ring``: the window layers' (``(wk, wv)`` or nothing)."""
 
         def linear(h, idx, state, conv):
+            lp = _layer_of(params[lin_kind], idx)
+            if lin_kind == "lightning":  # no conv: the leaf is None and stays so
+                return *lightning_mixer(h, lp, cfg, state, idx, slot, positions, valid, plan, n_lanes), conv
             fn = kda_mixer if lin_kind == "kda" else gdn_mixer
-            return fn(h, _layer_of(params[lin_kind], idx), cfg, state, conv, idx, slot, valid, plan, n_lanes)
+            return fn(h, lp, cfg, state, conv, idx, slot, valid, plan, n_lanes)
 
         def positional(h, idx, *rows, in_loop=False):
             lp = _layer_of(params[pos_kind], idx)
             if pos_kind == "mla":
                 return mla_mixer(h, lp, cfg, *rows, idx, slot, positions, valid, plan, n_lanes, in_loop=in_loop)
-            return full_mixer(h, lp, cfg, *rows, idx, slot, positions, valid, plan, n_lanes)
+            fn = sparse_mixer if pos_kind == "sparse" else full_mixer
+            return fn(h, lp, cfg, *rows, idx, slot, positions, valid, plan, n_lanes)
 
         def windowed(h, idx, *ring):
             with jax.named_scope("attn_window"):
@@ -1178,24 +1398,31 @@ def forward(
             return (x, rows, ring, state, conv), None
         h = rms_norm(x, attn_norm, cfg.norm_eps).astype(act)
         y, rows, ring, state, conv = mixer(h, rows, ring, state, conv, is_pos, m_idx)
+        y2 = lambda x: ffn(rms_norm(x, mlp_norm, cfg.norm_eps), is_dense, f_idx).astype(jnp.float32)  # noqa: E731
+        if cfg.residual_scale != 1.0:  # µP: every sublayer's output scaled before the residual adds it
+            x = x + y.astype(jnp.float32) * cfg.residual_scale
+            return (x + y2(x) * cfg.residual_scale, rows, ring, state, conv), None
         x = x + y.astype(jnp.float32)
-        x = x + ffn(rms_norm(x, mlp_norm, cfg.norm_eps), is_dense, f_idx).astype(jnp.float32)
+        x = x + y2(x)
         return (x, rows, ring, state, conv), None
 
     xs = (
         params["layers"]["attn_norm"], params["layers"]["mlp_norm"],
         jnp.asarray(kinds), jnp.asarray(mixer_idx), jnp.asarray(dense), jnp.asarray(ffn_idx, jnp.int32),
     )
+    carried = cache.carried()
     (x, rows, ring, state, conv), _ = lax.scan(
-        layer_step, (x, cache.rows(), cache.ring(), cache.state, cache.conv), xs
+        layer_step, (x, tuple(carried.values()), cache.ring(), cache.state, cache.conv), xs
     )
     if n_lanes:
         # the head's rows: the chunk's ``last`` and the lanes', not T + B
         x = jnp.concatenate([lax.dynamic_slice_in_dim(x[0], last, 1, 0), x[0, t:]], axis=0)
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps).astype(act)
-    logits = _proj(x, params["lm_head"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    if cfg.logit_divisor != 1.0:
+        x = x / cfg.logit_divisor
+    logits = _proj(x.astype(act), params["lm_head"])
     if not keep_cache:
         return logits, None
-    named = dict(zip([n for n in cache.POSITIONAL if getattr(cache, n) is not None], rows))
+    named = dict(zip(carried, rows))
     named.update(zip(cache.RING, ring))
     return logits, cache._replace(**named, state=state, conv=conv, stop=stop)

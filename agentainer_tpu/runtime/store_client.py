@@ -21,6 +21,7 @@ import asyncio
 import os
 import random
 import struct
+import time
 from typing import Any
 
 import aiohttp
@@ -56,6 +57,23 @@ TRANSIENT_ERRORS = (
     asyncio.IncompleteReadError,  # EOFError subclass: peer closed mid-frame
     aiohttp.ClientConnectionError,
 )
+
+
+# a blob's part: under the store socket's 64 MiB frame (native/dataplane.cc
+# ``handle_uds_conn``), with room for its base64 on the HTTP path
+PART_BYTES = 32 << 20
+_PARTS = b"ATPU-PARTS1 "
+
+
+def _manifest(raw: bytes | None) -> tuple[str, int, int] | None:
+    """``(generation, parts, size)`` of a blob stored in parts, else None."""
+    if raw is None or not raw.startswith(_PARTS) or len(raw) > 128:
+        return None
+    try:
+        generation, n, size = raw[len(_PARTS):].decode().split()
+        return generation, int(n), int(size)
+    except ValueError:
+        return None
 
 
 class _UDSPool:
@@ -420,11 +438,65 @@ class StoreClient:
         await self._op("set", key, value=value, ttl=ttl)
 
     async def set_bytes(self, key: str, blob: bytes, ttl: float | None = None) -> None:
-        import base64
-
-        await self._op("set_b64", key, value_b64=base64.b64encode(blob).decode(), ttl=ttl)
+        """``blob`` under ``key``. One over :data:`PART_BYTES` (the store
+        socket closes a connection on a frame over 64 MiB: a long session's
+        snapshot is hundreds of MB) goes in parts, ``key:part:<generation>:<i>``,
+        and ``key`` holds a manifest naming them, written LAST: a reader, or a
+        crash in the middle, finds the generation before whole. The
+        generation before is deleted once the new one stands."""
+        if len(blob) <= PART_BYTES:
+            await self._set_blob(key, blob, ttl)
+            return
+        old = _manifest(await self._get_blob(key))
+        generation = f"{time.time_ns():x}"
+        n = -(-len(blob) // PART_BYTES)
+        view = memoryview(blob)
+        for i in range(n):
+            await self._set_blob(f"{key}:part:{generation}:{i}", bytes(view[i * PART_BYTES : (i + 1) * PART_BYTES]), ttl)
+        await self._set_blob(key, _PARTS + f"{generation} {n} {len(blob)}".encode(), ttl)
+        if old is not None:
+            for i in range(old[1]):
+                await self.delete(f"{key}:part:{old[0]}:{i}")
 
     async def get_bytes(self, key: str) -> bytes | None:
+        raw = await self._get_blob(key)
+        parts = _manifest(raw)
+        if parts is None:
+            return raw
+        generation, n, size = parts
+        got = [await self._get_blob(f"{key}:part:{generation}:{i}") for i in range(n)]
+        if any(p is None for p in got) or sum(map(len, got)) != size:
+            return None  # a part expired or was lost: no snapshot, never a torn one
+        return b"".join(got)
+
+    async def _set_blob(self, key: str, blob: bytes, ttl: float | None) -> None:
+        """One value of bytes. The store socket's frames carry bytes as they
+        are; only the HTTP path's JSON needs base64 (encoded off the loop's
+        thread: 32 MiB hold the interpreter for tens of ms)."""
+        if self.connected and self._uds is not None:
+            frame = _enc(_OP_NUM["set"], [key.encode(), blob, b"" if ttl is None else repr(float(ttl)).encode()])
+
+            async def attempt():
+                await faults.fire_async("store_client.rpc")
+                return self._decode_result("set", *await self._uds.roundtrip(frame))
+
+            await self._with_retry(attempt)
+            return
+        import base64
+
+        value = await asyncio.to_thread(lambda: base64.b64encode(blob).decode())
+        await self._op("set_b64", key, value_b64=value, ttl=ttl)
+
+    async def _get_blob(self, key: str) -> bytes | None:
+        if self.connected and self._uds is not None:
+            async def attempt():
+                await faults.fire_async("store_client.rpc")
+                status, vals = await self._uds.roundtrip(_enc(_OP_NUM["get"], [key.encode()]))
+                if status == 1:
+                    raise RuntimeError(vals[0].decode("utf-8", "replace") if vals else "store error")
+                return vals[0] if status == 0 and vals else None
+
+            return await self._with_retry(attempt)
         import base64
 
         raw = await self._op("get_b64", key)

@@ -9,6 +9,7 @@ UDS binary store path the echo engine uses for its conversation writes.
 
 import asyncio
 import json
+import os
 
 import aiohttp
 import pytest
@@ -240,6 +241,46 @@ def test_head_chunked_and_connection_close(tmp_path):
             )
             raw = await _raw_http(port, huge)
             assert not raw.startswith(b"HTTP/1.1 200"), raw[:80]
+        finally:
+            await teardown(services, task, session)
+
+    asyncio.run(body())
+
+
+def test_uds_takes_a_blob_over_its_frame_cap_in_parts(tmp_path):
+    """The store socket closes a connection on a frame over 64 MiB; a long
+    session's snapshot is hundreds of MB. ``StoreClient.set_bytes`` sends such
+    a blob as parts of 32 MiB and a manifest, raw bytes on the socket, and
+    ``get_bytes`` reads it back whole; one frame over the cap still resets."""
+
+    async def body():
+        services, task, session = await start_stack(tmp_path)
+        try:
+            resp = await session.post("/agents", json={"name": "dp-blob", "model": "echo"}, headers=AUTH)
+            aid = (await resp.json())["data"]["id"]
+            await session.post(f"/agents/{aid}/start", headers=AUTH)
+
+            from agentainer_tpu.runtime import store_client
+            from agentainer_tpu.store.schema import Keys
+
+            token = services.store.get(Keys.internal_token(aid))
+            token = token.decode() if isinstance(token, bytes) else token
+            client = store_client.StoreClient(store_sock=services.backend.store_sock, agent_id=aid, token=token, retries=0)
+            try:
+                key = f"agent:{aid}:kv:long"
+                blob = os.urandom(1 << 20) * 70 + b"tail"  # 70 MiB: three parts
+                await client.set_bytes(key, blob, ttl=60)
+                assert await client.get_bytes(key) == blob
+                parts = sorted(k for k in await client.keys(key + "*") if k != key)
+                assert len(parts) == 3 and all(":part:" in k for k in parts)
+                raw = services.store.get(key)
+                assert raw.startswith(b"ATPU-PARTS1 ") and raw.endswith(f" 3 {len(blob)}".encode())
+                await client.set_bytes(key, blob[:1000], ttl=60)  # a short one again: one value, no base64 on the way
+                assert services.store.get(key) == blob[:1000] and await client.get_bytes(key) == blob[:1000]
+                with pytest.raises((ConnectionError, asyncio.IncompleteReadError, OSError)):
+                    await client._set_blob(key, blob, 60)  # one frame over the cap: the peer hangs up
+            finally:
+                await client.close()
         finally:
             await teardown(services, task, session)
 

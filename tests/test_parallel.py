@@ -91,6 +91,9 @@ ASSIGNED = {
     # and with two kinds of attention and a ring in its cache (PR 50)
     "laguna-xs.2": [(1, 1), (1, 1), (1, 1), (1, 1)],
     "tiny-laguna": [(1, 1), (1, 1), (1, 1), (1, 1)],
+    # and with chosen key blocks beside a conv-less linear state (PR 54)
+    "minicpm-sala": [(1, 1), (1, 1), (1, 1), (1, 1)],
+    "tiny-minicpm-sala": [(1, 1), (1, 1), (1, 1), (1, 1)],
 }
 
 

@@ -769,6 +769,77 @@ def test_a_laguna_engine_names_both_kinds_the_gate_the_rotaries_and_the_rings_by
         assert scope in text, scope
 
 
+# -- chosen key blocks beside a conv-less linear state in one slot (MiniCPM-SALA) ------
+def test_a_minicpm_sala_engine_names_both_kinds_the_selection_the_state_and_the_pooled_leafs_bytes():
+    """Names the benchmark's readers and a reader of a capture rely on:
+    ``model_arch.layer_kinds`` with ``sparse`` and ``lightning``;
+    ``attention.{sparse,lightning}_{prefill,decode}``; the ``attention.sparse``
+    block (the sizes and ``steps_dense`` / ``steps_sparse``, ``blocks_live`` /
+    ``blocks_selected`` / ``blocks_forced``, ``rows_live`` / ``rows_read``,
+    ``pooled_rows_scored``) and the ``linear`` block (``kind``,
+    ``state_bytes_lane``, ``rows_chunked``, ``steps``); ``cache`` bytes by leaf
+    with ``ck`` and the state and no conv; ``engine.snapshot`` /
+    ``engine.restore`` spans carrying ``bytes=`` with the pooled leaf, a row
+    every 4 positions of the snapshot's bucket; the ``jax.named_scope``s of
+    the two mixers in the steps' metadata; and the launch ledger counting the
+    step programs as it counts the others'."""
+    options = {"max_batch": 2, "max_seq": 256, "prefill_chunk": 32, "decode_chunk": 4}
+    eng = LLMEngine.create("tiny-minicpm-sala", options=options)
+    seen = []
+    span = eng._spans.span
+    eng._spans.span = lambda name, **attrs: (seen.append((name, attrs)), span(name, **attrs))[1]
+    try:
+        async def drive():
+            text = "a document long enough that the session passes the tiny dense_len of ninety-six rows and chooses its blocks"
+            await eng.chat("s", text, max_tokens=9)
+            eng.snapshot_min_gap_s = eng.snapshot_busy_gap_s = 0.0
+            blob = await eng.snapshot_session("s")
+            return await eng.restore_session("t", blob)
+
+        assert asyncio.run(drive()) is True
+        time.sleep(0.2)
+        m = eng.metrics()
+        tokens = jnp.zeros((1, 32), jnp.int32)
+        prefill = eng._prefill.lower(eng.params, eng.cache, jnp.int32(0), tokens, tokens, jnp.int32(4)).as_text(debug_info=True)
+        decode = eng._decode_n.lower(
+            eng.params, eng.cache, eng._dtok, eng._dpos, eng._dtemps, eng._dtopk, eng._dtopp,
+            jax.random.split(jax.random.PRNGKey(0), 1),
+        ).as_text(debug_info=True)
+    finally:
+        eng.shutdown()
+    a, cache, lin = m["attention"], m["cache"], m["linear"]
+    assert m["model_arch"]["layer_kinds"] == {"lightning": 4, "sparse": 4}
+    for key in ("sparse_prefill", "sparse_decode", "lightning_prefill", "lightning_decode", "reason"):
+        assert a[key], key
+    sp = a["sparse"]
+    assert {k: sp[k] for k in ("kernel", "stride", "block", "init_blocks", "window", "topk", "dense_len")} == {
+        "kernel": 8, "stride": 4, "block": 16, "init_blocks": 1, "window": 32, "topk": 6, "dense_len": 96}
+    for key in ("steps_dense", "steps_sparse", "blocks_live", "blocks_selected", "blocks_forced", "rows_live", "rows_read",
+                "pooled_rows_scored"):
+        assert sp[key] > 0, key
+    assert sp["blocks_forced"] < sp["blocks_selected"] < sp["blocks_live"] and sp["rows_read"] < sp["rows_live"]
+    assert lin["kind"] == "lightning" and lin["conv"] is False and lin["state_bytes_lane"] == 4 * 4 * 16 * 16 * 4
+    assert lin["rows_chunked"] + lin["steps"] == sp["steps_dense"] + sp["steps_sparse"] and lin["steps"] >= 8
+    assert cache["kinds"] == ["k", "v", "ck", "state"] and all(cache[leaf + "_bytes"] > 0 for leaf in cache["kinds"])
+    assert cache["ck_bytes"] * 4 == cache["k_bytes"] and cache["state_resets"] >= 1
+    for name in ("engine.snapshot", "engine.restore", "engine.state_reset"):
+        assert m["phases"][name]["n"] >= 1, (name, sorted(m["phases"]))
+    carried = {name: attrs["bytes"] for name, attrs in seen if name in ("engine.snapshot", "engine.restore") and "bytes" in attrs}
+    assert set(carried) == {"engine.snapshot", "engine.restore"}
+    sizes = {name: {k: int(v) for k, v in (part.split("=") for part in text.split(","))} for name, text in carried.items()}
+    row = 4 * 2 * 16 * 4  # a K row of the 4 sparse layers: 2 heads of 16, float32 here
+    assert sorted(sizes["engine.snapshot"]) == sorted(sizes["engine.restore"]) == ["ck", "k", "state", "v"]
+    assert sizes["engine.snapshot"]["k"] == 128 * row and sizes["engine.snapshot"]["ck"] == 128 // 4 * row  # the position's bucket
+    assert 96 * row < sizes["engine.restore"]["k"] <= 128 * row and sizes["engine.restore"]["ck"] == 128 // 4 * row
+    assert sizes["engine.restore"]["state"] == sizes["engine.snapshot"]["state"] == 4 * 4 * 16 * 16 * 4
+    for scope in ("sparse_index", "sparse_select", "sparse_attend", "lightning_chunk", "attn_gate"):
+        assert scope in prefill, scope
+    for scope in ("sparse_index", "sparse_select", "sparse_attend", "lightning_step"):
+        assert scope in decode, scope
+    ledger = m["launches"]
+    assert any(name.startswith("jit_prefill") for name in ledger) and any(name.startswith("jit_decode_n") for name in ledger)
+
+
 def test_attention_names_the_prefill_tile_where_the_flash_kernel_serves(monkeypatch):
     """``/metrics`` ``attention.prefill_tile`` (ISSUE 49): where the plan says
     ``pallas:flash_prefill`` over the dense arena (a TPU engine; here the plan

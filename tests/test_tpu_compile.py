@@ -419,6 +419,11 @@ def _hybrid_case(model: str, where):
             get_config("mistral-small-4-119b"), n_layers=9, layer_kinds=("mla",) * 9, experts_held=32,
             max_seq_len=seq, name=model,
         )
+    elif model == "sala-32l":
+        # the served model whole: all 32 layers, the whole vocabulary, 8 lanes
+        # of 49,152 (benchmark/configs/minicpm-sala-9b-1chip.json)
+        lanes, seq = 8, 49_152
+        cfg = dataclasses.replace(get_config("minicpm-sala"), max_seq_len=seq, name=model)
     elif model == "laguna-40l":
         # the served share whole: 40 layers, 32 of 256 experts, the whole
         # vocabulary, 8 lanes of 16,384 (benchmark/configs/laguna-xs2-33b-ep8-1chip.json)
@@ -669,6 +674,49 @@ def test_laguna_step_fits_the_chip_and_keeps_every_leaf_in_place_on_v5e(v5e, mon
         stated = json.load(f)["memory"]["compiled_live_bytes"][step]
     assert abs(live - stated) < 0.02 * stated, (live, stated)
     assert abs(cfg.param_count() - 5_961_517_056) == 0  # the share: 5.96 GB of int8 weights
+
+
+SALA_CONFIG = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmark", "configs", "minicpm-sala-9b-1chip.json")
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill", "mixed"])
+def test_minicpm_sala_step_fits_the_chip_and_keeps_every_leaf_in_place_on_v5e(v5e, step):
+    """MiniCPM-SALA whole at its REAL size (32 layers, the whole vocabulary, 8
+    lanes of 49,152, int8 as served): the step programs compile for a v5e, the
+    four leaves (``k``, ``v`` 3.22 GB, the pooled keys 0.10 GB, the lightning
+    state 0.40 GB) are donated in place and none is copied or relaid out, no
+    weight stack is copied, and the step's live bytes fit a v5e's 15.75 GB and
+    are what the configuration file states (``compiled_live_bytes``). A
+    lane's step under ``dense_len`` takes ``flash_decode`` at 32 / 2 heads;
+    what a sparse row does with its blocks and the lightning rule are XLA's."""
+    import json
+
+    cfg, cache, plan, steps = _hybrid_case("sala-32l", SingleDeviceSharding(v5e.devices[0]))
+    assert plan.sparse_decode == "pallas:flash_decode+xla:block_gather" and plan.lightning_decode == "xla_step"
+    assert cache.k.shape == (8, 8, 49_152, 2, 128) and cache.ck.shape == (8, 8, 3072, 2, 128)
+    assert cache.state.shape == (24, 8, 32, 128, 128) and cache.conv is None and cache.latent is None
+    fn, args = steps[step]
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    calls = re.findall(r'%([a-z_]+)[.\d]* = [^\n]*custom_call_target="tpu_custom_call"', text)
+    assert sorted(calls) == {"decode": ["flash_decode"], "prefill": [], "mixed": ["flash_decode"]}[step], calls
+    mem = compiled.memory_analysis()
+    leaves = dict(cache.leaves())
+    assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize for a in leaves.values())
+    for name, a in leaves.items():
+        shape = ",".join(map(str, a.shape))
+        assert not re.search(rf"\[{shape}\][^ ]* (copy|transpose)\(", text), name
+    for shape in ("8,4096,4096", "24,4096,4096", "32,4096,16384", "32,16384,4096"):
+        assert not re.search(rf"\[{shape}\][^ ]* (copy|transpose)\(", text), shape
+    live = mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    print(f"sala-32l {step}: args {mem.argument_size_in_bytes / 1e9:.2f} GB, temp {mem.temp_size_in_bytes / 1e9:.2f} GB, "
+          f"out {mem.output_size_in_bytes / 1e9:.2f} GB, aliased {mem.alias_size_in_bytes / 1e9:.2f} GB, live {live / 1e9:.2f} GB")
+    assert 13.0e9 < live < V5E_USABLE_BYTES
+    if os.path.exists(SALA_CONFIG):
+        with open(SALA_CONFIG) as f:
+            stated = json.load(f)["memory"]["compiled_live_bytes"][step]
+        assert abs(live - stated) < 0.02 * stated, (live, stated)
+    assert cfg.param_count() == 9_476_833_280 + 373_504  # 9.48 GB of int8 weights
 
 
 @pytest.mark.parametrize("step", ["decode", "prefill", "mixed"])

@@ -417,26 +417,26 @@ def _plan_gdn_full(cfg: ModelConfig, use_pallas: bool) -> HybridPlan:
 
 
 def _plan_lightning_sparse(cfg: ModelConfig, use_pallas: bool) -> HybridPlan:
-    """Lightning beside block-sparse attention. The state's rule and what a
-    sparse row does with its chosen blocks are XLA's on every backend (a
-    gather of the listed blocks for a lane's step, a row-by-block mask over
-    runs of the lane's rows for a chunk); a lane's step under
-    ``cfg.sparse_dense_len`` takes the dense flash kernel where it takes the
-    head counts."""
+    """Lightning beside block-sparse attention. The state's rule and a chunk's
+    sparse rows (a row-by-block mask over runs of the lane's rows) are XLA's on
+    every backend. A lane's step takes two kernels where they take the head
+    counts: the dense flash kernel under ``cfg.sparse_dense_len`` and, past
+    it, ``sparse_decode``, which copies the listed blocks from the arena
+    where it lies; elsewhere XLA gathers them."""
     from ..ops.pallas_attention import kernel_supported
 
-    why, dense = [], "xla:attention_reference"
+    why, dense, blocks = [], "xla:attention_reference", "xla:block_gather"
     if not use_pallas:
         why.append("no tpu backend")
     elif kernel_supported(cfg.n_heads, stored_kv_heads(cfg.n_kv_heads), cfg.head_dim):
-        dense = "pallas:flash_decode"
-        why.append("tpu backend; a dense row's K/V read where it lies, a sparse row's blocks gathered by XLA")
+        dense, blocks = "pallas:flash_decode", "pallas:sparse_decode"
+        why.append("tpu backend; a lane's K/V rows read where they lie, a dense row's and a sparse row's listed blocks")
     else:
         why.append(f"heads {cfg.n_heads}/{cfg.n_kv_heads} x {cfg.head_dim}: not the flash kernels' shapes")
     sparse, lightning = bool(cfg.n_sparse), cfg.linear_kind == "lightning"
     return HybridPlan(
         "", "", "", "", "; ".join(why),
-        sparse_decode=f"{dense}+xla:block_gather" if sparse else "",
+        sparse_decode=f"{dense}+{blocks}" if sparse else "",
         sparse_prefill="xla:block_mask" if sparse else "",
         lightning_decode="xla_step" if lightning else "", lightning_prefill="xla_chunked" if lightning else "",
     )
@@ -983,8 +983,8 @@ def sparse_mixer(
     context is at most ``cfg.sparse_dense_len`` rows reads all of it; past
     that it scores the lane's visible pooled keys (``sparse_index``), takes
     ``cfg.sparse_topk`` blocks (``sparse_select``) and reads those alone
-    (``sparse_attend``): a lane's step gathers the listed blocks, a chunk's
-    rows see their blocks through a row-by-block mask. The output is gated by
+    (``sparse_attend``): a lane's step by the plan's kernel or XLA's gather, a
+    chunk's rows through a row-by-block mask. The output is gated by
     a sigmoid of the normed input as wide as itself. ``n_lanes`` as
     :func:`mla_mixer`: both groups' rows and pooled keys are written before
     either group is read."""
@@ -1000,6 +1000,7 @@ def sparse_mixer(
     q, k = _attn_rotate(q, positions, cfg, "full"), _attn_rotate(k, positions, cfg, "full")
     heads = lambda a, n: jnp.pad(a.astype(h.dtype), [(0, 0), (0, 0), (0, n - a.shape[2]), (0, 0)])  # noqa: E731
     dense = attn_ops.pallas_dense if plan.sparse_decode.startswith("pallas:") else attn_ops._reference_dense
+    listed = sparse_ops.blocks_step(kernel=plan.sparse_decode.endswith("pallas:sparse_decode"))
 
     def append(pooled, positions, valid, slot):  # the kernels a group's rows completed
         lanes = jnp.arange(positions.shape[0]) + (0 if slot is None else slot)
@@ -1034,8 +1035,7 @@ def sparse_mixer(
             under = positions[:, 0] < sizes.dense_len
             seen = jnp.where(valid & under[:, None], positions, 0)
             o_dense = dense(heads(q, stored * (nh // nkv)), ck, cv, seen, None, idx, slot)[:, :, :nh]
-            o_blocks = sparse_ops.attend_blocks(
-                q[:, 0], ck, cv, idx, jnp.arange(b) + lane, blocks[:, 0], positions[:, 0], nkv, sizes.block)
+            o_blocks = listed(q[:, 0], ck, cv, idx, lane, blocks[:, 0], positions[:, 0], nkv, sizes.block)
             return jnp.where(under[:, None, None, None], o_dense.astype(jnp.float32), o_blocks[:, None])
 
     o = _by_group(attend, n_lanes, q, positions, valid, slot)

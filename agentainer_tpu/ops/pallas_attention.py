@@ -562,6 +562,194 @@ def flash_decode(
     return out.reshape(b, h, hd)
 
 
+# ---------------------------------------------------------------------------
+# a lane's step over its chosen key blocks (ops/sparse_attention.py)
+# ---------------------------------------------------------------------------
+#
+# A block-sparse layer's step reads ``topk`` listed blocks of ``block`` rows a
+# K/V head, anywhere in the lane's rows. A block with every stored head is one
+# contiguous run of the arena (64 rows x 2 heads x 128 bf16: 32 KB), far too
+# small for one block a grid step, and its address comes from a list, not from
+# the grid. So the grid is the lanes, K and V stay in HBM (``pl.ANY``), and the
+# kernel copies a CHUNK of listed blocks at a time into one half of a
+# double-buffered VMEM scratch with its own async copies, the next chunk's
+# (or the next lane's first) in flight under this one's matmuls. A block that
+# a later head lists at the place where the first head lists it is copied
+# once and read by both: the selection puts its forced blocks, the same for
+# every head, first and in order (``select_blocks``: +inf scores, ``top_k``
+# breaks ties by index), which is half the list.
+
+_SPARSE_KV_VMEM = 4 << 20
+
+
+def sparse_chunk(kv: int, stored: int, hd: int, dtype, block: int, topk: int) -> int:
+    """Listed blocks ``sparse_decode`` copies a chunk: a divisor of ``topk``,
+    as many as reach 512 rows a head (``flash_decode``'s block) while K and V
+    chunks of every head, two buffers each, fit ``_SPARSE_KV_VMEM``."""
+    rows = _SPARSE_KV_VMEM // (4 * kv * stored * hd * jnp.dtype(dtype).itemsize)
+    most = max(1, min(512, rows) // block)
+    return next(n for n in range(min(most, topk), 0, -1) if topk % n == 0)
+
+
+def _sparse_decode_kernel(
+    layer_ref,  # [1] int32 (SMEM, scalar prefetch)
+    slot_ref,  # [1] int32 (SMEM, scalar prefetch)
+    pos_ref,  # [B] int32 (SMEM, scalar prefetch)
+    blocks_ref,  # [B * KV * topk] int32 (SMEM, scalar prefetch) the lists, -1: none
+    q_ref,  # [KV, G, hd]
+    k_hbm,  # [L, Bc, S, KVs, hd] where it lies
+    v_hbm,
+    o_ref,  # [KV, G, hd] f32
+    k_buf,  # [2 * KV * per, 1, block, KVs, hd]: buffer, head that listed it, place in the chunk
+    v_buf,
+    sems,  # DMA [2, 2]: K or V, buffer
+    m_ref,  # [KV, G, 1] f32
+    l_ref,  # [KV, G, 1] f32
+    acc_ref,  # [KV, G, hd] f32
+    *,
+    block: int,
+    topk: int,
+    per: int,
+    scale: float,
+):
+    ib, nb = pl.program_id(0), pl.num_programs(0)
+    kv = q_ref.shape[0]
+    n_chunks = topk // per
+    layer, first_lane = layer_ref[0], slot_ref[0]
+
+    def listed(lane, h, i):
+        """Place ``i`` of head ``h``'s list, and whether the head holds a copy
+        of its own there: head 0 does, a later head where it lists another
+        block than head 0."""
+        e = blocks_ref[(lane * kv + h) * topk + i]
+        return e, (e != blocks_ref[lane * kv * topk + i]) if h else True
+
+    def copies(lane, c, buf, go):
+        """Start (or wait for) the copies of chunk ``c`` of ``lane``'s lists
+        into buffer ``buf``: the blocks that are there, once each."""
+        for h in range(kv):
+            for j in range(per):
+                e, own = listed(lane, h, c * per + j)
+
+                @pl.when((e >= 0) & own)
+                def _():
+                    # (a list made for a position past the arena's end may name a block past it)
+                    rows = pl.ds(pl.multiple_of(jnp.minimum(e, k_hbm.shape[2] // block - 1) * block, block), block)
+                    at = (buf * kv + h) * per + j
+                    for hbm, into, sem in ((k_hbm, k_buf, sems.at[0, buf]), (v_hbm, v_buf, sems.at[1, buf])):
+                        go(pltpu.make_async_copy(hbm.at[layer, first_lane + lane, rows], into.at[at, 0], sem))
+
+    start, wait = (lambda cp: cp.start()), (lambda cp: cp.wait())
+
+    @pl.when(ib == 0)
+    def _first():
+        # a place whose entry is -1 is never copied into: what the scratch
+        # held before the call may be NaN, and 0 * NaN would reach acc
+        v_buf[...] = jnp.zeros_like(v_buf)
+        copies(0, 0, 0, start)
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    pos = pos_ref[ib]
+    col = lax.broadcasted_iota(jnp.int32, (1, per * block), 1)
+
+    def chunk(c, carry):
+        buf = (ib * n_chunks + c) % 2
+        wraps = c + 1 == n_chunks
+        nxt_lane, nxt = jnp.where(wraps, ib + 1, ib), jnp.where(wraps, 0, c + 1)
+
+        @pl.when(nxt_lane < nb)
+        def _ahead():
+            copies(nxt_lane, nxt, 1 - buf, start)
+
+        copies(ib, c, buf, wait)
+        for h in range(kv):
+            # where each block's rows lie in the scratch, and the position
+            # each row holds: past ``pos`` for a block that is not there
+            at, held = [], None
+            for j in range(per):
+                e, own = listed(ib, h, c * per + j)
+                at.append((buf * kv + jnp.where(own, h, 0)) * per + j)
+                first = jnp.where(e >= 0, e * block, pos + 1) - j * block
+                held = first + col if j == 0 else jnp.where(col >= j * block, first + col, held)
+            seen = held <= pos  # [1, per * block]
+            qb = q_ref[h].astype(k_hbm.dtype)  # [G, hd]
+            kb = jnp.concatenate([_head(k_buf.at[pl.ds(a, 1)], h).astype(k_hbm.dtype) for a in at], axis=0)
+            vb = jnp.concatenate([_head(v_buf.at[pl.ds(a, 1)], h).astype(v_hbm.dtype) for a in at], axis=0)
+            s = lax.dot_general(qb, kb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            s = jnp.where(seen, s * scale, NEG_INF)  # [G, per * block]
+            m_prev = m_ref[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+            l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + lax.dot_general(
+                p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+        return carry
+
+    lax.fori_loop(0, n_chunks, chunk, 0)
+    o_ref[...] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def sparse_decode(
+    q: jnp.ndarray,  # [B, H, hd]
+    k: jnp.ndarray,  # [L, Bc, S, KVs, hd] the stacked arena
+    v: jnp.ndarray,
+    blocks: jnp.ndarray,  # [B, KV, topk] int32: each K/V head's blocks, -1 for none
+    q_positions: jnp.ndarray,  # [B] int32
+    layer,  # int32 scalar: the layer of the stack to read
+    slot=0,  # int32 scalar: sequence b reads arena row slot + b
+    *,
+    block: int,
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Single-token attention over listed key blocks of ``block`` rows
+    (``ops/sparse_attention.attend_blocks``, which is its reference): head
+    group ``g`` of lane ``b`` sees row ``r`` iff ``r // block`` is in
+    ``blocks[b, g]`` and ``r <= q_positions[b]``. The listed blocks are copied
+    from the arena where it lies, and nothing else of it is read; the heads
+    past ``KV`` that the arena stores as padding are not attended. float32
+    out, whatever ``q`` is."""
+    b, h, hd = q.shape
+    kv, topk = blocks.shape[1:]
+    stored = k.shape[3]
+    g = h // kv
+    if k.shape[2] % block:
+        raise ValueError(f"an arena of {k.shape[2]} rows is not whole key blocks of {block}")
+    per = sparse_chunk(kv, stored, hd, k.dtype, block, topk)
+    kernel = functools.partial(_sparse_decode_kernel, block=block, topk=topk, per=per, scale=1.0 / (hd**0.5))
+    q_spec = pl.BlockSpec((None, kv, g, hd), lambda ib, *scalars: (ib, 0, 0, 0))
+    where_it_lies = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,  # layer, slot, positions, the lists
+        grid=(b,),
+        in_specs=[q_spec, where_it_lies, where_it_lies],
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((2 * kv * per, 1, block, stored, hd), k.dtype),
+            pltpu.VMEM((2 * kv * per, 1, block, stored, hd), v.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((kv, g, 1), jnp.float32),
+            pltpu.VMEM((kv, g, 1), jnp.float32),
+            pltpu.VMEM((kv, g, hd), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kv, g, hd), jnp.float32),
+        # a lane's last chunk starts the next lane's first copies
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="sparse_decode",
+    )(_scalar(layer), _scalar(slot), q_positions.astype(jnp.int32), blocks.astype(jnp.int32).reshape(-1),
+      q.reshape(b, kv, g, hd), k, v)
+    return out.reshape(b, h, hd)
+
+
 def kernel_supported(n_heads: int, n_kv_heads: int, head_dim: int) -> bool:
     """The kernels assume lane-aligned head_dim, clean GQA grouping, and a
     KV-head count whose ``[KV, hd]`` rows are stored unpadded (1, 2, 4 or a

@@ -24,14 +24,16 @@ heads a K/V head:
    and K/V head. float32 at ``highest`` precision: the scores are a few
    thousandths of the layer's projections, and a near-tie at the cut is a
    discontinuity like a router's.
-3. **Attention over the chosen blocks**: for one query row a lane
-   (:func:`attend_blocks`) the listed blocks' rows are gathered and nothing
-   else of the arena is read; for a chunk of rows (:func:`attend_masked`) the
-   sets become a row-by-block mask over the lane's rows, read a run of blocks
-   at a time up to the chunk's last position with an online softmax. A query
-   whose context is at most ``dense_len`` rows reads all of it: in a chunk
-   its mask is every block, and a lane's step takes the dense kernel
-   (``models/hybrid.sparse_mixer``).
+3. **Attention over the chosen blocks**: for one query row a lane the listed
+   blocks' rows are read and nothing else of the arena: on a TPU by
+   ``pallas_attention.sparse_decode``, a kernel that copies the listed blocks
+   out of the arena where it lies; elsewhere, and as that kernel's reference,
+   by XLA's gather (:func:`attend_blocks`; :func:`blocks_step` is the choice).
+   For a chunk of rows (:func:`attend_masked`) the sets become a row-by-block
+   mask over the lane's rows, read a run of blocks at a time up to the chunk's
+   last position with an online softmax. A query whose context is at most
+   ``dense_len`` rows reads all of it: in a chunk its mask is every block, and
+   a lane's step takes the dense kernel (``models/hybrid.sparse_mixer``).
 """
 
 from __future__ import annotations
@@ -134,6 +136,19 @@ def attend_blocks(q, k, v, idx, lanes, blocks, positions, n_kv: int, block: int)
     p = jnp.where(seen[:, :, None], p, 0.0)
     o = jnp.einsum("bkgr,bkrd->bkgd", p.astype(v.dtype), vv, preferred_element_type=jnp.float32)
     return (o / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)).reshape(b, h, hd)
+
+
+def blocks_step(kernel: bool):
+    """:func:`attend_blocks` as a plan names it, for lanes ``lane .. lane + b``
+    (``f(q, k, v, idx, lane, blocks, positions, n_kv, block)``): the Pallas
+    kernel that copies the listed blocks out of the arena where it lies
+    (``pallas_attention.sparse_decode``), or XLA's gather."""
+    if not kernel:
+        return lambda q, k, v, idx, lane, *rest: attend_blocks(q, k, v, idx, jnp.arange(q.shape[0]) + lane, *rest)
+    from .pallas_attention import sparse_decode
+
+    return lambda q, k, v, idx, lane, blocks, positions, n_kv, block: sparse_decode(
+        q, k, v, blocks, positions, idx, lane, block=block)
 
 
 def rows_by_blocks(blocks, positions, n_blocks: int, dense_len: int):

@@ -358,7 +358,7 @@ def test_the_plan_and_the_cache_name_both_kinds():
     assert plan.kinds() == {"lightning": ("xla_chunked", "xla_step"),
                             "sparse": ("xla:block_mask", "xla:attention_reference+xla:block_gather")}
     big = hybrid.plan_hybrid(get_config("minicpm-sala"), use_pallas=True)
-    assert big.sparse_decode == "pallas:flash_decode+xla:block_gather" and big.describe()["arena"] == "stack+layer"
+    assert big.sparse_decode == "pallas:flash_decode+pallas:sparse_decode" and big.describe()["arena"] == "stack+layer"
     cache = jax.eval_shape(lambda: init_cache(get_config("minicpm-sala"), 8, 49152))
     assert list(cache.leaves()) == ["k", "v", "ck", "state"] and cache.conv is None
     assert cache.k.shape == (8, 8, 49152, 2, 128) and cache.ck.shape == (8, 8, 3072, 2, 128)

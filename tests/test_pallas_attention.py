@@ -657,3 +657,146 @@ def test_ring_block_is_what_every_registered_configuration_had():
         if kernel_supported(cfg.n_heads, cfg.n_kv_heads, cfg.head_dim):
             now[name] = tuple(ring_block(cfg.n_kv_heads, cfg.head_dim, d) for d in (jnp.bfloat16, jnp.float32))
     assert now == had
+
+
+# -- sparse_decode: a lane's step over its listed key blocks --------------------
+
+SP_BLOCK, SP_TOPK, SP_ROWS = 64, 16, 2048  # two chunks of 8 places a list; 32 blocks a lane
+
+
+def _sparse_case(case: str):
+    """``(blocks [b, 2, topk], positions [b], layer, slot)`` of a named case:
+    lists as ``select_blocks`` would make them (block 0 and the blocks of the
+    last 512 rows forced, the same for both heads and first in the list) but
+    for what the case says."""
+    rng = np.random.default_rng(7)
+    layer, slot, positions = 1, 0, [1500, 1337]
+    n_blocks = SP_ROWS // SP_BLOCK
+
+    def lists(pos, free=(None, None), order=False):
+        cur = pos // SP_BLOCK
+        forced = [0] + list(range(cur - 7, cur + 1))
+        out = []
+        for h in range(2):
+            rest = [x for x in range(1, cur - 7)]
+            pick = list(rng.choice(rest, SP_TOPK - len(forced), replace=False)) if free[h] is None else list(free[h])
+            got = forced + pick
+            out.append(list(rng.permutation(got)) if order and h else got)
+        return out
+
+    if case == "short_context":  # fewer blocks than places: -1 fills the lists
+        positions = [700, 70]
+        blocks = [[list(range(p // SP_BLOCK + 1)) + [-1] * (SP_TOPK - p // SP_BLOCK - 1)] * 2 for p in positions]
+    elif case == "none_first":  # a first chunk with nothing in it, and a lane with nothing at all
+        blocks = [[[-1] * 8 + [0, 3, 5, 9, 20, 21, 22, 23], [-1] * 8 + [23, 22, 0, 1, -1, 2, -1, 4]], [[-1] * SP_TOPK] * 2]
+    elif case == "mid_block":
+        positions = [1500 - 1500 % SP_BLOCK + 31, 1337 - 1337 % SP_BLOCK + 1]
+        blocks = [lists(p) for p in positions]
+    elif case == "heads_equal":
+        blocks = [[lists(p)[0]] * 2 for p in positions]
+    elif case == "heads_disjoint":  # but for the forced blocks
+        blocks = [lists(p, free=(range(1, 8), range(8, 15))) for p in positions]
+    elif case == "heads_reordered":  # the same sets, head 1's in another order
+        blocks = [lists(p, free=(range(2, 9), range(2, 9)), order=True) for p in positions]
+    elif case == "slot2":
+        slot, blocks = 2, [lists(p) for p in positions]
+    elif case == "layer0":
+        layer, blocks = 0, [lists(p) for p in positions]
+    elif case == "layer_last":
+        layer, blocks = 2, [lists(p) for p in positions]
+    elif case == "arena_end":  # the last block of the arena, read to its last row and short of it
+        positions = [SP_ROWS - 1, SP_ROWS - 9]
+        blocks = [lists(p) for p in positions]
+        assert all(n_blocks - 1 in b[0] for b in blocks)
+    else:
+        raise AssertionError(case)
+    return jnp.asarray(blocks, jnp.int32), jnp.asarray(positions, jnp.int32), layer, slot
+
+
+def _sparse_arena(dtype, lanes=4, layers=3, kv=2, hd=32):
+    k = _rand(jax.random.PRNGKey(11), layers, lanes, SP_ROWS, kv, hd).astype(dtype)
+    v = _rand(jax.random.PRNGKey(12), layers, lanes, SP_ROWS, kv, hd).astype(dtype)
+    return k, v
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize(
+    "case",
+    ["short_context", "none_first", "mid_block", "heads_equal", "heads_disjoint", "heads_reordered", "slot2", "layer0",
+     "layer_last", "arena_end"],
+)
+def test_sparse_decode_is_attend_blocks(case, dtype):
+    """The kernel that copies a lane's listed blocks out of the stack against
+    ``sparse_attention.attend_blocks``, XLA's gather of the same rows: float32
+    arenas to rounding, bfloat16 arenas to the rounding of the probabilities
+    (the kernel rounds them against a chunk's running maximum)."""
+    from agentainer_tpu.ops.pallas_attention import sparse_decode
+    from agentainer_tpu.ops.sparse_attention import attend_blocks
+
+    blocks, positions, layer, slot = _sparse_case(case)
+    k, v = _sparse_arena(dtype)
+    q = _rand(jax.random.PRNGKey(13), 2, 6, k.shape[-1])  # 3 query heads a K/V head
+    want = attend_blocks(q, k, v, layer, jnp.arange(2) + slot, blocks, positions, 2, SP_BLOCK)
+    got = sparse_decode(q, k, v, blocks, positions, layer, slot, block=SP_BLOCK, interpret=True)
+    assert got.shape == want.shape == (2, 6, k.shape[-1]) and got.dtype == want.dtype == jnp.float32
+    tol = 2e-6 if dtype == jnp.float32 else 4e-3
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=tol, rtol=tol)
+    if case == "none_first":
+        assert not np.asarray(got[1]).any()  # nothing listed: zeros, as the reference gives
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+def test_sparse_decode_sees_no_row_it_was_not_given(dtype):
+    """A row of a listed block past the lane's position, and every row of a
+    block that is not listed (for the head that does not list it), may hold
+    anything: large values there leave the output as it was, to the bit."""
+    from agentainer_tpu.ops.pallas_attention import sparse_decode
+
+    blocks, positions, layer, slot = _sparse_case("heads_disjoint")
+    positions = positions - 20  # the current block is listed and ends 20 + rows past the position
+    k, v = _sparse_arena(dtype)
+    q = _rand(jax.random.PRNGKey(13), 2, 6, k.shape[-1])
+    before = sparse_decode(q, k, v, blocks, positions, layer, slot, block=SP_BLOCK, interpret=True)
+    row = np.arange(SP_ROWS)
+    unseen = np.ones((2, SP_ROWS, 2), bool)  # lane, row, head
+    for b in range(2):
+        for h in range(2):
+            listed = np.isin(row // SP_BLOCK, np.asarray(blocks[b, h]))
+            unseen[b, :, h] = ~(listed & (row <= int(positions[b])))
+    assert unseen[:, :, 0].sum() != unseen[:, :, 1].sum() or (unseen[:, :, 0] != unseen[:, :, 1]).any()
+    big = jnp.asarray(unseen)[..., None]
+    k2 = k.at[layer, slot:slot + 2].set(jnp.where(big, 3e4, k[layer, slot:slot + 2]).astype(dtype))
+    v2 = v.at[layer, slot:slot + 2].set(jnp.where(big, -3e4, v[layer, slot:slot + 2]).astype(dtype))
+    after = sparse_decode(q, k2, v2, blocks, positions, layer, slot, block=SP_BLOCK, interpret=True)
+    np.testing.assert_array_equal(np.asarray(after), np.asarray(before))
+    assert np.isfinite(np.asarray(after)).all()
+
+
+@pytest.mark.parametrize(
+    "kv, stored, hd, dtype, block, topk, per",
+    [
+        (2, 2, 128, jnp.bfloat16, 64, 64, 8),  # minicpm-sala as served: 512 rows a head, 2 MB of scratch
+        (2, 2, 128, jnp.float32, 64, 64, 8),
+        (2, 2, 32, jnp.float32, 64, 16, 8),  # the cases above: two chunks
+        (4, 4, 128, jnp.bfloat16, 64, 64, 4),  # more heads: the scratch's 4 MiB cut the chunk
+        (8, 8, 128, jnp.bfloat16, 64, 64, 1),
+        (2, 2, 128, jnp.bfloat16, 64, 12, 6),  # a divisor of the list's places
+        (2, 2, 128, jnp.bfloat16, 1024, 4, 1),  # a block longer than a chunk
+    ],
+)
+def test_sparse_chunk_follows_the_shapes(kv, stored, hd, dtype, block, topk, per):
+    from agentainer_tpu.ops.pallas_attention import _SPARSE_KV_VMEM, sparse_chunk
+
+    got = sparse_chunk(kv, stored, hd, dtype, block, topk)
+    assert got == per and topk % got == 0
+    if got > 1:
+        assert 4 * kv * got * block * stored * hd * jnp.dtype(dtype).itemsize <= _SPARSE_KV_VMEM
+
+
+def test_sparse_decode_refuses_an_arena_that_is_not_whole_blocks():
+    from agentainer_tpu.ops.pallas_attention import sparse_decode
+
+    k = jnp.zeros((1, 1, 96, 2, 32), jnp.float32)
+    with pytest.raises(ValueError, match="not whole key blocks"):
+        sparse_decode(jnp.zeros((1, 4, 32)), k, k, jnp.zeros((1, 2, 1), jnp.int32), jnp.zeros((1,), jnp.int32), 0,
+                      block=64, interpret=True)

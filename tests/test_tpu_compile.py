@@ -687,19 +687,22 @@ def test_minicpm_sala_step_fits_the_chip_and_keeps_every_leaf_in_place_on_v5e(v5
     state 0.40 GB) are donated in place and none is copied or relaid out, no
     weight stack is copied, and the step's live bytes fit a v5e's 15.75 GB and
     are what the configuration file states (``compiled_live_bytes``). A
-    lane's step under ``dense_len`` takes ``flash_decode`` at 32 / 2 heads;
-    what a sparse row does with its blocks and the lightning rule are XLA's."""
+    lane's step takes ``flash_decode`` at 32 / 2 heads under ``dense_len`` and
+    ``sparse_decode`` past it, which copies the listed blocks itself: no gather
+    of the 65,536 chosen rows is left; a chunk's mask and lightning are XLA's."""
     import json
 
     cfg, cache, plan, steps = _hybrid_case("sala-32l", SingleDeviceSharding(v5e.devices[0]))
-    assert plan.sparse_decode == "pallas:flash_decode+xla:block_gather" and plan.lightning_decode == "xla_step"
+    assert plan.sparse_decode == "pallas:flash_decode+pallas:sparse_decode" and plan.lightning_decode == "xla_step"
     assert cache.k.shape == (8, 8, 49_152, 2, 128) and cache.ck.shape == (8, 8, 3072, 2, 128)
     assert cache.state.shape == (24, 8, 32, 128, 128) and cache.conv is None and cache.latent is None
     fn, args = steps[step]
     compiled = fn.lower(*args).compile()
     text = compiled.as_text()
     calls = re.findall(r'%([a-z_]+)[.\d]* = [^\n]*custom_call_target="tpu_custom_call"', text)
-    assert sorted(calls) == {"decode": ["flash_decode"], "prefill": [], "mixed": ["flash_decode"]}[step], calls
+    lanes_step = ["flash_decode", "sparse_decode"]
+    assert sorted(calls) == {"decode": lanes_step, "prefill": [], "mixed": lanes_step}[step], calls
+    assert "bf16[65536,128]" not in text and not re.search(r"bf16\[8,2,4096,128\][^ ]* gather\(", text)
     mem = compiled.memory_analysis()
     leaves = dict(cache.leaves())
     assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize for a in leaves.values())

@@ -30,8 +30,9 @@ OLMoE (``num_experts``, ``norm_topk_prob``; QK-norm where its weights are):
     …mlp.experts.{e}.{gate,up,down}_proj → w_gate/w_up/w_down[i,e]ᵀ
     …self_attn.{q,k}_norm.weight         → q_norm/k_norm[i]     [H*hd]
 
-``model_type: mistral4`` (Mistral-Small-4): ``config_from_hf`` gives the hybrid
-block's configuration (MLA in every layer); ``load_hf_params`` refuses the
+``model_type: mistral4`` (Mistral-Small-4) and ``model_type: solar_open2``
+(Solar-Open2): ``config_from_hf`` gives the hybrid block's configuration (MLA
+in every layer; KDA beside gated NoPE GQA); ``load_hf_params`` refuses the
 hybrid block by name until a checkpoint is there to check tensor names against.
 """
 
@@ -93,6 +94,8 @@ def config_from_hf(path: str | Path) -> ModelConfig:
     doc = json.loads((path / "config.json").read_text())
     if doc.get("model_type") == "mistral4":
         return _mistral4_config(doc)
+    if doc.get("model_type") == "solar_open2":
+        return _solar_open2_config(doc)
     # Mixtral publishes ``num_local_experts``, OLMoE ``num_experts``,
     # SmallThinker ``moe_num_primary_experts``
     smallthinker = "moe_num_primary_experts" in doc
@@ -180,6 +183,50 @@ def _mistral4_config(doc: dict) -> ModelConfig:
         q_pos_scale_beta=float(rope.get("llama_4_scaling_beta", 0.0)),
         n_shared_experts=int(doc["n_shared_experts"]),
         moe_router="softmax",
+    )
+
+
+def _solar_open2_config(doc: dict) -> ModelConfig:
+    """``model_type: solar_open2`` (Solar-Open2): the hybrid block with KDA
+    beside gated NoPE GQA, from the keys the benchmark's
+    ``families/solar_open2.model_config`` reads. What the keys do not state
+    (the sigmoid router with a selection bias, the gate as wide as the output,
+    KDA's low-rank pairs as the Kimi Linear report has them) is the family's
+    convention, listed under ``assumed`` in the benchmark's configuration
+    file. The config only: the weights' names wait for a checkpoint's index."""
+    lin = doc["linear_attn_config"]
+    layers = int(doc["num_hidden_layers"])
+    gqa = {int(i) for i in doc["gqa_layers"]}
+    if doc.get("use_rope") or doc.get("kda_use_full_proj") or int(doc["first_k_dense_replace"]):
+        raise ValueError("solar_open2: served without rotary embedding, with KDA's low-rank pairs and no dense layer")
+    if lin.get("num_kv_heads") not in (None, lin["num_heads"]):
+        raise ValueError("solar_open2: KDA is served with as many key/value heads as query heads")
+    if not doc.get("norm_topk_prob", True):
+        raise ValueError("solar_open2: the sigmoid rule served renormalises the chosen experts")
+    return ModelConfig(
+        name="solar_open2-import",
+        vocab_size=int(doc["vocab_size"]),
+        dim=int(doc["hidden_size"]),
+        n_layers=layers,
+        n_heads=int(doc["num_attention_heads"]),
+        n_kv_heads=int(doc["num_key_value_heads"]),
+        head_size=int(doc["head_dim"]),
+        ffn_dim=int(doc["moe_intermediate_size"]),
+        max_seq_len=int(doc["max_position_embeddings"]),
+        rope_theta=0.0,  # ``use_rope: false``: the published base is unused
+        norm_eps=float(doc["rms_norm_eps"]),
+        n_experts=int(doc["n_routed_experts"]),
+        experts_per_token=int(doc["num_experts_per_tok"]),
+        moe_renormalize=True,
+        layer_kinds=tuple("full" if i in gqa else "kda" for i in range(layers)),
+        kda_heads=int(lin["num_heads"]),
+        kda_head_dim=int(lin["head_dim"]),
+        kda_conv=int(lin["short_conv_kernel_size"]),
+        delta_neg_eigval=bool(doc.get("kda_allow_neg_eigval", False)),
+        attn_gate="full" if doc.get("use_gqa_gate") else False,
+        n_shared_experts=int(doc["n_shared_experts"]),
+        moe_router="sigmoid",
+        moe_scale=float(doc.get("routed_scaling_factor", 1.0)),
     )
 
 

@@ -1504,6 +1504,11 @@ class LLMEngine:
                 window_decode_blocks_stored=0, window_wraps=0,
                 global_decode_rows=0, window_decode_rows=0,
             )
+        if self._hybrid and k is not None:
+            # the output gate's form ("none", "per_head" or "full": a sparse
+            # layer's is as wide as its output) and the K/V heads a row is
+            # STORED with (``hybrid.stored_kv_heads``: 32 for a model's 30)
+            self.attention.update(gate="full" if cfg.n_sparse else cfg.gate_form, kv_heads_stored=int(k.shape[3]))
         if self._windowed and self._hybrid:
             # what differs by kind of layer: query heads, the gate, the rotary
             from ..models.hybrid import attention_by_kind
@@ -1530,6 +1535,10 @@ class LLMEngine:
         if self._recurrent:
             self.linear = {
                 "kind": cfg.linear_kind, "layers": cfg.n_linear,
+                # the state's shape a lane and layer, and whether β reaches 2
+                # (the transition then has a negative eigenvalue)
+                "heads": cfg.kda_heads, "head_dim": cfg.kda_head_dim,
+                "neg_eigval": bool(cfg.delta_neg_eigval and cfg.linear_kind != "lightning"),
                 "state_bytes_lane": int(self.cache.state.nbytes // self.max_batch),
                 "conv": self.cache.conv is not None, "rows_chunked": 0, "steps": 0,
             }
@@ -1590,6 +1599,11 @@ class LLMEngine:
             # experts in this chip's stack (all of them unless it holds a
             # share), the shared experts every token also takes, the rule
             "experts_held": cfg.n_held if cfg.is_moe else 0,
+            # the chip's share in the deployment's words: experts ``offset ..
+            # offset + held`` of the ``published`` the router scores
+            "held": cfg.n_held if cfg.is_moe else 0,
+            "published": cfg.n_experts,
+            "offset": cfg.expert_offset if cfg.is_moe else 0,
             "shared_experts": cfg.n_shared_experts,
             "router": cfg.moe_router if cfg.is_moe else None,
             "top_k": cfg.experts_per_token if cfg.is_moe else 0,

@@ -12,7 +12,10 @@ is that block with latent attention in every layer and no linear mixer (a
 low-rank query, a YaRN rotary embedding on the shared key dims); Laguna-XS.2
 is that block with full attention beside sliding-window attention (two head
 counts, two rotary embeddings, a gate a head; the window layers' rows a
-ring). Tiny variants exist for CI and the virtual CPU mesh —
+ring); MiniCPM-SALA is block-sparse attention beside lightning attention;
+Solar-Open2-250B is KDA with negative eigenvalues beside NoPE GQA with a
+full-width gate, every layer sigmoid-routed experts. Tiny variants exist for
+CI and the virtual CPU mesh —
 same code path, small shapes.
 
 All dims are chosen TPU-aware: head_dim and hidden sizes are multiples of
@@ -127,9 +130,11 @@ class ModelConfig:
     # ``rope_factor`` > 1), cos and sin both times ``rope_attention_factor``
     rope_partial: float = 1.0
     rope_attention_factor: float = 1.0
-    # every attention head's output times a sigmoid of its own, a linear
-    # function of the layer's normed input (``wg [d, heads]``)
-    attn_gate: bool = False
+    # attention's output times a sigmoid of a linear function of the layer's
+    # normed input. ``True`` or "per_head": one gate a head (``wg [d, heads]``:
+    # Laguna); "full": one a channel, as wide as the output (``wg [d, heads ·
+    # head_dim]``: Solar-Open2's ``use_gqa_gate``). Read ``gate_form``
+    attn_gate: bool | str = False
     # -- "sparse" beside "lightning" (MiniCPM-SALA); 0: the model has no such layer --
     # a pooled key is the mean of ``sparse_kernel`` consecutive keys, one
     # every ``sparse_stride``; a key block is ``sparse_block`` rows; a query
@@ -200,6 +205,8 @@ class ModelConfig:
             raise ValueError("window_layers names window layers and window is 0")
         if self.ffn_act not in ("silu", "relu"):
             raise ValueError(f"ffn_act {self.ffn_act!r}: silu or relu")
+        if self.attn_gate not in (False, True, "per_head", "full"):
+            raise ValueError(f'attn_gate {self.attn_gate!r}: False, True ("per_head") or "full"')
         if (self.rope_factor != 1.0 or self.q_pos_scale_beta) and self.rope_original_max <= 0:
             raise ValueError("rope_factor and q_pos_scale_beta are read against rope_original_max, which is 0")
         if self.mla_rotary and (self.mla_rope_dim % 2 or not self.rope_theta):
@@ -237,6 +244,16 @@ class ModelConfig:
     def heads_of(self, kind: str) -> int:
         """Query heads of a layer of ``kind``."""
         return self.window_heads if kind == WINDOW_KIND else self.n_heads
+
+    @property
+    def gate_form(self) -> str:
+        """The output gate of the "full" and "swa" layers: "none", "per_head"
+        or "full" (``attn_gate``)."""
+        return {False: "none", True: "per_head"}.get(self.attn_gate, self.attn_gate)
+
+    def gate_width(self, heads: int) -> int:
+        """Columns of such a layer's ``wg`` (0: no gate)."""
+        return {"none": 0, "per_head": heads, "full": heads * self.head_dim}[self.gate_form]
 
     @property
     def rotary_dim(self) -> int:
@@ -340,8 +357,8 @@ class ModelConfig:
         )
         hd = self.head_dim
 
-        def attention(heads: int) -> int:  # q, o, k, v, the gate a head, the two norms
-            n = 2 * d * heads * hd + 2 * d * self.n_kv_heads * hd + (d * heads if self.attn_gate else 0)
+        def attention(heads: int) -> int:  # q, o, k, v, the gate (a head or full width), the two norms
+            n = 2 * d * heads * hd + 2 * d * self.n_kv_heads * hd + d * self.gate_width(heads)
             return n + ((heads + self.n_kv_heads) * hd if self.qk_norm else 0)
 
         # q, o, the full-width gate, k, v, and a norm vector a head for q and k
@@ -956,6 +973,83 @@ TINY_MINICPM_SALA = register(
         logit_divisor=2.0,
         n_dense_layers=8,
         dense_ffn_dim=128,
+    )
+)
+
+def solar_open2_kinds(n_layers: int, period: int = 4) -> tuple[str, ...]:
+    """Solar-Open2's published ``gqa_layers`` (0-indexed: 0, 4, 8, …): every
+    ``period``-th layer from layer 0 is softmax GQA, the rest KDA — G K K K."""
+    return tuple("full" if i % period == 0 else "kda" for i in range(n_layers))
+
+
+# Solar-Open2-250B (upstage/Solar-Open2-250B config.json, ``model_type:
+# solar_open2``: 48 layers, hidden 4096; ``gqa_layers`` 0, 4, …, 44 are softmax
+# attention with 64 query / 8 K/V heads of 128, no rotary embedding
+# (``use_rope: false``), no QK-norm, and a sigmoid gate as wide as the output
+# (``use_gqa_gate``); the other 36 are KDA, 64 heads of 128 with a conv of 4,
+# low-rank decay and gate pairs (``kda_use_full_proj: false``) and β = 2 ·
+# sigmoid (``kda_allow_neg_eigval``); every layer a MoE (``first_k_dense_
+# replace`` 0: the published ``intermediate_size`` 10240 is used by none) of
+# 320 experts of 1280, top-8 behind a sigmoid router with a selection bias,
+# renormalised x 1, one shared expert; vocabulary 196,608, untied). All 320
+# experts: 250.29 B parameters, 14.7 B active a token — a chip serves its share
+# (``experts_held``; benchmark/configs).
+SOLAR_OPEN2 = register(
+    ModelConfig(
+        name="solar-open2",
+        vocab_size=196_608,
+        dim=4096,
+        n_layers=48,
+        n_heads=64,
+        n_kv_heads=8,
+        head_size=128,
+        ffn_dim=1280,
+        max_seq_len=1_048_576,
+        rope_theta=0.0,  # ``use_rope: false`` (the published 10000 is unused)
+        norm_eps=1e-5,
+        n_experts=320,
+        experts_per_token=8,
+        moe_renormalize=True,
+        layer_kinds=solar_open2_kinds(48),
+        kda_heads=64,
+        kda_head_dim=128,
+        kda_conv=4,
+        delta_neg_eigval=True,
+        attn_gate="full",
+        n_shared_experts=1,
+        moe_router="sigmoid",
+        moe_scale=1.0,
+    )
+)
+
+# The same block at CI shapes: two periods and a layer (G K K K G K K K G:
+# each kind after the other, both ways), 8 query heads over 2 K/V heads of 16
+# (a group of 4), 4 KDA heads of 16, 8 experts top-2 beside a shared one.
+TINY_SOLAR_OPEN2 = register(
+    ModelConfig(
+        name="tiny-solar-open2",
+        vocab_size=512,
+        dim=64,
+        n_layers=9,
+        n_heads=8,
+        n_kv_heads=2,
+        head_size=16,
+        ffn_dim=32,
+        max_seq_len=256,
+        rope_theta=0.0,
+        norm_eps=1e-5,
+        n_experts=8,
+        experts_per_token=2,
+        moe_renormalize=True,
+        layer_kinds=solar_open2_kinds(9),
+        kda_heads=4,
+        kda_head_dim=16,
+        kda_conv=4,
+        delta_neg_eigval=True,
+        attn_gate="full",
+        n_shared_experts=1,
+        moe_router="sigmoid",
+        moe_scale=1.0,
     )
 )
 

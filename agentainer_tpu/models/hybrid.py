@@ -1,7 +1,7 @@
 """The hybrid block (Kimi-Linear, Olmo-Hybrid, Mistral-Small-4, Laguna,
-MiniCPM-SALA): a linear mixer beside a positional one, a positional one alone,
-or full attention beside sliding-window attention, and two FFNs, through the
-one forward.
+MiniCPM-SALA, Solar-Open2): a linear mixer beside a positional one, a
+positional one alone, or full attention beside sliding-window attention, and
+two FFNs, through the one forward.
 
 ``models/llama.forward`` hands a config with ``layer_kinds`` to
 :func:`forward` here; the engine calls one ``forward`` and never learns a
@@ -28,7 +28,11 @@ with
   narrower than values, so a rectangular state, and a full-rank SiLU output
   gate) or **full** (softmax attention over K/V rows read by the dense flash
   kernels, QK-norm over the whole projections, no rotary embedding where the
-  model has none). A model has one linear kind and one positional kind;
+  model has none). A model has one linear kind and one positional kind, and
+  any linear kind may stand beside any positional one: Solar-Open2 is **KDA**
+  (``β = 2 · sigmoid`` under ``cfg.delta_neg_eigval``, as GDN) beside **full**
+  (64 query heads over 8 K/V heads, no rotary embedding, no QK-norm, the
+  output gated by a sigmoid as wide as itself: ``cfg.attn_gate == "full"``);
 - or the mixer **lightning** (``ops/lightning.py``: linear attention with a
   constant decay a head, no conv, no β, no erase term; a norm a head on q and
   k, rotate-half RoPE, one RMSNorm over the concatenated output and a
@@ -308,15 +312,15 @@ def restore_lane(cache: HybridCache, lane, leaves: dict) -> HybridCache:
 
 
 class HybridPlan(NamedTuple):
-    """Which implementation each mechanism's calls trace, chosen once (an
-    engine chooses at build and reports it)."""
+    """Which implementation each kind of layer's calls trace, chosen once
+    and a kind at a time (:func:`plan_hybrid`; an engine chooses at build and
+    reports it). "": the model has no such layer."""
 
-    kda_decode: str
-    kda_prefill: str
-    mla_decode: str
-    mla_prefill: str
-    reason: str
-    # the other family's kinds ("": the model has no such layer)
+    kda_decode: str = ""
+    kda_prefill: str = ""
+    mla_decode: str = ""
+    mla_prefill: str = ""
+    reason: str = ""
     gdn_decode: str = ""
     gdn_prefill: str = ""
     full_decode: str = ""
@@ -352,26 +356,24 @@ class HybridPlan(NamedTuple):
 
 
 def plan_hybrid(cfg: ModelConfig, use_pallas: bool | None = None) -> HybridPlan:
+    """The plan, chosen A KIND AT A TIME: each kind of layer the model has
+    answers for itself (:data:`_KIND_PLANS`: its prefill and decode forms, and
+    why it takes no kernel where it takes none), so any pair the block can
+    hold gets a kernel and a rule for both of its kinds (KDA beside "full":
+    Solar-Open2)."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
-    if cfg.linear_kind == "lightning" or cfg.positional_kind == "sparse":
-        return _plan_lightning_sparse(cfg, use_pallas)
-    if cfg.linear_kind == "gdn" or cfg.positional_kind == "full":
-        return _plan_gdn_full(cfg, use_pallas)
-    if cfg.linear_kind is None:
-        # latent attention alone: no state kernel to choose, and the latent
-        # kernels take any width that is whole lane tiles (``latent_width``)
-        mla = ("pallas_mla_decode", "pallas_mla_prefill") if use_pallas else ("xla_absorbed",) * 2
-        why = "tpu backend; the latent stack read where it lies" if use_pallas else "no tpu backend"
-        return HybridPlan("", "", *mla, why, mla_rotary=rotary_kind(cfg))
-    aligned = cfg.kda_head_dim % 128 == 0 and cfg.kda_heads % 8 == 0
-    if use_pallas and aligned:
-        return HybridPlan(
-            "pallas_kda_decode", "xla_chunked", "pallas_mla_decode", "pallas_mla_prefill",
-            "tpu backend; state and latent stacks read where they lie", mla_rotary=rotary_kind(cfg),
-        )
-    why = "no tpu backend" if not use_pallas else "KDA heads not (8, 128)-aligned"
-    return HybridPlan("xla_step", "xla_chunked", "xla_absorbed", "xla_absorbed", why, mla_rotary=rotary_kind(cfg))
+    forms, missed = {}, []
+    for kind in (cfg.linear_kind, cfg.positional_kind, WINDOW_KIND if cfg.n_window else None):
+        if kind is None:
+            continue
+        prefill, decode, miss = _KIND_PLANS[kind](cfg, use_pallas)
+        forms.update({kind + "_prefill": prefill, kind + "_decode": decode})
+        if miss and miss not in missed:  # "full" and "swa" give one answer
+            missed.append(miss)
+    # a kind's ``miss`` says why a TPU's kernel was not taken: off a TPU there is one reason for all
+    why = "no tpu backend" if not use_pallas else "; ".join(missed) or f"tpu backend; {_read_in_place(cfg)}"
+    return HybridPlan(**forms, reason=why, mla_rotary=rotary_kind(cfg) if cfg.n_mla else "")
 
 
 def rotary_kind(cfg: ModelConfig) -> str:
@@ -382,64 +384,86 @@ def rotary_kind(cfg: ModelConfig) -> str:
     return f"{freqs}, theta {cfg.rope_theta:g}, {'adjacent pairs' if cfg.rope_interleave else 'split halves'}"
 
 
-def _plan_gdn_full(cfg: ModelConfig, use_pallas: bool) -> HybridPlan:
-    """GDN beside full attention: the state kernel where a lane's ``[dk,
-    H·dv]`` tile is whole (8, 128) tiles and the heads pack into lane-aligned
-    windows; the dense flash kernels where they take the STORED head count."""
-    from ..ops.pallas_attention import kernel_supported
+def _read_in_place(cfg: ModelConfig) -> str:
+    """What a plan whose every kind took its kernels says of the model's leaves."""
+    if cfg.n_sparse or cfg.linear_kind == "lightning":
+        return "a lane's K/V rows read where they lie, a dense row's and a sparse row's listed blocks"
+    if cfg.linear_kind is None and cfg.positional_kind == "mla":
+        return "the latent stack read where it lies"
+    return f"state and {'latent' if cfg.positional_kind == 'mla' else 'K/V'} stacks read where they lie"
+
+
+def _kda_aligned(cfg: ModelConfig) -> bool:
+    return cfg.kda_head_dim % 128 == 0 and cfg.kda_heads % 8 == 0
+
+
+def _plan_kda(cfg: ModelConfig, use_pallas: bool):
+    """The state kernel where a head's ``[dk, dv]`` tile is whole (8, 128)
+    tiles in blocks of 8 heads; the chunked rule is XLA's on every backend."""
+    if use_pallas and _kda_aligned(cfg):
+        return "xla_chunked", "pallas_kda_decode", ""
+    return "xla_chunked", "xla_step", "KDA heads not (8, 128)-aligned"
+
+
+def _plan_gdn(cfg: ModelConfig, use_pallas: bool):
+    """The state kernel where a lane's ``[dk, H·dv]`` tile is whole (8, 128)
+    tiles and the heads pack into lane-aligned windows."""
     from ..ops.pallas_kda import gdn_supported
 
-    why = []
     h, dk, dv = cfg.kda_heads, cfg.kda_head_dim, cfg.delta_v_dim
-    gdn = "xla_step"
-    if not use_pallas:
-        why.append("no tpu backend")
-    elif not gdn_supported(h, dk, dv):
-        why.append(f"GDN state tile [{dk}, {h}x{dv}] is not whole (8, 128) tiles")
-    else:
-        gdn = "pallas_gdn_decode"
-    full = ("xla:attention_reference",) * 2
-    if use_pallas:
-        kv = stored_kv_heads(cfg.n_kv_heads)
-        counts = {cfg.n_heads, cfg.window_heads} if cfg.n_window else {cfg.n_heads}
-        if all(kernel_supported(kv * (n // cfg.n_kv_heads), kv, cfg.head_dim) for n in counts):
-            full = ("pallas:flash_prefill", "pallas:flash_decode")
-        else:
-            why.append(f"heads {sorted(counts)}/{kv} stored x {cfg.head_dim}: not the flash kernels' shapes")
-    if not why:
-        why.append("tpu backend; state and K/V stacks read where they lie")
-    return HybridPlan(
-        "", "", "", "", "; ".join(why),
-        gdn_decode=gdn if cfg.linear_kind else "", gdn_prefill="xla_chunked" if cfg.linear_kind else "",
-        full_decode=full[1] if cfg.positional_kind else "", full_prefill=full[0] if cfg.positional_kind else "",
-        swa_decode=full[1] if cfg.n_window else "", swa_prefill=full[0] if cfg.n_window else "",
-    )
+    if use_pallas and gdn_supported(h, dk, dv):
+        return "xla_chunked", "pallas_gdn_decode", ""
+    return "xla_chunked", "xla_step", f"GDN state tile [{dk}, {h}x{dv}] is not whole (8, 128) tiles"
 
 
-def _plan_lightning_sparse(cfg: ModelConfig, use_pallas: bool) -> HybridPlan:
-    """Lightning beside block-sparse attention. The state's rule and a chunk's
-    sparse rows (a row-by-block mask over runs of the lane's rows) are XLA's on
-    every backend. A lane's step takes two kernels where they take the head
-    counts: the dense flash kernel under ``cfg.sparse_dense_len`` and, past
-    it, ``sparse_decode``, which copies the listed blocks from the arena
-    where it lies; elsewhere XLA gathers them."""
+def _plan_lightning(cfg: ModelConfig, use_pallas: bool):
+    """The state's rule is XLA's on every backend."""
+    return "xla_chunked", "xla_step", ""
+
+
+def _plan_mla(cfg: ModelConfig, use_pallas: bool):
+    """The latent kernels take any width that is whole lane tiles
+    (``latent_width``). Beside KDA layers whose heads miss the state kernel's
+    tiles the latent rows stay XLA's too (a tiny preset on a TPU: the answer
+    it had when the plan was chosen by pair)."""
+    if use_pallas and (not cfg.n_kda or _kda_aligned(cfg)):
+        return "pallas_mla_prefill", "pallas_mla_decode", ""
+    return "xla_absorbed", "xla_absorbed", ""
+
+
+def _plan_full(cfg: ModelConfig, use_pallas: bool):
+    """The dense flash kernels where they take the STORED head count, of the
+    "full" layers and of the "swa" layers beside them (the same kernels,
+    ``window=``): one answer for both kinds."""
     from ..ops.pallas_attention import kernel_supported
 
-    why, dense, blocks = [], "xla:attention_reference", "xla:block_gather"
-    if not use_pallas:
-        why.append("no tpu backend")
-    elif kernel_supported(cfg.n_heads, stored_kv_heads(cfg.n_kv_heads), cfg.head_dim):
-        dense, blocks = "pallas:flash_decode", "pallas:sparse_decode"
-        why.append("tpu backend; a lane's K/V rows read where they lie, a dense row's and a sparse row's listed blocks")
-    else:
-        why.append(f"heads {cfg.n_heads}/{cfg.n_kv_heads} x {cfg.head_dim}: not the flash kernels' shapes")
-    sparse, lightning = bool(cfg.n_sparse), cfg.linear_kind == "lightning"
-    return HybridPlan(
-        "", "", "", "", "; ".join(why),
-        sparse_decode=f"{dense}+{blocks}" if sparse else "",
-        sparse_prefill="xla:block_mask" if sparse else "",
-        lightning_decode="xla_step" if lightning else "", lightning_prefill="xla_chunked" if lightning else "",
-    )
+    kv = stored_kv_heads(cfg.n_kv_heads)
+    counts = {cfg.n_heads, cfg.window_heads} if cfg.n_window else {cfg.n_heads}
+    if use_pallas and all(kernel_supported(kv * (n // cfg.n_kv_heads), kv, cfg.head_dim) for n in counts):
+        return "pallas:flash_prefill", "pallas:flash_decode", ""
+    miss = f"heads {sorted(counts)}/{kv} stored x {cfg.head_dim}: not the flash kernels' shapes"
+    return "xla:attention_reference", "xla:attention_reference", miss
+
+
+def _plan_sparse(cfg: ModelConfig, use_pallas: bool):
+    """A chunk's sparse rows (a row-by-block mask over runs of the lane's
+    rows) are XLA's on every backend. A lane's step takes two kernels where
+    they take the head counts: the dense flash kernel under
+    ``cfg.sparse_dense_len`` and, past it, ``sparse_decode``, which copies the
+    listed blocks from the arena where it lies; elsewhere XLA gathers them."""
+    from ..ops.pallas_attention import kernel_supported
+
+    if use_pallas and kernel_supported(cfg.n_heads, stored_kv_heads(cfg.n_kv_heads), cfg.head_dim):
+        return "xla:block_mask", "pallas:flash_decode+pallas:sparse_decode", ""
+    miss = f"heads {cfg.n_heads}/{cfg.n_kv_heads} x {cfg.head_dim}: not the flash kernels' shapes"
+    return "xla:block_mask", "xla:attention_reference+xla:block_gather", miss
+
+
+# kind -> (cfg, use_pallas) -> (prefill, decode, why a TPU's kernel is not taken or "")
+_KIND_PLANS = {
+    "kda": _plan_kda, "gdn": _plan_gdn, "lightning": _plan_lightning,
+    "mla": _plan_mla, "full": _plan_full, "sparse": _plan_sparse, WINDOW_KIND: _plan_full,
+}
 
 
 def attention_by_kind(cfg: ModelConfig) -> dict:
@@ -454,7 +478,7 @@ def attention_by_kind(cfg: ModelConfig) -> dict:
     swa = f"rope, theta {cfg.swa_rope_theta:g}, all {cfg.head_dim} dims" if cfg.swa_rope_theta else "none"
     return {
         "heads": {"full": cfg.n_heads, WINDOW_KIND: cfg.window_heads},
-        "gate": "per_head" if cfg.attn_gate else "none",
+        "gate": cfg.gate_form,
         "rotary": {"full": full, WINDOW_KIND: swa},
     }
 
@@ -481,8 +505,8 @@ def param_shapes(cfg: ModelConfig) -> dict:
             "wv": ((n, d, cfg.n_kv_heads * hd), True),
             "wo": ((n, heads * hd, d), True),
         }
-        if cfg.attn_gate:
-            out["wg"] = ((n, d, heads), True)
+        if cfg.attn_gate:  # a gate a head, or as wide as the output
+            out["wg"] = ((n, d, cfg.gate_width(heads)), True)
         if cfg.qk_norm:
             out.update(q_norm=((n, heads * hd), False), k_norm=((n, cfg.n_kv_heads * hd), False))
         return out
@@ -623,8 +647,25 @@ def embed_rms(cfg: ModelConfig) -> float | None:
     not, every sublayer is a product of small numbers, and a rounding of its
     input is doubled by each (the reference with bfloat16 matmul inputs read
     1.84 % at 4 layers that way, my chip run, PR 32). A served model's stream
-    is of order one from its first layer."""
-    return 1.0 if cfg.post_norm else None
+    is of order one from its first layer.
+
+    1 also where the FIRST mixer is softmax attention with no positional
+    embedding ("full" first, ``rope_theta`` 0: Solar-Open2's layer 0). Under
+    random weights such a layer's late rows all come out as nearly the same
+    average of the values, three times the 0.02-scale embedding beside it; the
+    first FFN reads that sum, and after one layer every late row of the stream
+    is nearly ONE vector: the token is lost. The linear layers that follow
+    then step their state by near-identical keys, and a rounding of that
+    shared vector is the same error in every row, which a state's memory adds
+    up coherently while the tokens' own signal adds like a random walk: the
+    reference with bfloat16 matmul inputs itself read 2.2-5.9 % at 5 layers (G
+    K K K G, the error 0.5 % after the attention layer and 1.8 % after the
+    first KDA layer: my chip run and CPU readings at published widths, PR 57),
+    and 0.36 % with rows of RMS 1, which keep the token the larger part of
+    the stream, as a trained embedding does."""
+    if cfg.post_norm or (cfg.layer_kinds[:1] == ("full",) and not cfg.rope_theta):
+        return 1.0
+    return None
 
 
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> dict:
@@ -819,6 +860,8 @@ def kda_mixer(h, lp, cfg: ModelConfig, state, conv, idx, slot, valid, plan: Hybr
     decay_in = _proj(_proj(h, lp["w_fa"]).astype(h.dtype), lp["w_fb"]) + lp["dt_bias"].astype(jnp.float32)
     g = -jnp.exp(lp["a_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(decay_in).reshape(b, t, nh, dk)
     beta = jax.nn.sigmoid(_proj(h, lp["w_beta"]))
+    if cfg.delta_neg_eigval:  # β ∈ (0, 2): the transition I − β k kᵀ has an eigenvalue in (−1, 1)
+        beta = beta * 2.0
     g, beta = kda_ops.mask_inputs(g, beta, valid)
 
     def rule(q, k, v, g, beta, slot, state):
@@ -826,13 +869,15 @@ def kda_mixer(h, lp, cfg: ModelConfig, state, conv, idx, slot, valid, plan: Hybr
         if t == 1 and plan.kda_decode == "pallas_kda_decode" and slot is None:
             from ..ops.pallas_kda import kda_decode
 
-            o, state = kda_decode(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state, idx)
+            with jax.named_scope("kda_step"):
+                o, state = kda_decode(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state, idx)
             return o[:, None], state
         rows = _rows(state, idx, slot, b)
         if t == 1:
-            o, rows = kda_ops.kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], rows)
+            with jax.named_scope("kda_step"):
+                o, rows = kda_ops.kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], rows)
             o = o[:, None]
-        else:
+        else:  # its two halves are scoped ``kda_prepass`` and ``kda_scan``
             o, rows = kda_ops.kda_chunked(q, k, v, g, beta, rows)
         return o, _put_rows(state, rows, idx, slot)
 
@@ -912,6 +957,20 @@ def _attn_rotate(x, positions, cfg: ModelConfig, kind: str):
         )
 
 
+def _gated(o, h, wg, per_head: bool):
+    """Attention's output ``o [B, T, H, hd]`` times a sigmoid of a linear
+    function of the layer's normed input ``h``, as ``[B, T, H·hd]`` float32:
+    ``wg [d, H]`` gates a head (``per_head``: Laguna), ``[d, H·hd]`` every
+    channel of the output (the sparse layers; a "full" layer under
+    ``attn_gate="full"``)."""
+    b, t, nh, hd = o.shape
+    with jax.named_scope("attn_gate"):
+        o = o.astype(jnp.float32)
+        if per_head:
+            return (o * jax.nn.sigmoid(_proj(h, wg))[..., None]).reshape(b, t, nh * hd)
+        return o.reshape(b, t, nh * hd) * jax.nn.sigmoid(_proj(h, wg))
+
+
 def full_mixer(
     h, lp, cfg: ModelConfig, ck, cv, idx, slot, positions, valid, plan: HybridPlan, n_lanes: int = 0,
     kind: str = "full", arena_len: int | None = None,
@@ -927,7 +986,8 @@ def full_mixer(
     where parked lanes sit and whose writes a ring drops) and a row sees its
     last ``cfg.window`` positions (the kernels' and the reference's
     ``window=``). The kind's own head count, rotary embedding and, under
-    ``cfg.attn_gate``, a sigmoid gate a head on the output.
+    ``cfg.attn_gate``, a sigmoid gate on the output, a head or as wide as it
+    (:func:`_gated`).
 
     Every model that has this mixer runs it as the body of its kind's
     0-or-1-trip loop (:func:`forward`'s ``of_kind``), where the projections
@@ -967,10 +1027,8 @@ def full_mixer(
         ck = ck.at[idx, lanes, at(positions)].set(heads(k, stored).astype(ck.dtype))
         cv = cv.at[idx, lanes, at(positions)].set(heads(v, stored).astype(cv.dtype))
     o = _by_group(attend, n_lanes, q, positions, valid, slot)
-    if cfg.attn_gate:
-        with jax.named_scope("attn_gate"):
-            o = o.astype(jnp.float32) * jax.nn.sigmoid(_proj(h, lp["wg"]))[..., None]
-    return _proj(o.reshape(b, t, nh * hd).astype(h.dtype), lp["wo"]), ck, cv
+    o = _gated(o, h, lp["wg"], cfg.gate_form == "per_head") if cfg.attn_gate else o.reshape(b, t, nh * hd)
+    return _proj(o.astype(h.dtype), lp["wo"]), ck, cv
 
 
 def sparse_mixer(
@@ -1039,9 +1097,7 @@ def sparse_mixer(
             return jnp.where(under[:, None, None, None], o_dense.astype(jnp.float32), o_blocks[:, None])
 
     o = _by_group(attend, n_lanes, q, positions, valid, slot)
-    with jax.named_scope("attn_gate"):
-        o = o.astype(jnp.float32).reshape(b, t, nh * hd) * jax.nn.sigmoid(_proj(h, lp["wg"]))
-    return _proj(o.astype(h.dtype), lp["wo"]), ck, cv, pooled
+    return _proj(_gated(o, h, lp["wg"], per_head=False).astype(h.dtype), lp["wo"]), ck, cv, pooled
 
 
 def lightning_mixer(h, lp, cfg: ModelConfig, state, idx, slot, positions, valid, plan: HybridPlan, n_lanes: int = 0):

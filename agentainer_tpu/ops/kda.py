@@ -236,8 +236,10 @@ def kda_chunked(q, k, v, g, beta, state, chunk: int = CHUNK):
         o = _mm("bhtk,bhkv->bhtv", q_gamma, s) + _mm("bhts,bhsv->bhtv", scores_q, u)
         return s * gamma_end + _mm("bhsk,bhsv->bhkv", k_to_end, u), o
 
-    xs = _before_the_state(lay(q), lay(k), lay(v), lay(g), lay(beta[..., None])[..., 0])
-    state, o = lax.scan(step, state.astype(f32), xs)  # o [n, B, H, C, dv]
+    with jax.named_scope("kda_prepass"):
+        xs = _before_the_state(lay(q), lay(k), lay(v), lay(g), lay(beta[..., None])[..., 0])
+    with jax.named_scope("kda_scan"):
+        state, o = lax.scan(step, state.astype(f32), xs)  # o [n, B, H, C, dv]
     o = jnp.moveaxis(jnp.moveaxis(o, 0, 1), 3, 2).reshape(b, n * chunk, h, -1)
     return o[:, :t], state
 

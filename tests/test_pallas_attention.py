@@ -646,10 +646,12 @@ def test_ring_block_is_what_every_registered_configuration_had():
     from agentainer_tpu.ops.pallas_attention import kernel_supported, ring_block
 
     had = {  # (bfloat16, float32) at c5c67e8; ``laguna-xs.2`` came with PR 50 (8 K/V heads of 128, as Llama's)
-        # and ``minicpm-sala`` with PR 54 (2 K/V heads of 128; it has no ring: the dense kernels' shapes alone)
+        # and ``minicpm-sala`` with PR 54 (2 K/V heads of 128; it has no ring: the dense kernels' shapes alone),
+        # ``solar-open2`` with PR 57 (8 K/V heads of 128 under 64 query heads; no ring either)
         "bench-1b": (512, 512), "laguna-xs.2": (512, 512), "llama3-8b": (512, 512), "minicpm-sala": (512, 512),
         "mistral-small-4-119b": (512, 256),
         "mixtral-8x7b": (512, 512), "olmoe-1b-7b": (512, 256), "smallthinker-21b": (512, 512),
+        "solar-open2": (512, 512),
     }
     now = {}
     for name in list_configs():

@@ -94,6 +94,9 @@ ASSIGNED = {
     # and with chosen key blocks beside a conv-less linear state (PR 54)
     "minicpm-sala": [(1, 1), (1, 1), (1, 1), (1, 1)],
     "tiny-minicpm-sala": [(1, 1), (1, 1), (1, 1), (1, 1)],
+    # and with K/V rows beside a KDA state, the plan chosen a kind at a time (PR 57)
+    "solar-open2": [(1, 1), (1, 1), (1, 1), (1, 1)],
+    "tiny-solar-open2": [(1, 1), (1, 1), (1, 1), (1, 1)],
 }
 
 

@@ -622,6 +622,7 @@ def test_moe_block_names_no_path_for_a_dense_model(served):
         assert doc["moe"] == {
             "impl": "none", "experts": 0, "top_k": 0, "renormalize": False,
             "experts_held": 0, "shared_experts": 0, "router": None,
+            "held": 0, "published": 0, "offset": 0,
             "prefill_impl": "none", "routed_from_rows": None,
             "assignments": 0, "rows_all_experts": 0, "rows_routed": 0, "rows_gathered_in_kernel": 0,
         }
@@ -649,6 +650,8 @@ def test_moe_block_names_the_path_the_steps_trace(config, options, impl, prefill
         "impl": impl, "experts": cfg.n_experts, "top_k": cfg.experts_per_token, "renormalize": cfg.moe_renormalize,
         # every expert is in the stack, none is shared, the router is a softmax (ISSUE 30's three keys)
         "experts_held": cfg.n_experts, "shared_experts": 0, "router": "softmax",
+        # the share in the deployment's words: experts offset .. offset + held of the published (PR 57)
+        "held": cfg.n_experts, "published": cfg.n_experts, "offset": 0,
         "prefill_impl": prefill_impl, "routed_from_rows": cut,
         "assignments": 0, "rows_all_experts": 0, "rows_routed": 0, "rows_gathered_in_kernel": 0,
     }
@@ -838,6 +841,91 @@ def test_a_minicpm_sala_engine_names_both_kinds_the_selection_the_state_and_the_
         assert scope in decode, scope
     ledger = m["launches"]
     assert any(name.startswith("jit_prefill") for name in ledger) and any(name.startswith("jit_decode_n") for name in ledger)
+
+
+def test_a_solar_open2_engine_names_the_state_the_gate_the_share_and_every_leafs_bytes():
+    """Names the benchmark's readers and a reader of a capture rely on (ISSUE
+    57): ``model_arch.layer_kinds`` with ``kda`` and ``full``;
+    ``attention.{kda,full}_{prefill,decode}``, ``attention.gate`` ("full": as
+    wide as the output) and the K/V heads a row is stored with; the ``linear``
+    block's ``heads``, ``head_dim``, ``neg_eigval`` beside ``state_bytes_lane``
+    and the counters; the ``moe`` block's ``held`` / ``published`` / ``offset``
+    for a chip that holds a share; ``cache`` bytes of each of the four leaf
+    kinds; ``engine.snapshot`` / ``engine.restore`` spans carrying ``bytes=`` by
+    leaf; the ``jax.named_scope``s of the rule's calls and the gate in the
+    steps' metadata; and the launch ledger counting the step programs."""
+    import dataclasses
+
+    from agentainer_tpu.models import configs
+
+    share = dataclasses.replace(configs.get_config("tiny-solar-open2"), name="tiny-solar-open2-share", experts_held=2, expert_offset=4)
+    options = {"max_batch": 2, "max_seq": 256, "prefill_chunk": 32, "decode_chunk": 4}
+    configs.register(share)
+    try:
+        eng = LLMEngine.create(share.name, options=options)
+    finally:
+        configs._REGISTRY.pop(share.name)  # the tables of other test files list the registry whole
+    seen = []
+    span = eng._spans.span
+    eng._spans.span = lambda name, **attrs: (seen.append((name, attrs)), span(name, **attrs))[1]
+    try:
+        async def drive():
+            await eng.chat("s", "a prompt of a few chunks, so that the chunked rule and then the step both run", max_tokens=9)
+            eng.snapshot_min_gap_s = eng.snapshot_busy_gap_s = 0.0
+            blob = await eng.snapshot_session("s")
+            return await eng.restore_session("t", blob)
+
+        assert asyncio.run(drive()) is True
+        time.sleep(0.2)
+        m = eng.metrics()
+        tokens = jnp.zeros((1, 32), jnp.int32)
+        prefill = eng._prefill.lower(eng.params, eng.cache, jnp.int32(0), tokens, tokens, jnp.int32(4)).as_text(debug_info=True)
+        decode = eng._decode_n.lower(
+            eng.params, eng.cache, eng._dtok, eng._dpos, eng._dtemps, eng._dtopk, eng._dtopp,
+            jax.random.split(jax.random.PRNGKey(0), 1),
+        ).as_text(debug_info=True)
+    finally:
+        eng.shutdown()
+    a, cache, lin, moe = m["attention"], m["cache"], m["linear"], m["moe"]
+    assert m["model_arch"]["layer_kinds"] == {"full": 3, "kda": 6}
+    for key in ("kda_prefill", "kda_decode", "full_prefill", "full_decode", "reason"):
+        assert a[key], key
+    assert a["gate"] == "full" and a["kv_heads_stored"] == 2
+    assert (lin["kind"], lin["heads"], lin["head_dim"], lin["neg_eigval"], lin["conv"]) == ("kda", 4, 16, True, True)
+    assert lin["state_bytes_lane"] == 6 * 4 * 16 * 16 * 4 and lin["rows_chunked"] > 0 and lin["steps"] >= 8
+    assert (moe["held"], moe["published"], moe["offset"]) == (2, 8, 4) and moe["experts_held"] == 2 and moe["experts"] == 8
+    assert cache["kinds"] == ["k", "v", "state", "conv"] and all(cache[leaf + "_bytes"] > 0 for leaf in cache["kinds"])
+    assert cache["state_bytes"] == 2 * lin["state_bytes_lane"] and cache["k_bytes"] == 3 * 2 * 256 * 2 * 16 * 4
+    assert cache["conv_bytes"] == 2 * 6 * 3 * 192 * 4 and cache["state_resets"] >= 1
+    for name in ("engine.snapshot", "engine.restore", "engine.state_reset"):
+        assert m["phases"][name]["n"] >= 1, (name, sorted(m["phases"]))
+    carried = {name: attrs["bytes"] for name, attrs in seen if name in ("engine.snapshot", "engine.restore") and "bytes" in attrs}
+    sizes = {name: {k: int(v) for k, v in (part.split("=") for part in text.split(","))} for name, text in carried.items()}
+    assert sorted(sizes["engine.snapshot"]) == sorted(sizes["engine.restore"]) == ["conv", "k", "state", "v"]
+    assert sizes["engine.restore"]["state"] == sizes["engine.snapshot"]["state"] == lin["state_bytes_lane"]
+    for scope in ("kda_prepass", "kda_scan", "attn_gate", "moe_shared_expert"):
+        assert scope in prefill, scope
+    for scope in ("kda_step", "attn_gate"):
+        assert scope in decode, scope
+    assert "kda_scan" not in decode
+    ledger = m["launches"]
+    assert any(name.startswith("jit_prefill") for name in ledger) and any(name.startswith("jit_decode_n") for name in ledger)
+
+
+def test_attention_names_the_gate_and_the_stored_heads_of_every_block_with_kv_rows():
+    """``attention.gate`` and ``attention.kv_heads_stored`` (ISSUE 57) by the
+    block's kind: a gate a head (Laguna), as wide as the output (a sparse
+    layer's; Solar-Open2's), none (Olmo-Hybrid, whose 6 K/V heads are stored as
+    8); absent where the cache has no K/V rows (Kimi-Linear's latent leaf)."""
+    want = {"tiny-laguna": ("per_head", 2), "tiny-minicpm-sala": ("full", 2), "tiny-olmo-hybrid": ("none", 8),
+            "tiny-solar-open2": ("full", 2), "tiny-kimi-linear": None}
+    for name, said in want.items():
+        eng = LLMEngine.create(name, options={"max_batch": 2, "max_seq": 256, "prefill_chunk": 32, "skip_warmup": True})
+        try:
+            a = eng.metrics()["attention"]
+        finally:
+            eng.shutdown()
+        assert (a.get("gate"), a.get("kv_heads_stored")) == (said or (None, None)), name
 
 
 def test_attention_names_the_prefill_tile_where_the_flash_kernel_serves(monkeypatch):

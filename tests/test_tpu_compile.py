@@ -393,7 +393,7 @@ def _hybrid_case(model: str, where):
 
     from agentainer_tpu.engine.quant import synthetic_quantized_params
     from agentainer_tpu.models import hybrid
-    from agentainer_tpu.models.configs import kimi_linear_kinds, olmo_hybrid_kinds
+    from agentainer_tpu.models.configs import kimi_linear_kinds, olmo_hybrid_kinds, solar_open2_kinds
     from agentainer_tpu.models.llama import init_cache
 
     if model == "kimi-5l":
@@ -424,6 +424,15 @@ def _hybrid_case(model: str, where):
         # of 49,152 (benchmark/configs/minicpm-sala-9b-1chip.json)
         lanes, seq = 8, 49_152
         cfg = dataclasses.replace(get_config("minicpm-sala"), max_seq_len=seq, name=model)
+    elif model == "solar-8l":
+        # the served share whole: layers 0-7 (G K K K G K K K), 40 of 320
+        # experts, the whole vocabulary, 64 lanes of 4,096
+        # (benchmark/configs/solar-open2-250b-ep8-1chip.json)
+        lanes, seq = 64, 4096
+        cfg = dataclasses.replace(
+            get_config("solar-open2"), n_layers=8, layer_kinds=solar_open2_kinds(8), experts_held=40,
+            max_seq_len=seq, name=model,
+        )
     elif model == "laguna-40l":
         # the served share whole: 40 layers, 32 of 256 experts, the whole
         # vocabulary, 8 lanes of 16,384 (benchmark/configs/laguna-xs2-33b-ep8-1chip.json)
@@ -720,6 +729,63 @@ def test_minicpm_sala_step_fits_the_chip_and_keeps_every_leaf_in_place_on_v5e(v5
             stated = json.load(f)["memory"]["compiled_live_bytes"][step]
         assert abs(live - stated) < 0.02 * stated, (live, stated)
     assert cfg.param_count() == 9_476_833_280 + 373_504  # 9.48 GB of int8 weights
+
+
+SOLAR_CONFIG = os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmark", "configs", "solar-open2-250b-ep8-1chip.json")
+
+
+@pytest.mark.parametrize("step", ["decode", "prefill", "mixed"])
+def test_solar_open2_step_fits_the_chip_and_keeps_every_leaf_in_place_on_v5e(v5e, monkeypatch, step):
+    """Solar-Open2's served share at its REAL size (layers 0-7, 40 of 320
+    experts, the whole vocabulary of 196,608, 64 lanes of 4,096, int8 as
+    served): ``jit_decode_n``, ``jit_prefill`` and ``jit_prefill_with_decode``
+    compile for a v5e with the kernels the plan names, a kind at a time:
+    ``kda_decode`` at 64 heads on the ``[6, 64, 64, 128, 128]`` float32 state
+    (1.61 GB) where it lies, ``flash_decode`` / ``flash_prefill`` at 64 query
+    heads over 8 stored K/V heads without rotary embedding, the grouped FFN
+    over 40 held experts (the first held count that is no multiple of 16) past
+    the MoE cut. The four leaves are donated and aliased and none is copied,
+    transposed or padded; the logits are ``[64, 196608]`` (``[65, …]`` mixed),
+    never a chunk's 256 rows; and the step's live bytes fit a v5e's 15.75 GB
+    and are what the configuration file states (``compiled_live_bytes``)."""
+    import json
+
+    if step != "decode":  # the grouped FFN's kernel is chosen by the backend, which is the CPU here
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg, cache, plan, steps = _hybrid_case("solar-8l", SingleDeviceSharding(v5e.devices[0]))
+    assert plan.kinds() == {"kda": ("xla_chunked", "pallas_kda_decode"), "full": ("pallas:flash_prefill", "pallas:flash_decode")}
+    assert cache.state.shape == (6, 64, 64, 128, 128) and cache.state.dtype == jnp.float32
+    assert cache.k.shape == (2, 64, 4096, 8, 128) and cache.conv.shape == (6, 64, 3 * 24576) and cache.latent is None
+    fn, args = steps[step]
+    compiled = fn.lower(*args).compile()
+    text = compiled.as_text()
+    calls = re.findall(r'%([a-z_]+)[.\d]* = [^\n]*custom_call_target="tpu_custom_call"', text)
+    want = {"decode": ["flash_decode", "kda_decode"], "prefill": ["flash_prefill", "moe_grouped_ffn"],
+            "mixed": ["flash_decode", "flash_prefill", "kda_decode", "moe_grouped_ffn"]}[step]
+    assert sorted(calls) == want, calls
+    mem = compiled.memory_analysis()
+    leaves = dict(cache.leaves())
+    assert set(leaves) == {"k", "v", "state", "conv"}
+    assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize for a in leaves.values())
+    for name, a in leaves.items():
+        shape = ",".join(map(str, a.shape))
+        assert not re.search(rf"\[{shape}\][^ ]* (copy|transpose|pad)\(", text), name
+    # no weight stack copied either: the KDA projections, the gate, the held experts, the head
+    for shape in ("6,4096,24576", "6,8192,4096", "2,4096,8192", "2,8192,4096", "8,40,4096,1280", "8,40,1280,4096", "4096,196608"):
+        assert not re.search(rf"\[{shape}\][^ ]* (copy|transpose)\(", text), shape
+    v = cfg.vocab_size
+    if step != "prefill":  # the head on the lanes' rows (and the chunk's last): never the chunk's 256
+        assert not re.search(rf"\[(1,)?(256|{256 + 64}),{v}\]", text)
+        assert re.search(rf"f32\[{64 + (step == 'mixed')},{v}\]", text)
+    live = mem.argument_size_in_bytes + mem.temp_size_in_bytes + mem.output_size_in_bytes - mem.alias_size_in_bytes
+    print(f"solar-8l {step}: args {mem.argument_size_in_bytes / 1e9:.2f} GB, temp {mem.temp_size_in_bytes / 1e9:.2f} GB, "
+          f"out {mem.output_size_in_bytes / 1e9:.2f} GB, aliased {mem.alias_size_in_bytes / 1e9:.2f} GB, live {live / 1e9:.2f} GB")
+    assert 11.0e9 < live < V5E_USABLE_BYTES
+    if os.path.exists(SOLAR_CONFIG):
+        with open(SOLAR_CONFIG) as f:
+            stated = json.load(f)["memory"]["compiled_live_bytes"][step]
+        assert abs(live - stated) < 0.02 * stated, (live, stated)
+    assert cfg.param_count() == 7_824_662_144  # 7.82 GB of int8 weights
 
 
 @pytest.mark.parametrize("step", ["decode", "prefill", "mixed"])
